@@ -200,13 +200,24 @@ class GcsService:
         cfg = global_config()
         interval = cfg.gcs_heartbeat_interval_ms / 1000.0
         threshold = cfg.health_check_failure_threshold
+        last_tick = time.monotonic()
         while not self._stopped.wait(interval):
             now = time.monotonic()
+            # A detector that was not running cannot judge: when THIS loop
+            # overslept (the whole host stalls for 4-5 s while a process
+            # brings the TPU runtime up or down — measured on a v5e host,
+            # CHANGES.md PR 21 — and a raylet in the same process or on
+            # the same host was stalled with it), credit every node the
+            # time nobody was watching instead of declaring it dead.
+            stalled = (now - last_tick) - interval
+            last_tick = now
             dead = []
             with self._lock:
                 for node_id, info in self.nodes.items():
                     if not info["alive"]:
                         continue
+                    if stalled > interval:
+                        info["last_heartbeat"] += stalled
                     if now - info["last_heartbeat"] > interval * threshold:
                         info["alive"] = False
                         dead.append(node_id)

@@ -27,17 +27,25 @@ from ray_tpu._private.raylet import Raylet
 def autodetect_tpu_chips() -> int:
     """Detect local TPU chips without initializing JAX.
 
-    Reference: python/ray/_private/accelerator.py:153 _autodetect_num_tpus
-    reads /dev/accel* and GKE env vars. We honor TPU_CHIPS_OVERRIDE for
-    tests, /dev/accel* device files, and fall back to 0.
+    Reference: python/ray/_private/accelerator.py:153 _autodetect_num_tpus.
+    ``RT_NUM_TPUS`` overrides (tests). Otherwise one chip per
+    ``/dev/accel*`` device file (v2-v4 hosts) or, where there are none,
+    per numbered VFIO group ``/dev/vfio/<n>`` — how a v5e host exposes
+    its chips (one such file on the one-chip machine; /dev/vfio/vfio is
+    the container device, not a chip). 0 when neither exists.
     """
     override = os.environ.get("RT_NUM_TPUS")
     if override:
         return int(override)
-    try:
-        return len([d for d in os.listdir("/dev") if d.startswith("accel")])
-    except OSError:
-        return 0
+
+    def _ls(path: str) -> list[str]:
+        try:
+            return os.listdir(path)
+        except OSError:
+            return []
+
+    accel = [d for d in _ls("/dev") if d.startswith("accel")]
+    return len(accel) or len([d for d in _ls("/dev/vfio") if d.isdigit()])
 
 
 class NodeHandle:

@@ -784,6 +784,10 @@ class Raylet:
             if handle in self._idle_workers:
                 self._idle_workers.remove(handle)
             spec = handle.current_task
+        if handle.assigned_chips:
+            # the chips go back to the pool below: the next claimant must
+            # not start before this process has let go of them
+            self._reap(handle)
         if handle.is_actor_worker and handle.actor_id is not None:
             self._on_actor_worker_death(handle, spec)
         else:
@@ -799,6 +803,23 @@ class Raylet:
                     # dies as an ordinary worker crash instead
                     oom_reason = handle.oom_killed[0]
                 self._on_task_worker_death(spec, oom_reason=oom_reason)
+
+    @staticmethod
+    def _reap(handle: WorkerHandle, timeout: float = 30.0) -> None:
+        """Stop a worker and wait until its process has exited. A chip
+        belongs to one process at a time (libtpu holds it, and its lock,
+        until the process is gone), so chips are returned to the pool
+        only after their holder was reaped."""
+        proc = handle.proc
+        if proc is None:
+            return
+        if proc.poll() is None:
+            proc.terminate()
+        try:
+            proc.wait(timeout)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
 
     def _on_task_worker_death(self, spec: dict, oom_reason: str | None = None) -> None:
         from ray_tpu.exceptions import OutOfMemoryError
@@ -1316,6 +1337,21 @@ class Raylet:
                     if tid is not None and actor["inflight"].pop(tid, None) is not None:
                         actor["executing"] = max(0, actor["executing"] - 1)
             self._pump_actor(aid)
+        elif handle.assigned_chips:
+            # A task worker that was handed chips keeps them (its JAX
+            # backend stays initialized) for as long as it lives: retire
+            # it instead of pooling it, and free the chips once it is gone.
+            # Off the RPC thread — the worker is waiting for this reply.
+            with self._lock:
+                self._all_workers.pop(wid, None)
+
+            def _retire():
+                self._reap(handle)
+                self._release_task_resources(handle)
+                with self._dispatch_cv:
+                    self._dispatch_cv.notify_all()
+
+            threading.Thread(target=_retire, daemon=True).start()
         else:
             self._release_task_resources(handle)
             with self._lock:

@@ -533,9 +533,16 @@ class RpcClient:
         self._connect_timeout = connect_timeout
         self._auto_reconnect = auto_reconnect
         self._reconnect_window = reconnect_window
-        self._send_lock = threading.Lock()
+        # RLocks: an allocation inside either critical section can start a
+        # garbage collection, an ObjectRef.__del__ it runs frees its object
+        # with an RPC on this same client, and with plain locks the thread
+        # then waits for itself (seen as fixtures stuck in serve.shutdown()
+        # with "Garbage-collecting" on top of call_async). A re-entered
+        # call is whole — its own msgid, future and send — so nesting is
+        # safe: sendall has returned before anything allocates again.
+        self._send_lock = threading.RLock()
         self._pending: dict[int, Future] = {}
-        self._pending_lock = threading.Lock()
+        self._pending_lock = threading.RLock()
         self._msgid = 0
         self._gen = 0  # connection generation; bumped by reconnect()
         self._notify_handler = notify_handler

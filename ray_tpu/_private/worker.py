@@ -16,6 +16,7 @@ pinning).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from concurrent.futures import Future
@@ -38,6 +39,36 @@ from ray_tpu.exceptions import (
 
 _GET_POLL_MS = 2000  # per-attempt blocking window; between attempts we check
                      # for eviction + lineage reconstruction
+
+
+# what this process was started with, restored when a pooled worker that
+# ran a chip-less task is next handed chips
+_JAX_PLATFORMS_AT_START = os.environ.get("JAX_PLATFORMS")
+
+
+def _bind_chips(chips: list[int]) -> None:
+    """Point this process's JAX at exactly the chips the raylet assigned.
+
+    With chips: ``TPU_VISIBLE_CHIPS`` lists them, as the reference does.
+    Without: an EMPTY ``TPU_VISIBLE_CHIPS`` hides nothing — libtpu ignores
+    it and the process sees (and, on first device use, seizes) every chip
+    of the host (measured on a v5e host, CHANGES.md PR 21). A task that
+    was assigned no chip must not take one from the task that was, so it
+    is held to the CPU platform instead. Only effective before the
+    process's first JAX device use, like every platform choice."""
+    if chips:
+        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
+        platforms = _JAX_PLATFORMS_AT_START
+        if platforms is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = platforms
+    else:
+        os.environ.pop("TPU_VISIBLE_CHIPS", None)
+        platforms = os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.config.jax_platforms != platforms:
+        jax.config.update("jax_platforms", platforms)
 
 
 class CoreWorker:
@@ -525,7 +556,7 @@ class CoreWorker:
             # context (task_id, chips env) stays that of the creation task
             self._method_pool.submit(self._execute_actor_method_concurrent, spec)
             return
-        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
+        _bind_chips(chips)
         os.environ["RT_TASK_RESOURCES"] = repr(spec["resources"])
         prev_task = self.task_id
         self.task_id = TaskID(spec["task_id"])

@@ -1,6 +1,6 @@
 """User-facing exception types.
 
-Equivalent of the reference's python/ray/exceptions.py error taxonomy
+Equivalent of the reference's python/ray/exceptions.py error hierarchy
 (RayError / RayTaskError / RayActorError / ObjectLostError ...).
 """
 from __future__ import annotations

@@ -110,19 +110,12 @@ def ring_attention_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8 (check_rep became check_vma)
-        _rep_kw = {"check_vma": False}
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-        _rep_kw = {"check_rep": False}
-
     spec = P(batch_axes, None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_rep_kw,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -100,16 +100,10 @@ def pipeline_apply(
         outputs = jax.lax.psum(outputs * mask, axis)
         return jnp.reshape(outputs, x.shape[0:1] + outputs.shape[2:])
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-    kwargs = dict(mesh=mesh, in_specs=(P(AxisNames.PIPE), P()), out_specs=P())
-    try:  # new API (jax.shard_map) renamed check_rep -> check_vma
-        return shard_map(spmd, check_vma=False, **kwargs)
-    except TypeError:
-        return shard_map(spmd, check_rep=False, **kwargs)
+    return jax.shard_map(
+        spmd, mesh=mesh, in_specs=(P(AxisNames.PIPE), P()), out_specs=P(),
+        check_vma=False,
+    )
 
 
 def simple_stage_mlp(d_model: int, d_hidden: int):

@@ -32,11 +32,13 @@ uninterrupted one by construction — no RNG state to replay.
 from __future__ import annotations
 
 import logging
+import os
 import uuid
 from collections import OrderedDict
 from typing import Any
 
 from ray_tpu._private import chaos
+from ray_tpu._private.compile_cache import enable_compile_cache
 from ray_tpu.exceptions import EngineOverloadedError
 from ray_tpu.serve.deployment import Application, deployment
 from ray_tpu.serve.llm import obs
@@ -100,6 +102,9 @@ class LLMDeployment:
             engine_config = dataclasses.replace(
                 engine_config or EngineConfig(), mesh=mesh
             )
+        # the replica compiles every bucket's prefill/chunk/decode step:
+        # keep them in the one persistent cache
+        self._compile_cache = enable_compile_cache()
         self.engine = LLMEngine(engine_config)
         # Disaggregated serving: binding a prefill Application here makes
         # serve.run deploy both pools as one app (Application.flatten);
@@ -259,6 +264,9 @@ class LLMDeployment:
         """Engine introspection (unary method — callable via handle)."""
         out = self.engine.stats()
         out["requests_resumed"] = self._resumed_total
+        out["compile_signatures"] = sorted(self.engine.fns.signatures)
+        out["compile_cache"] = dict(self._compile_cache)
+        out["pid"] = os.getpid()
         return out
 
     def request_timeline(self, request_id: str) -> dict | None:

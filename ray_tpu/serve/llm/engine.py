@@ -765,7 +765,6 @@ class LLMEngine:
         # (multiply+accumulate), the serving-side counterpart of the
         # training 6N rule (docs/ROOFLINE.md, benchmarks/gpt_mfu.py).
         self._flops_per_token = 2.0 * self.executor.num_params
-        self._peak_flops = self.executor.peak_tflops * 1e12
         # per step kind: ring of (clock, device_s, tokens) step samples
         # plus the last derived rates, for stats()/the decode bench
         self._goodput_windows: dict[str, deque] = {}
@@ -2212,9 +2211,11 @@ class LLMEngine:
         if dev_s <= 0.0 or toks <= 0:
             return
         tps = toks / dev_s
+        # a device with no published peak has no MFU: 0.0, never a guess
+        peak_flops = (self.executor.peak_tflops or 0.0) * 1e12
         mfu = (
-            tps * self._flops_per_token / self._peak_flops
-            if self._peak_flops > 0.0
+            tps * self._flops_per_token / peak_flops
+            if peak_flops > 0.0
             else 0.0
         )
         self._m_goodput.set(tps, tags={"kind": kind})

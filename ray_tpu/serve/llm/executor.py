@@ -9,8 +9,8 @@ never sees. Two interchangeable implementations:
 
 - `SingleDeviceExecutor` — exactly the PR 1-5 behavior: one chip, plain
   `jnp.asarray` staging, unsharded weights and KV pool. The default.
-- `ShardedExecutor` — a tp/fsdp mesh over several chips (ROADMAP item 1:
-  models larger than one chip's HBM). It builds a mesh from
+- `ShardedExecutor` — a tp/fsdp mesh over several chips (models larger
+  than one chip's HBM). It builds a mesh from
   `ray_tpu.parallel.mesh.MeshSpec`, shards the weights with the same
   logical-axis rules training uses (parallel/sharding.py DEFAULT_RULES:
   heads/mlp/vocab -> tp, embed -> fsdp), and shards the paged KV pool
@@ -393,25 +393,38 @@ class ModelExecutor:
         return self._num_params
 
     @property
-    def peak_tflops(self) -> float:
-        """Aggregate peak bf16 TFLOP/s across this executor's devices —
-        the MFU denominator. Reuses the per-chip table the training
-        benchmarks publish against (benchmarks/gpt_mfu.py); on CPU the
-        nominal 0.5 TFLOP/s keeps the ratio defined (not meaningful as a
-        hardware ceiling, but nonzero and stable for CI)."""
+    def peak_tflops(self) -> float | None:
+        """Aggregate published peak bf16 TFLOP/s across this executor's
+        devices — the MFU denominator, from the one per-chip table
+        (benchmarks/gpt_mfu.py CHIP_PEAK_TFLOPS). None for a device that
+        has no published peak (the CPU included): the engine then reports
+        no MFU instead of one against an invented ceiling. Settable — a
+        test of the gauge hands the executor a peak of its own."""
         from ray_tpu.benchmarks.gpt_mfu import chip_peak_tflops
 
-        if getattr(self, "_peak_tflops", None) is None:
-            dev = self._devices()[0]
-            self._peak_tflops = (
-                chip_peak_tflops(dev) * float(self.num_devices)
-            )
+        if not hasattr(self, "_peak_tflops"):
+            try:
+                self._peak_tflops = (
+                    chip_peak_tflops(self._devices()[0])
+                    * float(self.num_devices)
+                )
+            except ValueError:
+                self._peak_tflops = None
         return self._peak_tflops
+
+    @peak_tflops.setter
+    def peak_tflops(self, value: float | None) -> None:
+        self._peak_tflops = value
 
     def _devices(self):
         import jax
 
         return jax.devices()
+
+    def _device_report(self) -> dict:
+        """What the steps actually run on, as JAX reports it."""
+        dev = self._devices()[0]
+        return {"platform": dev.platform, "device_kind": dev.device_kind}
 
     @property
     def attention_backend(self) -> str:
@@ -433,7 +446,7 @@ class ModelExecutor:
         executor is serving, over how many devices, and which decode
         attention backend the model steps compiled with."""
         return {"executor": self.kind, "devices": self.num_devices,
-                "mesh": None,
+                "mesh": None, **self._device_report(),
                 "attention_backend": self.attention_backend,
                 "quantization": getattr(
                     self.model_cfg, "quantization", None),
@@ -491,6 +504,26 @@ def _resolve_mesh(mesh, tp: int, fsdp: int):
     return build_mesh(spec, devices)
 
 
+def _in_mesh(name: str):
+    """``ModelExecutor``'s step ``name``, traced and run with the
+    executor's mesh set (``jax.set_mesh``): GSPMD needs no context, but
+    the Pallas attention kernel is an opaque custom call it cannot
+    partition, so ops/paged_attention.py reads the mesh from the context
+    and runs the kernel in a ``shard_map`` over the head axis. The mesh is
+    part of jit's trace key — the process-shared wrappers in decode.py
+    keep one program per (shape, mesh)."""
+    base = getattr(ModelExecutor, name)
+
+    def step(self, *args, **kwargs):
+        import jax
+
+        with jax.set_mesh(self.mesh):
+            return base(self, *args, **kwargs)
+
+    step.__name__ = name
+    return step
+
+
 class ShardedExecutor(ModelExecutor):
     """tp/fsdp execution over a device mesh.
 
@@ -509,7 +542,9 @@ class ShardedExecutor(ModelExecutor):
     The step functions themselves are the process-shared jit wrappers
     from decode.py: sharding flows from the committed params/pool inputs
     (GSPMD), so no pjit re-wrap, no new compile kinds, and the engine's
-    signature accounting is unchanged. Requires ``n_kv_head % tp == 0``
+    signature accounting is unchanged. The one thing GSPMD cannot split
+    is the compiled Pallas attention kernel, which therefore runs in a
+    ``shard_map`` over the head axis (see ``_in_mesh``). Requires ``n_kv_head % tp == 0``
     (the pool's head axis must split evenly) and a tp/fsdp-only mesh —
     dp/sp/pp/ep serving is future roadmap, not silently wrong."""
 
@@ -563,6 +598,11 @@ class ShardedExecutor(ModelExecutor):
         cache.k = jax.tree.map(lambda a: jax.device_put(a, sh), cache.k)
         cache.v = jax.tree.map(lambda a: jax.device_put(a, sh), cache.v)
 
+    prefill = _in_mesh("prefill")
+    prefill_chunk = _in_mesh("prefill_chunk")
+    decode_step = _in_mesh("decode_step")
+    verify_step = _in_mesh("verify_step")
+
     @property
     def num_devices(self) -> int:
         return self.mesh.devices.size
@@ -578,6 +618,7 @@ class ShardedExecutor(ModelExecutor):
             # the operator-facing mesh shape
             "mesh": {a: int(s) for a, s in self.mesh.shape.items()
                      if int(s) > 1},
+            **self._device_report(),
             "attention_backend": self.attention_backend,
             "quantization": getattr(self.model_cfg, "quantization", None),
             "speculative": self.speculative,

@@ -59,6 +59,11 @@ class TrainWorker:
                     rank=rank, world_size=world_size,
                 )
             return True
+        # this process is about to compile the train step: one shared
+        # persistent cache (the import is cheap — no backend is touched)
+        from ray_tpu._private.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         if not enabled or world_size <= 1:
             return True
         import jax
@@ -150,7 +155,12 @@ class WorkerGroup:
                 },
             )
             w = TrainWorker.options(
-                num_cpus=0,  # resources come from the bundle
+                num_cpus=0,  # the CPU share comes from the bundle
+                # the chips are DRAWN from the bundle, so the raylet binds
+                # them to this worker (a worker that was assigned no chip
+                # is held to the CPU platform — _private/worker.py)
+                num_tpus=(self.scaling.chips_per_worker
+                          if self.scaling.use_tpu else 0),
                 scheduling_strategy=PlacementGroupSchedulingStrategy(
                     placement_group=self.pg, placement_group_bundle_index=rank
                 ),

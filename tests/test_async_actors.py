@@ -11,6 +11,8 @@ import time
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 
 def test_concurrent_actor_overlaps_methods(ray_start):
     """N slow methods on a max_concurrency=N actor finish in ~1x the
@@ -134,7 +136,8 @@ def serve_cluster():
     from ray_tpu import serve
 
     ray_tpu.init(num_cpus=6)
-    serve.start(http_options={"port": 18127})
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": 18127})
     yield serve
     serve.shutdown()
     ray_tpu.shutdown()

@@ -18,6 +18,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu.util import metrics
 from ray_tpu.util.metrics import FleetAggregator, sample_key
 
@@ -203,6 +205,10 @@ def test_engine_goodput_and_mfu_nonzero_per_step_kind(jax_cpu):
                      block_size=8, num_blocks=64),
         auto_step=True,
     )
+    # the CPU has no published peak (chip_peak_tflops raises, the executor
+    # reports None and the engine no MFU): hand the gauge one of our own
+    assert eng.executor.peak_tflops is None
+    eng.executor.peak_tflops = 0.5
     try:
         out = eng.generate([1, 2, 3], max_new_tokens=8)
         assert len(out) == 8
@@ -235,21 +241,22 @@ def fleet_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    # EveryNode: per-node proxy ACTORS, so the fleet plane has a
-    # "proxy:" source to poll (Driver mode hosts the proxy in this
-    # process, which the controller cannot reach)
-    serve.start(http_options={"port": 0}, proxy_location="EveryNode")
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=_model_config(),
-                         seed=0),
-            num_replicas=2,
-            graceful_shutdown_timeout_s=2.0,
-        ),
-        name=APP, route_prefix="/fleet", timeout_s=300,
-    )
-    ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
-    dash = start_dashboard(port=DASH_PORT)
+    with shutdown_if_setup_fails():
+        # EveryNode: per-node proxy ACTORS, so the fleet plane has a
+        # "proxy:" source to poll (Driver mode hosts the proxy in this
+        # process, which the controller cannot reach)
+        serve.start(http_options={"port": 0}, proxy_location="EveryNode")
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=_model_config(),
+                             seed=0),
+                num_replicas=2,
+                graceful_shutdown_timeout_s=2.0,
+            ),
+            name=APP, route_prefix="/fleet", timeout_s=300,
+        )
+        ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
+        dash = start_dashboard(port=DASH_PORT)
     yield {"handle": handle, "ctrl": ctrl, "ray": ray_tpu}
     dash.stop()
     serve.shutdown()

@@ -35,6 +35,8 @@ import types
 import numpy as np
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
@@ -591,11 +593,12 @@ def host_tier_cluster():
         block_size=8, num_blocks=17, host_cache_bytes=1 << 24,
     )
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    handle = serve.run(
-        build_llm_app(ecfg, num_replicas=2),
-        name="llm-host-tier", route_prefix="/hosttier", timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        handle = serve.run(
+            build_llm_app(ecfg, num_replicas=2),
+            name="llm-host-tier", route_prefix="/hosttier", timeout_s=180,
+        )
     yield serve, handle, ecfg
     serve.shutdown()
     ray_tpu.shutdown()

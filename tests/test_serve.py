@@ -9,6 +9,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu.serve.autoscaling_policy import (
     AutoscalingDecider,
     calculate_desired_num_replicas,
@@ -64,7 +66,8 @@ def serve_cluster():
     from ray_tpu import serve
 
     ray_tpu.init(num_cpus=6)
-    serve.start(http_options={"port": 18123})
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": 18123})
     yield ray_tpu, serve
     serve.shutdown()
     ray_tpu.shutdown()

@@ -24,6 +24,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.serve.autoscaling_policy import (
@@ -309,43 +311,44 @@ def as_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT})
-    main_handle = serve.run(
-        build_llm_app(
-            # capacity 6 per replica (2 running + 4 queued): the 4-stream
-            # kill burst always fits on the survivor, and the shed phase
-            # overflows it with a 16-hog fleet
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                max_batch_size=2, max_prefill_batch=2, max_waiting=4,
-                block_size=16, num_blocks=256,
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT})
+        main_handle = serve.run(
+            build_llm_app(
+                # capacity 6 per replica (2 running + 4 queued): the 4-stream
+                # kill burst always fits on the survivor, and the shed phase
+                # overflows it with a 16-hog fleet
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    max_batch_size=2, max_prefill_batch=2, max_waiting=4,
+                    block_size=16, num_blocks=256,
+                ),
+                autoscaling_config=dict(min_replicas=2, max_replicas=2),
             ),
-            autoscaling_config=dict(min_replicas=2, max_replicas=2),
-        ),
-        name="llm-main", route_prefix="/main", timeout_s=300,
-    )
-    as_handle = serve.run(
-        build_llm_app(
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                max_batch_size=1, max_prefill_batch=1, max_waiting=1,
-                block_size=16, num_blocks=256,
+            name="llm-main", route_prefix="/main", timeout_s=300,
+        )
+        as_handle = serve.run(
+            build_llm_app(
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    max_batch_size=1, max_prefill_batch=1, max_waiting=1,
+                    block_size=16, num_blocks=256,
+                ),
+                autoscaling_config=dict(
+                    min_replicas=1, max_replicas=2,
+                    upscale_delay_periods=1, downscale_delay_periods=10_000,
+                    # hotness must come ONLY from rejections (probes we
+                    # control): queue-wait samples from the drain hand-off
+                    # must never re-trigger an upscale after the scale-down
+                    upscale_queue_wait_p95_s=30.0,
+                ),
+                graceful_shutdown_timeout_s=2.0,
             ),
-            autoscaling_config=dict(
-                min_replicas=1, max_replicas=2,
-                upscale_delay_periods=1, downscale_delay_periods=10_000,
-                # hotness must come ONLY from rejections (probes we
-                # control): queue-wait samples from the drain hand-off
-                # must never re-trigger an upscale after the scale-down
-                upscale_queue_wait_p95_s=30.0,
-            ),
-            graceful_shutdown_timeout_s=2.0,
-        ),
-        name="llm-as", route_prefix="/as", timeout_s=300,
-    )
-    from ray_tpu.serve.controller import CONTROLLER_NAME
+            name="llm-as", route_prefix="/as", timeout_s=300,
+        )
+        from ray_tpu.serve.controller import CONTROLLER_NAME
 
-    ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
+        ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
     yield {"main": main_handle, "as": as_handle, "ctrl": ctrl,
            "serve": serve, "ray": ray_tpu}
     serve.shutdown()

@@ -27,6 +27,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.serve.controller import (
@@ -348,20 +350,21 @@ def ft_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT})
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                max_batch_size=2, max_prefill_batch=2, max_waiting=4,
-                block_size=16, num_blocks=256,
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT})
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    max_batch_size=2, max_prefill_batch=2, max_waiting=4,
+                    block_size=16, num_blocks=256,
+                ),
+                num_replicas=1,
+                graceful_shutdown_timeout_s=2.0,
             ),
-            num_replicas=1,
-            graceful_shutdown_timeout_s=2.0,
-        ),
-        name=APP, route_prefix="/ft", timeout_s=300,
-    )
-    ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
+            name=APP, route_prefix="/ft", timeout_s=300,
+        )
+        ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
     yield {"handle": handle, "ctrl": ctrl, "serve": serve, "ray": ray_tpu}
     serve.shutdown()
     ray_tpu.shutdown()

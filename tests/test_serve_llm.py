@@ -16,6 +16,8 @@ import urllib.request
 import numpy as np
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 HTTP_PORT = 18151
 
 
@@ -269,11 +271,12 @@ def llm_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=6)
-    serve.start(http_options={"port": HTTP_PORT})
-    handle = serve.run(
-        build_llm_app(EngineConfig(model="llama", seed=0)),
-        name="llm", route_prefix="/llm", timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT})
+        handle = serve.run(
+            build_llm_app(EngineConfig(model="llama", seed=0)),
+            name="llm", route_prefix="/llm", timeout_s=180,
+        )
     yield serve, handle
     serve.shutdown()
     ray_tpu.shutdown()

@@ -25,6 +25,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.serve.autoscaling_policy import snapshot_is_hot
@@ -389,40 +391,41 @@ def dg_cluster():
         block_size=8, num_blocks=64,
     )
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT})
-    dg_handle = serve.run(
-        build_llm_app(
-            ecfg,
-            prefill_replicas=2,
-            autoscaling_config=dict(min_replicas=1, max_replicas=1),
-        ),
-        name="llm-dg", route_prefix="/dg", timeout_s=300,
-    )
-    # tight admission on the scaling app: rejections are the ONLY
-    # admission-side saturation probe the test drives
-    scfg = dataclasses.replace(
-        ecfg, max_batch_size=1, max_prefill_batch=1, max_waiting=1)
-    dgs_handle = serve.run(
-        build_llm_app(
-            scfg,
-            prefill_replicas=1,
-            prefill_options=dict(autoscaling_config=dict(
-                min_replicas=1, max_replicas=2, signal_mode="prefill",
-                upscale_delay_periods=1, downscale_delay_periods=10_000,
-                upscale_queue_wait_p95_s=30.0,
-            )),
-            autoscaling_config=dict(
-                min_replicas=1, max_replicas=2, signal_mode="decode",
-                upscale_delay_periods=1, downscale_delay_periods=10_000,
-                upscale_queue_wait_p95_s=30.0,
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT})
+        dg_handle = serve.run(
+            build_llm_app(
+                ecfg,
+                prefill_replicas=2,
+                autoscaling_config=dict(min_replicas=1, max_replicas=1),
             ),
-        ),
-        name="llm-dgs", route_prefix="/dgs", timeout_s=300,
-    )
-    from ray_tpu.serve.controller import CONTROLLER_NAME
+            name="llm-dg", route_prefix="/dg", timeout_s=300,
+        )
+        # tight admission on the scaling app: rejections are the ONLY
+        # admission-side saturation probe the test drives
+        scfg = dataclasses.replace(
+            ecfg, max_batch_size=1, max_prefill_batch=1, max_waiting=1)
+        dgs_handle = serve.run(
+            build_llm_app(
+                scfg,
+                prefill_replicas=1,
+                prefill_options=dict(autoscaling_config=dict(
+                    min_replicas=1, max_replicas=2, signal_mode="prefill",
+                    upscale_delay_periods=1, downscale_delay_periods=10_000,
+                    upscale_queue_wait_p95_s=30.0,
+                )),
+                autoscaling_config=dict(
+                    min_replicas=1, max_replicas=2, signal_mode="decode",
+                    upscale_delay_periods=1, downscale_delay_periods=10_000,
+                    upscale_queue_wait_p95_s=30.0,
+                ),
+            ),
+            name="llm-dgs", route_prefix="/dgs", timeout_s=300,
+        )
+        from ray_tpu.serve.controller import CONTROLLER_NAME
 
-    ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
-    prefill_handle = serve.get_deployment_handle("LLMPrefill", "llm-dg")
+        ctrl = ray_tpu.get_actor(CONTROLLER_NAME)
+        prefill_handle = serve.get_deployment_handle("LLMPrefill", "llm-dg")
     yield {
         "decode": dg_handle, "prefill": prefill_handle,
         "dgs": dgs_handle, "ctrl": ctrl, "serve": serve,

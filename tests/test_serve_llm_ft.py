@@ -19,6 +19,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
@@ -259,32 +261,33 @@ def ft_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    ft_handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=_model_config(), seed=0),
-            num_replicas=2,
-        ),
-        name="llm-ft", route_prefix="/llmft", timeout_s=180,
-    )
-    tiny_handle = serve.run(
-        build_llm_app(
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                max_batch_size=1, max_prefill_batch=1, max_waiting=1,
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        ft_handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=_model_config(), seed=0),
+                num_replicas=2,
             ),
-        ),
-        name="llm-tiny", route_prefix="/tiny", timeout_s=180,
-    )
+            name="llm-ft", route_prefix="/llmft", timeout_s=180,
+        )
+        tiny_handle = serve.run(
+            build_llm_app(
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    max_batch_size=1, max_prefill_batch=1, max_waiting=1,
+                ),
+            ),
+            name="llm-tiny", route_prefix="/tiny", timeout_s=180,
+        )
 
-    @serve.deployment
-    class Slow:
-        def __call__(self, payload):
-            time.sleep(0.8)
-            return "done"
+        @serve.deployment
+        class Slow:
+            def __call__(self, payload):
+                time.sleep(0.8)
+                return "done"
 
-    slow_handle = serve.run(Slow.bind(), name="slow", route_prefix="/slow",
-                            timeout_s=180)
+        slow_handle = serve.run(Slow.bind(), name="slow", route_prefix="/slow",
+                                timeout_s=180)
     yield serve, {"ft": ft_handle, "tiny": tiny_handle, "slow": slow_handle}
     serve.shutdown()
     ray_tpu.shutdown()

@@ -24,11 +24,13 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos, event_stats
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.util import metrics, tracing
 
-HTTP_PORT = 18173
+HTTP_PORT = 18183
 
 
 def _f32(cfg):
@@ -369,15 +371,16 @@ def obs_cluster(tmp_path_factory):
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options=None)
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=_model_config(),
-                         seed=0),
-            num_replicas=2,
-        ),
-        name="llm-obs", route_prefix="/llmobs", timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options=None)
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=_model_config(),
+                             seed=0),
+                num_replicas=2,
+            ),
+            name="llm-obs", route_prefix="/llmobs", timeout_s=180,
+        )
     yield serve, handle, flight_dir
     serve.shutdown()
     ray_tpu.shutdown()

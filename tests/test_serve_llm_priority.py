@@ -24,6 +24,8 @@ import time
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
@@ -428,18 +430,19 @@ def priority_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                block_size=4, num_blocks=24,
-                preemption=dict(PREEMPTION),
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    block_size=4, num_blocks=24,
+                    preemption=dict(PREEMPTION),
+                ),
+                num_replicas=2,
             ),
-            num_replicas=2,
-        ),
-        name="llm-prio", route_prefix="/prio", timeout_s=180,
-    )
+            name="llm-prio", route_prefix="/prio", timeout_s=180,
+        )
     yield serve, handle
     serve.shutdown()
     ray_tpu.shutdown()

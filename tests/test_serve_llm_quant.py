@@ -27,6 +27,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
@@ -633,15 +635,16 @@ def quant_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=_model_config(),
-                         seed=0, quantization="int8"),
-            num_replicas=2,
-        ),
-        name="llm-quant", route_prefix="/llmquant", timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=_model_config(),
+                             seed=0, quantization="int8"),
+                num_replicas=2,
+            ),
+            name="llm-quant", route_prefix="/llmquant", timeout_s=180,
+        )
     yield serve, handle
     serve.shutdown()
     ray_tpu.shutdown()

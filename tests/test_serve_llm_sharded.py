@@ -97,6 +97,8 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
     st = eng.stats()
     assert st["executor"] == {"executor": "sharded", "devices": 4,
                               "mesh": {"tp": 2, "fsdp": 2},
+                              "platform": "cpu", "device_kind": "cpu",
+                              "quantization": None,
                               "attention_backend": "xla",
                               "speculative": None}
     assert eng.debug_dump()["executor"]["mesh"] == {"tp": 2, "fsdp": 2}
@@ -111,6 +113,9 @@ def test_single_device_default_unchanged(jax_cpu):
     assert isinstance(eng.executor, SingleDeviceExecutor)
     assert eng.stats()["executor"] == {"executor": "single", "devices": 1,
                                        "mesh": None,
+                                       "platform": "cpu",
+                                       "device_kind": "cpu",
+                                       "quantization": None,
                                        "attention_backend": "xla",
                                        "speculative": None}
     assert len(eng.generate([5, 6, 7], max_new_tokens=4)) == 4

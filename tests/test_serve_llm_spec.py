@@ -22,10 +22,12 @@ import dataclasses
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18177
+HTTP_PORT = 18187
 
 # repeating-structure prompt: the regime prompt-lookup drafting targets.
 # This particular motif is one the tiny f32 llama greedily CONTINUES, so
@@ -381,17 +383,18 @@ def spec_ft_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(
-                model="llama", model_config=_model_config(), seed=0,
-                speculative_k=3,
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(
+                    model="llama", model_config=_model_config(), seed=0,
+                    speculative_k=3,
+                ),
+                num_replicas=2,
             ),
-            num_replicas=2,
-        ),
-        name="llm-spec-ft", route_prefix="/llmspec", timeout_s=180,
-    )
+            name="llm-spec-ft", route_prefix="/llmspec", timeout_s=180,
+        )
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()

@@ -35,10 +35,12 @@ import time
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18191
+HTTP_PORT = 18193
 
 VOCAB = 512  # tiny-config vocab: tokens < 256 are bytes, verbatim
 EOS = 0      # NUL never appears in grammar text, so the bit is unambiguous
@@ -602,16 +604,17 @@ def structured_cluster():
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=_model_config(),
-                         seed=0, eos_id=EOS, block_size=8, num_blocks=64),
-            num_replicas=2,
-        ),
-        name="llm-structured", route_prefix="/llmstructured",
-        timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=_model_config(),
+                             seed=0, eos_id=EOS, block_size=8, num_blocks=64),
+                num_replicas=2,
+            ),
+            name="llm-structured", route_prefix="/llmstructured",
+            timeout_s=180,
+        )
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()

@@ -15,6 +15,8 @@ import urllib.request
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 N_CHUNKS = 4
 CHUNK_GAP_S = 0.8
 # first chunk must land at least this long before the stream completes;
@@ -30,25 +32,26 @@ def streaming_cluster():
     from ray_tpu import serve
 
     ray_tpu.init(num_cpus=6)
-    serve.start(http_options={"port": HTTP_PORT},
-                grpc_options={"port": 0})
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": HTTP_PORT},
+                    grpc_options={"port": 0})
 
-    @serve.deployment
-    class Decoder:
-        """Fake LLM decode loop: one token per CHUNK_GAP_S."""
+        @serve.deployment
+        class Decoder:
+            """Fake LLM decode loop: one token per CHUNK_GAP_S."""
 
-        def __call__(self, payload):
-            prompt = (payload or {}).get("prompt", "")
-            for i in range(N_CHUNKS):
-                yield {"token": f"{prompt}-{i}"}
-                if i < N_CHUNKS - 1:
-                    time.sleep(CHUNK_GAP_S)
+            def __call__(self, payload):
+                prompt = (payload or {}).get("prompt", "")
+                for i in range(N_CHUNKS):
+                    yield {"token": f"{prompt}-{i}"}
+                    if i < N_CHUNKS - 1:
+                        time.sleep(CHUNK_GAP_S)
 
-        def plain(self, payload):
-            return {"done": True, "payload": payload}
+            def plain(self, payload):
+                return {"done": True, "payload": payload}
 
-    serve.run(Decoder.bind(), name="stream_app", route_prefix="/decode",
-              timeout_s=180)
+        serve.run(Decoder.bind(), name="stream_app", route_prefix="/decode",
+                  timeout_s=180)
     yield ray_tpu, serve
     serve.shutdown()
     ray_tpu.shutdown()

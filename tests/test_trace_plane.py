@@ -17,6 +17,8 @@ import time
 
 import pytest
 
+from conftest import shutdown_if_setup_fails
+
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.serve.slo import SLOSpec, default_slos, evaluate
@@ -374,14 +376,15 @@ def trace_cluster():
     mc = dataclasses.replace(
         LlamaConfig.tiny(), dtype=jnp.float32, attention="xla")
     ray_tpu.init(num_cpus=8)
-    serve.start(http_options={"port": 18177}, grpc_options=None)
-    handle = serve.run(
-        build_llm_app(
-            EngineConfig(model="llama", model_config=mc, seed=0),
-            num_replicas=2,
-        ),
-        name="llm-trace", route_prefix="/llmtrace", timeout_s=180,
-    )
+    with shutdown_if_setup_fails():
+        serve.start(http_options={"port": 18177}, grpc_options=None)
+        handle = serve.run(
+            build_llm_app(
+                EngineConfig(model="llama", model_config=mc, seed=0),
+                num_replicas=2,
+            ),
+            name="llm-trace", route_prefix="/llmtrace", timeout_s=180,
+        )
     yield serve, handle, mc
     serve.shutdown()
     ray_tpu.shutdown()
