@@ -266,6 +266,7 @@ def _flash_forward(
             vmem_limit_bytes=100 * 1024 * 1024,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
+        name="flash_fwd",
         interpret=interpret,
     )(qf, kf, vf)
     out, lse = (result[0], result[1]) if save_lse else (result[0], None)
@@ -515,6 +516,7 @@ def _flash_backward(
             vmem_limit_bytes=100 * 1024 * 1024,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(qf, kf, vf, dof, lse_b, delta_b)
 
@@ -551,6 +553,7 @@ def _flash_backward(
             vmem_limit_bytes=100 * 1024 * 1024,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qf, kf, vf, dof, lse_b, delta_b)
 

@@ -83,19 +83,23 @@ def family_quant_axes(family: str, model_cfg):
 _jit_cache: dict[tuple, tuple] = {}
 
 
+def _jit_named(fn, model_cfg):
+    """``jax.jit`` of ``fn`` with the config bound, under ``fn``'s own name:
+    a bare ``functools.partial`` has none, and its program would be
+    ``jit__unknown`` in every compiler dump and profiler trace."""
+    import jax
+
+    bound = functools.partial(fn, cfg=model_cfg)
+    bound.__name__ = fn.__name__
+    return jax.jit(bound)
+
+
 def _jitted(family: str, model_cfg):
     key = (family, model_cfg)
     hit = _jit_cache.get(key)
     if hit is None:
-        import jax
-
-        init, prefill_fn, decode_fn, verify_fn = FAMILIES[family](model_cfg)
-        hit = (
-            init,
-            jax.jit(functools.partial(prefill_fn, cfg=model_cfg)),
-            jax.jit(functools.partial(decode_fn, cfg=model_cfg)),
-            jax.jit(functools.partial(verify_fn, cfg=model_cfg)),
-        )
+        init, *steps = FAMILIES[family](model_cfg)
+        hit = (init, *(_jit_named(fn, model_cfg) for fn in steps))
         _jit_cache[key] = hit
     return hit
 

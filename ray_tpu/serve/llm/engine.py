@@ -94,7 +94,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
-from ray_tpu._private import chaos, event_stats
+from ray_tpu._private import chaos
 from ray_tpu.exceptions import (
     DeadlineExceededError,
     EngineDiedError,
@@ -622,6 +622,16 @@ class LLMEngine:
         # cache-stat values as of the previous flight record (deltas)
         self._flight_prev = {"cow": 0, "evict": 0, "demote": 0, "promote": 0}
         self._dumped = False  # one post-mortem dump per engine
+        # Host phases (obs.phase): the step in progress books into
+        # ``_step_phases`` ({name: [count, seconds]}; the executor books
+        # its two phases there too), and ``step()`` folds that into
+        # ``_phases[kind]`` under the kind the step went on to run
+        # ("none": nothing ran; the loop's ``engine.wait`` goes there too).
+        self._step_phases: dict[str, list] = {}
+        self._phases: dict[str, dict[str, list]] = {}
+        self._step_kind = "none"
+        self._decode_steps = 0  # decode dispatches ...
+        self._decode_steps_steady = 0  # ... fed from device tokens (lag 1)
         # ---- autoscaling signal windows (ISSUE 10) ----
         # Bounded sample/event rings feeding autoscaling_snapshot(): the
         # controller's policy wants recent-tail saturation (queue-wait
@@ -775,6 +785,7 @@ class LLMEngine:
         # signatures (attribute hook, forwarded through the executor —
         # DecodeFns stays constructible bare)
         self.executor.on_new_signature = self._on_new_signature
+        self.executor.phases = self._step_phases
 
     # ---------------- public API ----------------
 
@@ -848,6 +859,9 @@ class LLMEngine:
             self._m_structured.inc()
         if self._failed is not None:
             raise self._failed
+        # stamped before the lock, which a step in flight holds to its end:
+        # ``submitted - received`` in the timeline is the wait for it
+        received = obs.wall()
         with self._lock:
             if self._stopped:
                 raise RuntimeError("engine is shut down")
@@ -867,6 +881,7 @@ class LLMEngine:
             req.fsm = fsm
             self._next_id += 1
             req.submitted_clock = obs.clock()
+            self._tl(req, "received", ts=received)
             self._tl(req, "submitted", prompt_tokens=len(prompt),
                      max_new_tokens=sampling.max_new_tokens)
             self._waiting.append(req)
@@ -900,13 +915,15 @@ class LLMEngine:
         when idle."""
         with self._lock:
             self._step_begin = obs.clock()
+            self._step_kind = "none"
             try:
                 chaos.fire("engine.step")
-                self._step_expired = self._expire_deadlines_locked()
-                if self._preemption is not None:
-                    self._maybe_resume_locked()
-                    self._maybe_preempt_locked()
-                self._step_admitted = self._admit_locked()
+                with self._phase("engine.schedule"):
+                    self._step_expired = self._expire_deadlines_locked()
+                    if self._preemption is not None:
+                        self._maybe_resume_locked()
+                        self._maybe_preempt_locked()
+                    self._step_admitted = self._admit_locked()
                 # Fresh admissions prefill immediately (first token out the
                 # door); CONTINUING chunks of a long prompt alternate with
                 # decode so running sequences are never starved.
@@ -928,6 +945,20 @@ class LLMEngine:
                 return False
             finally:
                 self._step_begin = None
+                self._fold_phases_locked()
+
+    def _phase(self, name: str, **attrs) -> obs.phase:
+        """A host phase of the step in progress (obs.phase)."""
+        return obs.phase(self._step_phases, name, **attrs)
+
+    def _fold_phases_locked(self) -> None:
+        """Book the finished step's phases under the kind it ran."""
+        into = self._phases.setdefault(self._step_kind, {})
+        for name, (count, seconds) in self._step_phases.items():
+            rec = into.setdefault(name, [0, 0.0])
+            rec[0] += count
+            rec[1] += seconds
+        self._step_phases.clear()
 
     def cancel(self, request_id) -> bool:
         """Evict a waiting/prefilling/running request, fail its stream
@@ -1069,6 +1100,15 @@ class LLMEngine:
                 ),
                 "host_sync_bytes_total": self._sync_bytes_total,
                 "decode_inflight": 1 if self._pending is not None else 0,
+                # decode dispatches, and those fed from the pending step's
+                # device tokens (lag-1 survived); host seconds by step kind
+                # and phase, {kind: {phase: [count, seconds]}}
+                "decode_steps": self._decode_steps,
+                "decode_steps_steady": self._decode_steps_steady,
+                "phases": {
+                    kind: {name: list(rec) for name, rec in table.items()}
+                    for kind, table in self._phases.items()
+                },
                 "spec_steps": self._spec_steps,
                 "spec_drafted_tokens": self._spec_drafted_total,
                 "spec_accepted_tokens": self._spec_accepted_total,
@@ -1704,107 +1744,111 @@ class LLMEngine:
         prefix-seeded takes the paged chunk path at true positions."""
         batch = self._prefilling[: self.cfg.max_prefill_batch]
         chaos.fire("engine.prefill", batch=len(batch))
+        self._step_kind = "prefill"
         t0 = obs.clock()
         t0_wall = obs.wall()
-        # staged host-tier promotions land before capacity/COW work so a
-        # same-window eviction or fork of a promoted block is safe
-        self._apply_promotions_locked()
         bs = self.cfg.block_size
         cap = self.cfg.prefill_chunk_tokens
-        ns = []
-        for r in batch:
-            r.started = True
-            remaining = len(r.prefill_tokens) - r.prefill_done
-            ns.append(remaining if cap is None else min(remaining, cap))
-        pairs: list[tuple[int, int]] = []
-        for r, n in zip(batch, ns):
-            appended = self.cache.ensure_capacity(r.id, r.prefill_done + n)
-            r.drawn_blocks += appended
-            cow = self.cache.prepare_write(
-                r.id, r.prefill_done, r.prefill_done + n
-            )
-            r.drawn_blocks += len(cow)
-            pairs.extend(cow)
-        self._apply_copies_locked(pairs)
+        with self._phase("kv.reserve"):
+            # staged host-tier promotions land before capacity/COW work so
+            # a same-window eviction or fork of a promoted block is safe
+            self._apply_promotions_locked()
+            ns = []
+            for r in batch:
+                r.started = True
+                remaining = len(r.prefill_tokens) - r.prefill_done
+                ns.append(remaining if cap is None else min(remaining, cap))
+            pairs: list[tuple[int, int]] = []
+            for r, n in zip(batch, ns):
+                appended = self.cache.ensure_capacity(
+                    r.id, r.prefill_done + n
+                )
+                r.drawn_blocks += appended
+                cow = self.cache.prepare_write(
+                    r.id, r.prefill_done, r.prefill_done + n
+                )
+                r.drawn_blocks += len(cow)
+                pairs.extend(cow)
+            self._apply_copies_locked(pairs)
 
-        legacy = all(
-            r.prefill_done == 0 and n == len(r.prefill_tokens)
-            for r, n in zip(batch, ns)
-        )
-        S = pad_to_bucket(max(ns), self._length_buckets)
-        B = pad_to_bucket(len(batch), self._batch_buckets)
-        if legacy:
-            nb = S // bs
-        else:
-            ctx = pad_to_bucket(
-                max(r.prefill_done + n for r, n in zip(batch, ns)),
-                self._length_buckets,
+        with self._phase("engine.batch"):
+            legacy = all(
+                r.prefill_done == 0 and n == len(r.prefill_tokens)
+                for r, n in zip(batch, ns)
             )
-            nb = ctx // bs
-        tokens = self._scratch_buf("pf_tokens", (B, S), np.int32)
-        lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
-        starts = self._scratch_buf("pf_starts", (B,), np.int32)
-        tables = self._scratch_buf("pf_tables", (B, nb), np.int32)
-        # reused buffers: stale padding rows/columns must be re-zeroed
-        # (a stale table row could point at blocks now owned by a LIVE
-        # sequence — padding writes must stay on the garbage block)
-        tokens[len(batch):] = 0
-        lengths[:] = 1  # padding rows: length 1
-        starts[len(batch):] = 0
-        tables[len(batch):] = 0
-        for i, (r, n) in enumerate(zip(batch, ns)):
-            toks = r.prefill_tokens
-            tokens[i, :n] = toks[r.prefill_done : r.prefill_done + n]
-            tokens[i, n:] = 0
-            lengths[i] = n
-            starts[i] = r.prefill_done
-            tables[i] = self._table_for(r, nb)
-        sample = self._sample_args_locked(batch, B)
+            kind = self._step_kind = "prefill" if legacy else "prefill_chunk"
+            S = pad_to_bucket(max(ns), self._length_buckets)
+            B = pad_to_bucket(len(batch), self._batch_buckets)
+            if legacy:
+                nb = S // bs
+            else:
+                ctx = pad_to_bucket(
+                    max(r.prefill_done + n for r, n in zip(batch, ns)),
+                    self._length_buckets,
+                )
+                nb = ctx // bs
+            tokens = self._scratch_buf("pf_tokens", (B, S), np.int32)
+            lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
+            starts = self._scratch_buf("pf_starts", (B,), np.int32)
+            tables = self._scratch_buf("pf_tables", (B, nb), np.int32)
+            # reused buffers: stale padding rows/columns must be re-zeroed
+            # (a stale table row could point at blocks now owned by a LIVE
+            # sequence — padding writes must stay on the garbage block)
+            tokens[len(batch):] = 0
+            lengths[:] = 1  # padding rows: length 1
+            starts[len(batch):] = 0
+            tables[len(batch):] = 0
+            for i, (r, n) in enumerate(zip(batch, ns)):
+                toks = r.prefill_tokens
+                tokens[i, :n] = toks[r.prefill_done : r.prefill_done + n]
+                tokens[i, n:] = 0
+                lengths[i] = n
+                starts[i] = r.prefill_done
+                tables[i] = self._table_for(r, nb)
+            sample = self._sample_args_locked(batch, B)
+        span = {"kind": kind}
         if legacy:
             toks_dev = self.executor.prefill(
-                tokens, lengths, tables, sample=sample
+                tokens, lengths, tables, sample=sample, span=span
             )
         else:
             toks_dev = self.executor.prefill_chunk(
-                tokens, lengths, starts, tables, sample=sample
+                tokens, lengths, starts, tables, sample=sample, span=span
             )
         # first tokens sync immediately (lag 0): TTFT must not wait for
         # the next decode step, and only final-chunk rows emit anyway
         host = self._sync_tokens_locked(toks_dev, lag=0)
         # dt covers the phase's real cost — COW copies, padding, the
         # jitted call and THE host sync. The same value feeds the latency
-        # histogram, the flight record, event_stats, and the per-request
-        # chunk timeline entries, so every record agrees (one clock).
+        # histogram, the flight record and the per-request chunk timeline
+        # entries, so every record agrees (one clock).
         dt = obs.clock() - t0
-        kind = "prefill" if legacy else "prefill_chunk"
-        for i, (r, n) in enumerate(zip(batch, ns)):
-            toks = r.prefill_tokens
-            r.prefill_done += n
-            self._prefill_tokens_total += n
-            self._tl(r, kind, ts=t0_wall, dur_ms=round(dt * 1000.0, 3),
-                     tokens=n, prefill_done=r.prefill_done)
-            if self.cfg.prefix_caching:
-                self.cache.register_prefix(r.id, toks, r.prefill_done)
-            if r.prefill_done >= len(toks):
-                self._prefilling.remove(r)
-                # resume-from-preemption chains are fully resident again:
-                # from here the row decodes exactly like an unpaused one
-                r.pending_resume = None
-                # the model samples from last-VALID-token logits per row —
-                # for the final chunk that is the last prompt token (or,
-                # resuming, the last already-emitted token: the keyed
-                # sampler reproduces the next token byte-identically)
-                self._emit_token_locked(r, int(host[i]))
-                if not r.done:
-                    self._running.append(r)
-        self._m_util.set(self.cache.utilization)
-        self._sync_cache_counters_locked()
-        self._m_latency.observe(dt, tags={"kind": kind})
-        self._goodput_record_locked(kind, dt, int(sum(ns)))
-        event_stats.record(f"llm.engine.step.{kind}", dt)
-        self._flight_record_locked(
-            kind, t0_wall, dt, batch=len(batch), bucket_b=B, bucket_len=S,
-            nb=nb, tokens=int(sum(ns)),
+        with self._phase("engine.emit"):
+            for i, (r, n) in enumerate(zip(batch, ns)):
+                toks = r.prefill_tokens
+                r.prefill_done += n
+                self._prefill_tokens_total += n
+                self._tl(r, kind, ts=t0_wall, dur_ms=round(dt * 1000.0, 3),
+                         tokens=n, prefill_done=r.prefill_done)
+                if self.cfg.prefix_caching:
+                    self.cache.register_prefix(r.id, toks, r.prefill_done)
+                if r.prefill_done >= len(toks):
+                    self._prefilling.remove(r)
+                    # resume-from-preemption chains are fully resident
+                    # again: from here the row decodes exactly like an
+                    # unpaused one
+                    r.pending_resume = None
+                    # the model samples from last-VALID-token logits per
+                    # row — for the final chunk that is the last prompt
+                    # token (or, resuming, the last already-emitted token:
+                    # the keyed sampler reproduces the next token
+                    # byte-identically)
+                    self._emit_token_locked(r, int(host[i]))
+                    if not r.done:
+                        self._running.append(r)
+        self._account_step_locked(
+            kind, dt, t0_wall, int(sum(ns)), batch=len(batch), bucket_b=B,
+            bucket_len=S, nb=nb, tokens=int(sum(ns)),
             trace_ids=self._trace_ids_locked(batch),
         )
 
@@ -1821,6 +1865,7 @@ class LLMEngine:
         host state, rebuild the batch, and dispatch fresh from host
         tokens."""
         chaos.fire("engine.decode", batch=len(self._running))
+        self._step_kind = "decode"
         t0 = obs.clock()
         t0_wall = obs.wall()
         bs = self.cfg.block_size
@@ -1835,30 +1880,33 @@ class LLMEngine:
                 if len(r.generated) + r.inflight < r.sampling.max_new_tokens
             ]
 
-        batch = eligible()
+        with self._phase("engine.batch"):
+            batch = eligible()
+            # speculative draft-and-verify (cfg.speculative_k > 0) needs
+            # the rows' COMMITTED tokens on host, so a verify step can
+            # never be dispatched ahead: when any row has drafts, collapse
+            # the lag-1 pending first, re-draft on the reconciled state,
+            # and run ONE synchronous verify step committing 1..k+1 tokens
+            # per row. When no row drafts anything, fall through to the
+            # plain pipelined decode below — drafter-hostile traffic keeps
+            # the lag-1 dispatch-ahead path untouched.
+            proposals = (
+                self._propose_drafts_locked(batch)
+                if self._drafter is not None and batch else None
+            )
         emitted = 0
-        # ---- speculative draft-and-verify (cfg.speculative_k > 0) ----
-        # Drafting needs the rows' COMMITTED tokens on host, so a verify
-        # step can never be dispatched ahead: when any row has drafts,
-        # collapse the lag-1 pending first, re-draft on the reconciled
-        # state, and run ONE synchronous verify step committing 1..k+1
-        # tokens per row. When no row drafts anything, fall through to
-        # the plain pipelined decode below — drafter-hostile traffic
-        # keeps the lag-1 dispatch-ahead path untouched.
-        if self._drafter is not None and batch:
-            proposals = self._propose_drafts_locked(batch)
-            if proposals is not None:
-                if pending is not None:
-                    emitted += self._reconcile_locked(pending)
-                    pending = None
+        if proposals is not None:
+            if pending is not None:
+                emitted += self._reconcile_locked(pending)
+                pending = None
+                with self._phase("engine.batch"):
                     batch = eligible()
                     proposals = (
                         self._propose_drafts_locked(batch) if batch else None
                     )
-                if batch and proposals is not None:
-                    self._verify_locked(batch, proposals, t0, t0_wall,
-                                        emitted)
-                    return
+            if batch and proposals is not None:
+                self._verify_locked(batch, proposals, t0, t0_wall, emitted)
+                return
         # list equality is element identity here: same _Request objects
         # in the same order <=> nothing joined/finished/evicted.
         # Grammar-constrained rows force the lag to collapse every step:
@@ -1880,66 +1928,72 @@ class LLMEngine:
             # pure drain step: the reconcile above retired the last
             # in-flight tokens; record it so the flight ring shows the
             # lag collapsing rather than a mystery gap
-            dt = obs.clock() - t0
-            self._m_util.set(self.cache.utilization)
-            self._sync_cache_counters_locked()
-            self._m_latency.observe(dt, tags={"kind": "decode"})
-            self._goodput_record_locked("decode", dt, emitted)
-            event_stats.record("llm.engine.step.decode", dt)
-            self._flight_record_locked(
-                "decode", t0_wall, dt, batch=0, tokens=emitted,
+            self._account_step_locked(
+                "decode", obs.clock() - t0, t0_wall, emitted, batch=0,
+                tokens=emitted,
             )
             return
-        self._apply_promotions_locked()
-        pairs: list[tuple[int, int]] = []
-        for r in batch:
-            # effective length includes the in-flight token: its K/V row
-            # lands at position eff-1 during this dispatch
-            eff = r.total_len + r.inflight
-            appended = self.cache.ensure_capacity(r.id, eff)
-            r.drawn_blocks += appended
-            cow = self.cache.prepare_write(r.id, eff - 1, eff)
-            r.drawn_blocks += len(cow)
-            pairs.extend(cow)
-        self._apply_copies_locked(pairs)
-        B = pad_to_bucket(len(batch), self._batch_buckets)
-        # a row can HOLD blocks past its committed frontier (a verify
-        # step whose drafts were rejected appended them; they're reused
-        # as the frontier advances) — the table must span what's held,
-        # not just what's committed
-        ctx = pad_to_bucket(
-            max(
-                max(r.total_len + r.inflight,
-                    self.cache.num_allocated(r.id) * bs)
-                for r in batch
-            ),
-            self._length_buckets,
-        )
-        nb = ctx // bs
-        positions = self._scratch_buf("dec_positions", (B,), np.int32)
-        tables = self._scratch_buf("dec_tables", (B, nb), np.int32)
-        # reused buffers: re-zero padding rows (a stale table row could
-        # point at blocks now owned by a live sequence)
-        positions[len(batch):] = 0
-        tables[len(batch):] = 0
-        for i, r in enumerate(batch):
-            positions[i] = r.total_len + r.inflight - 1
-            tables[i] = self._table_for(r, nb)
-        if steady:
-            # feed step N+1 from step N's sampled ids without a host
-            # round-trip — THE datapath that makes the pipeline a win
-            # (the executor passes on-device arrays through untouched)
-            tokens_src = pending.tokens
-        else:
-            tokens = self._scratch_buf("dec_tokens", (B,), np.int32)
-            tokens[len(batch):] = 0
+        with self._phase("kv.reserve"):
+            self._apply_promotions_locked()
+            pairs: list[tuple[int, int]] = []
+            kv_tokens = 0
+            for r in batch:
+                # effective length includes the in-flight token: its K/V
+                # row lands at position eff-1 during this dispatch
+                eff = r.total_len + r.inflight
+                appended = self.cache.ensure_capacity(r.id, eff)
+                r.drawn_blocks += appended
+                cow = self.cache.prepare_write(r.id, eff - 1, eff)
+                r.drawn_blocks += len(cow)
+                pairs.extend(cow)
+                # what the attention kernel must read for this row: its
+                # context, in whole blocks
+                kv_tokens += -(-eff // bs) * bs
+            self._apply_copies_locked(pairs)
+        with self._phase("engine.batch"):
+            B = pad_to_bucket(len(batch), self._batch_buckets)
+            # a row can HOLD blocks past its committed frontier (a verify
+            # step whose drafts were rejected appended them; they're
+            # reused as the frontier advances) — the table must span
+            # what's held, not just what's committed
+            ctx = pad_to_bucket(
+                max(
+                    max(r.total_len + r.inflight,
+                        self.cache.num_allocated(r.id) * bs)
+                    for r in batch
+                ),
+                self._length_buckets,
+            )
+            nb = ctx // bs
+            positions = self._scratch_buf("dec_positions", (B,), np.int32)
+            tables = self._scratch_buf("dec_tables", (B, nb), np.int32)
+            # reused buffers: re-zero padding rows (a stale table row
+            # could point at blocks now owned by a live sequence)
+            positions[len(batch):] = 0
+            tables[len(batch):] = 0
             for i, r in enumerate(batch):
-                tokens[i] = r.generated[-1] if r.generated else r.prompt[-1]
-            tokens_src = tokens
+                positions[i] = r.total_len + r.inflight - 1
+                tables[i] = self._table_for(r, nb)
+            if steady:
+                # feed step N+1 from step N's sampled ids without a host
+                # round-trip — THE datapath that makes the pipeline a win
+                # (the executor passes on-device arrays through untouched)
+                tokens_src = pending.tokens
+            else:
+                tokens = self._scratch_buf("dec_tokens", (B,), np.int32)
+                tokens[len(batch):] = 0
+                for i, r in enumerate(batch):
+                    tokens[i] = (
+                        r.generated[-1] if r.generated else r.prompt[-1]
+                    )
+                tokens_src = tokens
+            sample = self._sample_args_locked(batch, B)
         next_dev = self.executor.decode_step(
-            tokens_src, positions, tables,
-            sample=self._sample_args_locked(batch, B),
+            tokens_src, positions, tables, sample=sample,
+            span={"kind": "decode", "kv_tokens": kv_tokens},
         )
+        self._decode_steps += 1
+        self._decode_steps_steady += steady
         for r in batch:
             r.inflight += 1
         self._pending = _PendingDecode(tokens=next_dev, batch=batch)
@@ -1948,17 +2002,24 @@ class LLMEngine:
             # above ran while N was still executing on device
             emitted += self._reconcile_locked(pending)
         dt = obs.clock() - t0
-        self._m_util.set(self.cache.utilization)
-        self._sync_cache_counters_locked()
-        self._m_latency.observe(dt, tags={"kind": "decode"})
-        self._goodput_record_locked("decode", dt, emitted)
         self._decode_step_window.append(dt)
-        event_stats.record("llm.engine.step.decode", dt)
-        self._flight_record_locked(
-            "decode", t0_wall, dt, batch=len(batch), bucket_b=B,
-            bucket_len=ctx, nb=nb, tokens=emitted,
-            trace_ids=self._trace_ids_locked(batch),
+        self._account_step_locked(
+            "decode", dt, t0_wall, emitted, batch=len(batch), bucket_b=B,
+            bucket_len=ctx, nb=nb, tokens=emitted, kv_tokens=kv_tokens,
+            steady=steady, trace_ids=self._trace_ids_locked(batch),
         )
+
+    def _account_step_locked(self, kind: str, dt: float, t0_wall: float,
+                             goodput_tokens: int, **fields) -> None:
+        """The block that ends every step, as the ``engine.account``
+        phase: gauges, the step-latency histogram, goodput and the flight
+        record, all from the ONE duration ``dt``."""
+        with self._phase("engine.account"):
+            self._m_util.set(self.cache.utilization)
+            self._sync_cache_counters_locked()
+            self._m_latency.observe(dt, tags={"kind": kind})
+            self._goodput_record_locked(kind, dt, goodput_tokens)
+            self._flight_record_locked(kind, t0_wall, dt, **fields)
 
     def _reconcile_locked(self, pending: _PendingDecode) -> int:
         """Collapse the dispatch lag for one in-flight decode step: sync
@@ -1972,17 +2033,18 @@ class LLMEngine:
         if self._pending is pending:
             self._pending = None
         toks = self._sync_tokens_locked(pending.tokens, lag=1)
-        self.cache.flush_quarantine()
-        emitted = 0
-        for i, r in enumerate(pending.batch):
-            r.inflight -= 1
-            if r.done:
-                # the <=1 wasted speculative row per finished request
-                self._release_blocks_locked(r)
-                continue
-            self._emit_token_locked(r, int(toks[i]))
-            emitted += 1
-        self._running = [r for r in self._running if not r.done]
+        with self._phase("engine.emit"):
+            self.cache.flush_quarantine()
+            emitted = 0
+            for i, r in enumerate(pending.batch):
+                r.inflight -= 1
+                if r.done:
+                    # the <=1 wasted speculative row per finished request
+                    self._release_blocks_locked(r)
+                    continue
+                self._emit_token_locked(r, int(toks[i]))
+                emitted += 1
+            self._running = [r for r in self._running if not r.done]
         return emitted
 
     def _propose_drafts_locked(self, batch: list) -> list[list[int]] | None:
@@ -2037,92 +2099,101 @@ class LLMEngine:
         on the spot; the remaining verdicts are dead and its blocks
         release exactly once through the normal completion path
         (``inflight`` is 0 here — verify never runs under the lag)."""
+        self._step_kind = "verify"
         bs = self.cfg.block_size
         W = self.cfg.speculative_k + 1
         draft_lens = [len(p) for p in proposals]
-        self._apply_promotions_locked()
-        pairs: list[tuple[int, int]] = []
-        for r, dl in zip(batch, draft_lens):
-            # the window writes K/V at positions total_len-1 ..
-            # total_len-1+dl (committed column + live draft columns;
-            # padding columns redirect to the garbage block, so the
-            # reservation only covers the clamped draft length)
-            eff = r.total_len + dl
-            appended = self.cache.ensure_capacity(r.id, eff)
-            r.drawn_blocks += appended
-            cow = self.cache.prepare_write(r.id, r.total_len - 1, eff)
-            r.drawn_blocks += len(cow)
-            pairs.extend(cow)
-        self._apply_copies_locked(pairs)
-        B = pad_to_bucket(len(batch), self._batch_buckets)
-        # span what each row HOLDS, not just this window: an earlier
-        # rejected window may have appended blocks past today's eff
-        ctx = pad_to_bucket(
-            max(
-                max(r.total_len + dl,
-                    self.cache.num_allocated(r.id) * bs)
-                for r, dl in zip(batch, draft_lens)
-            ),
-            self._length_buckets,
-        )
-        nb = ctx // bs
-        tokens = self._scratch_buf("vf_tokens", (B, W), np.int32)
-        starts = self._scratch_buf("vf_starts", (B,), np.int32)
-        dlen = self._scratch_buf("vf_dlen", (B,), np.int32)
-        tables = self._scratch_buf("vf_tables", (B, nb), np.int32)
-        # reused buffers: re-zero padding (a stale table row could point
-        # at blocks now owned by a live sequence)
-        tokens[len(batch):] = 0
-        starts[len(batch):] = 0
-        dlen[len(batch):] = 0
-        tables[len(batch):] = 0
-        for i, (r, props) in enumerate(zip(batch, proposals)):
-            tokens[i, 0] = r.generated[-1] if r.generated else r.prompt[-1]
-            tokens[i, 1:1 + len(props)] = props
-            tokens[i, 1 + len(props):] = 0
-            starts[i] = r.total_len - 1
-            dlen[i] = len(props)
-            tables[i] = self._table_for(r, nb)
-        sample = self._sample_args_locked(batch, B)
-        # verify windows need one allow-mask PER COLUMN (column s is
-        # sampled from the FSM state after consuming props[:s]) — the
-        # [B, W, words] leaf replaces the per-row decode mask, staged
-        # all-ones for unconstrained rows so the verify pytree (and the
-        # compile kind) is identical for mixed batches
-        words = (self.model_cfg.vocab_size + 31) // 32
-        vf_mask = self._scratch_buf("vf_mask", (B, W, words), np.uint32)
-        vf_mask[:] = 0xFFFFFFFF
-        for i, (r, props) in enumerate(zip(batch, proposals)):
-            if r.fsm is not None:
-                r.fsm.stage_verify_masks(vf_mask[i], props)
-        sample["mask"] = vf_mask
+        with self._phase("kv.reserve"):
+            self._apply_promotions_locked()
+            pairs: list[tuple[int, int]] = []
+            kv_tokens = 0
+            for r, dl in zip(batch, draft_lens):
+                # the window writes K/V at positions total_len-1 ..
+                # total_len-1+dl (committed column + live draft columns;
+                # padding columns redirect to the garbage block, so the
+                # reservation only covers the clamped draft length)
+                eff = r.total_len + dl
+                appended = self.cache.ensure_capacity(r.id, eff)
+                r.drawn_blocks += appended
+                cow = self.cache.prepare_write(r.id, r.total_len - 1, eff)
+                r.drawn_blocks += len(cow)
+                pairs.extend(cow)
+                kv_tokens += -(-eff // bs) * bs
+            self._apply_copies_locked(pairs)
+        with self._phase("engine.batch"):
+            B = pad_to_bucket(len(batch), self._batch_buckets)
+            # span what each row HOLDS, not just this window: an earlier
+            # rejected window may have appended blocks past today's eff
+            ctx = pad_to_bucket(
+                max(
+                    max(r.total_len + dl,
+                        self.cache.num_allocated(r.id) * bs)
+                    for r, dl in zip(batch, draft_lens)
+                ),
+                self._length_buckets,
+            )
+            nb = ctx // bs
+            tokens = self._scratch_buf("vf_tokens", (B, W), np.int32)
+            starts = self._scratch_buf("vf_starts", (B,), np.int32)
+            dlen = self._scratch_buf("vf_dlen", (B,), np.int32)
+            tables = self._scratch_buf("vf_tables", (B, nb), np.int32)
+            # reused buffers: re-zero padding (a stale table row could
+            # point at blocks now owned by a live sequence)
+            tokens[len(batch):] = 0
+            starts[len(batch):] = 0
+            dlen[len(batch):] = 0
+            tables[len(batch):] = 0
+            for i, (r, props) in enumerate(zip(batch, proposals)):
+                tokens[i, 0] = (
+                    r.generated[-1] if r.generated else r.prompt[-1]
+                )
+                tokens[i, 1:1 + len(props)] = props
+                tokens[i, 1 + len(props):] = 0
+                starts[i] = r.total_len - 1
+                dlen[i] = len(props)
+                tables[i] = self._table_for(r, nb)
+            sample = self._sample_args_locked(batch, B)
+            # verify windows need one allow-mask PER COLUMN (column s is
+            # sampled from the FSM state after consuming props[:s]) — the
+            # [B, W, words] leaf replaces the per-row decode mask, staged
+            # all-ones for unconstrained rows so the verify pytree (and
+            # the compile kind) is identical for mixed batches
+            words = (self.model_cfg.vocab_size + 31) // 32
+            vf_mask = self._scratch_buf("vf_mask", (B, W, words), np.uint32)
+            vf_mask[:] = 0xFFFFFFFF
+            for i, (r, props) in enumerate(zip(batch, proposals)):
+                if r.fsm is not None:
+                    r.fsm.stage_verify_masks(vf_mask[i], props)
+            sample["mask"] = vf_mask
         packed_dev = self.executor.verify_step(
             tokens, starts, dlen, tables, sample=sample,
+            span={"kind": "verify", "kv_tokens": kv_tokens},
         )
         packed = self._sync_verify_locked(packed_dev)
-        # a completed sync proves every earlier dispatch executed
-        self.cache.flush_quarantine()
-        drafted = sum(draft_lens)
-        accepted = 0
-        step_tokens = 0
-        for i, (r, dl) in enumerate(zip(batch, draft_lens)):
-            # device contract: 1 <= committed <= draft_len + 1; clamp
-            # anyway so a bad verdict can never overrun the budget
-            committed = max(1, min(int(packed[i, 0]), dl + 1))
-            accepted += committed - 1
-            if r.trace_ctx:
-                # traced rows carry the speculation outcome per window —
-                # rendered as an engine.verify span at finish (host list
-                # append only; untraced rows skip even that)
-                self._tl(r, "verify_window", ts=t0_wall,
-                         dur_ms=round((obs.clock() - t0) * 1000.0, 3),
-                         drafted=dl, accepted=committed - 1, window=W)
-            for j in range(committed):
-                self._emit_token_locked(r, int(packed[i, 1 + j]))
-                step_tokens += 1
-                if r.done:
-                    break
-        self._running = [r for r in self._running if not r.done]
+        with self._phase("engine.emit"):
+            # a completed sync proves every earlier dispatch executed
+            self.cache.flush_quarantine()
+            drafted = sum(draft_lens)
+            accepted = 0
+            step_tokens = 0
+            for i, (r, dl) in enumerate(zip(batch, draft_lens)):
+                # device contract: 1 <= committed <= draft_len + 1; clamp
+                # anyway so a bad verdict can never overrun the budget
+                committed = max(1, min(int(packed[i, 0]), dl + 1))
+                accepted += committed - 1
+                if r.trace_ctx:
+                    # traced rows carry the speculation outcome per window
+                    # — rendered as an engine.verify span at finish (host
+                    # list append only; untraced rows skip even that)
+                    self._tl(r, "verify_window", ts=t0_wall,
+                             dur_ms=round((obs.clock() - t0) * 1000.0, 3),
+                             drafted=dl, accepted=committed - 1, window=W)
+                for j in range(committed):
+                    self._emit_token_locked(r, int(packed[i, 1 + j]))
+                    step_tokens += 1
+                    if r.done:
+                        break
+            self._running = [r for r in self._running if not r.done]
         self._spec_steps += 1
         self._spec_drafted_total += drafted
         self._spec_accepted_total += accepted
@@ -2133,16 +2204,12 @@ class LLMEngine:
             self._m_spec_accepted.inc(accepted)
         self._m_spec_committed.inc(step_tokens)
         dt = obs.clock() - t0
-        self._m_util.set(self.cache.utilization)
-        self._sync_cache_counters_locked()
-        self._m_latency.observe(dt, tags={"kind": "verify"})
-        self._goodput_record_locked("verify", dt, emitted + step_tokens)
         self._decode_step_window.append(dt)
-        event_stats.record("llm.engine.step.verify", dt)
-        self._flight_record_locked(
-            "verify", t0_wall, dt, batch=len(batch), bucket_b=B,
-            bucket_len=ctx, nb=nb, window=W, drafted=drafted,
+        self._account_step_locked(
+            "verify", dt, t0_wall, emitted + step_tokens, batch=len(batch),
+            bucket_b=B, bucket_len=ctx, nb=nb, window=W, drafted=drafted,
             accepted=accepted, tokens=emitted + step_tokens,
+            kv_tokens=kv_tokens, steady=False,
             trace_ids=self._trace_ids_locked(batch),
         )
 
@@ -2150,9 +2217,9 @@ class LLMEngine:
         """The verify-step host sync: one packed [B, W+1] int32 array
         through the same blessed channel (executor.sync_verify ->
         _host_tokens), timed and metered exactly like the token sync."""
-        t0 = obs.clock()
-        packed = self.executor.sync_verify(packed_dev)
-        dt = obs.clock() - t0
+        with self._phase("engine.sync", lag=0) as ph:
+            packed = self.executor.sync_verify(packed_dev)
+        dt = ph.seconds
         self._m_sync.observe(dt)
         self._m_sync_bytes.inc(packed.nbytes)
         self._sync_seconds_total += dt
@@ -2172,9 +2239,9 @@ class LLMEngine:
         lagged token timestamps are explainable (docs/OBSERVABILITY.md).
         The transfer itself is the executor's ``sync_tokens``
         (executor._host_tokens — THE allowed host sync)."""
-        t0 = obs.clock()
-        toks = self.executor.sync_tokens(tokens_dev)
-        dt = obs.clock() - t0
+        with self._phase("engine.sync", lag=lag) as ph:
+            toks = self.executor.sync_tokens(tokens_dev)
+        dt = ph.seconds
         self._m_sync.observe(dt)
         self._m_sync_bytes.inc(toks.nbytes)
         self._sync_seconds_total += dt
@@ -2437,7 +2504,10 @@ class LLMEngine:
         ``engine.decode`` child."""
         tid = r.trace_ctx["trace_id"]
         events = r.timeline
-        start = events[0]["ts"]
+        # spans run from ``submitted``, inside the lock ("submit ->
+        # terminal event", docs/OBSERVABILITY.md); the wait for the lock
+        # before it, from ``received``, is the timeline's alone
+        start = next(e["ts"] for e in events if e["event"] == "submitted")
         end = events[-1]["ts"]
         ttft_ts = next(
             (e["ts"] for e in events if e["event"] == "first_token"), None)
@@ -2718,7 +2788,10 @@ class LLMEngine:
                         and not self._running
                         and not self._preempted
                     ):
-                        self._work.wait(timeout=0.05)
+                        with self._phase("engine.wait"):
+                            self._work.wait(timeout=0.05)
+                        self._step_kind = "none"
+                        self._fold_phases_locked()
 
     def _watchdog_loop(self) -> None:
         """Detect a wedged step. Deliberately LOCK-FREE: the failure mode

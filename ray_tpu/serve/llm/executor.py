@@ -41,6 +41,7 @@ from typing import Any
 
 import numpy as np
 
+from ray_tpu.serve.llm import obs
 from ray_tpu.serve.llm.decode import DecodeFns, family_param_axes
 
 logger = logging.getLogger("ray_tpu.serve.llm")
@@ -104,6 +105,10 @@ class ModelExecutor:
     - ``sync_verify(packed_dev)`` — the same sync point for a verify
       step's packed verdicts ([B, W+1] int32 through ``_host_tokens``).
     - ``on_new_signature`` — compile-event hook, forwarded to DecodeFns.
+
+    The four step methods also take ``span=``, the attributes of the
+    step's ``executor.dispatch`` phase, and book their two host phases
+    into ``phases`` (``_run``).
     """
 
     kind = "single"
@@ -123,6 +128,9 @@ class ModelExecutor:
         self.family = family
         self.model_cfg = model_cfg
         self.cache = cache
+        # where the step's host phases are booked ({name: [count,
+        # seconds]}); the engine hands over its own table
+        self.phases: dict = {}
         self.fns = DecodeFns(family, model_cfg)
         self.params = (
             params
@@ -213,37 +221,42 @@ class ModelExecutor:
 
     # ---------------- the step interface ----------------
 
-    def prefill(self, tokens, lengths, tables, sample=None):
-        toks, self.cache.k, self.cache.v = self.fns.prefill(
-            self.params, self.cache.k, self.cache.v,
-            self._dev(tokens), self._dev(lengths), self._dev(tables),
-            sample=self._dev_sample(sample),
-        )
-        return toks
-
-    def prefill_chunk(self, tokens, lengths, starts, tables, sample=None):
-        toks, self.cache.k, self.cache.v = self.fns.prefill(
-            self.params, self.cache.k, self.cache.v,
-            self._dev(tokens), self._dev(lengths), self._dev(tables),
-            start=self._dev(starts), sample=self._dev_sample(sample),
-        )
-        return toks
-
-    def decode_step(self, tokens, positions, tables, sample=None):
-        toks, self.cache.k, self.cache.v = self.fns.decode(
-            self.params, self.cache.k, self.cache.v,
-            self._dev(tokens), self._dev(positions), self._dev(tables),
-            sample=self._dev_sample(sample),
-        )
-        return toks
-
-    def verify_step(self, tokens, starts, draft_len, tables, sample=None):
-        out, self.cache.k, self.cache.v = self.fns.verify(
-            self.params, self.cache.k, self.cache.v,
-            self._dev(tokens), self._dev(starts), self._dev(draft_len),
-            self._dev(tables), sample=self._dev_sample(sample),
-        )
+    def _run(self, fn, arrays, sample, span, **staged):
+        """One jitted step, in the two host phases it has (obs.phase):
+        ``executor.stage`` moves the engine's numpy staging arrays
+        (``arrays`` in the call's order, ``staged`` by keyword, and the
+        ``sample`` pytree) on-device; ``executor.dispatch`` is the jitted
+        call until it returns, under the attributes the engine gives in
+        ``span`` (``kind``; ``kv_tokens`` for decode and verify).
+        Updates ``cache.k`` / ``cache.v`` in place."""
+        with obs.phase(self.phases, "executor.stage"):
+            dev = [self._dev(a) for a in arrays]
+            staged = {k: self._dev(v) for k, v in staged.items()}
+            sample = self._dev_sample(sample)
+        with obs.phase(self.phases, "executor.dispatch", **(span or {})):
+            out, self.cache.k, self.cache.v = fn(
+                self.params, self.cache.k, self.cache.v, *dev,
+                sample=sample, **staged,
+            )
         return out
+
+    def prefill(self, tokens, lengths, tables, sample=None, span=None):
+        return self._run(self.fns.prefill, (tokens, lengths, tables),
+                         sample, span)
+
+    def prefill_chunk(self, tokens, lengths, starts, tables, sample=None,
+                      span=None):
+        return self._run(self.fns.prefill, (tokens, lengths, tables),
+                         sample, span, start=starts)
+
+    def decode_step(self, tokens, positions, tables, sample=None, span=None):
+        return self._run(self.fns.decode, (tokens, positions, tables),
+                         sample, span)
+
+    def verify_step(self, tokens, starts, draft_len, tables, sample=None,
+                    span=None):
+        return self._run(self.fns.verify,
+                         (tokens, starts, draft_len, tables), sample, span)
 
     def copy_blocks(self, pairs: list[tuple[int, int]]) -> None:
         """Clone shared KV blocks on device (COW) before a write lands.

@@ -4,7 +4,7 @@ histograms, and the engine flight recorder.
 Design constraints (ISSUE 4 / docs/OBSERVABILITY.md):
 
 - **One clock.** Every duration the engine records — step latency
-  histograms, flight-recorder records, event_stats — flows through
+  histograms, flight-recorder records, phase totals — flows through
   ``clock()`` (monotonic), and every absolute timestamp (timelines,
   spans, chrome export) through ``wall()``. tests/test_sanitizers.py
   lints serve/llm for stray ``time.time()`` / ``time.perf_counter()``
@@ -36,6 +36,54 @@ logger = logging.getLogger("ray_tpu.serve.llm")
 # line up across processes (timelines, spans, chrome export).
 clock = time.perf_counter
 wall = time.time
+
+# The profiler's host-span class, imported at first use: this module (and
+# engine.py) import no jax at module level.
+_annotation = None
+
+
+class phase:
+    """One host phase of an engine step, as a context manager, booked twice:
+
+    - as a ``jax.profiler.TraceAnnotation(name, **attrs)``: a span on the
+      profiler's own clock, beside the device's operations, while a
+      profiler session is active — and a flag test while none is. The
+      profiler session is the only switch there is.
+    - into ``totals`` (``{name: [count, seconds]}``, the engine's), on
+      ``clock()``, always: what ``engine.stats()["phases"]`` reports.
+
+    ``seconds`` holds the duration once the phase has closed, for a caller
+    that feeds another record from the same reading. Phases of one step
+    follow one another and never overlap, so their seconds add up. Never
+    open one inside a per-row loop: one span around the loop.
+    """
+
+    __slots__ = ("_totals", "_name", "_span", "_t0", "seconds")
+
+    def __init__(self, totals: dict, name: str, **attrs):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._totals = totals
+        self._name = name
+        self._span = _annotation(name, **attrs)
+
+    def __enter__(self) -> "phase":
+        self._span.__enter__()
+        self._t0 = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = clock() - self._t0
+        self._span.__exit__(*exc)
+        rec = self._totals.get(self._name)
+        if rec is None:
+            rec = self._totals[self._name] = [0, 0.0]
+        rec[0] += 1
+        rec[1] += self.seconds
+
 
 # Serving-appropriate buckets: TTFT spans "prefix-hit tiny model" (ms) to
 # "cold 70B prefill" (tens of seconds); per-output-token tracks decode

@@ -124,6 +124,35 @@ def test_flash_forward_backward_compiles(one_chip):
     ) >= 2
 
 
+def test_flash_kernel_names_reach_the_lowered_text(one_chip):
+    """Each flash kernel's ``name=`` is in what the chip's compiler is
+    handed, so a trace shows ``flash_fwd`` and not ``jvp___``. Four KV
+    blocks or fewer fuse dq into the dk/dv sweep; more take the second
+    pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_attention
+
+    def lowered(seq, block):
+        x = _struct((2, 4, seq, 64), jnp.bfloat16, one_chip)
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, block_q=block, block_kv=block,
+                                  interpret=False)
+            return jnp.sum(out.astype(jnp.float32))
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).as_text()
+
+    fused = lowered(1024, 1024)
+    assert "flash_fwd" in fused and "flash_bwd_dkv" in fused
+    assert "flash_bwd_dq" not in fused
+    two_pass = lowered(2048, 256)
+    assert all(n in two_pass
+               for n in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
+
+
 def test_sharded_decode_step_compiles_partitioned(topo, monkeypatch):
     """One tp=4 decode step of GPT-2 125M on the four-device mesh, as
     ShardedExecutor runs it (weights by the training rules, pool split

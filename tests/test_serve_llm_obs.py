@@ -26,7 +26,7 @@ import pytest
 
 from conftest import shutdown_if_setup_fails
 
-from ray_tpu._private import chaos, event_stats
+from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.util import metrics, tracing
 
@@ -72,8 +72,10 @@ def test_request_timeline_phase_order(jax_cpu):
     # live timeline is queryable mid-flight
     live = eng.request_timeline(s.request_id)
     assert live is not None
-    assert [e["event"] for e in live["events"]] == ["submitted"]
-    assert live["events"][0]["prompt_tokens"] == 3
+    # ``received`` is stamped before the scheduler's lock is taken,
+    # ``submitted`` inside it
+    assert [e["event"] for e in live["events"]] == ["received", "submitted"]
+    assert live["events"][1]["prompt_tokens"] == 3
     assert live["finish_reason"] is None
     for _ in range(50):
         if s.done:
@@ -84,8 +86,7 @@ def test_request_timeline_phase_order(jax_cpu):
     tl = eng.request_timeline(s.request_id)
     assert tl is not None and tl["finish_reason"] == "finished"
     events = [e["event"] for e in tl["events"]]
-    assert events[0] == "submitted"
-    assert events[1] == "admitted"
+    assert events[:3] == ["received", "submitted", "admitted"]
     prefills = [e for e in tl["events"]
                 if e["event"] in ("prefill", "prefill_chunk")]
     assert prefills, "timeline must show the prefill phase"
@@ -152,9 +153,26 @@ def test_latency_histograms_and_compile_events_exported(jax_cpu):
     shapes = [k for k in after
               if k.startswith("llm_compile_events_total{shape=")]
     assert shapes, "compile events must be tagged by shape"
-    # event_stats picked up the same phases
-    snap = event_stats.snapshot(prefix="llm.engine.step")
-    assert any(k.endswith(".decode") for k in snap)
+    # the phase totals agree with the histogram: every step ends in one
+    # ``engine.account``, which observes it once; and a step's phases lie
+    # inside the interval the histogram timed, all but ``engine.schedule``
+    # before it and ``engine.account`` after it (a prefill is timed up to
+    # its sync: its ``engine.emit`` comes after, too)
+    phases = eng.stats()["phases"]
+    assert set(phases) >= {"prefill", "decode"}
+    for kind in ("prefill", "decode"):
+        tag = f"{{kind={kind}}}"
+        steps = (after[f"llm_engine_step_latency_seconds_count{tag}"]
+                 - count(f"llm_engine_step_latency_seconds_count{tag}"))
+        timed = (after[f"llm_engine_step_latency_seconds_sum{tag}"]
+                 - count(f"llm_engine_step_latency_seconds_sum{tag}"))
+        assert phases[kind]["engine.account"][0] == steps
+        assert phases[kind]["engine.schedule"][0] == steps
+        outside = {"engine.schedule", "engine.account"} | (
+            {"engine.emit"} if kind == "prefill" else set())
+        inside = sum(sec for name, (_, sec) in phases[kind].items()
+                     if name not in outside)
+        assert 0 < inside <= timed + 1e-6
     eng.shutdown()
 
 
@@ -201,7 +219,10 @@ def test_flight_recorder_ring_is_bounded(jax_cpu):
             assert key in r, f"flight record missing {key}: {r}"
     assert dump["stats"]["failed"] is False
     assert dump["cache"]["num_blocks"] == eng.cache.cfg.num_blocks
-    assert dump["event_stats"]
+    # the process's event_stats ride along (the proxy's request timings;
+    # this process has none), the engine's own host time comes as phases
+    assert "event_stats" in dump
+    assert dump["stats"]["phases"]["decode"]["engine.account"][0] > 8
     eng.shutdown()
 
 
@@ -335,6 +356,16 @@ def test_engine_emits_request_spans_under_caller_trace(ray_start, jax_cpu):
     assert req["attrs"]["prompt_tokens"] == 3
     assert req["attrs"]["tokens"] == 4
     assert "engine.queued" in by_name
+    # the request's and the queue's span start at ``submitted``, inside the
+    # scheduler's lock, as they always have: ``received``, stamped before
+    # the wait for the lock, is the timeline's alone
+    events = {e["event"]: e["ts"]
+              for e in eng.request_timeline(s.request_id)["events"]}
+    assert events["received"] < events["submitted"]
+    assert req["start"] == events["submitted"]
+    assert by_name["engine.queued"][0]["start"] == events["submitted"]
+    assert req["attrs"]["ttft_s"] == pytest.approx(
+        events["first_token"] - events["submitted"], abs=1e-5)
     prefill_names = [n for n in by_name
                      if n in ("engine.prefill", "engine.prefill_chunk")]
     assert prefill_names, "per-chunk prefill spans missing"
