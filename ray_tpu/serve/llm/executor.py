@@ -115,10 +115,10 @@ class ModelExecutor:
     # set by build_executor from EngineConfig when speculation is on;
     # surfaced via describe() -> stats()/debug_dump()
     speculative: dict | None = None
-    # ShardedExecutor defers weight quantization until after
-    # shard_params (the axes tree must match the RAW param structure,
-    # and quantizing committed sharded arrays lets GSPMD place the
-    # scale shards next to their data).
+    # ShardedExecutor defers the weights' build step until after
+    # shard_params (the axes tree must match the RAW param structure;
+    # quantizing or casting committed sharded arrays lets GSPMD place
+    # the scale shards next to their data and keeps each leaf's sharding).
     _defer_quantize = False
 
     def __init__(self, family: str, model_cfg, cache, *,
@@ -131,7 +131,8 @@ class ModelExecutor:
         # where the step's host phases are booked ({name: [count,
         # seconds]}); the engine hands over its own table
         self.phases: dict = {}
-        self.fns = DecodeFns(family, model_cfg)
+        self.fns = DecodeFns(
+            family, model_cfg, platform=self._devices()[0].platform)
         self.params = (
             params
             if params is not None
@@ -139,6 +140,7 @@ class ModelExecutor:
         )
         if not self._defer_quantize:
             self._maybe_quantize_params()
+            self._store_compute_dtype()
 
     def _maybe_quantize_params(self) -> None:
         """Quantize the serving weights per ``model_cfg.quantization``
@@ -170,6 +172,54 @@ class ModelExecutor:
             family_quant_axes(self.family, self.model_cfg),
             kind,
         )
+
+    def _weights_by_axis(self):
+        """The weights tree flattened beside the family's quant-axes
+        tree: ``(leaves, axes, treedef)``, a QuantizedTensor counting as
+        one leaf. An axis >= 0 marks a matmul weight; ``-1`` leaves are
+        norm scales, biases and the MoE tables ``moe_forward`` reads raw."""
+        import jax
+
+        from ray_tpu.ops.quantization import QuantizedTensor
+        from ray_tpu.serve.llm.decode import family_quant_axes
+
+        leaves, treedef = jax.tree.flatten(
+            self.params, is_leaf=lambda t: isinstance(t, QuantizedTensor))
+        axes = treedef.flatten_up_to(
+            family_quant_axes(self.family, self.model_cfg))
+        return leaves, axes, treedef
+
+    def _store_compute_dtype(self) -> None:
+        """Store every matmul weight ONCE in ``model_cfg.dtype``, so the
+        ``.astype(cfg.dtype)`` seams of the step programs are no-ops and
+        no prefill, decode or verify call casts the weights again; the
+        ``-1`` leaves stay float32. Runs after ``_maybe_quantize_params``
+        and with its ordering: with ``quantization`` set it does nothing
+        (int8 / fp8 come from the float32 masters), and a leaf already
+        in ``cfg.dtype`` (pre-built params, float32 configs) or already
+        quantized passes through. Leaf by leaf, with the tree taken off
+        the executor meanwhile, so that ``leaves`` holds the executor's
+        only reference to each master and lets it go as its cast is
+        dispatched: a caller that does not hold the float32 tree never
+        has both whole trees on the device. Whatever stops the loop, the
+        executor gets a whole tree back (partly cast, and a second call
+        finishes it)."""
+        if getattr(self.model_cfg, "quantization", None) is not None:
+            return
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.quantization import QuantizedTensor
+
+        dtype = jnp.dtype(self.model_cfg.dtype)
+        leaves, axes, treedef = self._weights_by_axis()
+        self.params = None
+        try:
+            for i, axis in enumerate(axes):
+                if (axis >= 0 and not isinstance(leaves[i], QuantizedTensor)
+                        and leaves[i].dtype != dtype):
+                    leaves[i] = leaves[i].astype(dtype)
+        finally:
+            self.params = treedef.unflatten(leaves)
 
     # ---------------- compile-event hooks (DecodeFns pass-through) ----
 
@@ -405,6 +455,27 @@ class ModelExecutor:
             ))
         return self._num_params
 
+    def _weights_report(self) -> dict:
+        """What the build step left on the device: ``weight_dtype`` is the
+        quantization kind, else the dtype the matmul weights are stored
+        in; ``weight_bytes`` sums the whole tree (scale planes and the
+        float32 leaves included, all shards of a mesh). From shape
+        metadata alone, as ``num_params`` — no device sync; read anew on
+        every call, so it follows a tree that was assigned since."""
+        import jax
+
+        kind = getattr(self.model_cfg, "quantization", None)
+        if kind is None:
+            leaves, axes, _ = self._weights_by_axis()
+            kind = str(next(
+                t.dtype for t, axis in zip(leaves, axes) if axis >= 0))
+        return {
+            "weight_dtype": kind,
+            "weight_bytes": int(sum(
+                t.size * t.dtype.itemsize
+                for t in jax.tree.leaves(self.params))),
+        }
+
     @property
     def peak_tflops(self) -> float | None:
         """Aggregate published peak bf16 TFLOP/s across this executor's
@@ -463,6 +534,7 @@ class ModelExecutor:
                 "attention_backend": self.attention_backend,
                 "quantization": getattr(
                     self.model_cfg, "quantization", None),
+                **self._weights_report(),
                 "speculative": self.speculative}
 
 
@@ -601,8 +673,10 @@ class ShardedExecutor(ModelExecutor):
         # raw param structure, and quantizing committed sharded arrays
         # lets GSPMD keep each scale shard colocated with its data shard
         # (the amax reduction is over an axis, so the result is the same
-        # on any mesh).
+        # on any mesh). The cast to the compute dtype is elementwise on
+        # the committed shards and keeps their sharding.
         self._maybe_quantize_params()
+        self._store_compute_dtype()
         # The KV-head axis (axis 3) is the tp shard axis for the 5-d data
         # plane AND the 4-d scale plane of a quantized pool — one spec
         # serves both leaves.
@@ -624,18 +698,11 @@ class ShardedExecutor(ModelExecutor):
         return list(self.mesh.devices.flat)
 
     def describe(self) -> dict:
-        return {
-            "executor": self.kind,
-            "devices": self.num_devices,
-            # only the non-trivial axes — {"tp": 2, "fsdp": 2} reads as
-            # the operator-facing mesh shape
-            "mesh": {a: int(s) for a, s in self.mesh.shape.items()
-                     if int(s) > 1},
-            **self._device_report(),
-            "attention_backend": self.attention_backend,
-            "quantization": getattr(self.model_cfg, "quantization", None),
-            "speculative": self.speculative,
-        }
+        # only the non-trivial axes — {"tp": 2, "fsdp": 2} reads as the
+        # operator-facing mesh shape
+        return {**super().describe(),
+                "mesh": {a: int(s) for a, s in self.mesh.shape.items()
+                         if int(s) > 1}}
 
 
 def build_executor(cfg, model_cfg, cache, *, params=None) -> ModelExecutor:

@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import re
 
 import pytest
 
@@ -202,3 +203,83 @@ def test_sharded_decode_step_compiles_partitioned(topo, monkeypatch):
     # a quarter of the pool plus this device's share of the f32 weights —
     # well under half of the pool alone
     assert per_device < pool_bytes / 2
+
+
+@pytest.mark.parametrize("case", ["stored", "stored-tp4", "int8", "fp8"])
+def test_step_programs_take_no_cross_program_prefetch(topo, monkeypatch, case):
+    """GPT-2 125M's 64-row decode step as the serving cell runs it, under
+    the step programs' own compiler settings for the TPU (decode.py
+    ``_compiler_options``). On the tree the executor stores (matmul
+    weights bf16, ISSUE 25) the compiler would by default prefetch an
+    entry parameter across programs: on one chip the tied 77 MB ``wte``,
+    which then sits in the fast memory for the whole step and pushes each
+    layer's pool slice out of it; under tp=4 its shard. The chip's
+    compiler knows the option, and with it no program has such a
+    prefetch, as none had on float32 masters. A quantized program has
+    none to begin with and is the same text either way. No matmul weight
+    is cast in the step."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.gpt import (
+        GPTConfig, gpt_decode_step, gpt_init, gpt_param_axes, gpt_quant_axes,
+    )
+    from ray_tpu.ops.quantization import quantize_params
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import ShardingRules, param_shardings
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    options = decode._compiler_options("tpu")
+    assert options and decode._compiler_options("cpu") is None
+
+    quant = case if case in ("int8", "fp8") else None
+    cfg = dataclasses.replace(
+        GPTConfig.gpt2_small(), attention_backend="pallas",
+        quantization=quant,
+    )
+    masters = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    axes = gpt_quant_axes(cfg)
+    mesh = build_mesh(
+        MeshSpec(tp=4 if case.endswith("tp4") else 1),
+        list(topo.devices)[: 4 if case.endswith("tp4") else 1],
+    )
+    rep = NamedSharding(mesh, P())
+    if quant is None:
+        params = jax.tree.map(
+            lambda s, axis, sh: _struct(
+                s.shape, cfg.dtype if axis >= 0 else s.dtype, sh),
+            masters, axes,
+            param_shardings(gpt_param_axes(cfg), mesh, ShardingRules()),
+        )
+    else:
+        params = jax.tree.map(
+            lambda s: _struct(s.shape, s.dtype, rep),
+            jax.eval_shape(lambda p: quantize_params(p, axes, quant), masters),
+        )
+    B, bs, num_blocks = 64, 16, 4097
+    pool = _struct(
+        (cfg.n_layer, num_blocks, bs, cfg.n_head, cfg.head_dim), cfg.dtype,
+        NamedSharding(mesh, P(None, None, None, "tp")),
+    )
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=rep)
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(functools.partial(gpt_decode_step, cfg=cfg)).lower(
+            params, pool, pool, i32((B,)), i32((B,)),
+            i32((B, cfg.max_seq_len // bs)),
+        )
+        default = lowered.compile().as_text()
+        text = lowered.compile(compiler_options=options).as_text()
+    assert "cross_program_prefetch_index" not in text
+    assert 'custom_call_target="tpu_custom_call"' in text
+    if quant is not None:
+        assert text == default
+        return
+    assert "cross_program_prefetch_index" in default
+    if case == "stored":  # a partitioned program's parameters lose their names
+        # biases and norm scales stay float32 and are cast where used (a
+        # few KB); no matmul weight is
+        assert "%params__" in text and not re.search(
+            r"convert\(%params__(blocks____)?(wte|wpe|\w+_w)__", text)

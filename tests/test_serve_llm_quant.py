@@ -243,6 +243,78 @@ def test_weight_quantization_roundtrip(jax_cpu, family):
     assert n_quant > 0, "quant-axes tree marked nothing quantizable"
 
 
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("mesh_kw", [{}, {"tp": 2}], ids=["single", "tp2"])
+@pytest.mark.parametrize("kind", ["int8", "fp8"])
+def test_quantized_tree_is_built_from_float32_masters(jax_cpu, kind, mesh_kw):
+    """With ``quantization`` set the executor's tree is what
+    ``quantize_params`` gives on the float32 masters, byte for byte: the
+    store-in-compute-dtype step (ISSUE 25) never runs, although the
+    config computes in bfloat16 and it would have something to cast."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.ops.quantization import QuantizedTensor, quantize_params
+    from ray_tpu.serve.llm.decode import DecodeFns, family_quant_axes
+
+    mc = dataclasses.replace(LlamaConfig.tiny(), attention="xla")
+    assert mc.dtype == jnp.bfloat16
+    masters = DecodeFns("llama", mc).init(jax.random.PRNGKey(0), mc)
+    want = quantize_params(masters, family_quant_axes("llama", mc), kind)
+    eng = _engine(mc=mc, params=masters, quantization=kind, **mesh_kw)
+    try:
+        d = eng.executor.describe()
+        assert d["weight_dtype"] == kind
+        got = jax.tree.leaves(
+            eng.params, is_leaf=lambda t: isinstance(t, QuantizedTensor))
+        exp = jax.tree.leaves(
+            want, is_leaf=lambda t: isinstance(t, QuantizedTensor))
+        assert len(got) == len(exp)
+        n_bytes = 0
+        for g, e in zip(got, exp):
+            assert isinstance(g, QuantizedTensor) == isinstance(
+                e, QuantizedTensor)
+            for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(e)):
+                assert a.dtype == b.dtype and a.dtype != jnp.bfloat16
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+                n_bytes += b.size * b.dtype.itemsize
+        assert d["weight_bytes"] == n_bytes
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.timeout(300)
+def test_moe_expert_leaves_stay_float32(jax_cpu):
+    """``moe_forward`` reads router and expert weights raw (no ``astype``
+    seam), so the store-in-compute-dtype step leaves them float32, and
+    the streams are those of the program that casts at every use (the
+    same engine with the float32 masters put back)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.llm.decode import DecodeFns
+
+    mc = dataclasses.replace(LlamaConfig.tiny_moe(), attention="xla")
+    masters = DecodeFns("llama", mc).init(jax.random.PRNGKey(0), mc)
+    eng = _engine(mc=mc, params=masters)
+    parent = _engine(mc=mc, params=masters)
+    try:
+        blocks = eng.params["blocks"]
+        for name in ("moe_router", "moe_w_in", "moe_w_out", "ln1_scale"):
+            assert blocks[name].dtype == jnp.float32, name
+            assert blocks[name] is masters["blocks"][name]
+        for w in (blocks["wq"], blocks["wo"], eng.params["wte"],
+                  eng.params["lm_head"]):
+            assert w.dtype == jnp.bfloat16
+        parent.executor.params = masters
+        assert _generate_all(eng) == _generate_all(parent)
+    finally:
+        eng.shutdown()
+        parent.shutdown()
+
+
 # -------------------------------------------- agreement & perplexity
 
 @pytest.mark.timeout(300)

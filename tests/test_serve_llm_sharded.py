@@ -99,6 +99,8 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               "mesh": {"tp": 2, "fsdp": 2},
                               "platform": "cpu", "device_kind": "cpu",
                               "quantization": None,
+                              "weight_dtype": "float32",
+                              "weight_bytes": 4 * eng.executor.num_params,
                               "attention_backend": "xla",
                               "speculative": None}
     assert eng.debug_dump()["executor"]["mesh"] == {"tp": 2, "fsdp": 2}
@@ -116,6 +118,9 @@ def test_single_device_default_unchanged(jax_cpu):
                                        "platform": "cpu",
                                        "device_kind": "cpu",
                                        "quantization": None,
+                                       "weight_dtype": "float32",
+                                       "weight_bytes":
+                                           4 * eng.executor.num_params,
                                        "attention_backend": "xla",
                                        "speculative": None}
     assert len(eng.generate([5, 6, 7], max_new_tokens=4)) == 4
