@@ -1,0 +1,538 @@
+"""LFM2-MoE family: short-convolution and attention layers in one stack,
+dense and sparse-expert feed-forward layers, for serving.
+
+Follows the public ``lfm2_moe`` formulation (LiquidAI LFM2-24B-A2B's
+``config.json`` and the family's published modelling code). A block is::
+
+    h = x + Op(RMSNorm_op(x));   y = h + FFN(RMSNorm_ffn(h))
+
+``Op`` is, by ``layer_types[i]``, either the gated short convolution
+(``conv``; ops/short_conv.py) or grouped-query attention with an RMSNorm
+over each head's q and k BEFORE the rotary embedding (``full_attention``).
+``FFN`` is a dense SwiGLU in the first ``num_dense_layers`` layers and, in
+the rest, a dropless expert layer: sigmoid router with a stored selection
+bias, top-k, weights from the unbiased scores divided by their sum
+(ops/moe.py ``moe_route`` / ``moe_dropless``). Final RMSNorm; the output
+head is the embedding, tied.
+
+Same conventions as models/llama.py (pure param pytrees, float32 masters,
+activations in ``cfg.dtype``, the same prefill / decode-step contract) with
+three differences this family forces:
+
+- The layers are not one ``lax.scan``: they differ in kind, so the stack is
+  a Python loop and ``params["layers"]`` a list with one dict per layer
+  (no leaf is stacked over layers; one layer's experts are 1.2 GB in bf16).
+- Two kinds of per-sequence state. The paged K/V pool spans the ATTENTION
+  layers only (``cfg.n_kv_layer``). Each conv layer keeps the last
+  ``conv_L_cache - 1`` rows of its gated input per sequence, in a SLOT of
+  the ``state["conv"]`` array ``[n_conv_layer, slots, K-1, D]`` (slot 0 is
+  the garbage sink, as block 0 is); the step functions take ``state`` and
+  the rows' ``slots`` beside the pool and the block tables, and return the
+  next ``state``.
+- ``state`` also carries the expert layers' counters, added to inside the
+  program so that no step hands the host anything but its tokens:
+  ``pairs`` ``[2, E, 2]`` (routed token-expert pairs by expert, prefill and
+  decode apart, padding rows excluded) and ``reads`` ``[2]`` (experts that
+  got at least one token, summed over decode steps and expert layers),
+  each a (low, high) pair of uint32 words (``count_value``).
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.attention import mha_reference
+from ray_tpu.ops.layers import rms_norm, rope
+from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.short_conv import short_conv_decode, short_conv_prefill
+
+LAYER_KINDS = ("conv", "full_attention")
+
+
+@dataclass(frozen=True)
+class Lfm2MoeConfig:
+    vocab_size: int = 65536
+    max_seq_len: int = 128000
+    d_model: int = 2048
+    n_head: int = 32
+    n_kv_head: int = 8
+    head_dim: int = 64              # a key of its own: not d_model // n_head
+    layer_types: tuple[str, ...] = ("conv", "conv", "full_attention", "conv")
+    num_dense_layers: int = 2
+    d_mlp: int = 11776              # dense SwiGLU width
+    num_experts: int = 64
+    top_k: int = 4
+    d_expert: int = 1536            # each expert's SwiGLU width
+    conv_L_cache: int = 3           # taps of the short convolution
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
+    dtype: Any = jnp.bfloat16
+    # decode attention backend / serving quantization: see models/gpt.py
+    # GPTConfig. The engine refuses ``quantization`` for this family.
+    attention_backend: str = "auto"
+    quantization: str | None = None
+
+    def __post_init__(self):
+        # a JSON list arrives here: the config is a jit-cache key
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        bad = sorted(set(self.layer_types) - set(LAYER_KINDS))
+        if bad:
+            raise ValueError(
+                f"layer_types holds {bad}; this family has {LAYER_KINDS}")
+        if self.n_head % self.n_kv_head:
+            raise ValueError("n_head must be a multiple of n_kv_head")
+        if not 0 <= self.num_dense_layers <= len(self.layer_types):
+            raise ValueError("num_dense_layers exceeds the layer count")
+        if self.top_k > self.num_experts:
+            raise ValueError("top_k exceeds num_experts")
+
+    @staticmethod
+    def tiny(vocab_size: int = 512) -> "Lfm2MoeConfig":
+        return Lfm2MoeConfig(
+            vocab_size=vocab_size, max_seq_len=128, d_model=64, n_head=2,
+            n_kv_head=2, head_dim=16,
+            layer_types=("conv", "full_attention", "conv", "conv"),
+            num_dense_layers=1, d_mlp=128, num_experts=8, top_k=2,
+            d_expert=32,
+        )
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_kv_layer(self) -> int:
+        """Layers that cache K/V: what the paged pool spans."""
+        return self.layer_types.count("full_attention")
+
+    @property
+    def n_conv_layer(self) -> int:
+        return self.layer_types.count("conv")
+
+    @property
+    def n_moe_layer(self) -> int:
+        return self.n_layer - self.num_dense_layers
+
+
+def lfm2_moe_init(key: jax.Array, cfg: Lfm2MoeConfig) -> dict:
+    """Float32 masters, normal from ``key``, each matmul leaf with std
+    ``fan_in ** -0.5`` (0.022 at the published hidden size) and the
+    projections back into the residual stream a further ``(2 L) ** -0.5``
+    smaller, so that at ANY width, the tiny test preset included, every
+    layer moves the output and the router's scores spread. The embedding
+    has std ``d_model ** -0.5`` (tied head: logits of about unit spread).
+    ``moe_route_bias`` is drawn with std 0.02: small against the spread of
+    an expert's scores over tokens (about 0.2), so that the experts stay
+    about evenly loaded, as a trained checkpoint's bias is there to keep
+    them, and yet of the size of the gap between the k-th and the
+    (k+1)-th score, so that the biased selection differs from the unbiased
+    one for about a quarter of the routed pairs (zeros would leave the
+    mechanism untested; at std 0.1 some experts take twice their share
+    and a 64-row decode step meets 46 of 64 experts, not 62)."""
+    D, hd = cfg.d_model, cfg.head_dim
+    Hq, Hkv, K = cfg.n_head, cfg.n_kv_head, cfg.conv_L_cache
+    E, F, M = cfg.num_experts, cfg.d_expert, cfg.d_mlp
+    back = (2 * cfg.n_layer) ** -0.5
+
+    def norm(key, *shape, fan_in, gain=1.0):
+        return jax.random.normal(key, shape, jnp.float32) * (
+            gain * fan_in ** -0.5)
+
+    keys = jax.random.split(key, cfg.n_layer + 1)
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        k = iter(jax.random.split(keys[i], 8))
+        lp: dict = {"op_norm": jnp.ones((D,), jnp.float32),
+                    "ffn_norm": jnp.ones((D,), jnp.float32)}
+        if kind == "conv":
+            lp["short_conv_in"] = norm(next(k), D, 3 * D, fan_in=D)  # B C u
+            lp["short_conv_w"] = norm(next(k), K, D, fan_in=K)
+            lp["short_conv_out"] = norm(next(k), D, D, fan_in=D, gain=back)
+        else:
+            # 3 x larger: the per-head norm takes the scale out again, and
+            # WITHOUT the norm the softmax would be 9 x sharper (a trained
+            # q / k has no reason to be of unit size either)
+            lp["wq"] = norm(next(k), D, Hq * hd, fan_in=D, gain=3.0)
+            lp["wk"] = norm(next(k), D, Hkv * hd, fan_in=D, gain=3.0)
+            lp["wv"] = norm(next(k), D, Hkv * hd, fan_in=D)
+            lp["wo"] = norm(next(k), Hq * hd, D, fan_in=Hq * hd, gain=back)
+            lp["q_norm"] = jnp.ones((hd,), jnp.float32)
+            lp["k_norm"] = jnp.ones((hd,), jnp.float32)
+        if i < cfg.num_dense_layers:
+            lp["mlp_in"] = norm(next(k), D, 2 * M, fan_in=D)  # gate, up
+            lp["mlp_out"] = norm(next(k), M, D, fan_in=M, gain=back)
+        else:
+            lp["moe_route_w"] = norm(next(k), D, E, fan_in=D)
+            lp["moe_route_bias"] = norm(next(k), E, fan_in=1, gain=0.02)
+            lp["moe_gmm_w_in"] = norm(next(k), E, D, 2 * F, fan_in=D)
+            lp["moe_gmm_w_out"] = norm(next(k), E, F, D, fan_in=F,
+                                       gain=back)
+        layers.append(lp)
+    return {
+        "wte": norm(keys[-1], cfg.vocab_size, D, fan_in=D),
+        "layers": layers,
+        "ln_f_scale": jnp.ones((D,), jnp.float32),
+    }
+
+
+_LEAF_AXES = {
+    "op_norm": ("embed",), "ffn_norm": ("embed",),
+    "short_conv_in": ("embed", "mlp"), "short_conv_w": (None, "mlp"),
+    "short_conv_out": ("mlp", "embed"),
+    "wq": ("embed", "mlp"), "wk": ("embed", "mlp"), "wv": ("embed", "mlp"),
+    "wo": ("mlp", "embed"), "q_norm": (None,), "k_norm": (None,),
+    "mlp_in": ("embed", "mlp"), "mlp_out": ("mlp", "embed"),
+    "moe_route_w": (None, None), "moe_route_bias": (None,),
+    "moe_gmm_w_in": ("expert", None, "mlp"),
+    "moe_gmm_w_out": ("expert", "mlp", None),
+}
+# the contraction axis of each matmul weight; -1: kept as given (norm
+# scales, the conv filter, and the router, which is read in float32)
+_LEAF_QUANT = {
+    "short_conv_in": 0, "short_conv_out": 0, "wq": 0, "wk": 0, "wv": 0,
+    "wo": 0, "mlp_in": 0, "mlp_out": 0,
+    "moe_gmm_w_in": 1, "moe_gmm_w_out": 1,
+}
+
+
+def _leaf_tree(cfg: Lfm2MoeConfig, leaf, wte, ln_f) -> dict:
+    shape = jax.eval_shape(lambda: lfm2_moe_init(jax.random.PRNGKey(0), cfg))
+    return {
+        "wte": wte,
+        "layers": [{name: leaf(name) for name in lp}
+                   for lp in shape["layers"]],
+        "ln_f_scale": ln_f,
+    }
+
+
+def lfm2_moe_param_axes(cfg: Lfm2MoeConfig) -> dict:
+    """Logical axis names per leaf; the experts get an axis of their own."""
+    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
+                      ("embed",))
+
+
+def lfm2_moe_quant_axes(cfg: Lfm2MoeConfig) -> dict:
+    """Per leaf, the contraction axis of a matmul weight (>= 0: the
+    executor stores it in ``cfg.dtype``, experts included) or -1."""
+    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1)
+
+
+# ------------------------------------------------------------------ state
+
+
+def lfm2_moe_init_state(cfg: Lfm2MoeConfig, slots: int) -> dict:
+    """The per-sequence state beside the paged pool, zeroed: ``slots``
+    counts slot 0, the garbage sink of padding rows."""
+    return {
+        "conv": jnp.zeros(
+            (cfg.n_conv_layer, slots, cfg.conv_L_cache - 1, cfg.d_model),
+            cfg.dtype),
+        "pairs": jnp.zeros((2, cfg.num_experts, 2), jnp.uint32),
+        "reads": jnp.zeros((2,), jnp.uint32),
+    }
+
+
+def _count_add(acc: jax.Array, n: jax.Array) -> jax.Array:
+    """``acc`` [..., 2] uint32 (low, high words) plus ``n`` [...] >= 0."""
+    low = acc[..., 0] + n.astype(jnp.uint32)
+    high = acc[..., 1] + (low < acc[..., 0]).astype(jnp.uint32)
+    return jnp.stack([low, high], axis=-1)
+
+
+def count_value(acc) -> Any:
+    """Host side: the integers a (low, high) counter array holds."""
+    import numpy as np
+
+    a = np.asarray(acc).astype(np.uint64)
+    return (a[..., 1] << np.uint64(32)) + a[..., 0]
+
+
+def lfm2_moe_counters(state: dict) -> dict:
+    """``state``'s counters as plain integers (a device->host read)."""
+    pairs = count_value(state["pairs"])  # [2, E]: prefill, decode
+    return {
+        "moe_pairs_prefill": int(pairs[0].sum()),
+        "moe_pairs_decode": int(pairs[1].sum()),
+        "moe_expert_reads_decode": int(count_value(state["reads"])),
+        "moe_pairs_by_expert": [int(n) for n in pairs.sum(axis=0)],
+    }
+
+
+# ----------------------------------------------------------------- layers
+
+
+def _swiglu(h, w_in, w_out, dtype):
+    gate, up = jnp.split(h @ w_in.astype(dtype), 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ w_out.astype(dtype)
+
+
+def _ffn(x, lp, cfg: Lfm2MoeConfig, valid):
+    """RMSNorm + (SwiGLU | experts) + residual on x [B, S, D]. ``valid``
+    [B, S] marks the real tokens. Returns (x', the expert layer's routed
+    pairs by expert [E] int32, or None for a dense layer)."""
+    B, S, D = x.shape
+    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+    if "mlp_in" in lp:
+        return x + _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
+    flat = h.reshape(B * S, D)
+    weights, experts = moe_route(
+        flat, lp["moe_route_w"],
+        lp["moe_route_bias"] if cfg.use_expert_bias else None, cfg.top_k,
+        norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+    )
+    y, sizes = moe_dropless(
+        flat, weights, experts, lp["moe_gmm_w_in"], lp["moe_gmm_w_out"],
+        dtype=cfg.dtype, valid=valid.reshape(B * S),
+    )
+    return x + y.reshape(B, S, D), sizes
+
+
+def _counted(state: dict, conv, sizes: list, decode: bool) -> dict:
+    """The next ``state``: the conv rows as the step left them, and the
+    step's routed pairs (``sizes``: one [E] per expert layer) added to the
+    counters of its kind."""
+    kind = int(decode)
+    per_expert = sum(sizes)
+    out = {"conv": conv, "reads": state["reads"],
+           "pairs": state["pairs"].at[kind].set(
+               _count_add(state["pairs"][kind], per_expert))}
+    if decode:
+        out["reads"] = _count_add(
+            state["reads"], sum(jnp.sum(s > 0) for s in sizes))
+    return out
+
+
+def _rope_at(pos, cfg: Lfm2MoeConfig):
+    """(cos, sin) [B, S, head_dim // 2] at the true positions ``pos``
+    [B, S]: the angles themselves, not a table of ``max_seq_len`` rows."""
+    hd = cfg.head_dim
+    inv_freq = 1.0 / (
+        cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = pos.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _qkv(h, lp, cos, sin, cfg: Lfm2MoeConfig):
+    """Projections, the per-head RMSNorm on q and k, then the rotary
+    embedding. q [B, S, Hq, hd]; k, v [B, S, Hkv, hd] (the compact GQA
+    heads, as the cache stores them)."""
+    B, S, _ = h.shape
+    Hq, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    q = (h @ lp["wq"].astype(cfg.dtype)).reshape(B, S, Hq, hd)
+    k = (h @ lp["wk"].astype(cfg.dtype)).reshape(B, S, Hkv, hd)
+    v = (h @ lp["wv"].astype(cfg.dtype)).reshape(B, S, Hkv, hd)
+    q = rope(rms_norm(q, lp["q_norm"], cfg.norm_eps), cos, sin)
+    k = rope(rms_norm(k, lp["k_norm"], cfg.norm_eps), cos, sin)
+    return q, k, v
+
+
+def _head(h, params, cfg: Lfm2MoeConfig):
+    """[..., D] -> float32 logits over the tied embedding."""
+    return jnp.einsum(
+        "...d,vd->...v", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _set_layer(pool, i: int, layer):
+    return jax.tree.map(lambda a, b: a.at[i].set(b), pool, layer)
+
+
+def _layer(pool, i: int):
+    return jax.tree.map(lambda a: a[i], pool)
+
+
+def lfm2_moe_forward(params: dict, tokens: jax.Array,
+                     cfg: Lfm2MoeConfig) -> jax.Array:
+    """tokens [B, S] -> logits [B, S, V] float32: the whole sequence at
+    once, no cache and no state (the program's own full forward)."""
+    B, S = tokens.shape
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    cos, sin = _rope_at(
+        jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), cfg)
+    valid = jnp.ones((B, S), bool)
+    lengths = jnp.full((B,), S, jnp.int32)
+    for lp, kind in zip(params["layers"], cfg.layer_types):
+        h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            b, c, u = jnp.split(h @ lp["short_conv_in"].astype(cfg.dtype),
+                                3, axis=-1)
+            y, _ = short_conv_prefill(b * u, c, lp["short_conv_w"], None,
+                                      lengths)
+            x = x + y @ lp["short_conv_out"].astype(cfg.dtype)
+        else:
+            q, k, v = _qkv(h, lp, cos, sin, cfg)
+            attn = mha_reference(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), causal=True,
+            ).transpose(0, 2, 1, 3).reshape(B, S, -1)
+            x = x + attn @ lp["wo"].astype(cfg.dtype)
+        x, _ = _ffn(x, lp, cfg, valid)
+    return _head(rms_norm(x, params["ln_f_scale"], cfg.norm_eps), params, cfg)
+
+
+# ----------------------------------------------------------------------------
+# Cached inference paths (serve/llm engine): the contract of
+# models/llama.py llama_prefill / llama_decode_step, plus ``state`` after
+# the pool and ``slots`` [B] after the block tables. The pool is
+# [n_kv_layer, num_blocks, block_size, n_kv_head, head_dim].
+# ----------------------------------------------------------------------------
+
+
+def lfm2_moe_prefill(
+    params: dict,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    state: dict,
+    tokens: jax.Array,
+    lengths: jax.Array,
+    block_tables: jax.Array,
+    slots: jax.Array,
+    cfg: Lfm2MoeConfig,
+    start: jax.Array | None = None,
+    sample: dict | None = None,
+):
+    """Prompt pass. Returns (last-valid-token logits [B, V] float32 — or,
+    with ``sample``, the sampled first tokens [B] int32 —, cache_k',
+    cache_v', state').
+
+    ``start=None``: the chunk is the whole prompt; positions 0..S-1, the
+    convolutions begin from zeros whatever the slot held (a reused slot is
+    cleared by its first use). ``start`` [B]: row b's tokens sit at true
+    positions ``start[b]..``; attention covers the paged context and each
+    convolution continues from the slot's rows — zeros where ``start[b]``
+    is 0. Rows in slot 0 are padding: routed nowhere, counted nowhere."""
+    from ray_tpu.ops.kv_cache import write_kv
+    from ray_tpu.ops.paged_attention import prefill_attention, resolve_backend
+
+    B, S = tokens.shape
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    cols = jnp.arange(S, dtype=jnp.int32)[None, :]
+    pos = jnp.broadcast_to(cols, (B, S))
+    if start is not None:
+        pos = start[:, None] + cols
+    cos, sin = _rope_at(pos, cfg)
+    in_chunk = cols < lengths[:, None]
+    valid = in_chunk & (slots > 0)[:, None]
+    conv = state["conv"]
+    sizes = []
+    ai = ci = 0
+    for lp, kind in zip(params["layers"], cfg.layer_types):
+        h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            b, c, u = jnp.split(h @ lp["short_conv_in"].astype(cfg.dtype),
+                                3, axis=-1)
+            before = None
+            if start is not None:
+                before = jnp.where((start > 0)[:, None, None],
+                                   conv[ci, slots], 0)
+            y, after = short_conv_prefill(b * u, c, lp["short_conv_w"],
+                                          before, lengths)
+            conv = conv.at[ci, slots].set(after.astype(conv.dtype))
+            ci += 1
+            x = x + y @ lp["short_conv_out"].astype(cfg.dtype)
+        else:
+            q, k, v = _qkv(h, lp, cos, sin, cfg)
+            k_layer, v_layer = write_kv(
+                _layer(cache_k, ai), _layer(cache_v, ai), k, v, pos,
+                block_tables, valid=in_chunk,
+            )
+            if start is None and resolve_backend(
+                    cfg.attention_backend) != "pallas":
+                attn = mha_reference(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), causal=True,
+                ).transpose(0, 2, 1, 3)
+            else:
+                attn = prefill_attention(
+                    q, k_layer, v_layer, block_tables,
+                    jnp.where(in_chunk, pos, 0),
+                    backend=cfg.attention_backend,
+                )
+            cache_k = _set_layer(cache_k, ai, k_layer)
+            cache_v = _set_layer(cache_v, ai, v_layer)
+            ai += 1
+            x = x + attn.reshape(B, S, -1) @ lp["wo"].astype(cfg.dtype)
+        x, routed = _ffn(x, lp, cfg, valid)
+        if routed is not None:
+            sizes.append(routed)
+    state = _counted(state, conv, sizes, decode=False)
+    h = rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
+    logits = _head(h[jnp.arange(B), lengths - 1], params, cfg)
+    if sample is None:
+        return logits, cache_k, cache_v, state
+    from ray_tpu.ops.sampling import sample_tokens
+
+    new_pos = (lengths if start is None else start + lengths).astype(
+        jnp.int32)
+    return sample_tokens(logits, new_pos, sample), cache_k, cache_v, state
+
+
+def lfm2_moe_decode_step(
+    params: dict,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    state: dict,
+    tokens: jax.Array,
+    positions: jax.Array,
+    block_tables: jax.Array,
+    slots: jax.Array,
+    cfg: Lfm2MoeConfig,
+    sample: dict | None = None,
+):
+    """One incremental decode step: each conv layer reads its slot's two
+    rows and writes the next two, each attention layer appends one K/V row
+    and reads the paged context. Returns (next-token logits [B, V] float32
+    or sampled tokens [B] int32, cache_k', cache_v', state')."""
+    from ray_tpu.ops.kv_cache import write_kv
+    from ray_tpu.ops.paged_attention import decode_attention
+
+    B = tokens.shape[0]
+    x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
+    cos, sin = _rope_at(positions[:, None], cfg)
+    valid = (slots > 0)[:, None]
+    conv = state["conv"]
+    sizes = []
+    ai = ci = 0
+    for lp, kind in zip(params["layers"], cfg.layer_types):
+        h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
+        if kind == "conv":
+            b, c, u = jnp.split(
+                h[:, 0] @ lp["short_conv_in"].astype(cfg.dtype), 3, axis=-1)
+            y, after = short_conv_decode(b * u, c, lp["short_conv_w"],
+                                         conv[ci, slots])
+            conv = conv.at[ci, slots].set(after.astype(conv.dtype))
+            ci += 1
+            x = x + (y @ lp["short_conv_out"].astype(cfg.dtype))[:, None]
+        else:
+            q, k, v = _qkv(h, lp, cos, sin, cfg)
+            k_layer, v_layer = write_kv(
+                _layer(cache_k, ai), _layer(cache_v, ai), k[:, 0], v[:, 0],
+                positions, block_tables,
+            )
+            attn = decode_attention(
+                q[:, 0], k_layer, v_layer, block_tables, positions,
+                backend=cfg.attention_backend,
+            )
+            cache_k = _set_layer(cache_k, ai, k_layer)
+            cache_v = _set_layer(cache_v, ai, v_layer)
+            ai += 1
+            x = x + (attn.reshape(B, -1) @ lp["wo"].astype(cfg.dtype))[:, None]
+        x, routed = _ffn(x, lp, cfg, valid)
+        if routed is not None:
+            sizes.append(routed)
+    state = _counted(state, conv, sizes, decode=True)
+    h = rms_norm(x[:, 0], params["ln_f_scale"], cfg.norm_eps)
+    logits = _head(h, params, cfg)
+    if sample is None:
+        return logits, cache_k, cache_v, state
+    from ray_tpu.ops.sampling import sample_tokens
+
+    return (sample_tokens(logits, positions + 1, sample), cache_k, cache_v,
+            state)

@@ -1,14 +1,23 @@
-"""Mixture-of-Experts layer with expert parallelism over the `ep` mesh axis.
+"""Mixture-of-Experts layers: two of them, for two paths.
 
-The reference has NO MoE / expert parallelism (SURVEY.md §2.4 EP row:
-absent) — new first-class capability, built the TPU way: top-k gating with
-capacity-bounded one-hot dispatch einsums (static shapes — no ragged
-gather), experts sharded on the `ep` axis; under jit the dispatch/combine
-einsums against ep-sharded expert weights lower to XLA all-to-alls on ICI.
+**The served layer is the dropless one** (``moe_route`` + ``moe_dropless``,
+at the end of this file): a sigmoid router with a selection bias, top-k,
+the (token, expert) pairs sorted by expert, one grouped matrix product over
+the experts (``jax.lax.ragged_dot``), SwiGLU experts, weighted combine.
+Every routed pair is computed whatever the routing, so a token's output
+depends on its own row only (batched == solo) and the layer can be checked
+against a plain reference. ``models/lfm2_moe.py`` serves through it.
 
-Math follows the public Switch/GShard formulation: router softmax → top-k
-experts per token → capacity-truncated dispatch mask → expert MLPs →
-gate-weighted combine, plus the standard load-balancing auxiliary loss.
+**The capacity layer** (``moe_forward``) is the one the llama TRAINING path
+uses (``models/llama.py`` with ``num_experts > 0``), built for expert
+parallelism over the ``ep`` mesh axis: softmax router, top-k gating with
+capacity-bounded one-hot dispatch einsums (static shapes, no ragged
+gather), GELU experts sharded on ``ep`` so that the dispatch/combine
+einsums lower to all-to-alls, and the Switch load-balancing loss. Its
+capacity is a share of the BATCH (``int(cf * k * T / E)``) and overflow
+tokens are dropped, so a token's output depends on its batch-mates: right
+for training at a fixed batch, not servable. The reference has NO MoE /
+expert parallelism (SURVEY.md section 2.4 EP row: absent).
 """
 from __future__ import annotations
 
@@ -119,3 +128,87 @@ def moe_reference_dense(params: dict, x: jax.Array, cfg: MoEConfig) -> jax.Array
         )[0]  # [T, D]
         out = out + sel * gate_vals[:, j, None].astype(cfg.dtype)
     return out.astype(x.dtype)
+
+
+# ----------------------------------------------------------------------------
+# The served layer: dropless, sigmoid-routed, SwiGLU experts.
+# ----------------------------------------------------------------------------
+
+# what the published LFM2-MoE code adds to the sum of the chosen scores
+# before dividing by it (``norm_topk_prob``)
+ROUTE_NORM_EPS = 1e-6
+
+
+def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
+              top_k: int, *, norm_topk: bool = True, scale: float = 1.0):
+    """x [T, D] -> (weights [T, k] f32, experts [T, k] int32).
+
+    ``s = sigmoid(x @ router)``; the ``top_k`` experts are chosen by
+    ``s + bias`` (the stored selection bias), but weighted by the UNBIASED
+    ``s``, divided by their sum when ``norm_topk``, times ``scale``. All of
+    it in float32 at the highest matmul precision, whatever ``x`` is: a
+    near-tie between the k-th and the (k+1)-th score is the one place where
+    rounding changes WHICH weights a token meets."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores if bias is None else scores + bias.astype(
+            jnp.float32)
+        _, experts = jax.lax.top_k(chosen_by, top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk:
+            weights = weights / (
+                jnp.sum(weights, axis=-1, keepdims=True) + ROUTE_NORM_EPS)
+        return weights * scale, experts.astype(jnp.int32)
+
+
+def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                 w_in: jax.Array, w_out: jax.Array, *, dtype,
+                 valid: jax.Array | None = None):
+    """The expert layer proper: x [T, D] -> (y [T, D], pairs_by_expert [E]
+    int32).
+
+    ``w_in`` [E, D, 2F] packs each expert's gate and up projections (gate
+    first), ``w_out`` [E, F, D]. The T * k (token, expert) pairs are sorted
+    by expert and met by ONE grouped product each way
+    (``jax.lax.ragged_dot`` with the per-expert counts as group sizes:
+    products in ``dtype``, float32 accumulation), so any routing is
+    computed whole, all tokens on one expert included: no capacity, no
+    drop. ``valid`` [T] bool marks the real rows of a bucketed batch:
+    pairs of padding rows sort behind every expert's, belong to no group,
+    are not computed and not counted, and their output is zero."""
+    T, D = x.shape
+    k = experts.shape[1]
+    E = w_in.shape[0]
+    with jax.named_scope("moe_gmm"):
+        flat = experts.reshape(T * k)
+        if valid is not None:
+            flat = jnp.where(jnp.repeat(valid, k), flat, E)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
+        xs = x.astype(dtype)[order // k]
+        h = jax.lax.ragged_dot(
+            xs, w_in.astype(dtype), sizes,
+            preferred_element_type=jnp.float32,
+        )
+        gate, up = jnp.split(h, 2, axis=-1)
+        act = (jax.nn.silu(gate) * up).astype(dtype)
+        ys = jax.lax.ragged_dot(
+            act, w_out.astype(dtype), sizes,
+            preferred_element_type=jnp.float32,
+        )
+        # back to (token, choice) order, then the weighted sum over choices
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        yk = ys[back].reshape(T, k, D)
+        w = weights.astype(jnp.float32)
+        if valid is not None:
+            # rows past the last group hold whatever the product left there
+            yk = jnp.where(valid[:, None, None], yk, 0.0)
+            w = jnp.where(valid[:, None], w, 0.0)
+        y = jnp.einsum("tkd,tk->td", yk, w,
+                       precision=jax.lax.Precision.HIGHEST)
+        return y.astype(x.dtype), sizes

@@ -10,70 +10,96 @@ dashboards) can assert the bucketing keeps it bounded.
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 from typing import Callable
 
 
-def _gpt_fns(model_cfg):
-    from ray_tpu.models.gpt import (
-        gpt_decode_step,
-        gpt_init,
-        gpt_prefill,
-        gpt_verify_step,
-    )
+@dataclass(frozen=True)
+class Family:
+    """One model family the engine serves: everything the engine, the
+    executor and ``DecodeFns`` ask about a family, so that adding one
+    means one loader in ``FAMILIES`` below.
 
-    return gpt_init, gpt_prefill, gpt_decode_step, gpt_verify_step
+    ``init`` / ``prefill`` / ``decode_step`` / ``verify_step`` (None: the
+    family has no verify step) are the model's functions; ``param_axes``
+    and ``quant_axes`` map a model config to trees matching ``init``'s
+    output; ``default_config()`` is the tiny config an engine built
+    without one gets. ``init_state`` is None for a family whose only
+    per-sequence state is the paged K/V pool, else ``(model_cfg, slots) ->
+    pytree``: what the family keeps per running sequence BESIDE the pool
+    (its step functions then take ``state`` after the pool and ``slots``
+    after the block tables, and return the next ``state`` after the
+    pool); ``counters`` is ``state -> {name: int | [int]}``, the counters
+    the step programs keep inside ``state``, read for ``stats()``."""
+
+    init: Callable
+    prefill: Callable
+    decode_step: Callable
+    verify_step: Callable | None
+    param_axes: Callable
+    quant_axes: Callable
+    default_config: Callable
+    init_state: Callable | None = None
+    counters: Callable | None = None
 
 
-def _llama_fns(model_cfg):
-    from ray_tpu.models.llama import (
-        llama_decode_step,
-        llama_init,
-        llama_prefill,
-        llama_verify_step,
-    )
+def _gpt() -> Family:
+    from ray_tpu.models import gpt as m
 
-    return llama_init, llama_prefill, llama_decode_step, llama_verify_step
+    return Family(m.gpt_init, m.gpt_prefill, m.gpt_decode_step,
+                  m.gpt_verify_step, m.gpt_param_axes, m.gpt_quant_axes,
+                  m.GPTConfig.tiny)
 
 
-FAMILIES: dict[str, Callable] = {"gpt": _gpt_fns, "llama": _llama_fns}
+def _llama() -> Family:
+    from ray_tpu.models import llama as m
+
+    return Family(m.llama_init, m.llama_prefill, m.llama_decode_step,
+                  m.llama_verify_step, m.llama_param_axes,
+                  m.llama_quant_axes, m.LlamaConfig.tiny)
 
 
-def family_param_axes(family: str, model_cfg):
+def _lfm2_moe() -> Family:
+    from ray_tpu.models import lfm2_moe as m
+
+    # no verify step: rejected drafts would need the conv state rolled back
+    return Family(m.lfm2_moe_init, m.lfm2_moe_prefill,
+                  m.lfm2_moe_decode_step, None, m.lfm2_moe_param_axes,
+                  m.lfm2_moe_quant_axes, m.Lfm2MoeConfig.tiny,
+                  init_state=m.lfm2_moe_init_state,
+                  counters=m.lfm2_moe_counters)
+
+
+# THE registry of served families (``EngineConfig.model`` names a key);
+# each entry imports its model file when it is first asked for
+FAMILIES: dict[str, Callable[[], Family]] = {
+    "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
+}
+
+
+def get_family(name: str) -> Family:
+    """The family's entry, or a ValueError that names what is served."""
+    if name not in FAMILIES:
+        raise ValueError(
+            f"unknown model family {name!r}; expected one of "
+            f"{sorted(FAMILIES)}"
+        )
+    return FAMILIES[name]()
+
+
+def family_param_axes(name: str, model_cfg):
     """Logical-axis tree matching the family's init output — what a
-    sharded executor feeds parallel.sharding.shard_params. Kept next to
-    FAMILIES so adding a model family means extending exactly one
-    registry module."""
-    if family == "gpt":
-        from ray_tpu.models.gpt import gpt_param_axes
-
-        return gpt_param_axes(model_cfg)
-    if family == "llama":
-        from ray_tpu.models.llama import llama_param_axes
-
-        return llama_param_axes(model_cfg)
-    raise ValueError(
-        f"unknown model family {family!r}; expected one of "
-        f"{sorted(FAMILIES)}"
-    )
+    sharded executor feeds parallel.sharding.shard_params."""
+    return get_family(name).param_axes(model_cfg)
 
 
-def family_quant_axes(family: str, model_cfg):
+def family_quant_axes(name: str, model_cfg):
     """Per-leaf amax reduction-axis tree matching the family's init
     output — what the executor feeds ops/quantization.quantize_params
-    when ``model_cfg.quantization`` is set (-1 leaves stay f32). Lives
-    here for the same reason as family_param_axes."""
-    if family == "gpt":
-        from ray_tpu.models.gpt import gpt_quant_axes
+    when ``model_cfg.quantization`` is set, and what marks the matmul
+    weights it stores in the compute dtype (-1 leaves stay as given)."""
+    return get_family(name).quant_axes(model_cfg)
 
-        return gpt_quant_axes(model_cfg)
-    if family == "llama":
-        from ray_tpu.models.llama import llama_quant_axes
-
-        return llama_quant_axes(model_cfg)
-    raise ValueError(
-        f"unknown model family {family!r}; expected one of "
-        f"{sorted(FAMILIES)}"
-    )
 
 # Process-wide jit cache: jax.jit memoizes traces per *wrapper*, so two
 # engines over the same (family, config) — e.g. several replicas colocated
@@ -118,15 +144,18 @@ def _jitted(family: str, model_cfg, platform):
     key = (family, model_cfg, tuple(sorted((options or {}).items())))
     hit = _jit_cache.get(key)
     if hit is None:
-        init, *steps = FAMILIES[family](model_cfg)
-        hit = (init, *(_jit_named(fn, model_cfg, options) for fn in steps))
+        fam = get_family(family)
+        hit = (fam.init, *(
+            None if fn is None else _jit_named(fn, model_cfg, options)
+            for fn in (fam.prefill, fam.decode_step, fam.verify_step)))
         _jit_cache[key] = hit
     return hit
 
 
 class DecodeFns:
     """prefill(params, cache_k, cache_v, tokens, lengths, block_tables)
-    and decode(params, cache_k, cache_v, tokens, positions, block_tables),
+    and decode(params, cache_k, cache_v, tokens, positions, block_tables)
+    (``verify`` is None for a family without a verify step),
     jitted with the model config closed over as a static value. Compiled
     programs are shared process-wide per (family, config, compiler settings); the
     signature set below is per-instance, so each engine reports the
@@ -135,11 +164,7 @@ class DecodeFns:
     compiler settings (``_compiler_options``)."""
 
     def __init__(self, family: str, model_cfg, platform: str | None = None):
-        if family not in FAMILIES:
-            raise ValueError(
-                f"unknown model family {family!r}; expected one of "
-                f"{sorted(FAMILIES)}"
-            )
+        get_family(family)  # raises on a family that is not served
         self.family = family
         self.model_cfg = model_cfg
         self.init, self._prefill, self._decode, self._verify = _jitted(
@@ -160,7 +185,7 @@ class DecodeFns:
 
     def prefill(
         self, params, cache_k, cache_v, tokens, lengths, block_tables,
-        start=None, sample=None,
+        start=None, sample=None, state=None, slots=None,
     ):
         # start=None is the monolithic whole-prompt path (positions are
         # arange over the chunk, reference-attention formulation); a [B]
@@ -172,29 +197,33 @@ class DecodeFns:
         # (token ids out instead of logits), not its signature, so the
         # compile-count contract stays (prefill, prefill_chunk, decode)
         # x batch_buckets x length_buckets.
+        # ``state`` / ``slots``: a family that keeps per-sequence state
+        # beside the pool (``Family.state``) takes both and returns the
+        # next state after the pool; for the others neither argument
+        # exists, so nothing of it enters their programs.
         kind = "prefill" if start is None else "prefill_chunk"
         self._note(
             (kind, tuple(tokens.shape), tuple(block_tables.shape))
         )
+        args = (tokens, lengths, block_tables)
+        if state is not None:
+            args = (state, *args, slots)
         if start is None:
             return self._prefill(
-                params, cache_k, cache_v, tokens, lengths, block_tables,
-                sample=sample,
-            )
+                params, cache_k, cache_v, *args, sample=sample)
         return self._prefill(
-            params, cache_k, cache_v, tokens, lengths, block_tables,
-            start=start, sample=sample,
-        )
+            params, cache_k, cache_v, *args, start=start, sample=sample)
 
     def decode(self, params, cache_k, cache_v, tokens, positions,
-               block_tables, sample=None):
+               block_tables, sample=None, state=None, slots=None):
         self._note(
             ("decode", tuple(tokens.shape), tuple(block_tables.shape))
         )
+        args = (tokens, positions, block_tables)
+        if state is not None:
+            args = (state, *args, slots)
         return self._decode(
-            params, cache_k, cache_v, tokens, positions, block_tables,
-            sample=sample,
-        )
+            params, cache_k, cache_v, *args, sample=sample)
 
     def verify(self, params, cache_k, cache_v, tokens, starts, draft_len,
                block_tables, sample=None):

@@ -207,8 +207,8 @@ class SamplingParams:
 
 @dataclass(frozen=True)
 class EngineConfig:
-    model: str = "llama"          # gpt | llama (decode.py FAMILIES)
-    model_config: Any = None      # GPTConfig/LlamaConfig; None -> .tiny()
+    model: str = "llama"          # a key of decode.py FAMILIES
+    model_config: Any = None      # the family's config; None -> its tiny one
     block_size: int = 16
     num_blocks: int = 64
     max_batch_size: int = 8       # max concurrently-running sequences
@@ -453,16 +453,12 @@ class LLMEngine:
             import dataclasses
 
             cfg = dataclasses.replace(cfg, **overrides)
+        from ray_tpu.serve.llm.decode import get_family
+
+        family = get_family(cfg.model)  # raises on a name it does not serve
         model_cfg = cfg.model_config
         if model_cfg is None:
-            if cfg.model == "gpt":
-                from ray_tpu.models.gpt import GPTConfig
-
-                model_cfg = GPTConfig.tiny()
-            else:
-                from ray_tpu.models.llama import LlamaConfig
-
-                model_cfg = LlamaConfig.tiny()
+            model_cfg = family.default_config()
         # thread the decode-attention backend into the (static) model
         # config: EngineConfig wins, then a ModelParallelConfig-style
         # mesh object's knob, else the model config keeps its own
@@ -503,10 +499,20 @@ class LLMEngine:
             model_cfg = dataclasses.replace(model_cfg, quantization=quant)
         self.cfg = cfg
         self.model_cfg = model_cfg
+        # A family that keeps per-sequence state beside the pool
+        # (decode.py ``Family.init_state``): what cannot carry that state yet
+        # is refused here, by name, never served silently wrong.
+        self._stateful = family.init_state is not None
+        if self._stateful:
+            self._refuse_for_state(cfg, quant)
         n_kv = getattr(model_cfg, "n_kv_head", model_cfg.n_head)
+        # one slot per running sequence, and slot 0, the garbage sink
+        slots = cfg.max_batch_size + 1 if self._stateful else 0
         self.cache = PagedKVCache(
             KVCacheConfig(
-                n_layer=model_cfg.n_layer,
+                # the pool spans the layers that cache K/V: all of them,
+                # unless the family says otherwise
+                n_layer=getattr(model_cfg, "n_kv_layer", model_cfg.n_layer),
                 n_kv_head=n_kv,
                 head_dim=model_cfg.head_dim,
                 num_blocks=cfg.num_blocks,
@@ -514,7 +520,13 @@ class LLMEngine:
                 dtype=model_cfg.dtype,
                 host_cache_bytes=cfg.host_cache_bytes,
                 quantization=quant,
-            )
+                state_slots=slots,
+                # a prefix hit would need the recurrent state as it stood
+                # at the block boundary: no reuse for such a family
+                prefix_reuse=not self._stateful,
+            ),
+            state=(family.init_state(model_cfg, slots)
+                   if self._stateful else None),
         )
         # the ModelExecutor seam (executor.py): the engine schedules on
         # host state only; weights, the KV pool arrays, and the jitted
@@ -787,6 +799,46 @@ class LLMEngine:
         self.executor.on_new_signature = self._on_new_signature
         self.executor.phases = self._step_phases
 
+    @staticmethod
+    def _refuse_for_state(cfg: EngineConfig, quant) -> None:
+        """Raise for each option that cannot yet carry the per-sequence
+        state of a family like ``lfm2_moe``, with the reason."""
+        why = {
+            "speculative_k": (
+                cfg.speculative_k > 0,
+                "rejected drafts would need the per-sequence state rolled "
+                "back, and the family has no verify step"),
+            "host_cache_bytes": (
+                cfg.host_cache_bytes > 0,
+                "a block promoted from the host tier restores K/V but not "
+                "the state at its boundary"),
+            "preemption": (
+                cfg.preemption is not None,
+                "a paused stream's state slot is not demoted with its "
+                "blocks"),
+            "quantization": (
+                quant is not None,
+                "the family's expert and conv weights have no quantized "
+                "path"),
+            "tp/fsdp/mesh": (
+                cfg.mesh is not None or cfg.tp != 1 or cfg.fsdp != 1,
+                "ShardedExecutor has no expert axis and does not place the "
+                "state arrays"),
+        }
+        for option, (asked, reason) in why.items():
+            if asked:
+                raise ValueError(
+                    f"model {cfg.model!r} keeps per-sequence state beside "
+                    f"the paged cache and cannot be served with {option}: "
+                    f"{reason}")
+
+    def _refuse_handoff(self, what: str) -> None:
+        if self._stateful:
+            raise ValueError(
+                f"model {self.cfg.model!r} keeps per-sequence state beside "
+                f"the paged cache and cannot {what}: the prefill/decode "
+                "handoff moves K/V blocks, not the state at their boundary")
+
     # ---------------- public API ----------------
 
     def submit(
@@ -1004,6 +1056,7 @@ class LLMEngine:
         shorter — still valid — handoff. Call after prefill finished
         (e.g. a drained max_new_tokens=1 generate), when the prompt's
         blocks are content-addressed in the prefix cache."""
+        self._refuse_handoff("export a prefix")
         with self._lock:
             chain = self.cache.export_chain(prompt)
             if not chain:
@@ -1025,6 +1078,7 @@ class LLMEngine:
         (retries, concurrent identical prompts), a digest mismatch or a
         full pool stops the walk — earlier blocks still count. Returns
         the number of leading prompt blocks resident afterwards."""
+        self._refuse_handoff("adopt a prefix")
         bs = self.cache.cfg.block_size
         with self._lock:
             # Cap adoptions at the spare (unreserved) capacity: landing
@@ -1068,7 +1122,14 @@ class LLMEngine:
             cs = self.cache.stats
             hit = cs.prefix_hit_tokens
             computed = self._prefill_tokens_total
-            return {
+            # the counters the family's programs keep on the device
+            # (lfm2_moe: moe_pairs_*, moe_expert_reads_decode): only the
+            # reference is taken here. Reading it waits for the step in
+            # flight, so that is done below, with the lock released; a
+            # failed engine's device is not asked
+            counters = (None if self._failed is not None
+                        else self.executor.counter_state())
+            out = {
                 "waiting": len(self._waiting),
                 "prefilling": len(self._prefilling),
                 "running": len(self._running),
@@ -1078,6 +1139,11 @@ class LLMEngine:
                 "kv_used_blocks": self.cache.used_blocks,
                 "kv_utilization": self.cache.utilization,
                 "kv_high_water_blocks": cs.high_water_blocks,
+                # per-sequence state beside the pool (0 for a family that
+                # keeps none), and whether a prefix hit can be reused
+                "state_slots": self.cache.used_slots,
+                "state_slots_high_water": cs.state_slots_high_water,
+                "prefix_reuse": self.cache.cfg.prefix_reuse,
                 "num_compiled_shapes": self.fns.num_compiled_shapes,
                 "rejected_total": self._rejected_total,
                 "cancelled_total": self._cancelled_total,
@@ -1130,6 +1196,8 @@ class LLMEngine:
                 "executor": self.executor.describe(),
                 "failed": self._failed is not None,
             }
+        out.update(self.executor.read_counters(counters))
+        return out
 
     @property
     def fns(self):
@@ -1586,6 +1654,10 @@ class LLMEngine:
         logits, and that write lands in a shared hashed block, so it
         always triggers exactly one copy-on-write copy."""
         bs = self.cfg.block_size
+        if self._stateful and not self.cache.free_slots:
+            # every state slot is held (a cancelled row's goes back only
+            # when its in-flight step has been reconciled)
+            return False
         # Resumed-from-preemption rows prefill prompt + generated-so-far,
         # but the worst case is unchanged: len(toks) + tokens-still-to-
         # generate == len(prompt) + max_new_tokens, always.
@@ -1791,6 +1863,7 @@ class LLMEngine:
             lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
             starts = self._scratch_buf("pf_starts", (B,), np.int32)
             tables = self._scratch_buf("pf_tables", (B, nb), np.int32)
+            slots = self._slots_buf_locked("pf_slots", batch, B)
             # reused buffers: stale padding rows/columns must be re-zeroed
             # (a stale table row could point at blocks now owned by a LIVE
             # sequence — padding writes must stay on the garbage block)
@@ -1809,11 +1882,13 @@ class LLMEngine:
         span = {"kind": kind}
         if legacy:
             toks_dev = self.executor.prefill(
-                tokens, lengths, tables, sample=sample, span=span
+                tokens, lengths, tables, sample=sample, span=span,
+                slots=slots,
             )
         else:
             toks_dev = self.executor.prefill_chunk(
-                tokens, lengths, starts, tables, sample=sample, span=span
+                tokens, lengths, starts, tables, sample=sample, span=span,
+                slots=slots,
             )
         # first tokens sync immediately (lag 0): TTFT must not wait for
         # the next decode step, and only final-chunk rows emit anyway
@@ -1967,6 +2042,7 @@ class LLMEngine:
             nb = ctx // bs
             positions = self._scratch_buf("dec_positions", (B,), np.int32)
             tables = self._scratch_buf("dec_tables", (B, nb), np.int32)
+            slots = self._slots_buf_locked("dec_slots", batch, B)
             # reused buffers: re-zero padding rows (a stale table row
             # could point at blocks now owned by a live sequence)
             positions[len(batch):] = 0
@@ -1990,7 +2066,7 @@ class LLMEngine:
             sample = self._sample_args_locked(batch, B)
         next_dev = self.executor.decode_step(
             tokens_src, positions, tables, sample=sample,
-            span={"kind": "decode", "kv_tokens": kv_tokens},
+            span={"kind": "decode", "kv_tokens": kv_tokens}, slots=slots,
         )
         self._decode_steps += 1
         self._decode_steps_steady += steady
@@ -2334,6 +2410,18 @@ class LLMEngine:
             "top_p": top_p,
             "mask": mask,
         }
+
+    def _slots_buf_locked(self, name: str, batch: list,
+                          B: int) -> np.ndarray | None:
+        """[B] int32: each row's state slot, padding rows on slot 0 (the
+        garbage sink); None for a family that keeps no such state."""
+        if not self._stateful:
+            return None
+        slots = self._scratch_buf(name, (B,), np.int32)
+        slots[len(batch):] = 0
+        for i, r in enumerate(batch):
+            slots[i] = self.cache.slot(r.id)
+        return slots
 
     def _scratch_buf(self, name: str, shape: tuple, dtype) -> np.ndarray:
         """Reusable numpy staging buffer for one (name, shape) slot. TWO

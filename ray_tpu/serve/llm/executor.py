@@ -108,7 +108,12 @@ class ModelExecutor:
 
     The four step methods also take ``span=``, the attributes of the
     step's ``executor.dispatch`` phase, and book their two host phases
-    into ``phases`` (``_run``).
+    into ``phases`` (``_run``). For a family that keeps per-sequence
+    state beside the pool (``cache.state``; decode.py ``Family.state``)
+    the prefill and decode methods take ``slots=``, each row's state slot,
+    and ``cache.state`` is passed through the step and updated in place
+    like ``cache.k`` / ``cache.v``; the other families' calls are the same
+    calls as before, with no such argument.
     """
 
     kind = "single"
@@ -274,34 +279,45 @@ class ModelExecutor:
     def _run(self, fn, arrays, sample, span, **staged):
         """One jitted step, in the two host phases it has (obs.phase):
         ``executor.stage`` moves the engine's numpy staging arrays
-        (``arrays`` in the call's order, ``staged`` by keyword, and the
-        ``sample`` pytree) on-device; ``executor.dispatch`` is the jitted
-        call until it returns, under the attributes the engine gives in
-        ``span`` (``kind``; ``kv_tokens`` for decode and verify).
-        Updates ``cache.k`` / ``cache.v`` in place."""
+        (``arrays`` in the call's order, ``staged`` by keyword — a None
+        is left out —, and the ``sample`` pytree) on-device;
+        ``executor.dispatch`` is the jitted call until it returns, under
+        the attributes the engine gives in ``span`` (``kind``;
+        ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
+        ``cache.v`` in place, and ``cache.state`` where the family keeps
+        one."""
         with obs.phase(self.phases, "executor.stage"):
             dev = [self._dev(a) for a in arrays]
-            staged = {k: self._dev(v) for k, v in staged.items()}
+            staged = {k: self._dev(v) for k, v in staged.items()
+                      if v is not None}
             sample = self._dev_sample(sample)
         with obs.phase(self.phases, "executor.dispatch", **(span or {})):
-            out, self.cache.k, self.cache.v = fn(
-                self.params, self.cache.k, self.cache.v, *dev,
-                sample=sample, **staged,
-            )
+            if self.cache.state is None:
+                out, self.cache.k, self.cache.v = fn(
+                    self.params, self.cache.k, self.cache.v, *dev,
+                    sample=sample, **staged,
+                )
+            else:
+                out, self.cache.k, self.cache.v, self.cache.state = fn(
+                    self.params, self.cache.k, self.cache.v, *dev,
+                    sample=sample, state=self.cache.state, **staged,
+                )
         return out
 
-    def prefill(self, tokens, lengths, tables, sample=None, span=None):
+    def prefill(self, tokens, lengths, tables, sample=None, span=None,
+                slots=None):
         return self._run(self.fns.prefill, (tokens, lengths, tables),
-                         sample, span)
+                         sample, span, slots=slots)
 
     def prefill_chunk(self, tokens, lengths, starts, tables, sample=None,
-                      span=None):
+                      span=None, slots=None):
         return self._run(self.fns.prefill, (tokens, lengths, tables),
-                         sample, span, start=starts)
+                         sample, span, start=starts, slots=slots)
 
-    def decode_step(self, tokens, positions, tables, sample=None, span=None):
+    def decode_step(self, tokens, positions, tables, sample=None, span=None,
+                    slots=None):
         return self._run(self.fns.decode, (tokens, positions, tables),
-                         sample, span)
+                         sample, span, slots=slots)
 
     def verify_step(self, tokens, starts, draft_len, tables, sample=None,
                     span=None):
@@ -525,16 +541,59 @@ class ModelExecutor:
     def num_devices(self) -> int:
         return 1
 
+    def counter_state(self):
+        """The device arrays ``read_counters`` reads, as they stand now
+        (immutable, never donated: a later step leaves this reference
+        whole), or None for a family that keeps no counters. Costs
+        nothing: the engine takes it while it holds its lock."""
+        from ray_tpu.serve.llm.decode import get_family
+
+        if get_family(self.family).counters is None:
+            return None
+        return self.cache.state
+
+    def read_counters(self, state) -> dict:
+        """``counter_state()``'s counters as plain integers (decode.py
+        ``Family.counters``); {} for None. A device->host read that waits
+        for the step that was in flight when the reference was taken: for
+        ``stats()`` OUTSIDE the engine's lock, never for a step."""
+        from ray_tpu.serve.llm.decode import get_family
+
+        if state is None:
+            return {}
+        return get_family(self.family).counters(state)
+
+    def _state_report(self) -> dict:
+        """What the family holds per sequence: how many layers the paged
+        pool spans, the state slots beside it (None: the pool is all),
+        and whether a prefix hit can be reused."""
+        import jax
+
+        cfg = self.cache.cfg
+        state = None
+        if self.cache.state is not None:
+            state = {
+                "slots": cfg.state_slots - 1,  # slot 0 is the garbage sink
+                "bytes": int(sum(
+                    t.size * t.dtype.itemsize
+                    for t in jax.tree.leaves(self.cache.state))),
+                "arrays": {k: list(v.shape)
+                           for k, v in self.cache.state.items()},
+            }
+        return {"kv_layers": cfg.n_layer, "state": state,
+                "prefix_reuse": cfg.prefix_reuse}
+
     def describe(self) -> dict:
         """Stable summary for stats()/debug_dump()/benchmarks: which
-        executor is serving, over how many devices, and which decode
-        attention backend the model steps compiled with."""
+        executor is serving, over how many devices, which decode
+        attention backend the model steps compiled with, and what state
+        the family holds per sequence."""
         return {"executor": self.kind, "devices": self.num_devices,
                 "mesh": None, **self._device_report(),
                 "attention_backend": self.attention_backend,
                 "quantization": getattr(
                     self.model_cfg, "quantization", None),
-                **self._weights_report(),
+                **self._weights_report(), **self._state_report(),
                 "speculative": self.speculative}
 
 

@@ -40,6 +40,20 @@ hits, appends and COW copies, so the no-mid-flight-failure invariant is
 unchanged; ``release_all`` also drops the content-addressed set, keeping
 engine create/shutdown cycles leak-free.
 
+Per-sequence state slots (``state_slots > 0``): a family whose layers keep
+state of FIXED size per running sequence (a short convolution's last rows;
+models/lfm2_moe.py) holds it beside the pool, in ``state`` — a pytree the
+family builds, with a slot axis. The manager hands out the slot ids as it
+hands out blocks: ``allocate`` takes a slot with the sequence's table,
+``free`` / ``release_all`` return it, exactly once; slot 0 is the garbage
+sink of padding rows, as block 0 is. A freed slot is reusable at once, with
+no quarantine: every program that touches ``state`` takes the array the
+last one returned, so a step still in flight is ordered before the reuse,
+and a sequence's first chunk starts from zeros whatever its slot held.
+``prefix_reuse=False`` turns the prefix cache's LOOKUPS off for such a
+family (a hit would need the state as it stood at the block boundary):
+``peek_prefix`` answers as a miss and nothing is content-addressed.
+
 Host-memory tier (``host_cache_bytes > 0``): LRU eviction DEMOTES a full
 prefix block into a pinned host-side arena instead of discarding it —
 the plasma spill model from the Ray object store, applied to KV. Each
@@ -99,6 +113,12 @@ class KVCacheConfig:
     # the scale/compute reference dtype and the pool data dtype comes
     # from the kind.
     quantization: str | None = None
+    # Slots of per-sequence state beside the pool, slot 0 (the garbage
+    # sink) included; 0: the family keeps none. See the module docstring.
+    state_slots: int = 0
+    # False: no block is content-addressed and every prefix lookup misses
+    # (a family whose recurrent state a mapped block would not restore).
+    prefix_reuse: bool = True
 
     @property
     def usable_blocks(self) -> int:
@@ -124,6 +144,7 @@ class CacheStats:
     promotion_drops: int = 0     # queued promotions invalidated before landing
     demote_drops: int = 0        # demote captures that failed (content lost)
     host_corrupt_drops: int = 0  # arena entries failing RTKV verification
+    state_slots_high_water: int = 0  # most state slots held at once
     tables: dict = field(default_factory=dict)
 
 
@@ -223,10 +244,15 @@ class PagedKVCache:
     scheduler lock (one stepper at a time).
     """
 
-    def __init__(self, cfg: KVCacheConfig):
+    def __init__(self, cfg: KVCacheConfig, state=None):
         import jax.numpy as jnp
 
         self.cfg = cfg
+        # the family's per-sequence state arrays (device side, like k / v;
+        # the executor passes them through its steps) and their slot ids
+        self.state = state
+        self._free_slots: list[int] = list(range(cfg.state_slots - 1, 0, -1))
+        self._slots: dict[Any, int] = {}
         dtype = cfg.dtype if cfg.dtype is not None else jnp.bfloat16
         shape = (
             cfg.n_layer, cfg.num_blocks, cfg.block_size,
@@ -347,10 +373,31 @@ class PagedKVCache:
 
     # ---------------- allocate / append / free ----------------
 
+    @property
+    def free_slots(self) -> int:
+        """State slots an admission may take (0 without state slots)."""
+        return len(self._free_slots)
+
+    @property
+    def used_slots(self) -> int:
+        return len(self._slots)
+
+    def slot(self, seq_id) -> int:
+        """The sequence's state slot (its row of ``state``'s slot axis)."""
+        return self._slots[seq_id]
+
     def allocate(self, seq_id) -> None:
-        """Register a sequence with an empty block table."""
+        """Register a sequence with an empty block table and, where the
+        family keeps state slots, take one for it."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
+        if self.cfg.state_slots:
+            if not self._free_slots:
+                raise RuntimeError(
+                    "no free state slot: admission must check free_slots")
+            self._slots[seq_id] = self._free_slots.pop()
+            self.stats.state_slots_high_water = max(
+                self.stats.state_slots_high_water, len(self._slots))
         self._tables[seq_id] = []
         self._chain[seq_id] = (b"", 0)
         self._versions[seq_id] = 0
@@ -422,6 +469,8 @@ class PagedKVCache:
         table = self._tables.pop(seq_id)
         self._chain.pop(seq_id, None)
         self._versions.pop(seq_id, None)
+        if seq_id in self._slots:
+            self._free_slots.append(self._slots.pop(seq_id))
         for b in reversed(table):  # LIFO: newest block reused first
             self._deref(b, quarantine=quarantine)
         self.stats.freed_total += len(table)
@@ -472,6 +521,8 @@ class PagedKVCache:
         hit that later fails RTKV verification in ``assign_prefix`` just
         shortens the assigned prefix, which the over-sized reservation
         already covers."""
+        if not self.cfg.prefix_reuse:
+            return 0
         digest = b""
         bs = self.cfg.block_size
         hits = 0
@@ -542,6 +593,8 @@ class PagedKVCache:
         Must run right after ``allocate`` (empty table)."""
         table = self._tables[seq_id]
         assert not table, "assign_prefix requires an empty table"
+        if not self.cfg.prefix_reuse:
+            return 0
         digest = b""
         bs = self.cfg.block_size
         limit = len(tokens) // bs
@@ -594,6 +647,8 @@ class PagedKVCache:
         each prefill chunk). Blocks whose chain hash is already claimed
         (a concurrent identical prompt) stay private. -> newly registered
         block count."""
+        if not self.cfg.prefix_reuse:
+            return 0
         digest, hashed = self._chain[seq_id]
         table = self._tables[seq_id]
         bs = self.cfg.block_size
@@ -902,6 +957,8 @@ class PagedKVCache:
             "cached_blocks": self.cached_blocks,
             "reserved_blocks": self._reserved,
             "live_sequences": len(self._tables),
+            "state_slots": self.used_slots,
+            "state_slots_high_water": s.state_slots_high_water,
             "utilization": round(self.utilization, 4),
             "high_water_blocks": s.high_water_blocks,
             "allocated_total": s.allocated_total,

@@ -55,12 +55,13 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# GPT-2 125M's widths in both pool dtypes, and the GQA width ROADMAP R1-R2
-# will bring
+# GPT-2 125M's widths in both pool dtypes, the GQA width of the llama cells,
+# and GQA at heads of 64 (the lfm2_moe cell's attention layers)
 WIDTHS = {
     "gpt2-f32": (12, 12, 64, "float32"),
     "gpt2-bf16": (12, 12, 64, "bfloat16"),
     "gqa-bf16": (32, 8, 128, "bfloat16"),
+    "gqa64-bf16": (32, 8, 64, "bfloat16"),
 }
 
 
@@ -283,3 +284,129 @@ def test_step_programs_take_no_cross_program_prefetch(topo, monkeypatch, case):
         # few KB); no matmul weight is
         assert "%params__" in text and not re.search(
             r"convert\(%params__(blocks____)?(wte|wpe|\w+_w)__", text)
+
+
+def _lfm2_cell_shapes(one_chip):
+    """The lfm2_moe cell's config and its arguments' shapes, as the
+    benchmark builds them (bf16 matrix leaves from the reference's init)."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.models.lfm2_moe import lfm2_moe_init_state
+
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/lfm2-24b-a2b-8l.json"))
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    init = common.load_named("reference", "lfm2_moe").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: lfm2_moe_init_state(cfg, 65)))
+    pool = _struct((cfg.n_kv_layer, 4097, 16, cfg.n_kv_head, cfg.head_dim),
+                   cfg.dtype, one_chip)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    return cfg, params, pool, state, i32
+
+
+def test_lfm2_moe_decode_step_compiles_at_published_widths(
+        one_chip, monkeypatch):
+    """The lfm2_moe cell's 64-row decode step, as the executor compiles it:
+    it fits the chip beside nothing else (9.4 GB), the grouped expert
+    product is XLA's ``ragged-dot`` kernel fed the experts' matrices AS
+    STORED (no operation produces an expert-sized array: no cast, no
+    relayout of 1.2 GB a layer), the paged kernel is there at GQA heads of
+    64, and the expert layer's and the convolution's weights reach their
+    operations under the names the benchmark's readers look for."""
+    import jax
+
+    from ray_tpu.models.lfm2_moe import lfm2_moe_decode_step
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    cfg, params, pool, state, i32 = _lfm2_cell_shapes(one_chip)
+    B = 64
+    compiled = jax.jit(
+        functools.partial(lfm2_moe_decode_step, cfg=cfg)
+    ).lower(
+        params, pool, pool, state, i32((B,)), i32((B,)), i32((B, 160)),
+        i32((B,)),
+    ).compile(compiler_options=decode._compiler_options("tpu"))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert 8.0e9 < mem.argument_size_in_bytes < 8.6e9
+    assert total < 11e9, total
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    # two grouped products in each of six expert layers, on the stored leaves
+    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
+    assert len(calls) == 12
+    assert all(re.search(r"%params__layers___\d___moe_gmm_w_(in|out)__", c)
+               for c in calls)
+    produced = [ln for ln in entry.splitlines()
+                if re.search(r"= \w+\[64,(2048|1536),(3072|2048)\]", ln)
+                and " parameter(" not in ln]
+    assert not produced, produced[:2]
+    assert entry.count("%paged_attention") >= 2
+    for needle in ("moe_route_w", "moe_route_bias", "short_conv_w",
+                   "short_conv_in", "short_conv_out"):
+        assert re.search(rf"\(.*%params__layers___\d___{needle}__", entry), \
+            needle
+    assert "cross_program_prefetch_index" not in text
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_other_families_programs_are_what_their_functions_compile_to(
+        one_chip, family):
+    """ISSUE 26's guard: a third family with a state argument came to
+    ``decode.py`` and the executor, and the step programs of the two that
+    have none keep their compiled text. What ``DecodeFns``' own wrapper
+    compiles for the chip, called as the executor calls it, is to the
+    letter what the family's function compiles to alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.llm import decode
+
+    # the XLA attention backend: a Pallas call carries its kernel's own
+    # source locations inside its serialized body, which name the caller
+    fam = decode.get_family(family)
+    cfg = dataclasses.replace(fam.default_config(), attention_backend="xla")
+    init, step = fam.init, fam.decode_step
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    n_kv = getattr(cfg, "n_kv_head", cfg.n_head)
+    pool = _struct((cfg.n_layer, 33, 16, n_kv, cfg.head_dim), cfg.dtype,
+                   one_chip)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    args = (params, pool, pool, i32((4,)), i32((4,)), i32((4, 8)))
+    options = decode._compiler_options("tpu")
+    fns = decode.DecodeFns(family, cfg, platform="tpu")
+    through = fns._decode.lower(*args, sample=None).compile().as_text()
+    alone = jax.jit(functools.partial(step, cfg=cfg)).lower(*args).compile(
+        compiler_options=options).as_text()
+
+    def body(text):
+        """The computations, less what names the CALLER: the module's
+        name line, the tables of source files and stack frames, and each
+        instruction's ``metadata`` (the call site's line numbers)."""
+        text = text[text.index("\n", text.index("HloModule")):]
+        if "\nFileNames" in text:
+            text = text[:text.index("\nFileNames")] + text[
+                text.index("\n\n", text.index("\nStackFrames")):]
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+    assert body(through) == body(alone)
+    assert len(body(alone)) > 10000 and "fusion" in body(alone)
+    # and no entry parameter is a state array or a slot list
+    assert re.search(r"%params__[\w.]+ = \S+ parameter\(", through)
+    assert not re.search(r"%(state|slots)[\w.]* = \S+ parameter\(", through)

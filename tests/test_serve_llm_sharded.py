@@ -102,6 +102,8 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               "weight_dtype": "float32",
                               "weight_bytes": 4 * eng.executor.num_params,
                               "attention_backend": "xla",
+                              "kv_layers": 2, "state": None,
+                              "prefix_reuse": True,
                               "speculative": None}
     assert eng.debug_dump()["executor"]["mesh"] == {"tp": 2, "fsdp": 2}
 
@@ -122,6 +124,8 @@ def test_single_device_default_unchanged(jax_cpu):
                                        "weight_bytes":
                                            4 * eng.executor.num_params,
                                        "attention_backend": "xla",
+                                       "kv_layers": 2, "state": None,
+                                       "prefix_reuse": True,
                                        "speculative": None}
     assert len(eng.generate([5, 6, 7], max_new_tokens=4)) == 4
 
