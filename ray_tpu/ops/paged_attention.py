@@ -4,9 +4,10 @@ The TPU counterpart of ``ops/kv_cache.py``'s ``paged_attention`` and
 ``paged_prefill_attention``. The XLA formulation gathers every sequence's
 ENTIRE padded context (``gather_kv`` → ``[B, NB*block_size, H_kv, hd]``
 in HBM) before a masked softmax. This kernel is the vLLM PagedAttention
-shape instead: one ``pallas_call`` whose grid walks each sequence's block
+shape instead: one ``pallas_call`` that walks each sequence's block
 table and DMAs K/V **directly from the paged pool**
-(``[num_blocks, block_size, n_kv_head, hd]``) page by page into VMEM.
+(``[num_blocks, block_size, n_kv_head, hd]``), several pages at a time,
+into VMEM.
 Nothing is ever materialized at the padded context length, no head is ever
 repeated.
 
@@ -19,35 +20,65 @@ Design (same playbook as ``ops/attention.py``'s flash kernels):
 
 - TILING THE CHIP'S COMPILER ACCEPTS: Mosaic requires the last two
   dimensions of every block to be multiples of (8, 128) or to equal the
-  array's. The pool keeps its ``[num_blocks, block_size, H_kv, hd]``
-  layout, so a grid step takes ALL KV heads of one page — the
-  ``(1, block_size, H_kv, hd)`` block is full-extent in its last two
-  dimensions — and the head loop runs inside the kernel (static, unrolled;
-  head ``h``'s ``[block_size, hd]`` tile is a strided read of the VMEM
-  block). The quantized pools' ``[num_blocks, block_size, H_kv]`` scale
-  planes ride the same walk as ``(1, block_size, H_kv)`` blocks. The q/out
-  tiles are ``(1, H_kv, q_block*G, hd)`` with ``q_block*G`` a multiple of 8
-  (or the whole chunk), and the per-row positions arrive as a
-  ``[B, S*G, 1]`` column so the causal mask is a lane broadcast.
-- BLOCK-TABLE WALK VIA SCALAR PREFETCH: grid ``(B, q_blocks, kv_blocks)``.
-  The block table and the per-(b, q-block) causal frontier / window floor
-  (``qmax``/``qmin``, reduced from the per-row positions) ride in as
-  ``PrefetchScalarGridSpec`` scalar operands, so the K/V index maps read
-  ``tables[b, i]`` and point each grid step's DMA at the right physical
-  page. Pages the q-block cannot attend re-issue entry 0's index, which
-  Pallas dedupes into NO DMA at all, and ``@pl.when`` skips their compute
-  — padding costs neither bandwidth nor compute, and a chunk of C queries
-  against a T-token context costs O(C·T_attended) tiles.
+  array's, and slices a ref that stays in HBM only at whole such tiles.
+  The pool keeps its ``[num_blocks, block_size, H_kv, hd]`` layout and a
+  page is fetched whole, ALL its KV heads at once; the head loop runs
+  inside the kernel (static, unrolled), and head ``h``'s ``[tokens, hd]``
+  tile is a strided read of the VMEM block. Where ``[H_kv, hd]`` is made
+  of whole tiles (8 heads of 128) the kernel copies pages itself, several
+  a step (next paragraph). Where it is not (heads of 64, 12 heads, a
+  ``tp`` shard's 2) it cannot, and a lane-dense view of the pool that it
+  could copy costs two more relayouts of the whole pool a layer than XLA
+  already makes for such a pool (PERF.md, PR 27; ROADMAP S5a): those
+  shapes keep the ONE-PAGE WALK (``_walk_pages``: grid ``(B, q_blocks,
+  NB)``, a ``(1, block_size, H_kv, hd)`` BlockSpec a grid step, skipped
+  pages re-issuing entry 0's index so that Pallas elides their DMA). A
+  test of the shape, not of a model. The quantized pools'
+  ``[num_blocks, block_size, H_kv]`` scale planes have no whole tile a
+  page either: under the compute-block kernel each row's scales are
+  gathered through its table by XLA (``B x NB x block_size x H_kv``
+  floats) and ride in as a lane-dense ``[H_kv, tokens]`` tile a block,
+  applied to the SCORES and the probabilities: ``q . (k * s_t) = (q . k)
+  * s_t``. The q/out tiles are ``(1, H_kv, q_block*G, hd)`` with
+  ``q_block*G`` a multiple of 8 (or the whole chunk), and the per-row
+  positions arrive as a ``[B, S*G, 1]`` column so the causal mask is a
+  lane broadcast.
+- BLOCK-TABLE WALK BY THE KERNEL'S OWN COPIES: grid ``(B, q_blocks)``; the
+  K and V pools stay in HBM (``memory_space=pl.ANY``). The block table
+  and the per-(b, q-block) causal frontier / window floor (``qmax`` /
+  ``qmin``, reduced from the per-row positions) ride in as
+  ``PrefetchScalarGridSpec`` scalar operands. A grid step loops over the
+  row's COMPUTE BLOCKS of P consecutive table entries (``P * block_size``
+  tokens, 128 where the table is wide enough; ``_compute_block`` derives
+  P from the shapes and the VMEM they need, down to 1): it starts one
+  async copy per attended page of the block, ``pool[tables[b, e]]`` into a
+  two-slot VMEM scratch ``[2, P, *page]``, all in flight together, and
+  starts block i+1's copies before it computes block i. Entries past the
+  frontier, or — windowed — below the floor, start no copy, and the loop
+  runs only from the floor's block to the frontier's: a call costs the
+  pages it attends, not the table's width, and a chunk of C queries
+  against a T-token context costs O(C·T_attended). Per head the block is
+  ONE ``[P * block_size, hd]`` tile and one score matmul; the
+  running-softmax update is made once a block for all heads together, on
+  ``[H_kv, R, P * block_size]`` scores.
 - GQA COMPACTION: queries reshape ``[B, S, H_q, hd] → [B, H_kv, S*G, hd]``
   (``G = H_q // H_kv``); each head step computes the whole query group
   against the SHARED KV tile with one dot, so GQA is a free extra row
   dimension instead of a ``rep``× KV copy.
 - FLASH RUNNING SOFTMAX: per-(kv-head, row) running max / sum /
-  accumulator live in VMEM scratch across the innermost page axis; the
+  accumulator live in VMEM scratch across the loop over blocks (the max
+  and sum as a column a head, ``[H_kv, R, 1]``); the
   softmax is base-2 with ``scale * log2(e)`` folded into q once (exp2
   instead of exp, no rescale pass), bf16 inputs run the exp2 at half
   precision. A static ``window=`` adds the sliding-window variant that
   also skips pages below the window floor.
+
+THE KERNEL'S TEXT IS PAID FOR AT EVERY START: each process traces and
+lowers it anew for each of its ~50 step programs, cache hit or not, and
+the engine's warm start is an end-to-end metric (``setup_s``). So the page
+copies are a loop and not P unrolled branches, the walk's scalars use
+``lax.div / max / min`` (``//`` and ``jnp.clip`` lower through ``sign``),
+and only the two matmuls are unrolled per head (PERF.md, PR 27).
 
 SHARDING: a compiled kernel is an opaque custom call that GSPMD cannot
 partition. ``ShardedExecutor`` (serve/llm/executor.py) splits the pool's
@@ -70,6 +101,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import LOG2E, NEG_INF, pallas_interpret
@@ -89,7 +121,73 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-def _paged_attention_kernel(
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes an array takes in VMEM: its last dimension padded to 128
+    lanes, the one before it to the dtype's sublane tile (8 rows of 32
+    bits; 16-bit types pack 16 rows a tile, 8-bit types 32)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // itemsize)
+    *lead, rows, lanes = shape
+    return (
+        math.prod(lead) * -(-rows // sub) * sub * -(-lanes // 128) * 128
+        * itemsize
+    )
+
+
+# Tokens of context a compute block aims at: a whole lane tile of scores
+# for each MXU pass, and two where the q tile has a whole MXU pass of rows
+# or more. Measured on a v5e (PERF.md, PR 27): a tile of few rows (decode,
+# verify) is bound by the block's K/V tiles, and a longer block only adds
+# masked columns past the frontier; a tile of many rows pays for rescaling
+# its [R, hd] accumulator once a block, and wants fewer blocks.
+_BLOCK_TOKENS = 128
+_MANY_ROWS = 128
+# What a call may count on in VMEM (a v5e core has 128 MiB; the limit
+# handed to the compiler is twice the count, for what it spills); its
+# scoped default is 16 MiB, raised only as far as a call's shapes need.
+_VMEM_CAP = 48 * 1024 * 1024
+_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
+    """``(P, vmem_bytes)``: the pages of one compute block and what the
+    call then keeps in VMEM, from the shapes alone (``page`` is a page's
+    shape in the pool, tokens first). P is the largest power of two whose
+    ``P * bs`` tokens stay within what a block aims at, that the table is
+    wide enough for, and whose two-slot K/V scratch fits beside the q and
+    out tiles, the positions, the accumulators and a block's scores; a
+    page a block (P = 1) is the floor."""
+    bs = page[0]
+    tokens = _BLOCK_TOKENS * (2 if R >= _MANY_ROWS else 1)
+    fixed = (
+        # q and out tiles, double-buffered by the pipeline; positions
+        4 * _vmem_bytes((Hkv, R, hd), q_dtype)
+        + 2 * _vmem_bytes((R, 1), jnp.int32)
+        # running max and sum, accumulator
+        + 2 * _vmem_bytes((Hkv, R, 1), jnp.float32)
+        + _vmem_bytes((Hkv, R, hd), jnp.float32)
+    )
+    if quantized:
+        # a row's K and V scales, double-buffered: one lane a token
+        fixed += 4 * _vmem_bytes((Hkv, NB * bs), jnp.float32)
+
+    def need(p):
+        # two slots of K and V pages; a block's scores and probabilities
+        # for every head, its K and V tiles as values
+        return fixed + 4 * p * _vmem_bytes(page, kv_dtype) + 2 * (
+            _vmem_bytes((Hkv, R, p * bs), jnp.float32)
+            + Hkv * _vmem_bytes((p * bs, hd), jnp.float32)
+        )
+
+    P = 1
+    while (
+        2 * P * bs <= tokens and 2 * P <= NB and need(2 * P) <= _VMEM_CAP
+    ):
+        P *= 2
+    return P, need(P)
+
+
+def _page_walk_kernel(
     tables_ref,   # scalar prefetch: [B, NB] int32 block tables
     qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
     qmin_ref,     # scalar prefetch: [B, nqb] int32 floor per q-block
@@ -107,6 +205,11 @@ def _paged_attention_kernel(
     window: int | None,
     quantized: bool,
 ):
+    """The walk for a pool the compute-block kernel cannot copy from: grid
+    ``(B, q_blocks, NB)``, ONE page a grid step fetched by its BlockSpec
+    (the index map reads ``tables[b, i]``; a page the q-block cannot attend
+    re-issues entry 0's index, which Pallas dedupes into no DMA, and
+    ``@pl.when`` skips its compute). See ``_walk_pages``."""
     from jax.experimental import pallas as pl
 
     if quantized:
@@ -195,6 +298,254 @@ def _paged_attention_kernel(
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
+def _walk_pages(qf, pos_rows, tables, qmax, qmin, k_layer, v_layer, *,
+                R, window, interpret):
+    """``paged_attention`` for a pool whose ``[Hkv, hd]`` is not made of
+    whole (8, 128) tiles (heads of 64, 12 heads, a ``tp`` shard's 2). Mosaic
+    slices a ref that stays in HBM only at such tiles, so the kernel cannot
+    copy those pages itself; and the lane-dense view it could copy
+    (``[num_blocks, bs, Hkv * hd]``) costs two more relayouts of the whole
+    pool a layer than XLA already makes for such a pool (measured: PERF.md,
+    PR 27; ROADMAP S5a). Until the pool is stored lane-dense these shapes
+    keep the one-page walk: a BlockSpec fetches ``(1, bs, Hkv, hd)`` — full
+    extent in its last two dimensions — a grid step."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    quantized = isinstance(k_layer, QuantizedKV)
+    if quantized:
+        k_data, k_scale = k_layer.data, k_layer.scale
+        v_data, v_scale = v_layer.data, v_layer.scale
+    else:
+        k_data, v_data = k_layer, v_layer
+    B, Hkv, rows_all, hd = qf.shape
+    bs = k_data.shape[1]
+    NB = tables.shape[1]
+    nqb = qmax.shape[1]
+    q = qf
+
+    def q_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+        return (b, 0, j, 0)
+
+    def pos_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+        return (b, j, 0)
+
+    def _page(b, j, i, tables_ref, qmax_ref, qmin_ref):
+        # Walk the sequence's block table. Pages the q-block cannot
+        # attend (wholly past its frontier, or — windowed — wholly below
+        # its floor) re-issue entry 0's index: consecutive identical
+        # block tuples make Pallas skip the DMA, so skipped pages cost
+        # no bandwidth (their compute is skipped by the same test).
+        needed = i * bs <= qmax_ref[b, j]
+        if window is not None:
+            needed = jnp.logical_and(
+                needed, (i + 1) * bs > qmin_ref[b, j] - (window - 1)
+            )
+        return jnp.where(needed, tables_ref[b, i], tables_ref[b, 0])
+
+    def kv_map(*args):
+        return (_page(*args), 0, 0, 0)
+
+    def kv_scale_map(*args):
+        # a scale page is fetched iff its K/V page is
+        return (_page(*args), 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, Hkv, R, hd), q_map),
+        pl.BlockSpec((1, R, 1), pos_map),
+        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
+        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
+    ]
+    operands = [tables, qmax, qmin, qf, pos_rows, k_data, v_data]
+    if quantized:
+        in_specs += [
+            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
+            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
+        ]
+        operands += [k_scale, v_scale]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nqb, NB),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, R, 128), jnp.float32),
+            pltpu.VMEM((Hkv, R, 128), jnp.float32),
+            pltpu.VMEM((Hkv, R, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _page_walk_kernel, block_size=bs, window=window,
+            quantized=quantized,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_all, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        name="paged_attention",
+        interpret=interpret,
+    )(*operands)
+    return out
+
+
+def _paged_attention_kernel(
+    tables_ref,   # scalar prefetch: [B, NB] int32 block tables
+    qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
+    qmin_ref,     # scalar prefetch: [B, nqb] int32 floor per q-block
+    q_ref,        # [1, Hkv, R, hd] — this (b, q-block)'s rows for every kv
+                  # head, pre-scaled; row r = query (r // G) of the block,
+                  # group member (r % G); R = q_block * G
+    pos_ref,      # [1, R, 1] int32 — true position of each row's query
+    *rest,        # quantized: (ks_ref, vs_ref, k_hbm, v_hbm, o_ref, ...) —
+                  # ks/vs [1, n_blocks, Hkv, T] f32, this row's per-(head,
+                  # token) scales, gathered through its table; else (k_hbm,
+                  # v_hbm, o_ref, ...). Then the scratch: (k_buf, v_buf,
+                  # sems, m, l, acc).
+                  # k_hbm / v_hbm: the whole pool, in HBM, a page
+                  # [bs, Hkv, hd]; k_buf / v_buf: the two-slot VMEM
+                  # scratch of a block, [2, P * bs, Hkv, hd]
+    block_size: int,
+    pages: int,
+    window: int | None,
+    quantized: bool,
+):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if quantized:
+        ks_ref, vs_ref, *rest = rest
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    n_head, rows, hd = q_ref.shape[1:]
+    n_entries = tables_ref.shape[1]
+    bs, T = block_size, pages * block_size
+
+    # The table entries this q-block attends: up to the page of its causal
+    # frontier, and — windowed — from the page of the window floor of its
+    # EARLIEST query. Entries outside start no copy; a compute block with
+    # none is never visited, so the walk costs the pages attended and not
+    # the table's width.
+    # (lax.div / lax.max on these non-negative scalars, not ``//`` and
+    # ``jnp.clip``: their sign handling is a tenth of what lowering this
+    # kernel costs, and every process lowers it for each step program.)
+    div, lmax, lmin = lax.div, lax.max, lax.min
+    last = lmin(div(qmax_ref[b, j], bs), n_entries - 1)
+    first = jnp.int32(0)
+    if window is not None:
+        first = div(lmax(qmin_ref[b, j] - (window - 1), 0), bs)
+    lo, hi = div(first, pages), div(last, pages) + 1
+
+    def copies(i, op):
+        # block i's attended pages, each page one copy of K and one of V
+        # into slot i % 2. A loop over the pages and not P unrolled
+        # branches: every process traces and lowers the kernel's text anew
+        # for each of its step programs, so it stays a one-page kernel's.
+        slot = lax.rem(i, 2)
+        base = i * pages
+
+        def page(p, carry):
+            src = tables_ref[b, base + p]
+            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                copy = pltpu.make_async_copy(
+                    pool.at[src], buf.at[slot, dst], sems.at[slot]
+                )
+                copy.start() if op == "start" else copy.wait()
+            return carry
+
+        lax.fori_loop(
+            lmax(first - base, 0), lmin(last + 1 - base, pages), page, 0
+        )
+
+    def head(buf, slot, h):
+        # one head's [T, hd] tile out of the block's pages
+        x = buf[slot, :, h, :]
+        return x.astype(jnp.float32) if quantized else x
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # A page of a visited block that no copy wrote holds an older block's
+    # page, or at a call's start nothing yet; the mask zeroes its
+    # probabilities, and 0 x V must stay 0.
+    v_buf[...] = jnp.zeros_like(v_buf)
+
+    def step(i, carry):
+        # block i's pages fly while block i - 1 computes
+        pl.when(i < hi)(functools.partial(copies, i, "start"))
+        pl.when(i > lo)(functools.partial(block, i - 1))
+        return carry
+
+    def block(i):
+        slot = lax.rem(i, 2)
+        copies(i, "wait")
+        # per-ROW causal mask, shared by every head of the block
+        pos_rows = pos_ref[0]                              # [R, 1]
+        t = i * T + lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        mask = t <= pos_rows
+        if window is not None:
+            mask = jnp.logical_and(mask, t > pos_rows - window)
+        # Only the two matmuls run head by head (the loop is unrolled: a
+        # head's tile is a static slice); the running softmax is updated
+        # for all heads at once on [Hkv, R, T]. What a head adds to the
+        # kernel's text is what every process pays again, for each of its
+        # step programs, to trace and lower it.
+        k_v = [
+            (head(k_buf, slot, h), head(v_buf, slot, h))
+            for h in range(n_head)
+        ]
+        as_f32 = lax.convert_element_type
+        s = jnp.stack([
+            lax.dot_general(
+                as_f32(q_ref[0, h], jnp.float32) if quantized
+                else q_ref[0, h],      # [R, hd], pre-scaled
+                k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h, (k, _) in enumerate(k_v)
+        ])                             # [Hkv, R, T]
+        if quantized:
+            # dequantize the SCORES: q . (k * scale_t) is
+            # (q . k) * scale_t, a [1, T] row against R x T products
+            # where scaling K would take T x hd
+            s = s * ks_ref[0, i][:, None, :]
+        s = jnp.where(mask[None], s, NEG_INF)
+        m_prev = m_scr[...]                                # [Hkv, R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        # bf16 inputs: exp2 at half precision, matching the flash
+        # forward; f32 inputs keep a fully-f32 softmax
+        if q_ref.dtype == jnp.bfloat16 and not quantized:
+            p = jnp.exp2((s - m_new).astype(jnp.bfloat16))
+        else:
+            p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(
+            p, axis=2, keepdims=True, dtype=jnp.float32
+        )
+        if quantized:
+            # and V's scales ride the probabilities the same way
+            p = p * vs_ref[0, i][:, None, :]
+        p = p.astype(k_v[0][1].dtype)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.stack([
+            lax.dot_general(
+                lax.index_in_dim(p, h, 0, keepdims=False), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h, (_, v) in enumerate(k_v)
+        ])
+        m_scr[...] = m_new
+
+    lax.fori_loop(lo, hi + 1, step, 0)
+    l = l_scr[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
 def paged_prefill_attention_pallas(
     q: jax.Array,
     k_layer: jax.Array,
@@ -219,10 +570,11 @@ def paged_prefill_attention_pallas(
     int32 padded with the garbage block 0. Returns ``[B, S, H_q, hd]``
     in q.dtype.
 
-    The grid is ``(B, q_blocks, kv_blocks)`` with the kv axis innermost:
-    per (b, q-block) the flash running softmax walks the sequence's block
-    table, DMAing one physical ``[block_size, H_kv, hd]`` page per step
-    and looping its heads in-kernel (see the module docstring for why).
+    The grid is ``(B, q_blocks)``: per (b, q-block) the flash running
+    softmax loops over the compute blocks of the sequence's block table
+    that the q-block attends, copying each block's pages out of the pool
+    itself, the next block's while it computes this one, and looping the
+    heads in-kernel (see the module docstring for why).
     ``window=W`` (static) additionally masks ``t <= pos - W`` and skips
     pages wholly below the window floor — sliding-window attention at
     O(S·W) cost.
@@ -280,72 +632,85 @@ def paged_prefill_attention_pallas(
         pos[:, :, None], (B, Sp, G)
     ).reshape(B, Sp * G, 1)
     posb = pos.reshape(B, nqb, qb)
-    # causal frontier / window floor per (b, q-block) — the scalars the
-    # index map and @pl.when guards read. Padding rows sit at position 0,
+    # causal frontier / window floor per (b, q-block) — the scalars that
+    # bound the kernel's walk. Padding rows sit at position 0,
     # so they never extend the frontier (and only make the floor
     # conservative, never wrong).
     qmax = jnp.max(posb, axis=2).astype(jnp.int32)
     qmin = jnp.min(posb, axis=2).astype(jnp.int32)
 
-    def q_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+    if hd % 128 or Hkv % 8:
+        out = _walk_pages(
+            qf, pos_rows, tables, qmax, qmin, k_layer, v_layer,
+            R=R, window=window, interpret=interpret,
+        )
+        out = out.reshape(B, Hkv, Sp, G, hd).transpose(0, 2, 1, 3, 4)
+        return out.reshape(B, Sp, Hq, hd)[:, :S]
+    page = (bs, Hkv, hd)
+    pages, vmem = _compute_block(
+        page, Hkv, hd, R, NB, q.dtype, k_data.dtype, quantized
+    )
+    n_blocks = -(-NB // pages)
+
+    def q_map(b, j, tables_ref, qmax_ref, qmin_ref):
         return (b, 0, j, 0)
 
-    def pos_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+    def pos_map(b, j, tables_ref, qmax_ref, qmin_ref):
         return (b, j, 0)
 
-    def _page(b, j, i, tables_ref, qmax_ref, qmin_ref):
-        # Walk the sequence's block table. Pages the q-block cannot
-        # attend (wholly past its frontier, or — windowed — wholly below
-        # its floor) re-issue entry 0's index: consecutive identical
-        # block tuples make Pallas skip the DMA, so skipped pages cost
-        # no bandwidth (their compute is skipped by the same test).
-        needed = i * bs <= qmax_ref[b, j]
-        if window is not None:
-            needed = jnp.logical_and(
-                needed, (i + 1) * bs > qmin_ref[b, j] - (window - 1)
-            )
-        return jnp.where(needed, tables_ref[b, i], tables_ref[b, 0])
-
-    def kv_map(*args):
-        return (_page(*args), 0, 0, 0)
-
-    def kv_scale_map(*args):
-        # a scale page is fetched iff its K/V page is
-        return (_page(*args), 0, 0)
+    def scale_map(b, j, tables_ref, qmax_ref, qmin_ref):
+        return (b, 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Hkv, R, hd), q_map),
         pl.BlockSpec((1, R, 1), pos_map),
-        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
-        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
     ]
-    operands = [tables, qmax, qmin, qf, pos_rows, k_data, v_data]
+    operands = [tables, qmax, qmin, qf, pos_rows]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
-            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
-        ]
-        operands += [k_scale, v_scale]
+        # The scale planes' pages ([bs, Hkv] f32: no whole tile) cannot be
+        # copied out of HBM by the kernel: each row's scales are gathered
+        # through its table here — B x NB x bs x Hkv floats, against a
+        # pool's num_blocks x bs x Hkv x hd — one lane-dense [Hkv, T] tile
+        # a compute block.
+        padded = jnp.pad(tables, ((0, 0), (0, n_blocks * pages - NB)))
+
+        def row_scales(scale):
+            return scale[padded].reshape(
+                B, n_blocks, pages * bs, Hkv
+            ).transpose(0, 1, 3, 2)
+
+        spec = pl.BlockSpec((1, n_blocks, Hkv, pages * bs), scale_map)
+        in_specs += [spec, spec]
+        operands += [row_scales(k_scale), row_scales(v_scale)]
+    # the pools stay in HBM: the kernel copies the pages it attends itself
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands += [k_data, v_data]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, nqb, NB),
+        grid=(B, nqb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, R, 128), jnp.float32),
-            pltpu.VMEM((Hkv, R, 128), jnp.float32),
+            pltpu.VMEM((2, pages * bs, *page[1:]), k_data.dtype),
+            pltpu.VMEM((2, pages * bs, *page[1:]), v_data.dtype),
+            # one DMA semaphore a slot, shared by the slot's copies
+            pltpu.SemaphoreType.DMA((2,)),
+            # running max and sum: a column a head
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
             pltpu.VMEM((Hkv, R, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_attention_kernel, block_size=bs, window=window,
-            quantized=quantized,
+            _paged_attention_kernel, block_size=bs, pages=pages,
+            window=window, quantized=quantized,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Sp * G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
         ),
         name="paged_attention",
         interpret=interpret,

@@ -65,10 +65,24 @@ WIDTHS = {
 }
 
 
+# (B, table width NB, pool blocks, chunk S): a small engine's shapes, then
+# the benchmark cells' own — a 64-row decode against the 160-entry table of
+# the 2,560-token bucket and a pool of 4,097 blocks (``chat-closed``), the
+# 64-entry table of ``chat-closed-1k``, and the 4 x 2,048 prefill.
+SHAPES = {
+    "decode": {"small": (8, 64, 2048, 1), "cell": (64, 160, 4097, 1),
+               "cell-1k": (64, 64, 4097, 1)},
+    "prefill": {"small": (8, 64, 2048, 512), "cell": (4, 128, 4097, 2048)},
+}
+
+
 @pytest.mark.parametrize("quant", [None, "int8"])
 @pytest.mark.parametrize("width", sorted(WIDTHS))
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_paged_attention_compiles(one_chip, kind, width, quant):
+@pytest.mark.parametrize(
+    "kind,shape",
+    [(kind, shape) for kind in sorted(SHAPES) for shape in SHAPES[kind]],
+)
+def test_paged_attention_compiles(one_chip, kind, shape, width, quant):
     import jax
     import jax.numpy as jnp
 
@@ -79,7 +93,8 @@ def test_paged_attention_compiles(one_chip, kind, width, quant):
 
     hq, hkv, hd, dtype = WIDTHS[width]
     dtype = jnp.dtype(dtype)
-    B, bs, num_blocks, NB, S = 8, 16, 2048, 64, 512
+    B, NB, num_blocks, S = SHAPES[kind][shape]
+    bs = 16
     S_ = functools.partial(_struct, sharding=one_chip)
     if quant is None:
         pool = S_((num_blocks, bs, hkv, hd), dtype)
@@ -100,6 +115,27 @@ def test_paged_attention_compiles(one_chip, kind, width, quant):
         args = (S_((B, S, hq, hd), dtype), pool, pool, tables,
                 S_((B, S), jnp.int32))
     compiled = jax.jit(fn).lower(*args).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+@pytest.mark.parametrize("heads", [2, 3])
+def test_paged_attention_compiles_for_a_tp_shard(one_chip, heads):
+    """What one device of a ``tp`` mesh runs: its local KV heads only (8
+    over tp=4, 12 over tp=4), fewer than a sublane tile, so the page keeps
+    the one-page walk."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import paged_attention_pallas
+
+    hd = 128 if heads == 2 else 64
+    S_ = functools.partial(_struct, sharding=one_chip)
+    pool = S_((2048, 16, heads, hd), jnp.bfloat16)
+    args = (S_((8, 4 * heads, hd), jnp.bfloat16), pool, pool,
+            S_((8, 64), jnp.int32), S_((8,), jnp.int32))
+    compiled = jax.jit(
+        functools.partial(paged_attention_pallas, interpret=False)
+    ).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
 

@@ -58,36 +58,13 @@ def _engine(family, mc, **kw):
 
 
 def _pool(key, B, lengths, Hkv, hd, bs, NB, shuffle):
-    """A paged pool with ragged sequences: noise-filled blocks (block 0 is
-    the garbage sink), tables padded with 0 past each length, physical
-    ids optionally shuffled. Returns (k_layer, v_layer, tables,
-    positions) with positions = lengths - 1 (the decode query position)."""
-    import random as _random
-
-    import jax
+    """``_prefill_pool``'s paged pool for decode: returns (k_layer,
+    v_layer, tables, positions) with positions = lengths - 1 (the decode
+    query position)."""
     import jax.numpy as jnp
-    from ray_tpu.ops.kv_cache import write_kv
 
-    num_blocks = 1 + B * NB
-    ids = list(range(1, num_blocks))
-    if shuffle:
-        _random.Random(7).shuffle(ids)
-    rows, nxt = [], 0
-    for L in lengths:
-        need = -(-L // bs)
-        rows.append(ids[nxt:nxt + need] + [0] * (NB - need))
-        nxt += need
-    tables = jnp.asarray(rows, jnp.int32)
-    T = NB * bs
-    kc = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, hd))
-    vc = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, hd))
-    shape = (num_blocks, bs, Hkv, hd)
-    k_layer = jax.random.normal(jax.random.fold_in(key, 3), shape)
-    v_layer = jax.random.normal(jax.random.fold_in(key, 4), shape)
-    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    valid = pos < jnp.asarray(lengths, jnp.int32)[:, None]
-    k_layer, v_layer = write_kv(
-        k_layer, v_layer, kc, vc, pos, tables, valid=valid
+    k_layer, v_layer, tables, _, _ = _prefill_pool(
+        key, lengths, Hkv, hd, bs, NB, shuffle
     )
     return k_layer, v_layer, tables, jnp.asarray(lengths, jnp.int32) - 1
 
@@ -166,15 +143,21 @@ def test_backend_resolution_and_validation(jax_cpu):
 # ------------------------------------------- prefill kernel vs references
 
 
-def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle):
-    """Like ``_pool`` but also returns the dense per-row contexts (kc, vc)
-    written into the paged layers, so tests can build dense references
-    without re-gathering."""
+def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle, quant=None):
+    """A paged pool with ragged sequences: noise-filled blocks (block 0 is
+    the garbage sink), tables padded with 0 past each length, physical
+    ids optionally shuffled. Returns (k_layer, v_layer, tables, kc, vc):
+    the dense per-row contexts (kc, vc) are what was written into the
+    paged layers, so tests can build dense references without
+    re-gathering. ``quant`` makes the pool a ``QuantizedKV`` of
+    that kind (noise data under noise scales; ``write_kv`` quantizes what
+    it writes). A length of 0 is a padding row: an all-zero table."""
     import random as _random
 
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.kv_cache import write_kv
+    from ray_tpu.ops.quantization import QuantizedKV, quant_dtype
 
     B = len(lengths)
     num_blocks = 1 + B * NB
@@ -193,6 +176,14 @@ def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle):
     shape = (num_blocks, bs, Hkv, hd)
     k_layer = jax.random.normal(jax.random.fold_in(key, 3), shape)
     v_layer = jax.random.normal(jax.random.fold_in(key, 4), shape)
+    if quant is not None:
+        k_layer, v_layer = (
+            QuantizedKV(
+                (40.0 * x).astype(quant_dtype(quant)),
+                0.01 + jnp.abs(x[..., 0]),
+            )
+            for x in (k_layer, v_layer)
+        )
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     valid = pos < jnp.asarray(lengths, jnp.int32)[:, None]
     k_layer, v_layer = write_kv(
@@ -367,6 +358,131 @@ def test_prefill_kernel_qblock_padding_and_jit(jax_cpu):
     jitted = jax.jit(lambda *a: prefill_attention(*a, backend="pallas"))
     out_j = jitted(q, k_layer, v_layer, tables, positions)
     assert float(jnp.max(jnp.abs(out_j - ref))) < 2e-5
+
+
+# What a compute block of P pages adds to the walk (bs 8 and a table of 16
+# or more entries give P = 16: 128 tokens a block; 8 heads of 128 is the
+# page the kernel copies itself, 2 heads of 32 the page that keeps the
+# one-page walk). Each case:
+# (lengths, chunk S, table width NB, pool kwargs, kernel kwargs); the chunk
+# is the LAST S cached positions of each row, a length of 0 a padding row.
+_BLOCK_EDGES = {
+    # the last block holds 4 of 16 entries
+    "table-not-multiple-of-P": ([140, 155, 9], 1, 20, {}, {}),
+    # a context ending one short of, on, and one past a block boundary
+    "context-ends-on-boundary": ([127, 128, 129, 256], 1, 32, {}, {}),
+    "context-ends-inside-block": ([41, 200, 3], 1, 32, {}, {}),
+    "shuffled-pages": ([140, 250, 33], 1, 32, {"shuffle": True}, {}),
+    "padding-rows-zero-table": ([0, 150, 0, 12], 1, 32, {}, {}),
+    # the floor of the earliest query lies in the middle of block 1
+    "window-floor-inside-block": (
+        [200, 255], 6, 32, {"shuffle": True}, {"window": 20}
+    ),
+    "window-of-one-page": ([200, 141], 1, 32, {}, {"window": 5}),
+    # a chunk that starts at token 138 of 128-token blocks, in two q-blocks
+    "chunk-start-inside-block": (
+        [150, 250], 12, 32, {"shuffle": True}, {"q_block": 8}
+    ),
+    "int8-scale-planes": (
+        [140, 9, 250], 1, 32, {"shuffle": True, "quant": "int8"}, {}
+    ),
+    "int8-chunk-not-multiple-of-P": (
+        [150, 60], 5, 20, {"quant": "int8"}, {}
+    ),
+    "fp8-scale-planes": ([33, 180], 1, 32, {"quant": "fp8"}, {}),
+    # a table narrower than a block: P = 1, and 2 with a third entry
+    "P-forced-to-1": ([5, 8], 1, 1, {}, {}),
+    "P-forced-to-2": ([5, 16, 19], 3, 3, {}, {}),
+    # a page Mosaic cannot slice in HBM: the one-page walk
+    "unaligned-heads-one-page-walk": (
+        [140, 9], 1, 20, {"shuffle": True, "Hkv": 2, "hd": 32}, {}
+    ),
+    "unaligned-heads-int8-chunk": (
+        [40, 150], 4, 20, {"quant": "int8", "Hkv": 2, "hd": 32}, {}
+    ),
+}
+
+
+@pytest.mark.parametrize("edge", sorted(_BLOCK_EDGES))
+def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
+    """The edges a compute block of several pages creates, each against
+    the XLA formulation on the same pool, at the same tolerance as the
+    one-page cases above."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.kv_cache import paged_prefill_attention
+    from ray_tpu.ops.paged_attention import (
+        _compute_block, paged_prefill_attention_pallas,
+    )
+
+    lengths, S, NB, pool_kw, kernel_kw = _BLOCK_EDGES[edge]
+    pool_kw = dict(pool_kw)
+    Hkv, hd = pool_kw.pop("Hkv", 8), pool_kw.pop("hd", 128)
+    bs, G = 8, 2
+    key = jax.random.PRNGKey(sum(map(ord, edge)))
+    k_layer, v_layer, tables, _, _ = _prefill_pool(
+        key, lengths, Hkv, hd, bs, NB, pool_kw.pop("shuffle", False),
+        **pool_kw,
+    )
+    # the block the case was built for is the one the function derives
+    P, _ = _compute_block(
+        (bs, Hkv, hd), Hkv, hd, S * G, NB, jnp.float32, k_layer.dtype,
+        "quant" in pool_kw,
+    )
+    assert P == min(16, 1 << (NB.bit_length() - 1)), (edge, P)
+    starts = jnp.asarray([max(L - S, 0) for L in lengths], jnp.int32)
+    positions = starts[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    positions = jnp.where(
+        jnp.asarray(lengths)[:, None] > 0, positions, 0
+    )
+    q = jax.random.normal(
+        jax.random.fold_in(key, 9), (len(lengths), S, Hkv * G, hd)
+    )
+    window = kernel_kw.get("window")
+    ref = paged_prefill_attention(
+        q, k_layer, v_layer, tables, positions, window=window
+    )
+    out = paged_prefill_attention_pallas(
+        q, k_layer, v_layer, tables, positions, **kernel_kw
+    )
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    live = jnp.asarray(lengths)[:, None, None, None] > 0
+    assert float(jnp.max(jnp.abs(jnp.where(live, out - ref, 0.0)))) < 2e-5
+
+
+# (page, Hkv, hd, R, NB, pool dtype, quantized) -> pages a compute block
+_BLOCK_CHOICES = {
+    # the cells' shapes: 16-token pages, a few rows (decode) or a q tile
+    # of 128 queries x G (prefill)
+    "gqa-decode": (((16, 8, 128), 8, 128, 4, 160, "bfloat16", False), 8),
+    "gqa-prefill": (((16, 8, 128), 8, 128, 512, 128, "bfloat16", False), 16),
+    "gqa-verify": (((16, 8, 128), 8, 128, 16, 160, "bfloat16", False), 8),
+    "f32-prefill": (((16, 8, 128), 8, 128, 128, 64, "float32", False), 16),
+    "int8-decode": (((16, 8, 128), 8, 128, 4, 160, "int8", True), 8),
+    # a table narrower than a block
+    "one-entry-table": (((16, 8, 128), 8, 128, 4, 1, "bfloat16", False), 1),
+    "five-entry-table": (((16, 8, 128), 8, 128, 4, 5, "bfloat16", False), 4),
+    # a page that is a block already
+    "page-of-128": (((128, 8, 128), 8, 128, 4, 64, "bfloat16", False), 1),
+    "page-of-128-prefill": (
+        ((128, 8, 128), 8, 128, 512, 64, "bfloat16", False), 2
+    ),
+    # a q tile whose own buffers leave no room: the floor
+    "vmem-floor": (((16, 8, 128), 8, 128, 8192, 160, "float32", False), 1),
+}
+
+
+@pytest.mark.parametrize("shapes", sorted(_BLOCK_CHOICES))
+def test_compute_block_is_derived_from_shapes(jax_cpu, shapes):
+    import jax.numpy as jnp
+    from ray_tpu.ops.paged_attention import _VMEM_CAP, _compute_block
+
+    (page, Hkv, hd, R, NB, dtype, quantized), want = _BLOCK_CHOICES[shapes]
+    P, vmem = _compute_block(
+        page, Hkv, hd, R, NB, jnp.bfloat16, jnp.dtype(dtype), quantized
+    )
+    assert P == want
+    assert P == 1 or vmem <= _VMEM_CAP
 
 
 # ------------------------------------------------ engine stream parity
