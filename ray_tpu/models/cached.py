@@ -1,0 +1,309 @@
+"""The cached (serving) step of every family, written once.
+
+A served family runs three kinds of step against the paged K/V pool
+(ops/kv_cache.py; serve/llm drives them): ``prefill`` of a right-padded
+prompt chunk, ``decode_step`` of one token a row, ``verify_step`` of a
+speculative window. They are ONE step: tokens ``[B, S]`` at per-row true
+positions, every layer writing the chunk's K/V into its pool slice and
+attending over the paged context, then the head on some of the rows and
+a sampling epilogue. This file owns that step; a family's file
+(models/gpt.py, llama.py, lfm2_moe.py) holds only what is the family's
+own, in a ``CachedFamily``:
+
+- ``embed(params, tokens, step, cfg) -> (x [B, S, D], aux)``: the token
+  (and position) embedding, its table lookups through ``step.take``, and
+  whatever the layers need per position (rotary cos / sin; None for gpt),
+  which reaches them as ``step.aux``;
+- ``layer(x, lp, attend, step, state, cfg) -> (x, state)``: one layer over
+  the chunk. ``attend(q, k, v)`` (q ``[B, S, Hq, hd]``; k, v ``[B, S, Hkv,
+  hd]``, the compact GQA heads) is the cache side of the layer, written
+  below: it returns the attention output ``[B, S, Hq * hd]``. A layer
+  that does not attend does not call it;
+- ``final_norm(params, x, cfg)`` and ``head(params, h, cfg)`` (float32
+  logits over ``[..., D]``);
+- ``stack``: the key of ``params`` that holds the layers. A tree whose
+  leaves lead with the layer axis is a stack of like layers: one
+  ``lax.scan`` with the pool riding it as xs -> ys. A list of per-layer
+  trees is a stack of unlike layers: a Python loop, the pool indexed by
+  the attending layer's ordinal;
+- ``open_state`` / ``close_state``: for a family that keeps per-sequence
+  state BESIDE the pool (``state``, rows addressed by ``slots``), the
+  step's working form of it, threaded through ``layer``, and the next
+  state made of that. The working form may hold Python values (a layer
+  ordinal, a growing list), so these two go with a LIST of layers only:
+  a scanned stack carries what ``layer`` returns, which has to be arrays.
+  Absent: what ``layer`` returns is the next state (None where None was
+  given).
+
+Every step takes ``state=None, slots=None`` by keyword and returns ``(out,
+cache_k', cache_v', state')``: None is an empty pytree to ``jax.jit``, so
+a family without state has neither among its program's parameters.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.attention import mha_reference
+from ray_tpu.ops.kv_cache import write_kv
+from ray_tpu.ops.paged_attention import (
+    decode_attention,
+    prefill_attention,
+    resolve_backend,
+)
+from ray_tpu.ops.sampling import sample_tokens, verify_tokens
+
+
+@dataclass(frozen=True)
+class CachedFamily:
+    name: str  # the programs are jit_<name>_prefill / _decode_step / ...
+    stack: str
+    embed: Callable
+    layer: Callable
+    final_norm: Callable
+    head: Callable
+    open_state: Callable | None = None
+    close_state: Callable | None = None
+
+
+class Step(NamedTuple):
+    """One cached step as data. ``kind``: ``fresh`` (a prompt from
+    position 0: positions are an ``arange``, nothing is resident yet),
+    ``chunk`` (a prompt chunk whose row b starts at ``start[b]``, earlier
+    positions resident), ``decode`` (S = 1) or ``verify`` (S = W: the last
+    committed token, then the drafts)."""
+
+    kind: str
+    pos: jax.Array             # [B, S] each token's true position
+    valid: jax.Array | None    # [B, S] the real tokens; None: every one
+    block_tables: jax.Array    # [B, NB]
+    # [B], the one such array every kind has: the rows' real tokens
+    # (fresh, chunk), positions (decode) or first positions (verify)
+    rows: jax.Array
+    start: jax.Array | None    # [B] (chunk)
+    slots: jax.Array | None    # [B] the rows' slots in ``state``
+    aux: Any = None            # ``embed``'s second result
+
+    def table_pos(self, n: int) -> jax.Array:
+        """``pos`` made safe to index a table of ``n`` rows: a padding
+        column can run past it (it is masked anyway); a decode row has no
+        padding columns."""
+        if self.kind == "decode":
+            return self.pos
+        return jnp.minimum(self.pos, n - 1)
+
+    def take(self, table: jax.Array, index: jax.Array) -> jax.Array:
+        """``table[index]`` for ``index`` [B, S]: [B, S, D]. A decode
+        step's [B, 1] is gathered as [B] and lifted, which is the program
+        served so far (a [B, 1] gather compiles to another)."""
+        if self.kind == "decode":
+            return table[index[:, 0]][:, None]
+        return table[index]
+
+
+def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
+    if kind == "decode":
+        pos, valid = rows[:, None], None
+    else:
+        B, S = tokens.shape
+        cols = jnp.arange(S, dtype=jnp.int32)[None, :]
+        if kind == "verify":
+            pos, valid = rows[:, None] + cols, cols <= draft_len[:, None]
+        else:
+            pos = (jnp.broadcast_to(cols, (B, S)) if kind == "fresh"
+                   else start[:, None] + cols)
+            valid = cols < rows[:, None]
+    return Step(kind, pos, valid, block_tables, rows, start, slots)
+
+
+def attend_layer(step: Step, k_layer, v_layer, q, k, v, cfg):
+    """The cache side of one attention layer: the chunk's K/V go into the
+    layer's pool slice, then the attention call the kind asks for. Returns
+    (attention output [B, S, Hq * hd], k_layer', v_layer')."""
+    B, S = q.shape[:2]
+    tables, backend = step.block_tables, cfg.attention_backend
+    if step.kind == "decode":
+        k_layer, v_layer = write_kv(
+            k_layer, v_layer, k[:, 0], v[:, 0], step.rows, tables)
+        attn = decode_attention(
+            q[:, 0], k_layer, v_layer, tables, step.rows, backend=backend)
+        return attn.reshape(B, S, -1), k_layer, v_layer
+    k_layer, v_layer = write_kv(
+        k_layer, v_layer, k, v, step.pos, tables, valid=step.valid)
+    # The fresh-prompt shortcut attends over the UNQUANTIZED just-computed
+    # k / v, the chunk alone (a prompt is prefilled once, at bucketed
+    # shapes, where a kernel's grid buys nothing). Under a quantized pool
+    # it must not run: a chunked re-prefill (failover resume) reads the
+    # quantized pool back, and resumed streams stay byte-identical only if
+    # the first prefill saw the same quantized values. Under pallas the
+    # fused kernel reads the just-written pool (the padded context never
+    # exists in HBM).
+    if (
+        step.kind == "fresh"
+        and cfg.quantization is None
+        and resolve_backend(backend) != "pallas"
+    ):
+        attn = mha_reference(  # repeats GQA kv heads internally
+            q.transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            causal=True,
+        ).transpose(0, 2, 1, 3)
+    else:
+        attn = prefill_attention(
+            q, k_layer, v_layer, tables, jnp.where(step.valid, step.pos, 0),
+            backend=backend)
+    return attn.reshape(B, S, -1), k_layer, v_layer
+
+
+def _layer(pool, i: int):
+    return jax.tree.map(lambda a: a[i], pool)
+
+
+def _set_layer(pool, i: int, layer):
+    return jax.tree.map(lambda a, b: a.at[i].set(b), pool, layer)
+
+
+def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
+    """``x`` through the stack. Returns (x, cache_k', cache_v', state)."""
+    if not isinstance(layers, list):
+
+        def body(carry, xs):
+            x, state = carry
+            lp, *kv = xs
+
+            def attend(q, k, v):
+                attn, kv[0], kv[1] = attend_layer(step, *kv, q, k, v, cfg)
+                return attn
+
+            x, state = fam.layer(x, lp, attend, step, state, cfg)
+            return (x, state), tuple(kv)
+
+        (x, state), (cache_k, cache_v) = jax.lax.scan(
+            body, (x, state), (layers, cache_k, cache_v))
+        return x, cache_k, cache_v, state
+
+    attended = 0  # the pool spans the attending layers only
+
+    def attend(q, k, v):
+        nonlocal cache_k, cache_v, attended
+        attn, k_layer, v_layer = attend_layer(
+            step, _layer(cache_k, attended), _layer(cache_v, attended),
+            q, k, v, cfg)
+        cache_k = _set_layer(cache_k, attended, k_layer)
+        cache_v = _set_layer(cache_v, attended, v_layer)
+        attended += 1
+        return attn
+
+    for lp in layers:
+        x, state = fam.layer(x, lp, attend, step, state, cfg)
+    return x, cache_k, cache_v, state
+
+
+def _step(fam, kind, params, cache_k, cache_v, tokens, rows, block_tables,
+          cfg, *, start=None, draft_len=None, sample=None, state=None,
+          slots=None):
+    step = _plan(kind, tokens, rows, block_tables, start, draft_len, slots)
+    x, aux = fam.embed(params, tokens, step, cfg)
+    step = step._replace(aux=aux)
+    work = state if fam.open_state is None else fam.open_state(
+        state, step, cfg)
+    x, cache_k, cache_v, work = _walk(
+        fam, x, params[fam.stack], cache_k, cache_v, step, work, cfg)
+    state = work if fam.close_state is None else fam.close_state(
+        state, work, step, cfg)
+    # the rows that reach the head: a decode step's one, a prompt's last
+    # real token, every column of a verify window
+    if kind == "decode":
+        x = x[:, 0]
+    h = fam.final_norm(params, x, cfg)
+    if kind in ("fresh", "chunk"):
+        h = h[jnp.arange(tokens.shape[0]), rows - 1]
+    logits = fam.head(params, h, cfg)
+    if sample is None:
+        out = logits
+    elif kind == "verify":
+        out = verify_tokens(logits, rows, tokens, draft_len, sample)
+    else:
+        # the new token lands right after the row's last real one
+        if kind == "decode":
+            new_pos = rows + 1
+        else:
+            new_pos = (rows if start is None else start + rows).astype(
+                jnp.int32)
+        out = sample_tokens(logits, new_pos, sample)
+    return out, cache_k, cache_v, state
+
+
+def steps(fam: CachedFamily):
+    """The family's (prefill, decode_step, verify_step), each named
+    ``<fam.name>_<step>``: a jitted program takes its name from there.
+
+    All take ``(params, cache_k, cache_v, ...)``, the pool ``[n_kv_layer,
+    num_blocks, block_size, n_kv_head, head_dim]`` (block 0 is the garbage
+    sink), ``block_tables [B, NB]``, the static ``cfg``, and by keyword
+    ``sample`` (an ops/sampling.py pytree: sampling then runs inside the
+    program and token ids come back, not logits), ``state`` and ``slots``.
+    All return ``(out, cache_k', cache_v', state')``. Shapes are static in
+    (batch, padded length, blocks a row), so the engine's bucketing bounds
+    the compiled set.
+
+    ``prefill(..., tokens [B, S], lengths [B], block_tables, cfg,
+    start=None)``: right-padded prompts (a padding row has length 1 and an
+    all-garbage table). Every real position's K/V is written; ``out`` is
+    the last real token's logits ``[B, V]`` float32, or the sampled first
+    tokens ``[B]`` int32. ``start=None``: each prompt starts at position 0.
+    ``start [B]`` (chunked prefill, prefix-cache hits): row b's tokens sit
+    at true positions ``start[b]..`` and attention covers what is already
+    resident in the paged cache.
+
+    ``decode_step(..., tokens [B], positions [B], block_tables, cfg)``:
+    each sequence's newest token at its position; writes its K/V, attends
+    over the paged context (itself included). A padding row points at the
+    garbage block with position 0. ``out``: next-token logits ``[B, V]`` or
+    sampled tokens ``[B]``.
+
+    ``verify_step(..., tokens [B, W], starts [B], draft_len [B],
+    block_tables, cfg)``: speculative decoding's verify pass. Column 0 is
+    row b's last COMMITTED token (true position ``starts[b]``; its K/V is
+    not yet cached, exactly as in a decode step), columns 1..W-1 are
+    drafted candidates; columns past ``draft_len`` are padding. Valid
+    columns write K/V at their own positions: for accepted drafts that IS
+    the correct entry (accepted prefix => identical context => identical
+    K/V); rejected drafts leave garbage only BEYOND the committed
+    frontier, where the causal mask keeps it unattended until the
+    frontier's next window overwrites it, so no rollback pass is needed.
+    Padding columns go to the garbage block, so reservations only need to
+    cover ``draft_len`` positions past the frontier. ``out``: the packed
+    verdicts ``[B, W + 1]`` int32 of ``verify_tokens``, or with
+    ``sample=None`` the window's logits ``[B, W, V]`` float32."""
+
+    def prefill(params, cache_k, cache_v, tokens, lengths, block_tables,
+                cfg, start=None, sample=None, *, state=None, slots=None):
+        return _step(
+            fam, "fresh" if start is None else "chunk", params, cache_k,
+            cache_v, tokens, lengths, block_tables, cfg, start=start,
+            sample=sample, state=state, slots=slots)
+
+    def decode_step(params, cache_k, cache_v, tokens, positions,
+                    block_tables, cfg, sample=None, *, state=None,
+                    slots=None):
+        return _step(
+            fam, "decode", params, cache_k, cache_v, tokens[:, None],
+            positions, block_tables, cfg, sample=sample, state=state,
+            slots=slots)
+
+    def verify_step(params, cache_k, cache_v, tokens, starts, draft_len,
+                    block_tables, cfg, sample=None, *, state=None,
+                    slots=None):
+        return _step(
+            fam, "verify", params, cache_k, cache_v, tokens, starts,
+            block_tables, cfg, draft_len=draft_len, sample=sample,
+            state=state, slots=slots)
+
+    for fn in (prefill, decode_step, verify_step):
+        fn.__name__ = fn.__qualname__ = f"{fam.name}_{fn.__name__}"
+    return prefill, decode_step, verify_step

@@ -18,6 +18,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import cached
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import gelu, layer_norm
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
@@ -342,242 +343,41 @@ def gpt_loss(
 
 
 # ----------------------------------------------------------------------------
-# KV-cached inference paths (serve/llm engine). Shapes are static in
-# (batch, padded length, blocks-per-seq) so the engine's bucketing bounds
-# the XLA compile cache. Cache layout: [n_layer, num_blocks, block_size,
+# KV-cached inference paths (serve/llm engine): what models/cached.py's one
+# step needs of this family. Cache layout: [n_layer, num_blocks, block_size,
 # n_head, head_dim] (ops/kv_cache.py; block 0 is the garbage sink).
 # ----------------------------------------------------------------------------
 
 
-def gpt_prefill(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    lengths: jax.Array,
-    block_tables: jax.Array,
-    cfg: GPTConfig,
-    start: jax.Array | None = None,
-    sample: dict | None = None,
-):
-    """Prompt pass: run the causal forward over right-padded prompts,
-    writing every valid position's K/V into the paged cache.
+def _cached_embed(params, tokens, step, cfg: GPTConfig):
+    """Learned positional embeddings at the true positions."""
+    x = step.take(params["wte"].astype(cfg.dtype), tokens)
+    wpe = params["wpe"].astype(cfg.dtype)
+    if step.kind == "fresh":
+        return x + wpe[: tokens.shape[1]], None
+    return x + step.take(wpe, step.table_pos(cfg.max_seq_len)), None
 
-    tokens [B, S] int32, lengths [B] (valid prefix per row; padding rows
-    use length 1 + an all-garbage block table), block_tables [B, NB].
-    Returns (last-valid-token logits [B, V] f32, cache_k', cache_v');
-    with a ``sample`` pytree (ops/sampling.py) sampling fuses into the
-    jitted program and (sampled first tokens [B] int32, cache_k',
-    cache_v') comes back instead — logits never leave the device.
 
-    ``start=None``: the whole prompt starts at position 0. Under the XLA
-    backend attention is the reference kernel over the chunk alone —
-    prefill happens once per request at bucketed shapes, where flash's
-    grid setup buys nothing; under pallas it runs the fused paged-prefill
-    kernel off the just-written cache (the padded context never exists in
-    HBM). ``start`` [B] int32 (chunked prefill / prefix-cache hits): row
-    b's tokens sit at TRUE positions start[b].. and earlier positions are
-    already resident in the paged cache, so positional embeddings index
-    the true positions and attention covers the full paged context via
-    the ``prefill_attention`` backend dispatcher.
-    """
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import prefill_attention, resolve_backend
+def _cached_layer(x, bp, attend, step, state, cfg: GPTConfig):
+    x = _attn_residual(x, attend(*_attn_qkv(x, bp, cfg)), bp, cfg)
+    return _mlp_residual(x, bp, cfg), state
 
-    B, S = tokens.shape
-    D = cfg.d_model
-    if start is None:
-        pos = jnp.broadcast_to(
-            jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
-        )
-        x = params["wte"].astype(cfg.dtype)[tokens] + params["wpe"].astype(
-            cfg.dtype
-        )[:S]
-    else:
-        pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        # padding columns can run past the table; they are masked anyway
-        emb_pos = jnp.minimum(pos, cfg.max_seq_len - 1)
-        x = params["wte"].astype(cfg.dtype)[tokens] + params["wpe"].astype(
-            cfg.dtype
-        )[emb_pos]
-    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
 
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cfg)
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk, vv, pos, block_tables, valid=valid
-        )
-        # The fresh-KV shortcut attends over the UNQUANTIZED just-computed
-        # k/v; under a quantized pool it must not run — chunked re-prefill
-        # (failover resume) reads the quantized pool back, and resumed
-        # streams stay byte-identical only if the original prefill saw the
-        # same quantized values. So quantized prefill always attends off
-        # the just-written pool via prefill_attention.
-        if (
-            start is None
-            and cfg.quantization is None
-            and resolve_backend(cfg.attention_backend) != "pallas"
-        ):
-            attn = mha_reference(
-                q.transpose(0, 2, 1, 3),
-                kk.transpose(0, 2, 1, 3),
-                vv.transpose(0, 2, 1, 3),
-                causal=True,
-            ).transpose(0, 2, 1, 3).reshape(B, S, D)
-        else:
-            attn = prefill_attention(
-                q, k_layer, v_layer, block_tables,
-                jnp.where(valid, pos, 0),
-                backend=cfg.attention_backend,
-            ).reshape(B, S, D)
-        x = _attn_residual(x, attn, bp, cfg)
-        x = _mlp_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
+def _final_norm(params, x, cfg: GPTConfig):
+    return layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    h_last = h[jnp.arange(B), lengths - 1]  # [B, D]
-    logits = jnp.einsum(
-        "bd,vd->bv", h_last.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
+
+def _head(params, h, cfg: GPTConfig):
+    # tied embeddings, as in gpt_forward
+    return jnp.einsum(
+        "...d,vd->...v", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
     )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import sample_tokens
-
-    # the new token lands right after the last valid prompt token
-    new_pos = (lengths if start is None else start + lengths).astype(
-        jnp.int32
-    )
-    return sample_tokens(logits, new_pos, sample), cache_k, cache_v
 
 
-def gpt_decode_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    positions: jax.Array,
-    block_tables: jax.Array,
-    cfg: GPTConfig,
-    sample: dict | None = None,
-):
-    """One incremental decode step for a batch of sequences.
-
-    tokens [B] int32 (each sequence's newest token), positions [B] (its
-    logical position), block_tables [B, NB]. Writes the token's K/V, then
-    attends over the gathered paged context (mask includes self). Padding
-    rows point at the garbage block with position 0.
-    Returns (next-token logits [B, V] f32, cache_k', cache_v'); with a
-    ``sample`` pytree the logits never leave the device — returns
-    (sampled tokens [B] int32, cache_k', cache_v').
-    """
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import decode_attention
-
-    B = tokens.shape[0]
-    D = cfg.d_model
-    x = params["wte"].astype(cfg.dtype)[tokens] + params["wpe"].astype(
-        cfg.dtype
-    )[positions]
-    x = x[:, None, :]  # [B, 1, D]
-
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cfg)  # [B, 1, H, hd]
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk[:, 0], vv[:, 0], positions, block_tables
-        )
-        attn = decode_attention(
-            q[:, 0], k_layer, v_layer, block_tables, positions,
-            backend=cfg.attention_backend,
-        )
-        x = _attn_residual(x, attn.reshape(B, 1, D), bp, cfg)
-        x = _mlp_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
-
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = layer_norm(x[:, 0], params["ln_f_scale"], params["ln_f_bias"])
-    logits = jnp.einsum(
-        "bd,vd->bv", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import sample_tokens
-
-    return sample_tokens(logits, positions + 1, sample), cache_k, cache_v
-
-
-def gpt_verify_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    starts: jax.Array,
-    draft_len: jax.Array,
-    block_tables: jax.Array,
-    cfg: GPTConfig,
-    sample: dict | None = None,
-):
-    """Speculative-decoding verify pass; see models/llama.py
-    ``llama_verify_step`` for the full contract (window layout, K/V
-    discipline, packed return). This is the GPT-family twin: learned
-    positional embeddings indexed at the true window positions instead of
-    RoPE, and the tied-embedding logits head over ALL window positions
-    feeding the ``verify_tokens`` epilogue.
-    """
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import prefill_attention
-
-    B, W = tokens.shape
-    D = cfg.d_model
-    pos = starts[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-    # padding columns can run past the table; they are masked anyway
-    emb_pos = jnp.minimum(pos, cfg.max_seq_len - 1)
-    x = params["wte"].astype(cfg.dtype)[tokens] + params["wpe"].astype(
-        cfg.dtype
-    )[emb_pos]
-    valid = (
-        jnp.arange(W, dtype=jnp.int32)[None, :] <= draft_len[:, None]
-    )
-
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cfg)
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk, vv, pos, block_tables, valid=valid
-        )
-        attn = prefill_attention(
-            q, k_layer, v_layer, block_tables, jnp.where(valid, pos, 0),
-            backend=cfg.attention_backend,
-        ).reshape(B, W, D)
-        x = _attn_residual(x, attn, bp, cfg)
-        x = _mlp_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
-
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])  # [B, W, D]
-    logits = jnp.einsum(
-        "bwd,vd->bwv", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import verify_tokens
-
-    return (
-        verify_tokens(logits, starts, tokens, draft_len, sample),
-        cache_k,
-        cache_v,
-    )
+gpt_prefill, gpt_decode_step, gpt_verify_step = cached.steps(
+    cached.CachedFamily(
+        "gpt", "blocks", _cached_embed, _cached_layer, _final_norm, _head))
 
 
 def gpt_num_params(cfg: GPTConfig) -> int:

@@ -27,8 +27,8 @@ three differences this family forces:
   ``conv_L_cache - 1`` rows of its gated input per sequence, in a SLOT of
   the ``state["conv"]`` array ``[n_conv_layer, slots, K-1, D]`` (slot 0 is
   the garbage sink, as block 0 is); the step functions take ``state`` and
-  the rows' ``slots`` beside the pool and the block tables, and return the
-  next ``state``.
+  the rows' ``slots`` by keyword and return the next ``state``
+  (models/cached.py).
 - ``state`` also carries the expert layers' counters, added to inside the
   program so that no step hands the host anything but its tokens:
   ``pairs`` ``[2, E, 2]`` (routed token-expert pairs by expert, prefill and
@@ -44,6 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import cached
 from ray_tpu.ops.attention import mha_reference
 from ray_tpu.ops.layers import rms_norm, rope
 from ray_tpu.ops.moe import moe_dropless, moe_route
@@ -332,20 +333,16 @@ def _qkv(h, lp, cos, sin, cfg: Lfm2MoeConfig):
     return q, k, v
 
 
-def _head(h, params, cfg: Lfm2MoeConfig):
+def _final_norm(params, x, cfg: Lfm2MoeConfig):
+    return rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
+
+
+def _head(params, h, cfg: Lfm2MoeConfig):
     """[..., D] -> float32 logits over the tied embedding."""
     return jnp.einsum(
         "...d,vd->...v", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
     )
-
-
-def _set_layer(pool, i: int, layer):
-    return jax.tree.map(lambda a, b: a.at[i].set(b), pool, layer)
-
-
-def _layer(pool, i: int):
-    return jax.tree.map(lambda a: a[i], pool)
 
 
 def lfm2_moe_forward(params: dict, tokens: jax.Array,
@@ -374,165 +371,77 @@ def lfm2_moe_forward(params: dict, tokens: jax.Array,
             ).transpose(0, 2, 1, 3).reshape(B, S, -1)
             x = x + attn @ lp["wo"].astype(cfg.dtype)
         x, _ = _ffn(x, lp, cfg, valid)
-    return _head(rms_norm(x, params["ln_f_scale"], cfg.norm_eps), params, cfg)
+    return _head(params, _final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
-# Cached inference paths (serve/llm engine): the contract of
-# models/llama.py llama_prefill / llama_decode_step, plus ``state`` after
-# the pool and ``slots`` [B] after the block tables. The pool is
-# [n_kv_layer, num_blocks, block_size, n_kv_head, head_dim].
+# Cached inference paths (serve/llm engine): what models/cached.py's one
+# step needs of this family. The pool is [n_kv_layer, num_blocks,
+# block_size, n_kv_head, head_dim]; ``state`` and the rows' ``slots`` [B]
+# reach the steps by keyword.
+#
+# A fresh prompt's convolutions begin from zeros whatever the slot held (a
+# reused slot is cleared by its first use); a chunk's continue from the
+# slot's rows (zeros where ``start[b]`` is 0); a decode step reads its
+# slot's rows and writes the next. Rows in slot 0 are padding: routed
+# nowhere, counted nowhere.
 # ----------------------------------------------------------------------------
 
 
-def lfm2_moe_prefill(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    state: dict,
-    tokens: jax.Array,
-    lengths: jax.Array,
-    block_tables: jax.Array,
-    slots: jax.Array,
-    cfg: Lfm2MoeConfig,
-    start: jax.Array | None = None,
-    sample: dict | None = None,
-):
-    """Prompt pass. Returns (last-valid-token logits [B, V] float32 — or,
-    with ``sample``, the sampled first tokens [B] int32 —, cache_k',
-    cache_v', state').
-
-    ``start=None``: the chunk is the whole prompt; positions 0..S-1, the
-    convolutions begin from zeros whatever the slot held (a reused slot is
-    cleared by its first use). ``start`` [B]: row b's tokens sit at true
-    positions ``start[b]..``; attention covers the paged context and each
-    convolution continues from the slot's rows — zeros where ``start[b]``
-    is 0. Rows in slot 0 are padding: routed nowhere, counted nowhere."""
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import prefill_attention, resolve_backend
-
-    B, S = tokens.shape
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    cols = jnp.arange(S, dtype=jnp.int32)[None, :]
-    pos = jnp.broadcast_to(cols, (B, S))
-    if start is not None:
-        pos = start[:, None] + cols
-    cos, sin = _rope_at(pos, cfg)
-    in_chunk = cols < lengths[:, None]
-    valid = in_chunk & (slots > 0)[:, None]
-    conv = state["conv"]
-    sizes = []
-    ai = ci = 0
-    for lp, kind in zip(params["layers"], cfg.layer_types):
-        h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
-        if kind == "conv":
-            b, c, u = jnp.split(h @ lp["short_conv_in"].astype(cfg.dtype),
-                                3, axis=-1)
-            before = None
-            if start is not None:
-                before = jnp.where((start > 0)[:, None, None],
-                                   conv[ci, slots], 0)
-            y, after = short_conv_prefill(b * u, c, lp["short_conv_w"],
-                                          before, lengths)
-            conv = conv.at[ci, slots].set(after.astype(conv.dtype))
-            ci += 1
-            x = x + y @ lp["short_conv_out"].astype(cfg.dtype)
-        else:
-            q, k, v = _qkv(h, lp, cos, sin, cfg)
-            k_layer, v_layer = write_kv(
-                _layer(cache_k, ai), _layer(cache_v, ai), k, v, pos,
-                block_tables, valid=in_chunk,
-            )
-            if start is None and resolve_backend(
-                    cfg.attention_backend) != "pallas":
-                attn = mha_reference(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), causal=True,
-                ).transpose(0, 2, 1, 3)
-            else:
-                attn = prefill_attention(
-                    q, k_layer, v_layer, block_tables,
-                    jnp.where(in_chunk, pos, 0),
-                    backend=cfg.attention_backend,
-                )
-            cache_k = _set_layer(cache_k, ai, k_layer)
-            cache_v = _set_layer(cache_v, ai, v_layer)
-            ai += 1
-            x = x + attn.reshape(B, S, -1) @ lp["wo"].astype(cfg.dtype)
-        x, routed = _ffn(x, lp, cfg, valid)
-        if routed is not None:
-            sizes.append(routed)
-    state = _counted(state, conv, sizes, decode=False)
-    h = rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-    logits = _head(h[jnp.arange(B), lengths - 1], params, cfg)
-    if sample is None:
-        return logits, cache_k, cache_v, state
-    from ray_tpu.ops.sampling import sample_tokens
-
-    new_pos = (lengths if start is None else start + lengths).astype(
-        jnp.int32)
-    return sample_tokens(logits, new_pos, sample), cache_k, cache_v, state
+def _cached_embed(params, tokens, step, cfg: Lfm2MoeConfig):
+    x = step.take(params["wte"].astype(cfg.dtype), tokens)
+    return x, _rope_at(step.pos, cfg)
 
 
-def lfm2_moe_decode_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    state: dict,
-    tokens: jax.Array,
-    positions: jax.Array,
-    block_tables: jax.Array,
-    slots: jax.Array,
-    cfg: Lfm2MoeConfig,
-    sample: dict | None = None,
-):
-    """One incremental decode step: each conv layer reads its slot's two
-    rows and writes the next two, each attention layer appends one K/V row
-    and reads the paged context. Returns (next-token logits [B, V] float32
-    or sampled tokens [B] int32, cache_k', cache_v', state')."""
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import decode_attention
+def _open_state(state: dict, step, cfg: Lfm2MoeConfig) -> dict:
+    """The step's working state: the conv rows as the layers so far left
+    them and the ordinal of the next conv layer, each expert layer's
+    routed pairs, and the mask of the tokens that are routed."""
+    routed = (step.slots > 0)[:, None]
+    if step.valid is not None:
+        routed = step.valid & routed
+    return {"conv": state["conv"], "conv_done": 0, "sizes": [],
+            "routed": routed}
 
-    B = tokens.shape[0]
-    x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
-    cos, sin = _rope_at(positions[:, None], cfg)
-    valid = (slots > 0)[:, None]
-    conv = state["conv"]
-    sizes = []
-    ai = ci = 0
-    for lp, kind in zip(params["layers"], cfg.layer_types):
-        h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
-        if kind == "conv":
-            b, c, u = jnp.split(
-                h[:, 0] @ lp["short_conv_in"].astype(cfg.dtype), 3, axis=-1)
+
+def _cached_layer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
+    h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
+    if "short_conv_in" in lp:
+        conv, ci, slots = work["conv"], work["conv_done"], step.slots
+        decode = step.kind == "decode"  # one row a sequence: [B, D]
+        b, c, u = jnp.split(
+            (h[:, 0] if decode else h) @ lp["short_conv_in"].astype(cfg.dtype),
+            3, axis=-1)
+        if decode:
             y, after = short_conv_decode(b * u, c, lp["short_conv_w"],
                                          conv[ci, slots])
-            conv = conv.at[ci, slots].set(after.astype(conv.dtype))
-            ci += 1
-            x = x + (y @ lp["short_conv_out"].astype(cfg.dtype))[:, None]
         else:
-            q, k, v = _qkv(h, lp, cos, sin, cfg)
-            k_layer, v_layer = write_kv(
-                _layer(cache_k, ai), _layer(cache_v, ai), k[:, 0], v[:, 0],
-                positions, block_tables,
-            )
-            attn = decode_attention(
-                q[:, 0], k_layer, v_layer, block_tables, positions,
-                backend=cfg.attention_backend,
-            )
-            cache_k = _set_layer(cache_k, ai, k_layer)
-            cache_v = _set_layer(cache_v, ai, v_layer)
-            ai += 1
-            x = x + (attn.reshape(B, -1) @ lp["wo"].astype(cfg.dtype))[:, None]
-        x, routed = _ffn(x, lp, cfg, valid)
-        if routed is not None:
-            sizes.append(routed)
-    state = _counted(state, conv, sizes, decode=True)
-    h = rms_norm(x[:, 0], params["ln_f_scale"], cfg.norm_eps)
-    logits = _head(h, params, cfg)
-    if sample is None:
-        return logits, cache_k, cache_v, state
-    from ray_tpu.ops.sampling import sample_tokens
+            before = None
+            if step.kind != "fresh":
+                before = jnp.where((step.start > 0)[:, None, None],
+                                   conv[ci, slots], 0)
+            y, after = short_conv_prefill(b * u, c, lp["short_conv_w"],
+                                          before, step.rows)
+        work = {**work, "conv_done": ci + 1,
+                "conv": conv.at[ci, slots].set(after.astype(conv.dtype))}
+        y = y @ lp["short_conv_out"].astype(cfg.dtype)
+        x = x + (y[:, None] if decode else y)
+    else:
+        q, k, v = _qkv(h, lp, *step.aux, cfg)
+        x = x + attend(q, k, v) @ lp["wo"].astype(cfg.dtype)
+    x, sizes = _ffn(x, lp, cfg, work["routed"])
+    if sizes is not None:
+        work = {**work, "sizes": [*work["sizes"], sizes]}
+    return x, work
 
-    return (sample_tokens(logits, positions + 1, sample), cache_k, cache_v,
-            state)
+
+def _close_state(state: dict, work: dict, step, cfg: Lfm2MoeConfig) -> dict:
+    return _counted(state, work["conv"], work["sizes"],
+                    decode=step.kind == "decode")
+
+
+# no verify step: rejected drafts would need the conv state rolled back
+lfm2_moe_prefill, lfm2_moe_decode_step, _ = cached.steps(
+    cached.CachedFamily(
+        "lfm2_moe", "layers", _cached_embed, _cached_layer, _final_norm,
+        _head, open_state=_open_state, close_state=_close_state))

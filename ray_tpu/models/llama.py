@@ -20,6 +20,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import cached
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import rms_norm, rope, rope_cache
 from ray_tpu.ops.moe import MoEConfig, moe_forward
@@ -382,250 +383,48 @@ def llama_loss(
 
 
 # ----------------------------------------------------------------------------
-# KV-cached inference paths (serve/llm engine) — same contract as
-# models/gpt.py gpt_prefill/gpt_decode_step. GQA: the cache stores the
-# compact n_kv_head heads; repetition to n_head happens inside the
-# attention ops. Cache layout [n_layer, num_blocks, block_size, n_kv_head,
-# head_dim] (ops/kv_cache.py; block 0 is the garbage sink).
+# KV-cached inference paths (serve/llm engine): what models/cached.py's one
+# step needs of this family. GQA: the cache stores the compact n_kv_head
+# heads; repetition to n_head happens inside the attention ops. Cache layout
+# [n_layer, num_blocks, block_size, n_kv_head, head_dim] (ops/kv_cache.py).
 # ----------------------------------------------------------------------------
 
 
-def llama_prefill(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    lengths: jax.Array,
-    block_tables: jax.Array,
-    cfg: LlamaConfig,
-    start: jax.Array | None = None,
-    sample: dict | None = None,
-):
-    """Prompt pass with paged-cache writes; see gpt_prefill. Returns
-    (last-valid-token logits [B, V] f32, cache_k', cache_v') — or, with a
-    ``sample`` pytree (ops/sampling.py), (sampled first tokens [B] int32,
-    cache_k', cache_v'): sampling fuses into the jitted program and only
-    token ids ever cross to host.
-
-    ``start=None`` (the whole-prompt path): RoPE runs at positions 0..S-1;
-    under the XLA backend attention is the causal reference kernel over
-    the chunk alone, under pallas it is the fused paged-prefill kernel off
-    the just-written cache.
-
-    ``start`` [B] int32 (the chunked-prefill / prefix-cache path): row b's
-    tokens sit at TRUE positions start[b]..start[b]+lengths[b]-1; earlier
-    positions are already resident in the paged cache (a previous chunk,
-    or blocks mapped from the prefix cache), so attention covers the full
-    paged context via the ``prefill_attention`` backend dispatcher instead
-    of looking only at the chunk. RoPE indexes the true positions, exactly
-    like decode.
-    """
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import prefill_attention, resolve_backend
-
-    B, S = tokens.shape
-    D = cfg.d_model
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    if start is None:
-        cos, sin = rope_cache(S, cfg.head_dim, cfg.rope_theta)
-        pos = jnp.broadcast_to(
-            jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
-        )
-        rope_pos = None  # cos/sin already sliced to 0..S-1
+def _cached_embed(params, tokens, step, cfg: LlamaConfig):
+    """The embedding, and the rotary table with the rows of it each token
+    takes: a fresh prompt slices the table to its 0..S-1 (None: no
+    gather), every other kind indexes the true positions."""
+    x = step.take(params["wte"].astype(cfg.dtype), tokens)
+    if step.kind == "fresh":
+        rows, at = tokens.shape[1], None
     else:
-        cos, sin = rope_cache(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
-        pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        # padding columns can run past the table; they are masked anyway
-        rope_pos = jnp.minimum(pos, cfg.max_seq_len - 1)
-    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+        rows, at = cfg.max_seq_len, step.table_pos(cfg.max_seq_len)
+    return x, (*rope_cache(rows, cfg.head_dim, cfg.rope_theta), at)
 
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=rope_pos)
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk, vv, pos, block_tables, valid=valid
-        )
-        # see gpt_prefill: the fresh-KV shortcut is gated off under a
-        # quantized pool so prefill attends over the same quantized values
-        # a failover re-prefill would read back.
-        if (
-            start is None
-            and cfg.quantization is None
-            and resolve_backend(cfg.attention_backend) != "pallas"
-        ):
-            # mha_reference repeats GQA kv heads internally
-            attn = mha_reference(
-                q.transpose(0, 2, 1, 3),
-                kk.transpose(0, 2, 1, 3),
-                vv.transpose(0, 2, 1, 3),
-                causal=True,
-            )
-            attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
-        else:
-            attn = prefill_attention(
-                q, k_layer, v_layer, block_tables,
-                jnp.where(valid, pos, 0),
-                backend=cfg.attention_backend,
-            ).reshape(B, S, D)
-        x = x + attn @ bp["wo"].astype(cfg.dtype)
-        x, _ = _ffn_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
 
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = rms_norm(x, params["ln_f_scale"])
-    h_last = h[jnp.arange(B), lengths - 1]  # [B, D]
-    logits = jnp.einsum(
-        "bd,dv->bv", h_last.astype(cfg.dtype),
+def _cached_layer(x, bp, attend, step, state, cfg: LlamaConfig):
+    cos, sin, at = step.aux
+    q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=at)
+    x = x + attend(q, kk, vv) @ bp["wo"].astype(cfg.dtype)
+    x, _ = _ffn_residual(x, bp, cfg)
+    return x, state
+
+
+def _final_norm(params, x, cfg: LlamaConfig):
+    return rms_norm(x, params["ln_f_scale"])
+
+
+def _head(params, h, cfg: LlamaConfig):
+    return jnp.einsum(
+        "...d,dv->...v", h.astype(cfg.dtype),
         params["lm_head"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
     )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import sample_tokens
-
-    # the new token lands right after the last valid prompt token
-    new_pos = (lengths if start is None else start + lengths).astype(
-        jnp.int32
-    )
-    return sample_tokens(logits, new_pos, sample), cache_k, cache_v
 
 
-def llama_decode_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    positions: jax.Array,
-    block_tables: jax.Array,
-    cfg: LlamaConfig,
-    sample: dict | None = None,
-):
-    """One incremental decode step; see gpt_decode_step. RoPE is applied at
-    the TRUE sequence position via the `positions` arg of ops/layers.rope.
-    Returns (next-token logits [B, V] f32, cache_k', cache_v'); with a
-    ``sample`` pytree the logits never leave the device — returns
-    (sampled tokens [B] int32, cache_k', cache_v')."""
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import decode_attention
-
-    B = tokens.shape[0]
-    D = cfg.d_model
-    x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
-    cos, sin = rope_cache(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
-    pos2d = positions[:, None]  # [B, 1] — rope indexes tables per row
-
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=pos2d)
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk[:, 0], vv[:, 0], positions, block_tables
-        )
-        attn = decode_attention(
-            q[:, 0], k_layer, v_layer, block_tables, positions,
-            backend=cfg.attention_backend,
-        )  # GQA handled inside (cache holds n_kv_head heads)
-        x = x + attn.reshape(B, 1, D) @ bp["wo"].astype(cfg.dtype)
-        x, _ = _ffn_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
-
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = rms_norm(x[:, 0], params["ln_f_scale"])
-    logits = jnp.einsum(
-        "bd,dv->bv", h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import sample_tokens
-
-    return sample_tokens(logits, positions + 1, sample), cache_k, cache_v
-
-
-def llama_verify_step(
-    params: dict,
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    tokens: jax.Array,
-    starts: jax.Array,
-    draft_len: jax.Array,
-    block_tables: jax.Array,
-    cfg: LlamaConfig,
-    sample: dict | None = None,
-):
-    """Speculative-decoding verify pass: score a [B, W] window in one call.
-
-    ``tokens`` [B, W] int32 — column 0 is row b's last COMMITTED token
-    (true position ``starts`` [B]; its K/V is not yet cached, exactly as in
-    a decode step), columns 1..W-1 are drafted candidates; columns past
-    ``draft_len`` [B] are padding. The body is the chunked-prefill
-    formulation at true positions (RoPE indexed per position, K/V written
-    for the valid window, ``prefill_attention`` over the full paged
-    context) but keeps logits at ALL window positions instead of the last
-    valid one, feeding the ``verify_tokens`` epilogue (ops/sampling.py).
-
-    K/V discipline: valid columns write at their own positions — for
-    accepted drafts that IS the correct cache entry (accepted prefix =>
-    identical context => identical K/V). Rejected drafts leave garbage
-    only BEYOND the committed frontier, where the causal mask
-    ``t <= position`` keeps it unattended until the frontier's next window
-    overwrites those positions; no rollback pass is needed. Padding
-    columns are redirected to the garbage block, so reservations only need
-    to cover ``draft_len`` positions past the frontier.
-
-    Returns (packed verdicts [B, W+1] int32 — see ``verify_tokens``,
-    cache_k', cache_v'); with ``sample=None`` returns the raw window
-    logits [B, W, V] f32 instead of verdicts (debug path).
-    """
-    from ray_tpu.ops.kv_cache import write_kv
-    from ray_tpu.ops.paged_attention import prefill_attention
-
-    B, W = tokens.shape
-    D = cfg.d_model
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    cos, sin = rope_cache(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
-    pos = starts[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-    # padding columns can run past the table; they are masked anyway
-    rope_pos = jnp.minimum(pos, cfg.max_seq_len - 1)
-    valid = (
-        jnp.arange(W, dtype=jnp.int32)[None, :] <= draft_len[:, None]
-    )
-
-    def body(x, xs):
-        bp, k_layer, v_layer = xs
-        q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=rope_pos)
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, kk, vv, pos, block_tables, valid=valid
-        )
-        attn = prefill_attention(
-            q, k_layer, v_layer, block_tables, jnp.where(valid, pos, 0),
-            backend=cfg.attention_backend,
-        ).reshape(B, W, D)
-        x = x + attn @ bp["wo"].astype(cfg.dtype)
-        x, _ = _ffn_residual(x, bp, cfg)
-        return x, (k_layer, v_layer)
-
-    x, (cache_k, cache_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v)
-    )
-    h = rms_norm(x, params["ln_f_scale"])  # [B, W, D]
-    logits = jnp.einsum(
-        "bwd,dv->bwv", h.astype(cfg.dtype),
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if sample is None:
-        return logits, cache_k, cache_v
-    from ray_tpu.ops.sampling import verify_tokens
-
-    return (
-        verify_tokens(logits, starts, tokens, draft_len, sample),
-        cache_k,
-        cache_v,
-    )
+llama_prefill, llama_decode_step, llama_verify_step = cached.steps(
+    cached.CachedFamily(
+        "llama", "blocks", _cached_embed, _cached_layer, _final_norm, _head))
 
 
 def llama_num_params(cfg: LlamaConfig) -> int:

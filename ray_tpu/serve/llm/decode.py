@@ -1,11 +1,11 @@
 """Jitted incremental forwards per model family + compile-cache tracking.
 
 One `DecodeFns` per engine: it binds the (static) model config into the
-family's prefill / decode-step functions (models/gpt.py, models/llama.py),
-jits them once, and records every distinct input-shape signature it is
-called with. Because jit caches by shape, the signature set size IS the
-number of compiled programs — the engine exposes it so tests (and ops
-dashboards) can assert the bucketing keeps it bounded.
+family's prefill / decode / verify steps (models/cached.py, through the
+family's file), jits them once, and records every distinct input-shape
+signature it is called with. Because jit caches by shape, the signature
+set size IS the number of compiled programs — the engine exposes it so
+tests (and ops dashboards) can assert the bucketing keeps it bounded.
 """
 from __future__ import annotations
 
@@ -21,16 +21,18 @@ class Family:
     means one loader in ``FAMILIES`` below.
 
     ``init`` / ``prefill`` / ``decode_step`` / ``verify_step`` (None: the
-    family has no verify step) are the model's functions; ``param_axes``
+    family has no verify step) are the model's functions, the three
+    steps of models/cached.py: each takes ``state=None, slots=None`` by
+    keyword and returns ``(out, cache_k, cache_v, state)``. ``param_axes``
     and ``quant_axes`` map a model config to trees matching ``init``'s
     output; ``default_config()`` is the tiny config an engine built
     without one gets. ``init_state`` is None for a family whose only
-    per-sequence state is the paged K/V pool, else ``(model_cfg, slots) ->
-    pytree``: what the family keeps per running sequence BESIDE the pool
-    (its step functions then take ``state`` after the pool and ``slots``
-    after the block tables, and return the next ``state`` after the
-    pool); ``counters`` is ``state -> {name: int | [int]}``, the counters
-    the step programs keep inside ``state``, read for ``stats()``."""
+    per-sequence state is the paged K/V pool (its ``state`` stays None),
+    else ``(model_cfg, slots) -> pytree``: what the family keeps per
+    running sequence BESIDE the pool, its rows addressed by the steps'
+    ``slots``; ``counters`` is ``state -> {name: int | [int]}``, the
+    counters the step programs keep inside ``state``, read for
+    ``stats()``."""
 
     init: Callable
     prefill: Callable
@@ -138,6 +140,24 @@ def _jit_named(fn, model_cfg, options):
     return jax.jit(bound, compiler_options=options)
 
 
+def _with_stack_room(fn, args, kwargs):
+    return fn(*args, **kwargs)
+
+
+# A call that traces its program goes a few hundred Python frames deep, in
+# many small calls. CPython (3.11 on) keeps a thread's frames in 16 KB
+# chunks: the frame that does not fit maps a new chunk, and that chunk is
+# unmapped as the same frame returns, so every call made right at a
+# chunk's end costs a map, a page fault and an unmap (5 us in a plain VM,
+# 10-70 us on the chip's host, against 0.03 us). Whether a trace's hot
+# calls sit there depends on nothing but the depth the step was called
+# at: the GPT-2 cell's 38 kernel traces took 3.1 s or 16.6 s by it
+# (PERF.md, PR 28). A frame that asks for 512 KB of stack opens a 1 MB
+# chunk of its own, and whatever is called below it fits in the rest.
+_with_stack_room.__code__ = _with_stack_room.__code__.replace(
+    co_stacksize=1 << 16)
+
+
 def _jitted(family: str, model_cfg, platform):
     # platforms that get the same settings share their wrappers
     options = _compiler_options(platform)
@@ -155,7 +175,8 @@ def _jitted(family: str, model_cfg, platform):
 class DecodeFns:
     """prefill(params, cache_k, cache_v, tokens, lengths, block_tables)
     and decode(params, cache_k, cache_v, tokens, positions, block_tables)
-    (``verify`` is None for a family without a verify step),
+    (``verify`` is None for a family without a verify step), each
+    returning (out, cache_k, cache_v, state),
     jitted with the model config closed over as a static value. Compiled
     programs are shared process-wide per (family, config, compiler settings); the
     signature set below is per-instance, so each engine reports the
@@ -177,11 +198,15 @@ class DecodeFns:
         # so per-instance first-use is the per-engine compile event)
         self.on_new_signature = None
 
-    def _note(self, sig: tuple) -> None:
-        if sig not in self._signatures:
-            self._signatures.add(sig)
-            if self.on_new_signature is not None:
-                self.on_new_signature(sig)
+    def _call(self, fn, sig: tuple, *args, **kwargs):
+        """``fn(*args, **kwargs)``, the signature noted. The first call of
+        a signature is the one that may trace: it gets stack room."""
+        if sig in self._signatures:
+            return fn(*args, **kwargs)
+        self._signatures.add(sig)
+        if self.on_new_signature is not None:
+            self.on_new_signature(sig)
+        return _with_stack_room(fn, args, kwargs)
 
     def prefill(
         self, params, cache_k, cache_v, tokens, lengths, block_tables,
@@ -197,48 +222,37 @@ class DecodeFns:
         # (token ids out instead of logits), not its signature, so the
         # compile-count contract stays (prefill, prefill_chunk, decode)
         # x batch_buckets x length_buckets.
-        # ``state`` / ``slots``: a family that keeps per-sequence state
-        # beside the pool (``Family.state``) takes both and returns the
-        # next state after the pool; for the others neither argument
-        # exists, so nothing of it enters their programs.
+        # ``state`` / ``slots``: what a family keeps per sequence beside
+        # the pool (``Family.init_state``) and the rows' slots in it. None
+        # for the others, and None is an empty pytree to ``jax.jit``, so
+        # nothing of either enters their programs.
         kind = "prefill" if start is None else "prefill_chunk"
-        self._note(
-            (kind, tuple(tokens.shape), tuple(block_tables.shape))
-        )
-        args = (tokens, lengths, block_tables)
-        if state is not None:
-            args = (state, *args, slots)
-        if start is None:
-            return self._prefill(
-                params, cache_k, cache_v, *args, sample=sample)
-        return self._prefill(
-            params, cache_k, cache_v, *args, start=start, sample=sample)
+        return self._call(
+            self._prefill,
+            (kind, tuple(tokens.shape), tuple(block_tables.shape)),
+            params, cache_k, cache_v, tokens, lengths, block_tables,
+            start=start, sample=sample, state=state, slots=slots)
 
     def decode(self, params, cache_k, cache_v, tokens, positions,
                block_tables, sample=None, state=None, slots=None):
-        self._note(
-            ("decode", tuple(tokens.shape), tuple(block_tables.shape))
-        )
-        args = (tokens, positions, block_tables)
-        if state is not None:
-            args = (state, *args, slots)
-        return self._decode(
-            params, cache_k, cache_v, *args, sample=sample)
+        return self._call(
+            self._decode,
+            ("decode", tuple(tokens.shape), tuple(block_tables.shape)),
+            params, cache_k, cache_v, tokens, positions, block_tables,
+            sample=sample, state=state, slots=slots)
 
     def verify(self, params, cache_k, cache_v, tokens, starts, draft_len,
-               block_tables, sample=None):
+               block_tables, sample=None, state=None, slots=None):
         # speculative-decoding verify window: tokens [B, W] with W fixed
         # per engine at speculative_k + 1 (per-row draft availability is
         # DATA — draft_len — not shape), so the signature set adds exactly
         # ("verify",) x batch_buckets x tables-width and stays frozen
         # under mixed speculative/plain traffic.
-        self._note(
-            ("verify", tuple(tokens.shape), tuple(block_tables.shape))
-        )
-        return self._verify(
+        return self._call(
+            self._verify,
+            ("verify", tuple(tokens.shape), tuple(block_tables.shape)),
             params, cache_k, cache_v, tokens, starts, draft_len,
-            block_tables, sample=sample,
-        )
+            block_tables, sample=sample, state=state, slots=slots)
 
     @property
     def num_compiled_shapes(self) -> int:

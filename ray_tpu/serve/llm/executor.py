@@ -108,12 +108,11 @@ class ModelExecutor:
 
     The four step methods also take ``span=``, the attributes of the
     step's ``executor.dispatch`` phase, and book their two host phases
-    into ``phases`` (``_run``). For a family that keeps per-sequence
-    state beside the pool (``cache.state``; decode.py ``Family.state``)
-    the prefill and decode methods take ``slots=``, each row's state slot,
-    and ``cache.state`` is passed through the step and updated in place
-    like ``cache.k`` / ``cache.v``; the other families' calls are the same
-    calls as before, with no such argument.
+    into ``phases`` (``_run``). ``cache.state`` (decode.py
+    ``Family.init_state``: what a family keeps per sequence beside the
+    pool; None for the others) is passed through every step and updated
+    in place like ``cache.k`` / ``cache.v``; the prefill and decode methods
+    take ``slots=``, each row's slot in it (None: left out of the call).
     """
 
     kind = "single"
@@ -284,24 +283,18 @@ class ModelExecutor:
         ``executor.dispatch`` is the jitted call until it returns, under
         the attributes the engine gives in ``span`` (``kind``;
         ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
-        ``cache.v`` in place, and ``cache.state`` where the family keeps
-        one."""
+        ``cache.v`` and ``cache.state`` (None where the family keeps
+        none) in place."""
         with obs.phase(self.phases, "executor.stage"):
             dev = [self._dev(a) for a in arrays]
             staged = {k: self._dev(v) for k, v in staged.items()
                       if v is not None}
             sample = self._dev_sample(sample)
         with obs.phase(self.phases, "executor.dispatch", **(span or {})):
-            if self.cache.state is None:
-                out, self.cache.k, self.cache.v = fn(
-                    self.params, self.cache.k, self.cache.v, *dev,
-                    sample=sample, **staged,
-                )
-            else:
-                out, self.cache.k, self.cache.v, self.cache.state = fn(
-                    self.params, self.cache.k, self.cache.v, *dev,
-                    sample=sample, state=self.cache.state, **staged,
-                )
+            out, self.cache.k, self.cache.v, self.cache.state = fn(
+                self.params, self.cache.k, self.cache.v, *dev,
+                sample=sample, state=self.cache.state, **staged,
+            )
         return out
 
     def prefill(self, tokens, lengths, tables, sample=None, span=None,

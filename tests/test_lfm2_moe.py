@@ -324,24 +324,26 @@ def _serve_logits(cfg, params, prompt, new, chunk=None, slot=1, state=None):
     out = []
     if chunk is None:
         logits, k, v, state = lfm2_moe_prefill(
-            params, k, v, state, jnp.asarray([prompt], jnp.int32),
-            jnp.asarray([n], jnp.int32), table, slots, cfg)
+            params, k, v, jnp.asarray([prompt], jnp.int32),
+            jnp.asarray([n], jnp.int32), table, cfg, state=state,
+            slots=slots)
     else:
         for s in range(0, n, chunk):
             part = prompt[s:s + chunk]
             toks = np.zeros((1, chunk), np.int32)
             toks[0, :len(part)] = part
             logits, k, v, state = lfm2_moe_prefill(
-                params, k, v, state, jnp.asarray(toks),
-                jnp.asarray([len(part)], jnp.int32), table, slots, cfg,
-                start=jnp.asarray([s], jnp.int32))
+                params, k, v, jnp.asarray(toks),
+                jnp.asarray([len(part)], jnp.int32), table, cfg,
+                start=jnp.asarray([s], jnp.int32), state=state, slots=slots)
     seq = list(prompt)
     for _ in range(new):
         out.append(np.asarray(logits[0]))
         seq.append(int(np.argmax(out[-1])))
         logits, k, v, state = lfm2_moe_decode_step(
-            params, k, v, state, jnp.asarray([seq[-1]], jnp.int32),
-            jnp.asarray([len(seq) - 1], jnp.int32), table, slots, cfg)
+            params, k, v, jnp.asarray([seq[-1]], jnp.int32),
+            jnp.asarray([len(seq) - 1], jnp.int32), table, cfg, state=state,
+            slots=slots)
     return np.stack(out), seq, state
 
 
@@ -419,13 +421,14 @@ def test_batched_equals_solo_bit_for_bit(tiny):
             slots[r] = r + 1
         state = lfm2_moe_init_state(cfg, 4)
         logits, k, v, state = lfm2_moe_prefill(
-            params, pool, pool, state, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(tables), jnp.asarray(slots), cfg)
+            params, pool, pool, jnp.asarray(toks), jnp.asarray(lens),
+            jnp.asarray(tables), cfg, state=state, slots=jnp.asarray(slots))
         nxt = np.where(slots > 0, np.asarray(jnp.argmax(logits, -1)), 0)
         pos = np.where(slots > 0, lens, 0).astype(np.int32)
         logits2, *_ = lfm2_moe_decode_step(
-            params, k, v, state, jnp.asarray(nxt.astype(np.int32)),
-            jnp.asarray(pos), jnp.asarray(tables), jnp.asarray(slots), cfg)
+            params, k, v, jnp.asarray(nxt.astype(np.int32)),
+            jnp.asarray(pos), jnp.asarray(tables), cfg, state=state,
+            slots=jnp.asarray(slots))
         return np.asarray(logits), np.asarray(logits2)
 
     together = run([0, 1, 2])
@@ -711,10 +714,12 @@ def test_other_families_take_no_state_argument(jax_cpu, family):
     strip = lambda t: t.split("\n", 1)[1]  # the module's name line
     assert strip(through) == strip(own)
     assert "state" not in through and "slots" not in through
-    # and the call the executor makes carries no such keyword
+    # and the call the executor makes carries None for both
     calls = []
     real = engine.fns._decode
     engine.fns._decode = lambda *a, **k: (calls.append(k), real(*a, **k))[1]
     engine.generate([1, 2, 3], max_new_tokens=3)
-    assert calls and all(set(k) == {"sample"} for k in calls)
+    assert calls and all(
+        set(k) == {"sample", "state", "slots"} and k["state"] is None
+        and k["slots"] is None for k in calls)
     engine.shutdown()

@@ -555,25 +555,49 @@ def test_chunked_prefill_streams_identical_across_backends(jax_cpu, family):
     assert outs["pallas"] == outs["xla"], family
 
 
+class _OracleDrafter:
+    """Drafts what an engine WITHOUT speculation generated for the same
+    request (``streams``: (prompt, its tokens) pairs, told apart by what
+    was generated so far). A random-init model does not repeat its
+    prompt, so the n-gram drafter would propose nothing and no verify
+    program would ever run; with the oracle every decode step of these
+    requests is a verify step."""
+
+    def __init__(self, streams):
+        self._streams = [(list(p), list(out)) for p, out in streams]
+
+    def propose(self, prompt, generated, k):
+        done = len(generated)
+        for p, out in self._streams:
+            if p == list(prompt) and out[:done] == list(generated):
+                return out[done:done + k]
+        return []
+
+
 @pytest.mark.parametrize("family", ["gpt", "llama"])
 def test_spec_verify_streams_identical_across_backends(jax_cpu, family):
     """Speculative decoding's verify windows run the prefill kernel at
     per-column positions with padding columns clamped to 0 — the stream
     (committed tokens only) must still be byte-identical across
-    backends, greedy and sampled."""
+    backends, greedy and sampled, and to the stream without speculation."""
     motif = [435, 326, 262, 138, 158, 21, 39, 9]
-    outs = {}
+    requests = [(motif * 3, dict()),
+                (motif * 3, dict(temperature=0.9, top_p=0.8, seed=5))]
+    eng = _engine(family, _model_config(family), attention_backend="xla")
+    plain = [eng.generate(p, max_new_tokens=12, **kw) for p, kw in requests]
+    eng.shutdown()
+    drafter = _OracleDrafter(
+        [(p, out) for (p, _), out in zip(requests, plain)])
     for backend in ("xla", "pallas"):
         eng = _engine(family, _model_config(family),
-                      attention_backend=backend, speculative_k=2)
-        outs[backend] = [
-            eng.generate(motif * 3, max_new_tokens=12),
-            eng.generate(motif * 3, max_new_tokens=10,
-                         temperature=0.9, top_p=0.8, seed=5),
-        ]
+                      attention_backend=backend, speculative_k=2,
+                      drafter=drafter)
+        outs = [eng.generate(p, max_new_tokens=12, **kw)
+                for p, kw in requests]
         assert any(s[0] == "verify" for s in eng.fns.signatures), backend
+        assert eng.stats()["spec_accepted_tokens"] > 0, backend
         eng.shutdown()
-    assert outs["pallas"] == outs["xla"], family
+        assert outs == plain, (family, backend)
 
 
 def test_sharded_chunked_and_verify_streams_identical(jax_cpu):
@@ -581,22 +605,25 @@ def test_sharded_chunked_and_verify_streams_identical(jax_cpu):
     the head-sharded pool — the prefill kernel is head-count-agnostic, so
     streams match XLA under GSPMD unchanged."""
     motif = [435, 326, 262, 138, 158, 21, 39, 9]
-    long_prompt = list(range(3, 43, 2))
-    outs = {}
+    requests = [(list(range(3, 43, 2)), dict()),
+                (motif * 3, dict(temperature=0.9, top_p=0.8, seed=5))]
+    sharded = dict(tp=2, fsdp=2, prefill_chunk_tokens=8)
+    eng = _engine("llama", _model_config(), attention_backend="xla",
+                  **sharded)
+    plain = [eng.generate(p, max_new_tokens=10, **kw) for p, kw in requests]
+    eng.shutdown()
+    drafter = _OracleDrafter(
+        [(p, out) for (p, _), out in zip(requests, plain)])
     for backend in ("xla", "pallas"):
-        eng = _engine("llama", _model_config(),
-                      attention_backend=backend, tp=2, fsdp=2,
-                      prefill_chunk_tokens=8, speculative_k=2)
+        eng = _engine("llama", _model_config(), attention_backend=backend,
+                      speculative_k=2, drafter=drafter, **sharded)
         assert eng.executor.kind == "sharded"
-        outs[backend] = [
-            eng.generate(long_prompt, max_new_tokens=8),
-            eng.generate(motif * 3, max_new_tokens=10,
-                         temperature=0.9, top_p=0.8, seed=5),
-        ]
+        outs = [eng.generate(p, max_new_tokens=10, **kw)
+                for p, kw in requests]
         kinds = {s[0] for s in eng.fns.signatures}
         assert {"prefill_chunk", "verify"} <= kinds, (backend, kinds)
         eng.shutdown()
-    assert outs["pallas"] == outs["xla"]
+        assert outs == plain, backend
 
 
 def test_backend_via_model_parallel_config(jax_cpu):

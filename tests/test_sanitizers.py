@@ -527,12 +527,14 @@ def test_decode_attention_path_never_materializes_kv():
     ``jnp.repeat`` blows compact GQA KV heads up rep x — either one
     silently reintroduces the O(T) HBM traffic the paged kernels exist to
     avoid. Scope: all of ops/paged_attention.py (the Pallas kernels and
-    both dispatchers), everything lexically inside the models'
-    ``*_decode_step``, ``*_prefill`` and ``*_verify_step`` (including the
-    nested scan ``body`` closures — where calling kv_cache's
+    both dispatchers), all of models/cached.py (the cached steps' one
+    skeleton, the cache side of every attention layer included) and
+    everything lexically inside what a family hands that skeleton —
+    ``_cached_embed``, ``_cached_layer`` and the q/k/v helper the layer
+    calls, in gpt.py, llama.py and lfm2_moe.py — where calling kv_cache's
     ``paged_prefill_attention`` directly is ALSO banned: it would bypass
     the ``prefill_attention`` backend dispatcher, silently pinning the
-    path to the gather formulation), and — for the XLA fallback's GQA
+    path to the gather formulation —, and — for the XLA fallback's GQA
     math — the repeat ban alone in kv_cache's paged attention functions
     (``gather_kv`` is the dense formulation's legitimate core)."""
     import ast
@@ -587,20 +589,35 @@ def test_decode_attention_path_never_materializes_kv():
     offenders += offending_calls(
         dispatcher, banned={"gather_kv", "repeat"},
     )
-    for model, family in (("gpt.py", "gpt"), ("llama.py", "llama")):
-        offenders += offending_calls(
-            root / "ray_tpu" / "models" / model,
-            banned={"gather_kv", "repeat"},
-            within={f"{family}_decode_step", f"{family}_prefill",
-                    f"{family}_verify_step"},
+    # what each family hands the skeleton (models/cached.py): a rename
+    # must not un-lint it, so every scoped name has to be a function there
+    cached_paths = {
+        "gpt.py": {"_cached_embed", "_cached_layer", "_attn_qkv"},
+        "llama.py": {"_cached_embed", "_cached_layer", "_attn_qkv"},
+        "lfm2_moe.py": {"_cached_embed", "_cached_layer", "_qkv"},
+    }
+    for model, within in cached_paths.items():
+        path = root / "ray_tpu" / "models" / model
+        defined = {
+            n.name for n in ast.walk(ast.parse(path.read_text()))
+            if isinstance(n, ast.FunctionDef)
+        }
+        assert within <= defined, (
+            f"models/{model} lost {sorted(within - defined)}: the lint "
+            f"below would pass without reading the family's cached path"
         )
-        # the prefill/verify paths must route through the backend
-        # dispatcher, never the XLA fallback directly
+        # compact GQA heads reach ``attend``; the prefill / verify paths
+        # go through the backend dispatcher, never the XLA fallback
         offenders += offending_calls(
-            root / "ray_tpu" / "models" / model,
-            banned={"paged_prefill_attention"},
-            within={f"{family}_prefill", f"{family}_verify_step"},
+            path,
+            banned={"gather_kv", "repeat", "paged_prefill_attention"},
+            within=within,
         )
+    # the cached steps' shared part is models/cached.py: all of it
+    offenders += offending_calls(
+        root / "ray_tpu" / "models" / "cached.py",
+        banned={"gather_kv", "repeat", "paged_prefill_attention"},
+    )
     offenders += offending_calls(
         root / "ray_tpu" / "ops" / "kv_cache.py",
         banned={"repeat"},
@@ -610,6 +627,45 @@ def test_decode_attention_path_never_materializes_kv():
     assert not offenders, (
         f"materializing ops in the paged attention paths: {offenders}"
     )
+
+
+@pytest.mark.parametrize("name", [
+    "write_kv", "prefill_attention", "decode_attention", "sample_tokens",
+    "verify_tokens",
+])
+def test_cached_step_is_written_once(name):
+    """ISSUE 28: the cache side of an attention layer (``write_kv``, then
+    ``prefill_attention`` or ``decode_attention``) and the sampling
+    epilogue (``sample_tokens`` / ``verify_tokens``) are called from
+    models/cached.py and from no family's file: a family supplies its
+    embedding, ONE layer function, norm and head, and the step's skeleton
+    is not copied again. Neither called nor imported elsewhere under
+    ray_tpu/models/."""
+    import ast
+    import pathlib
+
+    models = pathlib.Path(__file__).resolve().parents[1] / "ray_tpu" / "models"
+
+    def uses(path):
+        out = []
+        for node in ast.walk(ast.parse(path.read_text(), str(path))):
+            if isinstance(node, ast.Call):
+                f = node.func
+                called = f.id if isinstance(f, ast.Name) else getattr(
+                    f, "attr", None)
+                if called == name:
+                    out.append(f"{path.name}:{node.lineno}")
+            elif isinstance(node, ast.ImportFrom):
+                if any(a.name == name for a in node.names):
+                    out.append(f"{path.name}:{node.lineno} (import)")
+        return out
+
+    # the one caller must exist under its linted name: a rename of the
+    # file or the op would silently un-lint the families
+    assert len(uses(models / "cached.py")) >= 2, name
+    offenders = [u for path in sorted(models.glob("*.py"))
+                 if path.name != "cached.py" for u in uses(path)]
+    assert not offenders, f"{name} outside models/cached.py: {offenders}"
 
 
 def test_no_full_pool_dequant_outside_attention_kernels():
@@ -638,6 +694,7 @@ def test_no_full_pool_dequant_outside_attention_kernels():
     }
     targets = sorted((root / "ray_tpu" / "serve" / "llm").rglob("*.py"))
     targets += [
+        root / "ray_tpu" / "models" / "cached.py",  # where the pool is
         root / "ray_tpu" / "models" / "gpt.py",
         root / "ray_tpu" / "models" / "llama.py",
         root / "ray_tpu" / "ops" / "kv_cache.py",

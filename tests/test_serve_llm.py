@@ -74,7 +74,7 @@ def test_paged_decode_logits_match_full_forward(jax_cpu, family):
     cache.ensure_capacity("s", len(prompt), reserved=False)
     tokens = np.zeros((1, 8), np.int32)
     tokens[0, : len(prompt)] = prompt
-    logits, cache.k, cache.v = fns.prefill(
+    logits, cache.k, cache.v, _ = fns.prefill(
         params, cache.k, cache.v,
         jnp.asarray(tokens), jnp.asarray([len(prompt)], np.int32),
         jnp.asarray(cache.block_table("s", 1)[None, :]),
@@ -89,7 +89,7 @@ def test_paged_decode_logits_match_full_forward(jax_cpu, family):
         seq.append(tok)
         cache.ensure_capacity("s", len(seq), reserved=False)
         nb = -(-16 // bs)  # context bucket 16 for these lengths
-        logits, cache.k, cache.v = fns.decode(
+        logits, cache.k, cache.v, _ = fns.decode(
             params, cache.k, cache.v,
             jnp.asarray([tok], np.int32),
             jnp.asarray([len(seq) - 1], np.int32),

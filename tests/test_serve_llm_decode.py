@@ -92,10 +92,10 @@ def test_fused_greedy_token_matches_host_argmax(jax_cpu, family):
         jnp.asarray(tokens), jnp.asarray([len(prompt)], np.int32),
         jnp.asarray(cache.block_table("s", 1)[None, :]),
     )
-    logits, cache.k, cache.v = fns.prefill(params, cache.k, cache.v, *args)
+    logits, cache.k, cache.v, _ = fns.prefill(params, cache.k, cache.v, *args)
     cache2 = fresh_cache()
     cache2.ensure_capacity("s", len(prompt), reserved=False)
-    tok, cache2.k, cache2.v = fns.prefill(
+    tok, cache2.k, cache2.v, _ = fns.prefill(
         params, cache2.k, cache2.v, *args, sample=greedy
     )
     ref = int(np.argmax(np.asarray(logits)[0]))
@@ -110,10 +110,10 @@ def test_fused_greedy_token_matches_host_argmax(jax_cpu, family):
         jnp.asarray([seq_len - 1], np.int32),
         jnp.asarray(c.block_table("s", 2)[None, :]),
     )
-    logits, cache.k, cache.v = fns.decode(
+    logits, cache.k, cache.v, _ = fns.decode(
         params, cache.k, cache.v, *dec_args(cache)
     )
-    tok, cache2.k, cache2.v = fns.decode(
+    tok, cache2.k, cache2.v, _ = fns.decode(
         params, cache2.k, cache2.v, *dec_args(cache2), sample=greedy
     )
     assert int(np.asarray(tok)[0]) == int(np.argmax(np.asarray(logits)[0]))
@@ -262,3 +262,57 @@ def test_host_sync_moves_o_batch_int32_not_logits(jax_cpu):
     st = eng.stats()
     assert st["host_sync_bytes_total"] == sum(r["sync_bytes"] for r in recs)
     assert st["host_sync_seconds_total"] > 0.0
+
+
+def _leaf(x):
+    return x
+
+
+def _hot_faults(n):
+    """Minor page faults taken by ``n`` calls of a tiny function made from
+    here: none, unless the caller's frame ends a data-stack chunk."""
+    import resource
+
+    before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+    for _ in range(n):
+        _leaf(1)
+    return resource.getrusage(resource.RUSAGE_THREAD).ru_minflt - before
+
+
+def _at_depth(depth, n):
+    return _hot_faults(n) if depth == 0 else _at_depth(depth - 1, n)
+
+
+@pytest.mark.parametrize("depths", [range(0, 150), range(150, 300)])
+def test_first_call_of_a_signature_gets_stack_room(depths):
+    """PR 28: CPython keeps a thread's frames in 16 KB chunks and unmaps
+    a chunk as the frame that opened it returns, so a tracer's small calls
+    made right at a chunk's end each cost a map, a page fault and an unmap
+    (the GPT-2 cell's kernel traces: 3.1 s or 16.6 s by the depth the step
+    was called at). Under ``_with_stack_room`` no depth has such calls;
+    ``DecodeFns._call`` gives that room to a signature's first call, the
+    one that traces, and to no other."""
+    from ray_tpu.serve.llm import decode
+
+    n = 2000
+    roomy = max(
+        decode._with_stack_room(_at_depth, (d, n), {}) for d in depths)
+    assert roomy < n // 20, (
+        f"{roomy} page faults in {n} tiny calls under the roomy frame")
+
+    fns = decode.DecodeFns("gpt", decode.get_family("gpt").default_config())
+    frames = []
+
+    def step(*args, **kwargs):
+        import sys
+
+        frames.append(sys._getframe(1).f_code.co_name)
+        return args, kwargs
+
+    sig = ("decode", (4,), (4, 8))
+    seen = []
+    fns.on_new_signature = seen.append
+    for _ in range(3):
+        assert fns._call(step, sig, 1, 2, sample=3) == ((1, 2), {"sample": 3})
+    assert frames == ["_with_stack_room", "_call", "_call"]
+    assert seen == [sig] and fns.signatures == {sig}

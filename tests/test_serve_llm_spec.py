@@ -249,13 +249,16 @@ def test_verify_adds_exactly_one_compile_kind(jax_cpu):
     _drain(eng, streams)
     after = eng.fns.signatures
     # fresh sampling configs are data, not signature: no new kinds, and
-    # the verify signature set is exactly what the first wave compiled
-    # (plain decode/prefill may still walk its pre-existing bucket
-    # ladder as contexts grow — that ladder predates speculation)
+    # the verify programs' TOKEN shapes (the frozen window) are exactly
+    # what the first wave compiled. Their block-table widths, like plain
+    # decode's and prefill's, may still walk the pre-existing bucket
+    # ladder as contexts grow or start shorter — that ladder predates
+    # speculation.
     assert {s[0] for s in after} <= {
         "prefill", "prefill_chunk", "decode", "verify"
     }
-    assert {s for s in after if s[0] == "verify"} == verify_sigs
+    assert {s[1] for s in after if s[0] == "verify"} == {
+        s[1] for s in verify_sigs}
 
 
 # --------------------------------------- EOS mid-window, exactly-once

@@ -190,7 +190,7 @@ def _parent_streams(family, mc, masters, prompts=PROMPTS, n=NEW_TOKENS):
         cache.ensure_capacity(i, len(seq), reserved=False)
         tokens = np.zeros((1, 16), np.int32)
         tokens[0, : len(seq)] = seq
-        logits, cache.k, cache.v = fns.prefill(
+        logits, cache.k, cache.v, _ = fns.prefill(
             masters, cache.k, cache.v, jnp.asarray(tokens),
             jnp.asarray([len(seq)], np.int32),
             jnp.asarray(cache.block_table(i, 16 // bs)[None, :]))
@@ -201,7 +201,7 @@ def _parent_streams(family, mc, masters, prompts=PROMPTS, n=NEW_TOKENS):
                 break
             seq.append(out[-1])
             cache.ensure_capacity(i, len(seq), reserved=False)
-            logits, cache.k, cache.v = fns.decode(
+            logits, cache.k, cache.v, _ = fns.decode(
                 masters, cache.k, cache.v,
                 jnp.asarray([out[-1]], np.int32),
                 jnp.asarray([len(seq) - 1], np.int32),
