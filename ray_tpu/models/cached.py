@@ -4,9 +4,14 @@ A served family runs three kinds of step against the paged K/V pool
 (ops/kv_cache.py; serve/llm drives them): ``prefill`` of a right-padded
 prompt chunk, ``decode_step`` of one token a row, ``verify_step`` of a
 speculative window. They are ONE step: tokens ``[B, S]`` at per-row true
-positions, every layer writing the chunk's K/V into its pool slice and
-attending over the paged context, then the head on some of the rows and
-a sampling epilogue. This file owns that step; a family's file
+positions, every layer writing the chunk's K/V rows into the pool at its
+own layer index and attending over the paged context there, then the head
+on some of the rows and a sampling epilogue. The pool is a buffer the step
+OWNS: the step programs donate it (serve/llm/decode.py ``_jit_named``),
+each layer scatters B x S rows into it where it stands and the kernel
+reads the whole pool at a layer index (``attend_layer`` says for which
+pools a layer's slab still goes out and back, inside the one pool). This
+file owns that step; a family's file
 (models/gpt.py, llama.py, lfm2_moe.py) holds only what is the family's
 own, in a ``CachedFamily``:
 
@@ -23,9 +28,9 @@ own, in a ``CachedFamily``:
   logits over ``[..., D]``);
 - ``stack``: the key of ``params`` that holds the layers. A tree whose
   leaves lead with the layer axis is a stack of like layers: one
-  ``lax.scan`` with the pool riding it as xs -> ys. A list of per-layer
-  trees is a stack of unlike layers: a Python loop, the pool indexed by
-  the attending layer's ordinal;
+  ``lax.scan`` that carries the pool and takes the layer's index as xs.
+  A list of per-layer trees is a stack of unlike layers: a Python loop,
+  the pool's layer the attending layer's ordinal;
 - ``open_state`` / ``close_state``: for a family that keeps per-sequence
   state BESIDE the pool (``state``, rows addressed by ``slots``), the
   step's working form of it, threaded through ``layer``, and the next
@@ -52,6 +57,7 @@ from ray_tpu.ops.kv_cache import write_kv
 from ray_tpu.ops.paged_attention import (
     decode_attention,
     prefill_attention,
+    reads_pool_in_place,
     resolve_backend,
 )
 from ray_tpu.ops.sampling import sample_tokens, verify_tokens
@@ -119,20 +125,53 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
     return Step(kind, pos, valid, block_tables, rows, start, slots)
 
 
-def attend_layer(step: Step, k_layer, v_layer, q, k, v, cfg):
-    """The cache side of one attention layer: the chunk's K/V go into the
-    layer's pool slice, then the attention call the kind asks for. Returns
-    (attention output [B, S, Hq * hd], k_layer', v_layer')."""
+def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
+    """The cache side of one attention layer, on the WHOLE pools and the
+    layer's index in them (an int32 scalar, traced under the scan): the
+    chunk's K/V rows are scattered into the pools at ``[layer, blk,
+    slot]``, then the attention call the kind asks for reads the pools at
+    that layer. Nothing slices a layer's slab out of a pool or writes one
+    back. Returns (attention output [B, S, Hq * hd], cache_k', cache_v').
+
+    The exception is a pool whose pages are not whole tiles
+    (``reads_pool_in_place``: heads of 64, 12 heads, a ``tp`` shard's 2).
+    XLA relays whatever the scatter and the kernel are handed of such a
+    pool, and handed the whole pool it relays the whole pool around the
+    layer loop (compiled for a v5e at GPT-2's widths: 6.4 GB of
+    temporaries; PERF.md, PR 29). There the layer's slab is taken out of
+    the pool, written and attended as one layer's pool, and put back where
+    it was, in place: what the scan did with the pool as xs -> ys, less
+    the second pool."""
+    if reads_pool_in_place(cache_k):
+        return _attend(step, cache_k, cache_v, layer, q, k, v, cfg)
+    # lax's own index ops: ``a[layer]`` / ``.at[layer].set`` wrap a traced
+    # index in bounds handling that costs two more passes over the slab
+    slabs = jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        (cache_k, cache_v))
+    attn, *slabs = _attend(step, *slabs, None, q, k, v, cfg)
+    cache_k, cache_v = jax.tree.map(
+        lambda a, slab: jax.lax.dynamic_update_index_in_dim(a, slab, layer, 0),
+        (cache_k, cache_v), tuple(slabs))
+    return attn, cache_k, cache_v
+
+
+def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
+    """``attend_layer`` on pools read at ``layer``, or (None) on one
+    layer's."""
     B, S = q.shape[:2]
     tables, backend = step.block_tables, cfg.attention_backend
     if step.kind == "decode":
-        k_layer, v_layer = write_kv(
-            k_layer, v_layer, k[:, 0], v[:, 0], step.rows, tables)
+        cache_k, cache_v = write_kv(
+            cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
+            layer=layer)
         attn = decode_attention(
-            q[:, 0], k_layer, v_layer, tables, step.rows, backend=backend)
-        return attn.reshape(B, S, -1), k_layer, v_layer
-    k_layer, v_layer = write_kv(
-        k_layer, v_layer, k, v, step.pos, tables, valid=step.valid)
+            q[:, 0], cache_k, cache_v, tables, step.rows, backend=backend,
+            layer=layer)
+        return attn.reshape(B, S, -1), cache_k, cache_v
+    cache_k, cache_v = write_kv(
+        cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
+        layer=layer)
     # The fresh-prompt shortcut attends over the UNQUANTIZED just-computed
     # k / v, the chunk alone (a prompt is prefilled once, at bucketed
     # shapes, where a kernel's grid buys nothing). Under a quantized pool
@@ -154,47 +193,41 @@ def attend_layer(step: Step, k_layer, v_layer, q, k, v, cfg):
         ).transpose(0, 2, 1, 3)
     else:
         attn = prefill_attention(
-            q, k_layer, v_layer, tables, jnp.where(step.valid, step.pos, 0),
-            backend=backend)
-    return attn.reshape(B, S, -1), k_layer, v_layer
-
-
-def _layer(pool, i: int):
-    return jax.tree.map(lambda a: a[i], pool)
-
-
-def _set_layer(pool, i: int, layer):
-    return jax.tree.map(lambda a, b: a.at[i].set(b), pool, layer)
+            q, cache_k, cache_v, tables, jnp.where(step.valid, step.pos, 0),
+            backend=backend, layer=layer)
+    return attn.reshape(B, S, -1), cache_k, cache_v
 
 
 def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
-    """``x`` through the stack. Returns (x, cache_k', cache_v', state)."""
+    """``x`` through the stack, every attending layer updating the pools
+    where they stand (the step programs donate them: serve/llm/decode.py).
+    Returns (x, cache_k', cache_v', state)."""
     if not isinstance(layers, list):
-
+        # the pools ride the scan as CARRY, never as xs -> ys: a layer
+        # scatters its rows into them and reads its pages out of them
         def body(carry, xs):
-            x, state = carry
-            lp, *kv = xs
+            x, state, *kv = carry
+            lp, layer = xs
 
             def attend(q, k, v):
-                attn, kv[0], kv[1] = attend_layer(step, *kv, q, k, v, cfg)
+                attn, kv[0], kv[1] = attend_layer(
+                    step, *kv, layer, q, k, v, cfg)
                 return attn
 
             x, state = fam.layer(x, lp, attend, step, state, cfg)
-            return (x, state), tuple(kv)
+            return (x, state, *kv), None
 
-        (x, state), (cache_k, cache_v) = jax.lax.scan(
-            body, (x, state), (layers, cache_k, cache_v))
+        (x, state, cache_k, cache_v), _ = jax.lax.scan(
+            body, (x, state, cache_k, cache_v),
+            (layers, jnp.arange(cache_k.shape[0], dtype=jnp.int32)))
         return x, cache_k, cache_v, state
 
     attended = 0  # the pool spans the attending layers only
 
     def attend(q, k, v):
         nonlocal cache_k, cache_v, attended
-        attn, k_layer, v_layer = attend_layer(
-            step, _layer(cache_k, attended), _layer(cache_v, attended),
-            q, k, v, cfg)
-        cache_k = _set_layer(cache_k, attended, k_layer)
-        cache_v = _set_layer(cache_v, attended, v_layer)
+        attn, cache_k, cache_v = attend_layer(
+            step, cache_k, cache_v, attended, q, k, v, cfg)
         attended += 1
         return attn
 
@@ -247,9 +280,11 @@ def steps(fam: CachedFamily):
     sink), ``block_tables [B, NB]``, the static ``cfg``, and by keyword
     ``sample`` (an ops/sampling.py pytree: sampling then runs inside the
     program and token ids come back, not logits), ``state`` and ``slots``.
-    All return ``(out, cache_k', cache_v', state')``. Shapes are static in
-    (batch, padded length, blocks a row), so the engine's bucketing bounds
-    the compiled set.
+    All return ``(out, cache_k', cache_v', state')``: the pools with the
+    step's rows written, and as the executor jits a step (pools donated)
+    the very buffers that came in. Shapes are static in (batch, padded
+    length, blocks a row), so the engine's bucketing bounds the compiled
+    set.
 
     ``prefill(..., tokens [B, S], lengths [B], block_tables, cfg,
     start=None)``: right-padded prompts (a padding row has length 1 and an

@@ -15,6 +15,10 @@ dynamic shapes, bounded compile cache). Host-side block accounting (the
 allocator, free lists, reuse) lives in serve/llm/kv_cache.py; these
 functions are pure array ops so the model decode paths (models/gpt.py,
 models/llama.py) can use them without depending on the serve layer.
+``write_kv`` also takes the WHOLE pools ``[n_layer, num_blocks, ...]`` and
+a ``layer=`` index, which is how the cached step writes (models/cached.py:
+the pool is donated to the step programs and updated where it stands);
+``copy_blocks`` and ``land_blocks`` donate the pools they are handed.
 
 Attention here is the XLA formulation, the CPU default and reference
 semantics: decode gathers blocks, masks and softmaxes; prefill does the
@@ -68,6 +72,7 @@ def write_kv(
     block_tables: jax.Array,
     *,
     valid: jax.Array | None = None,
+    layer: jax.Array | int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Scatter new keys/values into a layer's paged cache.
 
@@ -76,31 +81,33 @@ def write_kv(
     that are padding — their writes are redirected to the reserved garbage
     block 0, slot 0, keeping the scatter shape-static.
 
+    ``layer`` given: ``k_layer`` / ``v_layer`` are the WHOLE pools
+    ``[n_layer, num_blocks, ...]`` and the rows land at ``[layer, blk,
+    slot]``. That is how the cached step calls it (models/cached.py): the
+    pool is updated where it stands, B x S rows a layer.
+
     A ``QuantizedKV`` pool quantizes the incoming values at exactly this
     scatter's granularity — one amax per (token, kv-head) row — and lands
     data and scale with the same (blk, slot) indices, so incremental
     decode appends never touch (or re-quantize) previously written slots.
     """
-    block_size = k_layer.shape[1]
+    block_size = k_layer.shape[-3]
     blk, slot = physical_slots(positions, block_tables, block_size)
     if valid is not None:
         blk = jnp.where(valid, blk, 0)
         slot = jnp.where(valid, slot, 0)
+    at = (blk, slot) if layer is None else (layer, blk, slot)
     if isinstance(k_layer, QuantizedKV):
         kind = "int8" if k_layer.data.dtype == jnp.int8 else "fp8"
         kq, ks = quantize_kv(k, kind)
         vq, vs = quantize_kv(v, kind)
         k_layer = QuantizedKV(
-            k_layer.data.at[blk, slot].set(kq),
-            k_layer.scale.at[blk, slot].set(ks),
-        )
+            k_layer.data.at[at].set(kq), k_layer.scale.at[at].set(ks))
         v_layer = QuantizedKV(
-            v_layer.data.at[blk, slot].set(vq),
-            v_layer.scale.at[blk, slot].set(vs),
-        )
+            v_layer.data.at[at].set(vq), v_layer.scale.at[at].set(vs))
         return k_layer, v_layer
-    k_layer = k_layer.at[blk, slot].set(k.astype(k_layer.dtype))
-    v_layer = v_layer.at[blk, slot].set(v.astype(v_layer.dtype))
+    k_layer = k_layer.at[at].set(k.astype(k_layer.dtype))
+    v_layer = v_layer.at[at].set(v.astype(v_layer.dtype))
     return k_layer, v_layer
 
 
@@ -312,8 +319,10 @@ def _land_blocks(
 # (fetched from the object store by a decode replica) into the paged pool
 # across all layers in one fused op. Callers pad the block-id list to a
 # pow2 bucket with id 0 (the garbage block) and zero payload rows, so the
-# jitted shape set stays closed exactly like ``copy_blocks``.
-land_blocks = jax.jit(_land_blocks)
+# jitted shape set stays closed exactly like ``copy_blocks``. The pools are
+# donated, as they are to the step programs (serve/llm/decode.py): the
+# caller rebinds them from the result and the ones it passed are deleted.
+land_blocks = jax.jit(_land_blocks, donate_argnums=(0, 1))
 
 
 # Copy-on-write block duplication for the prefix cache: when a sequence
@@ -325,7 +334,8 @@ land_blocks = jax.jit(_land_blocks)
 # the garbage block onto itself is a no-op — so the jitted shape set
 # stays closed. Jitted once at module level: every engine in the process
 # shares the compiled programs (same discipline as decode.py's _jit_cache).
-copy_blocks = jax.jit(_copy_blocks)
+# The pools are donated (see ``land_blocks``).
+copy_blocks = jax.jit(_copy_blocks, donate_argnums=(0, 1))
 
 
 def paged_attention(

@@ -16,6 +16,16 @@ offsets and the verify windows: decode is the S = 1 case of the
 multi-token kernel (``paged_attention_pallas`` reshapes and calls
 ``paged_prefill_attention_pallas``).
 
+THE POOL IS READ WHERE IT STANDS: the cached step (models/cached.py) hands
+every entry point here the WHOLE pools ``[n_layer, num_blocks, block_size,
+n_kv_head, hd]`` and ``layer=``, an int32 scalar that reaches the kernel
+as one more scalar-prefetch word; a page is ``pool[layer, id]`` and no
+``pool[layer]`` exists outside the kernel. That holds where the kernel
+copies pages itself (next paragraph); ``reads_pool_in_place`` says for
+which pools, and the step takes any other pool's slab out and puts it
+back. Without ``layer=`` the pool is one layer's
+``[num_blocks, block_size, n_kv_head, hd]``, as everywhere below.
+
 Design (same playbook as ``ops/attention.py``'s flash kernels):
 
 - TILING THE CHIP'S COMPILER ACCEPTS: Mosaic requires the last two
@@ -149,6 +159,29 @@ _VMEM_CAP = 48 * 1024 * 1024
 _VMEM_DEFAULT = 16 * 1024 * 1024
 
 
+def _whole_tiles(Hkv: int, hd: int) -> bool:
+    """Whether a page's ``[Hkv, hd]`` is made of whole (8, 128) tiles: the
+    kernel then copies pages out of the pool itself, and XLA keeps such a
+    pool at rest in the layout the kernel reads. A test of the shape, not
+    of a model."""
+    return hd % 128 == 0 and Hkv % 8 == 0
+
+
+def reads_pool_in_place(pool) -> bool:
+    """Whether the step may hand the kernel the WHOLE pool and a layer
+    index (``_whole_tiles`` of the heads one device holds: a ``tp`` mesh
+    splits them). For any other pool XLA relays what the kernel is handed
+    from the layout the pool rests in (heads of 64, 12 heads, a ``tp``
+    shard's 2: PERF.md, PR 27 and 29; ROADMAP S5a), and handed the whole
+    pool it relays the whole pool, padded to the tiles, around the layer
+    loop. Such a pool's layers go through the kernel one slab at a time
+    (models/cached.py ``attend_layer``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = 1 if mesh.empty else mesh.shape.get("tp", 1)
+    Hkv, hd = pool.shape[-2:]
+    return _whole_tiles(Hkv // tp, hd)
+
+
 def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
     """``(P, vmem_bytes)``: the pages of one compute block and what the
     call then keeps in VMEM, from the shapes alone (``page`` is a page's
@@ -191,11 +224,14 @@ def _page_walk_kernel(
     tables_ref,   # scalar prefetch: [B, NB] int32 block tables
     qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
     qmin_ref,     # scalar prefetch: [B, nqb] int32 floor per q-block
+    layer_ref,    # scalar prefetch: [1] int32, the pool's layer (the index
+                  # maps read it; the body never does)
     q_ref,        # [1, Hkv, R, hd] — this (b, q-block)'s rows for every kv
                   # head, pre-scaled; row r = query (r // G) of the block,
                   # group member (r % G); R = q_block * G
     pos_ref,      # [1, R, 1] int32 — true position of each row's query
-    k_ref,        # [1, bs, Hkv, hd] — one physical KV page, all kv heads
+    k_ref,        # [1, bs, Hkv, hd] — one physical KV page of the layer,
+                  # all kv heads (the layer axis is squeezed away)
     v_ref,        # [1, bs, Hkv, hd]
     *rest,        # quantized: (ks_ref, vs_ref, o_ref, scratch...) — the
                   # [1, bs, Hkv] per-(slot, head) f32 scale pages ride the
@@ -207,9 +243,10 @@ def _page_walk_kernel(
 ):
     """The walk for a pool the compute-block kernel cannot copy from: grid
     ``(B, q_blocks, NB)``, ONE page a grid step fetched by its BlockSpec
-    (the index map reads ``tables[b, i]``; a page the q-block cannot attend
-    re-issues entry 0's index, which Pallas dedupes into no DMA, and
-    ``@pl.when`` skips its compute). See ``_walk_pages``."""
+    (the index map reads the layer and ``tables[b, i]``; a page the
+    q-block cannot attend re-issues entry 0's index, which Pallas dedupes
+    into no DMA, and ``@pl.when`` skips its compute). See
+    ``_walk_pages``."""
     from jax.experimental import pallas as pl
 
     if quantized:
@@ -298,7 +335,7 @@ def _page_walk_kernel(
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def _walk_pages(qf, pos_rows, tables, qmax, qmin, k_layer, v_layer, *,
+def _walk_pages(qf, pos_rows, tables, qmax, qmin, layer, k_pool, v_pool, *,
                 R, window, interpret):
     """``paged_attention`` for a pool whose ``[Hkv, hd]`` is not made of
     whole (8, 128) tiles (heads of 64, 12 heads, a ``tp`` shard's 2). Mosaic
@@ -308,29 +345,30 @@ def _walk_pages(qf, pos_rows, tables, qmax, qmin, k_layer, v_layer, *,
     pool a layer than XLA already makes for such a pool (measured: PERF.md,
     PR 27; ROADMAP S5a). Until the pool is stored lane-dense these shapes
     keep the one-page walk: a BlockSpec fetches ``(1, bs, Hkv, hd)`` — full
-    extent in its last two dimensions — a grid step."""
+    extent in its last two dimensions — a grid step, at the pool's
+    ``[layer, page]`` (the layer a squeezed leading block dimension)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    quantized = isinstance(k_layer, QuantizedKV)
+    quantized = isinstance(k_pool, QuantizedKV)
     if quantized:
-        k_data, k_scale = k_layer.data, k_layer.scale
-        v_data, v_scale = v_layer.data, v_layer.scale
+        k_data, k_scale = k_pool.data, k_pool.scale
+        v_data, v_scale = v_pool.data, v_pool.scale
     else:
-        k_data, v_data = k_layer, v_layer
+        k_data, v_data = k_pool, v_pool
     B, Hkv, rows_all, hd = qf.shape
-    bs = k_data.shape[1]
+    bs = k_data.shape[2]
     NB = tables.shape[1]
     nqb = qmax.shape[1]
     q = qf
 
-    def q_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+    def q_map(b, j, i, *refs):
         return (b, 0, j, 0)
 
-    def pos_map(b, j, i, tables_ref, qmax_ref, qmin_ref):
+    def pos_map(b, j, i, *refs):
         return (b, j, 0)
 
-    def _page(b, j, i, tables_ref, qmax_ref, qmin_ref):
+    def _page(b, j, i, tables_ref, qmax_ref, qmin_ref, layer_ref):
         # Walk the sequence's block table. Pages the q-block cannot
         # attend (wholly past its frontier, or — windowed — wholly below
         # its floor) re-issue entry 0's index: consecutive identical
@@ -344,27 +382,27 @@ def _walk_pages(qf, pos_rows, tables, qmax, qmin, k_layer, v_layer, *,
         return jnp.where(needed, tables_ref[b, i], tables_ref[b, 0])
 
     def kv_map(*args):
-        return (_page(*args), 0, 0, 0)
+        return (args[-1][0], _page(*args), 0, 0, 0)
 
     def kv_scale_map(*args):
         # a scale page is fetched iff its K/V page is
-        return (_page(*args), 0, 0)
+        return (args[-1][0], _page(*args), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Hkv, R, hd), q_map),
         pl.BlockSpec((1, R, 1), pos_map),
-        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
-        pl.BlockSpec((1, bs, Hkv, hd), kv_map),
+        pl.BlockSpec((None, 1, bs, Hkv, hd), kv_map),
+        pl.BlockSpec((None, 1, bs, Hkv, hd), kv_map),
     ]
-    operands = [tables, qmax, qmin, qf, pos_rows, k_data, v_data]
+    operands = [tables, qmax, qmin, layer, qf, pos_rows, k_data, v_data]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
-            pl.BlockSpec((1, bs, Hkv), kv_scale_map),
+            pl.BlockSpec((None, 1, bs, Hkv), kv_scale_map),
+            pl.BlockSpec((None, 1, bs, Hkv), kv_scale_map),
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, nqb, NB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
@@ -394,6 +432,7 @@ def _paged_attention_kernel(
     tables_ref,   # scalar prefetch: [B, NB] int32 block tables
     qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
     qmin_ref,     # scalar prefetch: [B, nqb] int32 floor per q-block
+    layer_ref,    # scalar prefetch: [1] int32, the pool's layer
     q_ref,        # [1, Hkv, R, hd] — this (b, q-block)'s rows for every kv
                   # head, pre-scaled; row r = query (r // G) of the block,
                   # group member (r % G); R = q_block * G
@@ -403,9 +442,10 @@ def _paged_attention_kernel(
                   # token) scales, gathered through its table; else (k_hbm,
                   # v_hbm, o_ref, ...). Then the scratch: (k_buf, v_buf,
                   # sems, m, l, acc).
-                  # k_hbm / v_hbm: the whole pool, in HBM, a page
-                  # [bs, Hkv, hd]; k_buf / v_buf: the two-slot VMEM
-                  # scratch of a block, [2, P * bs, Hkv, hd]
+                  # k_hbm / v_hbm: the whole pool, every layer, in HBM,
+                  # a page [bs, Hkv, hd] at [layer, id]; k_buf / v_buf:
+                  # the two-slot VMEM scratch of a block,
+                  # [2, P * bs, Hkv, hd]
     block_size: int,
     pages: int,
     window: int | None,
@@ -420,6 +460,7 @@ def _paged_attention_kernel(
 
     b = pl.program_id(0)
     j = pl.program_id(1)
+    layer = layer_ref[0]
     n_head, rows, hd = q_ref.shape[1:]
     n_entries = tables_ref.shape[1]
     bs, T = block_size, pages * block_size
@@ -452,7 +493,7 @@ def _paged_attention_kernel(
             dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
             for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
                 copy = pltpu.make_async_copy(
-                    pool.at[src], buf.at[slot, dst], sems.at[slot]
+                    pool.at[layer, src], buf.at[slot, dst], sems.at[slot]
                 )
                 copy.start() if op == "start" else copy.wait()
             return carry
@@ -546,6 +587,15 @@ def _paged_attention_kernel(
     o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
+def _as_pools(k_layer, v_layer, layer):
+    """``(k_pool, v_pool, layer)``, the layer an int32 scalar: one layer's
+    slab (``layer`` None) is a pool of that one layer, read at 0."""
+    if layer is None:
+        k_layer, v_layer = jax.tree.map(lambda a: a[None], (k_layer, v_layer))
+        layer = 0
+    return k_layer, v_layer, jnp.asarray(layer, jnp.int32)
+
+
 def paged_prefill_attention_pallas(
     q: jax.Array,
     k_layer: jax.Array,
@@ -555,6 +605,7 @@ def paged_prefill_attention_pallas(
     *,
     scale: float | None = None,
     window: int | None = None,
+    layer: jax.Array | int | None = None,
     q_block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -569,6 +620,12 @@ def paged_prefill_attention_pallas(
     ``[num_blocks, block_size, H_kv, hd]``, ``block_tables`` ``[B, NB]``
     int32 padded with the garbage block 0. Returns ``[B, S, H_q, hd]``
     in q.dtype.
+
+    ``layer`` given (an int32 scalar, traced or not): ``k_layer`` /
+    ``v_layer`` are the WHOLE pools ``[n_layer, num_blocks, ...]`` and the
+    kernel reads its pages at that layer: the index is one more scalar
+    word to the kernel, and no ``pool[layer]`` exists outside it. That is
+    how the cached step calls it. Without it the pool is one layer's.
 
     The grid is ``(B, q_blocks)``: per (b, q-block) the flash running
     softmax loops over the compute blocks of the sequence's block table
@@ -590,6 +647,8 @@ def paged_prefill_attention_pallas(
 
     if interpret is None:
         interpret = pallas_interpret()
+    k_layer, v_layer, layer = _as_pools(k_layer, v_layer, layer)
+    layer = layer.reshape(1)
     quantized = isinstance(k_layer, QuantizedKV)
     if quantized:
         k_data, k_scale = k_layer.data, k_layer.scale
@@ -597,7 +656,7 @@ def paged_prefill_attention_pallas(
     else:
         k_data, v_data = k_layer, v_layer
     B, S, Hq, hd = q.shape
-    _, bs, Hkv, _ = k_data.shape
+    _, _, bs, Hkv, _ = k_data.shape
     if Hq % Hkv:
         raise ValueError(
             f"query heads ({Hq}) must be a multiple of KV heads ({Hkv})"
@@ -639,9 +698,9 @@ def paged_prefill_attention_pallas(
     qmax = jnp.max(posb, axis=2).astype(jnp.int32)
     qmin = jnp.min(posb, axis=2).astype(jnp.int32)
 
-    if hd % 128 or Hkv % 8:
+    if not _whole_tiles(Hkv, hd):
         out = _walk_pages(
-            qf, pos_rows, tables, qmax, qmin, k_layer, v_layer,
+            qf, pos_rows, tables, qmax, qmin, layer, k_layer, v_layer,
             R=R, window=window, interpret=interpret,
         )
         out = out.reshape(B, Hkv, Sp, G, hd).transpose(0, 2, 1, 3, 4)
@@ -652,20 +711,20 @@ def paged_prefill_attention_pallas(
     )
     n_blocks = -(-NB // pages)
 
-    def q_map(b, j, tables_ref, qmax_ref, qmin_ref):
+    def q_map(b, j, *refs):
         return (b, 0, j, 0)
 
-    def pos_map(b, j, tables_ref, qmax_ref, qmin_ref):
+    def pos_map(b, j, *refs):
         return (b, j, 0)
 
-    def scale_map(b, j, tables_ref, qmax_ref, qmin_ref):
+    def scale_map(b, j, *refs):
         return (b, 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Hkv, R, hd), q_map),
         pl.BlockSpec((1, R, 1), pos_map),
     ]
-    operands = [tables, qmax, qmin, qf, pos_rows]
+    operands = [tables, qmax, qmin, layer, qf, pos_rows]
     if quantized:
         # The scale planes' pages ([bs, Hkv] f32: no whole tile) cannot be
         # copied out of HBM by the kernel: each row's scales are gathered
@@ -675,7 +734,7 @@ def paged_prefill_attention_pallas(
         padded = jnp.pad(tables, ((0, 0), (0, n_blocks * pages - NB)))
 
         def row_scales(scale):
-            return scale[padded].reshape(
+            return scale[layer[0], padded].reshape(
                 B, n_blocks, pages * bs, Hkv
             ).transpose(0, 1, 3, 2)
 
@@ -686,7 +745,7 @@ def paged_prefill_attention_pallas(
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands += [k_data, v_data]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, nqb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
@@ -727,6 +786,7 @@ def paged_attention_pallas(
     positions: jax.Array,
     *,
     scale: float | None = None,
+    layer: jax.Array | int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Single-token decode attention straight off the paged KV pool: the
@@ -739,28 +799,37 @@ def paged_attention_pallas(
     """
     return paged_prefill_attention_pallas(
         q[:, None], k_layer, v_layer, block_tables, positions[:, None],
-        scale=scale, interpret=interpret,
+        scale=scale, layer=layer, interpret=interpret,
     )[:, 0]
 
 
-def _over_heads(kernel, q, k_layer, v_layer, block_tables, positions):
-    """Run ``kernel`` on q ``[B, S, H_q, hd]``; under a mesh whose ``tp``
-    axis is wider than 1 (``ShardedExecutor`` sets it around its steps),
-    inside a ``shard_map`` over the head axis — GSPMD cannot partition the
-    compiled kernel, and would all-gather the pool to run it whole."""
+def _over_heads(kernel, q, k_pool, v_pool, block_tables, positions, layer):
+    """Run ``kernel`` on q ``[B, S, H_q, hd]`` and the whole pools at
+    ``layer``; under a mesh whose ``tp`` axis is wider than 1
+    (``ShardedExecutor`` sets it around its steps), inside a ``shard_map``
+    over the head axis — GSPMD cannot partition the compiled kernel, and
+    would all-gather the pool to run it whole."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.shape.get("tp", 1) == 1:
-        return kernel(q, k_layer, v_layer, block_tables, positions)
+        return kernel(q, k_pool, v_pool, block_tables, positions, layer)
     heads = P(None, None, "tp", None)
-    pool = heads
-    if isinstance(k_layer, QuantizedKV):
-        pool = QuantizedKV(heads, P(None, None, "tp"))
+    pool = P(None, None, None, "tp", None)
+    if isinstance(k_pool, QuantizedKV):
+        pool = QuantizedKV(pool, P(None, None, None, "tp"))
     return jax.shard_map(
         kernel,
-        in_specs=(heads, pool, pool, P(), P()),
+        in_specs=(heads, pool, pool, P(), P(), P()),
         out_specs=heads,
         check_vma=False,
-    )(q, k_layer, v_layer, block_tables, positions)
+    )(q, k_pool, v_pool, block_tables, positions, layer)
+
+
+def _at_layer(k_pool, v_pool, layer):
+    """The XLA formulations (the CPU default and the reference semantics;
+    no benchmark cell runs them) index the layer first."""
+    if layer is None:
+        return k_pool, v_pool
+    return jax.tree.map(lambda a: a[layer], (k_pool, v_pool))
 
 
 def decode_attention(
@@ -772,22 +841,24 @@ def decode_attention(
     *,
     scale: float | None = None,
     backend: str = "auto",
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Backend dispatcher for decode attention — the one entry point the
     model decode steps call. ``backend`` is the ``attention_backend`` knob
     threaded from ``EngineConfig`` through the model config; "auto" picks
     the Pallas kernel on TPU and the XLA formulation elsewhere. Both
     backends share the exact call signature and numerics contract
-    (tests/test_paged_attention.py)."""
+    (tests/test_paged_attention.py). ``layer``: see ``prefill_attention``."""
     if resolve_backend(backend) == "pallas":
         return prefill_attention(
             q[:, None], k_layer, v_layer, block_tables, positions[:, None],
-            scale=scale, backend="pallas",
+            scale=scale, backend="pallas", layer=layer,
         )[:, 0]
     from ray_tpu.ops.kv_cache import paged_attention as _xla_paged_attention
 
     return _xla_paged_attention(
-        q, k_layer, v_layer, block_tables, positions, scale=scale
+        q, *_at_layer(k_layer, v_layer, layer), block_tables, positions,
+        scale=scale,
     )
 
 
@@ -801,6 +872,7 @@ def prefill_attention(
     scale: float | None = None,
     backend: str = "auto",
     window: int | None = None,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Backend dispatcher for multi-token paged attention — the one entry
     point the model prefill, chunked-prefill, and verify paths call.
@@ -809,19 +881,23 @@ def prefill_attention(
     jit-cache key, zero new compile kinds); both backends share the exact
     call signature and numerics contract (tests/test_paged_attention.py).
     ``window`` selects sliding-window attention (see
-    ``paged_prefill_attention_pallas``)."""
+    ``paged_prefill_attention_pallas``). ``layer`` given: ``k_layer`` /
+    ``v_layer`` are the whole pools ``[n_layer, num_blocks, ...]``, read at
+    that layer; the kernel takes the index as data and never sees a
+    ``pool[layer]``."""
     if resolve_backend(backend) == "pallas":
+        k_pool, v_pool, layer = _as_pools(k_layer, v_layer, layer)
         return _over_heads(
-            functools.partial(
-                paged_prefill_attention_pallas, scale=scale, window=window
-            ),
-            q, k_layer, v_layer, block_tables, positions,
+            lambda q, k, v, tables, pos, layer: paged_prefill_attention_pallas(
+                q, k, v, tables, pos, scale=scale, window=window,
+                layer=layer),
+            q, k_pool, v_pool, block_tables, positions, layer,
         )
     from ray_tpu.ops.kv_cache import (
         paged_prefill_attention as _xla_paged_prefill,
     )
 
     return _xla_paged_prefill(
-        q, k_layer, v_layer, block_tables, positions,
+        q, *_at_layer(k_layer, v_layer, layer), block_tables, positions,
         scale=scale, window=window,
     )

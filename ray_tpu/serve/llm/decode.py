@@ -2,8 +2,9 @@
 
 One `DecodeFns` per engine: it binds the (static) model config into the
 family's prefill / decode / verify steps (models/cached.py, through the
-family's file), jits them once, and records every distinct input-shape
-signature it is called with. Because jit caches by shape, the signature
+family's file), jits them once with the K/V pools donated
+(``_jit_named``), and records every distinct input-shape signature it is
+called with. Because jit caches by shape, the signature
 set size IS the number of compiled programs — the engine exposes it so
 tests (and ops dashboards) can assert the bucketing keeps it bounded.
 """
@@ -132,12 +133,21 @@ def _compiler_options(platform: str | None) -> dict | None:
 def _jit_named(fn, model_cfg, options):
     """``jax.jit`` of ``fn`` with the config bound, under ``fn``'s own name:
     a bare ``functools.partial`` has none, and its program would be
-    ``jit__unknown`` in every compiler dump and profiler trace."""
+    ``jit__unknown`` in every compiler dump and profiler trace.
+
+    The pools ``cache_k`` and ``cache_v`` are DONATED (both leaves of a
+    ``QuantizedKV`` pool): the program's output pools are its input
+    buffers, updated where they stand (models/cached.py), and the arrays
+    the caller passed are deleted by the call. A caller rebinds its pools
+    from the outputs, as the executor's ``_run`` does, and keeps no other
+    reference across a step. Nothing else is donated: not the weights,
+    and not ``state``, of which ``executor.counter_state()`` hands out a
+    reference that is read after later steps."""
     import jax
 
     bound = functools.partial(fn, cfg=model_cfg)
     bound.__name__ = fn.__name__
-    return jax.jit(bound, compiler_options=options)
+    return jax.jit(bound, donate_argnums=(1, 2), compiler_options=options)
 
 
 def _with_stack_room(fn, args, kwargs):
@@ -177,7 +187,9 @@ class DecodeFns:
     and decode(params, cache_k, cache_v, tokens, positions, block_tables)
     (``verify`` is None for a family without a verify step), each
     returning (out, cache_k, cache_v, state),
-    jitted with the model config closed over as a static value. Compiled
+    jitted with the model config closed over as a static value and the
+    pools donated: the ``cache_k`` / ``cache_v`` passed in are deleted by
+    the call, the returned ones are the same buffers updated. Compiled
     programs are shared process-wide per (family, config, compiler settings); the
     signature set below is per-instance, so each engine reports the
     shapes IT exercised. ``platform`` is that of the devices the steps

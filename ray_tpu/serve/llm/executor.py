@@ -90,7 +90,8 @@ class ModelExecutor:
 
     - ``prefill(tokens, lengths, tables, sample=)`` — monolithic
       whole-prompt prefill; returns on-device [B] sampled token ids and
-      updates ``cache.k``/``cache.v`` in place.
+      updates ``cache.k``/``cache.v`` in place (the pools are donated to
+      every step program and rebound from its outputs: ``_run``).
     - ``prefill_chunk(tokens, lengths, starts, tables, sample=)`` — the
       chunked/prefix path at true positions.
     - ``decode_step(tokens, positions, tables, sample=)`` — one decode
@@ -110,8 +111,9 @@ class ModelExecutor:
     step's ``executor.dispatch`` phase, and book their two host phases
     into ``phases`` (``_run``). ``cache.state`` (decode.py
     ``Family.init_state``: what a family keeps per sequence beside the
-    pool; None for the others) is passed through every step and updated
-    in place like ``cache.k`` / ``cache.v``; the prefill and decode methods
+    pool; None for the others) is passed through every step and rebound
+    from its outputs like ``cache.k`` / ``cache.v``, but never donated;
+    the prefill and decode methods
     take ``slots=``, each row's slot in it (None: left out of the call).
     """
 
@@ -283,8 +285,17 @@ class ModelExecutor:
         ``executor.dispatch`` is the jitted call until it returns, under
         the attributes the engine gives in ``span`` (``kind``;
         ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
-        ``cache.v`` and ``cache.state`` (None where the family keeps
-        none) in place."""
+        ``cache.v`` in place, literally: the step programs donate both
+        pools (decode.py ``_jit_named``), so the arrays passed in are
+        deleted by the call and the ones bound here are the same device
+        buffers with the step's rows written. Under lag-1 dispatch the
+        pool handed to step n + 1 is step n's output, still being
+        computed: donating a pending buffer is ordinary stream order.
+        Whatever else reads the pool (``export_blocks``, ``copy_blocks``,
+        ``land_blocks``) takes ``cache.k`` / ``cache.v`` as they stand
+        when it runs and holds nothing across a step. ``cache.state``
+        (None where the family keeps none) is rebound too but NOT
+        donated: ``counter_state()`` hands out a reference to it."""
         with obs.phase(self.phases, "executor.stage"):
             dev = [self._dev(a) for a in arrays]
             staged = {k: self._dev(v) for k, v in staged.items()
@@ -322,7 +333,9 @@ class ModelExecutor:
         The (src, dst) list pads to a pow2 bucket with (0, 0) — copying
         the garbage block onto itself — so the jitted shape set stays
         closed. Runs sharded for free: the pool arrays carry their mesh
-        sharding and block indices are head-axis-invariant."""
+        sharding and block indices are head-axis-invariant. The pools are
+        donated here as to the steps, so the first COW does not stand a
+        second pool beside the one the steps update in place."""
         if not pairs:
             return
         from ray_tpu.ops.kv_cache import copy_blocks
@@ -386,7 +399,7 @@ class ModelExecutor:
         ONE batched transfer per call. On a mesh the committed inputs
         re-shard along kv heads automatically (same GSPMD inference as
         every other call), so both executors serve promotions through
-        this one method."""
+        this one method. The pools are donated, as to the steps."""
         if not block_ids:
             return
         import jax

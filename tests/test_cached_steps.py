@@ -169,3 +169,222 @@ def test_decode_is_the_one_token_case(families, family, as_kind):
         np.testing.assert_array_equal(a[0, 1:], b[0, 1:])
         np.testing.assert_allclose(a[:, 1:], b[:, 1:], atol=1e-6)
     assert np.abs(k[:, 1:]).sum() > 0
+
+
+# ---------------------------------------------------------------------------
+# The pool is updated where it stands (ISSUE 29): the whole pools and a layer
+# index go down to ``write_kv`` and the attention call. What a step leaves in
+# them is, bit for bit, what PR 28's walk left: every layer on its own slab,
+# the pool rebuilt from the slabs.
+
+
+def _walk_by_slabs(fam, x, layers, cache_k, cache_v, step, state, cfg):
+    """PR 28's ``_walk``: the pools as xs -> ys of the layer scan (a list
+    of layers: the slab sliced out at the attending ordinal and set back),
+    each layer written and attended as ONE layer's pool."""
+    import jax
+
+    from ray_tpu.models import cached
+
+    if not isinstance(layers, list):
+
+        def body(carry, xs):
+            x, state = carry
+            lp, *kv = xs
+
+            def attend(q, k, v):
+                attn, kv[0], kv[1] = cached._attend(
+                    step, *kv, None, q, k, v, cfg)
+                return attn
+
+            x, state = fam.layer(x, lp, attend, step, state, cfg)
+            return (x, state), tuple(kv)
+
+        (x, state), (cache_k, cache_v) = jax.lax.scan(
+            body, (x, state), (layers, cache_k, cache_v))
+        return x, cache_k, cache_v, state
+    pools, attended = [cache_k, cache_v], 0
+
+    def attend(q, k, v):
+        nonlocal attended
+        attn, *slabs = cached._attend(
+            step, *jax.tree.map(lambda a: a[attended], tuple(pools)), None,
+            q, k, v, cfg)
+        pools[:] = jax.tree.map(
+            lambda a, slab: a.at[attended].set(slab), tuple(pools),
+            tuple(slabs))
+        attended += 1
+        return attn
+
+    for lp in layers:
+        x, state = fam.layer(x, lp, attend, step, state, cfg)
+    return x, *pools, state
+
+
+# pages of whole (8, 128) tiles take the whole-pool path, the tiny presets'
+# pages (2 heads of 16) the slab path: ops/paged_attention.py
+# ``reads_pool_in_place``
+TILES = {"gpt": {"n_head": 8, "d_model": 1024},
+         "llama": {"n_head": 8, "n_kv_head": 8, "d_model": 1024},
+         "lfm2_moe": {"n_head": 8, "n_kv_head": 8, "head_dim": 128}}
+
+
+def _random_pool(rng, shape, quant):
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.quantization import QuantizedKV
+
+    if quant is None:
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    return QuantizedKV(
+        jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+        jnp.asarray(rng.uniform(0.01, 0.1, shape[:-1]), jnp.float32))
+
+
+@pytest.mark.parametrize("quant", [None, "int8"])
+@pytest.mark.parametrize("pages", ["tiny", "tiles"])
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("family", ["gpt", "llama", "lfm2_moe"])
+def test_step_leaves_the_pool_the_slab_walk_left(
+        families, monkeypatch, family, kind, pages, quant):
+    """After each kind of step, on a pool that held something everywhere:
+    logits, pools (data and scales) and state equal the slab walk's bit
+    for bit; the rows written are the step's real tokens' and nothing
+    else; every block outside the rows' tables but block 0, the one sink,
+    is as it was."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import cached
+    from ray_tpu.ops.paged_attention import reads_pool_in_place
+
+    fam, cfg, params, _, seqs = families[family]
+    if kind == "verify" and fam.verify_step is None:
+        return
+    cfg = dataclasses.replace(cfg, quantization=quant)
+    if pages == "tiles":
+        cfg = dataclasses.replace(cfg, **TILES[family])
+        params = fam.init(jax.random.PRNGKey(2), cfg)
+
+    def run(walk):
+        rng = np.random.default_rng(29)
+        served = _Served(fam, cfg, params)
+        served.k = _random_pool(rng, served.k.shape, quant)
+        served.v = _random_pool(rng, served.k.shape, quant)
+        assert reads_pool_in_place(served.k) == (pages == "tiles")
+        with monkeypatch.context() as m:
+            if walk is not None:
+                m.setattr(cached, "_walk", walk)
+            cut = [8, 5]
+            if kind == "fresh":
+                before = served.k, served.v
+                out = served.prefill(seqs)
+                written = [range(len(s)) for s in seqs]
+            elif kind == "chunk":
+                served.prefill([s[:c] for s, c in zip(seqs, cut)])
+                before = served.k, served.v
+                out = served.prefill(
+                    [s[c:] for s, c in zip(seqs, cut)], start=cut)
+                written = [range(c, len(s)) for s, c in zip(seqs, cut)]
+            elif kind == "decode":
+                served.prefill([s[:-1] for s in seqs])
+                before = served.k, served.v
+                out = served.decode([s[-1] for s in seqs],
+                                    [len(s) - 1 for s in seqs])
+                written = [[len(s) - 1] for s in seqs]
+            else:
+                W, draft_len = 4, [3, 1]
+                starts = [len(s) - 1 - d for s, d in zip(seqs, draft_len)]
+                served.prefill([s[:n] for s, n in zip(seqs, starts)])
+                before = served.k, served.v
+                windows = np.zeros((2, W), np.int32)
+                for r, (s, n, d) in enumerate(zip(seqs, starts, draft_len)):
+                    windows[r, :d + 1] = s[n:n + d + 1]
+                out = served.verify(windows, starts, draft_len)
+                written = [range(n, n + d + 1)
+                           for n, d in zip(starts, draft_len)]
+        return out, before, (served.k, served.v), served.state, written
+
+    got, want = run(None), run(_walk_by_slabs)
+    np.testing.assert_array_equal(got[0], want[0])
+    for a, b in zip(jax.tree.leaves((got[1:4])), jax.tree.leaves(want[1:4])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # what changed in the pool: the rows written, block 0, and no more
+    _, before, after, _, written = got
+    tables = np.asarray(_Served(fam, cfg, params).tables)
+    for was, now in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        was, now = np.asarray(was), np.asarray(now)
+        changed = np.zeros(now.shape[:3], bool)  # [layer, block, slot]
+        changed[:, 0] = True
+        for r, positions in enumerate(written):
+            for p in positions:
+                changed[:, tables[r, p // BS], p % BS] = True
+        np.testing.assert_array_equal(now[~changed], was[~changed])
+        rows = changed.copy()
+        rows[:, 0] = False
+        assert (now[rows] != was[rows]).any(axis=tuple(
+            range(1, now[rows].ndim))).all()
+
+
+def _platform_donates():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.zeros((8,))
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama", "lfm2_moe"])
+def test_executor_donates_the_pools_and_nothing_else(jax_cpu, family):
+    """Every kind of step, ``copy_blocks`` and ``land_blocks`` consume the
+    pool arrays the executor held (where the platform donates: the CPU
+    does) and rebind ``cache.k`` / ``cache.v``; ``export_blocks`` reads
+    the pools as they stand; the weights and ``state`` are not donated: a
+    ``counter_state()`` taken before the steps still reads after them."""
+    import jax
+
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    engine = LLMEngine(EngineConfig(model=family, num_blocks=33),
+                       auto_step=False)
+    ex, cache = engine.executor, engine.cache
+    donates = _platform_donates()
+    held = ex.counter_state()
+    assert (held is None) == (family != "lfm2_moe")
+    params = jax.tree.leaves(ex.params)
+    B, NB, S, W = 2, 4, 8, 3
+    i32 = lambda *shape: np.ones(shape, np.int32)
+    tables = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+    slots = i32(B) if cache.state is not None else None
+    steps = [
+        lambda: ex.prefill(i32(B, S), 5 * i32(B), tables, slots=slots),
+        lambda: ex.prefill_chunk(i32(B, S), 3 * i32(B), 5 * i32(B), tables,
+                                 slots=slots),
+        lambda: ex.decode_step(i32(B), 8 * i32(B), tables, slots=slots),
+    ]
+    if ex.fns._verify is not None:
+        steps.append(lambda: ex.verify_step(
+            i32(B, W), 9 * i32(B), i32(B), tables))
+    steps += [
+        lambda: ex.copy_blocks([(1, 9), (5, 10), (2, 11)]),
+        lambda: ex.land_blocks([12], *ex.export_blocks([1])),
+    ]
+    for step in steps:
+        before = jax.tree.leaves((cache.k, cache.v))
+        step()
+        after = jax.tree.leaves((cache.k, cache.v))
+        jax.block_until_ready(after)
+        assert all(a.is_deleted() == donates for a in before)
+        assert not any(a.is_deleted() for a in after + params)
+    k, v = ex.export_blocks([1, 9, 12])
+    for got in jax.tree.leaves((k, v)):  # the COW clone and the landing
+        np.testing.assert_array_equal(got[:, 0], got[:, 1])
+        np.testing.assert_array_equal(got[:, 0], got[:, 2])
+        assert np.abs(got[:, 0].astype(np.float32)).sum() > 0
+    if held is not None:
+        assert not any(a.is_deleted() for a in jax.tree.leaves(held))
+        was, now = ex.read_counters(held), ex.read_counters(
+            ex.counter_state())
+        assert was != now and set(was) == set(now)
+    engine.shutdown()

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 import re
 
@@ -242,6 +243,161 @@ def test_sharded_decode_step_compiles_partitioned(topo, monkeypatch):
     assert per_device < pool_bytes / 2
 
 
+def _pool_sized_results(text, num_blocks, slab_elems):
+    """``(instruction, opcode, dims, opcodes inside its fusion)`` of every
+    instruction outside the fused computations whose result holds an array
+    of a layer's slab of the pool or more: ``num_blocks`` divides its
+    element count (4,097 = 17 x 241 divides no weight's), and it is no
+    parameter, tuple plumbing, ``while`` or ``bitcast``, which make no
+    buffer."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+    fused = set(re.findall(r"kind=k\w+, calls=%?([\w.\-]+)", text))
+    found = []
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line)
+            if not m:
+                continue
+            result = m.group(2)
+            depth = end = 0
+            if result.startswith("("):  # a tuple's type holds spaces
+                for end, c in enumerate(result):
+                    depth += (c == "(") - (c == ")")
+                    if depth == 0:
+                        break
+            end = result.index(" ", end)
+            op = re.match(r"\s*([\w\-]+)\(", result[end:])
+            op = op.group(1) if op else "?"
+            if op in ("parameter", "get-tuple-element", "tuple", "while",
+                      "bitcast", "conditional", "call"):
+                continue
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result[:end]):
+                n = math.prod(map(int, dims.split(",")))
+                if n >= slab_elems and n % num_blocks == 0:
+                    calls = re.search(r"calls=%?([\w.\-]+)", result)
+                    inside = "\n".join(bodies.get(calls.group(1), [])) \
+                        if calls else ""
+                    found.append((m.group(1), op, dims, set(
+                        re.findall(r" ([\w\-]+)\(", inside))))
+    return found
+
+
+# (config of benchmark/configs, tp, kind): the benchmark cells' 64-row
+# decode and 4-row prefill programs against the cells' pool of 4,097 blocks
+POOL_PROGRAMS = {
+    "mistral-decode": ("mistral-7b-v0.3-6l", 1, "decode"),
+    "mistral-prefill": ("mistral-7b-v0.3-6l", 1, "prefill"),
+    "mistral-decode-tp4": ("mistral-7b-v0.3-6l", 4, "decode"),
+    "gpt2-decode": ("gpt2-small", 1, "decode"),
+    "gpt2-prefill": ("gpt2-small", 1, "prefill"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(POOL_PROGRAMS))
+def test_step_programs_update_the_pool_in_place(topo, monkeypatch, case):
+    """ISSUE 29's counter is a property of the compiled program. As
+    ``DecodeFns`` compiles a step (pools donated, the step programs' own
+    options), for the described v5e: ``input_output_alias`` names
+    ``cache_k`` and ``cache_v``, so no second pool exists. Where a page is
+    whole (8, 128) tiles (Mistral on one chip) NOTHING but the two scatter
+    fusions, in place, produces as much as one layer's slab: no slice, no
+    update, no copy. Where it is not (GPT-2's 12 heads of 64; a tp = 4
+    shard's 2 heads) the layer's slab still goes through the kernel as
+    PR 28 had it (``attend_layer``; ROADMAP S5a), but inside the ONE
+    donated pool: nothing makes or copies a whole pool, and the program's
+    temporaries stay a few slabs, not the 6.4 GB of a whole GPT-2 pool
+    relaid around the layer loop."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import ShardingRules, param_shardings
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    config, tp, kind = POOL_PROGRAMS[case]
+    held = common.load_json(
+        os.path.join(root, f"benchmark/configs/{config}.json"))
+    cfg = dataclasses.replace(
+        common.model_config(held), attention_backend="pallas")
+    fam = decode.get_family(held["family"])
+    mesh = build_mesh(MeshSpec(tp=tp), list(topo.devices)[:tp])
+    rep = NamedSharding(mesh, P())
+    # the tree the executor stores: matmul weights in the compute dtype
+    params = jax.tree.map(
+        lambda s, axis, sh: _struct(
+            s.shape, cfg.dtype if axis >= 0 else s.dtype, sh),
+        jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg)),
+        fam.quant_axes(cfg),
+        param_shardings(fam.param_axes(cfg), mesh, ShardingRules()),
+    )
+    n_kv = getattr(cfg, "n_kv_head", cfg.n_head)
+    num_blocks, bs = 4097, 16
+    pool = _struct(
+        (cfg.n_layer, num_blocks, bs, n_kv, cfg.head_dim), cfg.dtype,
+        NamedSharding(mesh, P(None, None, None, "tp")))
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=rep)
+    S = min(2048, cfg.max_seq_len)
+    fns = decode.DecodeFns(held["family"], cfg, platform="tpu")
+    with jax.set_mesh(mesh):
+        if kind == "decode":
+            lowered = fns._decode.lower(
+                params, pool, pool, i32((64,)), i32((64,)),
+                i32((64, min(2560, cfg.max_seq_len) // bs)), sample=None)
+        else:
+            lowered = fns._prefill.lower(
+                params, pool, pool, i32((4, S)), i32((4,)),
+                i32((4, S // bs)), sample=None)
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # both pools are the program's own output buffers
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    aliased = set(map(int, re.findall(r"\}: \((\d+),", alias)))
+    entry = text[text.index("ENTRY"):]
+    pools = {int(n) for n in re.findall(
+        r"%cache_[kv][.\d]* = [^\n]*? parameter\((\d+)\)", entry)}
+    if tp == 1:  # a partitioned program's parameters lose their names
+        assert len(pools) == 2 and pools == aliased, (pools, alias)
+    assert len(aliased) == 2, alias
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize // tp
+    assert mem.alias_size_in_bytes >= pool_bytes  # tiles pad a layout
+    slab = num_blocks * bs * (n_kv // tp) * cfg.head_dim
+    big = _pool_sized_results(text, num_blocks, slab)
+    scatters = [b for b in big if "scatter" in b[3] or b[1] == "scatter"]
+    assert len(scatters) == 2, big
+    rest = [b for b in big if b not in scatters]
+    if tp == 1 and cfg.head_dim % 128 == 0 and n_kv % 8 == 0:
+        assert not rest, rest
+        return
+    # the slab path: the slices, the relayout copies and the in-place
+    # updates of ONE slab each; nothing makes or copies a whole pool
+    whole = [b for b in rest
+             if math.prod(map(int, b[2].split(","))) > slab
+             and "dynamic-update-slice" not in b[3]]
+    assert not whole, whole
+    assert mem.temp_size_in_bytes < 16 * slab * pool.dtype.itemsize, mem
+
+
 @pytest.mark.parametrize("case", ["stored", "stored-tp4", "int8", "fp8"])
 def test_step_programs_take_no_cross_program_prefetch(topo, monkeypatch, case):
     """GPT-2 125M's 64-row decode step as the serving cell runs it, under
@@ -428,8 +584,9 @@ def test_other_families_programs_are_what_their_functions_compile_to(
     options = decode._compiler_options("tpu")
     fns = decode.DecodeFns(family, cfg, platform="tpu")
     through = fns._decode.lower(*args, sample=None).compile().as_text()
-    alone = jax.jit(functools.partial(step, cfg=cfg)).lower(*args).compile(
-        compiler_options=options).as_text()
+    alone = jax.jit(
+        functools.partial(step, cfg=cfg), donate_argnums=(1, 2)  # the pools
+    ).lower(*args).compile(compiler_options=options).as_text()
 
     def body(text):
         """The computations, less what names the CALLER: the module's

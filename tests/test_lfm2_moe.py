@@ -708,8 +708,8 @@ def test_other_families_take_no_state_argument(jax_cpu, family):
             jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
             jnp.zeros((B, nb), jnp.int32))
     own = jax.jit(functools.partial(
-        get_family(family).decode_step, cfg=cfg)).lower(
-        *args).as_text()
+        get_family(family).decode_step, cfg=cfg),
+        donate_argnums=(1, 2)).lower(*args).as_text()  # as decode.py does
     through = engine.fns._decode.lower(*args, sample=None).as_text()
     strip = lambda t: t.split("\n", 1)[1]  # the module's name line
     assert strip(through) == strip(own)

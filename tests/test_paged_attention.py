@@ -450,6 +450,86 @@ def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
     assert float(jnp.max(jnp.abs(jnp.where(live, out - ref, 0.0)))) < 2e-5
 
 
+@pytest.mark.parametrize("edge", [
+    "shuffled-pages", "int8-scale-planes", "window-floor-inside-block",
+    "chunk-start-inside-block", "unaligned-heads-one-page-walk",
+    "unaligned-heads-int8-chunk",
+])
+def test_kernel_reads_the_whole_pool_at_a_layer(jax_cpu, edge):
+    """``(pool, layer)``: both Pallas paths handed the WHOLE multi-layer
+    pool and a layer index (static, and traced under jit through the
+    dispatchers) give what the XLA formulation gives on ``pool[layer]``,
+    and bit for bit what the same kernel gives on that slab alone;
+    ``write_kv`` at a layer writes that slab's rows and no other
+    layer's."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.kv_cache import paged_prefill_attention, write_kv
+    from ray_tpu.ops.paged_attention import (
+        decode_attention, paged_prefill_attention_pallas, prefill_attention,
+    )
+
+    lengths, S, NB, pool_kw, kernel_kw = _BLOCK_EDGES[edge]
+    pool_kw = dict(pool_kw)
+    Hkv, hd = pool_kw.pop("Hkv", 8), pool_kw.pop("hd", 128)
+    bs, G, L = 8, 2, 3
+    key = jax.random.PRNGKey(sum(map(ord, edge)))
+    slabs = [
+        _prefill_pool(jax.random.fold_in(key, 50 + i), lengths, Hkv, hd, bs,
+                      NB, pool_kw.get("shuffle", False),
+                      quant=pool_kw.get("quant"))
+        for i in range(L)]
+    tables = slabs[0][2]
+    stack = lambda *a: jnp.stack(a)
+    k_pool = jax.tree.map(stack, *[s[0] for s in slabs])
+    v_pool = jax.tree.map(stack, *[s[1] for s in slabs])
+    starts = jnp.asarray([max(n - S, 0) for n in lengths], jnp.int32)
+    positions = starts[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    q = jax.random.normal(
+        jax.random.fold_in(key, 9), (len(lengths), S, Hkv * G, hd))
+    window = kernel_kw.get("window")
+    for layer in (0, L - 1):
+        k_layer, v_layer = slabs[layer][:2]
+        ref = paged_prefill_attention(
+            q, k_layer, v_layer, tables, positions, window=window)
+        out = paged_prefill_attention_pallas(
+            q, k_pool, v_pool, tables, positions, layer=layer, **kernel_kw)
+        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5, (edge, layer)
+        alone = paged_prefill_attention_pallas(
+            q, k_layer, v_layer, tables, positions, **kernel_kw)
+        assert jnp.array_equal(out, alone), (edge, layer)
+    # a traced layer, through the dispatchers the cached step calls
+    jitted = jax.jit(lambda layer, backend: prefill_attention(
+        q, k_pool, v_pool, tables, positions, window=window,
+        backend=backend, layer=layer), static_argnums=1)
+    for backend in ("pallas", "xla"):
+        got = jitted(jnp.int32(1), backend)
+        ref = paged_prefill_attention(
+            q, *slabs[1][:2], tables, positions, window=window)
+        assert float(jnp.max(jnp.abs(got - ref))) < 2e-5, (edge, backend)
+    if window is None:
+        got = jax.jit(lambda layer: decode_attention(
+            q[:, -1], k_pool, v_pool, tables, positions[:, -1],
+            backend="pallas", layer=layer))(jnp.int32(2))
+        ref = paged_prefill_attention(
+            q[:, -1:], *slabs[2][:2], tables, positions[:, -1:])[:, 0]
+        assert float(jnp.max(jnp.abs(got - ref))) < 2e-5, edge
+    # the scatter: rows at [layer, blk, slot], every other layer untouched
+    rows = jax.random.normal(
+        jax.random.fold_in(key, 10), (len(lengths), S, Hkv, hd))
+    valid = jnp.asarray(lengths)[:, None] > jnp.zeros((1, S), jnp.int32)
+    k_new, v_new = jax.jit(lambda layer: write_kv(
+        k_pool, v_pool, rows, 2 * rows, positions, tables, valid=valid,
+        layer=layer))(jnp.int32(1))
+    k_slab, v_slab = write_kv(
+        *slabs[1][:2], rows, 2 * rows, positions, tables, valid=valid)
+    for pool, new, slab in ((k_pool, k_new, k_slab), (v_pool, v_new, v_slab)):
+        for was, now, want in zip(*map(jax.tree.leaves, (pool, new, slab))):
+            assert jnp.array_equal(now[1], want)
+            assert jnp.array_equal(now[0], was[0])
+            assert jnp.array_equal(now[2], was[2])
+
+
 # (page, Hkv, hd, R, NB, pool dtype, quantized) -> pages a compute block
 _BLOCK_CHOICES = {
     # the cells' shapes: 16-token pages, a few rows (decode) or a q tile
