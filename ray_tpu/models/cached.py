@@ -23,7 +23,14 @@ own, in a ``CachedFamily``:
   the chunk. ``attend(q, k, v)`` (q ``[B, S, Hq, hd]``; k, v ``[B, S, Hkv,
   hd]``, the compact GQA heads) is the cache side of the layer, written
   below: it returns the attention output ``[B, S, Hq * hd]``. A layer
-  that does not attend does not call it;
+  that does not attend does not call it. In a LIST of layers a layer may
+  name where its K/V lives, ``attend(q, k, v, group=g, slot=s,
+  window=w)``: the step's block tables are then ``[G, B, NB]``, one table
+  a GROUP of layers (serve/llm/kv_cache.py: a sliding layer's group gives
+  back the blocks behind its window), ``slot`` is the layer's index in the
+  pool (its ordinal in its group) and ``window`` makes the attention
+  sliding. Without them: the one table ``[B, NB]``, the pool's layer the
+  attending layer's ordinal, full attention;
 - ``final_norm(params, x, cfg)`` and ``head(params, h, cfg)`` (float32
   logits over ``[..., D]``);
 - ``stack``: the key of ``params`` that holds the layers. A tree whose
@@ -85,7 +92,7 @@ class Step(NamedTuple):
     kind: str
     pos: jax.Array             # [B, S] each token's true position
     valid: jax.Array | None    # [B, S] the real tokens; None: every one
-    block_tables: jax.Array    # [B, NB]
+    block_tables: jax.Array    # [B, NB]; by group of layers [G, B, NB]
     # [B], the one such array every kind has: the rows' real tokens
     # (fresh, chunk), positions (decode) or first positions (verify)
     rows: jax.Array
@@ -125,13 +132,16 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
     return Step(kind, pos, valid, block_tables, rows, start, slots)
 
 
-def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
+def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
+                 tables=None, window=None):
     """The cache side of one attention layer, on the WHOLE pools and the
     layer's index in them (an int32 scalar, traced under the scan): the
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
     slot]``, then the attention call the kind asks for reads the pools at
     that layer. Nothing slices a layer's slab out of a pool or writes one
     back. Returns (attention output [B, S, Hq * hd], cache_k', cache_v').
+    ``tables`` [B, NB]: the layer's own table (None: ``step``'s);
+    ``window``: sliding attention over the last ``window`` positions.
 
     The exception is a pool whose pages are not whole tiles
     (``reads_pool_in_place``: heads of 64, 12 heads, a ``tp`` shard's 2).
@@ -143,31 +153,35 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
     it was, in place: what the scan did with the pool as xs -> ys, less
     the second pool."""
     if reads_pool_in_place(cache_k):
-        return _attend(step, cache_k, cache_v, layer, q, k, v, cfg)
+        return _attend(step, cache_k, cache_v, layer, q, k, v, cfg, tables,
+                       window)
     # lax's own index ops: ``a[layer]`` / ``.at[layer].set`` wrap a traced
     # index in bounds handling that costs two more passes over the slab
     slabs = jax.tree.map(
         lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
         (cache_k, cache_v))
-    attn, *slabs = _attend(step, *slabs, None, q, k, v, cfg)
+    attn, *slabs = _attend(step, *slabs, None, q, k, v, cfg, tables, window)
     cache_k, cache_v = jax.tree.map(
         lambda a, slab: jax.lax.dynamic_update_index_in_dim(a, slab, layer, 0),
         (cache_k, cache_v), tuple(slabs))
     return attn, cache_k, cache_v
 
 
-def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
+def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg, tables=None,
+            window=None):
     """``attend_layer`` on pools read at ``layer``, or (None) on one
     layer's."""
     B, S = q.shape[:2]
-    tables, backend = step.block_tables, cfg.attention_backend
+    backend = cfg.attention_backend
+    if tables is None:
+        tables = step.block_tables
     if step.kind == "decode":
         cache_k, cache_v = write_kv(
             cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
             layer=layer)
         attn = decode_attention(
             q[:, 0], cache_k, cache_v, tables, step.rows, backend=backend,
-            layer=layer)
+            layer=layer, window=window)
         return attn.reshape(B, S, -1), cache_k, cache_v
     cache_k, cache_v = write_kv(
         cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
@@ -182,6 +196,7 @@ def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
     # exists in HBM).
     if (
         step.kind == "fresh"
+        and window is None  # the shortcut's mask is causal, no more
         and cfg.quantization is None
         and resolve_backend(backend) != "pallas"
     ):
@@ -194,7 +209,7 @@ def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg):
     else:
         attn = prefill_attention(
             q, cache_k, cache_v, tables, jnp.where(step.valid, step.pos, 0),
-            backend=backend, layer=layer)
+            backend=backend, layer=layer, window=window)
     return attn.reshape(B, S, -1), cache_k, cache_v
 
 
@@ -224,10 +239,12 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
 
     attended = 0  # the pool spans the attending layers only
 
-    def attend(q, k, v):
+    def attend(q, k, v, *, group=None, slot=None, window=None):
         nonlocal cache_k, cache_v, attended
         attn, cache_k, cache_v = attend_layer(
-            step, cache_k, cache_v, attended, q, k, v, cfg)
+            step, cache_k, cache_v, attended if slot is None else slot,
+            q, k, v, cfg,
+            None if group is None else step.block_tables[group], window)
         attended += 1
         return attn
 
@@ -277,7 +294,8 @@ def steps(fam: CachedFamily):
 
     All take ``(params, cache_k, cache_v, ...)``, the pool ``[n_kv_layer,
     num_blocks, block_size, n_kv_head, head_dim]`` (block 0 is the garbage
-    sink), ``block_tables [B, NB]``, the static ``cfg``, and by keyword
+    sink), ``block_tables [B, NB]`` (``[G, B, NB]`` for a family whose
+    layers name their group), the static ``cfg``, and by keyword
     ``sample`` (an ops/sampling.py pytree: sampling then runs inside the
     program and token ids come back, not logits), ``state`` and ``slots``.
     All return ``(out, cache_k', cache_v', state')``: the pools with the

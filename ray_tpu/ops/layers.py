@@ -57,3 +57,50 @@ def rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions=None) -> jax.Ar
     s = jnp.expand_dims(sin, -2)
     out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_partial(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """``rope`` on the first ``2 * cos.shape[-1]`` of the head's dimensions
+    (rotate-half within them); the rest pass unchanged. With tables as wide
+    as half the head it is ``rope``."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return rope(x, cos, sin)
+    return jnp.concatenate(
+        [rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0,
+                  truncate: bool = True):
+    """YaRN's inverse frequencies [dim // 2] float32 (numpy: constants of
+    the traced program): a blend of ``base ** (-2i / dim)`` (kept where a
+    dimension turns more than ``beta_fast`` times over ``original_max``
+    positions) and the same divided by ``factor`` (where it turns fewer
+    than ``beta_slow`` times), linear between. The formula is
+    transformers' ``modeling_rope_utils._compute_yarn_parameters`` (4.57),
+    which tests/test_laguna.py holds it to."""
+    import math
+
+    import numpy as np
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))) / (
+            2 * math.log(base))
+
+    low, high = correction_dim(beta_fast), correction_dim(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001  # as the published code: no division by zero
+    pos_freqs = np.float32(base) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    extrapolation = np.float32(1.0) / pos_freqs
+    interpolation = np.float32(1.0) / (np.float32(factor) * pos_freqs)
+    ramp = np.clip(
+        (np.arange(dim // 2, dtype=np.float32) - np.float32(low))
+        / np.float32(high - low), 0, 1).astype(np.float32)
+    keep = 1 - ramp
+    return (interpolation * (1 - keep) + extrapolation * keep).astype(
+        np.float32)

@@ -167,7 +167,8 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
 
 def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  w_in: jax.Array, w_out: jax.Array, *, dtype,
-                 valid: jax.Array | None = None):
+                 valid: jax.Array | None = None,
+                 held: tuple[int, int] | None = None):
     """The expert layer proper: x [T, D] -> (y [T, D], pairs_by_expert [E]
     int32).
 
@@ -179,13 +180,32 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     computed whole, all tokens on one expert included: no capacity, no
     drop. ``valid`` [T] bool marks the real rows of a bucketed batch:
     pairs of padding rows sort behind every expert's, belong to no group,
-    are not computed and not counted, and their output is zero."""
+    are not computed and not counted, and their output is zero.
+
+    ``held = (first, count)``: this device holds experts ``first ..
+    first + count - 1`` of the ones the router scores (``w_in`` and
+    ``w_out`` lead with ``count``, ``experts`` keeps the router's ids).
+    A pair routed to an expert that is not held sorts behind every group
+    with the padding rows, is not computed and not counted; ``y`` is then
+    the part of the layer's output that the held experts give, and the
+    parts of all the holders add up to the whole layer's. No exchange of
+    rows is made here: that is the caller's, across devices."""
     T, D = x.shape
     k = experts.shape[1]
     E = w_in.shape[0]
     with jax.named_scope("moe_gmm"):
         flat = experts.reshape(T * k)
-        if valid is not None:
+        kept = None  # [T * k]: the pairs some group computes; None: all
+        if held is not None:
+            first, count = held
+            if count != E:
+                raise ValueError(
+                    f"held names {count} experts, the weights hold {E}")
+            kept = (flat >= first) & (flat < first + count)
+            if valid is not None:
+                kept = kept & jnp.repeat(valid, k)
+            flat = jnp.where(kept, flat - first, E)
+        elif valid is not None:
             flat = jnp.where(jnp.repeat(valid, k), flat, E)
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
@@ -205,7 +225,11 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
             jnp.arange(T * k, dtype=jnp.int32))
         yk = ys[back].reshape(T, k, D)
         w = weights.astype(jnp.float32)
-        if valid is not None:
+        if kept is not None:
+            kept = kept.reshape(T, k)
+            yk = jnp.where(kept[:, :, None], yk, 0.0)
+            w = jnp.where(kept, w, 0.0)
+        elif valid is not None:
             # rows past the last group hold whatever the product left there
             yk = jnp.where(valid[:, None, None], yk, 0.0)
             w = jnp.where(valid[:, None], w, 0.0)

@@ -220,6 +220,13 @@ def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
     return P, need(P)
 
 
+def _kernel_name(window: int | None) -> str:
+    """The name a call has in compiler dumps and device traces: the
+    windowed calls have one of their own, so that a trace parts the
+    sliding layers' time from the full layers'."""
+    return "paged_attention" if window is None else "paged_attention_window"
+
+
 def _page_walk_kernel(
     tables_ref,   # scalar prefetch: [B, NB] int32 block tables
     qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
@@ -422,7 +429,7 @@ def _walk_pages(qf, pos_rows, tables, qmax, qmin, layer, k_pool, v_pool, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        name="paged_attention",
+        name=_kernel_name(window),
         interpret=interpret,
     )(*operands)
     return out
@@ -771,7 +778,7 @@ def paged_prefill_attention_pallas(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
         ),
-        name="paged_attention",
+        name=_kernel_name(window),
         interpret=interpret,
     )(*operands)
     out = out.reshape(B, Hkv, Sp, G, hd).transpose(0, 2, 1, 3, 4)
@@ -841,6 +848,7 @@ def decode_attention(
     *,
     scale: float | None = None,
     backend: str = "auto",
+    window: int | None = None,
     layer: jax.Array | int | None = None,
 ) -> jax.Array:
     """Backend dispatcher for decode attention — the one entry point the
@@ -848,11 +856,13 @@ def decode_attention(
     threaded from ``EngineConfig`` through the model config; "auto" picks
     the Pallas kernel on TPU and the XLA formulation elsewhere. Both
     backends share the exact call signature and numerics contract
-    (tests/test_paged_attention.py). ``layer``: see ``prefill_attention``."""
-    if resolve_backend(backend) == "pallas":
+    (tests/test_paged_attention.py). ``window`` and ``layer``: see
+    ``prefill_attention`` (a windowed decode is its S = 1 case under
+    either backend)."""
+    if resolve_backend(backend) == "pallas" or window is not None:
         return prefill_attention(
             q[:, None], k_layer, v_layer, block_tables, positions[:, None],
-            scale=scale, backend="pallas", layer=layer,
+            scale=scale, backend=backend, window=window, layer=layer,
         )[:, 0]
     from ray_tpu.ops.kv_cache import paged_attention as _xla_paged_attention
 

@@ -73,10 +73,21 @@ def _lfm2_moe() -> Family:
                   counters=m.lfm2_moe_counters)
 
 
+def _laguna() -> Family:
+    from ray_tpu.models import laguna as m
+
+    # no verify step: a rejected window may reach behind freed blocks
+    return Family(m.laguna_init, m.laguna_prefill, m.laguna_decode_step,
+                  None, m.laguna_param_axes, m.laguna_quant_axes,
+                  m.LagunaConfig.tiny, init_state=m.laguna_init_state,
+                  counters=m.laguna_counters)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
     "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
+    "laguna": _laguna,
 }
 
 

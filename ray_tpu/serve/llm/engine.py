@@ -503,9 +503,12 @@ class LLMEngine:
         # (decode.py ``Family.init_state``): what cannot carry that state yet
         # is refused here, by name, never served silently wrong.
         self._stateful = family.init_state is not None
-        if self._stateful:
-            self._refuse_for_state(cfg, quant)
-        n_kv = getattr(model_cfg, "n_kv_head", model_cfg.n_head)
+        # A family whose layers keep their K/V by GROUP (``kv_table_groups``:
+        # kv_cache.py "Tables by group"): one table a group, windowed
+        # groups give blocks back. What cannot carry that is refused too.
+        groups = tuple(getattr(model_cfg, "kv_table_groups", ()))
+        self._refuse_for_state(cfg, quant, self._stateful, bool(groups))
+        n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
         # one slot per running sequence, and slot 0, the garbage sink
         slots = cfg.max_batch_size + 1 if self._stateful else 0
         self.cache = PagedKVCache(
@@ -522,12 +525,35 @@ class LLMEngine:
                 quantization=quant,
                 state_slots=slots,
                 # a prefix hit would need the recurrent state as it stood
-                # at the block boundary: no reuse for such a family
-                prefix_reuse=not self._stateful,
+                # at the block boundary, or a windowed group's blocks there,
+                # which were given back: no reuse for such a family
+                prefix_reuse=not self._stateful and not groups,
+                groups=groups,
             ),
             state=(family.init_state(model_cfg, slots)
                    if self._stateful else None),
         )
+        # What the rows of a prefill step hold past their reservations
+        # while a chunk is written (kv_cache.py ``prefill_room``), set
+        # aside once: 0 without windowed groups.
+        # the window of the sliding layers (0: none): what their calls
+        # attend of a row, for ``executor.dispatch``'s ``kv_tokens_window``
+        self._kv_window = max(
+            (window or 0 for window, _ in groups), default=0)
+        self._kv_room = self.cache.cfg.prefill_room(
+            cfg.max_prefill_batch,
+            min(cfg.prefill_chunk_tokens or model_cfg.max_seq_len,
+                model_cfg.max_seq_len))
+        if self._kv_room:
+            if not self.cache.can_reserve(self._kv_room):
+                raise ValueError(
+                    f"the windowed layers of model {cfg.model!r} need "
+                    f"{self._kv_room} blocks set aside for prefill "
+                    f"(max_prefill_batch x windowed groups x blocks of a "
+                    f"chunk) and the pool has "
+                    f"{self.cache.cfg.usable_blocks}: set "
+                    "prefill_chunk_tokens, or a larger num_blocks")
+            self.cache.reserve(self._kv_room)
         # the ModelExecutor seam (executor.py): the engine schedules on
         # host state only; weights, the KV pool arrays, and the jitted
         # step calls live behind the executor — single-device by
@@ -800,37 +826,58 @@ class LLMEngine:
         self.executor.phases = self._step_phases
 
     @staticmethod
-    def _refuse_for_state(cfg: EngineConfig, quant) -> None:
-        """Raise for each option that cannot yet carry the per-sequence
-        state of a family like ``lfm2_moe``, with the reason."""
-        why = {
-            "speculative_k": (
-                cfg.speculative_k > 0,
-                "rejected drafts would need the per-sequence state rolled "
-                "back, and the family has no verify step"),
-            "host_cache_bytes": (
-                cfg.host_cache_bytes > 0,
-                "a block promoted from the host tier restores K/V but not "
-                "the state at its boundary"),
-            "preemption": (
-                cfg.preemption is not None,
-                "a paused stream's state slot is not demoted with its "
-                "blocks"),
-            "quantization": (
-                quant is not None,
-                "the family's expert and conv weights have no quantized "
-                "path"),
+    def _refuse_for_state(cfg: EngineConfig, quant, stateful: bool,
+                          grouped: bool) -> None:
+        """Raise for each option that cannot yet carry what the family
+        keeps: per-sequence state beside the pool (``lfm2_moe``) or tables
+        by group of layers (``laguna``), each with its reason."""
+        asked = {
+            "speculative_k": cfg.speculative_k > 0,
+            "host_cache_bytes": cfg.host_cache_bytes > 0,
+            "preemption": cfg.preemption is not None,
+            "quantization": quant is not None,
             "tp/fsdp/mesh": (
-                cfg.mesh is not None or cfg.tp != 1 or cfg.fsdp != 1,
-                "ShardedExecutor has no expert axis and does not place the "
-                "state arrays"),
+                cfg.mesh is not None or cfg.tp != 1 or cfg.fsdp != 1),
         }
-        for option, (asked, reason) in why.items():
-            if asked:
-                raise ValueError(
-                    f"model {cfg.model!r} keeps per-sequence state beside "
-                    f"the paged cache and cannot be served with {option}: "
-                    f"{reason}")
+        for keeps, what, why in (
+            (stateful, "keeps per-sequence state beside the paged cache", {
+                "speculative_k":
+                    "rejected drafts would need the per-sequence state "
+                    "rolled back, and the family has no verify step",
+                "host_cache_bytes":
+                    "a block promoted from the host tier restores K/V but "
+                    "not the state at its boundary",
+                "preemption":
+                    "a paused stream's state slot is not demoted with its "
+                    "blocks",
+                "quantization":
+                    "the family's expert and conv weights have no "
+                    "quantized path",
+                "tp/fsdp/mesh":
+                    "ShardedExecutor has no expert axis and does not place "
+                    "the state arrays"}),
+            (grouped, "keeps its K/V in tables by group of layers", {
+                "speculative_k":
+                    "a rejected window may reach behind blocks a windowed "
+                    "group already gave back",
+                "host_cache_bytes":
+                    "the host tier holds one block a digest, a chain of "
+                    "the one table",
+                "preemption":
+                    "a paused stream's chain is demoted from one table, "
+                    "and the windowed groups' blocks behind it are gone",
+                "quantization":
+                    "the scale planes of a quantized pool are not laid "
+                    "out by group",
+                "tp/fsdp/mesh":
+                    "ShardedExecutor places one table a step and has no "
+                    "expert axis"}),
+        ):
+            for option, reason in why.items():
+                if keeps and asked[option]:
+                    raise ValueError(
+                        f"model {cfg.model!r} {what} and cannot be served "
+                        f"with {option}: {reason}")
 
     def _refuse_handoff(self, what: str) -> None:
         if self._stateful:
@@ -838,6 +885,11 @@ class LLMEngine:
                 f"model {self.cfg.model!r} keeps per-sequence state beside "
                 f"the paged cache and cannot {what}: the prefill/decode "
                 "handoff moves K/V blocks, not the state at their boundary")
+        if self.cache.cfg.groups:
+            raise ValueError(
+                f"model {self.cfg.model!r} keeps its K/V in tables by group "
+                f"of layers and cannot {what}: the prefill/decode handoff "
+                "moves the blocks of one table")
 
     # ---------------- public API ----------------
 
@@ -880,11 +932,12 @@ class LLMEngine:
                 f"({sampling.max_new_tokens}) exceeds model max_seq_len "
                 f"{self.model_cfg.max_seq_len}"
             )
-        need = self.cache.cfg.blocks_for(total)
-        if need > self.cache.cfg.usable_blocks:
+        need = self.cache.cfg.request_blocks(total)
+        if need > self.cache.cfg.usable_blocks - self._kv_room:
             raise ValueError(
                 f"request needs {need} KV blocks "
-                f"but the pool only has {self.cache.cfg.usable_blocks}"
+                f"but the pool only has "
+                f"{self.cache.cfg.usable_blocks - self._kv_room}"
             )
         # grammar constraint: compile (LRU-cached) and position the FSM
         # cursor OUTSIDE the scheduler lock — compile is submit-path
@@ -1139,6 +1192,13 @@ class LLMEngine:
                 "kv_used_blocks": self.cache.used_blocks,
                 "kv_utilization": self.cache.utilization,
                 "kv_high_water_blocks": cs.high_water_blocks,
+                # tables by group of layers ([] for one table): a group's
+                # window, layers, blocks held now and at most; the blocks
+                # the windowed groups took, and gave back behind the
+                # window while their sequence lived
+                "kv_groups": self.cache.group_report(),
+                "kv_window_blocks_taken": cs.window_blocks_taken,
+                "kv_window_blocks_freed": cs.window_blocks_freed,
                 # per-sequence state beside the pool (0 for a family that
                 # keeps none), and whether a prefix hit can be reused
                 "state_slots": self.cache.used_slots,
@@ -1412,7 +1472,9 @@ class LLMEngine:
         r.blocks_released = True
         leftover = r.reserved_blocks - r.drawn_blocks
         self.cache.free(r.id, quarantine=self._pending is not None)
-        if leftover > 0:
+        if leftover:
+            # below zero for a row cut off inside a prefill step, whose
+            # chunk drew on the engine's prefill room: the room gets it back
             self.cache.release_reservation(leftover)
         self._work.notify_all()  # freed blocks may unblock admissions
 
@@ -1439,7 +1501,7 @@ class LLMEngine:
             except ValueError:  # pragma: no cover — already gone
                 pass
             else:
-                self._waiting_blocks -= self.cache.cfg.blocks_for(
+                self._waiting_blocks -= self.cache.cfg.request_blocks(
                     len(r.prompt) + r.sampling.max_new_tokens
                 )
         r.done = True
@@ -1663,7 +1725,7 @@ class LLMEngine:
         # generate == len(prompt) + max_new_tokens, always.
         toks = req.prefill_tokens
         total = len(toks) + (req.sampling.max_new_tokens - len(req.generated))
-        need = self.cache.cfg.blocks_for(total)
+        need = self.cache.cfg.request_blocks(total)
         max_hit_blocks = None
         if self.cfg.prefix_caching:
             hit_blocks = self.cache.peek_prefix(toks)
@@ -1745,7 +1807,7 @@ class LLMEngine:
             req = order[idx]
             if self._try_admit_one_locked(req):
                 self._waiting.remove(req)
-                self._waiting_blocks -= self.cache.cfg.blocks_for(
+                self._waiting_blocks -= self.cache.cfg.request_blocks(
                     len(req.prompt) + req.sampling.max_new_tokens
                 )
                 self._prefilling.append(req)
@@ -1832,6 +1894,8 @@ class LLMEngine:
                 ns.append(remaining if cap is None else min(remaining, cap))
             pairs: list[tuple[int, int]] = []
             for r, n in zip(batch, ns):
+                r.drawn_blocks -= self.cache.free_behind(
+                    r.id, r.prefill_done)
                 appended = self.cache.ensure_capacity(
                     r.id, r.prefill_done + n
                 )
@@ -1862,7 +1926,7 @@ class LLMEngine:
             tokens = self._scratch_buf("pf_tokens", (B, S), np.int32)
             lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
             starts = self._scratch_buf("pf_starts", (B,), np.int32)
-            tables = self._scratch_buf("pf_tables", (B, nb), np.int32)
+            tables = self._tables_buf("pf_tables", B, nb)
             slots = self._slots_buf_locked("pf_slots", batch, B)
             # reused buffers: stale padding rows/columns must be re-zeroed
             # (a stale table row could point at blocks now owned by a LIVE
@@ -1870,14 +1934,14 @@ class LLMEngine:
             tokens[len(batch):] = 0
             lengths[:] = 1  # padding rows: length 1
             starts[len(batch):] = 0
-            tables[len(batch):] = 0
+            tables[..., len(batch):, :] = 0
             for i, (r, n) in enumerate(zip(batch, ns)):
                 toks = r.prefill_tokens
                 tokens[i, :n] = toks[r.prefill_done : r.prefill_done + n]
                 tokens[i, n:] = 0
                 lengths[i] = n
                 starts[i] = r.prefill_done
-                tables[i] = self._table_for(r, nb)
+                tables[..., i, :] = self._table_for(r, nb)
             sample = self._sample_args_locked(batch, B)
         span = {"kind": kind}
         if legacy:
@@ -1893,6 +1957,14 @@ class LLMEngine:
         # first tokens sync immediately (lag 0): TTFT must not wait for
         # the next decode step, and only final-chunk rows emit anyway
         host = self._sync_tokens_locked(toks_dev, lag=0)
+        if self._kv_room:
+            # the chunk is written: what it put behind the window goes
+            # back now, so that a row holds no more than it reserved
+            # between steps and the prefill room is whole for the next
+            with self._phase("kv.reserve"):
+                for r, n in zip(batch, ns):
+                    r.drawn_blocks -= self.cache.free_behind(
+                        r.id, r.prefill_done + n)
         # dt covers the phase's real cost — COW copies, padding, the
         # jitted call and THE host sync. The same value feeds the latency
         # histogram, the flight record and the per-request chunk timeline
@@ -2012,10 +2084,12 @@ class LLMEngine:
             self._apply_promotions_locked()
             pairs: list[tuple[int, int]] = []
             kv_tokens = 0
+            kv_tokens_window = 0
             for r in batch:
                 # effective length includes the in-flight token: its K/V
                 # row lands at position eff-1 during this dispatch
                 eff = r.total_len + r.inflight
+                r.drawn_blocks -= self.cache.free_behind(r.id, eff - 1)
                 appended = self.cache.ensure_capacity(r.id, eff)
                 r.drawn_blocks += appended
                 cow = self.cache.prepare_write(r.id, eff - 1, eff)
@@ -2024,6 +2098,9 @@ class LLMEngine:
                 # what the attention kernel must read for this row: its
                 # context, in whole blocks
                 kv_tokens += -(-eff // bs) * bs
+                if self._kv_window:
+                    # and what a sliding layer's call attends of it
+                    kv_tokens_window += min(eff, self._kv_window)
             self._apply_copies_locked(pairs)
         with self._phase("engine.batch"):
             B = pad_to_bucket(len(batch), self._batch_buckets)
@@ -2041,15 +2118,15 @@ class LLMEngine:
             )
             nb = ctx // bs
             positions = self._scratch_buf("dec_positions", (B,), np.int32)
-            tables = self._scratch_buf("dec_tables", (B, nb), np.int32)
+            tables = self._tables_buf("dec_tables", B, nb)
             slots = self._slots_buf_locked("dec_slots", batch, B)
             # reused buffers: re-zero padding rows (a stale table row
             # could point at blocks now owned by a live sequence)
             positions[len(batch):] = 0
-            tables[len(batch):] = 0
+            tables[..., len(batch):, :] = 0
             for i, r in enumerate(batch):
                 positions[i] = r.total_len + r.inflight - 1
-                tables[i] = self._table_for(r, nb)
+                tables[..., i, :] = self._table_for(r, nb)
             if steady:
                 # feed step N+1 from step N's sampled ids without a host
                 # round-trip — THE datapath that makes the pipeline a win
@@ -2064,9 +2141,12 @@ class LLMEngine:
                     )
                 tokens_src = tokens
             sample = self._sample_args_locked(batch, B)
+        span = {"kind": "decode", "kv_tokens": kv_tokens}
+        if self._kv_window:
+            span["kv_tokens_window"] = kv_tokens_window
         next_dev = self.executor.decode_step(
-            tokens_src, positions, tables, sample=sample,
-            span={"kind": "decode", "kv_tokens": kv_tokens}, slots=slots,
+            tokens_src, positions, tables, sample=sample, span=span,
+            slots=slots,
         )
         self._decode_steps += 1
         self._decode_steps_steady += steady
@@ -2422,6 +2502,14 @@ class LLMEngine:
         for i, r in enumerate(batch):
             slots[i] = self.cache.slot(r.id)
         return slots
+
+    def _tables_buf(self, name: str, B: int, nb: int) -> np.ndarray:
+        """A step's block tables, to be filled: ``[B, nb]``, or with
+        tables by group ``[G, B, nb]`` (``tables[..., i, :]`` is row i's
+        either way)."""
+        groups = len(self.cache.cfg.groups)
+        return self._scratch_buf(
+            name, (groups, B, nb) if groups else (B, nb), np.int32)
 
     def _scratch_buf(self, name: str, shape: tuple, dtype) -> np.ndarray:
         """Reusable numpy staging buffer for one (name, shape) slot. TWO

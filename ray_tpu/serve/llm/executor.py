@@ -572,7 +572,8 @@ class ModelExecutor:
     def _state_report(self) -> dict:
         """What the family holds per sequence: how many layers the paged
         pool spans, the state slots beside it (None: the pool is all),
-        and whether a prefix hit can be reused."""
+        whether a prefix hit can be reused, and the groups of layers that
+        have a table each, where the family names them."""
         import jax
 
         cfg = self.cache.cfg
@@ -586,8 +587,16 @@ class ModelExecutor:
                 "arrays": {k: list(v.shape)
                            for k, v in self.cache.state.items()},
             }
-        return {"kv_layers": cfg.n_layer, "state": state,
-                "prefix_reuse": cfg.prefix_reuse}
+        report = {"kv_layers": cfg.n_layer, "state": state,
+                  "prefix_reuse": cfg.prefix_reuse}
+        if cfg.groups:
+            # tables by group: ``kv_layers`` is then a GROUP's layers (the
+            # pool's layer axis), and a group its window and the model's
+            # layers whose K/V it holds
+            report["kv_groups"] = [
+                {"window": window, "layers": list(layers)}
+                for window, layers in cfg.groups]
+        return report
 
     def describe(self) -> dict:
         """Stable summary for stats()/debug_dump()/benchmarks: which

@@ -54,6 +54,25 @@ and a sequence's first chunk starts from zeros whatever its slot held.
 family (a hit would need the state as it stood at the block boundary):
 ``peek_prefix`` answers as a miss and nothing is content-addressed.
 
+Tables by group (``groups``): a family whose layers do not all keep the
+same tokens (models/laguna.py: full layers keep every one, sliding layers
+the last ``window``) names GROUPS of layers, each of ``n_layer`` layers, so
+that a block id means ``n_layer`` slots whatever its group and all groups
+draw on this ONE pool, one free list, one ``num_blocks``. A sequence then
+holds one table a group, ``block_table`` gives ``[G, pad_to]``, and
+``free_behind`` returns a windowed group's blocks that lie wholly behind
+the window of every query still to come; their table entries become block
+0, which the windowed kernel never copies (its walk starts at the page of
+the window's floor). Memory moves between the kinds by demand: nothing is
+split for a user to size. A reservation stays its sequence's own for its
+whole life (``free_behind`` credits it back, the next block draws on it
+again): ``KVCacheConfig.request_blocks`` is what a request needs reserved,
+``prefill_room`` what the engine sets aside once for the rows of a prefill
+step, whose chunk is written before the blocks behind it go back. Groups
+carry neither the prefix cache nor the host tier (``prefix_reuse`` is off:
+a hit would need the sliding groups' blocks at the hit's boundary, which
+were freed); the engine refuses what else cannot carry them.
+
 Host-memory tier (``host_cache_bytes > 0``): LRU eviction DEMOTES a full
 prefix block into a pinned host-side arena instead of discarding it —
 the plasma spill model from the Ray object store, applied to KV. Each
@@ -119,6 +138,11 @@ class KVCacheConfig:
     # False: no block is content-addressed and every prefix lookup misses
     # (a family whose recurrent state a mapped block would not restore).
     prefix_reuse: bool = True
+    # Groups of layers, each with a table of its own: ``(window, layers)``
+    # a group, ``window`` None for layers that keep every token, ``layers``
+    # the model's layer indices (for reports). Empty: one table for all
+    # layers, as ever. See the module docstring.
+    groups: tuple = ()
 
     @property
     def usable_blocks(self) -> int:
@@ -126,6 +150,30 @@ class KVCacheConfig:
 
     def blocks_for(self, num_tokens: int) -> int:
         return -(-num_tokens // self.block_size)  # ceil
+
+    def window_blocks(self, window: int) -> int:
+        """The most blocks a windowed group holds for a row between steps:
+        ``window`` tokens at any offset in their blocks, and one block of
+        slack."""
+        return self.blocks_for(window) + 2
+
+    def request_blocks(self, num_tokens: int) -> int:
+        """What admission reserves for a request that may reach
+        ``num_tokens``: every block of each group that keeps all tokens,
+        and of a windowed group what a decoding row holds."""
+        full = self.blocks_for(num_tokens)
+        if not self.groups:
+            return full
+        return sum(
+            full if window is None else min(full, self.window_blocks(window))
+            for window, _ in self.groups)
+
+    def prefill_room(self, rows: int, chunk_tokens: int) -> int:
+        """What the engine reserves ONCE for the rows of a prefill step: a
+        windowed group holds a step's whole chunk until the step is
+        written, ``blocks_for(chunk)`` past what the row reserved."""
+        return rows * self.blocks_for(chunk_tokens) * sum(
+            window is not None for window, _ in self.groups)
 
 
 @dataclass
@@ -145,6 +193,10 @@ class CacheStats:
     demote_drops: int = 0        # demote captures that failed (content lost)
     host_corrupt_drops: int = 0  # arena entries failing RTKV verification
     state_slots_high_water: int = 0  # most state slots held at once
+    # windowed groups: blocks taken, and blocks given back behind the
+    # window while their sequence lived
+    window_blocks_taken: int = 0
+    window_blocks_freed: int = 0
     tables: dict = field(default_factory=dict)
 
 
@@ -288,7 +340,19 @@ class PagedKVCache:
         # speculative write it carries) has executed. flush_quarantine()
         # moves them to the free list at that sync.
         self._quarantine: list[int] = []
+        # group 0's table (the only one without ``cfg.groups``): what every
+        # path below that knows nothing of groups reads and writes
         self._tables: dict[Any, list[int]] = {}
+        # the other groups' tables, in group order; a table is indexed by
+        # logical block, and a windowed group's entries behind ``_floor``
+        # (one floor a group) are 0: given back
+        self._more: dict[Any, list[list[int]]] = {}
+        self._floor: dict[Any, list[int]] = {}
+        # each group's window (None: it keeps every token); one table
+        # that keeps everything where no group is named
+        self._windows = [window for window, _ in cfg.groups] or [None]
+        self._group_held = [0] * len(self._windows)
+        self._group_high = [0] * len(self._windows)
         self._reserved = 0
         # prefix cache state
         self._ref: dict[int, int] = {}            # block -> live references
@@ -399,6 +463,9 @@ class PagedKVCache:
             self.stats.state_slots_high_water = max(
                 self.stats.state_slots_high_water, len(self._slots))
         self._tables[seq_id] = []
+        if self.cfg.groups:
+            self._more[seq_id] = [[] for _ in self.cfg.groups[1:]]
+            self._floor[seq_id] = [0] * len(self.cfg.groups)
         self._chain[seq_id] = (b"", 0)
         self._versions[seq_id] = 0
 
@@ -427,19 +494,79 @@ class PagedKVCache:
         """Append blocks until the sequence can hold ``num_tokens``.
         Draws from this sequence's reservation when ``reserved``.
         Returns the number of blocks appended."""
-        table = self._tables[seq_id]
         appended = 0
-        while len(table) * self.cfg.block_size < num_tokens:
-            b = self._take_block(reserved=reserved)
-            self._ref[b] = 1
-            table.append(b)
-            appended += 1
+        for g, table in enumerate(self._group_tables(seq_id)):
+            grown = 0
+            while len(table) * self.cfg.block_size < num_tokens:
+                b = self._take_block(reserved=reserved)
+                self._ref[b] = 1
+                table.append(b)
+                grown += 1
+            if grown:
+                self._group_held[g] += grown
+                self._group_high[g] = max(
+                    self._group_high[g], self._group_held[g])
+                if self._windows[g] is not None:
+                    self.stats.window_blocks_taken += grown
+            appended += grown
         if appended:
             self._versions[seq_id] += 1
             self.stats.high_water_blocks = max(
                 self.stats.high_water_blocks, self.used_blocks
             )
         return appended
+
+    def _group_tables(self, seq_id) -> list[list[int]]:
+        """The sequence's tables in group order (one without groups)."""
+        return [self._tables[seq_id], *self._more.get(seq_id, ())]
+
+    def free_behind(self, seq_id, next_pos: int) -> int:
+        """Give back the blocks of the windowed groups that no query at
+        ``next_pos`` or later can see: a query at ``q`` sees ``t > q -
+        window``, so a block goes when its last position is at or below
+        ``next_pos - window``. Its table entry becomes block 0. The
+        sequence's reservation is credited with what went back (it is the
+        sequence's own for its whole life; the block the frontier needs
+        next draws on it again), so the caller lowers its count of drawn
+        blocks by the result. No quarantine, as for a state slot: every
+        program that touches the pool takes the buffer the last one
+        returned (the pools are donated down the chain of steps), so a
+        step in flight that still reads the block is ordered before
+        whatever writes it next. -> blocks given back."""
+        if not self.cfg.groups:
+            return 0
+        freed = 0
+        floors = self._floor[seq_id]
+        bs = self.cfg.block_size
+        for g, window in enumerate(self._windows):
+            # every decode step asks; a floor moves once a block
+            if window is None or (next_pos - window + 1) // bs <= floors[g]:
+                continue
+            table = self._tables[seq_id] if g == 0 else \
+                self._more[seq_id][g - 1]
+            upto = min(len(table), (next_pos - window + 1) // bs)
+            for i in range(floors[g], upto):
+                self._deref(table[i])
+                table[i] = 0
+            if upto > floors[g]:
+                self._group_held[g] -= upto - floors[g]
+                freed += upto - floors[g]
+                floors[g] = upto
+        if freed:
+            self._reserved += freed
+            self._versions[seq_id] += 1
+            self.stats.window_blocks_freed += freed
+            self.stats.freed_total += freed
+        return freed
+
+    def group_report(self) -> list[dict]:
+        """A group: its window, its layers, the blocks live tables hold in
+        it now and the most they ever held. [] without groups."""
+        return [
+            {"window": window, "layers": list(layers),
+             "blocks": self._group_held[g],
+             "high_water_blocks": self._group_high[g]}
+            for g, (window, layers) in enumerate(self.cfg.groups)]
 
     def _deref(self, b: int, *, quarantine: bool = False) -> None:
         self._ref[b] -= 1
@@ -471,10 +598,16 @@ class PagedKVCache:
         self._versions.pop(seq_id, None)
         if seq_id in self._slots:
             self._free_slots.append(self._slots.pop(seq_id))
-        for b in reversed(table):  # LIFO: newest block reused first
-            self._deref(b, quarantine=quarantine)
-        self.stats.freed_total += len(table)
-        return len(table)
+        # a windowed group's entries behind its floor are 0: given back
+        floors = self._floor.pop(seq_id, (0,))
+        held = 0
+        for g, t in enumerate([table, *self._more.pop(seq_id, ())]):
+            for b in reversed(t[floors[g]:]):  # LIFO: newest reused first
+                self._deref(b, quarantine=quarantine)
+            self._group_held[g] -= len(t) - floors[g]
+            held += len(t) - floors[g]
+        self.stats.freed_total += held
+        return held
 
     def flush_quarantine(self) -> int:
         """Return quarantined blocks to the free list; -> count. The
@@ -927,13 +1060,20 @@ class PagedKVCache:
 
     def block_table(self, seq_id, pad_to: int) -> np.ndarray:
         """[pad_to] int32 table, unallocated tail padded with garbage
-        block 0 (those positions are always masked)."""
+        block 0 (those positions are always masked); with groups
+        ``[G, pad_to]``, one row a group (a windowed group's entries behind
+        its floor are block 0 too)."""
         table = self._tables[seq_id]
         if len(table) > pad_to:
             raise ValueError(
                 f"sequence {seq_id!r} holds {len(table)} blocks, "
                 f"table was asked to fit in {pad_to}"
             )
+        if self.cfg.groups:
+            out = np.zeros((len(self.cfg.groups), pad_to), np.int32)
+            for g, t in enumerate(self._group_tables(seq_id)):
+                out[g, : len(t)] = t
+            return out
         out = np.zeros((pad_to,), np.int32)
         out[: len(table)] = table
         return out
@@ -959,6 +1099,9 @@ class PagedKVCache:
             "live_sequences": len(self._tables),
             "state_slots": self.used_slots,
             "state_slots_high_water": s.state_slots_high_water,
+            "groups": self.group_report(),
+            "window_blocks_taken": s.window_blocks_taken,
+            "window_blocks_freed": s.window_blocks_freed,
             "utilization": round(self.utilization, 4),
             "high_water_blocks": s.high_water_blocks,
             "allocated_total": s.allocated_total,

@@ -555,6 +555,75 @@ def test_lfm2_moe_decode_step_compiles_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+def test_laguna_decode_step_compiles_at_published_widths(
+        one_chip, monkeypatch):
+    """The laguna cell's 64-row decode step, as the executor compiles it,
+    tables by group ``[4, 64, 1152]`` at the widest context bucket: both
+    pools are updated in place (nothing pool-sized among its temporaries:
+    8 layers' worth of slabs would be 4.3 GB), the two full layers call
+    ``paged_attention`` and the six sliding ones ``paged_attention_window``
+    at GQA groups of 6 and 8, the grouped product is fed the 32 held
+    experts' matrices as stored, and the new leaves reach their operations
+    under the names the benchmark's readers look for."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.models.laguna import laguna_init_state
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/laguna-xs.2-ep8-8l.json"))
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    init = common.load_named("reference", "laguna").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: laguna_init_state(cfg, 65)))
+    pool = _struct((cfg.n_kv_layer, 32769, 16, cfg.n_kv_head, cfg.head_dim),
+                   cfg.dtype, one_chip)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    B, groups = 64, len(cfg.kv_table_groups)
+    assert (groups, cfg.n_kv_layer) == (4, 2)
+    compiled = decode.DecodeFns("laguna", cfg, platform="tpu")._decode.lower(
+        params, pool, pool, i32((B,)), i32((B,)), i32((groups, B, 1152)),
+        sample=None, state=state, slots=i32((B,)),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 7.0e9 < mem.argument_size_in_bytes < 7.5e9  # 2.96 + 4.29 GB
+    assert mem.alias_size_in_bytes >= 2 * pool.size * 2  # both pools
+    assert mem.temp_size_in_bytes < 0.2e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    full = len(re.findall(r"%paged_attention[.\d]* = ", entry))
+    sliding = len(re.findall(r"%paged_attention_window[.\d]* = ", entry))
+    assert (full, sliding) == (2, 6), (full, sliding)
+    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
+    assert len(calls) == 14  # two grouped products in each of 7 layers
+    # gate/up as stored; the smaller down matrices (32 MB a layer) the
+    # compiler may bring into fast memory in slices first
+    assert sum(bool(re.search(r"%params__layers___\d___moe_gmm_w_in__", c))
+               for c in calls) == 7
+    produced = [ln for ln in entry.splitlines()
+                if re.search(r"= \w+\[32,(2048|512),(1024|2048)\]", ln)
+                and " parameter(" not in ln
+                and "S(1)" not in ln]  # staged into fast memory, no cast
+    assert not produced, produced[:2]
+    for needle in ("moe_route_w", "moe_shared_w_in", "moe_shared_w_out",
+                   "attn_gate_w"):
+        assert re.search(rf"\(.*%params__layers___\d___{needle}__", entry), \
+            needle
+    assert "cross_program_prefetch_index" not in text
+
+
 @pytest.mark.parametrize("family", ["gpt", "llama"])
 def test_other_families_programs_are_what_their_functions_compile_to(
         one_chip, family):
