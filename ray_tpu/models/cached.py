@@ -9,9 +9,8 @@ own layer index and attending over the paged context there, then the head
 on some of the rows and a sampling epilogue. The pool is a buffer the step
 OWNS: the step programs donate it (serve/llm/decode.py ``_jit_named``),
 each layer scatters B x S rows into it where it stands and the kernel
-reads the whole pool at a layer index (``attend_layer`` says for which
-pools a layer's slab still goes out and back, inside the one pool). This
-file owns that step; a family's file
+reads the whole pool at a layer index (``attend_layer``). This file owns
+that step; a family's file
 (models/gpt.py, llama.py, lfm2_moe.py) holds only what is the family's
 own, in a ``CachedFamily``:
 
@@ -64,7 +63,6 @@ from ray_tpu.ops.kv_cache import write_kv
 from ray_tpu.ops.paged_attention import (
     decode_attention,
     prefill_attention,
-    reads_pool_in_place,
     resolve_backend,
 )
 from ray_tpu.ops.sampling import sample_tokens, verify_tokens
@@ -139,38 +137,12 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
     slot]``, then the attention call the kind asks for reads the pools at
     that layer. Nothing slices a layer's slab out of a pool or writes one
-    back. Returns (attention output [B, S, Hq * hd], cache_k', cache_v').
-    ``tables`` [B, NB]: the layer's own table (None: ``step``'s);
-    ``window``: sliding attention over the last ``window`` positions.
-
-    The exception is a pool whose pages are not whole tiles
-    (``reads_pool_in_place``: heads of 64, 12 heads, a ``tp`` shard's 2).
-    XLA relays whatever the scatter and the kernel are handed of such a
-    pool, and handed the whole pool it relays the whole pool around the
-    layer loop (compiled for a v5e at GPT-2's widths: 6.4 GB of
-    temporaries; PERF.md, PR 29). There the layer's slab is taken out of
-    the pool, written and attended as one layer's pool, and put back where
-    it was, in place: what the scan did with the pool as xs -> ys, less
-    the second pool."""
-    if reads_pool_in_place(cache_k):
-        return _attend(step, cache_k, cache_v, layer, q, k, v, cfg, tables,
-                       window)
-    # lax's own index ops: ``a[layer]`` / ``.at[layer].set`` wrap a traced
-    # index in bounds handling that costs two more passes over the slab
-    slabs = jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
-        (cache_k, cache_v))
-    attn, *slabs = _attend(step, *slabs, None, q, k, v, cfg, tables, window)
-    cache_k, cache_v = jax.tree.map(
-        lambda a, slab: jax.lax.dynamic_update_index_in_dim(a, slab, layer, 0),
-        (cache_k, cache_v), tuple(slabs))
-    return attn, cache_k, cache_v
-
-
-def _attend(step: Step, cache_k, cache_v, layer, q, k, v, cfg, tables=None,
-            window=None):
-    """``attend_layer`` on pools read at ``layer``, or (None) on one
-    layer's."""
+    back, whatever the heads: a pool whose pages would not be whole tiles
+    is stored lane-dense (ops/paged_attention.py ``pool_shape``), so every
+    pool rests in the order the scatter and the kernel read. Returns
+    (attention output [B, S, Hq * hd], cache_k', cache_v'). ``tables``
+    [B, NB]: the layer's own table (None: ``step``'s); ``window``: sliding
+    attention over the last ``window`` positions."""
     B, S = q.shape[:2]
     backend = cfg.attention_backend
     if tables is None:
@@ -293,8 +265,9 @@ def steps(fam: CachedFamily):
     ``<fam.name>_<step>``: a jitted program takes its name from there.
 
     All take ``(params, cache_k, cache_v, ...)``, the pool ``[n_kv_layer,
-    num_blocks, block_size, n_kv_head, head_dim]`` (block 0 is the garbage
-    sink), ``block_tables [B, NB]`` (``[G, B, NB]`` for a family whose
+    num_blocks, block_size, n_kv_head, head_dim]`` or lane-dense ``[..,
+    n_kv_head * head_dim]`` (ops/paged_attention.py ``pool_shape``; block 0
+    is the garbage sink), ``block_tables [B, NB]`` (``[G, B, NB]`` for a family whose
     layers name their group), the static ``cfg``, and by keyword
     ``sample`` (an ops/sampling.py pytree: sampling then runs inside the
     program and token ids come back, not logits), ``state`` and ``slots``.

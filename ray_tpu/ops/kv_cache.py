@@ -6,7 +6,12 @@ layer's keys/values as fixed-size physical blocks
 
     k_layer, v_layer: [num_blocks, block_size, n_kv_head, head_dim]
 
-and each sequence owns a BLOCK TABLE — logical position p of sequence b
+or, where ``[n_kv_head, head_dim]`` is not whole (8, 128) tiles, with a
+token's heads as ONE lane-dense row, ``[num_blocks, block_size, n_kv_head *
+head_dim]`` (ops/paged_attention.py ``pool_shape`` says which; the cache
+manager allocates it). Every function here takes either: a write flattens
+its rows to the pool's, a read splits the row into heads of q's size after
+indexing. Each sequence owns a BLOCK TABLE — logical position p of sequence b
 lives at (block_tables[b, p // block_size], p % block_size). Block tables
 are dense int32 arrays padded with block 0, which is reserved as a garbage
 sink: every out-of-range or padding write is redirected there, so the
@@ -91,32 +96,43 @@ def write_kv(
     data and scale with the same (blk, slot) indices, so incremental
     decode appends never touch (or re-quantize) previously written slots.
     """
-    block_size = k_layer.shape[-3]
+    # [.., block_size, H_kv, hd], or lane-dense [.., block_size, H_kv * hd]:
+    # a token's K/V then lands as ONE row
+    lead = 1 if layer is None else 2
+    block_size = k_layer.shape[lead]
+    row = k_layer.shape[lead + 1:]
     blk, slot = physical_slots(positions, block_tables, block_size)
     if valid is not None:
         blk = jnp.where(valid, blk, 0)
         slot = jnp.where(valid, slot, 0)
     at = (blk, slot) if layer is None else (layer, blk, slot)
+
+    def rows(x):
+        return x.reshape(*blk.shape, *row)
+
     if isinstance(k_layer, QuantizedKV):
         kind = "int8" if k_layer.data.dtype == jnp.int8 else "fp8"
         kq, ks = quantize_kv(k, kind)
         vq, vs = quantize_kv(v, kind)
         k_layer = QuantizedKV(
-            k_layer.data.at[at].set(kq), k_layer.scale.at[at].set(ks))
+            k_layer.data.at[at].set(rows(kq)), k_layer.scale.at[at].set(ks))
         v_layer = QuantizedKV(
-            v_layer.data.at[at].set(vq), v_layer.scale.at[at].set(vs))
+            v_layer.data.at[at].set(rows(vq)), v_layer.scale.at[at].set(vs))
         return k_layer, v_layer
-    k_layer = k_layer.at[at].set(k.astype(k_layer.dtype))
-    v_layer = v_layer.at[at].set(v.astype(v_layer.dtype))
+    k_layer = k_layer.at[at].set(rows(k.astype(k_layer.dtype)))
+    v_layer = v_layer.at[at].set(rows(v.astype(v_layer.dtype)))
     return k_layer, v_layer
 
 
 def gather_kv(
-    k_layer: jax.Array, v_layer: jax.Array, block_tables: jax.Array
+    k_layer: jax.Array, v_layer: jax.Array, block_tables: jax.Array,
+    *, head_dim: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Materialize each sequence's cached context in position order:
     [B, NB * block_size, H_kv, hd]. Unallocated table entries point at the
-    garbage block; the caller masks those positions.
+    garbage block; the caller masks those positions. ``head_dim`` (q's):
+    what splits a lane-dense layer's rows into heads, after the indexing;
+    None reads it off a layer stored by heads.
 
     For a ``QuantizedKV`` pool this is the sanctioned XLA-fallback dequant
     (f32 out): the gathered context is ONE sequence batch's working set,
@@ -124,20 +140,19 @@ def gather_kv(
     tests/test_sanitizers.py allowlists exactly this function and the
     streaming slab path below."""
     B, NB = block_tables.shape
-    _, Bs, H, hd = k_layer.shape
-    if isinstance(k_layer, QuantizedKV):
-        keys = (
-            k_layer.data[block_tables].astype(jnp.float32)
-            * k_layer.scale[block_tables][..., None]
-        ).reshape(B, NB * Bs, H, hd)
-        values = (
-            v_layer.data[block_tables].astype(jnp.float32)
-            * v_layer.scale[block_tables][..., None]
-        ).reshape(B, NB * Bs, H, hd)
-        return keys, values
-    keys = k_layer[block_tables].reshape(B, NB * Bs, H, hd)
-    values = v_layer[block_tables].reshape(B, NB * Bs, H, hd)
-    return keys, values
+    Bs = k_layer.shape[1]
+    hd = k_layer.shape[-1] if head_dim is None else head_dim
+
+    def context(layer):
+        if not isinstance(layer, QuantizedKV):
+            return layer[block_tables].reshape(B, NB * Bs, -1, hd)
+        data = layer.data[block_tables].astype(jnp.float32)
+        return (
+            data.reshape(B, NB, Bs, -1, hd)
+            * layer.scale[block_tables][..., None]
+        ).reshape(B, NB * Bs, -1, hd)
+
+    return context(k_layer), context(v_layer)
 
 
 # Context length (NB * block_size) at and above which
@@ -170,6 +185,10 @@ def _paged_prefill_streaming(
     bs = k_layer.shape[1]
     NB = block_tables.shape[1]
 
+    def heads(x):
+        # a lane-dense slab's rows split into heads; a no-op otherwise
+        return x.reshape(B, bs, Hkv, hd)
+
     def _slab(carry, xs):
         m, l, acc = carry
         i, blk = xs
@@ -177,11 +196,11 @@ def _paged_prefill_streaming(
             # per-slab dequant (one block's worth, in registers/VMEM —
             # never the whole pool); allowlisted by the dequant lint.
             kb, vb = k_layer[blk], v_layer[blk]
-            keys = kb.data.astype(jnp.float32) * kb.scale[..., None]
-            values = vb.data.astype(jnp.float32) * vb.scale[..., None]
+            keys = heads(kb.data.astype(jnp.float32)) * kb.scale[..., None]
+            values = heads(vb.data.astype(jnp.float32)) * vb.scale[..., None]
         else:
-            keys = k_layer[blk]      # [B, bs, Hkv, hd]
-            values = v_layer[blk]
+            keys = heads(k_layer[blk])      # [B, bs, Hkv, hd]
+            values = heads(v_layer[blk])
         s = jnp.einsum(
             "bshgd,bthd->bshgt", qg, keys,
             preferred_element_type=jnp.float32,
@@ -247,7 +266,7 @@ def paged_prefill_attention(
     """
     B, S, Hq, hd = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    Hkv = k_layer.shape[2]
+    Hkv = math.prod(k_layer.shape[2:]) // hd  # by heads, or lane-dense
     # GQA without materializing rep x copies of K/V: queries regroup onto
     # their shared KV head ([B,S,Hq,hd] -> [B,S,Hkv,G,hd] — query head h
     # serves kv head h // G) and the einsums contract against the COMPACT
@@ -260,7 +279,8 @@ def paged_prefill_attention(
             scale=scale, window=window,
         )
         return out.reshape(B, S, Hq, hd).astype(q.dtype)
-    keys, values = gather_kv(k_layer, v_layer, block_tables)  # [B,T,Hkv,hd]
+    keys, values = gather_kv(
+        k_layer, v_layer, block_tables, head_dim=hd)  # [B, T, Hkv, hd]
     logits = jnp.einsum(
         "bshgd,bthd->bshgt", qg, keys, preferred_element_type=jnp.float32
     ) * scale
@@ -283,8 +303,8 @@ def paged_prefill_attention(
 def _copy_blocks(
     cache_k: jax.Array, cache_v: jax.Array, src: jax.Array, dst: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    # cache_k/v: [n_layer, num_blocks, block_size, H_kv, hd] (plain pools)
-    # or QuantizedKV pytrees whose scale leaf drops the trailing hd axis;
+    # cache_k/v: [n_layer, num_blocks, block_size, ...] (plain pools, by
+    # heads or lane-dense) or QuantizedKV pytrees with their scale planes;
     # src/dst: [P]. The tree map moves every leaf — quantized COW clones
     # data AND scale planes in the same fused op, no dequant round-trip.
     def _cp(a):
@@ -300,9 +320,9 @@ def _land_blocks(
     k_new: jax.Array,
     v_new: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
-    # cache_k/v: [n_layer, num_blocks, block_size, H_kv, hd] pools (or
+    # cache_k/v: [n_layer, num_blocks, block_size, ...] pools (or
     # QuantizedKV pytrees); blocks: [P]; k_new/v_new: matching payloads
-    # [n_layer, P, ...] per leaf. Quantized handoffs land the wire's
+    # [n_layer, P, ...] per leaf, in the pool's own trailing shape. Quantized handoffs land the wire's
     # already-quantized data and scale planes verbatim — bit-exact with
     # the exporter's pool, which is what keeps disaggregated streams
     # byte-identical within a quantized config.
@@ -357,7 +377,8 @@ def paged_attention(
     """
     B, Hq, hd = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    keys, values = gather_kv(k_layer, v_layer, block_tables)  # [B, T, Hkv, hd]
+    keys, values = gather_kv(
+        k_layer, v_layer, block_tables, head_dim=hd)  # [B, T, Hkv, hd]
     Hkv = keys.shape[2]
     # GQA via grouped einsum over the compact KV heads (see
     # paged_prefill_attention) — no rep x K/V expansion in HBM.

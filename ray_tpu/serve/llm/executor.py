@@ -375,16 +375,39 @@ class ModelExecutor:
                 )
 
             return (
-                jax.tree.map(_empty, self.cache.k),
-                jax.tree.map(_empty, self.cache.v),
+                self._by_heads(jax.tree.map(_empty, self.cache.k)),
+                self._by_heads(jax.tree.map(_empty, self.cache.v)),
             )
         width = 1 << (len(block_ids) - 1).bit_length()
         ids = np.zeros((width,), np.int32)
         for i, b in enumerate(block_ids):
             ids[i] = b
-        k = _host_blocks(self.cache.k[:, self._dev(ids)])
-        v = _host_blocks(self.cache.v[:, self._dev(ids)])
+        k = self._by_heads(_host_blocks(self.cache.k[:, self._dev(ids)]))
+        v = self._by_heads(_host_blocks(self.cache.v[:, self._dev(ids)]))
         return k[:, : len(block_ids)], v[:, : len(block_ids)]
+
+    def _by_heads(self, blocks):
+        """Host blocks as they leave the pool -> ``[n_layer, n, block_size,
+        H_kv, hd]``, whatever shape the pool is stored in (a lane-dense
+        pool's rows split into heads: a reshape of a host array, the same
+        bytes). What the wire, the host tier and a peer's pool see never
+        depends on the stored shape."""
+        cfg = self.cache.cfg
+
+        def split(a):
+            return a.reshape(a.shape[:3] + (cfg.n_kv_head, cfg.head_dim))
+
+        if isinstance(blocks, np.ndarray):
+            return split(blocks)
+        return type(blocks)(split(blocks.data), blocks.scale)
+
+    def _as_stored(self, blocks):
+        """The inverse of ``_by_heads``, for blocks about to land."""
+        import jax
+
+        return jax.tree.map(
+            lambda a, pool: np.reshape(a, a.shape[:2] + pool.shape[2:]),
+            blocks, self.cache.k)
 
     def land_blocks(
         self, block_ids: list[int], k_new: np.ndarray, v_new: np.ndarray
@@ -410,6 +433,7 @@ class ModelExecutor:
         ids = np.zeros((width,), np.int32)
         for i, b in enumerate(block_ids):
             ids[i] = b
+        k_new, v_new = self._as_stored(k_new), self._as_stored(v_new)
         if width != len(block_ids):
 
             def _pad(a):
@@ -587,7 +611,10 @@ class ModelExecutor:
                 "arrays": {k: list(v.shape)
                            for k, v in self.cache.state.items()},
             }
-        report = {"kv_layers": cfg.n_layer, "state": state,
+        # the shape the pool is STORED in says which layout a run ran: by
+        # heads, or lane-dense (kv_cache.py)
+        report = {"kv_layers": cfg.n_layer,
+                  "kv_pool_shape": list(self.cache.k.shape), "state": state,
                   "prefix_reuse": cfg.prefix_reuse}
         if cfg.groups:
             # tables by group: ``kv_layers`` is then a GROUP's layers (the
@@ -693,8 +720,10 @@ class ShardedExecutor(ModelExecutor):
       DEFAULT_RULES — heads/mlp/vocab shard over tp (Megatron), embed
       over fsdp (ZeRO-3); exactly the layout the training side proves.
     - paged KV pool: ``cache.k``/``cache.v``
-      ([layer, block, slot, kv_head, head_dim]) shard along the KV-HEAD
-      axis over tp and replicate over fsdp. Block granularity, tables,
+      ([layer, block, slot, kv_head, head_dim], or lane-dense [layer,
+      block, slot, kv_head * head_dim] where a device's heads are not
+      whole tiles) shard along the KV-HEAD axis, a lane-dense row into
+      contiguous heads, over tp and replicate over fsdp. Block granularity, tables,
       prefix hashes, COW and quarantine bookkeeping stay host-side in
       kv_cache.py, byte-for-byte the single-chip code.
 
@@ -751,9 +780,12 @@ class ShardedExecutor(ModelExecutor):
         # the committed shards and keeps their sharding.
         self._maybe_quantize_params()
         self._store_compute_dtype()
-        # The KV-head axis (axis 3) is the tp shard axis for the 5-d data
-        # plane AND the 4-d scale plane of a quantized pool — one spec
-        # serves both leaves.
+        # A device holds n_kv / tp heads: the pools are stored as THAT
+        # page asks (kv_cache.py ``stored_for``). Axis 3 is then the tp
+        # shard axis of every leaf: the KV heads of a pool by heads and of
+        # a quantized pool's scale plane, a lane-dense pool's row of heads
+        # (contiguous heads a device) — one spec serves them all.
+        cache.stored_for(tp_size)
         kv_spec = PartitionSpec(None, None, None, AxisNames.TENSOR)
         sh = NamedSharding(self.mesh, kv_spec)
         cache.k = jax.tree.map(lambda a: jax.device_put(a, sh), cache.k)

@@ -6,7 +6,18 @@ structure): the cache is ONE preallocated array pair per model —
 
     k, v: [n_layer, num_blocks, block_size, n_kv_head, head_dim]
 
-— and sequences own logical-position-ordered lists of physical block ids.
+— or, where the ``[n_kv_head, head_dim]`` one device holds is not whole
+(8, 128) tiles (heads of 64, 12 heads, a ``tp`` shard's 2), with a token's
+heads as one lane-dense row,
+
+    k, v: [n_layer, num_blocks, block_size, n_kv_head * head_dim]
+
+(ops/paged_attention.py ``pool_shape``: the order such a pool rests in on
+the chip is then the order written, and the step programs read and write
+it where it stands; ``stored_for`` re-lays the pools for a ``tp`` mesh).
+The stored shape stays on the device: what leaves the pool for the host
+(export, the host tier, the RTKV wire) is by heads, byte for byte. Sequences
+own logical-position-ordered lists of physical block ids.
 Fragmentation-free growth (append one block at a time), O(1) free, and
 blocks returned on sequence completion are immediately reusable, so the
 steady-state footprint is set by CONCURRENT tokens, not total traffic.
@@ -306,10 +317,8 @@ class PagedKVCache:
         self._free_slots: list[int] = list(range(cfg.state_slots - 1, 0, -1))
         self._slots: dict[Any, int] = {}
         dtype = cfg.dtype if cfg.dtype is not None else jnp.bfloat16
-        shape = (
-            cfg.n_layer, cfg.num_blocks, cfg.block_size,
-            cfg.n_kv_head, cfg.head_dim,
-        )
+        shape = self.pool_shape()
+        scales = shape[:3] + (cfg.n_kv_head,)
         if cfg.quantization is not None:
             from ray_tpu.ops.quantization import (
                 QuantizedKV,
@@ -323,10 +332,10 @@ class PagedKVCache:
             # scale planes — write_kv quantizes at exactly this
             # granularity, so appends never re-quantize a block.
             self.k = QuantizedKV(
-                jnp.zeros(shape, qdt), jnp.zeros(shape[:-1], jnp.float32)
+                jnp.zeros(shape, qdt), jnp.zeros(scales, jnp.float32)
             )
             self.v = QuantizedKV(
-                jnp.zeros(shape, qdt), jnp.zeros(shape[:-1], jnp.float32)
+                jnp.zeros(shape, qdt), jnp.zeros(scales, jnp.float32)
             )
         else:
             self.k = jnp.zeros(shape, dtype)
@@ -397,6 +406,31 @@ class PagedKVCache:
         # before landing loses nothing.
         self._unlanded: set[int] = set()
         self.stats = CacheStats()
+
+    # ---------------- the pools' stored shape ----------------
+
+    def pool_shape(self, tp: int = 1) -> tuple[int, ...]:
+        """The shape ``k`` / ``v`` (a quantized pool's data) are stored in
+        where a ``tp`` mesh splits the heads: by heads, or lane-dense
+        (ops/paged_attention.py ``pool_shape``)."""
+        from ray_tpu.ops.paged_attention import pool_shape
+
+        cfg = self.cfg
+        return pool_shape(cfg.n_layer, cfg.num_blocks, cfg.block_size,
+                          cfg.n_kv_head, cfg.head_dim, tp)
+
+    def stored_for(self, tp: int) -> None:
+        """Re-lay the pools as a ``tp`` mesh holds them (``ShardedExecutor``,
+        before it places them): fewer heads a device can only turn a pool
+        by heads into a lane-dense one, a reshape of the same bytes. The
+        scale planes stay ``[.., block_size, n_kv_head]``."""
+        import jax
+
+        shape = self.pool_shape(tp)
+        # only a leaf laid by heads (5-D) has anything to re-lay
+        self.k, self.v = jax.tree.map(
+            lambda a: a.reshape(shape) if a.ndim == 5 else a,
+            (self.k, self.v))
 
     # ---------------- reservation (admission control) ----------------
 

@@ -63,10 +63,13 @@ class _Served:
         import jax.numpy as jnp
 
         self.fam, self.cfg, self.params = fam, cfg, params
-        n_kv = getattr(cfg, "n_kv_head", cfg.n_head)
-        self.k = self.v = jnp.zeros(
-            (getattr(cfg, "n_kv_layer", cfg.n_layer), 1 + 2 * NB, BS, n_kv,
-             cfg.head_dim), cfg.dtype)
+        from ray_tpu.ops.paged_attention import pool_shape
+
+        # as the cache manager stores it: by heads, or lane-dense
+        self.n_kv = getattr(cfg, "n_kv_head", cfg.n_head)
+        self.k = self.v = jnp.zeros(pool_shape(
+            getattr(cfg, "n_kv_layer", cfg.n_layer), 1 + 2 * NB, BS,
+            self.n_kv, cfg.head_dim), cfg.dtype)
         self.tables = jnp.asarray(
             [[1 + r * NB + i for i in range(NB)] for r in range(2)],
             jnp.int32)
@@ -193,7 +196,7 @@ def _walk_by_slabs(fam, x, layers, cache_k, cache_v, step, state, cfg):
             lp, *kv = xs
 
             def attend(q, k, v):
-                attn, kv[0], kv[1] = cached._attend(
+                attn, kv[0], kv[1] = cached.attend_layer(
                     step, *kv, None, q, k, v, cfg)
                 return attn
 
@@ -207,7 +210,7 @@ def _walk_by_slabs(fam, x, layers, cache_k, cache_v, step, state, cfg):
 
     def attend(q, k, v):
         nonlocal attended
-        attn, *slabs = cached._attend(
+        attn, *slabs = cached.attend_layer(
             step, *jax.tree.map(lambda a: a[attended], tuple(pools)), None,
             q, k, v, cfg)
         pools[:] = jax.tree.map(
@@ -221,15 +224,14 @@ def _walk_by_slabs(fam, x, layers, cache_k, cache_v, step, state, cfg):
     return x, *pools, state
 
 
-# pages of whole (8, 128) tiles take the whole-pool path, the tiny presets'
-# pages (2 heads of 16) the slab path: ops/paged_attention.py
-# ``reads_pool_in_place``
+# pages of whole (8, 128) tiles are stored by heads, the tiny presets' pages
+# (2 heads of 16) lane-dense: ops/paged_attention.py ``pool_shape``
 TILES = {"gpt": {"n_head": 8, "d_model": 1024},
          "llama": {"n_head": 8, "n_kv_head": 8, "d_model": 1024},
          "lfm2_moe": {"n_head": 8, "n_kv_head": 8, "head_dim": 128}}
 
 
-def _random_pool(rng, shape, quant):
+def _random_pool(rng, shape, n_kv, quant):
     import jax.numpy as jnp
 
     from ray_tpu.ops.quantization import QuantizedKV
@@ -238,7 +240,8 @@ def _random_pool(rng, shape, quant):
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
     return QuantizedKV(
         jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
-        jnp.asarray(rng.uniform(0.01, 0.1, shape[:-1]), jnp.float32))
+        jnp.asarray(rng.uniform(0.01, 0.1, shape[:3] + (n_kv,)),
+                    jnp.float32))
 
 
 @pytest.mark.parametrize("quant", [None, "int8"])
@@ -256,7 +259,6 @@ def test_step_leaves_the_pool_the_slab_walk_left(
     import jax.numpy as jnp
 
     from ray_tpu.models import cached
-    from ray_tpu.ops.paged_attention import reads_pool_in_place
 
     fam, cfg, params, _, seqs = families[family]
     if kind == "verify" and fam.verify_step is None:
@@ -269,9 +271,9 @@ def test_step_leaves_the_pool_the_slab_walk_left(
     def run(walk):
         rng = np.random.default_rng(29)
         served = _Served(fam, cfg, params)
-        served.k = _random_pool(rng, served.k.shape, quant)
-        served.v = _random_pool(rng, served.k.shape, quant)
-        assert reads_pool_in_place(served.k) == (pages == "tiles")
+        served.k = _random_pool(rng, served.k.shape, served.n_kv, quant)
+        served.v = _random_pool(rng, served.k.shape, served.n_kv, quant)
+        assert (served.k.ndim == 5) == (pages == "tiles")
         with monkeypatch.context() as m:
             if walk is not None:
                 m.setattr(cached, "_walk", walk)

@@ -172,7 +172,7 @@ def test_gpt_loss_fused_vs_unfused(jax_cpu):
 
 
 def _paged_setup(key, lengths, n_kv_head, head_dim, block_size, n_blocks_per_seq,
-                 shuffle):
+                 shuffle, lane_dense=False):
     """Build a paged KV pool holding ragged sequences.
 
     Returns (k_contig, v_contig, k_layer, v_layer, block_tables): contiguous
@@ -181,7 +181,10 @@ def _paged_setup(key, lengths, n_kv_head, head_dim, block_size, n_blocks_per_seq
     pre-filled with noise (so any accidental read of an unowned block is
     loud), tables of sequences shorter than the capacity are padded with 0,
     and `shuffle` scrambles the physical id assignment so tests cover
-    non-contiguous layouts."""
+    non-contiguous layouts. `lane_dense` stores a token's heads as ONE row,
+    [num_blocks, block_size, Hkv * hd] (what the cache manager does where
+    [Hkv, hd] is not whole tiles): the same values, written by the same
+    `write_kv`."""
     import random as _random
 
     import jax
@@ -212,6 +215,9 @@ def _paged_setup(key, lengths, n_kv_head, head_dim, block_size, n_blocks_per_seq
     pool_shape = (num_blocks, block_size, n_kv_head, head_dim)
     k_layer = jax.random.normal(jax.random.fold_in(key, 3), pool_shape)
     v_layer = jax.random.normal(jax.random.fold_in(key, 4), pool_shape)
+    if lane_dense:
+        k_layer, v_layer = (
+            x.reshape(num_blocks, block_size, -1) for x in (k_layer, v_layer))
     pos = jnp.broadcast_to(jnp.arange(T_cap, dtype=jnp.int32), (B, T_cap))
     valid = pos < jnp.asarray(lengths, jnp.int32)[:, None]
     k_layer, v_layer = write_kv(
@@ -220,9 +226,10 @@ def _paged_setup(key, lengths, n_kv_head, head_dim, block_size, n_blocks_per_seq
     return k_contig, v_contig, k_layer, v_layer, block_tables
 
 
+@pytest.mark.parametrize("stored", ["heads", "lane-dense"])
 @pytest.mark.parametrize("gqa", [1, 2, 4])
 @pytest.mark.parametrize("shuffle", [False, True])
-def test_paged_attention_matches_reference(jax_cpu, gqa, shuffle):
+def test_paged_attention_matches_reference(jax_cpu, gqa, shuffle, stored):
     """Decode-time paged attention == mha_reference's causal row at each
     sequence's last position, over ragged lengths, block-0-padded tables,
     and (shuffle=True) scrambled physical block ids."""
@@ -236,8 +243,9 @@ def test_paged_attention_matches_reference(jax_cpu, gqa, shuffle):
     Hkv, hd, bs, NB = 2, 32, 8, 4
     Hq = Hkv * gqa
     kc, vc, k_layer, v_layer, tables = _paged_setup(
-        key, lengths, Hkv, hd, bs, NB, shuffle
+        key, lengths, Hkv, hd, bs, NB, shuffle, stored == "lane-dense"
     )
+    assert k_layer.ndim == (3 if stored == "lane-dense" else 4)
     B, T_cap = kc.shape[:2]
     q_full = jax.random.normal(jax.random.fold_in(key, 5), (B, T_cap, Hq, hd))
     ref_full = mha_reference(  # [B, Hq, T_cap, hd]
@@ -257,10 +265,14 @@ def test_paged_attention_matches_reference(jax_cpu, gqa, shuffle):
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5, (gqa, shuffle)
 
 
+@pytest.mark.parametrize("stored", ["heads", "lane-dense", "lane-dense-stream"])
 @pytest.mark.parametrize("gqa", [1, 2, 4])
-def test_paged_prefill_attention_matches_reference(jax_cpu, gqa):
+def test_paged_prefill_attention_matches_reference(
+        jax_cpu, gqa, stored, monkeypatch):
     """Chunked-prefill paged attention == causal mha_reference on every
-    valid (non-padding) query row, shuffled tables + ragged lengths."""
+    valid (non-padding) query row, shuffled tables + ragged lengths; over a
+    pool stored by heads or lane-dense, the latter under the streaming scan
+    too."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import mha_reference
@@ -271,8 +283,13 @@ def test_paged_prefill_attention_matches_reference(jax_cpu, gqa):
     Hkv, hd, bs, NB = 2, 16, 8, 4
     Hq = Hkv * gqa
     kc, vc, k_layer, v_layer, tables = _paged_setup(
-        key, lengths, Hkv, hd, bs, NB, shuffle=True
+        key, lengths, Hkv, hd, bs, NB, shuffle=True,
+        lane_dense=stored != "heads"
     )
+    if stored == "lane-dense-stream":
+        import ray_tpu.ops.kv_cache as kvc
+
+        monkeypatch.setattr(kvc, "PREFILL_STREAM_MIN_T", 1)
     B, T_cap = kc.shape[:2]
     q_full = jax.random.normal(jax.random.fold_in(key, 5), (B, T_cap, Hq, hd))
     lens = jnp.asarray(lengths, jnp.int32)

@@ -151,12 +151,19 @@ def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle, quant=None):
     paged layers, so tests can build dense references without
     re-gathering. ``quant`` makes the pool a ``QuantizedKV`` of
     that kind (noise data under noise scales; ``write_kv`` quantizes what
-    it writes). A length of 0 is a padding row: an all-zero table."""
+    it writes). A length of 0 is a padding row: an all-zero table. The
+    layers are in the shape the cache manager STORES them in
+    (``pool_shape``): by heads where ``[Hkv, hd]`` is whole (8, 128)
+    tiles, lane-dense ``[num_blocks, bs, Hkv * hd]`` everywhere else:
+    every case below that ran the one-page walk (2 heads of 16 or 32,
+    heads of 64, 12 heads, a ``tp`` shard's 2) runs the compute-block
+    kernel over a lane-dense pool."""
     import random as _random
 
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.kv_cache import write_kv
+    from ray_tpu.ops.paged_attention import pool_shape
     from ray_tpu.ops.quantization import QuantizedKV, quant_dtype
 
     B = len(lengths)
@@ -176,14 +183,18 @@ def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle, quant=None):
     shape = (num_blocks, bs, Hkv, hd)
     k_layer = jax.random.normal(jax.random.fold_in(key, 3), shape)
     v_layer = jax.random.normal(jax.random.fold_in(key, 4), shape)
+    stored = pool_shape(1, num_blocks, bs, Hkv, hd)[1:]
     if quant is not None:
         k_layer, v_layer = (
             QuantizedKV(
-                (40.0 * x).astype(quant_dtype(quant)),
+                (40.0 * x).astype(quant_dtype(quant)).reshape(stored),
                 0.01 + jnp.abs(x[..., 0]),
             )
             for x in (k_layer, v_layer)
         )
+    else:
+        k_layer, v_layer = k_layer.reshape(stored), v_layer.reshape(stored)
+    assert (len(stored) == 4) == (Hkv % 8 == 0 and hd % 128 == 0)
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     valid = pos < jnp.asarray(lengths, jnp.int32)[:, None]
     k_layer, v_layer = write_kv(
@@ -362,8 +373,9 @@ def test_prefill_kernel_qblock_padding_and_jit(jax_cpu):
 
 # What a compute block of P pages adds to the walk (bs 8 and a table of 16
 # or more entries give P = 16: 128 tokens a block; 8 heads of 128 is the
-# page the kernel copies itself, 2 heads of 32 the page that keeps the
-# one-page walk). Each case:
+# page stored by heads, every other a lane-dense page, a head a lane slice
+# of it; 3 heads of 64 and 2 of 32 are rows of no whole lanes, padded for
+# the kernel). Each case:
 # (lengths, chunk S, table width NB, pool kwargs, kernel kwargs); the chunk
 # is the LAST S cached positions of each row, a length of 0 a padding row.
 _BLOCK_EDGES = {
@@ -393,12 +405,41 @@ _BLOCK_EDGES = {
     # a table narrower than a block: P = 1, and 2 with a third entry
     "P-forced-to-1": ([5, 8], 1, 1, {}, {}),
     "P-forced-to-2": ([5, 16, 19], 3, 3, {}, {}),
-    # a page Mosaic cannot slice in HBM: the one-page walk
+    # pages that are no whole tiles by heads: stored lane-dense
     "unaligned-heads-one-page-walk": (
         [140, 9], 1, 20, {"shuffle": True, "Hkv": 2, "hd": 32}, {}
     ),
     "unaligned-heads-int8-chunk": (
         [40, 150], 4, 20, {"quant": "int8", "Hkv": 2, "hd": 32}, {}
+    ),
+    # GPT-2's page (12 heads of 64: a row of 768 lanes), lfm2's (8 of 64)
+    "heads-of-64-x12-decode": (
+        [140, 9, 250], 1, 32, {"shuffle": True, "Hkv": 12, "hd": 64}, {}
+    ),
+    "heads-of-64-x12-chunk": (
+        [150, 250], 12, 32, {"shuffle": True, "Hkv": 12, "hd": 64},
+        {"q_block": 8}
+    ),
+    "heads-of-64-x8-window": (
+        [200, 255], 6, 32, {"shuffle": True, "Hkv": 8, "hd": 64},
+        {"window": 20}
+    ),
+    "heads-of-64-x8-int8": (
+        [140, 9, 250], 1, 32, {"quant": "int8", "Hkv": 8, "hd": 64}, {}
+    ),
+    "heads-of-64-x12-fp8-chunk": (
+        [150, 60], 5, 20, {"quant": "fp8", "Hkv": 12, "hd": 64}, {}
+    ),
+    # a tp shard's 2 heads of 128 (a row of 256 lanes) and 3 of 64 (192:
+    # no whole lanes, the slab padded for the kernel)
+    "tp-shard-2-heads-verify": (
+        [40, 150], 4, 20, {"shuffle": True, "Hkv": 2, "hd": 128}, {}
+    ),
+    "tp-shard-3-heads-of-64": (
+        [140, 250], 1, 32, {"shuffle": True, "Hkv": 3, "hd": 64}, {}
+    ),
+    "tp-shard-3-heads-of-64-int8": (
+        [40, 150], 4, 20, {"quant": "int8", "Hkv": 3, "hd": 64}, {}
     ),
 }
 
@@ -426,7 +467,7 @@ def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
     )
     # the block the case was built for is the one the function derives
     P, _ = _compute_block(
-        (bs, Hkv, hd), Hkv, hd, S * G, NB, jnp.float32, k_layer.dtype,
+        k_layer.shape[1:], Hkv, hd, S * G, NB, jnp.float32, k_layer.dtype,
         "quant" in pool_kw,
     )
     assert P == min(16, 1 << (NB.bit_length() - 1)), (edge, P)
@@ -453,11 +494,12 @@ def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
 @pytest.mark.parametrize("edge", [
     "shuffled-pages", "int8-scale-planes", "window-floor-inside-block",
     "chunk-start-inside-block", "unaligned-heads-one-page-walk",
-    "unaligned-heads-int8-chunk",
+    "unaligned-heads-int8-chunk", "heads-of-64-x12-chunk",
+    "heads-of-64-x8-int8", "tp-shard-3-heads-of-64",
 ])
 def test_kernel_reads_the_whole_pool_at_a_layer(jax_cpu, edge):
-    """``(pool, layer)``: both Pallas paths handed the WHOLE multi-layer
-    pool and a layer index (static, and traced under jit through the
+    """``(pool, layer)``: the kernel handed the WHOLE multi-layer pool, by
+    heads or lane-dense, and a layer index (static, and traced under jit through the
     dispatchers) give what the XLA formulation gives on ``pool[layer]``,
     and bit for bit what the same kernel gives on that slab alone;
     ``write_kv`` at a layer writes that slab's rows and no other
@@ -521,8 +563,8 @@ def test_kernel_reads_the_whole_pool_at_a_layer(jax_cpu, edge):
     k_new, v_new = jax.jit(lambda layer: write_kv(
         k_pool, v_pool, rows, 2 * rows, positions, tables, valid=valid,
         layer=layer))(jnp.int32(1))
-    k_slab, v_slab = write_kv(
-        *slabs[1][:2], rows, 2 * rows, positions, tables, valid=valid)
+    k_slab, v_slab = jax.jit(lambda k, v: write_kv(
+        k, v, rows, 2 * rows, positions, tables, valid=valid))(*slabs[1][:2])
     for pool, new, slab in ((k_pool, k_new, k_slab), (v_pool, v_new, v_slab)):
         for was, now, want in zip(*map(jax.tree.leaves, (pool, new, slab))):
             assert jnp.array_equal(now[1], want)
@@ -549,6 +591,11 @@ _BLOCK_CHOICES = {
     ),
     # a q tile whose own buffers leave no room: the floor
     "vmem-floor": (((16, 8, 128), 8, 128, 8192, 160, "float32", False), 1),
+    # lane-dense pages: the GPT-2 cell's (16 x 768) and lfm2's (16 x 512)
+    "gpt2-decode": (((16, 768), 12, 64, 1, 64, "bfloat16", False), 8),
+    "gpt2-prefill": (((16, 768), 12, 64, 128, 64, "bfloat16", False), 16),
+    "lfm2-decode": (((16, 512), 8, 64, 4, 160, "bfloat16", False), 8),
+    "lfm2-int8-prefill": (((16, 512), 8, 64, 512, 128, "int8", True), 16),
 }
 
 

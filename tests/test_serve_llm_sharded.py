@@ -54,8 +54,9 @@ def _drain(eng, streams, steps=400):
 
 def _kv_tp_axis(arr):
     """The mesh axis the pool array is partitioned over at its head dim
-    (index 3 of [layer, block, slot, kv_head, head_dim]); None if
-    replicated there."""
+    (index 3 of [layer, block, slot, kv_head, head_dim], and of a
+    lane-dense [layer, block, slot, kv_head * head_dim]: contiguous heads
+    a device); None if replicated there."""
     spec = arr.sharding.spec
     return spec[3] if len(spec) > 3 else None
 
@@ -102,7 +103,10 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               "weight_dtype": "float32",
                               "weight_bytes": 4 * eng.executor.num_params,
                               "attention_backend": "xla",
-                              "kv_layers": 2, "state": None,
+                              "kv_layers": 2,
+                              # lane-dense: 2 heads of 16 are no tile
+                              "kv_pool_shape": [2, 64, 8, 32],
+                              "state": None,
                               "prefix_reuse": True,
                               "speculative": None}
     assert eng.debug_dump()["executor"]["mesh"] == {"tp": 2, "fsdp": 2}
@@ -124,7 +128,9 @@ def test_single_device_default_unchanged(jax_cpu):
                                        "weight_bytes":
                                            4 * eng.executor.num_params,
                                        "attention_backend": "xla",
-                                       "kv_layers": 2, "state": None,
+                                       "kv_layers": 2,
+                                       "kv_pool_shape": [2, 64, 8, 32],
+                                       "state": None,
                                        "prefix_reuse": True,
                                        "speculative": None}
     assert len(eng.generate([5, 6, 7], max_new_tokens=4)) == 4
