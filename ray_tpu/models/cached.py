@@ -78,6 +78,7 @@ class CachedFamily:
     head: Callable
     open_state: Callable | None = None
     close_state: Callable | None = None
+    place: Callable | None = None
 
 
 class Step(NamedTuple):
@@ -97,6 +98,9 @@ class Step(NamedTuple):
     start: jax.Array | None    # [B] (chunk)
     slots: jax.Array | None    # [B] the rows' slots in ``state``
     aux: Any = None            # ``embed``'s second result
+    # [B, S] each token's index in the step's table, where its K/V is
+    # written and the mask stands (``CachedFamily.place``); None: ``pos``
+    at: jax.Array | None = None
 
     def table_pos(self, n: int) -> jax.Array:
         """``pos`` made safe to index a table of ``n`` rows: a padding
@@ -131,7 +135,7 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
 
 
 def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
-                 tables=None, window=None):
+                 tables=None, window=None, then=None):
     """The cache side of one attention layer, on the WHOLE pools and the
     layer's index in them (an int32 scalar, traced under the scan): the
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
@@ -142,22 +146,27 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     pool rests in the order the scatter and the kernel read. Returns
     (attention output [B, S, Hq * hd], cache_k', cache_v'). ``tables``
     [B, NB]: the layer's own table (None: ``step``'s); ``window``: sliding
-    attention over the last ``window`` positions."""
+    attention over the last ``window`` positions; ``then``: what else the
+    layer writes into the pools, after its K/V and before it attends."""
     B, S = q.shape[:2]
     backend = cfg.attention_backend
     if tables is None:
         tables = step.block_tables
     if step.kind == "decode":
+        at = step.rows if step.at is None else step.at[:, 0]
         cache_k, cache_v = write_kv(
-            cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
-            layer=layer)
+            cache_k, cache_v, k[:, 0], v[:, 0], at, tables, layer=layer)
+        if then is not None:
+            cache_k, cache_v = then(cache_k, cache_v, layer)
         attn = decode_attention(
-            q[:, 0], cache_k, cache_v, tables, step.rows, backend=backend,
+            q[:, 0], cache_k, cache_v, tables, at, backend=backend,
             layer=layer, window=window)
         return attn.reshape(B, S, -1), cache_k, cache_v
+    at = step.pos if step.at is None else step.at
     cache_k, cache_v = write_kv(
-        cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
-        layer=layer)
+        cache_k, cache_v, k, v, at, tables, valid=step.valid, layer=layer)
+    if then is not None:
+        cache_k, cache_v = then(cache_k, cache_v, layer)
     # The fresh-prompt shortcut attends over the UNQUANTIZED just-computed
     # k / v, the chunk alone (a prompt is prefilled once, at bucketed
     # shapes, where a kernel's grid buys nothing). Under a quantized pool
@@ -180,7 +189,7 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
         ).transpose(0, 2, 1, 3)
     else:
         attn = prefill_attention(
-            q, cache_k, cache_v, tables, jnp.where(step.valid, step.pos, 0),
+            q, cache_k, cache_v, tables, jnp.where(step.valid, at, 0),
             backend=backend, layer=layer, window=window)
     return attn.reshape(B, S, -1), cache_k, cache_v
 
@@ -196,9 +205,11 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
             x, state, *kv = carry
             lp, layer = xs
 
-            def attend(q, k, v):
+            def attend(q, k, v, *, group=None, then=None):
                 attn, kv[0], kv[1] = attend_layer(
-                    step, *kv, layer, q, k, v, cfg)
+                    step, *kv, layer, q, k, v, cfg,
+                    None if group is None else step.block_tables[group],
+                    then=then)
                 return attn
 
             x, state = fam.layer(x, lp, attend, step, state, cfg)
@@ -229,6 +240,8 @@ def _step(fam, kind, params, cache_k, cache_v, tokens, rows, block_tables,
           cfg, *, start=None, draft_len=None, sample=None, state=None,
           slots=None):
     step = _plan(kind, tokens, rows, block_tables, start, draft_len, slots)
+    if fam.place is not None:
+        step = step._replace(at=fam.place(step.pos, cfg))
     x, aux = fam.embed(params, tokens, step, cfg)
     step = step._replace(aux=aux)
     work = state if fam.open_state is None else fam.open_state(

@@ -83,11 +83,20 @@ def _laguna() -> Family:
                   counters=m.laguna_counters)
 
 
+def _evabyte() -> Family:
+    from ray_tpu.models import evabyte as m
+
+    # no verify step: a rejected draft would already be in its chunk's sum
+    return Family(m.evabyte_init, m.evabyte_prefill, m.evabyte_decode_step,
+                  None, m.evabyte_param_axes, m.evabyte_quant_axes,
+                  m.EvaByteConfig.tiny)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
     "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
-    "laguna": _laguna,
+    "laguna": _laguna, "evabyte": _evabyte,
 }
 
 

@@ -104,7 +104,13 @@ from ray_tpu.exceptions import (
 from ray_tpu.serve._shapes import pad_to_bucket, pow2_buckets
 from ray_tpu.serve.llm import obs, structured
 from ray_tpu.serve.llm.executor import build_executor
-from ray_tpu.serve.llm.kv_cache import KVCacheConfig, PagedKVCache, _block_key
+from ray_tpu.serve.llm.kv_cache import (
+    KVCacheConfig,
+    PagedKVCache,
+    _block_key,
+    group_kind,
+    is_composed,
+)
 from ray_tpu.util import metrics, tracing
 
 logger = logging.getLogger("ray_tpu.serve.llm")
@@ -507,7 +513,16 @@ class LLMEngine:
         # kv_cache.py "Tables by group"): one table a group, windowed
         # groups give blocks back. What cannot carry that is refused too.
         groups = tuple(getattr(model_cfg, "kv_table_groups", ()))
-        self._refuse_for_state(cfg, quant, self._stateful, bool(groups))
+        # ... or keeps a ring of one window's K/V and a table of chunk
+        # summaries that every layer reads, COMPOSED into a step's table
+        # (kv_cache.py "A ring and a table of slots"): its own refusals
+        composed = is_composed(groups)
+        self._refuse_for_state(
+            cfg, quant, self._stateful, bool(groups) and not composed,
+            composed)
+        # why no prompt prefix is reused (None: it is), for ``stats()``
+        self._prefix_reuse_why = self._no_prefix_reuse(
+            self._stateful, bool(groups), composed)
         n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
         # one slot per running sequence, and slot 0, the garbage sink
         slots = cfg.max_batch_size + 1 if self._stateful else 0
@@ -527,7 +542,7 @@ class LLMEngine:
                 # a prefix hit would need the recurrent state as it stood
                 # at the block boundary, or a windowed group's blocks there,
                 # which were given back: no reuse for such a family
-                prefix_reuse=not self._stateful and not groups,
+                prefix_reuse=self._prefix_reuse_why is None,
                 groups=groups,
             ),
             state=(family.init_state(model_cfg, slots)
@@ -539,7 +554,18 @@ class LLMEngine:
         # the window of the sliding layers (0: none): what their calls
         # attend of a row, for ``executor.dispatch``'s ``kv_tokens_window``
         self._kv_window = max(
-            (window or 0 for window, _ in groups), default=0)
+            (window for window, _ in groups
+             if group_kind(window) == "sliding"), default=0)
+        # a composed family's (window, chunk): a decode row attends ``t mod
+        # W + 1`` exact rows and ``W / C`` summaries a closed window
+        # (``kv_tokens_window``, ``kv_chunks``), and its steps are counted
+        # by the chunks and windows they complete
+        self._kv_ring = self.cache.cfg.window_chunk
+        self._eva_counts = dict.fromkeys(
+            ("chunks_written_prefill", "chunks_written_decode",
+             "windows_closed_prefill", "windows_closed_decode"), 0)
+        if composed:
+            self._check_composed_chunks(cfg, *self._kv_ring)
         self._kv_room = self.cache.cfg.prefill_room(
             cfg.max_prefill_batch,
             min(cfg.prefill_chunk_tokens or model_cfg.max_seq_len,
@@ -591,6 +617,13 @@ class LLMEngine:
                     f"length bucket {b} is not a multiple of "
                     f"block_size={cfg.block_size}"
                 )
+        # A composed table has ONE width whatever the context's bucket,
+        # that of the longest: its width follows the windows closed, which
+        # no step's time follows (the kernel copies the pages it attends),
+        # so a width a bucket would only be programs.
+        self._composed_nb = self.cache.cfg.composed_blocks(
+            min(self._length_buckets[-1], model_cfg.max_seq_len)
+        ) if composed else None
         self._lock = threading.RLock()
         self._work = threading.Condition(self._lock)
         self._waiting: deque[_Request] = deque()
@@ -826,11 +859,47 @@ class LLMEngine:
         self.executor.phases = self._step_phases
 
     @staticmethod
+    def _no_prefix_reuse(stateful: bool, grouped: bool,
+                         composed: bool) -> str | None:
+        """Why a prompt's prefix is never mapped onto resident blocks for
+        such a family (``prefix_caching`` then finds nothing, silently: it
+        is on by default); None where it is reused."""
+        if stateful:
+            return ("a hit would need the per-sequence state as it stood "
+                    "at the block's boundary")
+        if composed:
+            return ("a hit would need the ring as it stood at the hit's "
+                    "boundary, and the summary blocks are not "
+                    "content-addressed")
+        if grouped:
+            return ("a hit would need the windowed groups' blocks at its "
+                    "boundary, which were given back")
+        return None
+
+    @staticmethod
+    def _check_composed_chunks(cfg: EngineConfig, window: int,
+                               chunk: int) -> None:
+        """A composed family's prefill step lies inside ONE window and
+        starts on a chunk's first position, so that its queries share a
+        composed table and its whole chunks are summarised from the fresh
+        K/V: ``prefill_chunk_tokens`` divides the window and is whole
+        chunks (steps start on its multiples)."""
+        cap = cfg.prefill_chunk_tokens
+        if cap is None or window % cap or cap % chunk:
+            raise ValueError(
+                f"model {cfg.model!r} attends exactly inside windows of "
+                f"{window} positions and by chunks of {chunk} behind them: "
+                f"prefill_chunk_tokens must divide {window} and be a "
+                f"multiple of {chunk}, so that a prefill step lies inside "
+                f"one window and summarises whole chunks; it is {cap}")
+
+    @staticmethod
     def _refuse_for_state(cfg: EngineConfig, quant, stateful: bool,
-                          grouped: bool) -> None:
+                          grouped: bool, composed: bool = False) -> None:
         """Raise for each option that cannot yet carry what the family
-        keeps: per-sequence state beside the pool (``lfm2_moe``) or tables
-        by group of layers (``laguna``), each with its reason."""
+        keeps: per-sequence state beside the pool (``lfm2_moe``), tables
+        by group of layers (``laguna``) or a ring and a table of chunk
+        summaries (``evabyte``), each with its reason."""
         asked = {
             "speculative_k": cfg.speculative_k > 0,
             "host_cache_bytes": cfg.host_cache_bytes > 0,
@@ -872,6 +941,26 @@ class LLMEngine:
                 "tp/fsdp/mesh":
                     "ShardedExecutor places one table a step and has no "
                     "expert axis"}),
+            (composed, "keeps a ring of one window's K/V and a table of "
+                       "chunk summaries a sequence", {
+                "speculative_k":
+                    "a rejected draft's K/V may already be folded into "
+                    "its chunk's summary, and the family has no verify "
+                    "step",
+                "host_cache_bytes":
+                    "the host tier holds one block a digest of one "
+                    "table; a ring block holds whatever window came "
+                    "last, and a summary block has no digest",
+                "preemption":
+                    "a paused stream's chain is demoted from one table "
+                    "of prompt blocks; neither the ring nor the "
+                    "summaries are",
+                "quantization":
+                    "a summary is written at a token's shape in the "
+                    "pool's own dtype, and the scale planes of a "
+                    "quantized pool have no slot for it",
+                "tp/fsdp/mesh":
+                    "ShardedExecutor places one table a step"}),
         ):
             for option, reason in why.items():
                 if keeps and asked[option]:
@@ -885,6 +974,12 @@ class LLMEngine:
                 f"model {self.cfg.model!r} keeps per-sequence state beside "
                 f"the paged cache and cannot {what}: the prefill/decode "
                 "handoff moves K/V blocks, not the state at their boundary")
+        if self.cache.cfg.composed:
+            raise ValueError(
+                f"model {self.cfg.model!r} keeps a ring of one window's K/V "
+                f"and a table of chunk summaries and cannot {what}: the "
+                "prefill/decode handoff moves a prompt's blocks of one "
+                "table, and a ring holds only the last window's")
         if self.cache.cfg.groups:
             raise ValueError(
                 f"model {self.cfg.model!r} keeps its K/V in tables by group "
@@ -1204,6 +1299,11 @@ class LLMEngine:
                 "state_slots": self.cache.used_slots,
                 "state_slots_high_water": cs.state_slots_high_water,
                 "prefix_reuse": self.cache.cfg.prefix_reuse,
+                "prefix_reuse_why_not": self._prefix_reuse_why,
+                # a composed family's steps: the chunk summaries they
+                # wrote and the windows they closed (0 for the others)
+                **{f"eva_{name}": n
+                   for name, n in self._eva_counts.items()},
                 "num_compiled_shapes": self.fns.num_compiled_shapes,
                 "rejected_total": self._rejected_total,
                 "cancelled_total": self._cancelled_total,
@@ -1830,14 +1930,22 @@ class LLMEngine:
             self._m_queue.set(len(self._waiting))
         return admitted
 
-    def _table_for(self, r: _Request, nb: int) -> np.ndarray:
+    def _table_for(self, r: _Request, nb: int, pos: int = 0) -> np.ndarray:
         """Host block table for one request, rebuilt only when a block was
-        appended/replaced (version bump) or the padded width changed."""
-        key = (nb, self.cache.table_version(r.id))
+        appended/replaced (version bump), the padded width changed or, for
+        a composed table, the step's first query at ``pos`` lies in
+        another window than the last one's."""
+        key = (nb, self.cache.table_version(r.id),
+               self.cache.table_epoch(pos))
         if r.table_key != key:
-            r.table_np = self.cache.block_table(r.id, nb)
+            r.table_np = self.cache.block_table(r.id, nb, pos)
             r.table_key = key
         return r.table_np
+
+    def _table_blocks(self, ctx: int) -> int:
+        """The width of a step's tables for contexts in the bucket ``ctx``
+        (a composed table's: ``_composed_nb``)."""
+        return self._composed_nb or ctx // self.cfg.block_size
 
     def _apply_copies_locked(self, pairs: list[tuple[int, int]]) -> None:
         """Clone shared blocks on device (COW) before a write lands —
@@ -1922,7 +2030,7 @@ class LLMEngine:
                     max(r.prefill_done + n for r, n in zip(batch, ns)),
                     self._length_buckets,
                 )
-                nb = ctx // bs
+                nb = self._table_blocks(ctx)
             tokens = self._scratch_buf("pf_tokens", (B, S), np.int32)
             lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
             starts = self._scratch_buf("pf_starts", (B,), np.int32)
@@ -1941,9 +2049,18 @@ class LLMEngine:
                 tokens[i, n:] = 0
                 lengths[i] = n
                 starts[i] = r.prefill_done
-                tables[..., i, :] = self._table_for(r, nb)
+                tables[..., i, :] = self._table_for(r, nb, r.prefill_done)
             sample = self._sample_args_locked(batch, B)
         span = {"kind": kind}
+        if self._kv_ring:
+            W, C = self._kv_ring
+            # what the step's summarise call is handed a layer: every
+            # row's chunks, padding among them
+            span["eva_chunks"] = B * (S // C)
+            for r, n in zip(batch, ns):
+                self._eva_counts["chunks_written_prefill"] += n // C
+                self._eva_counts["windows_closed_prefill"] += (
+                    (r.prefill_done + n) % W == 0)
         if legacy:
             toks_dev = self.executor.prefill(
                 tokens, lengths, tables, sample=sample, span=span,
@@ -2085,6 +2202,7 @@ class LLMEngine:
             pairs: list[tuple[int, int]] = []
             kv_tokens = 0
             kv_tokens_window = 0
+            kv_chunks = 0
             for r in batch:
                 # effective length includes the in-flight token: its K/V
                 # row lands at position eff-1 during this dispatch
@@ -2101,6 +2219,15 @@ class LLMEngine:
                 if self._kv_window:
                     # and what a sliding layer's call attends of it
                     kv_tokens_window += min(eff, self._kv_window)
+                if self._kv_ring:
+                    # a composed table's: the exact rows of the window the
+                    # row's position lies in, and a summary a chunk of
+                    # every window closed before it
+                    W, C = self._kv_ring
+                    kv_tokens_window += (eff - 1) % W + 1
+                    kv_chunks += (W // C) * ((eff - 1) // W)
+                    self._eva_counts["chunks_written_decode"] += eff % C == 0
+                    self._eva_counts["windows_closed_decode"] += eff % W == 0
             self._apply_copies_locked(pairs)
         with self._phase("engine.batch"):
             B = pad_to_bucket(len(batch), self._batch_buckets)
@@ -2116,7 +2243,7 @@ class LLMEngine:
                 ),
                 self._length_buckets,
             )
-            nb = ctx // bs
+            nb = self._table_blocks(ctx)
             positions = self._scratch_buf("dec_positions", (B,), np.int32)
             tables = self._tables_buf("dec_tables", B, nb)
             slots = self._slots_buf_locked("dec_slots", batch, B)
@@ -2126,7 +2253,7 @@ class LLMEngine:
             tables[..., len(batch):, :] = 0
             for i, r in enumerate(batch):
                 positions[i] = r.total_len + r.inflight - 1
-                tables[..., i, :] = self._table_for(r, nb)
+                tables[..., i, :] = self._table_for(r, nb, positions[i])
             if steady:
                 # feed step N+1 from step N's sampled ids without a host
                 # round-trip — THE datapath that makes the pipeline a win
@@ -2141,9 +2268,16 @@ class LLMEngine:
                     )
                 tokens_src = tokens
             sample = self._sample_args_locked(batch, B)
-        span = {"kind": "decode", "kv_tokens": kv_tokens}
-        if self._kv_window:
-            span["kv_tokens_window"] = kv_tokens_window
+        # what the kernels read this step, for the dispatch span and the
+        # flight record alike
+        kv = {"kv_tokens": kv_tokens}
+        if self._kv_window or self._kv_ring:
+            kv["kv_tokens_window"] = kv_tokens_window
+        if self._kv_ring:
+            kv["kv_chunks"] = kv_chunks
+        span = {"kind": "decode", **kv}
+        if self._kv_ring:
+            span["eva_chunks"] = B  # each row's current chunk, read back
         next_dev = self.executor.decode_step(
             tokens_src, positions, tables, sample=sample, span=span,
             slots=slots,
@@ -2161,7 +2295,7 @@ class LLMEngine:
         self._decode_step_window.append(dt)
         self._account_step_locked(
             "decode", dt, t0_wall, emitted, batch=len(batch), bucket_b=B,
-            bucket_len=ctx, nb=nb, tokens=emitted, kv_tokens=kv_tokens,
+            bucket_len=ctx, nb=nb, tokens=emitted, **kv,
             steady=steady, trace_ids=self._trace_ids_locked(batch),
         )
 

@@ -618,10 +618,13 @@ class ModelExecutor:
                   "prefix_reuse": cfg.prefix_reuse}
         if cfg.groups:
             # tables by group: ``kv_layers`` is then a GROUP's layers (the
-            # pool's layer axis), and a group its window and the model's
+            # pool's layer axis; all the model's under a ring and a slot
+            # table), and a group its kind, its window and the model's
             # layers whose K/V it holds
+            from ray_tpu.serve.llm.kv_cache import describe_group
+
             report["kv_groups"] = [
-                {"window": window, "layers": list(layers)}
+                describe_group(window, layers)
                 for window, layers in cfg.groups]
         return report
 

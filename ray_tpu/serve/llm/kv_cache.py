@@ -84,6 +84,23 @@ carry neither the prefix cache nor the host tier (``prefix_reuse`` is off:
 a hit would need the sliding groups' blocks at the hit's boundary, which
 were freed); the engine refuses what else cannot carry them.
 
+A ring and a table of slots (groups of kind ``("ring", W)`` and
+``("slots", C)``, models/evabyte.py): a family whose EVERY layer reads two
+tables. The ring holds the exact K/V of the sequence's current window of
+``W`` positions: ``W / block_size`` blocks, position ``t`` at ``t mod W``,
+reused in place when a window closes, so it grows to a window and stays.
+The slot table holds one slot a chunk of ``C`` positions (a chunk's
+summary has a token's shape), chunk ``c`` at slot ``c``: it grows a block
+every ``C x block_size`` positions. Both draw on the one pool and free
+list; the pool's layer axis spans all the model's layers and both tables
+index it alike. A step gets them COMPOSED (``block_table(.., pos=)``): row
+0 is ``[the slot table's blocks for the chunks of closed windows | the
+ring]``, the table a query at ``pos`` attends causally, and row 1 the slot
+table, where the step writes the summaries of the chunks it completes.
+``request_blocks`` is ``min(ceil(n / block_size), W / block_size) +
+ceil(ceil(n / C) / block_size)``. Such groups carry no prefix cache and no
+host tier either, and the engine refuses what else cannot carry them.
+
 Host-memory tier (``host_cache_bytes > 0``): LRU eviction DEMOTES a full
 prefix block into a pinned host-side arena instead of discarding it —
 the plasma spill model from the Ray object store, applied to KV. Each
@@ -123,6 +140,36 @@ def _block_key(prev: bytes, block_tokens) -> bytes:
     return h.digest()
 
 
+def group_kind(window) -> str:
+    """The kind of table a group's first entry names: None, every token is
+    kept (``full``); an int, the last ``window`` (``sliding``); ``("ring",
+    W)`` and ``("slots", C)``, see the module docstring."""
+    if window is None:
+        return "full"
+    return "sliding" if isinstance(window, int) else window[0]
+
+
+def is_composed(groups) -> bool:
+    """Whether ``groups`` hold a ring or a slot table: a step's table is
+    then composed of the two."""
+    return any(group_kind(window) in ("ring", "slots")
+               for window, _ in groups)
+
+
+def describe_group(window, layers) -> dict:
+    """A group for a report: its kind, its window (a sliding group's, a
+    ring's; None otherwise), a slot table's positions a slot, its layers."""
+    kind = group_kind(window)
+    out = {"window": None, "layers": list(layers), "kind": kind}
+    if kind == "sliding":
+        out["window"] = window
+    elif kind == "ring":
+        out["window"] = window[1]
+    elif kind == "slots":
+        out["every"] = window[1]
+    return out
+
+
 @dataclass(frozen=True)
 class KVCacheConfig:
     n_layer: int
@@ -155,6 +202,34 @@ class KVCacheConfig:
     # layers, as ever. See the module docstring.
     groups: tuple = ()
 
+    def __post_init__(self):
+        if not self.composed:
+            return
+        kinds = [group_kind(window) for window, _ in self.groups]
+        if kinds != ["ring", "slots"]:
+            raise ValueError(
+                f"a composed table is one ring and one slot table, in that "
+                f"order; the groups are {kinds}")
+        W, C = self.window_chunk
+        if W % self.block_size or (W // C) % self.block_size:
+            raise ValueError(
+                f"block_size {self.block_size} must divide the window "
+                f"({W}) and its {W // C} chunks: the composed table joins "
+                f"whole blocks of summaries to whole blocks of the ring")
+
+    @property
+    def composed(self) -> bool:
+        """Whether a step's table is composed of a ring and a slot table."""
+        return is_composed(self.groups)
+
+    @property
+    def window_chunk(self) -> tuple[int, int] | None:
+        """A composed cache's ``(W, C)``: the ring's window and the slot
+        table's positions a slot; None for any other."""
+        if not self.composed:
+            return None
+        return self.groups[0][0][1], self.groups[1][0][1]
+
     @property
     def usable_blocks(self) -> int:
         return self.num_blocks - 1  # block 0 is the garbage sink
@@ -168,15 +243,29 @@ class KVCacheConfig:
         slack."""
         return self.blocks_for(window) + 2
 
+    def table_blocks(self, window, num_tokens: int) -> int:
+        """Entries the table of a group ``window`` names has once its
+        sequence holds ``num_tokens`` (a sliding group's count from
+        position 0; ``free_behind`` says which are still held)."""
+        kind = group_kind(window)
+        if kind == "ring":
+            return self.blocks_for(min(num_tokens, window[1]))
+        if kind == "slots":
+            return self.blocks_for(-(-num_tokens // window[1]))
+        return self.blocks_for(num_tokens)
+
     def request_blocks(self, num_tokens: int) -> int:
         """What admission reserves for a request that may reach
         ``num_tokens``: every block of each group that keeps all tokens,
-        and of a windowed group what a decoding row holds."""
+        of a windowed group what a decoding row holds, a ring's window and
+        a slot table's slots."""
         full = self.blocks_for(num_tokens)
         if not self.groups:
             return full
         return sum(
-            full if window is None else min(full, self.window_blocks(window))
+            min(full, self.window_blocks(window))
+            if group_kind(window) == "sliding"
+            else self.table_blocks(window, num_tokens)
             for window, _ in self.groups)
 
     def prefill_room(self, rows: int, chunk_tokens: int) -> int:
@@ -184,7 +273,15 @@ class KVCacheConfig:
         windowed group holds a step's whole chunk until the step is
         written, ``blocks_for(chunk)`` past what the row reserved."""
         return rows * self.blocks_for(chunk_tokens) * sum(
-            window is not None for window, _ in self.groups)
+            group_kind(window) == "sliding" for window, _ in self.groups)
+
+    def composed_blocks(self, num_tokens: int) -> int:
+        """The width of the composed table of a query at ``num_tokens -
+        1``: the slot table's blocks for the chunks of closed windows, then
+        the ring."""
+        W, C = self.window_chunk
+        return (W // C // self.block_size) * ((num_tokens - 1) // W) \
+            + self.table_blocks(self.groups[0][0], num_tokens)
 
 
 @dataclass
@@ -531,7 +628,8 @@ class PagedKVCache:
         appended = 0
         for g, table in enumerate(self._group_tables(seq_id)):
             grown = 0
-            while len(table) * self.cfg.block_size < num_tokens:
+            want = self.cfg.table_blocks(self._windows[g], num_tokens)
+            while len(table) < want:
                 b = self._take_block(reserved=reserved)
                 self._ref[b] = 1
                 table.append(b)
@@ -540,7 +638,7 @@ class PagedKVCache:
                 self._group_held[g] += grown
                 self._group_high[g] = max(
                     self._group_high[g], self._group_held[g])
-                if self._windows[g] is not None:
+                if group_kind(self._windows[g]) == "sliding":
                     self.stats.window_blocks_taken += grown
             appended += grown
         if appended:
@@ -574,7 +672,8 @@ class PagedKVCache:
         bs = self.cfg.block_size
         for g, window in enumerate(self._windows):
             # every decode step asks; a floor moves once a block
-            if window is None or (next_pos - window + 1) // bs <= floors[g]:
+            if group_kind(window) != "sliding" \
+                    or (next_pos - window + 1) // bs <= floors[g]:
                 continue
             table = self._tables[seq_id] if g == 0 else \
                 self._more[seq_id][g - 1]
@@ -594,12 +693,13 @@ class PagedKVCache:
         return freed
 
     def group_report(self) -> list[dict]:
-        """A group: its window, its layers, the blocks live tables hold in
-        it now and the most they ever held. [] without groups."""
+        """A group: its kind, window and layers (``describe_group``), the
+        blocks live tables hold in it now and the most they ever held. []
+        without groups."""
         return [
-            {"window": window, "layers": list(layers),
-             "blocks": self._group_held[g],
-             "high_water_blocks": self._group_high[g]}
+            dict(describe_group(window, layers),
+                 blocks=self._group_held[g],
+                 high_water_blocks=self._group_high[g])
             for g, (window, layers) in enumerate(self.cfg.groups)]
 
     def _deref(self, b: int, *, quarantine: bool = False) -> None:
@@ -1092,12 +1192,38 @@ class PagedKVCache:
     def utilization(self) -> float:
         return self.used_blocks / max(1, self.cfg.usable_blocks)
 
-    def block_table(self, seq_id, pad_to: int) -> np.ndarray:
+    def table_epoch(self, pos: int) -> int:
+        """What of a step's position changes a COMPOSED table besides the
+        table's version: the window ``pos`` lies in (0 for any other)."""
+        if not self.cfg.composed:
+            return 0
+        return pos // self.cfg.window_chunk[0]
+
+    def block_table(self, seq_id, pad_to: int, pos: int = 0) -> np.ndarray:
         """[pad_to] int32 table, unallocated tail padded with garbage
         block 0 (those positions are always masked); with groups
         ``[G, pad_to]``, one row a group (a windowed group's entries behind
-        its floor are block 0 too)."""
+        its floor are block 0 too). A ring and a slot table give ``[2,
+        pad_to]`` for the step whose queries start at ``pos``: row 0 the
+        composed table (the slot table's blocks for the chunks of the
+        windows closed before ``pos``, then the ring), row 1 the slot
+        table."""
         table = self._tables[seq_id]
+        if self.cfg.composed:
+            slots = self._more[seq_id][0]
+            W, C = self.cfg.window_chunk
+            closed = (W // C // self.cfg.block_size) * (pos // W)
+            if closed > len(slots) or max(
+                    closed + len(table), len(slots)) > pad_to:
+                raise ValueError(
+                    f"sequence {seq_id!r} at position {pos} composes "
+                    f"{closed} of {len(slots)} summary blocks and "
+                    f"{len(table)} of the ring into a table of {pad_to}")
+            out = np.zeros((2, pad_to), np.int32)
+            out[0, :closed] = slots[:closed]
+            out[0, closed: closed + len(table)] = table
+            out[1, : len(slots)] = slots
+            return out
         if len(table) > pad_to:
             raise ValueError(
                 f"sequence {seq_id!r} holds {len(table)} blocks, "

@@ -668,6 +668,73 @@ def test_laguna_decode_step_compiles_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_evabyte_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The EvaByte cell's step programs as the executor compiles them, at
+    the cell's own shapes: the 24-row decode step and the 4 x 2,048 prompt
+    chunk over composed tables ``[2, B, 192]``, the fresh prefill over
+    ``[2, B, 128]``. Both pools (9.13 GB together) are updated in place
+    through the K/V write, the summaries' read-back and write and the
+    kernel's read: nothing pool-sized is among the temporaries. The one
+    scanned layer calls ``paged_attention`` at a GQA group of 1 over a
+    pool by heads, and the chunk summaries are the ``eva_summarize`` kernel
+    under its own name, which is how a trace finds their time inside a
+    scanned stack."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/evabyte-6.5b-8l.json"))
+    traffic = common.load_json(os.path.join(
+        root, "benchmark/traffic/bytes-chat-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    init = common.load_named("reference", "evabyte").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    pool = _struct(pool_shape(cfg.n_layer, traffic["num_blocks"], 16,
+                              cfg.n_kv_head, cfg.head_dim), cfg.dtype,
+                   one_chip)
+    assert pool.shape == (8, 4353, 16, 32, 128)  # by heads: whole tiles
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    fns = decode.DecodeFns("evabyte", cfg, platform="tpu")
+    if kind == "decode":
+        lowered = fns._decode.lower(
+            params, pool, pool, i32((24,)), i32((24,)), i32((2, 24, 192)),
+            sample=None)
+    else:
+        nb, more = (192, {"start": i32((4,))}) if kind == "prefill_chunk" \
+            else (128, {})
+        lowered = fns._prefill.lower(
+            params, pool, pool, i32((4, 2048)), i32((4,)), i32((2, 4, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = math.prod(pool.shape) * 2
+    assert abs(2 * pool_bytes - 9.13e9) < 0.01e9
+    # 3.26 GB of weights and the two pools
+    assert 12.3e9 < mem.argument_size_in_bytes < 12.5e9
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < (0.3e9 if kind == "decode" else 2.0e9), \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert len(re.findall(r"%paged_attention[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%eva_summarize[.\d]* = ", text)) == 1
+    assert "cross_program_prefetch_index" not in text
+
+
 def _body(text):
     """A compiled program's computations, less what names the CALLER: the
     module's name line, the tables of source files and stack frames, and
@@ -731,6 +798,10 @@ PARENTS_TEXT = {
     "kernel-decode": "8d9dbd2616788784",
     "kernel-prefill": "e0f7ebc42536e563",
     "kernel-window": "b1756db2466556f4",
+    # ISSUE 32's control, taken on PR 32's PARENT (dae2ee5): the decode
+    # programs of the two families whose pools rest lane-dense
+    "gpt2-decode": "7071bf766b22d625",
+    "lfm2-decode": "097b42605a3fe691",
 }
 PARENTS_JAX = "0.9.0"
 
@@ -797,7 +868,8 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
         assert _sha(re.sub(r" at [^\s:]+:\d+", "", jaxpr)) \
             == PARENTS_TEXT[case]
         return
-    config = {"mistral": "mistral-7b-v0.3-6l",
+    config = {"mistral": "mistral-7b-v0.3-6l", "gpt2": "gpt2-small",
+              "lfm2": "lfm2-24b-a2b-8l",
               "laguna": "laguna-xs.2-ep8-8l"}[which]
     held = common.load_json(
         os.path.join(root, f"benchmark/configs/{config}.json"))
@@ -806,23 +878,25 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
     fam = decode.get_family(held["family"])
     on_chip = lambda s: S_(s.shape, s.dtype)
     more = {}
-    if which == "mistral":
+    if which in ("mistral", "gpt2"):
         params = jax.tree.map(
             lambda s, axis: S_(s.shape, cfg.dtype if axis >= 0 else s.dtype),
             jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg)),
             fam.quant_axes(cfg))
-        num_blocks, tables = 4097, (64, 160)
+        num_blocks, tables = 4097, (64, 160 if which == "mistral" else 64)
     else:
-        init = common.load_named("reference", "laguna").init_fn()
+        init = common.load_named("reference", held["family"]).init_fn()
         params = jax.tree.map(on_chip, jax.eval_shape(
             lambda: init(jax.random.PRNGKey(0), cfg)))
         more = {"state": jax.tree.map(on_chip, jax.eval_shape(
             lambda: fam.init_state(cfg, 65))), "slots": i32((64,))}
-        num_blocks, tables = 32769, (4, 64, 1152)
-    pool = S_(pool_shape(cfg.n_kv_layer if which == "laguna"
-                         else cfg.n_layer, num_blocks, 16, cfg.n_kv_head,
+        num_blocks, tables = {"laguna": (32769, (4, 64, 1152)),
+                              "lfm2": (4097, (64, 160))}[which]
+    pool = S_(pool_shape(getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks,
+                         16, getattr(cfg, "n_kv_head", None) or cfg.n_head,
                          cfg.head_dim), cfg.dtype)
-    assert len(pool.shape) == 5  # by heads, as the parent stored it
+    # by heads, as PR 31's parent stored it, where the heads are of 128
+    assert len(pool.shape) == (5 if cfg.head_dim == 128 else 4)
     fns = decode.DecodeFns(held["family"], cfg, platform="tpu")
     if kind == "decode":
         lowered = fns._decode.lower(
@@ -832,8 +906,8 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
         lowered = fns._prefill.lower(
             params, pool, pool, i32((4, 2048)), i32((4,)), i32((4, 128)),
             sample=None)
-    assert _program_text_sha(lowered.compile().as_text()) \
-        == PARENTS_TEXT[case]
+    got = _program_text_sha(lowered.compile().as_text())
+    assert got == PARENTS_TEXT[case], got
 
 
 @pytest.mark.parametrize(

@@ -476,8 +476,9 @@ def test_engine_serves_through_the_grouped_cache(tiny, ref):
     described = st["executor"]
     assert described["kv_layers"] == 2
     assert described["kv_groups"] == [
-        {"window": None, "layers": [0, 4]}, {"window": 8, "layers": [1, 3]},
-        {"window": 8, "layers": [2]}]
+        {"kind": "full", "window": None, "layers": [0, 4]},
+        {"kind": "sliding", "window": 8, "layers": [1, 3]},
+        {"kind": "sliding", "window": 8, "layers": [2]}]
     engine.shutdown()
 
 
