@@ -45,15 +45,33 @@ resumed request re-prefills ``prompt + delivered`` and the keyed draws
 at the remaining positions are unchanged (this replaces the old
 host-side "burn one numpy uniform per token" RNG contract).
 
-The decode loop is pipelined with a one-step sync lag (dispatch-ahead,
-arXiv 2011.03641): step N+1's decode feeds DIRECTLY from step N's
-on-device sampled-token array, and the host syncs token ids one step
-behind, so bucketing, block-table/COW assembly and scheduler work hide
-under device compute via JAX async dispatch. Terminal conditions (EOS,
-max_tokens, cancel, deadline) are reconciled when the lagged tokens
-arrive — at most one wasted speculative row per just-finished request —
-and KV blocks freed while a dispatch is in flight are quarantined until
-the next sync proves the dispatch executed (kv_cache.flush_quarantine).
+The step loop is pipelined (dispatch-ahead, arXiv 2011.03641): NO step
+waits for its own tokens. A decode step is launched behind whatever step
+program is in flight — the last decode step, or a prefill whose final
+rows have just joined — with each row's input id taken where it is: on
+the host, or in that step's on-device id array at an index the engine
+knows (the same rows in the same order: the array itself; rows joined or
+left: one gather on the device, ``executor.feed_ids``). A prefill step's
+sync waits behind the next launch too. Only after a launch is the OLDER
+step reconciled (synced, its tokens emitted, its flight record written),
+so bucketing, block-table/COW assembly, emission and scheduler work hide
+under device compute via JAX async dispatch, across a join or a finish
+as in the steady state. At most TWO step programs are ever in flight;
+three things rest on that bound: the staging buffers alternate in pairs
+(``_scratch_buf``), a block freed with programs queued waits in the
+cache's quarantine for the sync of the NEWEST of them
+(kv_cache.flush_quarantine: the fence), and a reader of the profiler's
+trace pairs launches with runs at most two apart
+(benchmark/span_reduce.py). Terminal conditions (EOS, max_tokens, cancel,
+deadline) are reconciled when the lagged tokens arrive — at most one
+wasted speculative row per just-finished request. What still syncs
+everything in flight BEFORE its launch, on purpose: a batch with a
+grammar-constrained row (its allow-mask needs the last id on the host), a
+verify step (drafts are made from committed tokens), preemption and the
+handoff export, a step with nothing to launch (the drain), and a fatal
+path. A first token reaches its stream when its prefill's run ends, as
+with an immediate sync: the launch behind it costs the host a few ms and
+the prefill the device tens.
 
 Everything device-side sits behind the ModelExecutor seam (executor.py):
 the scheduler stages numpy, the executor owns weights, the paged KV pool
@@ -75,7 +93,8 @@ Failure semantics (docs/SERVING_LLM.md "Failure semantics"):
   fail with ``DeadlineExceededError``.
 - ``cancel(request_id)`` evicts a waiting, prefilling, or running
   sequence and returns its KV blocks (allocation AND leftover
-  reservation) immediately.
+  reservation): at once, or at the reconcile of the step program in
+  flight that still holds the row.
 - if a step raises, or wedges past ``step_timeout_s`` (watchdog thread),
   the engine fails closed: every in-flight stream gets an
   ``EngineDiedError`` (an ``ActorError`` — clients treat it exactly like
@@ -425,15 +444,32 @@ class _Request:
 
 
 @dataclass
-class _PendingDecode:
-    """One dispatched-but-unsynced decode step: the on-device sampled
-    tokens [B] int32 (row i belongs to ``batch[i]``; padding rows are
-    garbage) and the exact batch list it was dispatched over. The steady
-    state keeps exactly one of these in flight — step N+1 feeds from
-    ``tokens`` directly and the host syncs N's ids one step behind."""
+class _InFlight:
+    """One launched-but-unsynced step program: its on-device sampled ids
+    ``[B]`` int32 (row i belongs to ``batch[i]``; padding rows are
+    garbage), the exact batch list it was launched over, and ``seq``, its
+    number among the step programs this engine launched (what a sync's
+    ``lag`` and the block quarantine's fence are counted in). A decode
+    step needs no more; a prefill step also carries what its reconcile
+    books once its tokens are on the host: each row's chunk length, token
+    chain and ``prefill_done`` after the chunk, whether the chunk was the
+    row's last (its id is then the row's first token), and the step's
+    clocks and shape for the one flight record it gets."""
 
+    kind: str            # "decode" | "prefill" | "prefill_chunk"
     tokens: Any          # jax [B] int32, still on device
-    batch: list          # the _Request rows of this dispatch, in order
+    batch: list          # the _Request rows of this launch, in order
+    seq: int = 0         # set at the launch (``_launched_locked``)
+    rows: list | None = None    # prefill: (n, chain, done_after, final)
+    t0: float = 0.0
+    t0_wall: float = 0.0
+    fields: dict | None = None  # prefill: the flight record's shape fields
+    index: dict | None = None   # row -> its index in ``batch``, on demand
+
+    def row_of(self, r) -> int:
+        if self.index is None:
+            self.index = {row: j for j, row in enumerate(self.batch)}
+        return self.index[r]
 
 
 class LLMEngine:
@@ -663,16 +699,20 @@ class LLMEngine:
         # "prefill" | "decode" | None — drives prefill/decode alternation
         # and gives tests a step-order trace.
         self.last_step_kind: str | None = None
-        # ---- dispatch-ahead decode pipeline ----
-        # the one in-flight decode step (None when the lag is collapsed)
-        self._pending: _PendingDecode | None = None
+        # ---- dispatch-ahead pipeline ----
+        # the step programs launched and not yet synced, oldest first:
+        # never more than TWO (``_launched_locked``), and one or none
+        # between steps (a step launches, then reconciles what is older)
+        self._inflight: list[_InFlight] = []
+        self._launched = 0            # step programs launched, ever
+        self._inflight_high_water = 0
         # Reusable numpy scratch, keyed (name, shape): shapes come from
         # the closed bucket ladders so the pool is bounded. Each key holds
         # TWO buffers used alternately — jnp.asarray can alias host memory
         # zero-copy on the CPU backend, so a buffer must not be mutated
         # until the dispatch that consumed it has provably executed; with
-        # the lag-1 sync, the step before last has always synced by the
-        # time its buffer comes around again.
+        # at most two programs in flight, the launch before last has
+        # always synced by the time its buffer comes around again.
         self._scratch: dict[tuple, list] = {}
         self._sync_seconds_total = 0.0
         self._sync_bytes_total = 0
@@ -702,7 +742,12 @@ class LLMEngine:
         self._phases: dict[str, dict[str, list]] = {}
         self._step_kind = "none"
         self._decode_steps = 0  # decode dispatches ...
-        self._decode_steps_steady = 0  # ... fed from device tokens (lag 1)
+        # ... launched with a step in flight and nothing synced first ...
+        self._decode_steps_steady = 0
+        # ... of those, over another batch than that step's (ids gathered)
+        self._decode_steps_remapped = 0
+        self._prefill_steps = 0  # prefill dispatches ...
+        self._prefill_syncs_deferred = 0  # ... synced behind a later launch
         # ---- autoscaling signal windows (ISSUE 10) ----
         # Bounded sample/event rings feeding autoscaling_snapshot(): the
         # controller's policy wants recent-tail saturation (queue-wait
@@ -1135,8 +1180,8 @@ class LLMEngine:
                     self._prefill_chunk_locked()
                     self.last_step_kind = "prefill"
                     return True
-                if self._running or self._pending is not None:
-                    # pending-but-nothing-running still needs a step: the
+                if self._running or self._inflight:
+                    # in-flight-but-nothing-running still needs a step: the
                     # lagged tokens must be reconciled (and blocks freed)
                     # even when every row has since finished or evicted
                     self._decode_locked()
@@ -1162,9 +1207,11 @@ class LLMEngine:
 
     def cancel(self, request_id) -> bool:
         """Evict a waiting/prefilling/running request, fail its stream
-        with ``RequestCancelledError``, and return its KV blocks
-        immediately. Returns False when the request is unknown or already
-        finished (idempotent — safe to broadcast to every replica)."""
+        with ``RequestCancelledError``, and return its KV blocks: at once,
+        or, where a step program in flight still holds the row, at that
+        step's reconcile (exactly once either way). Returns False when
+        the request is unknown or already finished (idempotent — safe to
+        broadcast to every replica)."""
         with self._lock:
             req = self._find_locked(request_id)
             if req is None:
@@ -1206,6 +1253,8 @@ class LLMEngine:
         blocks are content-addressed in the prefix cache."""
         self._refuse_handoff("export a prefix")
         with self._lock:
+            # a prefill still in flight has not registered its blocks yet
+            self._collapse_locked()
             chain = self.cache.export_chain(prompt)
             if not chain:
                 return []
@@ -1325,12 +1374,21 @@ class LLMEngine:
                     self._sync_seconds_total, 6
                 ),
                 "host_sync_bytes_total": self._sync_bytes_total,
-                "decode_inflight": 1 if self._pending is not None else 0,
-                # decode dispatches, and those fed from the pending step's
-                # device tokens (lag-1 survived); host seconds by step kind
-                # and phase, {kind: {phase: [count, seconds]}}
+                # step programs launched and not yet synced (0 when
+                # drained), and the most there ever were: never above 2
+                "decode_inflight": len(self._inflight),
+                "steps_inflight_high_water": self._inflight_high_water,
+                # decode dispatches; those launched behind a step in
+                # flight with nothing synced first (the pipeline kept);
+                # of those, the ones over another batch than that step's
+                # (ids gathered on the device). Prefill dispatches, and
+                # those synced behind a later launch. Host seconds by step
+                # kind and phase, {kind: {phase: [count, seconds]}}
                 "decode_steps": self._decode_steps,
                 "decode_steps_steady": self._decode_steps_steady,
+                "decode_steps_remapped": self._decode_steps_remapped,
+                "prefill_steps": self._prefill_steps,
+                "prefill_syncs_deferred": self._prefill_syncs_deferred,
                 "phases": {
                     kind: {name: list(rec) for name, rec in table.items()}
                     for kind, table in self._phases.items()
@@ -1525,7 +1583,7 @@ class LLMEngine:
                     self._finish_obs_locked(r, "shutdown")
                     r.out.put(err)
                     r.out.put(_DONE)
-            self._pending = None
+            self._inflight.clear()
             self.cache.release_all()
             self._waiting.clear()
             self._waiting_blocks = 0
@@ -1562,16 +1620,17 @@ class LLMEngine:
     def _release_blocks_locked(self, r: _Request) -> None:
         """Return an admitted request's blocks (allocation + leftover
         reservation) to the pool EXACTLY ONCE, respecting the dispatch
-        lag: while the row still has an in-flight speculative step
-        (``inflight > 0``) release is deferred to the reconcile that
-        retires it, and blocks freed while any other dispatch is in
-        flight are quarantined until the next sync proves the dispatch
-        executed (kv_cache.free/flush_quarantine)."""
+        lag: while the row still has an in-flight step (``inflight > 0``)
+        release is deferred to the reconcile that retires it, and blocks
+        freed while any other dispatch is in flight are quarantined until
+        the sync of the NEWEST of them proves every one executed
+        (kv_cache.free/flush_quarantine: the fence)."""
         if r.blocks_released or r.inflight > 0:
             return
         r.blocks_released = True
         leftover = r.reserved_blocks - r.drawn_blocks
-        self.cache.free(r.id, quarantine=self._pending is not None)
+        self.cache.free(r.id, quarantine=bool(self._inflight),
+                        fence=self._launched)
         if leftover:
             # below zero for a row cut off inside a prefill step, whose
             # chunk drew on the engine's prefill room: the room gets it back
@@ -1721,13 +1780,12 @@ class LLMEngine:
         sampling reproduces the remaining tokens byte-identically."""
         chaos.fire("llm.preempt", request=r.id,
                    priority=r.sampling.priority)
-        if self._pending is not None:
-            # the victim (or a neighbor) may be in the dispatched step:
-            # reconcile first so its inflight count is 0 and the free
-            # below needs no quarantine. The victim may COMPLETE here —
-            # its lagged token was its last — in which case there is
-            # nothing left to pause.
-            self._reconcile_locked(self._pending)
+        # the victim (or a neighbor) may be in a dispatched step:
+        # reconcile first so its inflight count is 0 and the free
+        # below needs no quarantine. The victim may COMPLETE here —
+        # its lagged token was its last — in which case there is
+        # nothing left to pause.
+        self._collapse_locked()
         if r.done or r not in self._running:
             return False
         chain = list(r.prompt) + list(r.generated)
@@ -2071,98 +2129,143 @@ class LLMEngine:
                 tokens, lengths, starts, tables, sample=sample, span=span,
                 slots=slots,
             )
-        # first tokens sync immediately (lag 0): TTFT must not wait for
-        # the next decode step, and only final-chunk rows emit anyway
-        host = self._sync_tokens_locked(toks_dev, lag=0)
+        self._prefill_steps += 1
+        # The host's view moves on AT THE LAUNCH: the chunk is as good as
+        # written (whatever touches these blocks next is a later program
+        # on the one device), the rows of a FINAL chunk join the running
+        # set with their first token in flight (``inflight`` 1, which the
+        # budget rule and ``kv.reserve`` of the next decode step count),
+        # and the sync waits behind the next launch (``_reconcile_locked``
+        # books what needs the ids: the first tokens, ``register_prefix``,
+        # the timeline entry and the flight record).
+        rows = []
+        for r, n in zip(batch, ns):
+            toks = r.prefill_tokens
+            r.prefill_done += n
+            r.inflight += 1
+            self._prefill_tokens_total += n
+            final = r.prefill_done >= len(toks)
+            rows.append((n, toks, r.prefill_done, final))
+            if final:
+                self._prefilling.remove(r)
+                # resume-from-preemption chains are fully resident
+                # again: from here the row decodes exactly like an
+                # unpaused one
+                r.pending_resume = None
+                self._running.append(r)
         if self._kv_room:
             # the chunk is written: what it put behind the window goes
             # back now, so that a row holds no more than it reserved
-            # between steps and the prefill room is whole for the next
+            # between steps and the prefill room is whole for the next.
+            # Before the sync, as a decode step frees behind a step in
+            # flight: ``free_behind`` rests on the device's order
             with self._phase("kv.reserve"):
-                for r, n in zip(batch, ns):
+                for r in batch:
                     r.drawn_blocks -= self.cache.free_behind(
-                        r.id, r.prefill_done + n)
-        # dt covers the phase's real cost — COW copies, padding, the
-        # jitted call and THE host sync. The same value feeds the latency
-        # histogram, the flight record and the per-request chunk timeline
-        # entries, so every record agrees (one clock).
-        dt = obs.clock() - t0
-        with self._phase("engine.emit"):
-            for i, (r, n) in enumerate(zip(batch, ns)):
-                toks = r.prefill_tokens
-                r.prefill_done += n
-                self._prefill_tokens_total += n
-                self._tl(r, kind, ts=t0_wall, dur_ms=round(dt * 1000.0, 3),
-                         tokens=n, prefill_done=r.prefill_done)
-                if self.cfg.prefix_caching:
-                    self.cache.register_prefix(r.id, toks, r.prefill_done)
-                if r.prefill_done >= len(toks):
-                    self._prefilling.remove(r)
-                    # resume-from-preemption chains are fully resident
-                    # again: from here the row decodes exactly like an
-                    # unpaused one
-                    r.pending_resume = None
-                    # the model samples from last-VALID-token logits per
-                    # row — for the final chunk that is the last prompt
-                    # token (or, resuming, the last already-emitted token:
-                    # the keyed sampler reproduces the next token
-                    # byte-identically)
-                    self._emit_token_locked(r, int(host[i]))
-                    if not r.done:
-                        self._running.append(r)
-        self._account_step_locked(
-            kind, dt, t0_wall, int(sum(ns)), batch=len(batch), bucket_b=B,
-            bucket_len=S, nb=nb, tokens=int(sum(ns)),
-            trace_ids=self._trace_ids_locked(batch),
-        )
+                        r.id, r.prefill_done)
+        rec = self._launched_locked(_InFlight(
+            kind=kind, tokens=toks_dev, batch=batch, rows=rows,
+            t0=t0, t0_wall=t0_wall, fields=dict(
+                batch=len(batch), bucket_b=B, bucket_len=S, nb=nb,
+                tokens=int(sum(ns)), admitted=self._step_admitted,
+                expired=self._step_expired,
+                trace_ids=self._trace_ids_locked(batch))))
+        # what was in flight before it: its run has ended, or ends while
+        # this one runs
+        self._reconcile_older_locked(rec)
+        # Where another launch will follow (a chunk still to prefill, a
+        # row that can decode) the sync waits behind it; else it is made
+        # at once, as the first token must not wait for a step that may
+        # never come
+        if not self._prefilling and not self._eligible_locked():
+            self._reconcile_locked(rec)
+
+    def _eligible_locked(self) -> list[_Request]:
+        """The rows a decode step may be launched over. The budget counts
+        the tokens in flight too: a row at ``max_new_tokens - 1`` with one
+        in flight must not be dispatched again (its last token arrives at
+        a reconcile), and a row a prefill step just launched holds its
+        first."""
+        return [
+            r for r in self._running
+            if len(r.generated) + r.inflight < r.sampling.max_new_tokens
+        ]
+
+    def _launched_locked(self, rec: _InFlight) -> _InFlight:
+        """Book a step program that was just launched: number it, and hold
+        it until its ids are synced. At most TWO are ever in flight: the
+        staging buffers alternate in pairs (``_scratch_buf``), a freed
+        block waits for the sync of the newer (``_release_blocks_locked``),
+        and a reader of the profiler's trace pairs launches with runs a
+        shift of at most two apart."""
+        assert len(self._inflight) < 2, "a third step program in flight"
+        self._launched += 1
+        rec.seq = self._launched
+        self._inflight.append(rec)
+        self._inflight_high_water = max(
+            self._inflight_high_water, len(self._inflight))
+        return rec
+
+    def _reconcile_older_locked(self, rec: _InFlight) -> int:
+        """Reconcile what was launched before ``rec``, oldest first, now
+        that ``rec`` is queued behind it: the host's work for those ids
+        runs while the device runs ``rec``. -> tokens emitted."""
+        emitted = 0
+        while self._inflight and self._inflight[0] is not rec:
+            emitted += self._reconcile_locked(self._inflight[0])
+        return emitted
+
+    def _collapse_locked(self) -> int:
+        """Reconcile EVERYTHING in flight, oldest first: what a step does
+        that needs its rows' ids on the host before it can be launched (a
+        grammar-constrained row, a verify step), preemption, the handoff
+        export and the drain. -> tokens emitted."""
+        emitted = 0
+        while self._inflight:
+            emitted += self._reconcile_locked(self._inflight[0])
+        return emitted
 
     def _decode_locked(self) -> None:
-        """One pipelined decode iteration (the tentpole's dispatch-ahead
-        loop). Steady state — the eligible batch is exactly the batch of
-        the in-flight step — dispatches step N+1 feeding straight from
-        step N's on-device sampled-token array, THEN syncs step N's ids:
-        all the host-side work above the dispatch (bucketing, COW prep,
-        table/position packing) overlaps step N's device compute, and the
-        sync itself is near-free because step N already finished. Any
-        batch change (join, finish, eviction, a row hitting its token
-        budget) first collapses the lag: reconcile the pending step on
-        host state, rebuild the batch, and dispatch fresh from host
-        tokens."""
+        """One pipelined decode iteration (the dispatch-ahead loop). With a
+        step program in flight — the last decode step, or a prefill step
+        whose final rows have just joined — step N+1 is launched BEFORE
+        that step's ids are synced: each row's input id is either on the
+        host already or in the in-flight step's on-device id array, at an
+        index the engine knows, so the step's ids are put together on the
+        device (the same rows in the same order: that array itself; rows
+        joined or left: one gather, ``executor.feed_ids``). Only THEN is
+        the older step reconciled: all the host work above the dispatch
+        (bucketing, COW prep, table/position packing) and the emission of
+        the older step's tokens overlap device compute, and the sync is
+        near-free because that step already finished. What still
+        collapses the lag first, on purpose: a grammar-constrained row
+        (its allow-mask needs the last id on the host), a verify step
+        (drafts are made from committed tokens) and the drain."""
         chaos.fire("engine.decode", batch=len(self._running))
         self._step_kind = "decode"
         t0 = obs.clock()
         t0_wall = obs.wall()
         bs = self.cfg.block_size
-        pending = self._pending
-
-        def eligible() -> list[_Request]:
-            # budget counts the speculative in-flight token too — a row
-            # at max_new_tokens-1 with one token in flight must not be
-            # dispatched again (its last token arrives at reconcile)
-            return [
-                r for r in self._running
-                if len(r.generated) + r.inflight < r.sampling.max_new_tokens
-            ]
+        eligible = self._eligible_locked
 
         with self._phase("engine.batch"):
             batch = eligible()
             # speculative draft-and-verify (cfg.speculative_k > 0) needs
             # the rows' COMMITTED tokens on host, so a verify step can
             # never be dispatched ahead: when any row has drafts, collapse
-            # the lag-1 pending first, re-draft on the reconciled state,
+            # the lag first, re-draft on the reconciled state,
             # and run ONE synchronous verify step committing 1..k+1 tokens
             # per row. When no row drafts anything, fall through to the
             # plain pipelined decode below — drafter-hostile traffic keeps
-            # the lag-1 dispatch-ahead path untouched.
+            # the dispatch-ahead path untouched.
             proposals = (
                 self._propose_drafts_locked(batch)
                 if self._drafter is not None and batch else None
             )
         emitted = 0
         if proposals is not None:
-            if pending is not None:
-                emitted += self._reconcile_locked(pending)
-                pending = None
+            if self._inflight:
+                emitted += self._collapse_locked()
                 with self._phase("engine.batch"):
                     batch = eligible()
                     proposals = (
@@ -2171,22 +2274,15 @@ class LLMEngine:
             if batch and proposals is not None:
                 self._verify_locked(batch, proposals, t0, t0_wall, emitted)
                 return
-        # list equality is element identity here: same _Request objects
-        # in the same order <=> nothing joined/finished/evicted.
         # Grammar-constrained rows force the lag to collapse every step:
         # the allow-mask staged for step N+1 is a function of the FSM
         # state AFTER step N's token, which only exists host-side once
         # N's ids are synced — so reconcile first, then dispatch (lag-0
         # for constrained batches, the dispatch-ahead win preserved for
-        # everything else).
-        constrained = any(r.fsm is not None for r in batch)
-        steady = (
-            pending is not None and batch == pending.batch
-            and not constrained
-        )
-        if pending is not None and not steady:
-            emitted += self._reconcile_locked(pending)
-            pending = None
+        # everything else). So does a step with nothing to launch.
+        if self._inflight and (
+                not batch or any(r.fsm is not None for r in batch)):
+            emitted += self._collapse_locked()
             batch = eligible()
         if not batch:
             # pure drain step: the reconcile above retired the last
@@ -2197,6 +2293,11 @@ class LLMEngine:
                 tokens=emitted,
             )
             return
+        # the ONE step still in flight, if any: where the ids of the rows
+        # it holds are. ``steady``: this step is launched behind it with
+        # nothing synced first
+        ahead = self._inflight[-1] if self._inflight else None
+        steady = ahead is not None
         with self._phase("kv.reserve"):
             self._apply_promotions_locked()
             pairs: list[tuple[int, int]] = []
@@ -2254,19 +2355,34 @@ class LLMEngine:
             for i, r in enumerate(batch):
                 positions[i] = r.total_len + r.inflight - 1
                 tables[..., i, :] = self._table_for(r, nb, positions[i])
-            if steady:
-                # feed step N+1 from step N's sampled ids without a host
-                # round-trip — THE datapath that makes the pipeline a win
-                # (the executor passes on-device arrays through untouched)
-                tokens_src = pending.tokens
+            feed = None
+            same = steady and batch == ahead.batch
+            if same:
+                # list equality is element identity here: the same
+                # _Request objects in the same order. Feed step N+1 from
+                # step N's sampled ids without a host round-trip — THE
+                # datapath that makes the pipeline a win (the executor
+                # passes on-device arrays through untouched)
+                tokens_src = ahead.tokens
             else:
-                tokens = self._scratch_buf("dec_tokens", (B,), np.int32)
-                tokens[len(batch):] = 0
+                # rows joined or left: a row that rode the step in flight
+                # has its id in that step's array (row ``feed[0, i]``),
+                # any other on the host (``feed[1, i]``); padding rows
+                # take the host's 0
+                feed = self._scratch_buf("dec_feed", (2, B), np.int32)
+                feed[0] = -1
+                feed[1, len(batch):] = 0
                 for i, r in enumerate(batch):
-                    tokens[i] = (
-                        r.generated[-1] if r.generated else r.prompt[-1]
-                    )
-                tokens_src = tokens
+                    if r.inflight:
+                        feed[0, i] = ahead.row_of(r)
+                    else:
+                        feed[1, i] = (
+                            r.generated[-1] if r.generated else r.prompt[-1]
+                        )
+                if (feed[0] >= 0).any():
+                    tokens_src = ahead.tokens
+                else:  # every id is on the host: staged as it is
+                    tokens_src, feed = feed[1], None
             sample = self._sample_args_locked(batch, B)
         # what the kernels read this step, for the dispatch span and the
         # flight record alike
@@ -2280,23 +2396,26 @@ class LLMEngine:
             span["eva_chunks"] = B  # each row's current chunk, read back
         next_dev = self.executor.decode_step(
             tokens_src, positions, tables, sample=sample, span=span,
-            slots=slots,
+            slots=slots, feed=feed,
         )
+        remapped = steady and not same
         self._decode_steps += 1
         self._decode_steps_steady += steady
+        self._decode_steps_remapped += remapped
         for r in batch:
             r.inflight += 1
-        self._pending = _PendingDecode(tokens=next_dev, batch=batch)
-        if steady:
-            # reconcile step N only after dispatching N+1 — the host work
-            # above ran while N was still executing on device
-            emitted += self._reconcile_locked(pending)
+        rec = self._launched_locked(
+            _InFlight(kind="decode", tokens=next_dev, batch=batch))
+        # reconcile what was in flight only after dispatching N+1 — the
+        # host work above ran while it was still executing on device
+        emitted += self._reconcile_older_locked(rec)
         dt = obs.clock() - t0
         self._decode_step_window.append(dt)
         self._account_step_locked(
             "decode", dt, t0_wall, emitted, batch=len(batch), bucket_b=B,
             bucket_len=ctx, nb=nb, tokens=emitted, **kv,
-            steady=steady, trace_ids=self._trace_ids_locked(batch),
+            steady=steady, remapped=remapped,
+            trace_ids=self._trace_ids_locked(batch),
         )
 
     def _account_step_locked(self, kind: str, dt: float, t0_wall: float,
@@ -2311,31 +2430,89 @@ class LLMEngine:
             self._goodput_record_locked(kind, dt, goodput_tokens)
             self._flight_record_locked(kind, t0_wall, dt, **fields)
 
-    def _reconcile_locked(self, pending: _PendingDecode) -> int:
-        """Collapse the dispatch lag for one in-flight decode step: sync
-        its sampled ids (THE O(batch) int32 transfer), flush the block
-        quarantine (a completed sync proves every earlier dispatch
-        executed, so blocks freed before this step's dispatch are safe to
-        reuse), then emit/retire per row. Rows that terminated after the
-        dispatch (EOS raced the lag, cancel, deadline, failover) drop
-        their speculative token here and release their blocks — exactly
-        once, via the inflight-guarded release. Returns tokens emitted."""
-        if self._pending is pending:
-            self._pending = None
-        toks = self._sync_tokens_locked(pending.tokens, lag=1)
-        with self._phase("engine.emit"):
-            self.cache.flush_quarantine()
-            emitted = 0
-            for i, r in enumerate(pending.batch):
-                r.inflight -= 1
-                if r.done:
-                    # the <=1 wasted speculative row per finished request
-                    self._release_blocks_locked(r)
-                    continue
-                self._emit_token_locked(r, int(toks[i]))
-                emitted += 1
-            self._running = [r for r in self._running if not r.done]
+    def _reconcile_locked(self, rec: _InFlight) -> int:
+        """Collapse the dispatch lag for one step program in flight, the
+        OLDEST: sync its sampled ids (THE O(batch) int32 transfer), flush
+        the block quarantine up to it (a completed sync proves this and
+        every earlier dispatch executed, so blocks freed while none newer
+        was in flight are safe to reuse), then emit/retire per row. Rows
+        that terminated after the dispatch (EOS raced the lag, cancel,
+        deadline, failover) drop their speculative token here and release
+        their blocks — exactly once, via the inflight-guarded release. A
+        prefill step's reconcile also books what its step left open: the
+        chunk's timeline entry, ``register_prefix``, the first tokens of
+        the rows whose chunk was their last, and the step's flight record
+        (``dur_ms``: from the step's start to its ids on the host). The
+        host phases are booked under the RECONCILED step's kind, whatever
+        step runs them. Returns the decode tokens emitted (a prefill's
+        first tokens are no decode step's output)."""
+        assert rec is self._inflight[0], "reconcile oldest first"
+        self._inflight.pop(0)
+        booked = self._step_kind
+        if rec.kind != booked:
+            self._fold_phases_locked()
+            self._step_kind = rec.kind
+        try:
+            # honest: the launches that sat between this step and its sync
+            lag = self._launched - rec.seq
+            toks = self._sync_tokens_locked(rec.tokens, lag=lag)
+            with self._phase("engine.emit"):
+                self.cache.flush_quarantine(upto=rec.seq)
+                if rec.rows is None:
+                    emitted = self._emit_decoded_locked(rec, toks)
+                else:
+                    dt = obs.clock() - rec.t0
+                    self._emit_prefilled_locked(rec, toks, dt)
+                    emitted = 0
+                self._running = [r for r in self._running if not r.done]
+            if rec.rows is not None:
+                self._prefill_syncs_deferred += lag > 0
+                self._account_step_locked(
+                    rec.kind, dt, rec.t0_wall, rec.fields["tokens"],
+                    **rec.fields)
+        finally:
+            if rec.kind != booked:
+                self._fold_phases_locked()
+                self._step_kind = booked
         return emitted
+
+    def _emit_decoded_locked(self, rec: _InFlight, toks) -> int:
+        emitted = 0
+        for i, r in enumerate(rec.batch):
+            r.inflight -= 1
+            if r.done:
+                # the <=1 wasted speculative row per finished request
+                self._release_blocks_locked(r)
+                continue
+            self._emit_token_locked(r, int(toks[i]))
+            emitted += 1
+        return emitted
+
+    def _emit_prefilled_locked(self, rec: _InFlight, toks,
+                               dt: float) -> None:
+        """``dt`` covers the chunk's real cost — COW copies, padding, the
+        jitted call and THE host sync, wherever that was made. The same
+        value feeds the latency histogram, the flight record and the
+        per-request chunk timeline entries, so every record agrees (one
+        clock)."""
+        for i, (r, (n, chain, done, final)) in enumerate(
+                zip(rec.batch, rec.rows)):
+            r.inflight -= 1
+            self._tl(r, rec.kind, ts=rec.t0_wall,
+                     dur_ms=round(dt * 1000.0, 3), tokens=n,
+                     prefill_done=done)
+            if self.cfg.prefix_caching:
+                self.cache.register_prefix(r.id, chain, done)
+            if r.done:
+                # cancelled or expired with the chunk in flight
+                self._release_blocks_locked(r)
+            elif final:
+                # the model samples from last-VALID-token logits per
+                # row — for the final chunk that is the last prompt
+                # token (or, resuming, the last already-emitted token:
+                # the keyed sampler reproduces the next token
+                # byte-identically)
+                self._emit_token_locked(r, int(toks[i]))
 
     def _propose_drafts_locked(self, batch: list) -> list[list[int]] | None:
         """Ask the drafter for up to ``speculative_k`` candidate tokens
@@ -2459,6 +2636,7 @@ class LLMEngine:
             tokens, starts, dlen, tables, sample=sample,
             span={"kind": "verify", "kv_tokens": kv_tokens},
         )
+        self._launched += 1  # synced at once: never held in flight
         packed = self._sync_verify_locked(packed_dev)
         with self._phase("engine.emit"):
             # a completed sync proves every earlier dispatch executed
@@ -2524,9 +2702,11 @@ class LLMEngine:
     def _sync_tokens_locked(self, tokens_dev, *, lag: int) -> np.ndarray:
         """THE device->host sync: O(batch) int32 token ids, timed and
         metered. ``lag`` says how many dispatches sat between this
-        array's producing step and now (0 = prefill's immediate sync,
-        1 = the pipelined decode path); it lands in the flight record so
-        lagged token timestamps are explainable (docs/OBSERVABILITY.md).
+        array's producing step and now (0 = nothing was launched behind
+        it: a collapse, a prefill with nothing to follow; 1 = the
+        pipelined path, decode or prefill); it lands in the flight record
+        so lagged token timestamps are explainable
+        (docs/OBSERVABILITY.md).
         The transfer itself is the executor's ``sync_tokens``
         (executor._host_tokens — THE allowed host sync)."""
         with self._phase("engine.sync", lag=lag) as ph:
@@ -3055,7 +3235,7 @@ class LLMEngine:
         self._running = []
         self._preempted = []
         self._m_preempted_streams.set(0)
-        self._pending = None  # in-flight step dies with the engine
+        self._inflight.clear()  # in-flight steps die with the engine
         self.cache.release_all()
 
     # ---------------- background stepping ----------------

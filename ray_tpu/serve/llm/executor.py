@@ -57,6 +57,32 @@ def _host_tokens(tokens) -> np.ndarray:
     return np.asarray(tokens, np.int32)
 
 
+_feed_ids = None
+
+
+def feed_ids(source, feed):
+    """A decode step's input ids, put together ON THE DEVICE: row ``i`` is
+    ``source[feed[0, i]]`` where ``feed[0, i] >= 0`` (``source``: the
+    sampled ids of a step program still in flight, never synced), else
+    ``feed[1, i]``, an id the host already holds. One tiny jitted program,
+    ``jit_feed_ids`` (no step program's name is part of its own), a shape
+    a pair of row buckets ``(len(source), feed.shape[1])``: what lets a
+    decode step be launched before the step that samples its inputs has
+    been synced, whatever rows joined or left in between."""
+    global _feed_ids
+    if _feed_ids is None:
+        import jax
+        import jax.numpy as jnp
+
+        def feed_ids(source, feed):
+            index, held = feed[0], feed[1]
+            taken = jnp.take(source, jnp.maximum(index, 0), mode="clip")
+            return jnp.where(index >= 0, taken, held)
+
+        _feed_ids = jax.jit(feed_ids)
+    return _feed_ids(source, feed)
+
+
 def _host_blocks(kv) -> np.ndarray:
     """The SECOND allowed device->host sync, off the emit path entirely:
     materialize a handful of finished KV blocks for a disaggregated
@@ -94,9 +120,14 @@ class ModelExecutor:
       every step program and rebound from its outputs: ``_run``).
     - ``prefill_chunk(tokens, lengths, starts, tables, sample=)`` — the
       chunked/prefix path at true positions.
-    - ``decode_step(tokens, positions, tables, sample=)`` — one decode
-      step; ``tokens`` is either a host staging array (cold dispatch) or
-      the previous step's on-device array (the lag-1 steady feed).
+    - ``decode_step(tokens, positions, tables, sample=, feed=)`` — one
+      decode step; ``tokens`` is either a host staging array (cold
+      dispatch) or the previous step's on-device array (the lag-1 steady
+      feed, the same rows in the same order). With ``feed=`` (a ``[2, B]``
+      host array) ``tokens`` is the on-device ids of whatever step is in
+      flight and the step's ids are gathered from it on the device
+      (``feed_ids``): rows joined or left, nothing is synced. The
+      gather is compiled where a step shape is first run (``_warm_feed``).
     - ``verify_step(tokens, starts, draft_len, tables, sample=)`` — one
       speculative draft-and-verify step over a [B, W] window (column 0 =
       last committed token, then drafts); returns on-device packed
@@ -137,6 +168,10 @@ class ModelExecutor:
         # where the step's host phases are booked ({name: [count,
         # seconds]}); the engine hands over its own table
         self.phases: dict = {}
+        # ``_warm_feed``: a step's ids by width, and the decode row
+        # buckets run, whose pairs have their id gather compiled
+        self._ids_seen: dict[int, Any] = {}
+        self._feed_rows: set[int] = set()
         self.fns = DecodeFns(
             family, model_cfg, platform=self._devices()[0].platform)
         self.params = (
@@ -277,11 +312,13 @@ class ModelExecutor:
 
     # ---------------- the step interface ----------------
 
-    def _run(self, fn, arrays, sample, span, **staged):
+    def _run(self, fn, arrays, sample, span, feed=None, **staged):
         """One jitted step, in the two host phases it has (obs.phase):
         ``executor.stage`` moves the engine's numpy staging arrays
         (``arrays`` in the call's order, ``staged`` by keyword — a None
-        is left out —, and the ``sample`` pytree) on-device;
+        is left out —, and the ``sample`` pytree) on-device, and with
+        ``feed`` gathers the first of ``arrays`` from itself
+        (``feed_ids``: no phase and no ``executor.dispatch`` of its own);
         ``executor.dispatch`` is the jitted call until it returns, under
         the attributes the engine gives in ``span`` (``kind``;
         ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
@@ -298,6 +335,8 @@ class ModelExecutor:
         donated: ``counter_state()`` hands out a reference to it."""
         with obs.phase(self.phases, "executor.stage"):
             dev = [self._dev(a) for a in arrays]
+            if feed is not None:
+                dev[0] = feed_ids(dev[0], self._dev(feed))
             staged = {k: self._dev(v) for k, v in staged.items()
                       if v is not None}
             sample = self._dev_sample(sample)
@@ -310,18 +349,47 @@ class ModelExecutor:
 
     def prefill(self, tokens, lengths, tables, sample=None, span=None,
                 slots=None):
-        return self._run(self.fns.prefill, (tokens, lengths, tables),
-                         sample, span, slots=slots)
+        ids = self._run(self.fns.prefill, (tokens, lengths, tables),
+                        sample, span, slots=slots)
+        self._warm_feed(ids, sample)
+        return ids
 
     def prefill_chunk(self, tokens, lengths, starts, tables, sample=None,
                       span=None, slots=None):
-        return self._run(self.fns.prefill, (tokens, lengths, tables),
-                         sample, span, start=starts, slots=slots)
+        ids = self._run(self.fns.prefill, (tokens, lengths, tables),
+                        sample, span, start=starts, slots=slots)
+        self._warm_feed(ids, sample)
+        return ids
 
     def decode_step(self, tokens, positions, tables, sample=None, span=None,
-                    slots=None):
-        return self._run(self.fns.decode, (tokens, positions, tables),
-                         sample, span, slots=slots)
+                    slots=None, feed=None):
+        ids = self._run(self.fns.decode, (tokens, positions, tables),
+                        sample, span, feed=feed, slots=slots)
+        self._warm_feed(ids, sample, rows=ids.shape[0])
+        return ids
+
+    def _warm_feed(self, ids, sample, rows: int | None = None) -> None:
+        """Compile ``feed_ids`` for every pair (a step's ids of a width
+        this executor has produced, a decode step of a row bucket it has
+        run) as soon as the pair exists: called with the ids a prefill or
+        decode program just returned (``rows``: the decode step's own
+        bucket; without ``sample`` a step returns logits, and nothing is
+        done). A warm-up that reaches every step shape so reaches every
+        gather, and none is compiled under traffic. The sources are the
+        steps' OWN arrays (a few bytes, kept), so placement and commitment,
+        which are part of a jitted call's cache key, are the real ones."""
+        if sample is None:
+            return
+        width = ids.shape[0]
+        pairs = set()
+        if width not in self._ids_seen:
+            self._ids_seen[width] = ids
+            pairs |= {(width, B) for B in self._feed_rows}
+        if rows is not None and rows not in self._feed_rows:
+            self._feed_rows.add(rows)
+            pairs |= {(w, rows) for w in self._ids_seen}
+        for w, B in pairs:
+            feed_ids(self._ids_seen[w], self._dev(np.zeros((2, B), np.int32)))
 
     def verify_step(self, tokens, starts, draft_len, tables, sample=None,
                     span=None):

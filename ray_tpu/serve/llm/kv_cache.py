@@ -444,8 +444,12 @@ class PagedKVCache:
         # list, so they cannot be handed to a new allocation until the
         # engine's next token sync PROVES the in-flight step (and any
         # speculative write it carries) has executed. flush_quarantine()
-        # moves them to the free list at that sync.
-        self._quarantine: list[int] = []
+        # moves them to the free list at that sync. Each entry is
+        # ``(fence, block)``: the engine numbers its step programs as it
+        # launches them and may have TWO in flight, so a block freed then
+        # waits for the sync of the NEWER one (``free(fence=)``), not for
+        # whichever sync comes next. Fences never decrease down the list.
+        self._quarantine: list[tuple[int, int]] = []
         # group 0's table (the only one without ``cfg.groups``): what every
         # path below that knows nothing of groups reads and writes
         self._tables: dict[Any, list[int]] = {}
@@ -702,7 +706,8 @@ class PagedKVCache:
                  high_water_blocks=self._group_high[g])
             for g, (window, layers) in enumerate(self.cfg.groups)]
 
-    def _deref(self, b: int, *, quarantine: bool = False) -> None:
+    def _deref(self, b: int, *, quarantine: bool = False,
+               fence: int = 0) -> None:
         self._ref[b] -= 1
         if self._ref[b] == 0:
             del self._ref[b]
@@ -714,11 +719,12 @@ class PagedKVCache:
                 # step can scribble on them.
                 self._lru[b] = None  # appended at the MRU end
             elif quarantine:
-                self._quarantine.append(b)
+                self._quarantine.append((fence, b))
             else:
                 self._free.append(b)
 
-    def free(self, seq_id, *, quarantine: bool = False) -> int:
+    def free(self, seq_id, *, quarantine: bool = False,
+             fence: int = 0) -> int:
         """Drop a finished sequence's references; -> table length. Blocks
         it shared with live sequences stay put; sole-owned blocks return
         to the free list, except content-addressed ones, which park in the
@@ -726,7 +732,9 @@ class PagedKVCache:
 
         ``quarantine=True`` (the engine's dispatch-ahead path): sole-owned
         blocks park in the quarantine instead of the free list until
-        ``flush_quarantine`` — see the field comment in ``__init__``."""
+        ``flush_quarantine`` — see the field comment in ``__init__``.
+        ``fence`` is the number of the newest step program in flight: the
+        blocks stay parked until a flush ``upto`` that number or past it."""
         table = self._tables.pop(seq_id)
         self._chain.pop(seq_id, None)
         self._versions.pop(seq_id, None)
@@ -737,21 +745,29 @@ class PagedKVCache:
         held = 0
         for g, t in enumerate([table, *self._more.pop(seq_id, ())]):
             for b in reversed(t[floors[g]:]):  # LIFO: newest reused first
-                self._deref(b, quarantine=quarantine)
+                self._deref(b, quarantine=quarantine, fence=fence)
             self._group_held[g] -= len(t) - floors[g]
             held += len(t) - floors[g]
         self.stats.freed_total += held
         return held
 
-    def flush_quarantine(self) -> int:
+    def flush_quarantine(self, upto: int | None = None) -> int:
         """Return quarantined blocks to the free list; -> count. The
         engine calls this right after a token sync: completing the sync
-        proves every previously-dispatched device step has executed, so
-        blocks freed before those dispatches are safe to reuse."""
-        n = len(self._quarantine)
+        of step program ``upto`` proves it and every program launched
+        before it have executed, so blocks freed while none newer was in
+        flight (``fence <= upto``) are safe to reuse; one freed with a
+        newer program queued stays for that program's sync. ``None``:
+        nothing is in flight any more, everything goes back."""
+        q = self._quarantine
+        n = len(q)
+        if upto is not None:
+            n = 0
+            while n < len(q) and q[n][0] <= upto:
+                n += 1
         if n:
-            self._free.extend(self._quarantine)
-            self._quarantine.clear()
+            self._free.extend(b for _, b in q[:n])
+            del q[:n]
         return n
 
     def release_all(self) -> int:

@@ -204,3 +204,141 @@ def ray_cluster():
     core.shutdown()
     set_global_worker(None)
     cluster.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The widened dispatch-ahead pipeline (serve/llm/engine.py), one schedule for
+# every model family: tests/test_serve_llm_decode.py and the family files run
+# it on their own tiny engines.
+# ---------------------------------------------------------------------------
+
+PIPELINE_LENS = (3, 11, 5, 40, 7, 19)
+PIPELINE_NEWS = (4, 9, 6, 12, 3, 10)  # staggered: a row leaves every few steps
+
+
+def watch_pipeline(eng) -> dict:
+    """Count, on a hand-stepped engine, the step programs launched and not
+    yet synced (``most``: never above two), and hold every flush of the
+    block quarantine to its rule: what a flush ``upto`` a step's number
+    gives back was freed with no NEWER step queued, what it keeps was
+    (the engine frees nothing between a launch and the reconcile of the
+    step before it, so it keeps none today: the cache's own test does)."""
+    seen = {"out": 0, "most": 0, "flushes": 0}
+    ex, cache = eng.executor, eng.cache
+
+    def launching(fn):
+        def run(*a, **kw):
+            seen["out"] += 1
+            seen["most"] = max(seen["most"], seen["out"])
+            return fn(*a, **kw)
+        return run
+
+    for name in ("prefill", "prefill_chunk", "decode_step"):
+        setattr(ex, name, launching(getattr(ex, name)))
+    sync = ex.sync_tokens
+
+    def synced(tokens):
+        seen["out"] -= 1
+        return sync(tokens)
+
+    ex.sync_tokens = synced
+    flush = cache.flush_quarantine
+
+    def flushed(upto=None):
+        before = list(cache._quarantine)
+        n = flush(upto)
+        seen["flushes"] += 1
+        kept = cache._quarantine
+        assert before[n:] == kept, "a flush gives back the oldest first"
+        if upto is not None:
+            assert all(fence <= upto for fence, _ in before[:n])
+            assert all(fence > upto for fence, _ in kept)
+        free = set(cache._free)
+        assert not free & {b for _, b in kept}, "a quarantined block is free"
+        return n
+
+    cache.flush_quarantine = flushed
+    return seen
+
+
+def assert_pool_clean(eng) -> None:
+    """Every block back exactly once; what stays reserved is the room a
+    family with windowed layers sets aside for prefill, whole again."""
+    snap = eng.cache.debug_snapshot()
+    for key in ("used_blocks", "quarantined_blocks", "live_sequences"):
+        assert snap[key] == 0, snap
+    assert snap["reserved_blocks"] == eng._kv_room, snap
+    assert snap["freed_total"] == snap["allocated_total"], snap
+
+
+def run_widened_schedule(make, vocab: int, **sampling) -> dict:
+    """Three requests, three steps, three more that join in mid-stream, all
+    with staggered ``max_new_tokens``, and one cancelled in flight.
+    ``make(**kw)`` builds a hand-stepped engine. Streams must be the bytes
+    of solo runs, the pipeline must have
+    been kept across every finish and every join (``steady`` records over
+    another batch than the step before, ids gathered on the device), every
+    prefill synced behind the next launch, never three programs in flight,
+    every block back exactly once. -> the engine's ``stats()``."""
+    import numpy as np
+
+    from ray_tpu.exceptions import RequestCancelledError
+
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, vocab, size=n).tolist() for n in PIPELINE_LENS]
+    solo = []
+    for p, n in zip(prompts, PIPELINE_NEWS):
+        e = make()
+        solo.append(e.generate(p, max_new_tokens=n, **sampling))
+        e.shutdown()
+    eng = make(max_batch_size=4)
+    seen = watch_pipeline(eng)
+    streams = [eng.submit(p, max_new_tokens=n, **sampling)
+               for p, n in zip(prompts[:3], PIPELINE_NEWS[:3])]
+    for _ in range(3):
+        eng.step()
+    streams += [eng.submit(p, max_new_tokens=n, **sampling)
+                for p, n in zip(prompts[3:], PIPELINE_NEWS[3:])]
+    # and one that is cancelled with a step program that holds it in flight
+    victim = eng.submit(prompts[1][::-1], max_new_tokens=30, **sampling)
+    for _ in range(2):
+        eng.step()
+    assert eng.stats()["decode_inflight"] == 1
+    assert eng.cancel(victim.request_id) is True
+    for _ in range(2000):
+        if all(s.done for s in streams):
+            break
+        eng.step()
+    while eng.step():
+        pass
+    assert [list(s) for s in streams] == solo
+    with pytest.raises(RequestCancelledError):
+        list(victim)
+
+    st = eng.stats()
+    assert seen["most"] == 2 == st["steps_inflight_high_water"]
+    assert seen["out"] == 0 == st["decode_inflight"]
+    assert seen["flushes"] >= st["decode_steps"] + st["prefill_steps"]
+    steps = [r for r in eng.debug_dump()["steps"] if r["kind"] != "compile"]
+    decodes = [r for r in steps if r["kind"] == "decode" and r["batch"]]
+    prefills = [r for r in steps if r["kind"].startswith("prefill")]
+    # nothing in this traffic syncs before a launch: every decode step was
+    # launched behind a step in flight, the first behind its rows' prefill
+    assert all(r["steady"] for r in decodes), decodes
+    assert st["decode_steps_steady"] == st["decode_steps"] == len(decodes)
+    shrank = [b for a, b in zip(decodes, decodes[1:])
+              if b["batch"] < a["batch"]]
+    grew = [b for a, b in zip(decodes, decodes[1:])
+            if b["batch"] > a["batch"]]
+    assert shrank and grew, "the schedule has a finish and a join"
+    assert all(r["remapped"] for r in shrank + grew)
+    assert st["decode_steps_remapped"] == sum(r["remapped"] for r in decodes)
+    assert 0 < st["decode_steps_remapped"] < st["decode_steps"]
+    # a prefill's ids reach the host behind the launch that follows it
+    assert all(r["sync_lag"] == 1 for r in prefills), prefills
+    assert st["prefill_syncs_deferred"] == st["prefill_steps"] == len(prefills)
+    # a decode record's sync is the step before it: one launch sat between
+    assert {r["sync_lag"] for r in decodes if "sync_lag" in r} == {1}
+    assert_pool_clean(eng)
+    eng.shutdown()
+    return st

@@ -529,3 +529,17 @@ def test_dispatch_spans_carry_what_the_composed_table_reads(
     assert any(s.get("kv_chunks") == 2 * (W // C) for s in flight
                if s["kind"] == "decode")
     engine.shutdown()
+
+
+def test_widened_pipeline_matches_solo_runs(tiny):
+    """ISSUE 33's schedule (conftest ``run_widened_schedule``): a step's
+    composed table is made from its rows' positions, which the host knows
+    before any sync, so a decode step is launched behind a chunk still in
+    flight; the streams are the bytes of solo runs."""
+    from conftest import run_widened_schedule
+
+    cfg, params = tiny
+    st = run_widened_schedule(lambda **kw: _engine(cfg, params, **kw),
+                              cfg.vocab_size)
+    assert st["eva_windows_closed_prefill"] + \
+        st["eva_windows_closed_decode"] > 0

@@ -601,3 +601,16 @@ def test_engine_on_the_kernel_path_matches_the_reference(jax_cpu, ref):
         assert float(deficit.max()) < 1e-3, deficit
     assert engine.stats()["kv_window_blocks_freed"] > 0
     engine.shutdown()
+
+
+def test_widened_pipeline_matches_solo_runs(tiny):
+    """ISSUE 33's schedule (conftest ``run_widened_schedule``): blocks go
+    back behind the window while the chunk that passed them, or a decode
+    step, is still in flight (``free_behind`` rests on the device's
+    order), and the streams are the bytes of solo runs."""
+    from conftest import run_widened_schedule
+
+    cfg, params = tiny
+    st = run_widened_schedule(lambda **kw: _engine(cfg, params, **kw),
+                              cfg.vocab_size)
+    assert st["kv_window_blocks_freed"] > 0

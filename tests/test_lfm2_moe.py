@@ -726,3 +726,16 @@ def test_other_families_take_no_state_argument(jax_cpu, family):
         set(k) == {"sample", "state", "slots"} and k["state"] is None
         and k["slots"] is None for k in calls)
     engine.shutdown()
+
+
+def test_widened_pipeline_matches_solo_runs(tiny):
+    """ISSUE 33's schedule (conftest ``run_widened_schedule``): a joining
+    row's state slot is written by a prefill still in flight when the
+    decode step that reads it is launched; the device's order carries it,
+    and the streams are the bytes of solo runs."""
+    from conftest import run_widened_schedule
+
+    cfg, params = tiny
+    st = run_widened_schedule(lambda **kw: _engine(cfg, params, **kw),
+                              cfg.vocab_size)
+    assert st["state_slots"] == 0 and st["state_slots_high_water"] >= 3

@@ -3,13 +3,21 @@ greedy parity with the host argmax reference, byte-identical failover
 resume under keyed (seed, position) sampling, the bounded compile-kind
 contract with sampling fused into the step, lag-1 EOS termination with
 exactly-once block release, and the O(batch)-int32 host-sync budget.
+
+The widened pipeline (ISSUE 33): a decode step is launched behind whatever
+step is in flight with its ids gathered on the device, a prefill's sync
+waits behind the next launch, never more than two step programs in flight;
+what still collapses the lag; a first token's clock.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 import pytest
+
+from conftest import assert_pool_clean, run_widened_schedule, watch_pipeline
 
 
 def _f32(cfg):
@@ -237,6 +245,280 @@ def test_eos_under_lag_terminates_exactly_once(jax_cpu):
         pass
     assert again == expected
     assert eng.cache.debug_snapshot()["used_blocks"] == 0
+
+
+# ------------------------------------------------- the widened pipeline
+
+@pytest.mark.parametrize("family,sampling", [
+    ("gpt", {}), ("llama", {}),
+    ("llama", dict(temperature=0.8, top_p=0.9, seed=5)),
+])
+def test_widened_pipeline_matches_solo_runs(jax_cpu, family, sampling):
+    """Staggered budgets and joins in mid-stream: the bytes of solo runs,
+    greedy and sampled (a row's draws are keyed by seed and position,
+    whatever batch it rode in), with the pipeline kept across every finish
+    and join (conftest ``run_widened_schedule`` has the assertions)."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    mc = _model_config(family)
+
+    def make(**kw):
+        return LLMEngine(EngineConfig(model=family, model_config=mc,
+                                      block_size=8, num_blocks=128, **kw),
+                         auto_step=False)
+
+    run_widened_schedule(make, mc.vocab_size, **sampling)
+
+
+def test_quarantined_block_waits_for_the_newer_step(jax_cpu):
+    """Two step programs queued: a block freed then goes back at the sync
+    of the NEWER one, not at whichever sync comes next, and exactly once."""
+    from ray_tpu.serve.llm.kv_cache import KVCacheConfig, PagedKVCache
+
+    cache = PagedKVCache(KVCacheConfig(
+        n_layer=1, n_kv_head=1, head_dim=8, num_blocks=9, block_size=4))
+    for seq, fence in (("a", 1), ("b", 2), ("c", 2)):
+        cache.allocate(seq)
+        cache.ensure_capacity(seq, 8, reserved=False)
+    free0 = len(cache._free)
+    assert cache.free("a", quarantine=True, fence=1) == 2
+    assert cache.free("b", quarantine=True, fence=2) == 2  # two in flight
+    assert len(cache._free) == free0
+    assert cache.flush_quarantine(upto=1) == 2  # the older step's sync
+    assert [f for f, _ in cache._quarantine] == [2, 2]
+    assert cache.flush_quarantine(upto=1) == 0
+    assert cache.flush_quarantine(upto=2) == 2  # the newer one's
+    assert cache.free("c", quarantine=True, fence=2) == 2
+    assert cache.flush_quarantine() == 2  # nothing in flight: all of it
+    assert len(cache._free) == free0 + 6 == len(set(cache._free))
+    assert cache.debug_snapshot()["quarantined_blocks"] == 0
+
+
+def test_feed_ids_gathers_and_selects(jax_cpu):
+    """Row i is ``source[feed[0, i]]`` where that index is >= 0, else the
+    id the host held, ``feed[1, i]``; the program carries no step
+    program's name (a trace's reader pairs step programs by name)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.llm import executor
+
+    source = jnp.asarray([10, 11, 12, 13], jnp.int32)
+    feed = np.asarray([[2, -1, 0, -1, 3, -1, -1, -1],
+                       [99, 7, 98, 8, 97, 0, 0, 0]], np.int32)
+    out = np.asarray(executor.feed_ids(source, jnp.asarray(feed)))
+    assert out.tolist() == [12, 7, 10, 8, 13, 0, 0, 0]
+    name = executor._feed_ids.__name__
+    assert name == "feed_ids"
+    assert not any(n in f"jit_{name}" for n in
+                   ("_prefill", "_decode_step", "_verify_step"))
+
+
+def test_id_gather_is_compiled_with_the_step_shapes(jax_cpu):
+    """Every (ids of a width, decode row bucket) pair is compiled where
+    its step shapes are first run, so a warm-up that reaches the step
+    shapes reaches every gather: a second engine over the same shapes,
+    joins and finishes in another order, compiles none."""
+    from ray_tpu.serve.llm import executor
+
+    mc = _model_config()
+
+    def run(order):
+        eng = _engine(mc, max_batch_size=4)
+        streams = []
+        for n_rows, new in order:
+            streams += [eng.submit([i + 1, 2, 3], max_new_tokens=new + i)
+                        for i in range(n_rows)]
+            for _ in range(2):
+                eng.step()
+        _drain(eng, streams)
+        st = eng.stats()
+        eng.shutdown()
+        return st
+
+    run([(1, 3), (2, 3), (4, 3)])  # prefill and decode rows 1, 2, 4
+    size = executor._feed_ids._cache_size()
+    st = run([(2, 5), (1, 2), (1, 9), (2, 4)])
+    assert st["decode_steps_remapped"] > 0
+    assert executor._feed_ids._cache_size() == size
+
+
+def test_eos_races_the_lag_across_joins_and_finishes(jax_cpu):
+    """An EOS arrives one step late, as ever (one wasted row), also where
+    it is a row's FIRST token, sampled by a prefill whose sync waits
+    behind the next launch: nothing past it reaches the stream, neighbours
+    are the bytes of their solo runs, every block goes back once."""
+    mc = _model_config()
+    probe = _engine(mc).generate([4, 4, 8], max_new_tokens=3)
+    for eos in (probe[0], probe[1]):
+        others = [([7] * 9, 7), ([5, 6, 7, 8], 12), ([9, 9], 4)]
+        solo = []
+        for p, n in others:
+            out = _engine(mc, eos_id=eos).generate(p, max_new_tokens=n)
+            solo.append(out)
+        eng = _engine(mc, eos_id=eos, max_batch_size=4)
+        seen = watch_pipeline(eng)
+        streams = [eng.submit(*others[0][:1], max_new_tokens=others[0][1])]
+        eng.step()
+        eng.step()
+        s_eos = eng.submit([4, 4, 8], max_new_tokens=50)
+        streams += [eng.submit(p, max_new_tokens=n) for p, n in others[1:]]
+        _drain(eng, streams + [s_eos])
+        assert list(s_eos) == probe[: probe.index(eos) + 1]
+        assert [list(s) for s in streams] == solo
+        decodes = [r for r in eng.debug_dump()["steps"]
+                   if r["kind"] == "decode" and r["batch"]]
+        assert all(r["steady"] for r in decodes)
+        assert seen["most"] == 2 and seen["out"] == 0
+        assert_pool_clean(eng)
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+@pytest.mark.parametrize("when", ["prefill_in_flight", "decode_in_flight"])
+def test_eviction_with_a_step_in_flight(jax_cpu, how, when):
+    """A row cancelled, or past its deadline, while a step program that
+    holds it is in flight: its stream fails, its blocks go back exactly
+    once at that step's reconcile, the other rows are the bytes of their
+    solo runs and the pipeline goes on."""
+    from ray_tpu.serve.llm import DeadlineExceededError, RequestCancelledError
+
+    mc = _model_config()
+    keep = [([1, 2, 3], 9), ([7] * 11, 6)]
+    solo = [_engine(mc).generate(p, max_new_tokens=n) for p, n in keep]
+    eng = _engine(mc, max_batch_size=4)
+    eng.generate([5, 5, 5], max_new_tokens=3)  # compiled: deadlines are real
+    while eng.step():
+        pass
+    seen = watch_pipeline(eng)
+    streams = [eng.submit(p, max_new_tokens=n) for p, n in keep]
+    kw = dict(deadline_s=0.05) if how == "deadline" else {}
+    victim = eng.submit([9, 8, 7, 6], max_new_tokens=40, **kw)
+    eng.step()  # one prefill over the three rows, in flight
+    if when == "decode_in_flight":
+        eng.step()
+        eng.step()
+    assert eng.stats()["decode_inflight"] == 1
+    if how == "cancel":
+        assert eng.cancel(victim.request_id) is True
+    else:
+        time.sleep(0.08)
+    _drain(eng, streams)
+    with pytest.raises(RequestCancelledError if how == "cancel"
+                       else DeadlineExceededError):
+        list(victim)
+    assert [list(s) for s in streams] == solo
+    assert seen["most"] == 2 and seen["out"] == 0
+    assert_pool_clean(eng)
+    eng.shutdown()
+
+
+def test_what_still_collapses_the_lag(jax_cpu):
+    """A grammar-constrained row (its allow-mask needs the last id on the
+    host) and a verify step (drafts are made from committed tokens) sync
+    everything in flight BEFORE they launch: their records are not
+    ``steady``, their syncs have lag 0, and nothing is left in flight
+    behind a verify step."""
+    mc = _model_config()
+    eng = _engine(mc, max_batch_size=4, eos_id=2)
+    free = eng.submit([4, 5, 6], max_new_tokens=20)
+    bound = eng.submit([1, 2, 3], max_new_tokens=12,
+                       structured={"type": "regex", "pattern": "[a-z]{8}"})
+    _drain(eng, [free, bound])
+    assert len(list(bound)) == 8  # the pattern's eight letters
+    decodes = [r for r in eng.debug_dump()["steps"]
+               if r["kind"] == "decode" and r["batch"]]
+    with_bound = [r for r in decodes if r["batch"] == 2]
+    assert len(with_bound) >= 6
+    assert not any(r["steady"] for r in with_bound)
+    # the sync a step makes before its launch is the step's before it
+    assert {r["sync_lag"] for r in with_bound[1:]} == {0}
+    alone = [r for r in decodes if r["batch"] == 1]
+    assert alone and all(r["steady"] for r in alone[1:])
+    assert eng.stats()["decode_steps_steady"] < eng.stats()["decode_steps"]
+    assert_pool_clean(eng)
+    eng.shutdown()
+
+    motif = [435, 326, 262, 138, 158, 21, 39, 9]
+    eng = _engine(mc, speculative_k=3)
+    s = eng.submit(motif * 3, max_new_tokens=16)
+    for _ in range(200):
+        if s.done:
+            break
+        eng.step()
+        if eng.last_step_kind == "decode" and eng.debug_dump()["steps"][-1][
+                "kind"] == "verify":
+            assert eng.stats()["decode_inflight"] == 0
+    while eng.step():
+        pass
+    verifies = [r for r in eng.debug_dump()["steps"] if r["kind"] == "verify"]
+    assert verifies and not any(r["steady"] for r in verifies)
+    assert {r["sync_lag"] for r in verifies} == {0}
+    eng.shutdown()
+
+
+def test_first_token_does_not_wait_for_the_step_launched_behind(jax_cpu):
+    """With a clock. The device is played by a queue: a program ends its
+    duration after the later of its launch and the end of the one before,
+    and a sync returns when its program has ended. A prefill of 60 ms is
+    followed by a decode step of 80 ms launched behind it: the first token
+    reaches its stream when the PREFILL ends (a few ms of host work later,
+    as with an immediate sync), not when the decode step does, and the
+    running row's next token does not wait for the prefill's sync."""
+    mc = _model_config()
+    eng = _engine(mc, max_batch_size=4)
+    eng.generate([5, 5, 5], max_new_tokens=4)  # every shape below compiled
+    s0 = eng.submit([1, 2, 3], max_new_tokens=30)
+    for _ in range(4):
+        eng.step()
+    while eng.step() and eng.stats()["decode_inflight"] != 1:
+        pass
+    ex = eng.executor
+    device = {"free_at": 0.0, "ends": {}, "log": []}
+
+    def playing(fn, kind, dur):
+        def run(*a, **kw):
+            out = fn(*a, **kw)
+            now = time.perf_counter()
+            end = max(now, device["free_at"]) + dur
+            device["free_at"] = end
+            device["ends"][id(out)] = (kind, end, out)
+            device["log"].append((kind, now, end))
+            return out
+        return run
+
+    ex.prefill = playing(ex.prefill, "prefill", 0.060)
+    ex.decode_step = playing(ex.decode_step, "decode", 0.080)
+    sync = ex.sync_tokens
+
+    def synced(tokens):
+        kind, end, _ = device["ends"].pop(id(tokens), ("", 0.0, None))
+        left = end - time.perf_counter()
+        if left > 0:
+            time.sleep(left)
+        return sync(tokens)
+
+    ex.sync_tokens = synced
+    s1 = eng.submit([9, 8, 7, 6], max_new_tokens=5)
+    it = iter(s1)
+    eng.step()  # the prefill is launched; its sync waits
+    assert eng.last_step_kind == "prefill"
+    assert eng.stats()["decode_inflight"] == 1
+    eng.step()  # the decode step is launched behind it, THEN the sync
+    assert eng.last_step_kind == "decode"
+    next(it)
+    t_first = time.perf_counter()
+    (_, _, prefill_end), (_, decode_launch, decode_end) = [
+        e for e in device["log"] if e[0] in ("prefill", "decode")][-2:]
+    assert decode_launch < prefill_end, "the step was launched behind it"
+    assert t_first - prefill_end < 0.030, (t_first - prefill_end)
+    assert t_first < decode_end - 0.030, "it waited for the decode step"
+    rec = [r for r in eng.debug_dump()["steps"] if r["kind"] == "prefill"][-1]
+    assert rec["sync_lag"] == 1
+    # dur_ms: from the step's start to its ids on the host
+    assert 55.0 <= rec["dur_ms"] <= 60.0 + 35.0
+    ex.sync_tokens = sync
+    _drain(eng, [s0, s1])
+    eng.shutdown()
 
 
 # --------------------------------------------------- O(batch) sync budget

@@ -124,7 +124,7 @@ def _model_config():
         LlamaConfig.tiny(), dtype=jnp.float32, attention="xla")
 
 
-def _engine(**kw):
+def _engine(auto_step=True, **kw):
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
 
     kw.setdefault("block_size", 8)
@@ -132,7 +132,7 @@ def _engine(**kw):
     kw.setdefault("seed", 0)
     return LLMEngine(
         EngineConfig(model="llama", model_config=_model_config(), **kw),
-        auto_step=True,
+        auto_step=auto_step,
     )
 
 
@@ -237,16 +237,20 @@ def test_adopt_degrades_when_pool_is_tight(jax_cpu):
     records = donor.export_prefix(prompt)
     donor.shutdown()
 
-    # usable pool of 8 blocks; the hog's prefill+decode reserves 6
-    taker = _engine(num_blocks=9, max_batch_size=2, max_prefill_batch=2)
-    hog = iter(taker.submit([1] * 5, max_new_tokens=43))
-    next(hog)  # hog admitted + prefilled: its 6 blocks are reserved
+    # usable pool of 8 blocks; the hog's prefill+decode reserves 6. Stepped
+    # by hand: a tiny hog on a free-running engine can END before the
+    # adoption gets the engine's lock, and leave the pool wide open
+    taker = _engine(auto_step=False, num_blocks=9, max_batch_size=2,
+                    max_prefill_batch=2)
+    hog = taker.submit([1] * 5, max_new_tokens=43)
+    taker.step()  # hog admitted + prefilled: its 6 blocks are reserved
     landed = taker.adopt_prefix(prompt, records)
     assert landed < len(records), "tight pool must not fully adopt"
     out = taker.generate(prompt, max_new_tokens=6, temperature=0.8, seed=9)
     assert out == ref
-    for _ in hog:
+    while taker.step():
         pass
+    assert len(list(hog)) == 43
     taker.shutdown()
 
 

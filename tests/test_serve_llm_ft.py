@@ -106,9 +106,15 @@ def test_deadline_expiry_mid_decode_frees_blocks(jax_cpu):
     from ray_tpu.serve.llm import DeadlineExceededError
 
     eng = _engine()
+    # compiled first: the deadline is to lapse mid-generation, not inside
+    # the first step's compile (the first token reaches the stream at the
+    # prefill's reconcile, behind the first decode step's launch)
+    eng.generate([1, 2, 3], max_new_tokens=3)
+    while eng.step():
+        pass
     s = eng.submit([1, 2, 3], max_new_tokens=50, deadline_s=0.15)
-    eng.step()  # prefill (emits first token)
-    eng.step()  # decode
+    eng.step()  # prefill
+    eng.step()  # decode (emits first token)
     time.sleep(0.2)  # let the deadline lapse mid-generation
     eng.step()  # expiry sweep evicts the sequence
     got = []
@@ -131,6 +137,11 @@ def test_cancel_frees_every_reserved_block(jax_cpu):
     eng.step()  # prefill: blocks allocated, worst case reserved
     assert not _pool_is_clean(eng)
     assert eng.cancel(s.request_id) is True
+    # the prefill is still in flight with the row's first token (its sync
+    # waits behind the next launch): the blocks go back exactly once, at
+    # its reconcile, which the next step makes
+    assert eng.stats()["decode_inflight"] == 1 and not _pool_is_clean(eng)
+    assert eng.step() and not eng.step()
     assert _pool_is_clean(eng), "cancel must return allocation AND reservation"
     with pytest.raises(RequestCancelledError):
         list(s)

@@ -122,7 +122,7 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     eng = _engine()
     streams = [eng.submit([i + 1, 2, 3], max_new_tokens=6) for i in range(3)]
     seen: list[dict] = []
-    steps = 0
+    steps = records = 0
     last = {}
     for _ in range(100):
         if all(s.done for s in streams):
@@ -133,14 +133,23 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
         mine = recorder.spans[before:]
         seen += mine
         # every span of the step closed, none was opened inside another,
-        # and the step was accounted once, last
+        # and every step PROGRAM is accounted once: a decode step where it
+        # was launched, last; a prefill where its ids reached the host,
+        # which is the step after it when another launch followed it
         assert recorder.depth == 0
         assert all(s["closed"] and s["depth"] == 0 for s in mine)
         names = [s["name"] for s in mine]
         assert names[0] == "engine.schedule"
-        assert names.count("engine.account") == 1
-        assert names[-1] == "engine.account"
-        assert names.count("executor.dispatch") <= 1
+        kinds = [s["attrs"]["kind"] for s in mine
+                 if s["name"] == "executor.dispatch"]
+        assert len(kinds) <= 1
+        if kinds == ["decode"] or not kinds:
+            assert names[-1] == "engine.account"
+        assert names.count("engine.emit") == names.count("engine.sync")
+        ring = [r["kind"] for r in eng.debug_dump()["steps"]
+                if r["kind"] != "compile"]
+        assert names.count("engine.account") == len(ring) - records
+        records = len(ring)
         # totals only ever grow
         now = {(k, n): tuple(rec) for k, table in
                eng.stats()["phases"].items() for n, rec in table.items()}
@@ -150,7 +159,10 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     assert {s["name"] for s in seen} == STEP_PHASES
     phases = eng.stats()["phases"]
     assert set(phases) == {"prefill", "decode"}
-    assert sum(t["engine.account"][0] for t in phases.values()) == steps
+    # one account a step program launched, and one for the last, empty step
+    launched = sum(s["name"] == "executor.dispatch" for s in seen)
+    assert sum(t["engine.account"][0] for t in phases.values()) == \
+        launched + 1 == steps
     # what the spans said is what the totals counted
     for name in STEP_PHASES:
         assert sum(t.get(name, [0])[0] for t in phases.values()) == sum(
@@ -159,7 +171,10 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     # dispatch its kind (and what the kernel must read), the sync its lag
     attrs = {name: [s["attrs"] for s in seen if s["name"] == name]
              for name in STEP_PHASES}
-    assert [a["lag"] for a in attrs["engine.sync"]][0] == 0
+    # the prefill's ids are synced behind the first decode step's launch,
+    # and only the drain syncs with nothing launched behind
+    assert [a["lag"] for a in attrs["engine.sync"]][0] == 1
+    assert [a["lag"] for a in attrs["engine.sync"]][-1] == 0
     assert {a["lag"] for a in attrs["engine.sync"]} == {0, 1}
     assert all(set(a) == {"lag"} for a in attrs["engine.sync"])
     assert all(set(a) == ({"kind"} if a["kind"] == "prefill"
@@ -313,3 +328,125 @@ def test_profiler_session_returns_the_spans_with_attributes(jax_cpu,
                   for s, d, _ in spans[name])
     assert all(a[1] <= b[0] for a, b in zip(flat, flat[1:]))
     eng.shutdown()
+
+
+# --------------------------------- the trace's reader on the widened order
+
+def _widened_trace(honest: bool = True, clip: int = 0) -> dict:
+    """A hand-made trace in the order the widened pipeline leaves: a step
+    is launched behind the one in flight (a decode step over another batch
+    behind its id gather, ``jit_feed_ids``), THEN the older one is synced,
+    its sync labelled by the launches that sat between (1); one collapse
+    syncs the step just launched (0). The device's clock runs ``OFFSET``
+    ahead of the host's. ``honest=False`` labels a prefill's deferred sync
+    0, as an engine that did not count would; ``clip`` drops the first
+    dispatch spans, as a trace that opens in mid-stream does."""
+    OFFSET, LAUNCH = 1.3e6, 0.05e6
+    DUR = {"decode": 5e6, "prefill": 20e6}
+    order = "D D P D D D P D P D D D P D D".split()
+    spans, modules, ops = [], [], []
+    t, free = 10e6, 0.0
+    inflight = []  # (kind, run end on the host's clock, launches then)
+    launched = 0
+
+    def span(name, dur, **attrs):
+        nonlocal t
+        spans.append({"name": name, "start": t, "end": t + dur,
+                      "attrs": attrs})
+        t += dur
+
+    def sync(lag):
+        nonlocal t
+        kind, end, _ = inflight.pop(0)
+        if not honest and kind == "prefill":
+            lag = 0
+        span("engine.sync", max(0.0, end - t) + 0.05e6, lag=lag)
+        span("engine.emit", 0.8e6)
+
+    for i, k in enumerate(order):
+        kind = "decode" if k == "D" else "prefill"
+        span("engine.schedule", 0.3e6)
+        span("engine.batch", 0.6e6)
+        span("executor.stage", 0.4e6)
+        if kind == "decode" and i and order[i - 1] == "P":
+            start = max(free, t)  # rows joined: the ids are gathered
+            modules.append(("jit_feed_ids", start + OFFSET,
+                            start + 0.01e6 + OFFSET))
+            ops.append(("gather.1",) + modules[-1][1:])
+            free = start + 0.01e6
+        attrs = {"kind": kind}
+        if kind == "decode":
+            attrs["kv_tokens"] = 4096
+        span("executor.dispatch", 0.2e6, **attrs)
+        start = max(free, t + LAUNCH)
+        free = start + DUR[kind]
+        name = f"jit_llama_{'decode_step' if kind == 'decode' else kind}"
+        modules.append((name, start + OFFSET, free + OFFSET))
+        ops.append(("fusion.1", start + OFFSET, free + OFFSET))
+        launched += 1
+        inflight.append((kind, free, launched))
+        while len(inflight) > 1:
+            sync(launched - inflight[0][2])
+        if i == 9:  # a constrained row: everything is synced first
+            sync(0)
+        span("engine.account", 0.1e6)
+    sync(0)
+    clipped = 0
+    kept = []
+    for s in spans:
+        if s["name"] == "executor.dispatch" and clipped < clip:
+            clipped += 1
+            continue
+        kept.append(s)
+    return {"window": (12e6 + OFFSET, free + OFFSET), "spans": kept,
+            "planes": [{"ops": ops, "modules": modules}]}
+
+
+@pytest.mark.parametrize("clip,shift", [(0, 0), (1, -1), (2, -2)])
+def test_reader_pairs_every_step_of_the_widened_order(clip, shift):
+    """``benchmark.span_reduce`` on the new order (decode N, prefill P,
+    decode N+1 launched before P's sync): ONE shift, every step program
+    paired with its dispatch by name and order, the id gather's runs left
+    out of the pairing, the offset between the two clocks found."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import span_reduce
+
+    raw = _widened_trace(clip=clip)
+    runs = span_reduce.step_runs(raw["planes"][0]["modules"])
+    assert len(runs) == 15 and not any("feed_ids" in r[0] for r in runs)
+    spans = sorted(raw["spans"], key=lambda s: s["start"])
+    found = span_reduce.align(
+        [s for s in spans if s["name"] == "executor.dispatch"], runs,
+        [s for s in spans if s["name"] == "engine.sync"])
+    assert found is not None and found["shift"] == shift
+    assert len(found["pairs"]) == 15 - clip
+    assert found["offset_floor_ns"] <= found["offset_ns"]
+    reduced = span_reduce.reduce_raw(raw)
+    assert reduced["shift"] == shift and reduced["paired"] == 15 - clip
+    # the offset, the dispatch span and the fastest launch
+    assert reduced["clock_offset_us"] == pytest.approx(1550.0)
+    kinds = [s["attrs"]["kind"] for s in reduced["steps"]]
+    assert kinds.count("prefill") == 4 - (clip > 2)
+    # every launch found a step in flight but the one after the collapse:
+    # the device stood idle for that step's host work alone
+    assert 0.0 < reduced["idle_s"] < 0.004
+    assert sum(reduced["idle_by_layer_s"].values()) == pytest.approx(
+        reduced["idle_s"])
+
+
+def test_reader_needs_the_true_lag():
+    """A deferred prefill's sync labelled 0 would name the step launched
+    BEHIND the prefill as the one it waits for: its run ends long after
+    the sync, every shift's floor lies over its ceiling, and the reader
+    gives nothing rather than a guess. So ``engine.sync``'s ``lag`` counts
+    the launches that sat between (engine._reconcile_locked)."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import span_reduce
+
+    assert span_reduce.reduce_raw(_widened_trace(honest=False)) is None

@@ -197,6 +197,33 @@ def test_preempt_composes_with_structured_output(jax_cpu):
     eng.shutdown()
 
 
+@pytest.mark.timeout(240)
+def test_preemption_collapses_the_lag(jax_cpu):
+    """A pause syncs every step program in flight first (a decode step,
+    and a prefill whose sync waited behind it): the victim's blocks go
+    back with nothing queued, so none is quarantined, and the stream is
+    parked with every token it was owed."""
+    eng = _engine(preemption=dict(PREEMPTION))
+    frees = {}
+    free = eng.cache.free
+
+    def watched(seq_id, **kw):
+        frees.setdefault(seq_id, (eng.stats()["decode_inflight"],
+                                  kw.get("quarantine")))
+        return free(seq_id, **kw)
+
+    eng.cache.free = watched
+    batch, inter = _park_one(eng)
+    assert eng.stats()["steps_inflight_high_water"] == 2
+    assert frees[batch.request_id] == (0, False), frees
+    assert eng.cache.debug_snapshot()["quarantined_blocks"] == 0
+    _drain(eng, [batch] + inter)
+    assert list(batch) == _engine().generate(BATCH_PROMPT,
+                                             max_new_tokens=BATCH_NEW)
+    assert _pool_is_clean(eng)
+    eng.shutdown()
+
+
 # --------------------------------------------- block hygiene while parked
 
 def _park_one(eng, **sampling):
