@@ -143,6 +143,7 @@ _SIGNAL_RATE_WINDOW_S = 30.0
 # Window (obs.clock seconds) of per-step (device-time, tokens) samples
 # behind the llm_goodput_tokens_per_sec / llm_serving_mfu gauges.
 _GOODPUT_WINDOW_S = 30.0
+_GOODPUT_WINDOW_STEPS = 1024
 
 
 def _pctile(samples, q: float) -> float:
@@ -732,14 +733,32 @@ class LLMEngine:
         self._step_expired = 0
         # cache-stat values as of the previous flight record (deltas)
         self._flight_prev = {"cow": 0, "evict": 0, "demote": 0, "promote": 0}
+        # ... and the stepping thread's lock waits, the collector's
+        # seconds and the thread's CPU as of it (with the thread whose
+        # CPU that is): a record says what of each fell between the
+        # record before it and itself
+        self._lock_wait_s = 0.0
+        self._flight_host_prev = (0.0, obs.gc_watch.total_seconds(), 0.0)
+        self._flight_thread: int | None = None
         self._dumped = False  # one post-mortem dump per engine
         # Host phases (obs.phase): the step in progress books into
-        # ``_step_phases`` ({name: [count, seconds]}; the executor books
-        # its two phases there too), and ``step()`` folds that into
-        # ``_phases[kind]`` under the kind the step went on to run
-        # ("none": nothing ran; the loop's ``engine.wait`` goes there too).
-        self._step_phases: dict[str, list] = {}
+        # ``_step_phases`` (an ``obs.PhaseTable``; the executor books its
+        # two phases there too), and ``step()`` folds that into
+        # ``_phases[kind]`` (count and seconds: ``stats()["phases"]``) and
+        # ``_phase_cpu[kind]`` ([cpu_seconds, cpu_measured_seconds]) under
+        # the kind the step went on to run ("none": nothing ran; the
+        # loop's ``engine.wait`` goes there too). ``_host_spans``: the
+        # spans that are no phase of a step, the wait for this lock
+        # before one (``engine.lock``) and the executor's id gather inside
+        # ``executor.stage`` (``executor.feed``): ``stats()["host"]``.
+        # The phases of one step in ``obs.CPU_EVERY`` read the CPU clock
+        # (``_steps``); the two spans always do (a wait's share on the
+        # core says nothing of the next wait's).
+        self._step_phases = obs.PhaseTable()
         self._phases: dict[str, dict[str, list]] = {}
+        self._phase_cpu: dict[str, dict[str, list]] = {}
+        self._host_spans = obs.PhaseTable()
+        self._steps = 0
         self._step_kind = "none"
         self._decode_steps = 0  # decode dispatches ...
         # ... launched with a step in flight and nothing synced first ...
@@ -891,9 +910,10 @@ class LLMEngine:
         # (multiply+accumulate), the serving-side counterpart of the
         # training 6N rule (docs/ROOFLINE.md, benchmarks/gpt_mfu.py).
         self._flops_per_token = 2.0 * self.executor.num_params
-        # per step kind: ring of (clock, device_s, tokens) step samples
-        # plus the last derived rates, for stats()/the decode bench
-        self._goodput_windows: dict[str, deque] = {}
+        # per step kind: the window's (clock, device_s, tokens) step
+        # samples with their two running sums, plus the last derived
+        # rates, for stats()/the decode bench
+        self._goodput_windows: dict[str, list] = {}
         self._goodput_last: dict[str, dict] = {}
         self._m_goodput = obs.goodput_gauge()
         self._m_mfu = obs.mfu_gauge()
@@ -902,6 +922,8 @@ class LLMEngine:
         # DecodeFns stays constructible bare)
         self.executor.on_new_signature = self._on_new_signature
         self.executor.phases = self._step_phases
+        self.executor.spans = self._host_spans
+        obs.gc_watch.acquire()  # last: nothing above may raise past it
 
     @staticmethod
     def _no_prefix_reuse(stateful: bool, grouped: bool,
@@ -1157,40 +1179,62 @@ class LLMEngine:
         an in-flight prompt) OR one batched decode step. When both kinds
         of work exist the scheduler alternates, so a long chunked prefill
         never starves running sequences of decode steps. Returns False
-        when idle."""
-        with self._lock:
-            self._step_begin = obs.clock()
-            self._step_kind = "none"
-            try:
-                chaos.fire("engine.step")
-                with self._phase("engine.schedule"):
-                    self._step_expired = self._expire_deadlines_locked()
-                    if self._preemption is not None:
-                        self._maybe_resume_locked()
-                        self._maybe_preempt_locked()
-                    self._step_admitted = self._admit_locked()
-                # Fresh admissions prefill immediately (first token out the
-                # door); CONTINUING chunks of a long prompt alternate with
-                # decode so running sequences are never starved.
-                if self._prefilling and (
-                    self.last_step_kind != "prefill"
-                    or not self._running
-                    or any(not r.started for r in self._prefilling)
-                ):
-                    self._prefill_chunk_locked()
-                    self.last_step_kind = "prefill"
-                    return True
-                if self._running or self._inflight:
-                    # in-flight-but-nothing-running still needs a step: the
-                    # lagged tokens must be reconciled (and blocks freed)
-                    # even when every row has since finished or evicted
-                    self._decode_locked()
-                    self.last_step_kind = "decode"
-                    return True
-                return False
-            finally:
-                self._step_begin = None
-                self._fold_phases_locked()
+        when idle, and once shut down."""
+        self._lock_timed()
+        try:
+            return self._step_locked()
+        finally:
+            self._fold_phases_locked()
+            self._lock.release()
+
+    def _step_locked(self) -> bool:
+        """``step`` with the lock held. The caller books the step's
+        phases (``_fold_phases_locked``) before it lets the lock go."""
+        if self._stopped:
+            return False
+        self._step_phases.cpu = self._steps % obs.CPU_EVERY == 0
+        self._steps += 1
+        self._step_begin = obs.clock()
+        self._step_kind = "none"
+        try:
+            chaos.fire("engine.step")
+            with self._phase("engine.schedule"):
+                self._step_expired = self._expire_deadlines_locked()
+                if self._preemption is not None:
+                    self._maybe_resume_locked()
+                    self._maybe_preempt_locked()
+                self._step_admitted = self._admit_locked()
+            # Fresh admissions prefill immediately (first token out the
+            # door); CONTINUING chunks of a long prompt alternate with
+            # decode so running sequences are never starved.
+            if self._prefilling and (
+                self.last_step_kind != "prefill"
+                or not self._running
+                or any(not r.started for r in self._prefilling)
+            ):
+                self._prefill_chunk_locked()
+                self.last_step_kind = "prefill"
+                return True
+            if self._running or self._inflight:
+                # in-flight-but-nothing-running still needs a step: the
+                # lagged tokens must be reconciled (and blocks freed)
+                # even when every row has since finished or evicted
+                self._decode_locked()
+                self.last_step_kind = "decode"
+                return True
+            return False
+        finally:
+            self._step_begin = None
+
+    def _lock_timed(self) -> None:
+        """Take the engine's lock for a step, under the ``engine.lock``
+        span: from asking for it to having it. What holds it meanwhile
+        is a client inside ``submit`` or ``cancel``, or a reader of
+        ``stats()``. Booked once the lock is held. (The stepping loop
+        opens the span earlier, where its last step ended: ``_loop``.)"""
+        with obs.phase(self._host_spans, "engine.lock") as ph:
+            self._lock.acquire()
+        self._lock_wait_s += ph.seconds
 
     def _phase(self, name: str, **attrs) -> obs.phase:
         """A host phase of the step in progress (obs.phase)."""
@@ -1199,10 +1243,15 @@ class LLMEngine:
     def _fold_phases_locked(self) -> None:
         """Book the finished step's phases under the kind it ran."""
         into = self._phases.setdefault(self._step_kind, {})
-        for name, (count, seconds) in self._step_phases.items():
+        cpu_into = self._phase_cpu.setdefault(self._step_kind, {})
+        for name, (count, seconds, cpu, measured) in \
+                self._step_phases.items():
             rec = into.setdefault(name, [0, 0.0])
             rec[0] += count
             rec[1] += seconds
+            rec = cpu_into.setdefault(name, [0.0, 0.0])
+            rec[0] += cpu
+            rec[1] += measured
         self._step_phases.clear()
 
     def cancel(self, request_id) -> bool:
@@ -1392,6 +1441,26 @@ class LLMEngine:
                 "phases": {
                     kind: {name: list(rec) for name, rec in table.items()}
                     for kind, table in self._phases.items()
+                },
+                # what the stepping thread's time is made of besides:
+                # the spans that are no step's phase ([count, seconds,
+                # cpu_seconds]), the CPU seconds the thread itself ran of
+                # each phase above (a Python-only phase's seconds less
+                # these it stood off the CPU; read on one step in
+                # ``obs.CPU_EVERY`` and taken for all), the process's
+                # collector pauses by generation, and what
+                # ``executor.stage`` moved
+                "host": {
+                    "spans": {name: rec[:3] for name, rec
+                              in self._host_spans.items()},
+                    "phase_cpu": {
+                        kind: {name: obs.cpu_estimate(
+                            self._phases[kind][name][1], cpu, measured)
+                            for name, (cpu, measured) in table.items()}
+                        for kind, table in self._phase_cpu.items()},
+                    "gc": obs.gc_watch.totals(),
+                    "stage_transfers": self.executor.stage_transfers,
+                    "stage_bytes": self.executor.stage_bytes,
                 },
                 "spec_steps": self._spec_steps,
                 "spec_drafted_tokens": self._spec_drafted_total,
@@ -1594,6 +1663,7 @@ class LLMEngine:
             self._m_queue.set(0)
             self._m_util.set(self.cache.utilization)
             self._work.notify_all()
+        obs.gc_watch.release()
         for t in (self._thread, self._watchdog):
             if t is not None:
                 t.join(timeout=5)
@@ -2109,7 +2179,7 @@ class LLMEngine:
                 starts[i] = r.prefill_done
                 tables[..., i, :] = self._table_for(r, nb, r.prefill_done)
             sample = self._sample_args_locked(batch, B)
-        span = {"kind": kind}
+        span = {"kind": kind, "seq": self._launched + 1}
         if self._kv_ring:
             W, C = self._kv_ring
             # what the step's summarise call is handed a layer: every
@@ -2391,7 +2461,7 @@ class LLMEngine:
             kv["kv_tokens_window"] = kv_tokens_window
         if self._kv_ring:
             kv["kv_chunks"] = kv_chunks
-        span = {"kind": "decode", **kv}
+        span = {"kind": "decode", "seq": self._launched + 1, **kv}
         if self._kv_ring:
             span["eva_chunks"] = B  # each row's current chunk, read back
         next_dev = self.executor.decode_step(
@@ -2455,7 +2525,7 @@ class LLMEngine:
         try:
             # honest: the launches that sat between this step and its sync
             lag = self._launched - rec.seq
-            toks = self._sync_tokens_locked(rec.tokens, lag=lag)
+            toks = self._sync_tokens_locked(rec.tokens, lag=lag, seq=rec.seq)
             with self._phase("engine.emit"):
                 self.cache.flush_quarantine(upto=rec.seq)
                 if rec.rows is None:
@@ -2634,7 +2704,8 @@ class LLMEngine:
             sample["mask"] = vf_mask
         packed_dev = self.executor.verify_step(
             tokens, starts, dlen, tables, sample=sample,
-            span={"kind": "verify", "kv_tokens": kv_tokens},
+            span={"kind": "verify", "seq": self._launched + 1,
+                  "kv_tokens": kv_tokens},
         )
         self._launched += 1  # synced at once: never held in flight
         packed = self._sync_verify_locked(packed_dev)
@@ -2685,7 +2756,7 @@ class LLMEngine:
         """The verify-step host sync: one packed [B, W+1] int32 array
         through the same blessed channel (executor.sync_verify ->
         _host_tokens), timed and metered exactly like the token sync."""
-        with self._phase("engine.sync", lag=0) as ph:
+        with self._phase("engine.sync", lag=0, seq=self._launched) as ph:
             packed = self.executor.sync_verify(packed_dev)
         dt = ph.seconds
         self._m_sync.observe(dt)
@@ -2699,17 +2770,20 @@ class LLMEngine:
         }
         return packed
 
-    def _sync_tokens_locked(self, tokens_dev, *, lag: int) -> np.ndarray:
+    def _sync_tokens_locked(self, tokens_dev, *, lag: int,
+                            seq: int) -> np.ndarray:
         """THE device->host sync: O(batch) int32 token ids, timed and
         metered. ``lag`` says how many dispatches sat between this
         array's producing step and now (0 = nothing was launched behind
         it: a collapse, a prefill with nothing to follow; 1 = the
         pipelined path, decode or prefill); it lands in the flight record
         so lagged token timestamps are explainable
-        (docs/OBSERVABILITY.md).
+        (docs/OBSERVABILITY.md). ``seq`` is that step's launch number,
+        the one its ``executor.dispatch`` span carries: the sync cannot
+        end before the run of launch ``seq`` does, whatever the lag.
         The transfer itself is the executor's ``sync_tokens``
         (executor._host_tokens — THE allowed host sync)."""
-        with self._phase("engine.sync", lag=lag) as ph:
+        with self._phase("engine.sync", lag=lag, seq=seq) as ph:
             toks = self.executor.sync_tokens(tokens_dev)
         dt = ph.seconds
         self._m_sync.observe(dt)
@@ -2727,24 +2801,36 @@ class LLMEngine:
                                tokens: int) -> None:
         """Fold one step's (device-time, tokens) sample into the windowed
         ``llm_goodput_tokens_per_sec`` / ``llm_serving_mfu`` gauges for
-        its kind. ``dt`` is the step's one-clock duration — on the
-        pipelined steady path the lag-1 sync means it approximates ONE
-        device step (dispatching N+1 overlaps executing N), which is
-        exactly the attribution a utilization gauge wants; on lag-0
-        paths (prefill, verify, drain) it includes the blocking sync
-        (docs/OBSERVABILITY.md, "lag-1 caveat"). MFU is goodput times
-        the analytic 2N forward FLOPs/token over the executor's peak
-        FLOP rate. O(window) amortized: one append + horizon prune."""
+        its kind. ``dt`` is the step's one-clock duration. For a decode
+        step that is launch to launch ON THE HOST: one step's host work
+        with the sync of the step before it, which waits only where the
+        device's step is the longer. So it approximates ONE device step
+        where the device sets the pace, which is the attribution a
+        utilization gauge wants, and the host's own step where the host
+        does (the gauge then reads low by the device's idle share). For a
+        prefill it runs from the step's start to its ids on the host,
+        behind the next launch where one followed; for a verify step or
+        a drain it includes the blocking sync (docs/OBSERVABILITY.md,
+        "lag-1 caveat"). MFU is goodput times the analytic 2N forward
+        FLOPs/token over the executor's peak FLOP rate. O(1) a step: the
+        window's two sums are kept as samples enter and leave it (at
+        most ``_GOODPUT_WINDOW_STEPS``, none older than the horizon)."""
         now = obs.clock()
         win = self._goodput_windows.get(kind)
         if win is None:
-            win = self._goodput_windows[kind] = deque(maxlen=1024)
-        win.append((now, float(dt), int(tokens)))
+            # [samples, their device seconds, their tokens]
+            win = self._goodput_windows[kind] = [deque(), 0.0, 0]
+        samples = win[0]
+        samples.append((now, float(dt), int(tokens)))
+        win[1] += float(dt)
+        win[2] += int(tokens)
         horizon = now - _GOODPUT_WINDOW_S
-        while win and win[0][0] < horizon:
-            win.popleft()
-        dev_s = sum(s[1] for s in win)
-        toks = sum(s[2] for s in win)
+        while (len(samples) > _GOODPUT_WINDOW_STEPS
+               or samples[0][0] < horizon):
+            _, gone_s, gone_tokens = samples.popleft()
+            win[1] -= gone_s
+            win[2] -= gone_tokens
+        _, dev_s, toks = win
         if dev_s <= 0.0 or toks <= 0:
             return
         tps = toks / dev_s
@@ -2760,7 +2846,7 @@ class LLMEngine:
         self._goodput_last[kind] = {
             "tokens_per_sec": round(tps, 3),
             "mfu": round(mfu, 6),
-            "window_steps": len(win),
+            "window_steps": len(samples),
             "window_device_s": round(dev_s, 6),
             "window_tokens": toks,
         }
@@ -3149,6 +3235,21 @@ class LLMEngine:
             # the step that PAID for a host sync carries its cost + lag
             rec.update(self._last_sync)
             self._last_sync = None
+        # where a long step's time went that ``sync_ms`` does not say:
+        # what the stepping thread waited for this lock, what the
+        # collector held the process, and what the thread itself ran,
+        # each since the record before this one
+        host = (self._lock_wait_s, obs.gc_watch.total_seconds(),
+                obs.thread_cpu())
+        rec["lock_ms"], rec["gc_ms"], rec["cpu_ms"] = (
+            round((now - prev) * 1000.0, 3)
+            for now, prev in zip(host, self._flight_host_prev))
+        if self._flight_thread != threading.get_ident():
+            # the record before was another thread's (or there is none):
+            # its CPU clock is no base for this thread's
+            self._flight_thread = threading.get_ident()
+            rec["cpu_ms"] = 0.0
+        self._flight_host_prev = host
         self._flight_prev["cow"] = cs.cow_copies
         self._flight_prev["evict"] = cs.prefix_evicted_blocks
         self._flight_prev["demote"] = cs.demoted_blocks
@@ -3258,30 +3359,49 @@ class LLMEngine:
                 self._watchdog.start()
 
     def _loop(self) -> None:
-        while True:
+        """The stepping thread. It is under a span all the time: a step's
+        phases; where nothing is to be done the wait for work
+        (``engine.wait``, inside the step's hold: the condition lets the
+        lock go); and between two steps ``engine.lock``, opened where a
+        step's last phase ended and closed where the next step has the
+        lock. Inside it the loop books the step, lets the lock go, makes
+        its stop check in a hold of its own and asks again: the same
+        holds as ever, so a client waiting in ``submit`` meets the lock
+        as often as it did. What that span's seconds are made of is a
+        client or a reader holding the lock, or another thread holding
+        the interpreter."""
+        turn = obs.phase(self._host_spans, "engine.lock")
+        turn.__enter__()
+        error = None
+        while error is None:
             with self._lock:
                 if self._stopped:
-                    return
+                    break
             if self._failed is not None:
-                return
+                break
+            self._lock.acquire()
+            turn.__exit__(None, None, None)
+            self._lock_wait_s += turn.seconds
             try:
-                progressed = self.step()
+                if not self._step_locked() and not (
+                    self._stopped
+                    or self._waiting
+                    or self._prefilling
+                    or self._running
+                    or self._preempted
+                ):
+                    with self._phase("engine.wait"):
+                        self._work.wait(timeout=0.05)
             except Exception as e:  # noqa: BLE001 — fail closed, fan out
-                self._fail_engine(e)
-                return
-            if not progressed:
-                with self._work:
-                    if (
-                        not self._stopped
-                        and not self._waiting
-                        and not self._prefilling
-                        and not self._running
-                        and not self._preempted
-                    ):
-                        with self._phase("engine.wait"):
-                            self._work.wait(timeout=0.05)
-                        self._step_kind = "none"
-                        self._fold_phases_locked()
+                error = e
+            finally:
+                turn = obs.phase(self._host_spans, "engine.lock")
+                turn.__enter__()
+                self._fold_phases_locked()
+                self._lock.release()
+        turn.__exit__(None, None, None)
+        if error is not None:
+            self._fail_engine(error)
 
     def _watchdog_loop(self) -> None:
         """Detect a wedged step. Deliberately LOCK-FREE: the failure mode

@@ -165,9 +165,16 @@ class ModelExecutor:
         self.family = family
         self.model_cfg = model_cfg
         self.cache = cache
-        # where the step's host phases are booked ({name: [count,
-        # seconds]}); the engine hands over its own table
+        # where the step's host phases are booked (``obs.phase``); the
+        # engine hands over its own table
         self.phases: dict = {}
+        # ... and the span inside ``executor.stage`` (``executor.feed``),
+        # in a table of its own: phases of a step add up, and this one
+        # lies within another. What ``_run`` moved host -> device: arrays
+        # and their bytes
+        self.spans: dict = {}
+        self.stage_transfers = 0
+        self.stage_bytes = 0
         # ``_warm_feed``: a step's ids by width, and the decode row
         # buckets run, whose pairs have their id gather compiled
         self._ids_seen: dict[int, Any] = {}
@@ -318,9 +325,12 @@ class ModelExecutor:
         (``arrays`` in the call's order, ``staged`` by keyword — a None
         is left out —, and the ``sample`` pytree) on-device, and with
         ``feed`` gathers the first of ``arrays`` from itself
-        (``feed_ids``: no phase and no ``executor.dispatch`` of its own);
+        (``feed_ids``: the ``executor.feed`` span INSIDE the stage phase,
+        booked into ``spans``; no ``executor.dispatch`` of its own), and
+        counts the host arrays it moves (``stage_transfers``,
+        ``stage_bytes``: an array already on the device moves nothing);
         ``executor.dispatch`` is the jitted call until it returns, under
-        the attributes the engine gives in ``span`` (``kind``;
+        the attributes the engine gives in ``span`` (``kind``, ``seq``;
         ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
         ``cache.v`` in place, literally: the step programs donate both
         pools (decode.py ``_jit_named``), so the arrays passed in are
@@ -336,7 +346,13 @@ class ModelExecutor:
         with obs.phase(self.phases, "executor.stage"):
             dev = [self._dev(a) for a in arrays]
             if feed is not None:
-                dev[0] = feed_ids(dev[0], self._dev(feed))
+                with obs.phase(self.spans, "executor.feed"):
+                    dev[0] = feed_ids(dev[0], self._dev(feed))
+            moved = [a for a in (*arrays, feed, *staged.values(),
+                                 *(sample or {}).values())
+                     if isinstance(a, np.ndarray)]
+            self.stage_transfers += len(moved)
+            self.stage_bytes += sum(a.nbytes for a in moved)
             staged = {k: self._dev(v) for k, v in staged.items()
                       if v is not None}
             sample = self._dev_sample(sample)

@@ -6,10 +6,11 @@ Design constraints (ISSUE 4 / docs/OBSERVABILITY.md):
 - **One clock.** Every duration the engine records — step latency
   histograms, flight-recorder records, phase totals — flows through
   ``clock()`` (monotonic), and every absolute timestamp (timelines,
-  spans, chrome export) through ``wall()``. tests/test_sanitizers.py
-  lints serve/llm for stray ``time.time()`` / ``time.perf_counter()``
-  calls outside this module, so the records can never disagree about
-  what was measured.
+  spans, chrome export) through ``wall()``; what a thread itself ran of
+  a duration through ``thread_cpu()``. tests/test_sanitizers.py lints
+  serve/llm for stray ``time.time()`` / ``time.perf_counter()`` /
+  ``time.thread_time()`` calls outside this module, so the records can
+  never disagree about what was measured.
 - **Zero device syncs.** Nothing here touches jax values; the engine's
   single device->host sync point (``_host_tokens``) is unchanged.
 - **O(1) per step.** The flight recorder is a ``deque(maxlen=N)`` ring:
@@ -20,10 +21,12 @@ Design constraints (ISSUE 4 / docs/OBSERVABILITY.md):
 """
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import tempfile
+import threading
 import time
 from collections import deque
 
@@ -36,10 +39,50 @@ logger = logging.getLogger("ray_tpu.serve.llm")
 # line up across processes (timelines, spans, chrome export).
 clock = time.perf_counter
 wall = time.time
+# ... and the CPU seconds the CALLING thread has run: beside a duration on
+# ``clock()`` it says how much of it the thread was on a core. The rest it
+# waited: for the interpreter, for a lock, for the device, for the machine.
+# A kernel that accounts CPU time by its timer's ticks (the TPU host's: 10
+# ms) moves this clock a tick at a time: ONE reading is then that coarse,
+# and a sum over many phases right in expectation (a tick falls into a
+# phase as often as the thread runs there). There a reading is a system
+# call of ~6 us, a hundred times ``clock()``'s, which is why the phases of
+# only one step in ``CPU_EVERY`` read it (``PhaseTable.cpu``).
+thread_cpu = time.thread_time
+CPU_EVERY = 8
 
 # The profiler's host-span class, imported at first use: this module (and
 # engine.py) import no jax at module level.
 _annotation = None
+
+
+def _span_class():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class PhaseTable(dict):
+    """Where phases are booked: ``{name: [count, seconds, cpu_seconds,
+    cpu_measured_seconds]}``. ``cpu`` says whether a phase booked NOW reads
+    the thread's CPU clock beside the wall clock: the engine turns it on
+    for one step in ``CPU_EVERY``, and the last two numbers are the CPU
+    seconds and the wall seconds of those phases alone."""
+
+    cpu = True
+
+
+def cpu_estimate(seconds: float, cpu_seconds: float,
+                 measured_seconds: float) -> float:
+    """CPU seconds of ALL the phases behind ``seconds``, from the ones
+    whose CPU was read: their share on the core, taken for all. 0.0 where
+    none was read yet."""
+    if measured_seconds <= 0.0:
+        return 0.0
+    return cpu_seconds * seconds / measured_seconds
 
 
 class phase:
@@ -49,40 +92,114 @@ class phase:
       profiler's own clock, beside the device's operations, while a
       profiler session is active — and a flag test while none is. The
       profiler session is the only switch there is.
-    - into ``totals`` (``{name: [count, seconds]}``, the engine's), on
-      ``clock()``, always: what ``engine.stats()["phases"]`` reports.
+    - into ``totals`` (a ``PhaseTable``, the engine's), always: count and
+      seconds on ``clock()``, what ``stats()["phases"]`` reports; and,
+      while the table's ``cpu`` is on, the CPU seconds the thread ran of
+      the phase on ``thread_cpu()`` (with the phase's seconds once more,
+      as their base): what ``stats()["host"]`` estimates from.
 
     ``seconds`` holds the duration once the phase has closed, for a caller
     that feeds another record from the same reading. Phases of one step
-    follow one another and never overlap, so their seconds add up. Never
-    open one inside a per-row loop: one span around the loop.
+    follow one another and never overlap, so their seconds add up; a span
+    that lies INSIDE a phase (``executor.feed``) is booked into a table of
+    its own. Never open one inside a per-row loop: one span around the loop.
     """
 
-    __slots__ = ("_totals", "_name", "_span", "_t0", "seconds")
+    __slots__ = ("_totals", "_name", "_span", "_t0", "_cpu0", "seconds")
 
     def __init__(self, totals: dict, name: str, **attrs):
-        global _annotation
-        if _annotation is None:
-            from jax.profiler import TraceAnnotation
-
-            _annotation = TraceAnnotation
         self._totals = totals
         self._name = name
-        self._span = _annotation(name, **attrs)
+        self._span = (_annotation or _span_class())(name, **attrs)
 
     def __enter__(self) -> "phase":
         self._span.__enter__()
+        # (a plain dict as the table: every phase reads the CPU clock)
+        self._cpu0 = (thread_cpu() if getattr(self._totals, "cpu", True)
+                      else None)
         self._t0 = clock()
         return self
 
     def __exit__(self, *exc) -> None:
         self.seconds = clock() - self._t0
+        cpu = None if self._cpu0 is None else thread_cpu() - self._cpu0
         self._span.__exit__(*exc)
         rec = self._totals.get(self._name)
         if rec is None:
-            rec = self._totals[self._name] = [0, 0.0]
+            rec = self._totals[self._name] = [0, 0.0, 0.0, 0.0]
         rec[0] += 1
         rec[1] += self.seconds
+        if cpu is not None:
+            rec[2] += cpu
+            rec[3] += self.seconds
+
+
+class GcWatch:
+    """The collector's pauses, for the whole process: ONE ``gc.callbacks``
+    entry, in while any engine of the process lives (``acquire`` where an
+    engine is built, ``release`` where it shuts down). A collection stops
+    every Python thread, the step thread among them, so it is counted
+    here and not by an engine: collections and seconds by generation,
+    always, and a ``host.gc`` span (``generation``) on the profiler's
+    clock for a generation-2 collection, the only kind long enough to
+    show against a device step. The callback runs inside the collector:
+    it never raises and touches no jax value."""
+
+    def __init__(self):
+        self.collections = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self._users = 0
+        self._guard = threading.Lock()
+        self._t0 = None
+        self._span = None
+
+    def acquire(self) -> None:
+        _span_class()  # imported here, never inside the collector
+        with self._guard:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def release(self) -> None:
+        with self._guard:
+            self._users -= 1
+            if self._users == 0 and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+
+    @property
+    def installed(self) -> bool:
+        return self._on_gc in gc.callbacks
+
+    def total_seconds(self) -> float:
+        return self.seconds[0] + self.seconds[1] + self.seconds[2]
+
+    def totals(self) -> dict:
+        return {"collections": list(self.collections),
+                "seconds": list(self.seconds)}
+
+    def _on_gc(self, when: str, info: dict) -> None:
+        # collections do not nest and a collection starts and stops on
+        # one thread, so one pending start is all there is to keep
+        try:
+            gen = info["generation"]
+            if when == "start":
+                if gen == 2 and _annotation is not None:
+                    self._span = _annotation("host.gc", generation=gen)
+                    self._span.__enter__()
+                self._t0 = clock()
+            elif self._t0 is not None:
+                self.seconds[gen] += clock() - self._t0
+                self.collections[gen] += 1
+                self._t0 = None
+                if self._span is not None:
+                    span, self._span = self._span, None
+                    span.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 — an observer inside the collector
+            self._t0 = self._span = None
+
+
+# the process's one watch (``gc.callbacks`` is the process's own list)
+gc_watch = GcWatch()
 
 
 # Serving-appropriate buckets: TTFT spans "prefix-hit tiny model" (ms) to

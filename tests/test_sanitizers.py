@@ -392,9 +392,9 @@ def test_handoff_retry_paths_never_swallow_silently():
 def test_one_clock_in_llm_serving_path():
     """Observability lint (ISSUE 4): every duration/timestamp in
     serve/llm flows through obs.clock / obs.wall — a stray
-    ``time.time()`` or ``time.perf_counter()`` elsewhere in the engine
-    produces step records, histograms, and timelines that disagree about
-    what was measured. ``time.monotonic``/``time.sleep`` stay allowed
+    ``time.time()``, ``time.perf_counter()`` or ``time.thread_time()``
+    (obs.thread_cpu) elsewhere in the engine produces step records,
+    histograms, and timelines that disagree about what was measured. ``time.monotonic``/``time.sleep`` stay allowed
     (deadline math and the watchdog poll are not measurements). The
     preemption scheduler (ISSUE 17) raises the stakes: queue-wait
     pressure, starvation aging, and parked-time histograms all compare
@@ -406,7 +406,7 @@ def test_one_clock_in_llm_serving_path():
     root = pathlib.Path(__file__).resolve().parents[1]
     targets = sorted((root / "ray_tpu" / "serve" / "llm").rglob("*.py"))
     assert targets, "serving path sources not found"
-    forbidden = {"time", "perf_counter"}
+    forbidden = {"time", "perf_counter", "thread_time"}
     offenders = []
     for path in targets:
         if path.name == "obs.py":

@@ -21,6 +21,10 @@ STEP_PHASES = {
     "engine.schedule", "engine.batch", "kv.reserve", "executor.stage",
     "executor.dispatch", "engine.sync", "engine.emit", "engine.account",
 }
+# the spans that are no phase of a step: the wait for the engine's lock
+# before one, and the id gather INSIDE ``executor.stage``. They are booked
+# under ``stats()["host"]["spans"]``, never under ``stats()["phases"]``
+HOST_SPANS = {"engine.lock", "executor.feed"}
 
 
 def _model_config(family: str = "llama"):
@@ -63,15 +67,20 @@ class _Recorder:
         recorder = self
 
         class Span:
+            # a collection (``host.gc``, the process's, not the engine's)
+            # may fall anywhere, inside whatever is open: not kept
+            kept = name != "host.gc"
+
             def __enter__(self):
                 self.rec = {"name": name, "attrs": dict(attrs),
                             "depth": recorder.depth, "closed": False}
-                recorder.spans.append(self.rec)
-                recorder.depth += 1
+                if self.kept:
+                    recorder.spans.append(self.rec)
+                    recorder.depth += 1
                 return self
 
             def __exit__(self, *exc):
-                recorder.depth -= 1
+                recorder.depth -= self.kept
                 self.rec["closed"] = True
 
         return Span()
@@ -131,13 +140,21 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
         assert eng.step()
         steps += 1
         mine = recorder.spans[before:]
-        seen += mine
         # every span of the step closed, none was opened inside another,
         # and every step PROGRAM is accounted once: a decode step where it
         # was launched, last; a prefill where its ids reached the host,
         # which is the step after it when another launch followed it
         assert recorder.depth == 0
-        assert all(s["closed"] and s["depth"] == 0 for s in mine)
+        assert all(s["closed"] for s in mine)
+        # the step asked for the lock first; the only span opened inside
+        # another is the id gather, inside the stage phase
+        assert mine[0]["name"] == "engine.lock"
+        assert all(s["depth"] == (s["name"] == "executor.feed")
+                   for s in mine)
+        assert all(a["name"] == "executor.stage" for a, b in
+                   zip(mine, mine[1:]) if b["name"] == "executor.feed")
+        mine = [s for s in mine if s["name"] not in HOST_SPANS]
+        seen += mine
         names = [s["name"] for s in mine]
         assert names[0] == "engine.schedule"
         kinds = [s["attrs"]["kind"] for s in mine
@@ -176,10 +193,15 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     assert [a["lag"] for a in attrs["engine.sync"]][0] == 1
     assert [a["lag"] for a in attrs["engine.sync"]][-1] == 0
     assert {a["lag"] for a in attrs["engine.sync"]} == {0, 1}
-    assert all(set(a) == {"lag"} for a in attrs["engine.sync"])
-    assert all(set(a) == ({"kind"} if a["kind"] == "prefill"
-                          else {"kind", "kv_tokens"})
+    assert all(set(a) == {"lag", "seq"} for a in attrs["engine.sync"])
+    assert all(set(a) == ({"kind", "seq"} if a["kind"] == "prefill"
+                          else {"kind", "seq", "kv_tokens"})
                for a in attrs["executor.dispatch"])
+    # launches are numbered as they are made, every one is synced once,
+    # oldest first, and a sync's lag is the launches made since its own
+    launched_seq = [a["seq"] for a in attrs["executor.dispatch"]]
+    assert launched_seq == list(range(1, launched + 1))
+    assert [a["seq"] for a in attrs["engine.sync"]] == launched_seq
     assert all(a == {} for name in STEP_PHASES - {
         "engine.sync", "executor.dispatch"} for a in attrs[name])
     # the sync's histogram and the phase total are one reading
