@@ -709,11 +709,12 @@ class LLMEngine:
         self._inflight_high_water = 0
         # Reusable numpy scratch, keyed (name, shape): shapes come from
         # the closed bucket ladders so the pool is bounded. Each key holds
-        # TWO buffers used alternately — jnp.asarray can alias host memory
-        # zero-copy on the CPU backend, so a buffer must not be mutated
-        # until the dispatch that consumed it has provably executed; with
-        # at most two programs in flight, the launch before last has
-        # always synced by the time its buffer comes around again.
+        # TWO buffers used alternately — the jitted call they are handed to
+        # can alias host memory zero-copy on the CPU backend, so a buffer
+        # must not be mutated until the dispatch that consumed it has
+        # provably executed; with at most two programs in flight, the
+        # launch before last has always synced by the time its buffer
+        # comes around again.
         self._scratch: dict[tuple, list] = {}
         self._sync_seconds_total = 0.0
         self._sync_bytes_total = 0
@@ -1461,6 +1462,7 @@ class LLMEngine:
                     "gc": obs.gc_watch.totals(),
                     "stage_transfers": self.executor.stage_transfers,
                     "stage_bytes": self.executor.stage_bytes,
+                    "stage_masks": self.executor.stage_masks,
                 },
                 "spec_steps": self._spec_steps,
                 "spec_drafted_tokens": self._spec_drafted_total,
@@ -2689,19 +2691,12 @@ class LLMEngine:
                 starts[i] = r.total_len - 1
                 dlen[i] = len(props)
                 tables[i] = self._table_for(r, nb)
-            sample = self._sample_args_locked(batch, B)
             # verify windows need one allow-mask PER COLUMN (column s is
-            # sampled from the FSM state after consuming props[:s]) — the
-            # [B, W, words] leaf replaces the per-row decode mask, staged
-            # all-ones for unconstrained rows so the verify pytree (and
-            # the compile kind) is identical for mixed batches
-            words = (self.model_cfg.vocab_size + 31) // 32
-            vf_mask = self._scratch_buf("vf_mask", (B, W, words), np.uint32)
-            vf_mask[:] = 0xFFFFFFFF
-            for i, (r, props) in enumerate(zip(batch, proposals)):
-                if r.fsm is not None:
-                    r.fsm.stage_verify_masks(vf_mask[i], props)
-            sample["mask"] = vf_mask
+            # sampled from the FSM state after consuming props[:s]): the
+            # [B, W, words] leaf takes the per-row decode mask's place, all
+            # ones for unconstrained rows, so the verify pytree (and the
+            # compile kind) is identical for mixed batches
+            sample = self._sample_args_locked(batch, B, proposals)
         packed_dev = self.executor.verify_step(
             tokens, starts, dlen, tables, sample=sample,
             span={"kind": "verify", "seq": self._launched + 1,
@@ -2851,24 +2846,18 @@ class LLMEngine:
             "window_tokens": toks,
         }
 
-    def _sample_args_locked(self, batch: list, B: int) -> dict:
+    def _sample_args_locked(self, batch: list, B: int,
+                            proposals: list[list[int]] | None = None) -> dict:
         """Per-row sampling controls as [B] host staging arrays — the
         ``sample`` pytree consumed by ops/sampling.py inside the jitted
-        step (the executor moves the leaves on-device). Padding rows are
+        step (they ride the jitted call to the device). Padding rows are
         greedy (temperature 0) so the batch-wide all-greedy fast path
-        stays available whenever every REAL row is greedy."""
+        stays available whenever every REAL row is greedy. ``proposals``:
+        a verify window's drafts, a row each."""
         seeds = self._scratch_buf("sp_seeds", (B,), np.uint32)
         temp = self._scratch_buf("sp_temp", (B,), np.float32)
         top_k = self._scratch_buf("sp_top_k", (B,), np.int32)
         top_p = self._scratch_buf("sp_top_p", (B,), np.float32)
-        # the grammar allow-mask leaf is ALWAYS staged (all-ones = no
-        # constraint): mask is data, not signature, so constrained and
-        # unconstrained rows share one decode program and the compile
-        # kind set never grows (ops/sampling.apply_allow_mask is a
-        # bitwise identity on all-ones rows)
-        words = (self.model_cfg.vocab_size + 31) // 32
-        mask = self._scratch_buf("sp_mask", (B, words), np.uint32)
-        mask[:] = 0xFFFFFFFF
         n = len(batch)
         seeds[n:] = 0
         temp[n:] = 0.0
@@ -2880,16 +2869,42 @@ class LLMEngine:
             temp[i] = sp.temperature
             top_k[i] = sp.top_k
             top_p[i] = sp.top_p
-            if r.fsm is not None:
-                mask[i] = r.fsm.allow_row()
-                self._m_masked_frac.observe(r.fsm.masked_fraction())
         return {
             "seeds": seeds,
             "temperature": temp,
             "top_k": top_k,
             "top_p": top_p,
-            "mask": mask,
+            "mask": self._allow_mask_locked(batch, B, proposals),
         }
+
+    def _allow_mask_locked(self, batch: list, B: int,
+                           proposals: list[list[int]] | None):
+        """The grammar allow-mask leaf, ALWAYS part of ``sample`` (all
+        ones = no constraint): mask is data, not signature, so constrained
+        and unconstrained rows share one step program and the compile
+        kind set never grows (ops/sampling.apply_allow_mask is a bitwise
+        identity on all-ones rows). ``[B, words]`` uint32, a verify
+        window's ``[B, W, words]``. Where no row of the batch is
+        constrained it is the executor's RESIDENT all-ones array of that
+        shape: nothing is filled and nothing moved (at a real vocabulary
+        the mask is 97% of a step's staged bytes). Only a batch with a
+        constrained row stages one from the host."""
+        words = (self.model_cfg.vocab_size + 31) // 32
+        shape = ((B, words) if proposals is None
+                 else (B, self.cfg.speculative_k + 1, words))
+        if all(r.fsm is None for r in batch):
+            return self.executor.ones_mask(shape)
+        mask = self._scratch_buf("sp_mask", shape, np.uint32)
+        mask[:] = 0xFFFFFFFF
+        for i, r in enumerate(batch):
+            if r.fsm is None:
+                continue
+            if proposals is None:
+                mask[i] = r.fsm.allow_row()
+                self._m_masked_frac.observe(r.fsm.masked_fraction())
+            else:
+                r.fsm.stage_verify_masks(mask[i], proposals[i])
+        return mask
 
     def _slots_buf_locked(self, name: str, batch: list,
                           B: int) -> np.ndarray | None:
@@ -2913,8 +2928,9 @@ class LLMEngine:
 
     def _scratch_buf(self, name: str, shape: tuple, dtype) -> np.ndarray:
         """Reusable numpy staging buffer for one (name, shape) slot. TWO
-        buffers alternate per slot: jnp.asarray may alias small host
-        arrays zero-copy, so a buffer must not be rewritten until the
+        buffers alternate per slot: the jitted call a staging array is
+        handed to may alias it zero-copy (the CPU backend does) and runs
+        after it returns, so a buffer must not be rewritten until the
         dispatch consuming it has provably executed — under the lag-1
         pipeline a slot comes around again only after the intervening
         sync, which is exactly that proof. Callers must overwrite every

@@ -7,8 +7,9 @@ token sync — lives behind the `ModelExecutor` interface in this module,
 so "how many chips run the model" is an executor choice the scheduler
 never sees. Two interchangeable implementations:
 
-- `SingleDeviceExecutor` — exactly the PR 1-5 behavior: one chip, plain
-  `jnp.asarray` staging, unsharded weights and KV pool. The default.
+- `SingleDeviceExecutor` — exactly the PR 1-5 behavior: one chip, host
+  staging arrays handed to the jitted call as they are, unsharded weights
+  and KV pool. The default.
 - `ShardedExecutor` — a tp/fsdp mesh over several chips (models larger
   than one chip's HBM). It builds a mesh from
   `ray_tpu.parallel.mesh.MeshSpec`, shards the weights with the same
@@ -103,8 +104,10 @@ def _host_blocks(kv) -> np.ndarray:
 class ModelExecutor:
     """Device-side half of the LLM engine.
 
-    The engine stages every input as numpy (its bucketed scratch pool)
-    and calls one of the methods below; the executor owns placement:
+    The engine stages every input as numpy (its bucketed scratch pool;
+    the all-ones allow-mask of an unconstrained batch is ``ones_mask``'s
+    resident array) and calls one of the methods below; the executor owns
+    placement:
     where the weights live, how the paged KV pool arrays (`cache.k` /
     `cache.v`) are laid out, and which devices the jitted step runs on.
     Shared base implementation = the single-device datapath; subclasses
@@ -170,11 +173,13 @@ class ModelExecutor:
         self.phases: dict = {}
         # ... and the span inside ``executor.stage`` (``executor.feed``),
         # in a table of its own: phases of a step add up, and this one
-        # lies within another. What ``_run`` moved host -> device: arrays
-        # and their bytes
+        # lies within another. What ``_run``'s launches moved host ->
+        # device: arrays, their bytes, and the launches that moved a mask
         self.spans: dict = {}
         self.stage_transfers = 0
         self.stage_bytes = 0
+        self.stage_masks = 0
+        self._ones_masks: dict[tuple, Any] = {}
         # ``_warm_feed``: a step's ids by width, and the decode row
         # buckets run, whose pairs have their id gather compiled
         self._ids_seen: dict[int, Any] = {}
@@ -289,76 +294,89 @@ class ModelExecutor:
 
     # ---------------- staging ----------------
 
-    def _dev(self, x):
-        """Host staging array -> device. On-device arrays (the lag-1
-        token feed) pass through untouched. Uncommitted placement: jit
-        moves the value to wherever the executable's sharding wants it,
-        so the SAME code serves one chip and a mesh."""
-        import jax.numpy as jnp
+    def ones_mask(self, shape: tuple):
+        """The grammar allow-mask of a step none of whose rows is
+        constrained: all ones, ``[B, words]`` uint32 (a verify window's
+        ``[B, W, words]``), RESIDENT on the device — made once a shape and
+        handed to every such launch, so the 0.4-0.8 MB a 64-row step's
+        mask is at a real vocabulary are neither filled nor moved again.
+        It takes the ``mask`` leaf's place in the ``sample`` pytree with
+        the shape and dtype a staged mask has: data, not signature, the
+        same program. ``sample`` is donated to no program, and nothing
+        writes the array. Uncommitted, like a host array: under a mesh
+        the call itself copies it to where the executable wants it."""
+        mask = self._ones_masks.get(shape)
+        if mask is None:
+            import jax
 
-        return jnp.asarray(x)
-
-    def _dev_sample(self, sample: dict | None):
-        """Move the engine's ``sample=`` staging pytree on-device. The
-        grammar allow-mask leaf rides here like every other control:
-        ``[B, ceil(V/32)]`` uint32 for decode steps, ``[B, W, words]``
-        for verify windows (one allow-set per column). Validated at the
-        seam — a wrongly-typed mask would silently allow everything
-        after the kernel's bit unpack — and shared by both
-        SingleDeviceExecutor and ShardedExecutor (mask is replicated
-        data; the sampler applies it after the logits all-reduce)."""
-        if sample is None:
-            return None
-        mask = sample.get("mask")
-        if mask is not None:
-            assert mask.dtype == np.uint32 and mask.ndim in (2, 3), (
-                "grammar allow-mask must be packed uint32 [B, words] or "
-                f"[B, W, words], got {mask.dtype}/{mask.shape}"
-            )
-        return {k: self._dev(v) for k, v in sample.items()}
+            mask = self._ones_masks[shape] = jax.device_put(
+                np.full(shape, 0xFFFFFFFF, np.uint32))
+        return mask
 
     # ---------------- the step interface ----------------
 
     def _run(self, fn, arrays, sample, span, feed=None, **staged):
-        """One jitted step, in the two host phases it has (obs.phase):
-        ``executor.stage`` moves the engine's numpy staging arrays
-        (``arrays`` in the call's order, ``staged`` by keyword — a None
-        is left out —, and the ``sample`` pytree) on-device, and with
-        ``feed`` gathers the first of ``arrays`` from itself
-        (``feed_ids``: the ``executor.feed`` span INSIDE the stage phase,
-        booked into ``spans``; no ``executor.dispatch`` of its own), and
-        counts the host arrays it moves (``stage_transfers``,
-        ``stage_bytes``: an array already on the device moves nothing);
-        ``executor.dispatch`` is the jitted call until it returns, under
-        the attributes the engine gives in ``span`` (``kind``, ``seq``;
-        ``kv_tokens`` for decode and verify). Updates ``cache.k`` /
-        ``cache.v`` in place, literally: the step programs donate both
-        pools (decode.py ``_jit_named``), so the arrays passed in are
-        deleted by the call and the ones bound here are the same device
-        buffers with the step's rows written. Under lag-1 dispatch the
-        pool handed to step n + 1 is step n's output, still being
-        computed: donating a pending buffer is ordinary stream order.
-        Whatever else reads the pool (``export_blocks``, ``copy_blocks``,
-        ``land_blocks``) takes ``cache.k`` / ``cache.v`` as they stand
-        when it runs and holds nothing across a step. ``cache.state``
-        (None where the family keeps none) is rebound too but NOT
-        donated: ``counter_state()`` hands out a reference to it."""
+        """One jitted step, in the two host phases it has (obs.phase).
+        The engine's numpy staging arrays (``arrays`` in the call's order,
+        ``staged`` by keyword — a None is left out —, and the ``sample``
+        pytree) are handed to the jitted call AS THEY ARE: its own
+        argument path moves them, in the one crossing into the runtime,
+        with no Python conversion a leaf, to wherever the executable
+        wants them (uncommitted: the SAME code serves one chip and a
+        mesh). An array already on the device (the lag-1 token feed, the
+        resident all-ones mask) is passed through untouched. A staging
+        array may be aliased by the call (the CPU backend), so it is not
+        rewritten until the launch has provably run (engine.py
+        ``_scratch_buf``).
+
+        ``executor.stage`` is what is left to do before the call: it
+        counts the host arrays the launch moves (``stage_transfers``,
+        ``stage_bytes``; ``stage_masks``: the launches whose mask is one
+        of them, those with a constrained row), checks the grammar
+        allow-mask at the seam — ``[B, ceil(V/32)]`` uint32 for a decode
+        step, ``[B, W, words]`` for a verify window (one allow-set a
+        column); a wrongly-typed mask would silently allow everything
+        after the kernel's bit unpack — and with ``feed`` gathers the
+        first of ``arrays`` from itself (``feed_ids``: the
+        ``executor.feed`` span INSIDE the stage phase, booked into
+        ``spans``; no ``executor.dispatch`` of its own).
+        ``executor.dispatch`` is the jitted call until it returns — the
+        transfers are booked THERE — under the attributes the engine
+        gives in ``span`` (``kind``, ``seq``; ``kv_tokens`` for decode
+        and verify).
+
+        Updates ``cache.k`` / ``cache.v`` in place, literally: the step
+        programs donate both pools (decode.py ``_jit_named``), so the
+        arrays passed in are deleted by the call and the ones bound here
+        are the same device buffers with the step's rows written. Under
+        lag-1 dispatch the pool handed to step n + 1 is step n's output,
+        still being computed: donating a pending buffer is ordinary
+        stream order. Whatever else reads the pool (``export_blocks``,
+        ``copy_blocks``, ``land_blocks``) takes ``cache.k`` / ``cache.v``
+        as they stand when it runs and holds nothing across a step.
+        ``cache.state`` (None where the family keeps none) is rebound too
+        but NOT donated: ``counter_state()`` hands out a reference to
+        it."""
         with obs.phase(self.phases, "executor.stage"):
-            dev = [self._dev(a) for a in arrays]
-            if feed is not None:
-                with obs.phase(self.spans, "executor.feed"):
-                    dev[0] = feed_ids(dev[0], self._dev(feed))
+            staged = {k: v for k, v in staged.items() if v is not None}
             moved = [a for a in (*arrays, feed, *staged.values(),
                                  *(sample or {}).values())
                      if isinstance(a, np.ndarray)]
             self.stage_transfers += len(moved)
             self.stage_bytes += sum(a.nbytes for a in moved)
-            staged = {k: self._dev(v) for k, v in staged.items()
-                      if v is not None}
-            sample = self._dev_sample(sample)
+            mask = (sample or {}).get("mask")
+            if mask is not None:
+                assert mask.dtype == np.uint32 and mask.ndim in (2, 3), (
+                    "grammar allow-mask must be packed uint32 [B, words] "
+                    f"or [B, W, words], got {mask.dtype}/{mask.shape}"
+                )
+                self.stage_masks += isinstance(mask, np.ndarray)
+            if feed is not None:
+                with obs.phase(self.spans, "executor.feed"):
+                    arrays = (feed_ids(arrays[0], feed), *arrays[1:])
         with obs.phase(self.phases, "executor.dispatch", **(span or {})):
             out, self.cache.k, self.cache.v, self.cache.state = fn(
-                self.params, self.cache.k, self.cache.v, *dev,
+                self.params, self.cache.k, self.cache.v, *arrays,
                 sample=sample, state=self.cache.state, **staged,
             )
         return out
@@ -405,7 +423,7 @@ class ModelExecutor:
             self._feed_rows.add(rows)
             pairs |= {(w, rows) for w in self._ids_seen}
         for w, B in pairs:
-            feed_ids(self._ids_seen[w], self._dev(np.zeros((2, B), np.int32)))
+            feed_ids(self._ids_seen[w], np.zeros((2, B), np.int32))
 
     def verify_step(self, tokens, starts, draft_len, tables, sample=None,
                     span=None):
@@ -431,7 +449,7 @@ class ModelExecutor:
             src[i] = s
             dst[i] = d
         self.cache.k, self.cache.v = copy_blocks(
-            self.cache.k, self.cache.v, self._dev(src), self._dev(dst)
+            self.cache.k, self.cache.v, src, dst
         )
 
     def export_blocks(
@@ -466,8 +484,8 @@ class ModelExecutor:
         ids = np.zeros((width,), np.int32)
         for i, b in enumerate(block_ids):
             ids[i] = b
-        k = self._by_heads(_host_blocks(self.cache.k[:, self._dev(ids)]))
-        v = self._by_heads(_host_blocks(self.cache.v[:, self._dev(ids)]))
+        k = self._by_heads(_host_blocks(self.cache.k[:, ids]))
+        v = self._by_heads(_host_blocks(self.cache.v[:, ids]))
         return k[:, : len(block_ids)], v[:, : len(block_ids)]
 
     def _by_heads(self, blocks):
@@ -529,9 +547,7 @@ class ModelExecutor:
             k_new = jax.tree.map(_pad, k_new)
             v_new = jax.tree.map(_pad, v_new)
         self.cache.k, self.cache.v = land_blocks(
-            self.cache.k, self.cache.v, self._dev(ids),
-            jax.tree.map(self._dev, k_new), jax.tree.map(self._dev, v_new),
-        )
+            self.cache.k, self.cache.v, ids, k_new, v_new)
 
     def sync_tokens(self, tokens_dev) -> np.ndarray:
         """THE device->host transfer: one step's sampled ids as [B] int32

@@ -17,10 +17,12 @@ Design constraints:
   (``api.encode_text``: token id t < 256 <-> UTF-8 byte t), so the
   DFA alphabet is ``min(256, vocab_size)`` and token ids outside it
   are never allowed by a constrained row.
-- **Mask is data, not signature.** The engine stages one packed
-  uint32 row per batch slot into the ``sample=`` pytree every step
-  (all-ones for unconstrained rows), so constrained and unconstrained
-  rows share one decode program and the compile-kind set is frozen.
+- **Mask is data, not signature.** The ``sample=`` pytree of every
+  step holds one packed uint32 row per batch slot (all-ones for
+  unconstrained rows; staged from the host only for a batch with a
+  constrained row, else the executor's device-resident all-ones array),
+  so constrained and unconstrained rows share one decode program and
+  the compile-kind set is frozen.
 - **Unsatisfiable is a client error.** A grammar with no accepting
   path within the vocabulary raises :class:`GrammarError` — a
   ``ValueError`` subclass the proxies map to 400/INVALID_ARGUMENT,

@@ -72,7 +72,7 @@ def test_phase_names_and_shape_are_frozen(jax_cpu):
     # everything new lives under ONE key, and mirrors the phases' keys
     host = st["host"]
     assert set(host) == {"spans", "phase_cpu", "gc", "stage_transfers",
-                         "stage_bytes"}
+                         "stage_bytes", "stage_masks"}
     assert {k: set(v) for k, v in host["phase_cpu"].items()} == {
         k: set(v) for k, v in phases.items()}
     assert set(host["spans"]) <= {"engine.lock", "executor.feed"}
@@ -272,11 +272,14 @@ def test_stage_counts_what_it_moves(jax_cpu):
     launches = st["decode_steps"] + st["prefill_steps"]
     assert launches == st["phases"]["decode"]["executor.dispatch"][0] + \
         st["phases"]["prefill"]["executor.dispatch"][0]
-    # a prefill moves tokens, lengths, tables and five sampling leaves; a
-    # decode step positions, tables and the five, and its ids or the
-    # indices to gather them by unless the batch is the one in flight
-    assert 7 * launches <= host["stage_transfers"] <= 9 * launches
+    # a prefill moves tokens, lengths, tables and four sampling leaves; a
+    # decode step positions, tables and the four, and its ids or the
+    # indices to gather them by unless the batch is the one in flight.
+    # The fifth leaf, the allow-mask, rests on the device where no row is
+    # constrained: no launch moved one
+    assert 6 * launches <= host["stage_transfers"] <= 8 * launches
     assert host["stage_bytes"] >= 4 * host["stage_transfers"]
+    assert host["stage_masks"] == 0
     # rows left the batch between steps: their ids were gathered, under
     # a span of its own inside the stage phase
     assert st["decode_steps_remapped"] > 0
@@ -284,6 +287,35 @@ def test_stage_counts_what_it_moves(jax_cpu):
     assert feed[0] == st["decode_steps_remapped"]
     assert 0.0 < feed[1] < st["phases"]["decode"]["executor.stage"][1]
     assert "executor.feed" not in st["phases"]["decode"]
+    # under a grammar a mask IS moved, by the launches that hold a
+    # constrained row and by no other: counted where the engine sees the
+    # batch, beside the executor's own count
+    constrained = []
+    sample_args = eng._sample_args_locked
+
+    def seen(batch, *args):
+        constrained.append(any(r.fsm is not None for r in batch))
+        return sample_args(batch, *args)
+
+    eng._sample_args_locked = seen
+    words = (eng.model_cfg.vocab_size + 31) // 32
+    streams = [
+        eng.submit([1, 2, 3], max_new_tokens=3,
+                   structured={"type": "regex", "pattern": "(yes|no)"}),
+        eng.submit([4, 5, 6], max_new_tokens=12),
+    ]
+    _run(eng, streams)
+    st = eng.stats()
+    after = st["host"]
+    assert len(constrained) == \
+        st["decode_steps"] + st["prefill_steps"] - launches
+    assert 0 < sum(constrained) < len(constrained)
+    assert after["stage_masks"] == sum(constrained)
+    moved = after["stage_transfers"] - host["stage_transfers"]
+    assert 6 * len(constrained) + sum(constrained) <= moved \
+        <= 8 * len(constrained) + sum(constrained)
+    assert after["stage_bytes"] - host["stage_bytes"] >= \
+        4 * words * sum(constrained)
     eng.shutdown()
 
 
