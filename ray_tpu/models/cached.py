@@ -29,7 +29,11 @@ own, in a ``CachedFamily``:
   back the blocks behind its window), ``slot`` is the layer's index in the
   pool (its ordinal in its group) and ``window`` makes the attention
   sliding. Without them: the one table ``[B, NB]``, the pool's layer the
-  attending layer's ordinal, full attention;
+  attending layer's ordinal, full attention. A LATENT layer (one row a
+  token that every head reads as key and as value, the pool in planes:
+  models/pangu_ultra_moe.py) calls ``attend(q, row, rope, latent=scale)``
+  with q ``[B, S, H, C + R]`` and ONE ``row [B, S, C]`` and ``rope [B, S,
+  R]``, gets ``[B, S, H * C]`` back and un-absorbs it itself;
 - ``final_norm(params, x, cfg)`` and ``head(params, h, cfg)`` (float32
   logits over ``[..., D]``);
 - ``stack``: the key of ``params`` that holds the layers. A tree whose
@@ -62,6 +66,7 @@ from ray_tpu.ops.attention import mha_reference
 from ray_tpu.ops.kv_cache import write_kv
 from ray_tpu.ops.paged_attention import (
     decode_attention,
+    latent_attention,
     prefill_attention,
     resolve_backend,
 )
@@ -135,7 +140,7 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
 
 
 def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
-                 tables=None, window=None, then=None):
+                 tables=None, window=None, then=None, latent=None):
     """The cache side of one attention layer, on the WHOLE pools and the
     layer's index in them (an int32 scalar, traced under the scan): the
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
@@ -147,11 +152,31 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     (attention output [B, S, Hq * hd], cache_k', cache_v'). ``tables``
     [B, NB]: the layer's own table (None: ``step``'s); ``window``: sliding
     attention over the last ``window`` positions; ``then``: what else the
-    layer writes into the pools, after its K/V and before it attends."""
+    layer writes into the pools, after its K/V and before it attends.
+
+    ``latent``: the softmax scale of a LATENT layer over a pool in planes
+    (ops/paged_attention.py ``latent_attention``): ``k`` is then the
+    token's latent row ``[B, S, C]`` and ``v`` the key's rotary rest ``[B,
+    S, R]``, one of each for all heads, written to ``cache_k`` and
+    ``cache_v`` (the latent and the rotary plane); ``q`` is ``[B, S, H, C
+    + R]`` and what comes back ``[B, S, H * C]``, every kind of step
+    through the one call."""
     B, S = q.shape[:2]
     backend = cfg.attention_backend
     if tables is None:
         tables = step.block_tables
+    if latent is not None:
+        at = step.pos if step.at is None else step.at
+        one = step.kind == "decode"  # its rows are written as [B, .]
+        cache_k, cache_v = write_kv(
+            cache_k, cache_v, k[:, 0] if one else k, v[:, 0] if one else v,
+            at[:, 0] if one else at, tables, valid=step.valid, layer=layer)
+        attn = latent_attention(
+            q, cache_k, cache_v, tables,
+            at if step.valid is None else jnp.where(step.valid, at, 0),
+            latent_dim=k.shape[-1], scale=latent, backend=backend,
+            layer=layer)
+        return attn.reshape(B, S, -1), cache_k, cache_v
     if step.kind == "decode":
         at = step.rows if step.at is None else step.at[:, 0]
         cache_k, cache_v = write_kv(
@@ -222,12 +247,13 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
 
     attended = 0  # the pool spans the attending layers only
 
-    def attend(q, k, v, *, group=None, slot=None, window=None):
+    def attend(q, k, v, *, group=None, slot=None, window=None, latent=None):
         nonlocal cache_k, cache_v, attended
         attn, cache_k, cache_v = attend_layer(
             step, cache_k, cache_v, attended if slot is None else slot,
             q, k, v, cfg,
-            None if group is None else step.block_tables[group], window)
+            None if group is None else step.block_tables[group], window,
+            latent=latent)
         attended += 1
         return attn
 

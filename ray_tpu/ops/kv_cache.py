@@ -100,14 +100,19 @@ def write_kv(
     # a token's K/V then lands as ONE row
     lead = 1 if layer is None else 2
     block_size = k_layer.shape[lead]
-    row = k_layer.shape[lead + 1:]
     blk, slot = physical_slots(positions, block_tables, block_size)
     if valid is not None:
         blk = jnp.where(valid, blk, 0)
         slot = jnp.where(valid, slot, 0)
     at = (blk, slot) if layer is None else (layer, blk, slot)
 
-    def rows(x):
+    def rows(x, pool=k_layer):
+        # in the pool's own row; the two pools of a cache in PLANES (a
+        # latent row and its rotary part) have rows of their own widths,
+        # and a plane stored wider than its row (whole lanes) gets zeros
+        row = pool.shape[lead + 1:]
+        if len(row) == 1 and x.shape[-1] < row[0] and x.ndim == blk.ndim + 1:
+            x = jnp.pad(x, ((0, 0),) * blk.ndim + ((0, row[0] - x.shape[-1]),))
         return x.reshape(*blk.shape, *row)
 
     if isinstance(k_layer, QuantizedKV):
@@ -120,7 +125,7 @@ def write_kv(
             v_layer.data.at[at].set(rows(vq)), v_layer.scale.at[at].set(vs))
         return k_layer, v_layer
     k_layer = k_layer.at[at].set(rows(k.astype(k_layer.dtype)))
-    v_layer = v_layer.at[at].set(rows(v.astype(v_layer.dtype)))
+    v_layer = v_layer.at[at].set(rows(v.astype(v_layer.dtype), v_layer))
     return k_layer, v_layer
 
 
@@ -141,9 +146,10 @@ def gather_kv(
     streaming slab path below."""
     B, NB = block_tables.shape
     Bs = k_layer.shape[1]
-    hd = k_layer.shape[-1] if head_dim is None else head_dim
 
     def context(layer):
+        # None: each layer's own last axis (two planes differ in it)
+        hd = layer.shape[-1] if head_dim is None else head_dim
         if not isinstance(layer, QuantizedKV):
             return layer[block_tables].reshape(B, NB * Bs, -1, hd)
         data = layer.data[block_tables].astype(jnp.float32)
@@ -392,3 +398,43 @@ def paged_attention(
     probs = jax.nn.softmax(logits, axis=-1).astype(values.dtype)
     out = jnp.einsum("bhgt,bthd->bhgd", probs, values)
     return out.reshape(B, Hq, hd).astype(q.dtype)
+
+
+def paged_latent_attention(
+    q: jax.Array,
+    latent_layer: jax.Array,
+    rope_layer: jax.Array,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    latent_dim: int,
+    scale: float,
+) -> jax.Array:
+    """Latent (absorbed multi-head latent) attention over a cache in
+    PLANES, the XLA formulation: every query head attends ONE row a token,
+    whose key is ``[latent | rotary]`` and whose value is the latent part.
+
+    q ``[B, S, H, C + R]`` (``[q~ | q_rope]``, the up-projection of the
+    keys absorbed into the query; ``C = latent_dim``), ``latent_layer``
+    ``[num_blocks, block_size, >= C]``, ``rope_layer`` ``[num_blocks,
+    block_size, >= R]`` (a plane is stored at whole lanes: what lies past
+    its width is not read), ``positions`` ``[B, S]`` the queries' true
+    positions, their own rows already written. Returns ``[B, S, H, C]`` in
+    q's dtype: the probabilities' sum of latent rows, which the layer
+    un-absorbs. The context is gathered once (``gather_kv``) and feeds
+    both products."""
+    C = latent_dim
+    R = q.shape[-1] - C
+    latent, rope = gather_kv(latent_layer, rope_layer, block_tables)
+    latent, rope = latent[:, :, 0, :C], rope[:, :, 0, :R]  # [B, T, .]
+    logits = (
+        jnp.einsum("bshc,btc->bsht", q[..., :C], latent,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bshr,btr->bsht", q[..., C:], rope,
+                     preferred_element_type=jnp.float32)) * scale
+    T = latent.shape[1]
+    mask = (jnp.arange(T, dtype=positions.dtype)[None, None, :]
+            <= positions[:, :, None])
+    logits = jnp.where(mask[:, :, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(latent.dtype)
+    return jnp.einsum("bsht,btc->bshc", probs, latent).astype(q.dtype)

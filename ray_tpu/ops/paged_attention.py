@@ -191,6 +191,21 @@ def pool_shape(n_layer: int, num_blocks: int, block_size: int, Hkv: int,
     return (*lead, Hkv * hd)
 
 
+def _fit_pages(need, bs: int, R: int, NB: int):
+    """``(P, need(P))``: the largest power of two of pages whose ``P *
+    bs`` tokens stay within what a block aims at (``_BLOCK_TOKENS``, twice
+    that for a tile of many rows), that a table of ``NB`` entries is wide
+    enough for, and whose ``need(P)`` bytes of VMEM fit; a page a block is
+    the floor."""
+    tokens = _BLOCK_TOKENS * (2 if R >= _MANY_ROWS else 1)
+    P = 1
+    while (
+        2 * P * bs <= tokens and 2 * P <= NB and need(2 * P) <= _VMEM_CAP
+    ):
+        P *= 2
+    return P, need(P)
+
+
 def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
     """``(P, vmem_bytes)``: the pages of one compute block and what the
     call then keeps in VMEM, from the shapes alone (``page`` is a page's
@@ -200,7 +215,6 @@ def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
     out tiles, the positions, the accumulators and a block's scores; a
     page a block (P = 1) is the floor."""
     bs = page[0]
-    tokens = _BLOCK_TOKENS * (2 if R >= _MANY_ROWS else 1)
     fixed = (
         # q and out tiles, double-buffered by the pipeline; positions
         4 * _vmem_bytes((Hkv, R, hd), q_dtype)
@@ -221,12 +235,7 @@ def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
             + Hkv * _vmem_bytes((p * bs, hd), jnp.float32)
         )
 
-    P = 1
-    while (
-        2 * P * bs <= tokens and 2 * P <= NB and need(2 * P) <= _VMEM_CAP
-    ):
-        P *= 2
-    return P, need(P)
+    return _fit_pages(need, bs, R, NB)
 
 
 def _kernel_name(window: int | None) -> str:
@@ -234,6 +243,11 @@ def _kernel_name(window: int | None) -> str:
     windowed calls have one of their own, so that a trace parts the
     sliding layers' time from the full layers'."""
     return "paged_attention" if window is None else "paged_attention_window"
+
+
+# the latent calls' own name (``paged_latent_attention_pallas``): a trace
+# then parts the time of a pool in planes from that of K and V by head
+LATENT_KERNEL_NAME = "paged_attention_latent"
 
 
 def _paged_attention_kernel(
@@ -258,6 +272,9 @@ def _paged_attention_kernel(
     pages: int,
     window: int | None,
     quantized: bool,
+    latent: bool = False,  # a pool in PLANES: k_hbm the latent rows, key
+                           # AND value of every head; v_hbm the key's
+                           # rotary rest; q rows ``[q~ | q_rope]``
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -325,7 +342,10 @@ def _paged_attention_kernel(
     # A page of a visited block that no copy wrote holds an older block's
     # page, or at a call's start nothing yet; the mask zeroes its
     # probabilities, and 0 x V must stay 0.
-    v_buf[...] = jnp.zeros_like(v_buf)
+    if latent:  # the latent plane is the value
+        k_buf[...] = jnp.zeros_like(k_buf)
+    else:
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     def step(i, carry):
         # block i's pages fly while block i - 1 computes
@@ -347,19 +367,37 @@ def _paged_attention_kernel(
         # for all heads at once on [Hkv, R, T]. What a head adds to the
         # kernel's text is what every process pays again, for each of its
         # step programs, to trace and lower it.
-        k_v = [
-            (head(k_buf, slot, h), head(v_buf, slot, h))
-            for h in range(n_head)
-        ]
-        as_f32 = lax.convert_element_type
+        if latent:
+            # ONE [T, C] tile a block for all the query heads (the rows):
+            # the page that was copied once is key and value, the rotary
+            # plane's tile the key's rest: the scores are q~ . c + q_rope
+            # . k_r, one "head" of R rows
+            c = k_buf[slot]
+            k_v = [((c, v_buf[slot]), c)]
+        else:
+            k_v = [
+                ((head(k_buf, slot, h),), head(v_buf, slot, h))
+                for h in range(n_head)
+            ]
+
+        def q_parts(h):
+            # [R, hd], pre-scaled; a latent row's query in its two parts
+            if latent:
+                C = k_buf.shape[-1]
+                return q_ref[0, 0, :, :C], q_ref[0, 0, :, C:]
+            if quantized:
+                return (lax.convert_element_type(q_ref[0, h], jnp.float32),)
+            return (q_ref[0, h],)
+
         s = jnp.stack([
-            lax.dot_general(
-                as_f32(q_ref[0, h], jnp.float32) if quantized
-                else q_ref[0, h],      # [R, hd], pre-scaled
-                k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h, (k, _) in enumerate(k_v)
+            functools.reduce(jnp.add, [
+                lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                for q, k in zip(q_parts(h), k_parts)
+            ])
+            for h, (k_parts, _) in enumerate(k_v)
         ])                             # [Hkv, R, T]
         if quantized:
             # dequantize the SCORES: q . (k * scale_t) is
@@ -397,6 +435,35 @@ def _paged_attention_kernel(
     l = l_scr[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+def _q_tiles(q, positions, q_block):
+    """``q`` ``[B, S, ..]`` and its ``positions`` cut into tiles of
+    ``q_block`` queries along S: ``(q, pos, q_block, tiles)``. A tile that
+    is not the whole chunk has 8k queries; S is padded up to whole tiles
+    with position-0 rows, which the caller slices off."""
+    S = q.shape[1]
+    qb = q_block
+    if qb < S:
+        # a q tile that is not the whole chunk must have 8k rows
+        qb = -(-qb // 8) * 8
+    nqb = -(-S // qb)
+    Sp = nqb * qb
+    pos = positions.astype(jnp.int32)
+    if Sp != S:
+        q = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+        pos = jnp.pad(pos, ((0, 0), (0, Sp - S)))
+    return q, pos, qb, nqb
+
+
+def _frontiers(pos, nqb):
+    """``(qmax, qmin)`` ``[B, nqb]``: the causal frontier and the window
+    floor per (b, q-block), the scalars that bound the kernel's walk.
+    Padding rows sit at position 0, so they never extend the frontier (and
+    only make the floor conservative, never wrong)."""
+    posb = pos.reshape(pos.shape[0], nqb, -1)
+    return (jnp.max(posb, axis=2).astype(jnp.int32),
+            jnp.min(posb, axis=2).astype(jnp.int32))
 
 
 def _as_pools(k_layer, v_layer, layer):
@@ -501,17 +568,10 @@ def paged_prefill_attention_pallas(
     G = Hq // Hkv
     NB = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    qb = q_block if q_block is not None else min(S, 128)
-    if qb < S:
-        # a q tile that is not the whole chunk must have 8k rows
-        qb = -(-qb // 8) * 8
-    nqb = -(-S // qb)
+    q, pos, qb, nqb = _q_tiles(
+        q, positions, q_block if q_block is not None else min(S, 128))
     Sp = nqb * qb
     R = qb * G
-    pos = positions.astype(jnp.int32)
-    if Sp != S:
-        q = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
-        pos = jnp.pad(pos, ((0, 0), (0, Sp - S)))
     # fold softmax scale AND log2(e) into q once — base-2 softmax
     # in-kernel. [B, S, Hq, hd] -> [B, Hkv, S*G, hd]: query head h serves
     # kv head h // G (the jnp.repeat head mapping, compacted), and the
@@ -525,13 +585,7 @@ def paged_prefill_attention_pallas(
     pos_rows = jnp.broadcast_to(
         pos[:, :, None], (B, Sp, G)
     ).reshape(B, Sp * G, 1)
-    posb = pos.reshape(B, nqb, qb)
-    # causal frontier / window floor per (b, q-block) — the scalars that
-    # bound the kernel's walk. Padding rows sit at position 0,
-    # so they never extend the frontier (and only make the floor
-    # conservative, never wrong).
-    qmax = jnp.max(posb, axis=2).astype(jnp.int32)
-    qmin = jnp.min(posb, axis=2).astype(jnp.int32)
+    qmax, qmin = _frontiers(pos, nqb)
 
     page = k_data.shape[2:]
     pages, vmem = _compute_block(
@@ -733,3 +787,176 @@ def prefill_attention(
         q, *_at_layer(k_layer, v_layer, layer), block_tables, positions,
         scale=scale, window=window,
     )
+
+
+# ----------------------------------------------------------------------------
+# A pool in PLANES: latent attention (models/pangu_ultra_moe.py). A token's
+# row is one latent vector that every head reads, as key (with its rotary
+# rest beside it) and as value; the two pools the step carries hold the
+# latent plane and the rotary plane, each stored at whole lanes. The SAME
+# kernel walks the table (``latent=True``): a page of each plane is copied
+# once, the query heads are the rows of one tile, decode is S = 1.
+# ----------------------------------------------------------------------------
+
+# queries a tile of a chunk: with H heads each, ``q_block * H`` rows share
+# every page the tile copies
+_LATENT_Q_BLOCK = 8
+
+
+def plane_width(width: int) -> int:
+    """What a plane of ``width`` numbers a token is STORED at: whole lanes
+    of 128, so that a page is whole tiles, rests as written and is copied
+    where it stands (the latent 512 as it is; the rotary 64 as 128)."""
+    return -(-width // 128) * 128
+
+
+def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
+    """``(P, vmem_bytes)`` as ``_compute_block``, for pages of two planes
+    ``[bs, Cp]`` and ``[bs, Rp]`` and a tile of R rows."""
+    fixed = (
+        2 * _vmem_bytes((R, Cp + Rp), q_dtype)      # q, double-buffered
+        + 2 * _vmem_bytes((R, Cp), q_dtype)         # out
+        + 2 * _vmem_bytes((R, 1), jnp.int32)
+        + 2 * _vmem_bytes((R, 1), jnp.float32)      # running max and sum
+        + _vmem_bytes((R, Cp), jnp.float32)         # accumulator
+    )
+
+    def need(p):
+        return fixed + 2 * (
+            _vmem_bytes((p * bs, Cp), kv_dtype)
+            + _vmem_bytes((p * bs, Rp), kv_dtype)
+        ) + 2 * _vmem_bytes((R, p * bs), jnp.float32) + _vmem_bytes(
+            (R, Cp), jnp.float32)
+
+    return _fit_pages(need, bs, R, NB)
+
+
+def paged_latent_attention_pallas(
+    q: jax.Array,
+    latent_pool: jax.Array,
+    rope_pool: jax.Array,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    latent_dim: int,
+    scale: float,
+    layer: jax.Array | int | None = None,
+    q_block: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Latent attention straight off a pool in planes: same contract as
+    ``ops/kv_cache.paged_latent_attention``. q ``[B, S, H, C + R]``
+    (``[q~ | q_rope]``, ``C = latent_dim``), the pools ``[n_layer,
+    num_blocks, block_size, plane_width(C)]`` and ``[.., plane_width(R)]``
+    with ``layer`` (one layer's without), ``positions`` ``[B, S]``. Returns
+    ``[B, S, H, C]`` in q's dtype.
+
+    The kernel is ``_paged_attention_kernel`` with ``latent=True``, under
+    the name ``paged_attention_latent``: grid ``(B, q_blocks)``, a tile of
+    ``q_block`` queries x H heads as ROWS over the one shared row a token;
+    per compute block one copy of each plane's pages, the latent tile then
+    feeds the scores (``q~ . c``, the rotary plane's ``q_rope . k_r``
+    added) and the values (``p . c``). So a page is read once for keys and
+    values, at ``2 H (C + R + C)`` flop a token. A chunk of queries against
+    a resident context runs the same kernel: nothing of the context is ever
+    expanded by head in HBM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = pallas_interpret()
+    latent_pool, rope_pool, layer = _as_pools(latent_pool, rope_pool, layer)
+    layer = layer.reshape(1)
+    B, S, H, D = q.shape
+    C, R = latent_dim, D - latent_dim
+    bs, Cp = latent_pool.shape[2:]
+    Rp = rope_pool.shape[3]
+    NB = block_tables.shape[1]
+    q, pos, qb, nqb = _q_tiles(
+        q, positions,
+        q_block if q_block is not None else min(S, _LATENT_Q_BLOCK))
+    Sp = nqb * qb
+    rows = qb * H
+    q = q * jnp.asarray(scale * LOG2E, q.dtype)
+    # each part of a row padded to its plane's stored width (nothing at
+    # the latent 512; the rotary 64 to 128, against the plane's zeros)
+    qf = jnp.concatenate([
+        jnp.pad(q[..., :C], ((0, 0),) * 3 + ((0, Cp - C),)),
+        jnp.pad(q[..., C:], ((0, 0),) * 3 + ((0, Rp - R),)),
+    ], axis=-1).reshape(B, 1, Sp * H, Cp + Rp)
+    pos_rows = jnp.broadcast_to(
+        pos[:, :, None], (B, Sp, H)).reshape(B, Sp * H, 1)
+    qmax, qmin = _frontiers(pos, nqb)
+    pages, vmem = _latent_block(
+        bs, Cp, Rp, rows, NB, q.dtype, latent_pool.dtype)
+
+    def q_map(b, j, *refs):
+        return (b, 0, j, 0)
+
+    def pos_map(b, j, *refs):
+        return (b, j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, nqb),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows, Cp + Rp), q_map),
+            pl.BlockSpec((1, rows, 1), pos_map),
+            # the planes stay in HBM: the kernel copies the pages itself
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, rows, Cp), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, Cp), latent_pool.dtype),
+            pltpu.VMEM((2, pages * bs, Rp), rope_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, Cp), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_attention_kernel, block_size=bs, pages=pages,
+            window=None, quantized=False, latent=True,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, Sp * H, Cp), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
+        ),
+        name=LATENT_KERNEL_NAME,
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), qmax, qmin, layer, qf, pos_rows,
+      latent_pool, rope_pool)
+    return out.reshape(B, Sp, H, Cp)[:, :S, :, :C]
+
+
+def latent_attention(
+    q: jax.Array,
+    latent_pool: jax.Array,
+    rope_pool: jax.Array,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    latent_dim: int,
+    scale: float,
+    backend: str = "auto",
+    layer: jax.Array | int | None = None,
+) -> jax.Array:
+    """Backend dispatcher for latent attention over a pool in planes, the
+    one entry point of the cached step's latent layers, every kind of
+    step: q ``[B, S, H, C + R]`` at true ``positions`` ``[B, S]`` (decode
+    is S = 1), ``[B, S, H, C]`` back. "pallas": the kernel above; "xla":
+    ``ops/kv_cache.paged_latent_attention`` through ``gather_kv``."""
+    if resolve_backend(backend) == "pallas":
+        return paged_latent_attention_pallas(
+            q, latent_pool, rope_pool, block_tables, positions,
+            latent_dim=latent_dim, scale=scale, layer=layer)
+    from ray_tpu.ops.kv_cache import paged_latent_attention
+
+    return paged_latent_attention(
+        q, *_at_layer(latent_pool, rope_pool, layer), block_tables,
+        positions, latent_dim=latent_dim, scale=scale)

@@ -33,7 +33,10 @@ class Family:
     running sequence BESIDE the pool, its rows addressed by the steps'
     ``slots``; ``counters`` is ``state -> {name: int | [int]}``, the
     counters the step programs keep inside ``state``, read for
-    ``stats()``."""
+    ``stats()``. ``state_rows``: whether ``state`` holds rows a sequence
+    (True; a prefix hit, a pause or a handoff would need them at a block's
+    boundary) or only such counters (False: the slots then only tell a
+    step's padding rows from its real ones)."""
 
     init: Callable
     prefill: Callable
@@ -44,6 +47,7 @@ class Family:
     default_config: Callable
     init_state: Callable | None = None
     counters: Callable | None = None
+    state_rows: bool = True
 
 
 def _gpt() -> Family:
@@ -92,11 +96,24 @@ def _evabyte() -> Family:
                   m.EvaByteConfig.tiny)
 
 
+def _pangu_ultra_moe() -> Family:
+    from ray_tpu.models import pangu_ultra_moe as m
+
+    # no verify step: nothing drafts (the prediction module is not held)
+    return Family(m.pangu_ultra_moe_init, m.pangu_ultra_moe_prefill,
+                  m.pangu_ultra_moe_decode_step, None,
+                  m.pangu_ultra_moe_param_axes, m.pangu_ultra_moe_quant_axes,
+                  m.PanguUltraMoEConfig.tiny,
+                  init_state=m.pangu_ultra_moe_init_state,
+                  counters=m.pangu_ultra_moe_counters, state_rows=False)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
     "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
     "laguna": _laguna, "evabyte": _evabyte,
+    "pangu_ultra_moe": _pangu_ultra_moe,
 }
 
 

@@ -546,6 +546,9 @@ class LLMEngine:
         # (decode.py ``Family.init_state``): what cannot carry that state yet
         # is refused here, by name, never served silently wrong.
         self._stateful = family.init_state is not None
+        # ... of which only ROWS a sequence (a conv state) stand in the way
+        # of a prefix hit, a pause or a handoff; counters alone do not
+        self._state_rows = self._stateful and family.state_rows
         # A family whose layers keep their K/V by GROUP (``kv_table_groups``:
         # kv_cache.py "Tables by group"): one table a group, windowed
         # groups give blocks back. What cannot carry that is refused too.
@@ -554,13 +557,21 @@ class LLMEngine:
         # summaries that every layer reads, COMPOSED into a step's table
         # (kv_cache.py "A ring and a table of slots"): its own refusals
         composed = is_composed(groups)
+        # ... or caches ONE row a token for all heads, in planes
+        # (``kv_planes``: kv_cache.py "A pool in planes"): what describes a
+        # block by heads, or splits the pool along them, is refused
+        planes = tuple(getattr(model_cfg, "kv_planes", ()))
         self._refuse_for_state(
-            cfg, quant, self._stateful, bool(groups) and not composed,
-            composed)
+            cfg, quant, self._state_rows, bool(groups) and not composed,
+            composed, bool(planes))
         # why no prompt prefix is reused (None: it is), for ``stats()``
         self._prefix_reuse_why = self._no_prefix_reuse(
-            self._stateful, bool(groups), composed)
-        n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
+            self._state_rows, bool(groups), composed)
+        if planes:  # one row of the planes' widths, no head axis
+            n_kv, head_dim = 1, sum(width for _, width, _ in planes)
+        else:
+            n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
+            head_dim = model_cfg.head_dim
         # one slot per running sequence, and slot 0, the garbage sink
         slots = cfg.max_batch_size + 1 if self._stateful else 0
         self.cache = PagedKVCache(
@@ -569,7 +580,7 @@ class LLMEngine:
                 # unless the family says otherwise
                 n_layer=getattr(model_cfg, "n_kv_layer", model_cfg.n_layer),
                 n_kv_head=n_kv,
-                head_dim=model_cfg.head_dim,
+                head_dim=head_dim,
                 num_blocks=cfg.num_blocks,
                 block_size=cfg.block_size,
                 dtype=model_cfg.dtype,
@@ -581,6 +592,7 @@ class LLMEngine:
                 # which were given back: no reuse for such a family
                 prefix_reuse=self._prefix_reuse_why is None,
                 groups=groups,
+                planes=planes,
             ),
             state=(family.init_state(model_cfg, slots)
                    if self._stateful else None),
@@ -963,11 +975,13 @@ class LLMEngine:
 
     @staticmethod
     def _refuse_for_state(cfg: EngineConfig, quant, stateful: bool,
-                          grouped: bool, composed: bool = False) -> None:
+                          grouped: bool, composed: bool = False,
+                          latent: bool = False) -> None:
         """Raise for each option that cannot yet carry what the family
         keeps: per-sequence state beside the pool (``lfm2_moe``), tables
-        by group of layers (``laguna``) or a ring and a table of chunk
-        summaries (``evabyte``), each with its reason."""
+        by group of layers (``laguna``), a ring and a table of chunk
+        summaries (``evabyte``) or one latent row a token in planes
+        (``pangu_ultra_moe``), each with its reason."""
         asked = {
             "speculative_k": cfg.speculative_k > 0,
             "host_cache_bytes": cfg.host_cache_bytes > 0,
@@ -1029,6 +1043,24 @@ class LLMEngine:
                     "quantized pool have no slot for it",
                 "tp/fsdp/mesh":
                     "ShardedExecutor places one table a step"}),
+            (latent, "caches one latent row a token for all heads, in "
+                     "planes", {
+                "speculative_k":
+                    "the family has no verify step, and the "
+                    "multi-token-prediction module that would draft is "
+                    "not held",
+                "host_cache_bytes":
+                    "the host tier's record (kv_transfer.KVLayout) "
+                    "describes a block as n_kv_head x head_dim twice and "
+                    "cannot say planes",
+                "quantization":
+                    "a quantized pool's scale planes are one scale a "
+                    "(token, head) and a latent row has no head; the "
+                    "expert weights have no quantized path either",
+                "tp/fsdp/mesh":
+                    "ShardedExecutor splits the pool along its head axis "
+                    "and one shared row has none; it has no expert axis "
+                    "either"}),
         ):
             for option, reason in why.items():
                 if keeps and asked[option]:
@@ -1037,7 +1069,13 @@ class LLMEngine:
                         f"with {option}: {reason}")
 
     def _refuse_handoff(self, what: str) -> None:
-        if self._stateful:
+        if self.cache.cfg.planes:
+            raise ValueError(
+                f"model {self.cfg.model!r} caches one latent row a token "
+                f"for all heads, in planes, and cannot {what}: the "
+                "handoff's record (kv_transfer.KVLayout) describes a block "
+                "as n_kv_head x head_dim twice and cannot say planes")
+        if self._state_rows:
             raise ValueError(
                 f"model {self.cfg.model!r} keeps per-sequence state beside "
                 f"the paged cache and cannot {what}: the prefill/decode "
@@ -1391,6 +1429,9 @@ class LLMEngine:
                 # the windowed groups took, and gave back behind the
                 # window while their sequence lived
                 "kv_groups": self.cache.group_report(),
+                # what a token's row in the pool is: K and V by head, or a
+                # latent family's planes, and its bytes
+                "kv_pool": self.cache.cfg.describe_pool(),
                 "kv_window_blocks_taken": cs.window_blocks_taken,
                 "kv_window_blocks_freed": cs.window_blocks_freed,
                 # per-sequence state beside the pool (0 for a family that
@@ -2181,7 +2222,11 @@ class LLMEngine:
                 starts[i] = r.prefill_done
                 tables[..., i, :] = self._table_for(r, nb, r.prefill_done)
             sample = self._sample_args_locked(batch, B)
-        span = {"kind": kind, "seq": self._launched + 1}
+        # the (query, key) pairs the step's attention covers: each real
+        # query token at position p attends p + 1 positions
+        span = {"kind": kind, "seq": self._launched + 1,
+                "qk_pairs": sum(n * r.prefill_done + n * (n + 1) // 2
+                                for r, n in zip(batch, ns))}
         if self._kv_ring:
             W, C = self._kv_ring
             # what the step's summarise call is handed a layer: every
@@ -2451,9 +2496,14 @@ class LLMEngine:
                         feed[1, i] = (
                             r.generated[-1] if r.generated else r.prompt[-1]
                         )
-                if (feed[0] >= 0).any():
+                if steady:
+                    # gathered on the device even where the host holds
+                    # every id (a decode behind a chunk that is not its
+                    # row's last): a decode program is called with ids ON
+                    # THE DEVICE always, ONE argument form (executor.py
+                    # ``decode_step``)
                     tokens_src = ahead.tokens
-                else:  # every id is on the host: staged as it is
+                else:  # nothing in flight: every id is on the host
                     tokens_src, feed = feed[1], None
             sample = self._sample_args_locked(batch, B)
         # what the kernels read this step, for the dispatch span and the

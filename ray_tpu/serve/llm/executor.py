@@ -125,7 +125,9 @@ class ModelExecutor:
       chunked/prefix path at true positions.
     - ``decode_step(tokens, positions, tables, sample=, feed=)`` — one
       decode step; ``tokens`` is either a host staging array (cold
-      dispatch) or the previous step's on-device array (the lag-1 steady
+      dispatch, nothing in flight: put on the device before the call, so
+      that the program sees ONE form of ids) or the previous step's
+      on-device array (the lag-1 steady
       feed, the same rows in the same order). With ``feed=`` (a ``[2, B]``
       host array) ``tokens`` is the on-device ids of whatever step is in
       flight and the step's ids are gathered from it on the device
@@ -315,7 +317,8 @@ class ModelExecutor:
 
     # ---------------- the step interface ----------------
 
-    def _run(self, fn, arrays, sample, span, feed=None, **staged):
+    def _run(self, fn, arrays, sample, span, feed=None, put_first=False,
+             **staged):
         """One jitted step, in the two host phases it has (obs.phase).
         The engine's numpy staging arrays (``arrays`` in the call's order,
         ``staged`` by keyword — a None is left out —, and the ``sample``
@@ -339,7 +342,9 @@ class ModelExecutor:
         after the kernel's bit unpack — and with ``feed`` gathers the
         first of ``arrays`` from itself (``feed_ids``: the
         ``executor.feed`` span INSIDE the stage phase, booked into
-        ``spans``; no ``executor.dispatch`` of its own).
+        ``spans``; no ``executor.dispatch`` of its own); with
+        ``put_first`` the first of ``arrays`` is put on the device here
+        where it is still the host's (``decode_step``).
         ``executor.dispatch`` is the jitted call until it returns — the
         transfers are booked THERE — under the attributes the engine
         gives in ``span`` (``kind``, ``seq``; ``kv_tokens`` for decode
@@ -374,6 +379,10 @@ class ModelExecutor:
             if feed is not None:
                 with obs.phase(self.spans, "executor.feed"):
                     arrays = (feed_ids(arrays[0], feed), *arrays[1:])
+            elif put_first and isinstance(arrays[0], np.ndarray):
+                import jax
+
+                arrays = (jax.device_put(arrays[0]), *arrays[1:])
         with obs.phase(self.phases, "executor.dispatch", **(span or {})):
             out, self.cache.k, self.cache.v, self.cache.state = fn(
                 self.params, self.cache.k, self.cache.v, *arrays,
@@ -397,8 +406,19 @@ class ModelExecutor:
 
     def decode_step(self, tokens, positions, tables, sample=None, span=None,
                     slots=None, feed=None):
+        """``tokens`` reaches the program ON THE DEVICE, always: the ids
+        of the step in flight, ``feed_ids``' gather from them, or (nothing
+        in flight: the host holds every id) the host's array put there
+        first. A jitted call's fast path is keyed by its arguments' KINDS,
+        and a call that leaves it with compiler options set builds the
+        executable's wrapper anew (``MeshComputation.compile``: ~0.9 s for
+        a 5-layer unrolled program on a v5e, the interpreter lock held
+        all through): with two forms of ids the first decode step behind
+        a chunk that is not its row's last stalled every stream for that
+        long, once a row bucket, under traffic (PERF.md section 6, PR 39).
+        A warm-up that runs a row bucket once has now run its only form."""
         ids = self._run(self.fns.decode, (tokens, positions, tables),
-                        sample, span, feed=feed, slots=slots)
+                        sample, span, feed=feed, put_first=True, slots=slots)
         self._warm_feed(ids, sample, rows=ids.shape[0])
         return ids
 
@@ -715,7 +735,13 @@ class ModelExecutor:
         # heads, or lane-dense (kv_cache.py)
         report = {"kv_layers": cfg.n_layer,
                   "kv_pool_shape": list(self.cache.k.shape), "state": state,
-                  "prefix_reuse": cfg.prefix_reuse}
+                  "prefix_reuse": cfg.prefix_reuse,
+                  # what a token's row is (kind "latent": planes, then
+                  # ``kv_pool_shape`` is plane 0's and ``shapes`` has both)
+                  "kv_pool": cfg.describe_pool()}
+        if cfg.planes:
+            report["kv_pool"]["shapes"] = [
+                list(self.cache.k.shape), list(self.cache.v.shape)]
         if cfg.groups:
             # tables by group: ``kv_layers`` is then a GROUP's layers (the
             # pool's layer axis; all the model's under a ring and a slot

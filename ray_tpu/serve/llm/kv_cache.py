@@ -101,6 +101,28 @@ table, where the step writes the summaries of the chunks it completes.
 ceil(ceil(n / C) / block_size)``. Such groups carry no prefix cache and no
 host tier either, and the engine refuses what else cannot carry them.
 
+A pool in planes (``planes``): a family that caches of a token not K and V
+by head but ONE row that every head reads (latent attention,
+models/pangu_ultra_moe.py: a 512-wide latent vector, key and value at
+once, and the key's 64-wide rotary rest) says so in ONE description,
+``(name, width, stored width)`` a plane, that the family's config owns
+(``kv_planes``) and this manager, ``ops/kv_cache.py write_kv``, the
+executor's report and the refusals read. The two arrays the step programs
+carry and donate are then the two planes,
+
+    k: [n_layer, num_blocks, block_size, stored width of plane 0]
+    v: [n_layer, num_blocks, block_size, stored width of plane 1]
+
+each stored at whole lanes of 128 (the rotary 64 as 128, zeros behind: a
+page is then whole tiles, rests as written and is copied by the kernel
+where it stands; ``row_bytes`` says what the layer's mathematics needs,
+1,152 B, ``stored_row_bytes`` what the pool holds, 1,280 B: +11%). One
+table, every layer keeps every token: blocks, reservations, the prefix
+cache, copy-on-write and preemption are what they are for K and V by head,
+since a block's bytes are all they touch. The host tier and the RTKV
+record describe a block as ``n_kv_head x head_dim`` twice and cannot say
+"planes" yet: the engine refuses them for such a family.
+
 Host-memory tier (``host_cache_bytes > 0``): LRU eviction DEMOTES a full
 prefix block into a pinned host-side arena instead of discarding it —
 the plasma spill model from the Ray object store, applied to KV. Each
@@ -201,8 +223,21 @@ class KVCacheConfig:
     # the model's layer indices (for reports). Empty: one table for all
     # layers, as ever. See the module docstring.
     groups: tuple = ()
+    # A pool in planes: ``(name, width, stored width)`` of each of the two
+    # arrays, from the family's ``kv_planes``. Empty: K and V by head,
+    # ``n_kv_head x head_dim`` each. See the module docstring.
+    planes: tuple = ()
 
     def __post_init__(self):
+        if self.planes and (
+                len(self.planes) != 2 or self.quantization is not None
+                or self.host_cache_bytes or self.groups):
+            raise ValueError(
+                "a pool in planes is the step programs' two arrays, plain, "
+                "under one table and without a host tier; got "
+                f"planes={self.planes}, quantization={self.quantization}, "
+                f"host_cache_bytes={self.host_cache_bytes}, "
+                f"groups={self.groups}")
         if not self.composed:
             return
         kinds = [group_kind(window) for window, _ in self.groups]
@@ -233,6 +268,46 @@ class KVCacheConfig:
     @property
     def usable_blocks(self) -> int:
         return self.num_blocks - 1  # block 0 is the garbage sink
+
+    def _itemsize(self) -> int:
+        if self.quantization is not None:
+            return 1
+        return 2 if self.dtype is None else np.dtype(self.dtype).itemsize
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of pool data a token costs ONE layer by the widths of
+        what is cached (scale planes of a quantized pool apart): K and V
+        by head, or the planes' widths."""
+        widths = (sum(width for _, width, _ in self.planes) if self.planes
+                  else 2 * self.n_kv_head * self.head_dim)
+        return widths * self._itemsize()
+
+    @property
+    def stored_row_bytes(self) -> int:
+        """... and as the pool STORES it: a plane at whole lanes."""
+        if not self.planes:
+            return self.row_bytes
+        return sum(stored for _, _, stored in self.planes) * self._itemsize()
+
+    @property
+    def block_bytes(self) -> int:
+        """What one block id holds across the pool's layers, as stored."""
+        return self.block_size * self.n_layer * self.stored_row_bytes
+
+    def describe_pool(self) -> dict:
+        """What a token's row in the pool is, for ``describe()`` and
+        ``stats()``: its kind, the planes where it has them, and the bytes
+        above."""
+        out = {"kind": "latent" if self.planes else "heads",
+               "row_bytes": self.row_bytes,
+               "stored_row_bytes": self.stored_row_bytes,
+               "block_bytes": self.block_bytes}
+        if self.planes:
+            out["planes"] = [
+                {"name": name, "width": width, "stored_width": stored}
+                for name, width, stored in self.planes]
+        return out
 
     def blocks_for(self, num_tokens: int) -> int:
         return -(-num_tokens // self.block_size)  # ceil
@@ -416,7 +491,12 @@ class PagedKVCache:
         dtype = cfg.dtype if cfg.dtype is not None else jnp.bfloat16
         shape = self.pool_shape()
         scales = shape[:3] + (cfg.n_kv_head,)
-        if cfg.quantization is not None:
+        if cfg.planes:
+            # the two arrays are the two planes, a token's row in each
+            self.k, self.v = (
+                jnp.zeros(shape[:3] + (stored,), dtype)
+                for _, _, stored in cfg.planes)
+        elif cfg.quantization is not None:
             from ray_tpu.ops.quantization import (
                 QuantizedKV,
                 quant_dtype,

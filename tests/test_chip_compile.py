@@ -735,6 +735,90 @@ def test_evabyte_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_pangu_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The openPangu cell's step programs as the executor compiles them,
+    at the cell's own shapes: the 128-row decode step and the 1 x 2,048
+    chunk over a table ``[B, 768]`` (the 12,288-token bucket), the fresh
+    prefill over ``[1, 128]``. The pool is two PLANES, ``[5, 40961, 16,
+    512]`` and ``[.., 128]`` (4.19 GB together): both are in the program's
+    ``input_output_alias`` and nothing pool-sized is among its temporaries;
+    each of the 5 layers calls ``paged_attention_latent`` once; and nothing
+    in the program has the context's length as a dimension: no K or V of a
+    resident context by head (12,288 x 128 x 320 x 2 B = 1 GB a layer), no
+    gathered context, no score matrix. The expert leaves reach their
+    operations under the names the benchmark's readers look for."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/openpangu-ultra-moe-ep32-5l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/reason-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("pangu_ultra_moe")
+    init = common.load_named("reference", "pangu_ultra_moe").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    B = engine["max_batch_size"] if kind == "decode" else 1
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
+    planes = [_struct((cfg.n_layer, engine["num_blocks"], 16, stored),
+                      cfg.dtype, one_chip) for _, _, stored in cfg.kv_planes]
+    assert [p.shape for p in planes] == [(5, 40961, 16, 512),
+                                         (5, 40961, 16, 128)]
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    ctx = engine["length_buckets"][-1]
+    assert ctx == 12288 and engine["prefill_chunk_tokens"] == 2048
+    fns = decode.DecodeFns("pangu_ultra_moe", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        assert B == 128
+        lowered = fns._decode.lower(
+            params, *planes, i32((B,)), i32((B,)), i32((B, ctx // 16)),
+            sample=None, **more)
+    else:
+        nb = ctx // 16 if kind == "prefill_chunk" else 128
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, *planes, i32((B, 2048)), i32((B,)), i32((B, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    assert abs(pool_bytes - 4.194e9) < 0.001e9
+    # 6.82 GB of weights and the two planes
+    assert 10.9e9 < mem.argument_size_in_bytes < 11.1e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < (0.1e9 if kind == "decode" else 1.6e9), \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"%paged_attention_latent[.\d]* = ", entry)) == 5
+    assert not re.findall(r"%paged_attention[.\d]* = ", entry)
+    # no array anywhere has the context's 12,288 positions as a dimension
+    assert not re.search(r"[\[,]12288[\],]", text)
+    if kind == "decode":
+        for needle in ("moe_route_w", "moe_gmm_w_in", "moe_shared_w_in",
+                       "mla_w_uk", "mla_w_uv"):
+            assert re.search(
+                rf"\(.*%params__layers___\d___{needle}__", entry), needle
+    assert "cross_program_prefetch_index" not in text
+
+
 def _body(text):
     """A compiled program's computations, less what names the CALLER: the
     module's name line, the tables of source files and stack frames, and
@@ -802,6 +886,9 @@ PARENTS_TEXT = {
     # programs of the two families whose pools rest lane-dense
     "gpt2-decode": "7071bf766b22d625",
     "lfm2-decode": "097b42605a3fe691",
+    # ISSUE 39's control, taken on PR 39's PARENT (378095c): the fifth
+    # served family's decode program, composed tables [2, 24, 192]
+    "evabyte-decode": "661e55a08e3049ed",
 }
 PARENTS_JAX = "0.9.0"
 
@@ -870,7 +957,8 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
         return
     config = {"mistral": "mistral-7b-v0.3-6l", "gpt2": "gpt2-small",
               "lfm2": "lfm2-24b-a2b-8l",
-              "laguna": "laguna-xs.2-ep8-8l"}[which]
+              "laguna": "laguna-xs.2-ep8-8l",
+              "evabyte": "evabyte-6.5b-8l"}[which]
     held = common.load_json(
         os.path.join(root, f"benchmark/configs/{config}.json"))
     cfg = dataclasses.replace(
@@ -888,10 +976,12 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
         init = common.load_named("reference", held["family"]).init_fn()
         params = jax.tree.map(on_chip, jax.eval_shape(
             lambda: init(jax.random.PRNGKey(0), cfg)))
-        more = {"state": jax.tree.map(on_chip, jax.eval_shape(
-            lambda: fam.init_state(cfg, 65))), "slots": i32((64,))}
+        if which != "evabyte":  # it keeps no state beside the pool
+            more = {"state": jax.tree.map(on_chip, jax.eval_shape(
+                lambda: fam.init_state(cfg, 65))), "slots": i32((64,))}
         num_blocks, tables = {"laguna": (32769, (4, 64, 1152)),
-                              "lfm2": (4097, (64, 160))}[which]
+                              "lfm2": (4097, (64, 160)),
+                              "evabyte": (4353, (2, 24, 192))}[which]
     pool = S_(pool_shape(getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks,
                          16, getattr(cfg, "n_kv_head", None) or cfg.n_head,
                          cfg.head_dim), cfg.dtype)
@@ -899,8 +989,9 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
     assert len(pool.shape) == (5 if cfg.head_dim == 128 else 4)
     fns = decode.DecodeFns(held["family"], cfg, platform="tpu")
     if kind == "decode":
+        rows = tables[-2]
         lowered = fns._decode.lower(
-            params, pool, pool, i32((64,)), i32((64,)), i32(tables),
+            params, pool, pool, i32((rows,)), i32((rows,)), i32(tables),
             sample=None, **more)
     else:
         lowered = fns._prefill.lower(

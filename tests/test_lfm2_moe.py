@@ -676,13 +676,14 @@ def test_unknown_model_name_raises(jax_cpu):
     with pytest.raises(ValueError, match="unknown model family 'mamba'"):
         LLMEngine(EngineConfig(model="mamba"), auto_step=False)
     assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
-                                "llama"]
+                                "llama", "pangu_ultra_moe"]
     for name in ("gpt", "llama"):
         assert get_family(name).init_state is None
         assert get_family(name).verify_step is not None
     assert get_family("lfm2_moe").verify_step is None
     assert get_family("laguna").verify_step is None
     assert get_family("evabyte").verify_step is None
+    assert get_family("pangu_ultra_moe").verify_step is None
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])

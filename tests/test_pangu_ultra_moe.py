@@ -1,0 +1,691 @@
+"""The openPangu-Ultra-MoE family on the CPU at the tiny preset (hidden 64, 4
+heads, ``q_lora_rank`` 24, ``kv_lora_rank`` 16, nope 8 + rope 4, v 8, 1 dense
++ 2 expert layers, 8 experts of which 2 held, vocab 512 of which 64 held),
+seeded weights, float32: the program against the plain reference
+(benchmark/reference/pangu_ultra_moe.py: the EXPANDED form, so every
+comparison is also absorbed against expanded), the serving path (prefill,
+chunked prefill, then decode through the pool in planes) on both backends,
+the latent kernel in the Pallas interpreter against the XLA path with every
+page outside the tables poisoned, that a page is copied once, the holders'
+parts and the sliced head, a prefix hit and a pause, what the engine
+refuses, and what it reports.
+
+Program and reference in float32 compute the same mathematics and differ in
+the order of sums (and in WHERE the up-projections enter): 1e-4 on logits
+of size ~3 (seen 3e-6).
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+VOCAB_HELD = 64  # of 512
+
+
+@pytest.fixture(scope="module")
+def ref():
+    from benchmark import common
+
+    return common.load_named("reference", "pangu_ultra_moe")
+
+
+@pytest.fixture(scope="module")
+def tiny(jax_cpu):
+    """(float32 config that holds experts 2-3 of 8 and 64 rows of the
+    vocabulary's 512, its seeded params)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import (
+        PanguUltraMoEConfig, pangu_ultra_moe_init,
+    )
+
+    cfg = dataclasses.replace(
+        PanguUltraMoEConfig.tiny(VOCAB_HELD), dtype=jnp.float32,
+        experts_held=(2, 2))
+    return cfg, pangu_ultra_moe_init(jax.random.PRNGKey(1), cfg)
+
+
+def _engine(cfg, params, **kw):
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    settings = dict(model="pangu_ultra_moe", model_config=cfg, block_size=4,
+                    num_blocks=129, max_batch_size=4, prefill_chunk_tokens=16,
+                    length_buckets=(16, 32, 64, 128))
+    settings.update(kw)
+    return LLMEngine(EngineConfig(**settings), params=params,
+                     auto_step=False)
+
+
+def _prompts(lens, seed=0, vocab=VOCAB_HELD):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, vocab, size=n).tolist() for n in lens]
+
+
+def _drive(engine, streams, limit=4000):
+    for _ in range(limit):
+        if all(s.done for s in streams):
+            break
+        if not engine.step():
+            time.sleep(0.01)  # parked streams wait for the resume clock
+    while engine.step():
+        pass
+    assert all(s.done for s in streams)
+
+
+# ------------------------------------------------- the model and its config
+
+
+def test_tiny_preset_and_published_planes(jax_cpu):
+    """The tiny preset is the issue's, and at the published widths a
+    token's row is 512 + 64 numbers in two planes, the rotary one stored
+    at a whole lane tile."""
+    from ray_tpu.models.pangu_ultra_moe import PanguUltraMoEConfig
+
+    t = PanguUltraMoEConfig.tiny()
+    assert (t.d_model, t.n_head, t.q_lora_rank, t.kv_lora_rank,
+            t.qk_nope_head_dim, t.qk_rope_head_dim, t.v_head_dim) == (
+                64, 4, 24, 16, 8, 4, 8)
+    assert (t.n_layer, t.num_dense_layers, t.num_experts) == (3, 1, 8)
+    pub = PanguUltraMoEConfig()
+    assert pub.kv_planes == (("latent", 512, 512), ("rope", 64, 128))
+    assert abs(pub.softmax_scale - 192 ** -0.5) < 1e-12
+    assert pub.n_held == 256 and dataclasses.replace(
+        pub, experts_held=(0, 8)).n_held == 8
+    with pytest.raises(ValueError, match="experts_held"):
+        dataclasses.replace(pub, experts_held=(250, 8))
+
+
+def test_a_blocks_bytes_at_the_published_widths(jax_cpu):
+    """Reckoned, not allocated: 1,152 B a token a layer by the widths of
+    what is cached, 92,160 B a block id over 5 layers; as STORED (the
+    rotary plane at 128 lanes) 1,280 B and 102,400 B, +11%. By head the
+    same token would be 81,920 B."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import PanguUltraMoEConfig
+    from ray_tpu.serve.llm.kv_cache import KVCacheConfig
+
+    pub = PanguUltraMoEConfig()
+    kv = KVCacheConfig(n_layer=5, n_kv_head=1, head_dim=576,
+                       num_blocks=40961, block_size=16, dtype=jnp.bfloat16,
+                       planes=pub.kv_planes)
+    assert kv.row_bytes == 1152
+    assert kv.block_size * kv.n_layer * kv.row_bytes == 92160
+    assert kv.stored_row_bytes == 1280 and kv.block_bytes == 102400
+    assert kv.describe_pool()["kind"] == "latent"
+    by_head = KVCacheConfig(n_layer=5, n_kv_head=128, head_dim=160,
+                            dtype=jnp.bfloat16)  # (192 + 128) / 2 a head
+    assert by_head.row_bytes == 128 * (192 + 128) * 2 == 81920
+    assert by_head.describe_pool() == {
+        "kind": "heads", "row_bytes": 81920, "stored_row_bytes": 81920,
+        "block_bytes": 16 * 5 * 81920}
+    with pytest.raises(ValueError, match="planes"):
+        KVCacheConfig(n_layer=5, n_kv_head=1, head_dim=576,
+                      planes=pub.kv_planes, quantization="int8")
+
+
+def test_full_forward_matches_the_reference(tiny, ref):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import pangu_ultra_moe_forward
+
+    cfg, params = tiny
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 37), 1,
+                                cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        got = pangu_ultra_moe_forward(params, tokens, cfg)
+    want = ref.logits(params, tokens, cfg)
+    assert got.shape == (2, 37, VOCAB_HELD)
+    assert float(jnp.abs(want).max()) > 1.0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+def test_absorbed_attention_is_the_expanded_one_layer(tiny, ref):
+    """One layer's attention: the absorbed form (what the cached step
+    computes against the pool) is the expanded form (keys and values by
+    head), in the program and against the reference's."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import pangu_ultra_moe as m
+
+    cfg, params = tiny
+    lp = params["layers"][1]
+    u = jax.random.normal(jax.random.PRNGKey(3), (2, 29, cfg.d_model))
+    pos = np.broadcast_to(np.arange(29, dtype=np.int32), (2, 29))
+    def absorbed_attention(q_nope, q_rope, c, k_r):
+        # what the cached step computes against the pool, written out:
+        # W_uk absorbed into the query, ONE row a token as key and value,
+        # W_uv after the sum
+        s = (jnp.einsum("bshc,btc->bhst", m._absorb(q_nope, lp, cfg), c)
+             + jnp.einsum("bshr,btr->bhst", q_rope, k_r)) * cfg.softmax_scale
+        t = jnp.arange(c.shape[1])
+        p = jax.nn.softmax(
+            jnp.where(t[None, :] <= t[:, None], s, -1e30), axis=-1)
+        return m._unabsorb(jnp.einsum("bhst,btc->bshc", p, c), lp, cfg)
+
+    with jax.default_matmul_precision("highest"):
+        parts = m._queries_and_row(u, lp, *m._rotary_at(pos, cfg), cfg)
+        absorbed = absorbed_attention(*parts)
+        expanded = m.expanded_attention(*parts, lp, cfg)
+        want = np.stack([np.asarray(ref.attention(u[b], lp, cfg))
+                         for b in range(2)])
+        through_o = np.asarray(absorbed @ lp["mla_w_o"])
+    assert float(np.abs(np.asarray(expanded)).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
+                               atol=1e-5)
+    np.testing.assert_allclose(through_o, want, atol=1e-5)
+
+
+def test_rotary_pairs_are_by_halves(jax_cpu, ref):
+    """The program's one rotary function and the reference's turn the
+    same pairs (i, i + R / 2)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import pangu_ultra_moe as m
+
+    cfg = m.PanguUltraMoEConfig.tiny()
+    x = jax.random.normal(jax.random.PRNGKey(4), (9, 3, 4))
+    pos = jnp.arange(9, dtype=jnp.int32)[None]
+    got = m._rotate(x[None], *m._rotary_at(pos, cfg))[0]
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref._rotate(x, cfg.rope_theta)),
+                               atol=1e-6)
+    # position 0 turns nothing; a later one turns dimension 0 with 2
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(x[0]))
+    assert abs(float(got[5, 0, 1]) - float(x[5, 0, 1])) > 1e-4
+
+
+@pytest.mark.parametrize("change", ["post_norms", "whole_row", "held",
+                                    "shared"])
+def test_the_reference_notices_each_mechanism(tiny, ref, change,
+                                              monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    cfg, params = tiny
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 24), 1,
+                                cfg.vocab_size)
+    want = ref.logits(params, tokens, cfg)
+    other_cfg, other = cfg, params
+    if change == "post_norms":
+        monkeypatch.setattr(ref, "NO_POST_NORMS", True)
+    elif change == "whole_row":
+        monkeypatch.setattr(ref, "VALUE_IS_WHOLE_ROW", True)
+    elif change == "held":
+        other_cfg = dataclasses.replace(cfg, experts_held=(4, 2))
+    else:
+        other = dict(params, layers=[
+            {k: (jnp.zeros_like(v) if k == "moe_shared_w_out" else v)
+             for k, v in lp.items()} for lp in params["layers"]])
+    got = ref.logits(other, tokens, other_cfg)
+    assert float(jnp.abs(got - want).max()) > 1e-2, change
+
+
+def test_the_four_holders_parts_add_up_to_the_uncut_layer(tiny, ref):
+    """The parts that the 4 holders of 2 experts give, the shared expert
+    and everything else counted once, are the uncut reference layer: the
+    program's expert layer told which experts it holds, against the
+    reference's feed-forward over all 8."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import pangu_ultra_moe_init
+    from ray_tpu.ops.moe import moe_dropless, moe_route
+
+    cfg, _ = tiny
+    whole = dataclasses.replace(cfg, experts_held=None)
+    lp = pangu_ultra_moe_init(jax.random.PRNGKey(6), whole)["layers"][2]
+    z = jax.random.normal(jax.random.PRNGKey(7), (12, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        want = ref.ffn(z, lp, whole)
+        weights, experts = moe_route(
+            z, lp["moe_route_w"], None, cfg.top_k, norm_topk=True,
+            scale=cfg.routed_scaling_factor)
+        got = ref.shared_part(z, lp)
+        pairs = 0
+        for first in (0, 2, 4, 6):
+            part, sizes = moe_dropless(
+                z, weights, experts, lp["moe_gmm_w_in"][first:first + 2],
+                lp["moe_gmm_w_out"][first:first + 2], dtype=jnp.float32,
+                valid=jnp.ones((12,), bool), held=(first, 2))
+            got = got + part
+            pairs += int(sizes.sum())
+    assert pairs == 12 * cfg.top_k  # every routed pair met ONE holder
+    assert float(jnp.abs(want).max()) > 1e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_the_sliced_heads_logits_are_the_whole_heads_first_rows(tiny, ref):
+    """A model that holds 64 rows of the vocabulary gives, for ids of the
+    slice, the logits the whole head gives on those rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import (
+        pangu_ultra_moe_forward, pangu_ultra_moe_init,
+    )
+
+    cfg, _ = tiny
+    whole = dataclasses.replace(cfg, vocab_size=512)
+    params = pangu_ultra_moe_init(jax.random.PRNGKey(8), whole)
+    sliced = dict(params, wte=params["wte"][:VOCAB_HELD],
+                  lm_head=params["lm_head"][:, :VOCAB_HELD])
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (1, 20), 1,
+                                VOCAB_HELD)
+    with jax.default_matmul_precision("highest"):
+        all_rows = pangu_ultra_moe_forward(params, tokens, whole)
+        mine = pangu_ultra_moe_forward(sliced, tokens, cfg)
+    assert mine.shape[-1] == VOCAB_HELD and all_rows.shape[-1] == 512
+    np.testing.assert_allclose(np.asarray(mine),
+                               np.asarray(all_rows[..., :VOCAB_HELD]),
+                               atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(ref.logits(sliced, tokens, cfg)), np.asarray(mine),
+        atol=1e-4)
+
+
+# --------------------------------------------- the pool in planes, the kernel
+
+
+def test_write_kv_lands_each_plane_in_its_own_row(jax_cpu):
+    """``write_kv`` over two planes of different widths: the latent row as
+    it is, the rotary rest padded with zeros to its stored width."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.kv_cache import write_kv
+
+    lat = jnp.full((2, 5, 4, 16), -1.0)
+    rope = jnp.full((2, 5, 4, 8), -1.0)
+    c = jnp.arange(2 * 3 * 16, dtype=jnp.float32).reshape(2, 3, 16)
+    k_r = jnp.arange(2 * 3 * 4, dtype=jnp.float32).reshape(2, 3, 4) + 100
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos = jnp.asarray([[3, 4, 5], [0, 1, 2]], jnp.int32)
+    valid = jnp.asarray([[True, True, False], [True, True, True]])
+    lat, rope = write_kv(lat, rope, c, k_r, pos, tables, valid=valid, layer=1)
+    assert float(lat[0].max()) == -1.0  # the other layer is untouched
+    np.testing.assert_array_equal(np.asarray(lat[1, 1, 3]), np.asarray(c[0, 0]))
+    np.testing.assert_array_equal(np.asarray(lat[1, 2, 0]), np.asarray(c[0, 1]))
+    np.testing.assert_array_equal(np.asarray(rope[1, 3, 2, :4]),
+                                  np.asarray(k_r[1, 2]))
+    assert float(jnp.abs(rope[1, 3, 2, 4:]).max()) == 0.0  # the padding
+    assert float(lat[1, 2, 1].max()) == -1.0  # the masked token went to 0
+    # decode: one row a sequence
+    lat, rope = write_kv(lat, rope, c[:, 0], k_r[:, 0],
+                         jnp.asarray([6, 3], jnp.int32), tables, layer=0)
+    np.testing.assert_array_equal(np.asarray(rope[0, 2, 2, :4]),
+                                  np.asarray(k_r[0, 0]))
+
+
+def _latent_case(kind, seed=0, H=4, C=16, R=4, bs=4, NB=8, B=2):
+    """q, the two planes with every page OUTSIDE the tables poisoned, the
+    tables and positions of a decode step, a chunk against a resident
+    context, or a fresh prompt."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import plane_width
+
+    S = {"decode": 1, "chunk": 8, "fresh": 11}[kind]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    blocks = 1 + B * NB
+    lat = jax.random.normal(ks[0], (2, blocks + 3, bs, plane_width(C)))
+    rope = jax.random.normal(ks[1], (2, blocks + 3, bs, plane_width(R)))
+    # the planes' padding lanes hold zeros, as the pool's do
+    lat = lat.at[..., C:].set(0.0)
+    rope = rope.at[..., R:].set(0.0)
+    tables = np.zeros((B, NB), np.int32)
+    perm = np.random.default_rng(seed).permutation(np.arange(1, blocks))
+    ctx = {"decode": [13, 30], "chunk": [21, 9], "fresh": [0, 0]}[kind]
+    pos = np.zeros((B, S), np.int32)
+    for b in range(B):
+        n = -(-(ctx[b] + S) // bs)
+        tables[b, :n] = perm[b * NB: b * NB + n]
+        pos[b] = ctx[b] + np.arange(S)
+    poisoned = np.ones(blocks + 3, bool)
+    poisoned[tables[tables > 0]] = False
+    poison = jnp.asarray(poisoned)[None, :, None, None]
+    lat = jnp.where(poison, jnp.nan, lat)
+    rope = jnp.where(poison, jnp.inf, rope)
+    q = jax.random.normal(ks[2], (B, S, H, C + R))
+    return q, lat, rope, jnp.asarray(tables), jnp.asarray(pos), C
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "fresh"])
+def test_latent_kernel_matches_xla_with_every_other_page_poisoned(
+        jax_cpu, kind):
+    """The kernel in the Pallas interpreter == the XLA path through
+    ``gather_kv``, both reading the whole pools at a layer index; pages no
+    table names hold NaN and inf (block 0, which padding entries name, is
+    poisoned too: the walk never copies past a row's frontier)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import latent_attention
+
+    q, lat, rope, tables, pos, C = _latent_case(kind)
+    # the XLA path gathers a table's padding entries (block 0) and masks
+    # them: give IT a clean block 0; the kernel gets the poisoned one
+    want = latent_attention(
+        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        latent_dim=C, scale=0.3, backend="xla", layer=1)
+    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+                           scale=0.3, backend="pallas", layer=1)
+    assert got.shape == (*q.shape[:3], C)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_page_is_copied_once_for_keys_and_values(jax_cpu):
+    """The kernel's own text, counted: a compute block starts ONE copy a
+    plane's page (the latent page, the rotary page) and waits for each
+    once; the latent tile then feeds two of the three products (``q~ .
+    c`` and ``p . c``), the rotary tile the third. Nothing copies a page a
+    second time for the values."""
+    import functools
+
+    import jax
+
+    from ray_tpu.ops.paged_attention import (
+        LATENT_KERNEL_NAME, paged_latent_attention_pallas,
+    )
+
+    q, lat, rope, tables, pos, C = _latent_case("chunk")
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_latent_attention_pallas, latent_dim=C, scale=0.3, layer=1,
+        interpret=False))(q, lat, rope, tables, pos)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert LATENT_KERNEL_NAME in str(calls[0].params["name"]) \
+        or LATENT_KERNEL_NAME in str(calls[0].params)
+    body = str(calls[0].params["jaxpr"])
+    assert body.count("dma_start") == 2, body.count("dma_start")
+    assert body.count("dma_wait") == 2, body.count("dma_wait")
+    assert body.count("dot_general") == 3, body.count("dot_general")
+
+
+def test_latent_calls_have_a_kernel_name_of_their_own(jax_cpu):
+    """``paged_attention_latent`` holds ``paged_attention``: the accepted
+    share metric finds it, and a trace parts it from the by-head calls."""
+    from ray_tpu.ops.paged_attention import LATENT_KERNEL_NAME, _kernel_name
+
+    assert LATENT_KERNEL_NAME == "paged_attention_latent"
+    assert _kernel_name(None) in LATENT_KERNEL_NAME
+    assert LATENT_KERNEL_NAME not in (_kernel_name(None), _kernel_name(8))
+
+
+# ------------------------------------------------------- the cached steps
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_cached_steps_match_the_reference_logits(tiny, ref, backend):
+    """The family's own step functions on a hand-built table: a fresh
+    chunk, a chunk against the resident context, then decode through the
+    pool in planes: logits against the reference's (expanded, no cache)
+    at every step, to 1e-4."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.pangu_ultra_moe import (
+        pangu_ultra_moe_decode_step, pangu_ultra_moe_init_state,
+        pangu_ultra_moe_prefill,
+    )
+
+    cfg, params = tiny
+    cfg = dataclasses.replace(cfg, attention_backend=backend)
+    bs, NB = 4, 12
+    tokens = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(10), (40,), 1, cfg.vocab_size))
+    want = np.asarray(ref.logits(params, jnp.asarray(tokens[None]), cfg))[0]
+    k, v = (jnp.zeros((cfg.n_layer, 1 + NB, bs, stored))
+            for _, _, stored in cfg.kv_planes)
+    state = pangu_ultra_moe_init_state(cfg, 2)
+    slots = jnp.ones((1,), jnp.int32)
+    tables = jnp.asarray(1 + np.arange(NB, dtype=np.int32)[None])
+    done = 0
+    with jax.default_matmul_precision("highest"):
+        for n in (16, 11):
+            chunk = np.zeros((1, 16), np.int32)
+            chunk[0, :n] = tokens[done:done + n]
+            out, k, v, state = pangu_ultra_moe_prefill(
+                params, k, v, jnp.asarray(chunk), jnp.asarray([n]), tables,
+                cfg, start=None if done == 0 else jnp.asarray([done]),
+                state=state, slots=slots)
+            done += n
+            np.testing.assert_allclose(
+                np.asarray(out)[0], want[done - 1], atol=1e-4)
+        for pos in range(done, 40):
+            out, k, v, state = pangu_ultra_moe_decode_step(
+                params, k, v, jnp.asarray(tokens[pos:pos + 1]),
+                jnp.asarray([pos]), tables, cfg, state=state, slots=slots)
+            np.testing.assert_allclose(
+                np.asarray(out)[0], want[pos], atol=1e-4)
+    # all a layer kept of a token: its row, and zeros in the padding
+    assert float(jnp.abs(k[:, 1:11, :, :16]).min()) > 0
+    assert float(jnp.abs(k[..., 16:]).max()) == 0.0
+    assert float(jnp.abs(v[..., 4:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
+    """``EngineConfig(model="pangu_ultra_moe")`` through the normal path:
+    prompts shorter and longer than a chunk, greedy tokens the reference's
+    own at every position (its logit within 1e-4 of the largest), the pool
+    reported in planes, nothing held at the end."""
+    import jax.numpy as jnp
+
+    cfg, params = tiny
+    engine = _engine(cfg, params, attention_backend=backend)
+    prompts = _prompts([5, 23, 40, 61], seed=3)
+    streams = [engine.submit(prompts[0], max_new_tokens=12, temperature=0.0)]
+    engine.step()  # a whole prompt alone: the fresh prefill program
+    streams += [engine.submit(p, max_new_tokens=12, temperature=0.0)
+                for p in prompts[1:]]
+    _drive(engine, streams)
+    for p, s in zip(prompts, streams):
+        out = list(s)
+        assert len(out) == 12 and max(out) < VOCAB_HELD
+        logits = np.asarray(ref.logits(params, jnp.asarray([p + out]), cfg))[0]
+        rows = logits[len(p) - 1: len(p) + 11]
+        deficit = rows.max(-1) - rows[np.arange(12), out]
+        assert float(deficit.max()) < 1e-4, deficit
+    kinds = {sig[0] for sig in engine.fns.signatures}
+    assert {"prefill", "prefill_chunk", "decode"} <= kinds
+    st = engine.stats()
+    assert st["kv_used_blocks"] == 0 and st["prefix_reuse"] is True
+    assert st["kv_pool"]["kind"] == "latent"
+    assert st["kv_pool"]["row_bytes"] == (16 + 4) * 4
+    described = st["executor"]
+    assert described["attention_backend"] == backend
+    assert described["kv_pool_shape"] == [3, 129, 4, 128]
+    assert described["kv_pool"]["shapes"] == [[3, 129, 4, 128]] * 2
+    assert [p["name"] for p in described["kv_pool"]["planes"]] == [
+        "latent", "rope"]
+    assert [p["width"] for p in described["kv_pool"]["planes"]] == [16, 4]
+    assert described["kv_layers"] == 3 and "kv_groups" not in described
+    engine.shutdown()
+
+
+def test_program_names_are_the_familys(jax_cpu):
+    from ray_tpu.models import pangu_ultra_moe as m
+    from ray_tpu.serve.llm import decode
+
+    assert m.pangu_ultra_moe_prefill.__name__ == "pangu_ultra_moe_prefill"
+    assert m.pangu_ultra_moe_decode_step.__name__ == \
+        "pangu_ultra_moe_decode_step"
+    fam = decode.get_family("pangu_ultra_moe")
+    assert fam.verify_step is None and fam.state_rows is False
+    assert decode.get_family("lfm2_moe").state_rows is True
+
+
+def test_counters_count_routed_and_held_pairs(tiny):
+    cfg, params = tiny
+    engine = _engine(cfg, params)
+    prompts = _prompts([9, 30], seed=4)
+    streams = [engine.submit(p, max_new_tokens=6, temperature=0.0)
+               for p in prompts]
+    _drive(engine, streams)
+    st = engine.stats()
+    layers, k = cfg.n_moe_layer, cfg.top_k
+    assert st["moe_pairs_prefill"] == (9 + 30) * layers * k
+    # the first new token comes out of prefill; each later one of a step
+    assert st["moe_pairs_decode"] == 2 * 5 * layers * k
+    assert 0 < st["moe_pairs_held_prefill"] < st["moe_pairs_prefill"]
+    assert 0 < st["moe_pairs_held_decode"] < st["moe_pairs_decode"]
+    assert len(st["moe_pairs_by_expert"]) == 2  # the held experts
+    assert sum(st["moe_pairs_by_expert"]) == \
+        st["moe_pairs_held_prefill"] + st["moe_pairs_held_decode"]
+    assert 0 < st["moe_expert_reads_decode"] <= 5 * layers * 2
+    engine.shutdown()
+
+
+def test_dispatch_spans_carry_kv_tokens_and_qk_pairs(tiny, monkeypatch):
+    """``executor.dispatch``: ``kv_tokens`` on decode (each row's context
+    in whole blocks), ``qk_pairs`` on the prefill kinds (each real query
+    token at position p attends p + 1 positions)."""
+    from ray_tpu.serve.llm import obs
+
+    cfg, params = tiny
+    engine = _engine(cfg, params)
+    seen = []
+    real = obs.phase
+
+    def spy(table, name, **attrs):
+        if name == "executor.dispatch":
+            seen.append(attrs)
+        return real(table, name, **attrs)
+
+    monkeypatch.setattr(obs, "phase", spy)
+    streams = [engine.submit(p, max_new_tokens=3, temperature=0.0)
+               for p in _prompts([3, 21], seed=5)]
+    _drive(engine, streams)
+    prefills = [a for a in seen if a["kind"].startswith("prefill")]
+    decodes = [a for a in seen if a["kind"] == "decode"]
+    assert all("qk_pairs" in a for a in prefills)
+    assert all("kv_tokens" in a and "qk_pairs" not in a for a in decodes)
+    tri = lambda n: n * (n + 1) // 2
+    # prompts of 3 and 21 in chunks of 16: every (query, key) pair once
+    assert sum(a["qk_pairs"] for a in prefills) == tri(3) + tri(21)
+    chunked = [a for a in prefills if a["kind"] == "prefill_chunk"]
+    assert chunked and chunked[-1]["qk_pairs"] == tri(21) - tri(16)
+    # the short row decodes alone first (context 4: one block of 4), the
+    # long one joins behind its second chunk (context 22 and more: 24)
+    assert decodes[0]["kv_tokens"] == 4
+    assert max(a["kv_tokens"] for a in decodes) >= 4 + 24
+    engine.shutdown()
+
+
+# ---------------------------------- prefix reuse, a pause, what is refused
+
+
+def test_a_prefix_hit_gives_the_uninterrupted_tokens(tiny):
+    """A second request over the same 24-token prefix maps its blocks (a
+    block's bytes are all a hit needs) and streams what a cold engine
+    streams."""
+    cfg, params = tiny
+    shared = _prompts([24], seed=6)[0]
+    a, b = shared + [7, 9, 11], shared + [5, 3]
+    cold = _engine(cfg, params, prefix_caching=False)
+    want = [cold.generate(p, max_new_tokens=10, temperature=0.0)
+            for p in (a, b)]
+    cold.shutdown()
+    engine = _engine(cfg, params)
+    got = [engine.generate(p, max_new_tokens=10, temperature=0.0)
+           for p in (a, b)]
+    st = engine.stats()
+    assert got == want
+    assert st["prefix_hit_tokens"] >= 24 and st["prefix_reuse"] is True
+    assert st["prefix_reuse_why_not"] is None
+    engine.shutdown()
+
+
+def test_a_preempted_and_resumed_row_streams_what_an_unpaused_one_does(tiny):
+    """Paused under an interactive flood (its blocks content-addressed,
+    its allocation released) and resumed: the same greedy tokens."""
+    cfg, params = tiny
+    pre = dict(kv_pressure=0.5, queue_wait_s=0.05, resume_pressure=0.4)
+    prompt = [5, 6, 7, 8, 9, 11]
+    plain = _engine(cfg, params, num_blocks=24)
+    want = plain.generate(prompt, max_new_tokens=16, temperature=0.0)
+    plain.shutdown()
+    engine = _engine(cfg, params, num_blocks=24, preemption=pre)
+    batch = engine.submit(prompt, max_new_tokens=16, priority="batch",
+                          temperature=0.0)
+    engine.step()
+    engine.step()
+    flood = [engine.submit([13 + i, 4, 5], max_new_tokens=8,
+                           priority="interactive", temperature=0.0)
+             for i in range(6)]
+    time.sleep(pre["queue_wait_s"] + 0.02)
+    _drive(engine, [batch] + flood)
+    st = engine.stats()
+    assert st["preemptions_total"] >= 1 and st["preempted"] == 0
+    assert list(batch) == want
+    assert st["kv_used_blocks"] == 0
+    engine.shutdown()
+
+
+@pytest.mark.parametrize("option,match", [
+    ({"speculative_k": 2}, "speculative_k: the family has no verify step"),
+    ({"host_cache_bytes": 1 << 20}, "host_cache_bytes: the host tier's "
+                                    "record"),
+    ({"quantization": "int8"}, "quantization: a quantized pool's scale "
+                               "planes"),
+    ({"tp": 2}, "tp/fsdp/mesh: ShardedExecutor splits the pool along its "
+                "head axis"),
+    ({"fsdp": 2}, "one shared row has none"),
+])
+def test_what_a_latent_pool_cannot_carry_is_refused(tiny, option, match):
+    cfg, params = tiny
+    with pytest.raises(ValueError, match=match) as err:
+        _engine(cfg, params, **option)
+    assert "in planes" in str(err.value)
+    assert "pangu_ultra_moe" in str(err.value)
+
+
+def test_the_handoff_is_refused_by_the_records_reason(tiny):
+    cfg, params = tiny
+    engine = _engine(cfg, params)
+    prompt = _prompts([20], seed=7)[0]
+    with pytest.raises(ValueError, match="cannot say planes"):
+        engine.export_prefix(prompt)
+    with pytest.raises(ValueError, match="cannot say planes"):
+        engine.adopt_prefix(prompt, [])
+    engine.shutdown()
+
+
+def test_counters_alone_refuse_nothing_of_their_own(jax_cpu):
+    """A family whose ``state`` holds only counters is not refused what a
+    per-sequence state is: only what the planes cannot carry."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    ask = EngineConfig(model="pangu_ultra_moe", preemption={})
+    LLMEngine._refuse_for_state(ask, None, False, False, False, True)
+    with pytest.raises(ValueError, match="state slot"):
+        LLMEngine._refuse_for_state(ask, None, True, False, False, True)
+    with pytest.raises(ValueError, match="in planes"):
+        LLMEngine._refuse_for_state(
+            EngineConfig(model="pangu_ultra_moe", speculative_k=1), None,
+            False, False, False, True)
+
+
+def test_widened_pipeline_matches_solo_runs(tiny):
+    """ISSUE 33's schedule (conftest ``run_widened_schedule``) over the
+    pool in planes: joins, finishes and a cancel in flight, the streams
+    the bytes of solo runs."""
+    from conftest import run_widened_schedule
+
+    cfg, params = tiny
+    run_widened_schedule(lambda **kw: _engine(cfg, params, **kw),
+                         cfg.vocab_size)

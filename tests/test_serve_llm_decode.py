@@ -342,6 +342,52 @@ def test_id_gather_is_compiled_with_the_step_shapes(jax_cpu):
     assert executor._feed_ids._cache_size() == size
 
 
+def test_a_decode_program_has_one_form_of_ids(jax_cpu):
+    """A decode program is called with its ids ON THE DEVICE, always: the
+    array of the step in flight, the gather from it (also where the host
+    holds every id: a decode behind a chunk that is not its row's last)
+    or, with nothing in flight, the host's array put there first. jit's
+    fast path is keyed by its arguments' kinds, and a call that leaves it
+    rebuilds the executable's wrapper (0.9 s on the chip, every stream
+    stalled: PR 39): so one entry a decode shape, whatever the traffic."""
+    import jax
+
+    mc = dataclasses.replace(_model_config(), max_seq_len=96)  # own jits
+    eng = _engine(mc, max_batch_size=4, prefill_chunk_tokens=8,
+                  batch_buckets=(1, 4), length_buckets=(96,))
+    kinds, decode = [], eng.executor.fns.decode
+
+    def seen(params, k, v, tokens, *a, **kw):
+        kinds.append(type(tokens))
+        return decode(params, k, v, tokens, *a, **kw)
+
+    eng.executor.fns.decode = seen
+    first = eng.submit([1, 2, 3], max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    # a prompt of three chunks joins: decode steps run between its chunks
+    late = eng.submit(list(range(1, 21)), max_new_tokens=4)
+    _drain(eng, [first, late])
+    st = eng.stats()
+    assert len(kinds) == st["decode_steps"] == st["decode_steps_steady"] > 0
+    # every steady step over another batch gathered its ids on the device
+    assert st["host"]["spans"]["executor.feed"][0] == \
+        st["decode_steps_remapped"] > 0
+    shapes = {sig for sig in eng.executor.fns.signatures
+              if sig[0] == "decode"}
+    assert eng.executor.fns._decode._cache_size() == len(shapes)
+    # nothing in flight, the host holds the ids: a constrained row
+    # collapses the lag before every launch
+    bound = eng.submit([1, 2, 3], max_new_tokens=6,
+                       structured={"type": "regex", "pattern": "[a-z]{4}"})
+    _drain(eng, [bound])
+    st = eng.stats()
+    assert len(kinds) == st["decode_steps"] > st["decode_steps_steady"]
+    assert not any(issubclass(k, np.ndarray) for k in kinds)
+    assert all(issubclass(k, jax.Array) for k in kinds)
+    eng.shutdown()
+
+
 def test_eos_races_the_lag_across_joins_and_finishes(jax_cpu):
     """An EOS arrives one step late, as ever (one wasted row), also where
     it is a row's FIRST token, sampled by a prefill whose sync waits

@@ -194,7 +194,10 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     assert [a["lag"] for a in attrs["engine.sync"]][-1] == 0
     assert {a["lag"] for a in attrs["engine.sync"]} == {0, 1}
     assert all(set(a) == {"lag", "seq"} for a in attrs["engine.sync"])
-    assert all(set(a) == ({"kind", "seq"} if a["kind"] == "prefill"
+    # (PR 39: a prefill kind carries ``qk_pairs``, the positions its real
+    # query tokens attend)
+    assert all(set(a) == ({"kind", "seq", "qk_pairs"}
+                          if a["kind"] == "prefill"
                           else {"kind", "seq", "kv_tokens"})
                for a in attrs["executor.dispatch"])
     # launches are numbered as they are made, every one is synced once,

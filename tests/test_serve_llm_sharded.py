@@ -106,6 +106,10 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               "kv_layers": 2,
                               # lane-dense: 2 heads of 16 are no tile
                               "kv_pool_shape": [2, 64, 8, 32],
+                              # K and V by head: 2 x 2 heads x 16 x 4 B
+                              "kv_pool": {"kind": "heads", "row_bytes": 256,
+                                          "stored_row_bytes": 256,
+                                          "block_bytes": 4096},
                               "state": None,
                               "prefix_reuse": True,
                               "speculative": None}
@@ -130,6 +134,10 @@ def test_single_device_default_unchanged(jax_cpu):
                                        "attention_backend": "xla",
                                        "kv_layers": 2,
                                        "kv_pool_shape": [2, 64, 8, 32],
+                                       "kv_pool": {"kind": "heads",
+                                                   "row_bytes": 256,
+                                                   "stored_row_bytes": 256,
+                                                   "block_bytes": 4096},
                                        "state": None,
                                        "prefix_reuse": True,
                                        "speculative": None}
