@@ -403,7 +403,10 @@ class _Request:
         self.first_token_clock: float | None = None
         self.last_token_clock: float | None = None
         self.finish_reason: str | None = None
-        self.out: queue.Queue = queue.Queue()
+        # tokens, then _DONE (an exception first where the stream failed):
+        # the interpreter's C queue, so a put is one call and a blocked
+        # reader wakes having taken the interpreter once
+        self.out: queue.SimpleQueue = queue.SimpleQueue()
         self.generated: list[int] = []
         # sampling is keyed by (seed, absolute position) on device — no
         # RNG state to carry or fast-forward; start_index only offsets
@@ -442,6 +445,21 @@ class _Request:
         prompt + generated-so-far when resuming from preemption."""
         return (self.pending_resume if self.pending_resume is not None
                 else self.prompt)
+
+
+class _StepTokens:
+    """What the rows of one reconciled step share while their tokens go to
+    the streams: the ONE reading of both clocks (the rows came off the
+    device in one sync) and the values the TTFT and TPOT histograms get
+    for them, together, when the pass is over."""
+
+    __slots__ = ("now", "wall", "ttfts", "gaps")
+
+    def __init__(self):
+        self.now = obs.clock()
+        self.wall = obs.wall()
+        self.ttfts: list[float] = []
+        self.gaps: list[float] = []
 
 
 @dataclass
@@ -780,6 +798,8 @@ class LLMEngine:
         self._decode_steps_remapped = 0
         self._prefill_steps = 0  # prefill dispatches ...
         self._prefill_syncs_deferred = 0  # ... synced behind a later launch
+        # tokens the ``engine.emit`` passes put on streams
+        self._emit_rows = 0
         # ---- autoscaling signal windows (ISSUE 10) ----
         # Bounded sample/event rings feeding autoscaling_snapshot(): the
         # controller's policy wants recent-tail saturation (queue-wait
@@ -1504,6 +1524,7 @@ class LLMEngine:
                     "stage_transfers": self.executor.stage_transfers,
                     "stage_bytes": self.executor.stage_bytes,
                     "stage_masks": self.executor.stage_masks,
+                    "emit_rows": self._emit_rows,
                 },
                 "spec_steps": self._spec_steps,
                 "spec_drafted_tokens": self._spec_drafted_total,
@@ -2599,16 +2620,15 @@ class LLMEngine:
         return emitted
 
     def _emit_decoded_locked(self, rec: _InFlight, toks) -> int:
-        emitted = 0
-        for i, r in enumerate(rec.batch):
+        book = _StepTokens()
+        for r, tok in zip(rec.batch, toks.tolist()):
             r.inflight -= 1
             if r.done:
                 # the <=1 wasted speculative row per finished request
                 self._release_blocks_locked(r)
-                continue
-            self._emit_token_locked(r, int(toks[i]))
-            emitted += 1
-        return emitted
+            else:
+                self._emit_token_locked(r, tok, book)
+        return self._book_tokens_locked(book)
 
     def _emit_prefilled_locked(self, rec: _InFlight, toks,
                                dt: float) -> None:
@@ -2617,11 +2637,12 @@ class LLMEngine:
         value feeds the latency histogram, the flight record and the
         per-request chunk timeline entries, so every record agrees (one
         clock)."""
-        for i, (r, (n, chain, done, final)) in enumerate(
-                zip(rec.batch, rec.rows)):
+        book = _StepTokens()
+        dur_ms = round(dt * 1000.0, 3)
+        for r, tok, (n, chain, done, final) in zip(
+                rec.batch, toks.tolist(), rec.rows):
             r.inflight -= 1
-            self._tl(r, rec.kind, ts=rec.t0_wall,
-                     dur_ms=round(dt * 1000.0, 3), tokens=n,
+            self._tl(r, rec.kind, ts=rec.t0_wall, dur_ms=dur_ms, tokens=n,
                      prefill_done=done)
             if self.cfg.prefix_caching:
                 self.cache.register_prefix(r.id, chain, done)
@@ -2634,7 +2655,8 @@ class LLMEngine:
                 # token (or, resuming, the last already-emitted token:
                 # the keyed sampler reproduces the next token
                 # byte-identically)
-                self._emit_token_locked(r, int(toks[i]))
+                self._emit_token_locked(r, tok, book)
+        self._book_tokens_locked(book)
 
     def _propose_drafts_locked(self, batch: list) -> list[list[int]] | None:
         """Ask the drafter for up to ``speculative_k`` candidate tokens
@@ -2760,23 +2782,25 @@ class LLMEngine:
             drafted = sum(draft_lens)
             accepted = 0
             step_tokens = 0
-            for i, (r, dl) in enumerate(zip(batch, draft_lens)):
+            book = _StepTokens()
+            for r, dl, row in zip(batch, draft_lens, packed.tolist()):
                 # device contract: 1 <= committed <= draft_len + 1; clamp
                 # anyway so a bad verdict can never overrun the budget
-                committed = max(1, min(int(packed[i, 0]), dl + 1))
+                committed = max(1, min(row[0], dl + 1))
                 accepted += committed - 1
                 if r.trace_ctx:
                     # traced rows carry the speculation outcome per window
                     # — rendered as an engine.verify span at finish (host
                     # list append only; untraced rows skip even that)
                     self._tl(r, "verify_window", ts=t0_wall,
-                             dur_ms=round((obs.clock() - t0) * 1000.0, 3),
+                             dur_ms=round((book.now - t0) * 1000.0, 3),
                              drafted=dl, accepted=committed - 1, window=W)
-                for j in range(committed):
-                    self._emit_token_locked(r, int(packed[i, 1 + j]))
+                for tok in row[1:1 + committed]:
+                    self._emit_token_locked(r, tok, book)
                     step_tokens += 1
                     if r.done:
                         break
+            self._book_tokens_locked(book)
             self._running = [r for r in self._running if not r.done]
         self._spec_steps += 1
         self._spec_drafted_total += drafted
@@ -2993,9 +3017,16 @@ class LLMEngine:
         slot[2] ^= 1
         return slot[slot[2]]
 
-    def _emit_token_locked(self, r: _Request, tok: int) -> None:
-        is_eos = self.cfg.eos_id is not None and tok == self.cfg.eos_id
-        if r.fsm is not None and not is_eos:
+    def _emit_token_locked(self, r: _Request, tok: int,
+                           book: _StepTokens) -> None:
+        """Put one synced id on its request's stream, stamped with the
+        step's clocks (``book``); the histograms and counters get the
+        step's rows together (``_book_tokens_locked``). Only a request
+        with a grammar pays for its cursor, only one with stop sequences
+        for their match."""
+        is_eos = tok == self.cfg.eos_id
+        fsm = r.fsm
+        if fsm is not None and not is_eos:
             # advance the grammar cursor on the already-synced id BEFORE
             # emitting: a rejection (only reachable if on-device masking
             # degraded) terminates the stream WITHOUT the bad token, so
@@ -3003,27 +3034,41 @@ class LLMEngine:
             if not self._advance_fsm_locked(r, tok):
                 self._complete_locked(r)
                 return
-        r.generated.append(tok)
-        now = obs.clock()
+        generated = r.generated
+        generated.append(tok)
+        sampling = r.sampling
+        n = len(generated)
+        now = book.now
         if r.first_token_clock is None:
             r.first_token_clock = now
-            self._m_ttft.observe(now - r.submitted_clock)
-            self._tl(r, "first_token",
-                     index=r.sampling.start_index + len(r.generated) - 1)
+            book.ttfts.append(now - r.submitted_clock)
+            event = "first_token"
         else:
-            self._m_tpot.observe(now - r.last_token_clock)
-            self._tl(r, "token",
-                     index=r.sampling.start_index + len(r.generated) - 1)
+            book.gaps.append(now - r.last_token_clock)
+            event = "token"
+        r.timeline.append({"event": event, "ts": book.wall,
+                           "index": sampling.start_index + n - 1})
         r.last_token_clock = now
         r.out.put(tok)
-        self._m_tokens.inc()
         if (
-            len(r.generated) >= r.sampling.max_new_tokens
+            n >= sampling.max_new_tokens
             or is_eos
-            or (r.fsm is not None and r.fsm.must_stop)
-            or self._hits_stop_locked(r)
+            or (fsm is not None and fsm.must_stop)
+            or (sampling.stop and self._hits_stop_locked(r))
         ):
             self._complete_locked(r)
+
+    def _book_tokens_locked(self, book: _StepTokens) -> int:
+        """One reconciled step's tokens into the TTFT and TPOT histograms,
+        the tokens counter and ``stats()["host"]["emit_rows"]``: every
+        value observed, every token counted, once a step. Returns the
+        tokens the step put on streams."""
+        n = len(book.ttfts) + len(book.gaps)
+        self._m_ttft.observe_many(book.ttfts)
+        self._m_tpot.observe_many(book.gaps)
+        self._m_tokens.inc(n)
+        self._emit_rows += n
+        return n
 
     def _advance_fsm_locked(self, r: _Request, tok: int) -> bool:
         """Advance one request's grammar cursor on an emitted token id

@@ -19,7 +19,8 @@ from __future__ import annotations
 
 import logging
 import threading
-from collections import deque
+from bisect import bisect_left
+from collections import Counter as _Tally, deque
 from typing import Sequence
 
 logger = logging.getLogger("ray_tpu.metrics")
@@ -101,6 +102,21 @@ class Histogram(_Metric):
     def observe(self, value: float, tags: dict | None = None):
         h = self._h.labels(*self._labels(tags)) if self.tag_keys else self._h
         h.observe(value)
+
+    def observe_many(self, values: Sequence[float],
+                     tags: dict | None = None):
+        """``observe`` every one of ``values`` (a step's rows): each lands
+        in the bucket and the sum ``observe`` would put it in, but the sum
+        and a bucket are touched once for all of them."""
+        if not values:
+            return
+        h = self._h.labels(*self._labels(tags)) if self.tag_keys else self._h
+        bounds = h._upper_bounds
+        h._sum.inc(sum(values))
+        # a value's bucket: the first bound it does not pass (the last is
+        # +Inf)
+        for i, n in _Tally(bisect_left(bounds, v) for v in values).items():
+            h._buckets[i].inc(n)
 
 
 # Idempotent named-metric factories: prometheus_client raises on duplicate

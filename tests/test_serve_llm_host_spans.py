@@ -72,7 +72,7 @@ def test_phase_names_and_shape_are_frozen(jax_cpu):
     # everything new lives under ONE key, and mirrors the phases' keys
     host = st["host"]
     assert set(host) == {"spans", "phase_cpu", "gc", "stage_transfers",
-                         "stage_bytes", "stage_masks"}
+                         "stage_bytes", "stage_masks", "emit_rows"}
     assert {k: set(v) for k, v in host["phase_cpu"].items()} == {
         k: set(v) for k, v in phases.items()}
     assert set(host["spans"]) <= {"engine.lock", "executor.feed"}

@@ -401,27 +401,32 @@ def obs_cluster(tmp_path_factory):
     from ray_tpu import serve
     from ray_tpu.serve.llm import EngineConfig, build_llm_app
 
-    ray_tpu.init(num_cpus=8)
-    with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options=None)
-        handle = serve.run(
-            build_llm_app(
-                EngineConfig(model="llama", model_config=_model_config(),
-                             seed=0),
-                num_replicas=2,
-            ),
-            name="llm-obs", route_prefix="/llmobs", timeout_s=180,
-        )
-    yield serve, handle, flight_dir
-    serve.shutdown()
-    ray_tpu.shutdown()
-    chaos.clear()
-    for var, prev in ((chaos.ENV_VAR, prev_plan),
-                      ("RAY_TPU_FLIGHT_DIR", prev_flight)):
-        if prev is None:
-            os.environ.pop(var, None)
-        else:
-            os.environ[var] = prev
+    try:
+        ray_tpu.init(num_cpus=8)
+        with shutdown_if_setup_fails():
+            serve.start(http_options={"port": HTTP_PORT}, grpc_options=None)
+            handle = serve.run(
+                build_llm_app(
+                    EngineConfig(model="llama",
+                                 model_config=_model_config(), seed=0),
+                    num_replicas=2,
+                ),
+                name="llm-obs", route_prefix="/llmobs", timeout_s=180,
+            )
+        yield serve, handle, flight_dir
+        serve.shutdown()
+        ray_tpu.shutdown()
+    finally:
+        # also where the set-up failed (a taken port): a plan left in the
+        # environment kills the 71st decode step of every later test of
+        # this worker
+        chaos.clear()
+        for var, prev in ((chaos.ENV_VAR, prev_plan),
+                          ("RAY_TPU_FLIGHT_DIR", prev_flight)):
+            if prev is None:
+                os.environ.pop(var, None)
+            else:
+                os.environ[var] = prev
 
 
 def _http_generate(payload: dict, *, traced: bool):

@@ -375,24 +375,28 @@ def trace_cluster():
 
     mc = dataclasses.replace(
         LlamaConfig.tiny(), dtype=jnp.float32, attention="xla")
-    ray_tpu.init(num_cpus=8)
-    with shutdown_if_setup_fails():
-        serve.start(http_options={"port": 18177}, grpc_options=None)
-        handle = serve.run(
-            build_llm_app(
-                EngineConfig(model="llama", model_config=mc, seed=0),
-                num_replicas=2,
-            ),
-            name="llm-trace", route_prefix="/llmtrace", timeout_s=180,
-        )
-    yield serve, handle, mc
-    serve.shutdown()
-    ray_tpu.shutdown()
-    chaos.clear()
-    if prev is None:
-        os.environ.pop(chaos.ENV_VAR, None)
-    else:
-        os.environ[chaos.ENV_VAR] = prev
+    try:
+        ray_tpu.init(num_cpus=8)
+        with shutdown_if_setup_fails():
+            serve.start(http_options={"port": 18177}, grpc_options=None)
+            handle = serve.run(
+                build_llm_app(
+                    EngineConfig(model="llama", model_config=mc, seed=0),
+                    num_replicas=2,
+                ),
+                name="llm-trace", route_prefix="/llmtrace", timeout_s=180,
+            )
+        yield serve, handle, mc
+        serve.shutdown()
+        ray_tpu.shutdown()
+    finally:
+        # also where the set-up failed: a plan left in the environment
+        # kills the 71st decode step of every later test of this worker
+        chaos.clear()
+        if prev is None:
+            os.environ.pop(chaos.ENV_VAR, None)
+        else:
+            os.environ[chaos.ENV_VAR] = prev
 
 
 @pytest.mark.chaos
