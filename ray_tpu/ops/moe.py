@@ -1,9 +1,11 @@
 """Mixture-of-Experts layers: two of them, for two paths.
 
 **The served layer is the dropless one** (``moe_route`` + ``moe_dropless``,
-at the end of this file): a sigmoid router with a selection bias, top-k,
-the (token, expert) pairs sorted by expert, one grouped matrix product over
-the experts (``jax.lax.ragged_dot``), SwiGLU experts, weighted combine.
+at the end of this file): a router (sigmoid scores with a selection bias,
+or a softmax over the chosen logits), top-k, the (token, expert) pairs
+sorted by expert, one grouped matrix product over the experts
+(``jax.lax.ragged_dot``), gated experts (SwiGLU, or ReLU-gated), weighted
+combine.
 Every routed pair is computed whatever the routing, so a token's output
 depends on its own row only (batched == solo) and the layer can be checked
 against a plain reference. ``models/lfm2_moe.py`` serves through it.
@@ -131,29 +133,46 @@ def moe_reference_dense(params: dict, x: jax.Array, cfg: MoEConfig) -> jax.Array
 
 
 # ----------------------------------------------------------------------------
-# The served layer: dropless, sigmoid-routed, SwiGLU experts.
+# The served layer: dropless; sigmoid- or softmax-routed; gated experts.
 # ----------------------------------------------------------------------------
 
 # what the published LFM2-MoE code adds to the sum of the chosen scores
 # before dividing by it (``norm_topk_prob``)
 ROUTE_NORM_EPS = 1e-6
+ROUTE_SCORES = ("sigmoid", "softmax_topk")
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
-              top_k: int, *, norm_topk: bool = True, scale: float = 1.0):
+              top_k: int, *, norm_topk: bool = True, scale: float = 1.0,
+              score: str = "sigmoid"):
     """x [T, D] -> (weights [T, k] f32, experts [T, k] int32).
 
-    ``s = sigmoid(x @ router)``; the ``top_k`` experts are chosen by
-    ``s + bias`` (the stored selection bias), but weighted by the UNBIASED
-    ``s``, divided by their sum when ``norm_topk``, times ``scale``. All of
+    ``score="sigmoid"``: ``s = sigmoid(x @ router)``; the ``top_k`` experts
+    are chosen by ``s + bias`` (the stored selection bias), but weighted by
+    the UNBIASED ``s``, divided by their sum when ``norm_topk``, times
+    ``scale``. ``score="softmax_topk"`` (no bias): the ``top_k`` largest
+    LOGITS, weighted by a softmax over those k alone, which is the softmax
+    over all experts renormalised over the chosen (``norm_topk``; without
+    it, the softmax over all at the chosen), times ``scale``. All of
     it in float32 at the highest matmul precision, whatever ``x`` is: a
     near-tie between the k-th and the (k+1)-th score is the one place where
     rounding changes WHICH weights a token meets."""
+    if score not in ROUTE_SCORES:
+        raise ValueError(f"score must be one of {ROUTE_SCORES}, got {score!r}")
     with jax.named_scope("moe_route"):
         logits = jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
+        if score == "softmax_topk":
+            if bias is not None:
+                raise ValueError("a softmax_topk router has no selection bias")
+            chosen, experts = jax.lax.top_k(logits, top_k)
+            over = chosen if norm_topk else logits
+            weights = jnp.exp(chosen - jax.nn.logsumexp(
+                over, axis=-1, keepdims=True))
+            return weights * scale, experts.astype(jnp.int32)
         scores = jax.nn.sigmoid(logits)
         chosen_by = scores if bias is None else scores + bias.astype(
             jnp.float32)
@@ -168,13 +187,14 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
 def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  w_in: jax.Array, w_out: jax.Array, *, dtype,
                  valid: jax.Array | None = None,
-                 held: tuple[int, int] | None = None):
+                 held: tuple[int, int] | None = None, act: str = "silu"):
     """The expert layer proper: x [T, D] -> (y [T, D], pairs_by_expert [E]
     int32).
 
     ``w_in`` [E, D, 2F] packs each expert's gate and up projections (gate
-    first), ``w_out`` [E, F, D]. The T * k (token, expert) pairs are sorted
-    by expert and met by ONE grouped product each way
+    first), ``w_out`` [E, F, D]: an expert is ``(act(x gate) * (x up))
+    down``, ``act`` ``silu`` (SwiGLU) or ``relu``. The T * k (token,
+    expert) pairs are sorted by expert and met by ONE grouped product each way
     (``jax.lax.ragged_dot`` with the per-expert counts as group sizes:
     products in ``dtype``, float32 accumulation), so any routing is
     computed whole, all tokens on one expert included: no capacity, no
@@ -215,9 +235,9 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
             preferred_element_type=jnp.float32,
         )
         gate, up = jnp.split(h, 2, axis=-1)
-        act = (jax.nn.silu(gate) * up).astype(dtype)
+        gated = (EXPERT_ACTS[act](gate) * up).astype(dtype)
         ys = jax.lax.ragged_dot(
-            act, w_out.astype(dtype), sizes,
+            gated, w_out.astype(dtype), sizes,
             preferred_element_type=jnp.float32,
         )
         # back to (token, choice) order, then the weighted sum over choices

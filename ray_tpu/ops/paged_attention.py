@@ -25,8 +25,8 @@ so that its pages are whole tiles (``pool_shape``): ``[n_layer, num_blocks,
 block_size, n_kv_head, hd]`` where the ``[n_kv_head, hd]`` one device holds
 is whole (8, 128) tiles (8 heads of 128), and lane-dense, a token's heads
 ONE row, ``[n_layer, num_blocks, block_size, n_kv_head * hd]`` where it is
-not (heads of 64, 12 heads, a ``tp`` shard's 2). Without ``layer=`` the
-pool is one layer's ``[num_blocks, block_size, ...]``, as everywhere below.
+not (heads of 64, 12 heads, 4 of 128: ``[.., 512]``, a ``tp`` shard's 2).
+Without ``layer=`` the pool is one layer's ``[num_blocks, block_size, ...]``.
 
 Design (same playbook as ``ops/attention.py``'s flash kernels):
 
@@ -78,9 +78,9 @@ Design (same playbook as ``ops/attention.py``'s flash kernels):
   running-softmax update is made once a block for all heads together, on
   ``[H_kv, R, P * block_size]`` scores.
 - GQA COMPACTION: queries reshape ``[B, S, H_q, hd] → [B, H_kv, S*G, hd]``
-  (``G = H_q // H_kv``); each head step computes the whole query group
-  against the SHARED KV tile with one dot, so GQA is a free extra row
-  dimension instead of a ``rep``× KV copy.
+  (``G = H_q // H_kv``; met so far: 1, 4, 6, 7, 8, 128); a head step takes
+  the whole query group against the SHARED KV tile with one dot, so GQA is a
+  free extra row dimension (7 rows pad to 8) instead of a ``rep``× KV copy.
 - FLASH RUNNING SOFTMAX: per-(kv-head, row) running max / sum /
   accumulator live in VMEM scratch across the loop over blocks (the max
   and sum as a column a head, ``[H_kv, R, 1]``); the
@@ -179,9 +179,9 @@ def pool_shape(n_layer: int, num_blocks: int, block_size: int, Hkv: int,
     """The shape a K or V pool is STORED in (serve/llm/kv_cache.py
     allocates it, ``ShardedExecutor`` for its ``tp``). Where the ``[Hkv //
     tp, hd]`` one device holds is whole tiles: ``[.., Hkv, hd]``. Where it
-    is not (heads of 64, 12 heads, a ``tp`` shard's 2) the runtime would
-    rest such a minor pair in another order than the one written and relay
-    K and V around every kernel call (PERF.md, PR 27 and 29), so a token's
+    is not (heads of 64, 12 heads, 4 heads of 128, a ``tp`` shard's 2) the
+    runtime would rest such a minor pair in another order than written and
+    relay K and V around every kernel call (PERF.md, PR 27, 29), so a token's
     heads are ONE lane-dense row: ``[n_layer, num_blocks, block_size, Hkv *
     hd]``, whole tiles again and nothing padded (a ``tp`` mesh splits the
     row into contiguous heads a device)."""

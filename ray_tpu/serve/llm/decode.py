@@ -108,12 +108,24 @@ def _pangu_ultra_moe() -> Family:
                   counters=m.pangu_ultra_moe_counters, state_rows=False)
 
 
+def _smallthinker() -> Family:
+    from ray_tpu.models import smallthinker as m
+
+    # no verify step: a rejected window may reach behind freed blocks
+    return Family(m.smallthinker_init, m.smallthinker_prefill,
+                  m.smallthinker_decode_step, None,
+                  m.smallthinker_param_axes, m.smallthinker_quant_axes,
+                  m.SmallThinkerConfig.tiny,
+                  init_state=m.smallthinker_init_state,
+                  counters=m.smallthinker_counters, state_rows=False)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
     "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
     "laguna": _laguna, "evabyte": _evabyte,
-    "pangu_ultra_moe": _pangu_ultra_moe,
+    "pangu_ultra_moe": _pangu_ultra_moe, "smallthinker": _smallthinker,
 }
 
 

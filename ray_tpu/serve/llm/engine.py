@@ -796,6 +796,10 @@ class LLMEngine:
         self._decode_steps_steady = 0
         # ... of those, over another batch than that step's (ids gathered)
         self._decode_steps_remapped = 0
+        # the rows of the decode dispatches of a family with sliding
+        # layers, and those whose context exceeded the window (0 without)
+        self._decode_rows = 0
+        self._decode_rows_past_window = 0
         self._prefill_steps = 0  # prefill dispatches ...
         self._prefill_syncs_deferred = 0  # ... synced behind a later launch
         # tokens the ``engine.emit`` passes put on streams
@@ -1498,6 +1502,10 @@ class LLMEngine:
                 "decode_steps": self._decode_steps,
                 "decode_steps_steady": self._decode_steps_steady,
                 "decode_steps_remapped": self._decode_steps_remapped,
+                # where sliding layers are served: the decode dispatches'
+                # rows, and those past the window (``rows_past_window``)
+                "decode_rows": self._decode_rows,
+                "decode_rows_past_window": self._decode_rows_past_window,
                 "prefill_steps": self._prefill_steps,
                 "prefill_syncs_deferred": self._prefill_syncs_deferred,
                 "phases": {
@@ -2441,6 +2449,7 @@ class LLMEngine:
             pairs: list[tuple[int, int]] = []
             kv_tokens = 0
             kv_tokens_window = 0
+            rows_past_window = 0
             kv_chunks = 0
             for r in batch:
                 # effective length includes the in-flight token: its K/V
@@ -2458,6 +2467,8 @@ class LLMEngine:
                 if self._kv_window:
                     # and what a sliding layer's call attends of it
                     kv_tokens_window += min(eff, self._kv_window)
+                    # the rows whose sliding layers really slide
+                    rows_past_window += eff > self._kv_window
                 if self._kv_ring:
                     # a composed table's: the exact rows of the window the
                     # row's position lies in, and a summary a chunk of
@@ -2532,6 +2543,10 @@ class LLMEngine:
         kv = {"kv_tokens": kv_tokens}
         if self._kv_window or self._kv_ring:
             kv["kv_tokens_window"] = kv_tokens_window
+        if self._kv_window:
+            kv["rows_past_window"] = rows_past_window
+            self._decode_rows += len(batch)
+            self._decode_rows_past_window += rows_past_window
         if self._kv_ring:
             kv["kv_chunks"] = kv_chunks
         span = {"kind": "decode", "seq": self._launched + 1, **kv}

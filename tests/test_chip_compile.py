@@ -57,12 +57,15 @@ def _struct(shape, dtype, sharding):
 
 
 # GPT-2 125M's widths in both pool dtypes, the GQA width of the llama cells,
-# and GQA at heads of 64 (the lfm2_moe cell's attention layers)
+# GQA at heads of 64 (the lfm2_moe cell's attention layers), and an ODD
+# group of 7 over 4 heads of 128 (the smallthinker cell's: a page [4, 128]
+# is no whole tile, so its pool is lane-dense, 16 x 512)
 WIDTHS = {
     "gpt2-f32": (12, 12, 64, "float32"),
     "gpt2-bf16": (12, 12, 64, "bfloat16"),
     "gqa-bf16": (32, 8, 128, "bfloat16"),
     "gqa64-bf16": (32, 8, 64, "bfloat16"),
+    "gqa7-bf16": (28, 4, 128, "bfloat16"),
 }
 
 
@@ -100,7 +103,7 @@ def test_paged_attention_compiles(one_chip, kind, shape, width, quant):
     # one layer of the pool as the cache manager stores it: by heads at the
     # GQA width of 8 x 128, lane-dense at heads of 64 (16 x 768, 16 x 512)
     stored = pool_shape(1, num_blocks, bs, hkv, hd)[1:]
-    assert len(stored) == (4 if hd == 128 else 3)
+    assert len(stored) == (4 if hd == 128 and hkv % 8 == 0 else 3)
     if quant is None:
         pool = S_(stored, dtype)
     else:
@@ -121,6 +124,39 @@ def test_paged_attention_compiles(one_chip, kind, shape, width, quant):
                 S_((B, S), jnp.int32))
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_paged_attention_compiles_at_an_odd_group(one_chip, kind, window):
+    """The smallthinker cell's own calls: 28 query heads on 4 K/V heads of
+    128 (a group of SEVEN: a decode tile is [4, 7, 128], 7 sublanes of 8; a
+    prefill tile 128 x 7 = 896 rows) over the lane-dense pool ``[2, 65537,
+    16, 512]`` read at a layer, plain and at a window of 4,096 (256 pages a
+    row), a 48-row decode step and a 2,048-row chunk against the
+    1,024-entry table of the 16,384-token bucket."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import (
+        paged_prefill_attention_pallas, pool_shape,
+    )
+
+    S_ = functools.partial(_struct, sharding=one_chip)
+    pool = S_(pool_shape(2, 65537, 16, 4, 128), jnp.bfloat16)
+    assert pool.shape == (2, 65537, 16, 512)
+    B, S = (48, 1) if kind == "decode" else (1, 2048)
+    fn = functools.partial(paged_prefill_attention_pallas, window=window,
+                           layer=1, interpret=False)
+    compiled = jax.jit(fn).lower(
+        S_((B, S, 28, 128), jnp.bfloat16), pool, pool,
+        S_((B, 1024), jnp.int32), S_((B, S), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    name = "paged_attention" if window is None else "paged_attention_window"
+    assert re.search(rf"%{name}[.\d]* = ", text)
+    # the pool is read where it stands: no copy of it, no relayout
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
 @pytest.mark.parametrize("heads", [2, 3])
@@ -819,6 +855,91 @@ def test_pangu_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_smallthinker_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The smallthinker cell's step programs as the executor compiles them,
+    at the cell's own shapes: the 48-row decode step and the 4 x 2,048
+    chunk over tables by group ``[4, B, 1024]`` (the 16,384-token bucket),
+    the fresh prefill over ``[4, 4, 128]``. The pool is lane-dense ``[2,
+    65537, 16, 512]`` (4.29 GB, K and V together): both arrays are in the
+    program's ``input_output_alias`` and nothing pool-sized is among its
+    temporaries; the two full layers call ``paged_attention`` and the six
+    sliding ones ``paged_attention_window`` at a group of SEVEN; every
+    layer has its two grouped products over all 64 experts' matrices as
+    stored; and the leaves reach their operations under the names the
+    benchmark's readers look for."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/smallthinker-21b-a3b-8l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/doc-chat-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("smallthinker")
+    init = common.load_named("reference", "smallthinker").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    B = engine["max_batch_size"] if kind == "decode" else 4
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
+    pool = _struct(pool_shape(cfg.n_kv_layer, engine["num_blocks"], 16,
+                              cfg.n_kv_head, cfg.head_dim),
+                   cfg.dtype, one_chip)
+    assert pool.shape == (2, 65537, 16, 512)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    groups = len(cfg.kv_table_groups)
+    ctx = engine["length_buckets"][-1]
+    assert (groups, ctx, B) in ((4, 16384, 48), (4, 16384, 4))
+    fns = decode.DecodeFns("smallthinker", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        lowered = fns._decode.lower(
+            params, pool, pool, i32((B,)), i32((B,)),
+            i32((groups, B, ctx // 16)), sample=None, **more)
+    else:
+        nb = ctx // 16 if kind == "prefill_chunk" else 128
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, pool, pool, i32((B, 2048)), i32((B,)),
+            i32((groups, B, nb)), sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * math.prod(pool.shape) * 2
+    assert abs(pool_bytes - 4.295e9) < 0.001e9
+    # 7.93 GB of weights and the pool
+    assert 12.1e9 < mem.argument_size_in_bytes < 12.4e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < (0.2e9 if kind == "decode" else 3.0e9), \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    full = len(re.findall(r"%paged_attention[.\d]* = ", entry))
+    sliding = len(re.findall(r"%paged_attention_window[.\d]* = ", entry))
+    assert (full, sliding) == (2, 6), (full, sliding)
+    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
+    assert len(calls) == 16  # two grouped products in each of 8 layers
+    if kind == "decode":
+        for needle in ("moe_route_w", "moe_gmm_w_in"):
+            assert re.search(
+                rf"\(.*%params__layers___\d___{needle}__", entry), needle
+    assert "cross_program_prefetch_index" not in text
+
+
 def _body(text):
     """A compiled program's computations, less what names the CALLER: the
     module's name line, the tables of source files and stack frames, and
@@ -889,6 +1010,11 @@ PARENTS_TEXT = {
     # ISSUE 39's control, taken on PR 39's PARENT (378095c): the fifth
     # served family's decode program, composed tables [2, 24, 192]
     "evabyte-decode": "661e55a08e3049ed",
+    # ISSUE 41's control, taken on PR 41's PARENT (d31556f): ``moe_route``
+    # and ``moe_dropless`` gained options whose defaults the three expert
+    # families above and below run; the sixth family's decode program,
+    # planes [5, 40961, 16, 512 | 128], table [128, 768]
+    "pangu-decode": "cb7ad74bb4c0b8da",
 }
 PARENTS_JAX = "0.9.0"
 
@@ -958,7 +1084,8 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
     config = {"mistral": "mistral-7b-v0.3-6l", "gpt2": "gpt2-small",
               "lfm2": "lfm2-24b-a2b-8l",
               "laguna": "laguna-xs.2-ep8-8l",
-              "evabyte": "evabyte-6.5b-8l"}[which]
+              "evabyte": "evabyte-6.5b-8l",
+              "pangu": "openpangu-ultra-moe-ep32-5l"}[which]
     held = common.load_json(
         os.path.join(root, f"benchmark/configs/{config}.json"))
     cfg = dataclasses.replace(
@@ -976,26 +1103,35 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
         init = common.load_named("reference", held["family"]).init_fn()
         params = jax.tree.map(on_chip, jax.eval_shape(
             lambda: init(jax.random.PRNGKey(0), cfg)))
-        if which != "evabyte":  # it keeps no state beside the pool
-            more = {"state": jax.tree.map(on_chip, jax.eval_shape(
-                lambda: fam.init_state(cfg, 65))), "slots": i32((64,))}
         num_blocks, tables = {"laguna": (32769, (4, 64, 1152)),
                               "lfm2": (4097, (64, 160)),
-                              "evabyte": (4353, (2, 24, 192))}[which]
-    pool = S_(pool_shape(getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks,
-                         16, getattr(cfg, "n_kv_head", None) or cfg.n_head,
-                         cfg.head_dim), cfg.dtype)
-    # by heads, as PR 31's parent stored it, where the heads are of 128
-    assert len(pool.shape) == (5 if cfg.head_dim == 128 else 4)
+                              "evabyte": (4353, (2, 24, 192)),
+                              "pangu": (40961, (128, 768))}[which]
+        if which != "evabyte":  # it keeps no state beside the pool
+            rows = tables[-2]
+            more = {"state": jax.tree.map(on_chip, jax.eval_shape(
+                lambda: fam.init_state(cfg, rows + 1))),
+                "slots": i32((rows,))}
+    if which == "pangu":  # a pool in planes, each at its stored width
+        pools = [S_((cfg.n_layer, num_blocks, 16, stored), cfg.dtype)
+                 for _, _, stored in cfg.kv_planes]
+    else:
+        pool = S_(pool_shape(
+            getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,
+            getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim),
+            cfg.dtype)
+        # by heads, as PR 31's parent stored it, where the heads are of 128
+        assert len(pool.shape) == (5 if cfg.head_dim == 128 else 4)
+        pools = [pool, pool]
     fns = decode.DecodeFns(held["family"], cfg, platform="tpu")
     if kind == "decode":
         rows = tables[-2]
         lowered = fns._decode.lower(
-            params, pool, pool, i32((rows,)), i32((rows,)), i32(tables),
+            params, *pools, i32((rows,)), i32((rows,)), i32(tables),
             sample=None, **more)
     else:
         lowered = fns._prefill.lower(
-            params, pool, pool, i32((4, 2048)), i32((4,)), i32((4, 128)),
+            params, *pools, i32((4, 2048)), i32((4,)), i32((4, 128)),
             sample=None)
     got = _program_text_sha(lowered.compile().as_text())
     assert got == PARENTS_TEXT[case], got
