@@ -569,7 +569,7 @@ def paged_prefill_attention_pallas(
     NB = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     q, pos, qb, nqb = _q_tiles(
-        q, positions, q_block if q_block is not None else min(S, 128))
+        q, positions, q_block if q_block is not None else min(S, Q_TILE))
     Sp = nqb * qb
     R = qb * G
     # fold softmax scale AND log2(e) into q once — base-2 softmax
@@ -960,3 +960,14 @@ def latent_attention(
     return paged_latent_attention(
         q, *_at_layer(latent_pool, rope_pool, layer), block_tables,
         positions, latent_dim=latent_dim, scale=scale)
+
+
+# The most queries ``paged_prefill_attention_pallas`` gives one q tile: a
+# chunk of more is walked in tiles of this many, each its own grid step
+# with its own causal frontier. So Q_TILE tokens of a prompt cost the
+# kernel the same as a row of their own as they do as a tile of a longer
+# row, which is what lets the scheduler fill a prefill step with PIECES of
+# prompts (serve/llm/engine.py ``_prefill_chunk_locked``). Defined down
+# here so that no line of the kernel's call chain moves: a compiled step
+# program is found in the persistent cache by its callers' line numbers.
+Q_TILE = 128

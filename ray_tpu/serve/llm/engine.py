@@ -30,7 +30,10 @@ the compile cache). Chunk prefills reuse the SAME length buckets for both
 the chunk width and the context extent, so they add at most one more
 bounded signature family ("prefill_chunk") next to the monolithic
 "prefill" and "decode" kinds. `DecodeFns.num_compiled_shapes` reports the
-realized count.
+realized count. Where a sequence is K/V pages under one table (the dense
+families) a prefill step is filled by TOKENS instead: its rows are pieces
+of prompts, one q tile of the kernel each, and its programs the rungs of
+one row ladder, all "prefill_chunk" (``_prefill_chunk_locked``).
 
 Sampling is FUSED into the jitted model step (ops/sampling.py): greedy,
 temperature, top-k and top-p all run on device, so the per-token
@@ -102,6 +105,7 @@ Failure semantics (docs/SERVING_LLM.md "Failure semantics"):
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import math
 import queue
@@ -120,7 +124,11 @@ from ray_tpu.exceptions import (
     EngineOverloadedError,
     RequestCancelledError,
 )
-from ray_tpu.serve._shapes import pad_to_bucket, pow2_buckets
+from ray_tpu.serve._shapes import (
+    pad_to_bucket,
+    pow2_buckets,
+    stepped_buckets,
+)
 from ray_tpu.serve.llm import obs, structured
 from ray_tpu.serve.llm.executor import build_executor
 from ray_tpu.serve.llm.kv_cache import (
@@ -473,7 +481,10 @@ class _InFlight:
     books once its tokens are on the host: each row's chunk length, token
     chain and ``prefill_done`` after the chunk, whether the chunk was the
     row's last (its id is then the row's first token), and the step's
-    clocks and shape for the one flight record it gets."""
+    clocks and shape for the one flight record it gets. ``ids_at``: where
+    a PACKED prefill step's requests have their ids (``batch[j]``'s at row
+    ``ids_at[j]``, its last piece's; None: at row j, as in every other
+    step)."""
 
     kind: str            # "decode" | "prefill" | "prefill_chunk"
     tokens: Any          # jax [B] int32, still on device
@@ -483,11 +494,13 @@ class _InFlight:
     t0: float = 0.0
     t0_wall: float = 0.0
     fields: dict | None = None  # prefill: the flight record's shape fields
-    index: dict | None = None   # row -> its index in ``batch``, on demand
+    index: dict | None = None   # request -> the row of its id, on demand
+    ids_at: list | None = None  # packed prefill: the rows of ``batch``'s ids
 
     def row_of(self, r) -> int:
         if self.index is None:
-            self.index = {row: j for j, row in enumerate(self.batch)}
+            self.index = dict(zip(
+                self.batch, self.ids_at or range(len(self.batch))))
         return self.index[r]
 
 
@@ -691,6 +704,30 @@ class LLMEngine:
         self._composed_nb = self.cache.cfg.composed_blocks(
             min(self._length_buckets[-1], model_cfg.max_seq_len)
         ) if composed else None
+        # A prefill step is filled by TOKENS where the cache manager says
+        # a sequence may be split over the rows of one step
+        # (``one_table``): a row is then a PIECE of a prompt, as many
+        # tokens as the kernel gives one q tile (or the whole chunk where
+        # that is shorter), at its true first position under its
+        # sequence's table; a step holds up to ``_piece_rows[-1]`` of
+        # them: one chunk's worth (the longest bucket, or
+        # ``prefill_chunk_tokens``) and never fewer than
+        # ``max_prefill_batch``, so as many short prompts go together as
+        # ever. The rows are padded to a ladder of the engine's own
+        # (``stepped_buckets``) and every row carries ONE table width, the
+        # widest context's: the kernel's cost is the pages a row attends.
+        # ``_piece`` None: a row is a request, padded to the longest
+        # row's bucket (``_prefill_chunk_locked`` says who keeps that).
+        self._piece: int | None = None
+        if self.cache.cfg.one_table:
+            from ray_tpu.ops.paged_attention import Q_TILE
+
+            top = min(self._length_buckets[-1], model_cfg.max_seq_len)
+            chunk = min(cfg.prefill_chunk_tokens or top, top)
+            self._piece = min(Q_TILE, chunk)
+            self._piece_rows = stepped_buckets(
+                max(-(-chunk // self._piece), cfg.max_prefill_batch))
+            self._piece_nb = self._table_blocks(self._length_buckets[-1])
         self._lock = threading.RLock()
         self._work = threading.Condition(self._lock)
         self._waiting: deque[_Request] = deque()
@@ -727,6 +764,10 @@ class LLMEngine:
         self._cancelled_total = 0
         self._deadline_total = 0
         self._prefill_tokens_total = 0  # tokens actually run through prefill
+        # ... and the slots its launches held for them, rows x row length,
+        # padding included; the launches that were packed (``_piece``)
+        self._prefill_slots = 0
+        self._prefill_steps_packed = 0
         # "prefill" | "decode" | None — drives prefill/decode alternation
         # and gives tests a step-order trace.
         self.last_step_kind: str | None = None
@@ -1484,6 +1525,12 @@ class LLMEngine:
                 "kv_promoted_blocks": cs.promoted_blocks,
                 "cow_blocks": cs.cow_copies,
                 "prefill_tokens_total": computed,
+                # the slots the prefill launches held for those tokens
+                # (rows x row length of every launch, padding included:
+                # tokens / slots is how full the prefill programs ran),
+                # and the launches whose rows were pieces of prompts
+                "prefill_slots": self._prefill_slots,
+                "prefill_steps_packed": self._prefill_steps_packed,
                 "prefix_hit_rate": hit / max(1, hit + computed),
                 "host_sync_seconds_total": round(
                     self._sync_seconds_total, 6
@@ -2180,28 +2227,59 @@ class LLMEngine:
     def _prefill_chunk_locked(self) -> None:
         """Run ONE prefill call for up to ``max_prefill_batch`` admitted
         requests: each contributes its next chunk (the whole uncached
-        suffix when ``prefill_chunk_tokens`` is None). Cold whole prompts
-        take the monolithic reference path (start=None) — identical
-        numerics and compile signatures to PR 1; anything mid-prompt or
-        prefix-seeded takes the paged chunk path at true positions."""
-        batch = self._prefilling[: self.cfg.max_prefill_batch]
+        suffix when ``prefill_chunk_tokens`` is None).
+
+        A step is filled by TOKENS wherever a sequence may be split over
+        its rows (``_piece``: K/V pages under one table are all it
+        carries, kv_cache.py ``one_table``): the chunks are cut into
+        pieces of the kernel's q tile, a row each, ``lengths[i]`` its real
+        tokens (only a chunk's last piece is short), ``starts[i]`` its
+        true first position, ``tables[i]`` its sequence's table. Row i + 1
+        of a prompt sees row i's keys because a layer scatters the step's
+        K/V into the pool BEFORE its kernel reads the pool through the
+        table, masked by true position: the chunk program as it is
+        (``prefill_chunk``), cold prompt or not, so a first prefill and a
+        re-prefill go the same way. The rows are padded to the engine's
+        own ladder (``_piece_rows``); a chunk that does not fit what is
+        left of the step goes on in the next one at its ``prefill_done``.
+        A request's first token is the id at its LAST piece's row
+        (``_InFlight.ids_at``).
+
+        Every other layout keeps a row a request, padded to the longest
+        row's bucket: state slots beside the pool (a piece's short
+        convolution needs the piece before it, inside the same step),
+        tables by group (freeing behind a window, a ring composed by
+        position), a pool in planes. There a cold whole prompt takes the
+        program without ``start`` (kind ``prefill``), anything mid-prompt
+        the chunk program at true positions."""
+        P = self._piece
+        if P and not self._prefill_steps:
+            self._warm_pieces_locked()
+        cap = self.cfg.prefill_chunk_tokens
+        batch, ns = [], []
+        room = self._piece_rows[-1] if P else 0  # rows left in the step
+        for r in self._prefilling[: self.cfg.max_prefill_batch]:
+            remaining = len(r.prefill_tokens) - r.prefill_done
+            n = remaining if cap is None else min(remaining, cap)
+            if P:
+                n = min(n, room * P)
+                if not n:
+                    break  # the step is full: this one waits for the next
+                room -= -(-n // P)
+            batch.append(r)
+            ns.append(n)
         chaos.fire("engine.prefill", batch=len(batch))
         self._step_kind = "prefill"
         t0 = obs.clock()
         t0_wall = obs.wall()
         bs = self.cfg.block_size
-        cap = self.cfg.prefill_chunk_tokens
         with self._phase("kv.reserve"):
             # staged host-tier promotions land before capacity/COW work so
             # a same-window eviction or fork of a promoted block is safe
             self._apply_promotions_locked()
-            ns = []
-            for r in batch:
-                r.started = True
-                remaining = len(r.prefill_tokens) - r.prefill_done
-                ns.append(remaining if cap is None else min(remaining, cap))
             pairs: list[tuple[int, int]] = []
             for r, n in zip(batch, ns):
+                r.started = True
                 r.drawn_blocks -= self.cache.free_behind(
                     r.id, r.prefill_done)
                 appended = self.cache.ensure_capacity(
@@ -2216,21 +2294,31 @@ class LLMEngine:
             self._apply_copies_locked(pairs)
 
         with self._phase("engine.batch"):
-            legacy = all(
+            legacy = not P and all(
                 r.prefill_done == 0 and n == len(r.prefill_tokens)
                 for r, n in zip(batch, ns)
             )
             kind = self._step_kind = "prefill" if legacy else "prefill_chunk"
-            S = pad_to_bucket(max(ns), self._length_buckets)
-            B = pad_to_bucket(len(batch), self._batch_buckets)
-            if legacy:
-                nb = S // bs
+            # rows first[k] to first[k + 1] are request k's: its one row,
+            # or its chunk's pieces, its id at the last of them
+            first = list(itertools.accumulate(
+                (-(-n // P) if P else 1 for n in ns), initial=0))
+            used, ids_at = first[-1], None
+            if P:
+                ids_at = [j - 1 for j in first[1:]]
+                S, nb = P, self._piece_nb
+                B = pad_to_bucket(used, self._piece_rows)
             else:
-                ctx = pad_to_bucket(
-                    max(r.prefill_done + n for r, n in zip(batch, ns)),
-                    self._length_buckets,
-                )
-                nb = self._table_blocks(ctx)
+                S = pad_to_bucket(max(ns), self._length_buckets)
+                B = pad_to_bucket(len(batch), self._batch_buckets)
+                if legacy:
+                    nb = S // bs
+                else:
+                    ctx = pad_to_bucket(
+                        max(r.prefill_done + n for r, n in zip(batch, ns)),
+                        self._length_buckets,
+                    )
+                    nb = self._table_blocks(ctx)
             tokens = self._scratch_buf("pf_tokens", (B, S), np.int32)
             lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
             starts = self._scratch_buf("pf_starts", (B,), np.int32)
@@ -2239,18 +2327,25 @@ class LLMEngine:
             # reused buffers: stale padding rows/columns must be re-zeroed
             # (a stale table row could point at blocks now owned by a LIVE
             # sequence — padding writes must stay on the garbage block)
-            tokens[len(batch):] = 0
+            tokens[used:] = 0
             lengths[:] = 1  # padding rows: length 1
-            starts[len(batch):] = 0
-            tables[..., len(batch):, :] = 0
-            for i, (r, n) in enumerate(zip(batch, ns)):
+            starts[used:] = 0
+            tables[..., used:, :] = 0
+            flat = tokens.reshape(-1)
+            for k, (r, n) in enumerate(zip(batch, ns)):
                 toks = r.prefill_tokens
-                tokens[i, :n] = toks[r.prefill_done : r.prefill_done + n]
-                tokens[i, n:] = 0
-                lengths[i] = n
-                starts[i] = r.prefill_done
-                tables[..., i, :] = self._table_for(r, nb, r.prefill_done)
-            sample = self._sample_args_locked(batch, B)
+                done = r.prefill_done
+                # the chunk lies in its rows end to end: every piece but
+                # the last is full
+                i, j = first[k], first[k + 1]
+                flat[i * S : i * S + n] = toks[done : done + n]
+                flat[i * S + n : j * S] = 0
+                lengths[i:j] = S
+                lengths[j - 1] = n - (j - 1 - i) * S
+                starts[i:j] = range(done, done + n, S)
+                tables[..., i:j, :] = self._table_for(r, nb, done)[
+                    ..., None, :]
+            sample = self._sample_args_locked(batch, B, rows=ids_at)
         # the (query, key) pairs the step's attention covers: each real
         # query token at position p attends p + 1 positions
         span = {"kind": kind, "seq": self._launched + 1,
@@ -2273,8 +2368,10 @@ class LLMEngine:
         else:
             toks_dev = self.executor.prefill_chunk(
                 tokens, lengths, starts, tables, sample=sample, span=span,
-                slots=slots,
+                slots=slots, ids_width=self._ids_width(B) if P else None,
             )
+        self._prefill_slots += B * S
+        self._prefill_steps_packed += bool(P)
         self._prefill_steps += 1
         # The host's view moves on AT THE LAUNCH: the chunk is as good as
         # written (whatever touches these blocks next is a later program
@@ -2311,11 +2408,12 @@ class LLMEngine:
                         r.id, r.prefill_done)
         rec = self._launched_locked(_InFlight(
             kind=kind, tokens=toks_dev, batch=batch, rows=rows,
-            t0=t0, t0_wall=t0_wall, fields=dict(
+            ids_at=ids_at, t0=t0, t0_wall=t0_wall, fields=dict(
                 batch=len(batch), bucket_b=B, bucket_len=S, nb=nb,
                 tokens=int(sum(ns)), admitted=self._step_admitted,
                 expired=self._step_expired,
-                trace_ids=self._trace_ids_locked(batch))))
+                trace_ids=self._trace_ids_locked(batch),
+                **({"pieces": used} if P else {}))))
         # what was in flight before it: its run has ended, or ends while
         # this one runs
         self._reconcile_older_locked(rec)
@@ -2325,6 +2423,35 @@ class LLMEngine:
         # never come
         if not self._prefilling and not self._eligible_locked():
             self._reconcile_locked(rec)
+
+    def _warm_pieces_locked(self) -> None:
+        """Run every row count of the packed ladder once, over padding
+        rows alone (length 1 at position 0 under an all-zero table: block
+        0 is the garbage sink), before this engine's first prefill step:
+        the ladder is the engine's own, and which of its rungs a warm-up's
+        prompts reach is an accident of their lengths (prompts just under
+        a bucket's top are 4, 8, 16 pieces, never 1-3). So the programs,
+        and ``executor._warm_feed``'s id gathers of their widths, exist
+        before traffic whatever came first; programs are process-wide, so
+        a second engine over the same model finds them made. These are no
+        steps of the scheduler (``executor.warm_prefill_chunk``); the
+        watchdog's clock restarts a launch."""
+        for rows in self._piece_rows:
+            self.executor.warm_prefill_chunk(
+                np.zeros((rows, self._piece), np.int32),
+                np.ones((rows,), np.int32), np.zeros((rows,), np.int32),
+                np.zeros((rows, self._piece_nb), np.int32),
+                self._sample_args_locked([], rows), self._ids_width(rows))
+            self._step_begin = obs.clock()
+
+    def _ids_width(self, rows: int) -> int | None:
+        """The width a packed step of ``rows`` pieces hands its ids on at:
+        the decode row bucket that holds them, so that the gather of the
+        decode step behind it (a program a pair of widths) is one that
+        decode steps need of each other anyway; None (as they are) where
+        no bucket holds them or one fits exactly."""
+        width = pad_to_bucket(rows, self._batch_buckets)
+        return width if width > rows else None
 
     def _eligible_locked(self) -> list[_Request]:
         """The rows a decode step may be launched over. The budget counts
@@ -2505,7 +2632,8 @@ class LLMEngine:
                 positions[i] = r.total_len + r.inflight - 1
                 tables[..., i, :] = self._table_for(r, nb, positions[i])
             feed = None
-            same = steady and batch == ahead.batch
+            same = (steady and ahead.ids_at is None
+                    and batch == ahead.batch)
             if same:
                 # list equality is element identity here: the same
                 # _Request objects in the same order. Feed step N+1 from
@@ -2654,8 +2782,11 @@ class LLMEngine:
         clock)."""
         book = _StepTokens()
         dur_ms = round(dt * 1000.0, 3)
+        ids = toks.tolist()
+        if rec.ids_at is not None:  # packed: a request's last piece's row
+            ids = [ids[i] for i in rec.ids_at]
         for r, tok, (n, chain, done, final) in zip(
-                rec.batch, toks.tolist(), rec.rows):
+                rec.batch, ids, rec.rows):
             r.inflight -= 1
             self._tl(r, rec.kind, ts=rec.t0_wall, dur_ms=dur_ms, tokens=n,
                      prefill_done=done)
@@ -2936,23 +3067,26 @@ class LLMEngine:
         }
 
     def _sample_args_locked(self, batch: list, B: int,
-                            proposals: list[list[int]] | None = None) -> dict:
+                            proposals: list[list[int]] | None = None,
+                            rows: list[int] | None = None) -> dict:
         """Per-row sampling controls as [B] host staging arrays — the
         ``sample`` pytree consumed by ops/sampling.py inside the jitted
         step (they ride the jitted call to the device). Padding rows are
         greedy (temperature 0) so the batch-wide all-greedy fast path
         stays available whenever every REAL row is greedy. ``proposals``:
-        a verify window's drafts, a row each."""
+        a verify window's drafts, a row each. ``rows``: the row each
+        request's controls go to (a packed prefill step: where its id is
+        sampled, every other row as padding); None: row i."""
         seeds = self._scratch_buf("sp_seeds", (B,), np.uint32)
         temp = self._scratch_buf("sp_temp", (B,), np.float32)
         top_k = self._scratch_buf("sp_top_k", (B,), np.int32)
         top_p = self._scratch_buf("sp_top_p", (B,), np.float32)
-        n = len(batch)
+        n = 0 if rows is not None else len(batch)
         seeds[n:] = 0
         temp[n:] = 0.0
         top_k[n:] = 0
         top_p[n:] = 1.0
-        for i, r in enumerate(batch):
+        for i, r in zip(rows or range(len(batch)), batch):
             sp = r.sampling
             seeds[i] = sp.seed & 0xFFFFFFFF
             temp[i] = sp.temperature
@@ -2963,11 +3097,12 @@ class LLMEngine:
             "temperature": temp,
             "top_k": top_k,
             "top_p": top_p,
-            "mask": self._allow_mask_locked(batch, B, proposals),
+            "mask": self._allow_mask_locked(batch, B, proposals, rows),
         }
 
     def _allow_mask_locked(self, batch: list, B: int,
-                           proposals: list[list[int]] | None):
+                           proposals: list[list[int]] | None,
+                           rows: list[int] | None = None):
         """The grammar allow-mask leaf, ALWAYS part of ``sample`` (all
         ones = no constraint): mask is data, not signature, so constrained
         and unconstrained rows share one step program and the compile
@@ -2985,7 +3120,7 @@ class LLMEngine:
             return self.executor.ones_mask(shape)
         mask = self._scratch_buf("sp_mask", shape, np.uint32)
         mask[:] = 0xFFFFFFFF
-        for i, r in enumerate(batch):
+        for i, r in zip(rows or range(len(batch)), batch):
             if r.fsm is None:
                 continue
             if proposals is None:

@@ -84,6 +84,29 @@ def feed_ids(source, feed):
     return _feed_ids(source, feed)
 
 
+_pad_ids = None
+
+
+def pad_ids(ids, width: int):
+    """A step's sampled ids ``[rows]`` padded with zeros to ``[width]``, on
+    the device (``jit_pad_ids``, a shape a pair). What keeps ``feed_ids``'
+    shapes few: a packed prefill step's ids come a row a PIECE, at the row
+    counts of a ladder of the engine's own, and the decode step behind it
+    gathers from them, a program a (source width, row bucket) pair; handed
+    on at the width of a decode row bucket they add no pair to the ones
+    decode steps feed each other with."""
+    global _pad_ids
+    if _pad_ids is None:
+        import jax
+        import jax.numpy as jnp
+
+        def pad_ids(ids, width):
+            return jnp.pad(ids, (0, width - ids.shape[0]))
+
+        _pad_ids = jax.jit(pad_ids, static_argnums=1)
+    return _pad_ids(ids, width)
+
+
 def _host_blocks(kv) -> np.ndarray:
     """The SECOND allowed device->host sync, off the emit path entirely:
     materialize a handful of finished KV blocks for a disaggregated
@@ -398,11 +421,33 @@ class ModelExecutor:
         return ids
 
     def prefill_chunk(self, tokens, lengths, starts, tables, sample=None,
-                      span=None, slots=None):
+                      span=None, slots=None, ids_width=None):
+        """``ids_width``: hand the ids on padded to that many
+        (``pad_ids``; a packed step's, whose rows are pieces)."""
         ids = self._run(self.fns.prefill, (tokens, lengths, tables),
                         sample, span, start=starts, slots=slots)
+        return self._hand_on(ids, sample, ids_width)
+
+    def _hand_on(self, ids, sample, ids_width):
+        if ids_width is not None and sample is not None:
+            ids = pad_ids(ids, ids_width)
         self._warm_feed(ids, sample)
         return ids
+
+    def warm_prefill_chunk(self, tokens, lengths, starts, tables,
+                           sample, ids_width=None) -> None:
+        """``prefill_chunk`` over padding rows alone (length 1 at position
+        0 under an all-zero table: block 0 is the garbage sink), for the
+        shape's sake: the program exists afterwards, and ``_warm_feed``'s
+        gathers from ids of its width. The engine runs the row counts of
+        its packed ladder so before its first prefill step. No step of the
+        scheduler's: under no phase and in no staging counter. The
+        arguments are of the kinds a step's are (a jitted call's fast path
+        is keyed by them: ``decode_step``)."""
+        ids, self.cache.k, self.cache.v, self.cache.state = self.fns.prefill(
+            self.params, self.cache.k, self.cache.v, tokens, lengths, tables,
+            start=starts, sample=sample, state=self.cache.state)
+        self._hand_on(ids, sample, ids_width)
 
     def decode_step(self, tokens, positions, tables, sample=None, span=None,
                     slots=None, feed=None):
@@ -922,6 +967,7 @@ class ShardedExecutor(ModelExecutor):
 
     prefill = _in_mesh("prefill")
     prefill_chunk = _in_mesh("prefill_chunk")
+    warm_prefill_chunk = _in_mesh("warm_prefill_chunk")
     decode_step = _in_mesh("decode_step")
     verify_step = _in_mesh("verify_step")
 

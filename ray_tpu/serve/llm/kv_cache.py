@@ -258,6 +258,18 @@ class KVCacheConfig:
         return is_composed(self.groups)
 
     @property
+    def one_table(self) -> bool:
+        """Whether all a sequence carries from token to token is K/V pages
+        under ONE table: no state slot beside the pool (a piece's short
+        convolution would need the piece before it), no tables by group
+        (a window's blocks go back behind a position; a ring and a slot
+        table are composed by position) and no planes. Only then may the
+        scheduler split a sequence over ROWS of one prefill step, each row
+        at its true positions under the same table (engine.py "a prefill
+        step is filled by tokens")."""
+        return not (self.groups or self.planes or self.state_slots)
+
+    @property
     def window_chunk(self) -> tuple[int, int] | None:
         """A composed cache's ``(W, C)``: the ring's window and the slot
         table's positions a slot; None for any other."""

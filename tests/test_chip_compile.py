@@ -348,6 +348,11 @@ POOL_PROGRAMS = {
     "gpt2-decode-tp4": ("gpt2-small", 4, "decode"),
     "lfm2-decode": ("lfm2-24b-a2b-8l", 1, "decode"),
     "lfm2-prefill": ("lfm2-24b-a2b-8l", 1, "prefill"),
+    # ISSUE 42: what the dense cells' prefill steps run since: the chunk
+    # program over rows of one q tile, the longest rung of the packed
+    # ladder under the widest context's table
+    "mistral-prefill-packed": ("mistral-7b-v0.3-6l", 1, "packed"),
+    "gpt2-prefill-packed": ("gpt2-small", 1, "packed"),
 }
 
 
@@ -428,6 +433,14 @@ def test_step_programs_update_the_pool_in_place(topo, monkeypatch, case):
                 params, pool, pool, i32((64,)), i32((64,)),
                 i32((64, min(2560, cfg.max_seq_len) // bs)), sample=None,
                 **more)
+        elif kind == "packed":
+            from ray_tpu.ops.paged_attention import Q_TILE
+
+            top = min(2560, cfg.max_seq_len)
+            rows = top // Q_TILE
+            lowered = fns._prefill.lower(
+                params, pool, pool, i32((rows, Q_TILE)), i32((rows,)),
+                i32((rows, top // bs)), start=i32((rows,)), sample=None)
         else:
             if more:
                 more["slots"] = i32((4,))

@@ -223,12 +223,15 @@ def test_bounded_compiled_shapes(jax_cpu):
             break
         eng.step()
     assert all(s.done for s in streams)
-    # hard ceiling: kinds * batch buckets * length buckets
-    assert eng.num_compiled_shapes <= 2 * 3 * 3
-    # and in practice far fewer than distinct request shapes
-    assert eng.num_compiled_shapes < len(lengths)
-    for kind, tok_shape, table_shape in eng.fns.signatures:
-        assert tok_shape[0] in (1, 2, 4)  # every call hit a batch bucket
+    # hard ceiling: decode's batch buckets * length buckets, and the
+    # packed prefill ladder (rows of one piece, whatever the ten lengths)
+    assert eng.num_compiled_shapes <= 3 * 3 + len(eng._piece_rows)
+    sigs = eng.fns.signatures
+    assert {s[1] for s in sigs if s[0] != "decode"} == {
+        (rows, 32) for rows in (1, 2, 3, 4)}
+    for kind, tok_shape, table_shape in sigs:
+        if kind == "decode":
+            assert tok_shape[0] in (1, 2, 4)  # every call hit a batch bucket
 
 
 # ------------------------------------------- metrics
@@ -244,7 +247,7 @@ def test_engine_metrics_exported(jax_cpu):
     assert "llm_engine_queue_depth" in snap
     assert "llm_engine_kv_block_utilization" in snap
     prefill_count = snap.get(
-        'llm_engine_step_latency_seconds_count{kind=prefill}', 0)
+        'llm_engine_step_latency_seconds_count{kind=prefill_chunk}', 0)
     decode_count = snap.get(
         'llm_engine_step_latency_seconds_count{kind=decode}', 0)
     assert prefill_count >= 1 and decode_count >= 3

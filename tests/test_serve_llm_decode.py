@@ -532,7 +532,8 @@ def test_first_token_does_not_wait_for_the_step_launched_behind(jax_cpu):
             return out
         return run
 
-    ex.prefill = playing(ex.prefill, "prefill", 0.060)
+    # a dense family's cold prompt is packed: the chunk program
+    ex.prefill_chunk = playing(ex.prefill_chunk, "prefill", 0.060)
     ex.decode_step = playing(ex.decode_step, "decode", 0.080)
     sync = ex.sync_tokens
 
@@ -558,7 +559,8 @@ def test_first_token_does_not_wait_for_the_step_launched_behind(jax_cpu):
     assert decode_launch < prefill_end, "the step was launched behind it"
     assert t_first - prefill_end < 0.030, (t_first - prefill_end)
     assert t_first < decode_end - 0.030, "it waited for the decode step"
-    rec = [r for r in eng.debug_dump()["steps"] if r["kind"] == "prefill"][-1]
+    rec = [r for r in eng.debug_dump()["steps"]
+           if r["kind"] == "prefill_chunk"][-1]
     assert rec["sync_lag"] == 1
     # dur_ms: from the step's start to its ids on the host
     assert 55.0 <= rec["dur_ms"] <= 60.0 + 35.0
@@ -580,7 +582,8 @@ def test_host_sync_moves_o_batch_int32_not_logits(jax_cpu):
 
     recs = [r for r in eng.debug_dump()["steps"] if "sync_bytes" in r]
     assert recs, "no sync records in the flight ring"
-    buckets = set(eng._batch_buckets)
+    # a packed prefill step's ids are a row a piece, padded to its ladder
+    buckets = set(eng._batch_buckets) | set(eng._piece_rows)
     for r in recs:
         # 4 bytes per row, rows padded to a batch bucket — and nowhere
         # near a logits transfer (4 * bucket * vocab)

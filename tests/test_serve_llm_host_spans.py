@@ -63,7 +63,8 @@ def test_phase_names_and_shape_are_frozen(jax_cpu):
     st = eng.stats()
     eng.shutdown()
     phases = st["phases"]
-    assert set(phases) == {"prefill", "decode", "none"}
+    # a dense family's prompts are packed: the chunk kind, cold or not
+    assert set(phases) == {"prefill_chunk", "decode", "none"}
     assert {n for table in phases.values() for n in table} == FROZEN_PHASES
     for table in phases.values():
         for rec in table.values():
@@ -271,7 +272,7 @@ def test_stage_counts_what_it_moves(jax_cpu):
     host = st["host"]
     launches = st["decode_steps"] + st["prefill_steps"]
     assert launches == st["phases"]["decode"]["executor.dispatch"][0] + \
-        st["phases"]["prefill"]["executor.dispatch"][0]
+        st["phases"]["prefill_chunk"]["executor.dispatch"][0]
     # a prefill moves tokens, lengths, tables and four sampling leaves; a
     # decode step positions, tables and the four, and its ids or the
     # indices to gather them by unless the batch is the one in flight.
@@ -293,9 +294,9 @@ def test_stage_counts_what_it_moves(jax_cpu):
     constrained = []
     sample_args = eng._sample_args_locked
 
-    def seen(batch, *args):
+    def seen(batch, *args, **kw):
         constrained.append(any(r.fsm is not None for r in batch))
-        return sample_args(batch, *args)
+        return sample_args(batch, *args, **kw)
 
     eng._sample_args_locked = seen
     words = (eng.model_cfg.vocab_size + 31) // 32
@@ -373,9 +374,11 @@ def test_phases_read_the_cpu_clock_on_one_step_in_eight(jax_cpu, monkeypatch):
         assert eng.step()
         per_step.append(calls[0] - before)
     # always: the lock's span (2 readings) and the flight record's (1),
-    # a gather's span where there was one (2); beside them every phase's
-    # two on the steps that are measured, the first and the ninth
-    measured = [i for i, n in enumerate(per_step) if n > 5]
+    # a gather's span where there was one (2: the decode step behind a
+    # packed prefill takes its id from the last piece's row, and books
+    # that prefill's record too, 1); beside them every phase's two on the
+    # steps that are measured, the first and the ninth
+    measured = [i for i, n in enumerate(per_step) if n > 6]
     assert measured == [0, obs.CPU_EVERY]
     # (a prefill launched with its sync put off: five phases, no record)
     assert all(per_step[i] >= 2 + 2 * 5 for i in measured)

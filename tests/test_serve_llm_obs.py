@@ -159,8 +159,8 @@ def test_latency_histograms_and_compile_events_exported(jax_cpu):
     # before it and ``engine.account`` after it (a prefill is timed up to
     # its sync: its ``engine.emit`` comes after, too)
     phases = eng.stats()["phases"]
-    assert set(phases) >= {"prefill", "decode"}
-    for kind in ("prefill", "decode"):
+    assert set(phases) >= {"prefill_chunk", "decode"}  # packed: the chunk kind
+    for kind in ("prefill_chunk", "decode"):
         tag = f"{{kind={kind}}}"
         steps = (after[f"llm_engine_step_latency_seconds_count{tag}"]
                  - count(f"llm_engine_step_latency_seconds_count{tag}"))
@@ -169,7 +169,7 @@ def test_latency_histograms_and_compile_events_exported(jax_cpu):
         assert phases[kind]["engine.account"][0] == steps
         assert phases[kind]["engine.schedule"][0] == steps
         outside = {"engine.schedule", "engine.account"} | (
-            {"engine.emit"} if kind == "prefill" else set())
+            {"engine.emit"} if kind == "prefill_chunk" else set())
         inside = sum(sec for name, (_, sec) in phases[kind].items()
                      if name not in outside)
         assert 0 < inside <= timed + 1e-6

@@ -175,7 +175,7 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
         last = now
     assert {s["name"] for s in seen} == STEP_PHASES
     phases = eng.stats()["phases"]
-    assert set(phases) == {"prefill", "decode"}
+    assert set(phases) == {"prefill_chunk", "decode"}  # packed: the chunk kind
     # one account a step program launched, and one for the last, empty step
     launched = sum(s["name"] == "executor.dispatch" for s in seen)
     assert sum(t["engine.account"][0] for t in phases.values()) == \
@@ -197,7 +197,7 @@ def test_phases_balance_and_never_overlap(jax_cpu, recorder):
     # (PR 39: a prefill kind carries ``qk_pairs``, the positions its real
     # query tokens attend)
     assert all(set(a) == ({"kind", "seq", "qk_pairs"}
-                          if a["kind"] == "prefill"
+                          if a["kind"] == "prefill_chunk"
                           else {"kind", "seq", "kv_tokens"})
                for a in attrs["executor.dispatch"])
     # launches are numbered as they are made, every one is synced once,
@@ -344,7 +344,7 @@ def test_profiler_session_returns_the_spans_with_attributes(jax_cpu,
                         (e.start_ns, e.duration_ns, dict(e.stats)))
     assert set(spans) == STEP_PHASES
     dispatch = [stats for _, _, stats in spans["executor.dispatch"]]
-    assert [d["kind"] for d in dispatch][:2] == ["prefill", "decode"]
+    assert [d["kind"] for d in dispatch][:2] == ["prefill_chunk", "decode"]
     decode = [d for d in dispatch if d["kind"] == "decode"]
     assert decode and all(d["kv_tokens"] >= 16 for d in decode)
     assert {s["lag"] for _, _, s in spans["engine.sync"]} == {0, 1}

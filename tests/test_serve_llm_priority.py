@@ -305,7 +305,11 @@ def test_starvation_aging_floor(jax_cpu):
     a second time (anti-thrash), and completes byte-identical."""
     ref = _engine("llama").generate(BATCH_PROMPT, max_new_tokens=BATCH_NEW)
 
-    pc = dict(PREEMPTION, aging_s=0.4)
+    # resume_pressure below any pressure: nothing resumes but by its age.
+    # (With every prefill shape made before traffic no step stalls for a
+    # compile any more, and between two waves of this small flood the pool
+    # stands empty for a step: a stream resumed by THAT is not yet aged.)
+    pc = dict(PREEMPTION, aging_s=0.4, resume_pressure=-1.0)
     eng = _engine("llama", preemption=pc)
     batch = eng.submit(BATCH_PROMPT, max_new_tokens=BATCH_NEW,
                        priority="batch")

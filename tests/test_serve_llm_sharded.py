@@ -254,7 +254,8 @@ def test_sharded_host_sync_stays_o_batch_int32(jax_cpu):
 
     recs = [r for r in eng.debug_dump()["steps"] if "sync_bytes" in r]
     assert recs, "no sync records in the flight ring"
-    buckets = set(eng._batch_buckets)
+    # a packed prefill step's ids are a row a piece, padded to its ladder
+    buckets = set(eng._batch_buckets) | set(eng._piece_rows)
     for r in recs:
         assert r["sync_bytes"] % 4 == 0, r
         assert r["sync_bytes"] // 4 in buckets, r
