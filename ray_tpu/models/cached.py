@@ -146,8 +146,8 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
     slot]``, then the attention call the kind asks for reads the pools at
     that layer. Nothing slices a layer's slab out of a pool or writes one
-    back, whatever the heads: a pool whose pages would not be whole tiles
-    is stored lane-dense (ops/paged_attention.py ``pool_shape``), so every
+    back, whatever the heads: every pool is stored lane-dense, a token's
+    heads one row (ops/paged_attention.py ``pool_shape``), so every
     pool rests in the order the scatter and the kernel read. Returns
     (attention output [B, S, Hq * hd], cache_k', cache_v'). ``tables``
     [B, NB]: the layer's own table (None: ``step``'s); ``window``: sliding
@@ -303,10 +303,10 @@ def steps(fam: CachedFamily):
     """The family's (prefill, decode_step, verify_step), each named
     ``<fam.name>_<step>``: a jitted program takes its name from there.
 
-    All take ``(params, cache_k, cache_v, ...)``, the pool ``[n_kv_layer,
-    num_blocks, block_size, n_kv_head, head_dim]`` or lane-dense ``[..,
-    n_kv_head * head_dim]`` (ops/paged_attention.py ``pool_shape``; block 0
-    is the garbage sink), ``block_tables [B, NB]`` (``[G, B, NB]`` for a family whose
+    All take ``(params, cache_k, cache_v, ...)``, the pool lane-dense
+    ``[n_kv_layer, num_blocks, block_size, n_kv_head * head_dim]`` (a
+    test's own, by heads ``[.., n_kv_head, head_dim]``, is taken too;
+    ops/paged_attention.py ``pool_shape``; block 0 is the garbage sink), ``block_tables [B, NB]`` (``[G, B, NB]`` for a family whose
     layers name their group), the static ``cfg``, and by keyword
     ``sample`` (an ops/sampling.py pytree: sampling then runs inside the
     program and token ids come back, not logits), ``state`` and ``slots``.

@@ -457,7 +457,7 @@ def laguna_forward(params: dict, tokens: jax.Array,
 # ----------------------------------------------------------------------------
 # Cached inference paths (serve/llm engine): what models/cached.py's one
 # step needs of this family. The pool is [n_kv_layer, num_blocks,
-# block_size, n_kv_head, head_dim] and the step's block tables are
+# block_size, n_kv_head * head_dim] and the step's block tables are
 # [n_group, B, NB] (a family whose layers are all of one kind has one
 # group and still names it). Rows in slot 0 are padding: routed nowhere,
 # counted nowhere.

@@ -47,9 +47,9 @@ windowed attention are used as they are, with what this family forces:
 - The route is taken BEFORE ``attend`` and carried past it to the expert
   layer (``_cached_layer``: ``route -> attend -> experts``): no other
   family here routes from anything but the tensor its experts read.
-- 28 query heads over 4 K/V heads of 128: a group of SEVEN, and a page
-  ``[4, 128]`` that is no whole (8, 128) tile, so the pool rests
-  lane-dense, ``[n_kv_layer, num_blocks, block_size, 512]``
+- 28 query heads over 4 K/V heads of 128: a group of SEVEN over the
+  lane-dense pool every family has, here rows of 512,
+  ``[n_kv_layer, num_blocks, block_size, 512]``
   (ops/paged_attention.py ``pool_shape``).
 - ``state`` holds no per-sequence rows, only the expert layers' counters
   (``pairs`` ``[2, E, 2]``, ``reads`` ``[2]``, each a (low, high) pair of

@@ -2,16 +2,16 @@
 copy (COW for the prefix cache).
 
 The serving-side counterpart of ops/attention.py. A paged cache stores one
-layer's keys/values as fixed-size physical blocks
+layer's keys/values as fixed-size physical blocks, a token's heads ONE
+lane-dense row (ops/paged_attention.py ``pool_shape``; the cache manager
+allocates it so for every family)
 
-    k_layer, v_layer: [num_blocks, block_size, n_kv_head, head_dim]
+    k_layer, v_layer: [num_blocks, block_size, n_kv_head * head_dim]
 
-or, where ``[n_kv_head, head_dim]`` is not whole (8, 128) tiles, with a
-token's heads as ONE lane-dense row, ``[num_blocks, block_size, n_kv_head *
-head_dim]`` (ops/paged_attention.py ``pool_shape`` says which; the cache
-manager allocates it). Every function here takes either: a write flattens
-its rows to the pool's, a read splits the row into heads of q's size after
-indexing. Each sequence owns a BLOCK TABLE — logical position p of sequence b
+Every function here also takes a pool laid BY HEADS, ``[num_blocks,
+block_size, n_kv_head, head_dim]`` (a test's own array, a reference's): a
+write flattens its rows to the pool's, a read splits the row into heads of
+q's size after indexing. Each sequence owns a BLOCK TABLE — logical position p of sequence b
 lives at (block_tables[b, p // block_size], p % block_size). Block tables
 are dense int32 arrays padded with block 0, which is reserved as a garbage
 sink: every out-of-range or padding write is redirected there, so the

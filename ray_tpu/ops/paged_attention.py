@@ -6,7 +6,7 @@ ENTIRE padded context (``gather_kv`` → ``[B, NB*block_size, H_kv, hd]``
 in HBM) before a masked softmax. This kernel is the vLLM PagedAttention
 shape instead: one ``pallas_call`` that walks each sequence's block
 table and DMAs K/V **directly from the paged pool**
-(``[num_blocks, block_size, n_kv_head, hd]``), several pages at a time,
+(``[num_blocks, block_size, n_kv_head * hd]``), several pages at a time,
 into VMEM.
 Nothing is ever materialized at the padded context length, no head is ever
 repeated.
@@ -20,12 +20,18 @@ THE POOL IS READ WHERE IT STANDS: the cached step (models/cached.py) hands
 every entry point here the WHOLE pools and ``layer=``, an int32 scalar that
 reaches the kernel as one more scalar-prefetch word; a page is
 ``pool[layer, id]`` and no ``pool[layer]`` exists outside the kernel. That
-holds for every pool the cache manager allocates, because a pool is STORED
-so that its pages are whole tiles (``pool_shape``): ``[n_layer, num_blocks,
-block_size, n_kv_head, hd]`` where the ``[n_kv_head, hd]`` one device holds
-is whole (8, 128) tiles (8 heads of 128), and lane-dense, a token's heads
-ONE row, ``[n_layer, num_blocks, block_size, n_kv_head * hd]`` where it is
-not (heads of 64, 12 heads, 4 of 128: ``[.., 512]``, a ``tp`` shard's 2).
+holds for every pool the cache manager allocates, because there is ONE
+stored layout (``pool_shape``): lane-dense, a token's heads one row,
+``[n_layer, num_blocks, block_size, n_kv_head * hd]``, whatever the count
+and size of the heads (8 heads of 128: ``[.., 1024]``; 32 of 128: ``[..,
+4096]``; 12 of 64: ``[.., 768]``; 4 of 128: ``[.., 512]``; a ``tp`` shard's
+contiguous heads of the row). A page is then whole (8, 128) tiles, and a
+compute block of pages IS the ``[tokens, n_kv_head * hd]`` tile the
+products take, a head's keys a static lane slice of it. Up to PR 42 pools
+of 8k heads of 128 were stored BY HEADS (``[.., n_kv_head, hd]``, whole
+tiles too); a head's ``[tokens, hd]`` tile out of such a block is a strided
+read of single rows, and timed alone on the chip that read cost three times
+what the block's products and softmax cost (docs/MICROBENCHMARKS.md, PR 43).
 Without ``layer=`` the pool is one layer's ``[num_blocks, block_size, ...]``.
 
 Design (same playbook as ``ops/attention.py``'s flash kernels):
@@ -36,17 +42,16 @@ Design (same playbook as ``ops/attention.py``'s flash kernels):
   and the TPU runtime rests an array whose minor pair is not whole tiles
   (``[12, 64]``, ``[8, 64]``) in another order than the one written, so
   that XLA relays K and V around every call that wants them as written
-  (PERF.md, PR 27, 29 and 31). Hence the two stored shapes above: either
-  way a page is fetched whole, ALL its KV heads at once, by the kernel's
-  own copy; the head loop runs inside the kernel (static, unrolled), and
-  head ``h``'s ``[tokens, hd]`` tile is a strided read of the VMEM block
-  (pages by heads) or its static lane slice ``[:, h * hd:(h + 1) * hd]``
-  (lane-dense pages; ``Hkv`` and ``hd`` are read off q's shape and the
-  pool's row, nothing is told to the kernel). Two shapes fall outside: a
-  pool of such heads handed in BY HEADS (a test's own array; the cache
-  manager allocates none) is viewed lane-dense, on the chip a relayout of
-  it; and a lane-dense row that is not whole lanes (an odd count of heads
-  of 64: GPT-2's 12 over ``tp`` = 4) Mosaic refuses to slice ("Slice shape
+  (PERF.md, PR 27, 29 and 31). Hence the stored shape above: a page is
+  fetched whole, ALL its KV heads at once, by the kernel's own copy; the
+  head loop runs inside the kernel (static, unrolled), and head ``h``'s
+  ``[tokens, hd]`` tile is the static lane slice ``[:, h * hd:(h + 1) *
+  hd]`` of the block (``Hkv`` and ``hd`` are read off q's shape and the
+  pool's row). Two shapes fall outside: a pool handed in BY HEADS (a
+  test's own array; the cache manager allocates none) is viewed
+  lane-dense, on the chip a relayout of it; and a row that is not whole
+  lanes (an odd count of heads of 64: GPT-2's 12 over ``tp`` = 4) Mosaic
+  refuses to slice ("Slice shape
   along dimension 3 must be aligned to tiling (128), but is 192"): that
   layer's slab is padded to whole lanes for the call. The quantized pools'
   data planes (int8 / fp8) go the same way as the plain ones; their
@@ -77,6 +82,17 @@ Design (same playbook as ``ops/attention.py``'s flash kernels):
   ONE ``[P * block_size, hd]`` tile and one score matmul; the
   running-softmax update is made once a block for all heads together, on
   ``[H_kv, R, P * block_size]`` scores.
+- A PRODUCT A HEAD AT EVERY COUNT OF ROWS, ONE PATH: ISSUE 43 asked for ONE
+  product an operand for ALL heads where a q tile has few rows (decode: a
+  block-diagonal query ``[H_kv * R, H_kv * hd]`` against the whole tile).
+  Timed on a v5e over the same lane-dense tile it made the call 2% to 11%
+  LONGER at heads of 128 (its value product computes every head's columns
+  for every row and keeps an ``H_kv``-th) and 14% to 18% shorter at heads
+  of 64 (two heads share each stationary tile), and end to end it did not
+  clear the rule set for a second path (PERF.md, PR 43; ROADMAP S5(b) keeps
+  the heads-of-64 reading). A prefill tile (128 x G rows) could never take
+  it: block-diagonal, its zeros would multiply the MXU's real work by
+  ``H_kv``, and hundreds of rows already pay for a head's stationary tile.
 - GQA COMPACTION: queries reshape ``[B, S, H_q, hd] → [B, H_kv, S*G, hd]``
   (``G = H_q // H_kv``; met so far: 1, 4, 6, 7, 8, 128); a head step takes
   the whole query group against the SHARED KV tile with one dot, so GQA is a
@@ -151,13 +167,19 @@ def _vmem_bytes(shape, dtype) -> int:
     )
 
 
-# Tokens of context a compute block aims at: a whole lane tile of scores
-# for each MXU pass, and two where the q tile has a whole MXU pass of rows
-# or more. Measured on a v5e (PERF.md, PR 27): a tile of few rows (decode,
-# verify) is bound by the block's K/V tiles, and a longer block only adds
-# masked columns past the frontier; a tile of many rows pays for rescaling
-# its [R, hd] accumulator once a block, and wants fewer blocks.
+# What a compute block aims at. A tile of FEW rows (decode, verify) pays a
+# fixed ~550 cycles a block for its chain (products -> max -> exp2 -> sum ->
+# products -> accumulator), whatever the block holds, and each grid step's
+# first block is copied with nothing to compute beside it: measured on a
+# v5e over seven shapes (PERF.md, PR 43), the call is shortest where a
+# block's K tile (V's the same) is about half a MiB: 512 tokens of a
+# 1 KB row, 256 of a 2 KB row, and never fewer than a whole lane tile of
+# scores, 128 (a 8 KB row). A tile of many rows (a whole MXU pass or more:
+# prefill) pays for rescaling its [R, hd] accumulator once a block and keeps
+# the two lane tiles of scores a block it has had since PR 27.
 _BLOCK_TOKENS = 128
+_BLOCK_TOKENS_MOST = 512
+_BLOCK_BYTES = 512 * 1024
 _MANY_ROWS = 128
 # What a call may count on in VMEM (a v5e core has 128 MiB; the limit
 # handed to the compiler is twice the count, for what it spills); its
@@ -166,38 +188,41 @@ _VMEM_CAP = 48 * 1024 * 1024
 _VMEM_DEFAULT = 16 * 1024 * 1024
 
 
-def _whole_tiles(Hkv: int, hd: int) -> bool:
-    """Whether a page's ``[Hkv, hd]`` is made of whole (8, 128) tiles: XLA
-    then keeps a pool ``[.., Hkv, hd]`` at rest in the order written, and
-    the kernel copies its pages where they stand. A test of the shape, not
-    of a model."""
-    return hd % 128 == 0 and Hkv % 8 == 0
-
-
 def pool_shape(n_layer: int, num_blocks: int, block_size: int, Hkv: int,
-               hd: int, tp: int = 1) -> tuple[int, ...]:
+               hd: int) -> tuple[int, ...]:
     """The shape a K or V pool is STORED in (serve/llm/kv_cache.py
-    allocates it, ``ShardedExecutor`` for its ``tp``). Where the ``[Hkv //
-    tp, hd]`` one device holds is whole tiles: ``[.., Hkv, hd]``. Where it
-    is not (heads of 64, 12 heads, 4 heads of 128, a ``tp`` shard's 2) the
-    runtime would rest such a minor pair in another order than written and
-    relay K and V around every kernel call (PERF.md, PR 27, 29), so a token's
-    heads are ONE lane-dense row: ``[n_layer, num_blocks, block_size, Hkv *
-    hd]``, whole tiles again and nothing padded (a ``tp`` mesh splits the
-    row into contiguous heads a device)."""
-    lead = (n_layer, num_blocks, block_size)
-    if _whole_tiles(Hkv // tp, hd):
-        return (*lead, Hkv, hd)
-    return (*lead, Hkv * hd)
+    allocates it): a token's heads ONE lane-dense row, ``[n_layer,
+    num_blocks, block_size, Hkv * hd]``, for every count and size of heads.
+    A page is then whole (8, 128) tiles wherever the row is whole lanes, rests
+    in the order written and is copied where it stands (a minor pair ``[12,
+    64]`` or ``[4, 128]`` would rest in another order and be relaid around
+    every kernel call: PERF.md, PR 27, 29), and a compute block of it is the
+    ``[tokens, Hkv * hd]`` tile the kernel multiplies as it lies, a head's
+    keys a static lane slice of it (PR 43: a head's tile out of a block
+    stored BY HEADS, ``[tokens, Hkv, hd]``, was a strided read that cost
+    three times the products). A ``tp`` mesh splits the row into contiguous
+    heads a device."""
+    return (n_layer, num_blocks, block_size, Hkv * hd)
 
 
-def _fit_pages(need, bs: int, R: int, NB: int):
+def _block_tokens(R: int, row_bytes: int | None = None) -> int:
+    """The tokens a compute block aims at, for a q tile of ``R`` rows a
+    head over rows of ``row_bytes`` a token (see ``_BLOCK_BYTES``; None, the
+    latent planes': the lane tile of scores, two for a tile of many rows)."""
+    if R >= _MANY_ROWS:
+        return 2 * _BLOCK_TOKENS
+    tokens = _BLOCK_TOKENS
+    while (row_bytes and tokens < _BLOCK_TOKENS_MOST
+           and 2 * tokens * row_bytes <= _BLOCK_BYTES):
+        tokens *= 2
+    return tokens
+
+
+def _fit_pages(need, bs: int, tokens: int, NB: int):
     """``(P, need(P))``: the largest power of two of pages whose ``P *
-    bs`` tokens stay within what a block aims at (``_BLOCK_TOKENS``, twice
-    that for a tile of many rows), that a table of ``NB`` entries is wide
-    enough for, and whose ``need(P)`` bytes of VMEM fit; a page a block is
-    the floor."""
-    tokens = _BLOCK_TOKENS * (2 if R >= _MANY_ROWS else 1)
+    bs`` tokens stay within the ``tokens`` a block aims at, that a table of
+    ``NB`` entries is wide enough for, and whose ``need(P)`` bytes of VMEM
+    fit; a page a block is the floor."""
     P = 1
     while (
         2 * P * bs <= tokens and 2 * P <= NB and need(2 * P) <= _VMEM_CAP
@@ -209,12 +234,12 @@ def _fit_pages(need, bs: int, R: int, NB: int):
 def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
     """``(P, vmem_bytes)``: the pages of one compute block and what the
     call then keeps in VMEM, from the shapes alone (``page`` is a page's
-    shape in the pool, tokens first). P is the largest power of two whose
+    shape in the pool, ``[bs, row]``). P is the largest power of two whose
     ``P * bs`` tokens stay within what a block aims at, that the table is
     wide enough for, and whose two-slot K/V scratch fits beside the q and
     out tiles, the positions, the accumulators and a block's scores; a
     page a block (P = 1) is the floor."""
-    bs = page[0]
+    bs, row = page
     fixed = (
         # q and out tiles, double-buffered by the pipeline; positions
         4 * _vmem_bytes((Hkv, R, hd), q_dtype)
@@ -229,13 +254,14 @@ def _compute_block(page, Hkv, hd, R, NB, q_dtype, kv_dtype, quantized):
 
     def need(p):
         # two slots of K and V pages; a block's scores and probabilities
-        # for every head, its K and V tiles as values
+        # for every head, its K and V tiles and its value product as values
         return fixed + 4 * p * _vmem_bytes(page, kv_dtype) + 2 * (
             _vmem_bytes((Hkv, R, p * bs), jnp.float32)
-            + Hkv * _vmem_bytes((p * bs, hd), jnp.float32)
-        )
+            + _vmem_bytes((p * bs, row), jnp.float32)
+        ) + _vmem_bytes((Hkv, R, hd), jnp.float32)
 
-    return _fit_pages(need, bs, R, NB)
+    row_bytes = row * jnp.dtype(kv_dtype).itemsize
+    return _fit_pages(need, bs, _block_tokens(R, row_bytes), NB)
 
 
 def _kernel_name(window: int | None) -> str:
@@ -258,16 +284,16 @@ def _paged_attention_kernel(
     q_ref,        # [1, Hkv, R, hd] — this (b, q-block)'s rows for every kv
                   # head, pre-scaled; row r = query (r // G) of the block,
                   # group member (r % G); R = q_block * G
-    pos_ref,      # [1, R, 1] int32 — true position of each row's query
+    pos_ref,      # [1, rows, 1] int32 — true position of each row's query
     *rest,        # quantized: (ks_ref, vs_ref, k_hbm, v_hbm, o_ref, ...) —
                   # ks/vs [1, n_blocks, Hkv, T] f32, this row's per-(head,
                   # token) scales, gathered through its table; else (k_hbm,
                   # v_hbm, o_ref, ...). Then the scratch: (k_buf, v_buf,
                   # sems, m, l, acc).
                   # k_hbm / v_hbm: the whole pool, every layer, in HBM,
-                  # a page [bs, Hkv, hd] at [layer, id], or lane-dense
-                  # [bs, Hkv * hd]; k_buf / v_buf: the two-slot VMEM
-                  # scratch of a block, [2, P * bs, *page[1:]]
+                  # a page [bs, Hkv * hd] at [layer, id] (a latent plane's
+                  # [bs, width]); k_buf / v_buf: the two-slot VMEM scratch
+                  # of a block, [2, P * bs, row]
     block_size: int,
     pages: int,
     window: int | None,
@@ -328,12 +354,9 @@ def _paged_attention_kernel(
         )
 
     def head(buf, slot, h):
-        # one head's [T, hd] tile out of the block's pages: of a
-        # lane-dense page a static lane slice
-        if len(buf.shape) == 3:
-            x = buf[slot, :, h * hd:(h + 1) * hd]
-        else:
-            x = buf[slot, :, h, :]
+        # head h's [T, hd] out of the block's [T, Hkv * hd] tile as it
+        # lies: a static lane slice
+        x = buf[slot, :, h * hd:(h + 1) * hd]
         return x.astype(jnp.float32) if quantized else x
 
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
@@ -357,7 +380,7 @@ def _paged_attention_kernel(
         slot = lax.rem(i, 2)
         copies(i, "wait")
         # per-ROW causal mask, shared by every head of the block
-        pos_rows = pos_ref[0]                              # [R, 1]
+        pos_rows = pos_ref[0]                              # [rows, 1]
         t = i * T + lax.broadcasted_iota(jnp.int32, (rows, T), 1)
         mask = t <= pos_rows
         if window is not None:
@@ -540,16 +563,15 @@ def paged_prefill_attention_pallas(
     # the heads are read off the operands: q's head size and the pool's
     # row, ``[.., Hkv, hd]`` or lane-dense ``[.., Hkv * hd]``
     Hkv = math.prod(k_data.shape[3:]) // hd
-    if k_data.ndim == 5 and not _whole_tiles(Hkv, hd):
-        # Mosaic copies a page out of HBM only at whole tiles. The cache
-        # manager stores such a pool lane-dense (``pool_shape``); one
-        # handed in by heads (a test's own array) is VIEWED so, which on
-        # the chip is a relayout of it.
+    if k_data.ndim == 5:
+        # The cache manager stores every pool lane-dense (``pool_shape``);
+        # one handed in by heads (a test's own array) is VIEWED so, which
+        # on the chip is a relayout of it.
         k_data, v_data = (
             a.reshape(*a.shape[:3], Hkv * hd) for a in (k_data, v_data))
     page_layer = layer
-    if k_data.ndim == 4 and (Hkv * hd) % 128:
-        # ... and a lane-dense row only at whole lanes: a row that is not
+    if (Hkv * hd) % 128:
+        # Mosaic copies a row out of HBM only at whole lanes: a row that is not
         # (an odd count of heads of 64: GPT-2's 12 over tp = 4; a test's
         # small heads) is padded to them, ONE layer's slab a call (what the
         # compiler said: "Slice shape along dimension 3 must be aligned to
@@ -632,8 +654,8 @@ def paged_prefill_attention_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, *page[1:]), k_data.dtype),
-            pltpu.VMEM((2, pages * bs, *page[1:]), v_data.dtype),
+            pltpu.VMEM((2, pages * bs, page[1]), k_data.dtype),
+            pltpu.VMEM((2, pages * bs, page[1]), v_data.dtype),
             # one DMA semaphore a slot, shared by the slot's copies
             pltpu.SemaphoreType.DMA((2,)),
             # running max and sum: a column a head
@@ -828,7 +850,7 @@ def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
         ) + 2 * _vmem_bytes((R, p * bs), jnp.float32) + _vmem_bytes(
             (R, Cp), jnp.float32)
 
-    return _fit_pages(need, bs, R, NB)
+    return _fit_pages(need, bs, _block_tokens(R), NB)
 
 
 def paged_latent_attention_pallas(

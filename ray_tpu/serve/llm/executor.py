@@ -776,8 +776,7 @@ class ModelExecutor:
                 "arrays": {k: list(v.shape)
                            for k, v in self.cache.state.items()},
             }
-        # the shape the pool is STORED in says which layout a run ran: by
-        # heads, or lane-dense (kv_cache.py)
+        # the shape the pool is STORED in (lane-dense: kv_cache.py)
         report = {"kv_layers": cfg.n_layer,
                   "kv_pool_shape": list(self.cache.k.shape), "state": state,
                   "prefix_reuse": cfg.prefix_reuse,
@@ -894,10 +893,9 @@ class ShardedExecutor(ModelExecutor):
       DEFAULT_RULES — heads/mlp/vocab shard over tp (Megatron), embed
       over fsdp (ZeRO-3); exactly the layout the training side proves.
     - paged KV pool: ``cache.k``/``cache.v``
-      ([layer, block, slot, kv_head, head_dim], or lane-dense [layer,
-      block, slot, kv_head * head_dim] where a device's heads are not
-      whole tiles) shard along the KV-HEAD axis, a lane-dense row into
-      contiguous heads, over tp and replicate over fsdp. Block granularity, tables,
+      (lane-dense, [layer, block, slot, kv_head * head_dim]) shard along
+      the KV-HEAD axis, the row into contiguous heads a device, over tp
+      and replicate over fsdp. Block granularity, tables,
       prefix hashes, COW and quarantine bookkeeping stay host-side in
       kv_cache.py, byte-for-byte the single-chip code.
 
@@ -954,12 +952,9 @@ class ShardedExecutor(ModelExecutor):
         # the committed shards and keeps their sharding.
         self._maybe_quantize_params()
         self._store_compute_dtype()
-        # A device holds n_kv / tp heads: the pools are stored as THAT
-        # page asks (kv_cache.py ``stored_for``). Axis 3 is then the tp
-        # shard axis of every leaf: the KV heads of a pool by heads and of
-        # a quantized pool's scale plane, a lane-dense pool's row of heads
-        # (contiguous heads a device) — one spec serves them all.
-        cache.stored_for(tp_size)
+        # Axis 3 is the tp shard axis of every leaf: a pool's lane-dense
+        # row of heads (contiguous heads a device) and the KV heads of a
+        # quantized pool's scale plane — one spec serves them all.
         kv_spec = PartitionSpec(None, None, None, AxisNames.TENSOR)
         sh = NamedSharding(self.mesh, kv_spec)
         cache.k = jax.tree.map(lambda a: jax.device_put(a, sh), cache.k)

@@ -2,19 +2,15 @@
 + block-granular prefix cache (content-addressed blocks, COW, LRU evict).
 
 vLLM-style paging (PAPERS.md: serving Gemma on Cloud TPU uses the same
-structure): the cache is ONE preallocated array pair per model —
-
-    k, v: [n_layer, num_blocks, block_size, n_kv_head, head_dim]
-
-— or, where the ``[n_kv_head, head_dim]`` one device holds is not whole
-(8, 128) tiles (heads of 64, 12 heads, a ``tp`` shard's 2), with a token's
-heads as one lane-dense row,
+structure): the cache is ONE preallocated array pair per model, a token's
+heads one lane-dense row, whatever their count and size —
 
     k, v: [n_layer, num_blocks, block_size, n_kv_head * head_dim]
 
 (ops/paged_attention.py ``pool_shape``: the order such a pool rests in on
-the chip is then the order written, and the step programs read and write
-it where it stands; ``stored_for`` re-lays the pools for a ``tp`` mesh).
+the chip is the order written, the step programs read and write it where
+it stands, and a block of it is the tile the kernel multiplies as it
+lies; a ``tp`` mesh splits the row into contiguous heads a device).
 The stored shape stays on the device: what leaves the pool for the host
 (export, the host tier, the RTKV wire) is by heads, byte for byte. Sequences
 own logical-position-ordered lists of physical block ids.
@@ -602,28 +598,14 @@ class PagedKVCache:
 
     # ---------------- the pools' stored shape ----------------
 
-    def pool_shape(self, tp: int = 1) -> tuple[int, ...]:
-        """The shape ``k`` / ``v`` (a quantized pool's data) are stored in
-        where a ``tp`` mesh splits the heads: by heads, or lane-dense
-        (ops/paged_attention.py ``pool_shape``)."""
+    def pool_shape(self) -> tuple[int, ...]:
+        """The shape ``k`` / ``v`` (a quantized pool's data) are stored in:
+        lane-dense (ops/paged_attention.py ``pool_shape``)."""
         from ray_tpu.ops.paged_attention import pool_shape
 
         cfg = self.cfg
         return pool_shape(cfg.n_layer, cfg.num_blocks, cfg.block_size,
-                          cfg.n_kv_head, cfg.head_dim, tp)
-
-    def stored_for(self, tp: int) -> None:
-        """Re-lay the pools as a ``tp`` mesh holds them (``ShardedExecutor``,
-        before it places them): fewer heads a device can only turn a pool
-        by heads into a lane-dense one, a reshape of the same bytes. The
-        scale planes stay ``[.., block_size, n_kv_head]``."""
-        import jax
-
-        shape = self.pool_shape(tp)
-        # only a leaf laid by heads (5-D) has anything to re-lay
-        self.k, self.v = jax.tree.map(
-            lambda a: a.reshape(shape) if a.ndim == 5 else a,
-            (self.k, self.v))
+                          cfg.n_kv_head, cfg.head_dim)
 
     # ---------------- reservation (admission control) ----------------
 

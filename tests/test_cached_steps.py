@@ -224,8 +224,9 @@ def _walk_by_slabs(fam, x, layers, cache_k, cache_v, step, state, cfg):
     return x, *pools, state
 
 
-# pages of whole (8, 128) tiles are stored by heads, the tiny presets' pages
-# (2 heads of 16) lane-dense: ops/paged_attention.py ``pool_shape``
+# every page is stored lane-dense (ops/paged_attention.py ``pool_shape``):
+# the tiny presets' (2 heads of 16: a row of 32), and pages of 8 heads of
+# 128 (a row of 1,024: whole lanes, the cells' widths)
 TILES = {"gpt": {"n_head": 8, "d_model": 1024},
          "llama": {"n_head": 8, "n_kv_head": 8, "d_model": 1024},
          "lfm2_moe": {"n_head": 8, "n_kv_head": 8, "head_dim": 128}}
@@ -273,7 +274,7 @@ def test_step_leaves_the_pool_the_slab_walk_left(
         served = _Served(fam, cfg, params)
         served.k = _random_pool(rng, served.k.shape, served.n_kv, quant)
         served.v = _random_pool(rng, served.k.shape, served.n_kv, quant)
-        assert (served.k.ndim == 5) == (pages == "tiles")
+        assert served.k.ndim == 4
         with monkeypatch.context() as m:
             if walk is not None:
                 m.setattr(cached, "_walk", walk)

@@ -100,10 +100,10 @@ def test_paged_attention_compiles(one_chip, kind, shape, width, quant):
     B, NB, num_blocks, S = SHAPES[kind][shape]
     bs = 16
     S_ = functools.partial(_struct, sharding=one_chip)
-    # one layer of the pool as the cache manager stores it: by heads at the
-    # GQA width of 8 x 128, lane-dense at heads of 64 (16 x 768, 16 x 512)
+    # one layer of the pool as the cache manager stores it: lane-dense
+    # (16 x 1024 at the GQA width of 8 x 128, 16 x 768, 16 x 512)
     stored = pool_shape(1, num_blocks, bs, hkv, hd)[1:]
-    assert len(stored) == (4 if hd == 128 and hkv % 8 == 0 else 3)
+    assert stored == (num_blocks, bs, hkv * hd)
     if quant is None:
         pool = S_(stored, dtype)
     else:
@@ -265,7 +265,7 @@ def test_sharded_decode_step_compiles_partitioned(topo, monkeypatch):
     )
     B, bs, num_blocks, NB = 4, 16, 34 * 64 + 1, 64
     pool = _struct(
-        pool_shape(cfg.n_layer, num_blocks, bs, cfg.n_head, cfg.head_dim, 4),
+        pool_shape(cfg.n_layer, num_blocks, bs, cfg.n_head, cfg.head_dim),
         cfg.dtype, NamedSharding(mesh, P(None, None, None, "tp")),
     )
     assert pool.shape[3:] == (cfg.n_head * cfg.head_dim,)
@@ -361,10 +361,10 @@ def test_step_programs_update_the_pool_in_place(topo, monkeypatch, case):
     """ISSUE 29's and ISSUE 31's counter is a property of the compiled
     program. As ``DecodeFns`` compiles a step (pools donated, the step
     programs' own options), for the described v5e and the pool in the
-    shape the cache manager STORES it in (``pool_shape``: by heads where a
-    device's ``[Hkv, hd]`` is whole (8, 128) tiles, Mistral on one chip;
-    lane-dense where it is not: GPT-2's 12 heads of 64, lfm2's 8 of 64, a
-    tp = 4 shard's 2 or 3 heads): ``input_output_alias`` names ``cache_k``
+    shape the cache manager STORES it in (``pool_shape``: lane-dense for
+    every family since ISSUE 43: Mistral's 8 heads of 128, GPT-2's 12 of
+    64, lfm2's 8 of 64, a tp = 4 shard's 2 or 3 heads of its row):
+    ``input_output_alias`` names ``cache_k``
     and ``cache_v``, so no second pool exists; the pool parameters rest in
     the order written; NOTHING but the two scatter fusions, in place,
     produces as much as one layer's slab (no slice, no update, no relayout
@@ -418,9 +418,8 @@ def test_step_programs_update_the_pool_in_place(topo, monkeypatch, case):
     n_kv = getattr(cfg, "n_kv_head", cfg.n_head)
     n_layer = getattr(cfg, "n_kv_layer", cfg.n_layer)
     num_blocks, bs = 4097, 16
-    stored = pool_shape(n_layer, num_blocks, bs, n_kv, cfg.head_dim, tp)
-    whole = tp == 1 and cfg.head_dim % 128 == 0 and n_kv % 8 == 0
-    assert len(stored) == (5 if whole else 4), stored
+    stored = pool_shape(n_layer, num_blocks, bs, n_kv, cfg.head_dim)
+    assert stored == (n_layer, num_blocks, bs, n_kv * cfg.head_dim)
     pool = _struct(stored, cfg.dtype,
                    NamedSharding(mesh, P(None, None, None, "tp")))
     S = min(2048, cfg.max_seq_len)
@@ -681,7 +680,7 @@ def test_laguna_decode_step_compiles_at_published_widths(
         lambda: init(jax.random.PRNGKey(0), cfg)))
     state = jax.tree.map(on_chip, jax.eval_shape(
         lambda: laguna_init_state(cfg, 65)))
-    pool = _struct((cfg.n_kv_layer, 32769, 16, cfg.n_kv_head, cfg.head_dim),
+    pool = _struct((cfg.n_kv_layer, 32769, 16, cfg.n_kv_head * cfg.head_dim),
                    cfg.dtype, one_chip)
     i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
     B, groups = 64, len(cfg.kv_table_groups)
@@ -727,7 +726,7 @@ def test_evabyte_step_programs_compile_at_published_widths(
     through the K/V write, the summaries' read-back and write and the
     kernel's read: nothing pool-sized is among the temporaries. The one
     scanned layer calls ``paged_attention`` at a GQA group of 1 over a
-    pool by heads, and the chunk summaries are the ``eva_summarize`` kernel
+    lane-dense pool (rows of 4,096), and the chunk summaries are the ``eva_summarize`` kernel
     under its own name, which is how a trace finds their time inside a
     scanned stack."""
     import sys
@@ -756,7 +755,7 @@ def test_evabyte_step_programs_compile_at_published_widths(
     pool = _struct(pool_shape(cfg.n_layer, traffic["num_blocks"], 16,
                               cfg.n_kv_head, cfg.head_dim), cfg.dtype,
                    one_chip)
-    assert pool.shape == (8, 4353, 16, 32, 128)  # by heads: whole tiles
+    assert pool.shape == (8, 4353, 16, 4096)  # a token's 32 heads a row
     i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
     fns = decode.DecodeFns("evabyte", cfg, platform="tpu")
     if kind == "decode":
@@ -1004,29 +1003,30 @@ def test_other_families_programs_are_what_their_functions_compile_to(
     assert not re.search(r"%(state|slots)[\w.]* = \S+ parameter\(", through)
 
 
-# ISSUE 31's control: what the three cells whose pools are whole tiles run.
-# sha256[:16] of the text taken on PR 31's PARENT (bd7d1f9) with jax 0.9.0's
-# compiler for a described v5e: of a compiled program less what names the
-# caller and less the Mosaic kernel's serialized body (it carries source
-# lines), and of the kernel's own jaxpr less its source location.
+# What the cells' step programs and the kernel compile to: sha256[:16] of the
+# text under jax 0.9.0's compiler for a described v5e: of a compiled program
+# less what names the caller and less the Mosaic kernel's serialized body (it
+# carries source lines), and of the kernel's own jaxpr less its source
+# location. ISSUE 43 moved every program that holds the K/V kernel, and the
+# texts below are ITS tree's (taken on PR 43): Mistral's, laguna's and
+# EvaByte's through the stored layout (lane-dense where it was by heads: the
+# kernel's pages, the scatter's rows) and through the block a few-row tile
+# aims at; GPT-2's and lfm2's decode through the block alone. What ISSUE 43
+# did NOT touch keeps the text its own PR recorded, and
+# says so here: ``pangu-decode`` (the latent branch and its planes: ISSUE
+# 41's parent d31556f), and the trainer's flash kernels
+# (``test_flash_kernel_names_reach_the_lowered_text``).
 PARENTS_TEXT = {
-    "mistral-decode": "7cc8c17244f39daf",
-    "mistral-prefill": "afa567a16125eca1",
-    "laguna-decode": "4c2a361272621cf6",
-    "kernel-decode": "8d9dbd2616788784",
-    "kernel-prefill": "e0f7ebc42536e563",
-    "kernel-window": "b1756db2466556f4",
-    # ISSUE 32's control, taken on PR 32's PARENT (dae2ee5): the decode
-    # programs of the two families whose pools rest lane-dense
-    "gpt2-decode": "7071bf766b22d625",
-    "lfm2-decode": "097b42605a3fe691",
-    # ISSUE 39's control, taken on PR 39's PARENT (378095c): the fifth
-    # served family's decode program, composed tables [2, 24, 192]
-    "evabyte-decode": "661e55a08e3049ed",
-    # ISSUE 41's control, taken on PR 41's PARENT (d31556f): ``moe_route``
-    # and ``moe_dropless`` gained options whose defaults the three expert
-    # families above and below run; the sixth family's decode program,
-    # planes [5, 40961, 16, 512 | 128], table [128, 768]
+    "mistral-decode": "bc061138abf9994d",
+    "mistral-prefill": "b496beb461d9093b",
+    "laguna-decode": "fb6e307deb35fcc3",
+    "kernel-decode": "0a60dc1dcd26269e",
+    "kernel-prefill": "3eaf0087f777f8e6",
+    "kernel-window": "fb1235e986671dce",
+    "gpt2-decode": "ae8d5f0c0d8e2416",
+    "lfm2-decode": "1f57451824aeb915",
+    "evabyte-decode": "6735ace882751da0",
+    # untouched by ISSUE 43: planes [5, 40961, 16, 512 | 128], table [128, 768]
     "pangu-decode": "cb7ad74bb4c0b8da",
 }
 PARENTS_JAX = "0.9.0"
@@ -1042,58 +1042,23 @@ def _program_text_sha(text):
     return _sha(re.sub(r'"body":"[^"]*"', '"body":""', _body(text)))
 
 
-@pytest.mark.parametrize("case", sorted(PARENTS_TEXT))
-def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
-                                                    case):
-    """Pools of whole (8, 128) tiles (Mistral's and laguna's 8 heads of
-    128) keep their shape, their kernel body and their programs' TEXT
-    through ISSUE 31: the cells' Mistral decode and prefill programs and
-    laguna's decode program compile to what the parent compiled, and the
-    kernel's jaxpr at their shapes (decode, prefill, windowed decode) is
-    the parent's. So cells 1, 3 and 6 cannot move. A golden text holds for
-    one compiler: another jax skips."""
+def _cell_program(which, kind, S_):
+    """``(jitted step, args, kwargs)``: a cell's decode program (Mistral's
+    prefill too) as ``DecodeFns`` builds it for the chip, over the cell's
+    own shapes described on one chip."""
     import sys
 
     import jax
     import jax.numpy as jnp
 
-    if jax.__version__ != PARENTS_JAX:
-        pytest.skip(f"the parent's text was taken under jax {PARENTS_JAX}")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
     from benchmark import common
-    from ray_tpu.ops.paged_attention import (
-        paged_attention_pallas, paged_prefill_attention_pallas, pool_shape,
-    )
+    from ray_tpu.ops.paged_attention import pool_shape
     from ray_tpu.serve.llm import decode
 
-    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
-    S_ = functools.partial(_struct, sharding=one_chip)
     i32 = functools.partial(S_, dtype=jnp.int32)
-    which, kind = case.split("-")
-    if which == "kernel":
-        pool = S_(pool_shape(6, 4097, 16, 8, 128), jnp.bfloat16)
-        assert len(pool.shape) == 5
-        q = lambda *shape: S_(shape, jnp.bfloat16)
-        fn, args = {
-            "decode": (paged_attention_pallas,
-                       (q(64, 32, 128), pool, pool, i32((64, 160)),
-                        i32((64,)))),
-            "prefill": (paged_prefill_attention_pallas,
-                        (q(4, 2048, 32, 128), pool, pool, i32((4, 128)),
-                         i32((4, 2048)))),
-            "window": (functools.partial(paged_prefill_attention_pallas,
-                                         window=512),
-                       (q(64, 1, 48, 128), pool, pool, i32((64, 1152)),
-                        i32((64, 1)))),
-        }[kind]
-        jaxpr = str(jax.make_jaxpr(functools.partial(
-            fn, interpret=False, layer=1))(*args))
-        assert "pallas_call" in jaxpr
-        assert _sha(re.sub(r" at [^\s:]+:\d+", "", jaxpr)) \
-            == PARENTS_TEXT[case]
-        return
     config = {"mistral": "mistral-7b-v0.3-6l", "gpt2": "gpt2-small",
               "lfm2": "lfm2-24b-a2b-8l",
               "laguna": "laguna-xs.2-ep8-8l",
@@ -1133,21 +1098,114 @@ def test_whole_tile_pools_keep_the_parents_programs(one_chip, monkeypatch,
             getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,
             getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim),
             cfg.dtype)
-        # by heads, as PR 31's parent stored it, where the heads are of 128
-        assert len(pool.shape) == (5 if cfg.head_dim == 128 else 4)
+        assert len(pool.shape) == 4  # one stored layout
         pools = [pool, pool]
     fns = decode.DecodeFns(held["family"], cfg, platform="tpu")
     if kind == "decode":
         rows = tables[-2]
-        lowered = fns._decode.lower(
-            params, *pools, i32((rows,)), i32((rows,)), i32(tables),
-            sample=None, **more)
-    else:
-        lowered = fns._prefill.lower(
-            params, *pools, i32((4, 2048)), i32((4,)), i32((4, 128)),
-            sample=None)
+        return fns._decode, (
+            params, *pools, i32((rows,)), i32((rows,)), i32(tables)), {
+                "sample": None, **more}
+    return fns._prefill, (
+        params, *pools, i32((4, 2048)), i32((4,)), i32((4, 128))), {
+            "sample": None}
+
+
+@pytest.mark.parametrize("case", sorted(PARENTS_TEXT))
+def test_step_programs_compile_to_the_recorded_text(one_chip, monkeypatch,
+                                                    case):
+    """A PR that means to leave a cell's programs alone can see that it
+    did: the cells' decode programs (and Mistral's prefill) compile to the
+    text recorded above, and the kernel's jaxpr at Mistral's and laguna's
+    shapes (decode, prefill, windowed decode) is the recorded one. A PR
+    that moves the kernel (ISSUE 43 did) records the new texts and says
+    which it left. A golden text holds for one compiler: another jax
+    skips."""
+    import jax
+    import jax.numpy as jnp
+
+    if jax.__version__ != PARENTS_JAX:
+        pytest.skip(f"the texts were taken under jax {PARENTS_JAX}")
+    from ray_tpu.ops.paged_attention import (
+        paged_attention_pallas, paged_prefill_attention_pallas, pool_shape,
+    )
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    S_ = functools.partial(_struct, sharding=one_chip)
+    i32 = functools.partial(S_, dtype=jnp.int32)
+    which, kind = case.split("-")
+    if which == "kernel":
+        pool = S_(pool_shape(6, 4097, 16, 8, 128), jnp.bfloat16)
+        assert pool.shape == (6, 4097, 16, 1024)
+        q = lambda *shape: S_(shape, jnp.bfloat16)
+        fn, args = {
+            "decode": (paged_attention_pallas,
+                       (q(64, 32, 128), pool, pool, i32((64, 160)),
+                        i32((64,)))),
+            "prefill": (paged_prefill_attention_pallas,
+                        (q(4, 2048, 32, 128), pool, pool, i32((4, 128)),
+                         i32((4, 2048)))),
+            "window": (functools.partial(paged_prefill_attention_pallas,
+                                         window=512),
+                       (q(64, 1, 48, 128), pool, pool, i32((64, 1152)),
+                        i32((64, 1)))),
+        }[kind]
+        jaxpr = str(jax.make_jaxpr(functools.partial(
+            fn, interpret=False, layer=1))(*args))
+        assert "pallas_call" in jaxpr
+        assert _sha(re.sub(r" at [^\s:]+:\d+", "", jaxpr)) \
+            == PARENTS_TEXT[case]
+        return
+    jitted, args, kwargs = _cell_program(which, kind, S_)
+    lowered = jitted.lower(*args, **kwargs)
     got = _program_text_sha(lowered.compile().as_text())
     assert got == PARENTS_TEXT[case], got
+
+
+def _kernel_products(jaxpr):
+    """The count of ``dot_general`` in the body of every ``pallas_call`` of
+    a traced program, one entry a call (a scanned stack's one layer once)."""
+    calls = []
+
+    def walk(j, inside):
+        n = 0
+        for eqn in j.eqns:
+            n += inside and eqn.primitive.name == "dot_general"
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if not hasattr(sub, "eqns"):
+                        continue
+                    if eqn.primitive.name == "pallas_call":
+                        calls.append(walk(sub, True))
+                    else:
+                        n += walk(sub, inside)
+        return n
+
+    walk(jaxpr.jaxpr, False)
+    return calls
+
+
+# ONE path: a step program's paged kernel calls hold a score and a value
+# product for each K/V head of the lane-dense tile, whatever the head's size
+# (read off the traced program; a scanned stack's one layer once). ISSUE 43
+# tried ONE product an operand for all heads: over the same tile it was 2-11%
+# slower at heads of 128 and 14-18% faster at heads of 64, kernel alone, and
+# end to end it did not clear the rule set for a second path (PERF.md PR 43).
+DECODE_PRODUCTS = {"mistral": 8, "evabyte": 32, "laguna": 8, "gpt2": 12,
+                   "lfm2": 8}
+
+
+@pytest.mark.parametrize("which", sorted(DECODE_PRODUCTS))
+def test_decode_programs_hold_a_product_a_head(one_chip, monkeypatch, which):
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    jitted, args, kwargs = _cell_program(
+        which, "decode", functools.partial(_struct, sharding=one_chip))
+    calls = _kernel_products(jitted.trace(*args, **kwargs).jaxpr)
+    paged = [n for n in calls if n]
+    assert paged, calls
+    assert set(paged) == {2 * DECODE_PRODUCTS[which]}, calls
 
 
 @pytest.mark.parametrize(

@@ -1,8 +1,8 @@
-"""Where the K/V pool rests (ISSUE 31), at the engine: a pool whose ``[Hkv,
-hd]`` is not whole (8, 128) tiles is stored lane-dense, ``[n_layer,
-num_blocks, block_size, Hkv * hd]``, and nothing outside the device sees
-it (CPU, float32, tiny widths with heads of 64; the Pallas backend in the
-interpreter).
+"""Where the K/V pool rests (ISSUE 31, ISSUE 43), at the engine: EVERY
+pool is stored lane-dense, ``[n_layer, num_blocks, block_size, Hkv * hd]``
+(8 heads of 128 and 32 of 128 too, since ISSUE 43: one stored layout), and
+nothing outside the device sees it (CPU, float32, tiny widths with heads of
+64; the Pallas backend in the interpreter).
 
 Per family: greedy streams identical under both attention backends over
 the lane-dense pool; a block exported from it is byte-identical on the RTKV
@@ -151,3 +151,73 @@ def test_exported_blocks_are_the_same_bytes_whatever_the_pool(
     assert lane.executor.export_blocks([])[0].shape[2:] == k_new.shape[2:]
     for eng in (lane, heads):
         eng.shutdown()
+
+
+# every family the engine serves, with what keeps its tiny engine small
+# (tests/test_serve_llm_packed_prefill.py ``ROWS``)
+FAMILIES = {
+    "gpt": dict(num_blocks=33),
+    "llama": dict(num_blocks=33),
+    "lfm2_moe": dict(num_blocks=65),
+    "laguna": dict(block_size=4, num_blocks=129, prefill_chunk_tokens=16,
+                   length_buckets=(16, 32, 64, 128)),
+    "evabyte": dict(block_size=4, num_blocks=257, prefill_chunk_tokens=16,
+                    length_buckets=(16, 160)),
+    "smallthinker": dict(block_size=4, num_blocks=129,
+                         prefill_chunk_tokens=16,
+                         length_buckets=(16, 32, 64, 128)),
+    "pangu_ultra_moe": dict(block_size=4, num_blocks=129,
+                            prefill_chunk_tokens=16,
+                            length_buckets=(16, 32, 64, 128)),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_every_familys_pool_is_lane_dense(jax_cpu, family):
+    """One stored layout: a token's heads one row in every family's pool
+    (a latent family's planes are rows already), and ``describe()`` says
+    so."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    eng = LLMEngine(EngineConfig(model=family, max_batch_size=4,
+                                 **FAMILIES[family]), auto_step=False)
+    cache = eng.cache.cfg
+    described = eng.executor.describe()
+    assert described["kv_pool_shape"] == list(eng.cache.k.shape)
+    assert eng.cache.k.ndim == eng.cache.v.ndim == 4
+    if cache.planes:
+        assert eng.cache.k.shape[3] == cache.planes[0][2]
+    else:
+        assert eng.cache.k.shape == (
+            cache.n_layer, cache.num_blocks, cache.block_size,
+            cache.n_kv_head * cache.head_dim)
+    assert eng.stats()["executor"]["kv_pool_shape"] == list(eng.cache.k.shape)
+    eng.shutdown()
+
+
+# (K/V heads, head size): the benchmark's cells at published widths, then
+# ``tp`` = 4 shards of two of them and wide MHA rows
+@pytest.mark.parametrize("name,heads", [
+    ("mistral", (8, 128)),
+    ("gpt2", (12, 64)),
+    ("lfm2", (8, 64)),
+    ("laguna", (8, 128)),
+    ("evabyte", (32, 128)),
+    ("smallthinker", (4, 128)),
+    ("mistral-tp4-shard", (2, 128)),
+    ("gpt2-tp4-shard", (3, 64)),
+    ("mha-x128-of-64", (128, 64)),
+    ("mha-x160-of-64", (160, 64)),
+])
+def test_published_widths_store_one_row(name, heads):
+    """``pool_shape`` has one answer, a token's heads a row, and the cache
+    manager's pool is that shape."""
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm.kv_cache import KVCacheConfig, PagedKVCache
+
+    n_kv, hd = heads
+    assert pool_shape(6, 4097, 16, n_kv, hd) == (6, 4097, 16, n_kv * hd)
+    cache = PagedKVCache(KVCacheConfig(
+        n_layer=1, n_kv_head=n_kv, head_dim=hd, num_blocks=3, block_size=16))
+    assert cache.pool_shape() == (1, 3, 16, n_kv * hd)
+    assert cache.k.shape == cache.v.shape == cache.pool_shape()

@@ -588,7 +588,7 @@ def test_engine_on_the_kernel_path_matches_the_reference(jax_cpu, ref):
     engine = _engine(cfg, params, attention_backend="pallas", block_size=16,
                      num_blocks=65, prefill_chunk_tokens=32,
                      length_buckets=(32, 64, 128))
-    assert engine.cache.k.shape == (2, 65, 16, 8, 128)  # stored by heads
+    assert engine.cache.k.shape == (2, 65, 16, 1024)  # a token's heads a row
     prompts = _prompts([7, 45], seed=8)
     streams = [engine.submit(p, max_new_tokens=12, temperature=0.0)
                for p in prompts]

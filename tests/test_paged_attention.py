@@ -194,7 +194,7 @@ def _prefill_pool(key, lengths, Hkv, hd, bs, NB, shuffle, quant=None):
         )
     else:
         k_layer, v_layer = k_layer.reshape(stored), v_layer.reshape(stored)
-    assert (len(stored) == 4) == (Hkv % 8 == 0 and hd % 128 == 0)
+    assert stored == (num_blocks, bs, Hkv * hd)
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     valid = pos < jnp.asarray(lengths, jnp.int32)[:, None]
     k_layer, v_layer = write_kv(
@@ -453,7 +453,7 @@ def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
     import jax.numpy as jnp
     from ray_tpu.ops.kv_cache import paged_prefill_attention
     from ray_tpu.ops.paged_attention import (
-        _compute_block, paged_prefill_attention_pallas,
+        _block_tokens, _compute_block, paged_prefill_attention_pallas,
     )
 
     lengths, S, NB, pool_kw, kernel_kw = _BLOCK_EDGES[edge]
@@ -465,12 +465,21 @@ def test_kernel_compute_block_edges_match_xla(jax_cpu, edge):
         key, lengths, Hkv, hd, bs, NB, pool_kw.pop("shuffle", False),
         **pool_kw,
     )
-    # the block the case was built for is the one the function derives
+    # the block the case was built for is the one the function derives:
+    # the tokens a few-row tile aims at (128 over 8 heads of 128 in
+    # float32, a row of 4 KB; 512 over a quantized KB) in pages of 8, or
+    # what the table is wide enough for
+    data = k_layer.data if "quant" in pool_kw else k_layer
     P, _ = _compute_block(
-        k_layer.shape[1:], Hkv, hd, S * G, NB, jnp.float32, k_layer.dtype,
+        data.shape[1:], Hkv, hd, S * G, NB, jnp.float32, data.dtype,
         "quant" in pool_kw,
     )
-    assert P == min(16, 1 << (NB.bit_length() - 1)), (edge, P)
+    row_bytes = Hkv * hd * data.dtype.itemsize
+    aim = _block_tokens(S * G, row_bytes)
+    # the most tokens, a power of two, whose K tile is within half a MiB
+    most = 1 << ((512 * 1024 // row_bytes).bit_length() - 1)
+    assert aim == min(512, max(128, most)), (edge, aim)
+    assert P == min(aim // bs, 1 << (NB.bit_length() - 1)), (edge, P)
     starts = jnp.asarray([max(L - S, 0) for L in lengths], jnp.int32)
     positions = starts[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     positions = jnp.where(
@@ -572,30 +581,120 @@ def test_kernel_reads_the_whole_pool_at_a_layer(jax_cpu, edge):
             assert jnp.array_equal(now[2], was[2])
 
 
+# The kernel over the ONE stored layout against the XLA formulation: a
+# compute block is the lane-dense ``[tokens, Hkv * hd]`` tile and a head a
+# static lane slice of it, a score and a value product a head, whatever the
+# tile's rows: (Hkv, G, hd, chunk S, kernel kwargs, pool kwargs). The groups
+# are the cells' (1, 4, 6, 7, 8), the heads 64 and 128 (and 32, 96: a head
+# that is no whole lane tile, or half of one); every case has a ragged last
+# block and a padding row.
+_PRODUCTS = {
+    "g1-x32-hd128-evabyte": (32, 1, 128, 1, {}, {}),
+    "g4-x8-hd128-mistral": (8, 4, 128, 1, {}, {}),
+    "g6-x8-hd128-laguna-full": (8, 6, 128, 1, {}, {}),
+    "g7-x4-hd128-smallthinker": (4, 7, 128, 1, {}, {}),
+    "g8-x8-hd128-laguna-window": (
+        8, 8, 128, 1, {"window": 20}, {}),
+    "g7-x4-hd128-window-verify": (
+        4, 7, 128, 3, {"window": 37}, {"shuffle": True}),
+    "g4-x8-hd128-int8": (8, 4, 128, 1, {}, {"quant": "int8"}),
+    "g7-x4-hd128-fp8": (4, 7, 128, 1, {}, {"quant": "fp8"}),
+    "g1-x12-hd64-gpt2": (12, 1, 64, 1, {}, {}),
+    "g4-x8-hd64-lfm2": (8, 4, 64, 1, {}, {}),
+    "g6-x8-hd64": (8, 6, 64, 1, {}, {"shuffle": True}),
+    "g7-x4-hd64-window": (4, 7, 64, 1, {"window": 20}, {}),
+    "g8-x8-hd64-window-verify": (
+        8, 8, 64, 2, {"window": 37}, {"shuffle": True}),
+    "g4-x8-hd64-int8": (8, 4, 64, 1, {}, {"quant": "int8"}),
+    "g7-x4-hd64-fp8": (4, 7, 64, 1, {}, {"quant": "fp8"}),
+    "g1-x12-hd64-int8-verify": (
+        12, 1, 64, 5, {}, {"quant": "int8"}),
+    "g2-x2-hd32-tiny": (2, 2, 32, 1, {}, {}),
+    # all heads' rows together one MXU pass (8 x 16 = 128) and past it (136)
+    "edge-128-rows": (8, 4, 64, 4, {}, {"shuffle": True}),
+    "edge-136-rows": (8, 1, 64, 17, {}, {"shuffle": True}),
+    "edge-136-rows-int8": (
+        8, 1, 64, 17, {}, {"quant": "int8"}),
+    # a q tile of a prefill chunk: many rows a head
+    "g4-x8-hd64-chunk": (8, 4, 64, 40, {"q_block": 32}, {}),
+    # two q tiles of a chunk, each with its own frontier
+    "g2-x4-hd64-two-tiles": (4, 2, 64, 12, {"q_block": 8}, {}),
+    # a head that does not tile a lane tile
+    "hd-96-no-lane-tile": (2, 2, 96, 1, {}, {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PRODUCTS))
+def test_lane_dense_products_match_xla(jax_cpu, case):
+    """The kernel against the XLA formulation on the same lane-dense pool,
+    and its text: two products a K/V head a compute block, at every count
+    of rows."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.kv_cache import paged_prefill_attention
+    from ray_tpu.ops.paged_attention import paged_prefill_attention_pallas
+
+    Hkv, G, hd, S, kernel_kw, pool_kw = _PRODUCTS[case]
+    pool_kw = dict(pool_kw)
+    lengths, bs, NB = [140, 0, 250, 33], 8, 32
+    key = jax.random.PRNGKey(sum(map(ord, case)))
+    k_layer, v_layer, tables, _, _ = _prefill_pool(
+        key, lengths, Hkv, hd, bs, NB, pool_kw.pop("shuffle", False),
+        **pool_kw)
+    starts = jnp.asarray([max(L - S, 0) for L in lengths], jnp.int32)
+    positions = starts[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    positions = jnp.where(jnp.asarray(lengths)[:, None] > 0, positions, 0)
+    q = jax.random.normal(
+        jax.random.fold_in(key, 9), (len(lengths), S, Hkv * G, hd))
+
+    def kernel(q):
+        return paged_prefill_attention_pallas(
+            q, k_layer, v_layer, tables, positions, **kernel_kw)
+
+    text = str(jax.make_jaxpr(kernel)(q))
+    body = text[text.index("pallas_call"):]
+    assert body.count("dot_general") == 2 * Hkv
+    ref = paged_prefill_attention(
+        q, k_layer, v_layer, tables, positions,
+        window=kernel_kw.get("window"))
+    out = kernel(q)
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    live = jnp.asarray(lengths)[:, None, None, None] > 0
+    assert float(jnp.max(jnp.abs(jnp.where(live, out - ref, 0.0)))) < 2e-5
+
+
 # (page, Hkv, hd, R, NB, pool dtype, quantized) -> pages a compute block
 _BLOCK_CHOICES = {
-    # the cells' shapes: 16-token pages, a few rows (decode) or a q tile
-    # of 128 queries x G (prefill)
-    "gqa-decode": (((16, 8, 128), 8, 128, 4, 160, "bfloat16", False), 8),
-    "gqa-prefill": (((16, 8, 128), 8, 128, 512, 128, "bfloat16", False), 16),
-    "gqa-verify": (((16, 8, 128), 8, 128, 16, 160, "bfloat16", False), 8),
-    "f32-prefill": (((16, 8, 128), 8, 128, 128, 64, "float32", False), 16),
-    "int8-decode": (((16, 8, 128), 8, 128, 4, 160, "int8", True), 8),
+    # the cells' shapes: 16-token pages, a few rows (decode: a K tile of
+    # half a MiB a block, between 128 and 512 tokens) or a q tile of 128
+    # queries x G (prefill: 256 tokens)
+    "gqa-decode": (((16, 1024), 8, 128, 4, 160, "bfloat16", False), 16),
+    "gqa-prefill": (((16, 1024), 8, 128, 512, 128, "bfloat16", False), 16),
+    "gqa-verify": (((16, 1024), 8, 128, 16, 160, "bfloat16", False), 16),
+    "f32-prefill": (((16, 1024), 8, 128, 128, 64, "float32", False), 16),
+    "f32-decode": (((16, 1024), 8, 128, 4, 160, "float32", False), 8),
+    "int8-decode": (((16, 1024), 8, 128, 4, 160, "int8", True), 32),
     # a table narrower than a block
-    "one-entry-table": (((16, 8, 128), 8, 128, 4, 1, "bfloat16", False), 1),
-    "five-entry-table": (((16, 8, 128), 8, 128, 4, 5, "bfloat16", False), 4),
+    "one-entry-table": (((16, 1024), 8, 128, 4, 1, "bfloat16", False), 1),
+    "five-entry-table": (((16, 1024), 8, 128, 4, 5, "bfloat16", False), 4),
     # a page that is a block already
-    "page-of-128": (((128, 8, 128), 8, 128, 4, 64, "bfloat16", False), 1),
+    "page-of-128": (((128, 1024), 8, 128, 4, 64, "bfloat16", False), 2),
     "page-of-128-prefill": (
-        ((128, 8, 128), 8, 128, 512, 64, "bfloat16", False), 2
+        ((128, 1024), 8, 128, 512, 64, "bfloat16", False), 2
     ),
     # a q tile whose own buffers leave no room: the floor
-    "vmem-floor": (((16, 8, 128), 8, 128, 8192, 160, "float32", False), 1),
-    # lane-dense pages: the GPT-2 cell's (16 x 768) and lfm2's (16 x 512)
-    "gpt2-decode": (((16, 768), 12, 64, 1, 64, "bfloat16", False), 8),
+    "vmem-floor": (((16, 1024), 8, 128, 8192, 160, "float32", False), 1),
+    # the other cells' rows: GPT-2's (16 x 768), lfm2's and SmallThinker's
+    # (16 x 512), EvaByte's (16 x 4096: a lane tile of scores is the floor)
+    "gpt2-decode": (((16, 768), 12, 64, 1, 64, "bfloat16", False), 16),
     "gpt2-prefill": (((16, 768), 12, 64, 128, 64, "bfloat16", False), 16),
-    "lfm2-decode": (((16, 512), 8, 64, 4, 160, "bfloat16", False), 8),
+    "lfm2-decode": (((16, 512), 8, 64, 4, 160, "bfloat16", False), 32),
     "lfm2-int8-prefill": (((16, 512), 8, 64, 512, 128, "int8", True), 16),
+    "smallthinker-decode": (
+        ((16, 512), 4, 128, 7, 1024, "bfloat16", False), 32),
+    "evabyte-decode": (((16, 4096), 32, 128, 1, 192, "bfloat16", False), 8),
+    # a tp shard's narrow row stops at 512 tokens
+    "tp4-shard-decode": (((16, 256), 2, 128, 4, 160, "bfloat16", False), 32),
 }
 
 

@@ -104,7 +104,7 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               "weight_bytes": 4 * eng.executor.num_params,
                               "attention_backend": "xla",
                               "kv_layers": 2,
-                              # lane-dense: 2 heads of 16 are no tile
+                              # lane-dense: a token's 2 heads of 16 a row
                               "kv_pool_shape": [2, 64, 8, 32],
                               # K and V by head: 2 x 2 heads x 16 x 4 B
                               "kv_pool": {"kind": "heads", "row_bytes": 256,
