@@ -13,9 +13,9 @@ bf16; random weights from ``--seed``):
   first replica's process has exited — and the two sets of streams are
   compared under the device contract of docs/SERVING_LLM.md.
 - ``train``: ``JaxTrainer(..., ScalingConfig(num_workers=1, use_tpu=True))``
-  whose loop takes 10 steps of the benchmark's train step
-  (benchmarks/gpt_mfu.make_train_step) at bs 24 x seq 1,024 with the flash
-  kernels, on a fixed batch. The loss must be finite and fall.
+  whose loop takes 10 AdamW steps (jitted, parameters and optimizer state
+  donated) at bs 24 x seq 1,024 with the flash kernels, on a fixed batch.
+  The loss must be finite and fall.
 
 ``--chips 4`` runs the four-chip path and nothing else: the same engine
 with ``tp=4`` through ``ShardedExecutor`` and the single-chip engine it is
@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import subprocess
@@ -309,13 +310,24 @@ def train_loop(config: dict) -> None:
     import jax
     import jax.numpy as jnp
 
+    import optax
+
     from ray_tpu import train
     from ray_tpu._private.compile_cache import enable_compile_cache
-    from ray_tpu.benchmarks.gpt_mfu import make_train_step
+    from ray_tpu.models.gpt import gpt_init, gpt_loss
 
     cfg = config["model_config"]
     dev = jax.devices()[0]
-    step, params, opt_state = make_train_step(cfg)
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    tx = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    opt_state = tx.init(params)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(gpt_loss)(params, batch, cfg)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
     batch = {"tokens": jax.random.randint(
         jax.random.PRNGKey(config["seed"]),
         (config["bs"], config["seq"] + 1), 0, cfg.vocab_size, jnp.int32)}

@@ -1,6 +1,6 @@
 """The one place that decides where JAX's persistent compilation cache
 lives. Called once by each process that compiles: the serve.llm replica,
-the train worker, chip_smoke.py and bench.py.
+the train worker and chip_smoke.py.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own handling of it
 stands and no directory is set in code. Otherwise the cache is

@@ -110,7 +110,7 @@ def FoldedResNet(stage_sizes, num_classes: int = 1000,
     out-channel, b' = beta - mean * gamma/sqrt(var+eps)); consumes
     fold_batch_norm's params. Removes every BN read-modify-write pass from
     the serving graph — the conv epilogue is just bias+relu, which XLA
-    fuses into the convolution (VERDICT r3 #4: unfused BN is the ResNet
+    fuses into the convolution (unfused BN is the ResNet
     HBM ceiling; the training-time equivalent needs running stats and
     stays unfolded)."""
     return ResNet(stage_sizes=stage_sizes, num_classes=num_classes,

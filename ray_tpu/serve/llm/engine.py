@@ -986,7 +986,7 @@ class LLMEngine:
         # ---- serving goodput / MFU accounting (ISSUE 13) ----
         # Analytic forward FLOPs per token: 2 FLOPs per weight
         # (multiply+accumulate), the serving-side counterpart of the
-        # training 6N rule (docs/ROOFLINE.md, benchmarks/gpt_mfu.py).
+        # training 6N rule (docs/ROOFLINE.md).
         self._flops_per_token = 2.0 * self.executor.num_params
         # per step kind: the window's (clock, device_s, tokens) step
         # samples with their two running sums, plus the last derived

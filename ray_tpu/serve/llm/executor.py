@@ -646,7 +646,7 @@ class ModelExecutor:
         from the params pytree's shape metadata (no device sync) — the
         analytic-FLOPs input for serving MFU (2*n_params FLOPs/token,
         forward-only; cf. the training side's 6*n_params in
-        benchmarks/gpt_mfu.py and docs/ROOFLINE.md). QuantizedTensor
+        docs/ROOFLINE.md). QuantizedTensor
         leaves count their DATA elements only — the per-channel scale
         planes are bookkeeping, not model capacity — so MFU and the
         goodput gauges stay comparable between a quantized engine and
@@ -691,12 +691,12 @@ class ModelExecutor:
     def peak_tflops(self) -> float | None:
         """Aggregate published peak bf16 TFLOP/s across this executor's
         devices — the MFU denominator, from the one per-chip table
-        (benchmarks/gpt_mfu.py CHIP_PEAK_TFLOPS). None for a device that
+        (``CHIP_PEAK_TFLOPS`` at the end of this module, where adding a
+        row moves no line of a step's call chain and so no compile
+        cache key). None for a device that
         has no published peak (the CPU included): the engine then reports
         no MFU instead of one against an invented ceiling. Settable — a
         test of the gauge hands the executor a peak of its own."""
-        from ray_tpu.benchmarks.gpt_mfu import chip_peak_tflops
-
         if not hasattr(self, "_peak_tflops"):
             try:
                 self._peak_tflops = (
@@ -1004,3 +1004,29 @@ def build_executor(cfg, model_cfg, cache, *, params=None) -> ModelExecutor:
                         else None),
         }
     return ex
+
+
+# Published per-chip peak bf16 TFLOP/s by device_kind substring (Google
+# Cloud TPU documentation, one page per generation; ordering matters —
+# first substring match wins). A device that is not listed is an error,
+# never a default.
+CHIP_PEAK_TFLOPS = [
+    ("v6", 918.0),
+    ("v5p", 459.0),
+    ("v5 lite", 197.0),
+    ("v5e", 197.0),
+    ("v4", 275.0),
+    ("v3", 123.0),
+    ("v2", 45.0),
+]
+
+
+def chip_peak_tflops(device) -> float:
+    kind = getattr(device, "device_kind", "").lower()
+    for sub, peak in CHIP_PEAK_TFLOPS:
+        if sub in kind:
+            return peak
+    raise ValueError(
+        f"no published peak for device kind {kind!r} (platform "
+        f"{device.platform!r}); add it to CHIP_PEAK_TFLOPS with its source"
+    )

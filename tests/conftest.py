@@ -78,6 +78,16 @@ def shutdown_if_setup_fails():
         raise
 
 
+def serve_http_url(path: str) -> str:
+    """URL of ``path`` on this process's HTTP proxy. Cluster fixtures start
+    it on port 0: under ``--dist load`` two workers can each hold one
+    module's cluster at once, and a fixed port fails the second's set-up
+    ("address already in use")."""
+    from ray_tpu.serve import api
+
+    return f"http://127.0.0.1:{api._proxy.port}{path}"
+
+
 def _force_cpu_jax():
     import jax
 

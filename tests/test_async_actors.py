@@ -1,4 +1,4 @@
-"""Async actors / max_concurrency (VERDICT #8).
+"""Async actors / max_concurrency.
 
 Reference model: threaded actors via max_concurrency
 (src/ray/core_worker/transport/concurrency_group_manager.cc) — up to N
@@ -16,7 +16,7 @@ from conftest import shutdown_if_setup_fails
 
 def test_concurrent_actor_overlaps_methods(ray_start):
     """N slow methods on a max_concurrency=N actor finish in ~1x the
-    single-method latency — the VERDICT 'done' criterion."""
+    single-method latency."""
     rt = ray_start
 
     @rt.remote(max_concurrency=4)

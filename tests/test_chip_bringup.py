@@ -115,7 +115,7 @@ def test_compile_cache_defaults_into_the_checkout(
 
 
 def test_no_peak_is_invented_for_an_unlisted_device():
-    from ray_tpu.benchmarks.gpt_mfu import chip_peak_tflops
+    from ray_tpu.serve.llm.executor import chip_peak_tflops
 
     class _Dev:
         def __init__(self, kind, platform):

@@ -1,5 +1,5 @@
 """Every declared config flag must be READ somewhere outside config.py —
-a flag table that lies is worse than a short one (VERDICT r2 #9 / r3 #9).
+a flag table that lies is worse than a short one.
 Plus behavior tests for the round-4 wired flags."""
 import os
 import subprocess

@@ -2,7 +2,7 @@
 resource-aware actor-pool admission (reference:
 python/ray/data/_internal/execution/streaming_executor.py:49,
 streaming_executor_state.py — pipelined operator DAG with resource-aware
-admission; VERDICT r3 #5)."""
+admission)."""
 import time
 
 import pytest
@@ -85,7 +85,16 @@ def test_pool_below_cluster_size_pipelines(ray_start):
             time.sleep(0.2)
             return batch
 
+    def slow_read(r):
+        # an upstream stage of several waves (10 blocks on the 2 CPUs the
+        # pool leaves): with instant reads every block finishes inside one
+        # worker start-up, and whether the FIRST block (the one the pool
+        # waits for) lands before the last is the load's to decide
+        time.sleep(0.2)
+        return r
+
     rows = (data.range(10, parallelism=10)
+            .map(slow_read)
             .map_batches(Slow,
                          compute=ActorPoolStrategy(min_size=2, max_size=2))
             .take_all())

@@ -334,6 +334,23 @@ def test_two_replicas_report_relabeled_series_and_rollups(fleet_cluster):
     assert rollup[0]["value"] >= 16.0  # 4 streams x 4 tokens landed
     assert rollup[0]["labels"]["app"] == APP
 
+    # the latency histograms crossed it as well, and the fleet saw no
+    # fewer first tokens and gaps than the clients did (4 streams x 4
+    # tokens): the rollup's count is the sum of the replicas' own
+    for family, seen in (("llm_ttft_seconds", 4),
+                         ("llm_time_per_output_token_seconds", 12)):
+        counts = [
+            s for s in fleet["families"][family]["samples"]
+            if s["name"] == f"{family}_count"
+            and s["labels"].get("deployment") == DEP
+        ]
+        per_replica = [s["value"] for s in counts
+                       if "replica_id" in s["labels"]]
+        rolled = [s["value"] for s in counts
+                  if "replica_id" not in s["labels"]]
+        assert len(rolled) == 1 and rolled[0] >= seen, (family, counts)
+        assert rolled[0] == pytest.approx(sum(per_replica)), (family, counts)
+
     # the serving goodput gauges crossed the fleet plane too
     good = fleet["families"]["llm_goodput_tokens_per_sec"]["samples"]
     decode = [

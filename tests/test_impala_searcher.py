@@ -1,5 +1,5 @@
 """IMPALA (async sampling + V-trace) and the external-searcher adapter
-(VERDICT #9). Reference models: rllib/algorithms/impala/ and
+Reference models: rllib/algorithms/impala/ and
 tune/search/optuna/optuna_search.py.
 """
 from __future__ import annotations
@@ -85,7 +85,7 @@ def test_impala_learns_cartpole_local(jax_cpu):
 
 @pytest.mark.parametrize("ray_start", [{"num_cpus": 4}], indirect=True)
 def test_impala_async_sampling_with_actors(ray_start, jax_cpu):
-    """The VERDICT bar: CartPole improves with ASYNC actor sampling —
+    """CartPole improves with ASYNC actor sampling —
     runners keep one sample in flight, the learner consumes ready batches
     without a synchronous barrier."""
     from ray_tpu.rllib import CartPole, ImpalaConfig

@@ -40,7 +40,6 @@ from conftest import shutdown_if_setup_fails
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18167
 
 # shared system prompt: 4 full blocks at block_size=8
 PREFIX_TOKENS = 32
@@ -594,7 +593,7 @@ def host_tier_cluster():
     )
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
         handle = serve.run(
             build_llm_app(ecfg, num_replicas=2),
             name="llm-host-tier", route_prefix="/hosttier", timeout_s=180,

@@ -53,7 +53,7 @@ def test_cluster_nodes_have_separate_stores(two_node_cluster):
 
 def test_cross_node_get(two_node_cluster):
     """Node B's task creates an object; the driver (head store) gets it
-    through two separate store daemons — the VERDICT 'done' criterion."""
+    through two separate store daemons."""
     import ray_tpu
 
     @ray_tpu.remote(resources={"remote_res": 1.0})

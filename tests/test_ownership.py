@@ -67,7 +67,7 @@ def test_live_ref_survives_store_pressure(ray_start):
     indirect=True,
 )
 def test_put_2x_capacity_all_readable(ray_start):
-    """VERDICT #7 'done' criterion: put 2x store capacity, get everything."""
+    """Put 2x store capacity, get everything."""
     rt = ray_start
     refs = [rt.put(np.full(1024 * 1024, i, np.uint8)) for i in range(32)]
     for i, r in enumerate(refs):

@@ -150,7 +150,7 @@ def test_moe_expert_parallel_sharded(jax_cpu):
 def test_multichip_dryrun_compiles_without_spmd_remat():
     """The full dryrun (dp/fsdp/tp, ring-attention sp, pp, ep) must compile
     with ZERO '[SPMD] Involuntary full rematerialization' warnings — those
-    mean replicate-then-repartition traffic on every step (VERDICT r2 #6).
+    mean replicate-then-repartition traffic on every step.
     Subprocess: the dryrun needs its own 8-device CPU backend."""
     import os
     import subprocess

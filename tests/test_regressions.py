@@ -1,4 +1,4 @@
-"""Regression tests for the round-2 advisor findings (ADVICE.md):
+"""Regression tests for the round-2 advisor findings:
 (a) ActorPool leaks the actor when a task fails in get_next_unordered;
 (b) CoreWorker's GCS client latches dead after a GCS restart-in-place;
 (c) stale committed native binaries gated on mtime could be loaded;

@@ -41,7 +41,7 @@ def test_apex_learns_corridor_with_replay_actors(jax_cpu, ray_start):
 
 
 def test_apex_replay_actors_on_two_node_cluster(ray_cluster):
-    """The VERDICT bar: replay shards scheduled on a 2-node in-process
+    """Replay shards scheduled on a 2-node in-process
     cluster, experiences flowing through the inter-node object plane."""
     import time
 

@@ -7,6 +7,7 @@ report."""
 from __future__ import annotations
 
 import os
+import pathlib
 import subprocess
 import threading
 import time
@@ -767,11 +768,7 @@ def test_metrics_registry_matches_observability_docs():
     factory in serve code must have a table row, and every ``llm_*`` /
     ``serve_*`` name a table row documents must be registered by code —
     an undocumented metric is invisible to operators, a documented ghost
-    sends them querying a series that never exists. Bench-emitted keys
-    (the § Benchmark-emitted metrics table) are ghost-checked against
-    string literals in benchmarks/llm_serving.py: they live in the bench
-    JSON report, not the serve registry, but a documented bench key the
-    bench no longer emits is a ghost all the same."""
+    sends them querying a series that never exists."""
     import ast
     import pathlib
     import re
@@ -798,17 +795,6 @@ def test_metrics_registry_matches_observability_docs():
                     name, f"{path.relative_to(root)}:{node.lineno}")
     assert registered, "no metric registrations found under ray_tpu/serve/"
 
-    # bench-report keys: any llm_*/serve_* string literal in the bench
-    # module counts as emitted (keys are dict literals in result dicts,
-    # sometimes assembled from a prefix — the full names appear in the
-    # module docstring's report contract, which this deliberately honors)
-    bench_emitted: set[str] = set()
-    bench_src = (
-        root / "ray_tpu" / "benchmarks" / "llm_serving.py"
-    ).read_text()
-    bench_emitted.update(
-        re.findall(r"(?:llm|serve)_[a-z0-9_]+", bench_src))
-
     doc = root / "docs" / "OBSERVABILITY.md"
     documented: set[str] = set()
     for line in doc.read_text().splitlines():
@@ -824,7 +810,7 @@ def test_metrics_registry_matches_observability_docs():
     undocumented = {
         n: site for n, site in registered.items() if n not in documented
     }
-    ghosts = documented - set(registered) - bench_emitted
+    ghosts = documented - set(registered)
     assert not undocumented, (
         "metrics registered without a docs/OBSERVABILITY.md row: "
         f"{undocumented}"
@@ -833,6 +819,75 @@ def test_metrics_registry_matches_observability_docs():
         "docs/OBSERVABILITY.md documents metrics no serve code registers: "
         f"{sorted(ghosts)}"
     )
+
+
+def test_program_modules_import_nothing_from_benchmarks():
+    """The layers' arrows point one way (ISSUE 44): ``ray_tpu/benchmarks/``
+    holds tools that time a kernel alone on the chip and may import the
+    program; no module of the program imports them back. A benchmark
+    module on the serving executor's import path (as one was, for a table
+    of peaks) makes every cell depend on a script nobody serves with."""
+    import ast
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    pkg = root / "ray_tpu"
+    offenders = []
+    for path in sorted(pkg.rglob("*.py")):
+        if path.is_relative_to(pkg / "benchmarks"):
+            continue
+        tree = ast.parse(path.read_text(), filename=str(path))
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                # `from ray_tpu import benchmarks`, and relative forms
+                # (`from ..benchmarks import x`, `from .. import benchmarks`)
+                names = [node.module or ""] + [
+                    f"{node.module or ''}.{a.name}" for a in node.names]
+            else:
+                continue
+            if any("benchmarks" in n.split(".") for n in names):
+                offenders.append(f"{path.relative_to(root)}:{node.lineno}")
+    assert not offenders, (
+        f"program modules import ray_tpu.benchmarks: {offenders}")
+
+
+# the how-to-add-a-family text of docs/SERVING_LLM.md names files its
+# reader is about to write
+DOC_PLACEHOLDERS = {"models/myfam.py", "ray_tpu/models/myfam.py",
+                    "config.json"}
+# where a document's abbreviated path may start from
+DOC_PATH_BASES = ("", "ray_tpu", "ray_tpu/serve/llm", "ray_tpu/ops",
+                  "ray_tpu/models", "tests", "benchmark")
+
+
+_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+@pytest.mark.parametrize("doc", [
+    "README.md", "PERF.md",
+    *sorted(f"docs/{p.name}" for p in (_ROOT / "docs").glob("*.md")),
+])
+def test_documents_name_only_files_that_exist(doc):
+    """A document that sends its reader to a file that is gone (a deleted
+    benchmark, a renamed test) is evidence nobody can check (ISSUE 44).
+    Every backticked path with a source or record suffix, with or without
+    a ``::test`` tail, must exist under the root or one of the package
+    directories the documents abbreviate."""
+    import re
+
+    dead = set()
+    for token in re.findall(r"`([^`\s]+)`", (_ROOT / doc).read_text()):
+        m = re.match(
+            r"^([\w./-]+\.(?:py|md|jsonl|json|cpp))(?:::[\w:\[\]-]+)?$",
+            token)
+        if not m or m.group(1) in DOC_PLACEHOLDERS:
+            continue
+        if not any((_ROOT / base / m.group(1)).exists()
+                   for base in DOC_PATH_BASES):
+            dead.add(m.group(1))
+    assert not dead, f"{doc} names files that do not exist: {sorted(dead)}"
 
 
 def test_head_sampling_uses_seeded_rng():

@@ -10,8 +10,7 @@ the AutoscalingSnapshot surface and its gauges. Cluster tests run the
 tier-1 deterministic chaos storyline: a seeded burst with a mid-stream
 replica kill, fleet saturation shedding to HTTP 503 + Retry-After, a
 signal-driven scale-up, and a graceful drain that hands an in-flight
-stream to a survivor byte-identically (the slow full harness lives in
-test_serve_llm_load.py / benchmarks.llm_serving.run_load_bench).
+stream to a survivor byte-identically.
 """
 from __future__ import annotations
 
@@ -24,7 +23,7 @@ import urllib.request
 
 import pytest
 
-from conftest import shutdown_if_setup_fails
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
@@ -37,7 +36,6 @@ from ray_tpu.serve.autoscaling_policy import (
 )
 from ray_tpu.serve.config import AutoscalingConfig
 
-HTTP_PORT = 18173
 
 KILL_PROMPT = [5, 6, 7]
 KILL_SAMPLING = dict(max_new_tokens=8, temperature=0.8, seed=42)
@@ -312,7 +310,7 @@ def as_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT})
+        serve.start(http_options={"port": 0})
         main_handle = serve.run(
             build_llm_app(
                 # capacity 6 per replica (2 running + 4 queued): the 4-stream
@@ -495,8 +493,18 @@ def test_saturated_fleet_sheds_503_with_retry_after(as_cluster):
                 return False
             return False
 
+        from ray_tpu.util import metrics
+
+        def shed_count():
+            return sum(v for k, v in metrics.collect(
+                "llm_requests_shed_total").items() if "app=llm-main" in k)
+
+        shed_before = shed_count()
         assert _wait_for(router_sheds, timeout_s=30, interval=0.2), \
             "router never refused a fresh dispatch pre-dispatch"
+        # a shed request is counted as shed, apart from the engine's own
+        # rejections and from errors
+        assert shed_count() > shed_before
 
         # HTTP proxy: 503 + Retry-After. Polled for the same reason —
         # shed can flicker off while the router refuses the feeders and
@@ -505,7 +513,7 @@ def test_saturated_fleet_sheds_503_with_retry_after(as_cluster):
 
         def proxy_503():
             req = urllib.request.Request(
-                f"http://127.0.0.1:{HTTP_PORT}/main",
+                serve_http_url("/main"),
                 data=json.dumps(
                     {"prompt": "x", "max_new_tokens": 2}).encode(),
                 headers={"Content-Type": "application/json"},

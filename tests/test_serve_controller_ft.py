@@ -27,7 +27,7 @@ import urllib.request
 
 import pytest
 
-from conftest import shutdown_if_setup_fails
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
@@ -43,7 +43,6 @@ from ray_tpu.serve.controller import (
     encode_spec,
 )
 
-HTTP_PORT = 18174
 APP = "llm-ft"
 DEP = "LLMDeployment"
 
@@ -351,7 +350,7 @@ def ft_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT})
+        serve.start(http_options={"port": 0})
         handle = serve.run(
             build_llm_app(
                 EngineConfig(
@@ -412,7 +411,7 @@ def test_controller_killed_mid_upscale_orphan_reaped_data_plane_serves(
     assert [c["token"] for c in _stream(handle, outage)] == want_outage
     # and the proxy's liveness endpoint never depended on the controller
     hz = json.loads(urllib.request.urlopen(
-        f"http://127.0.0.1:{HTTP_PORT}/healthz", timeout=10).read())
+        serve_http_url("/healthz"), timeout=10).read())
     assert hz["status"] == "ok"
 
     # the restarted controller recovers, reaps the orphan, and converges

@@ -16,9 +16,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from conftest import shutdown_if_setup_fails
-
-HTTP_PORT = 18151
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 
 def _f32(cfg):
@@ -275,7 +273,7 @@ def llm_cluster():
 
     ray_tpu.init(num_cpus=6)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT})
+        serve.start(http_options={"port": 0})
         handle = serve.run(
             build_llm_app(EngineConfig(model="llama", seed=0)),
             name="llm", route_prefix="/llm", timeout_s=180,
@@ -307,7 +305,7 @@ def test_streaming_through_http_sse(llm_cluster):
     expected = [c["token"] for c in
                 handle.remote({"prompt": "hi there", "max_new_tokens": 6})]
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/llm",
+        serve_http_url("/llm"),
         data=json.dumps({"prompt": "hi there", "max_new_tokens": 6}).encode(),
         headers={"Content-Type": "application/json",
                  "Accept": "text/event-stream"},

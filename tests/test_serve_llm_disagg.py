@@ -32,8 +32,6 @@ from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.serve.autoscaling_policy import snapshot_is_hot
 from ray_tpu.serve.config import AutoscalingConfig
 
-HTTP_PORT = 18179
-
 
 # ---------------- wire format (no jax, no cluster) ----------------
 
@@ -396,7 +394,7 @@ def dg_cluster():
     )
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT})
+        serve.start(http_options={"port": 0})
         dg_handle = serve.run(
             build_llm_app(
                 ecfg,

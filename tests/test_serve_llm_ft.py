@@ -19,12 +19,11 @@ import urllib.request
 
 import pytest
 
-from conftest import shutdown_if_setup_fails
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18163
 
 # verified byte-identical resume vector: kill after 3 tokens of 8
 KILL_PROMPT = [5, 6, 7]
@@ -273,7 +272,7 @@ def ft_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
         ft_handle = serve.run(
             build_llm_app(
                 EngineConfig(model="llama", model_config=_model_config(), seed=0),
@@ -375,7 +374,7 @@ def test_overload_degrades_to_503_and_resource_exhausted(ft_cluster):
 
     # HTTP: overload -> 503 + Retry-After, decided BEFORE headers
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/tiny",
+        serve_http_url("/tiny"),
         data=json.dumps({"prompt": "x", "max_new_tokens": 4}).encode(),
         headers={"Content-Type": "application/json"},
     )
@@ -420,7 +419,7 @@ def test_overload_degrades_to_503_and_resource_exhausted(ft_cluster):
 def test_http_deadline_maps_to_504(ft_cluster):
     serve, _ = ft_cluster
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/tiny",
+        serve_http_url("/tiny"),
         data=json.dumps({"prompt": "x", "max_new_tokens": 4,
                          "deadline_s": 0.0}).encode(),
         headers={"Content-Type": "application/json"},

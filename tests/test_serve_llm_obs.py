@@ -24,13 +24,11 @@ import urllib.request
 
 import pytest
 
-from conftest import shutdown_if_setup_fails
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 from ray_tpu.util import metrics, tracing
-
-HTTP_PORT = 18183
 
 
 def _f32(cfg):
@@ -404,7 +402,7 @@ def obs_cluster(tmp_path_factory):
     try:
         ray_tpu.init(num_cpus=8)
         with shutdown_if_setup_fails():
-            serve.start(http_options={"port": HTTP_PORT}, grpc_options=None)
+            serve.start(http_options={"port": 0}, grpc_options=None)
             handle = serve.run(
                 build_llm_app(
                     EngineConfig(model="llama",
@@ -434,7 +432,7 @@ def _http_generate(payload: dict, *, traced: bool):
     if traced:
         headers["x-ray-tpu-trace"] = "1"
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/llmobs",
+        serve_http_url("/llmobs"),
         data=json.dumps(payload).encode(), headers=headers,
     )
     resp = urllib.request.urlopen(req, timeout=120)
@@ -490,7 +488,7 @@ def test_http_request_yields_one_trace_with_engine_spans(obs_cluster):
 @pytest.mark.timeout(300)
 def test_debug_llm_endpoint(obs_cluster):
     resp = urllib.request.urlopen(
-        f"http://127.0.0.1:{HTTP_PORT}/debug/llm?app=llm-obs", timeout=60)
+        serve_http_url("/debug/llm?app=llm-obs"), timeout=60)
     out = json.loads(resp.read())
     assert out["app"] == "llm-obs"
     dumps = [d for d in out["replicas"] if d]
@@ -500,7 +498,7 @@ def test_debug_llm_endpoint(obs_cluster):
         assert "steps" in d and "stats" in d and "cache" in d
     with pytest.raises(urllib.error.HTTPError) as err:
         urllib.request.urlopen(
-            f"http://127.0.0.1:{HTTP_PORT}/debug/llm?app=nope", timeout=60)
+            serve_http_url("/debug/llm?app=nope"), timeout=60)
     assert err.value.code == 404
 
 

@@ -48,7 +48,6 @@ AGREEMENT_FLOOR = 0.98
 KILL_PROMPT = [5, 6, 7]
 KILL_SAMPLING = dict(max_new_tokens=8, temperature=0.8, seed=42)
 KILL_AT_INDEX = 2
-HTTP_PORT = 18191
 
 
 def _f32(cfg):
@@ -708,7 +707,7 @@ def quant_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
         handle = serve.run(
             build_llm_app(
                 EngineConfig(model="llama", model_config=_model_config(),

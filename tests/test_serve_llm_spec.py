@@ -27,7 +27,6 @@ from conftest import shutdown_if_setup_fails
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18187
 
 # repeating-structure prompt: the regime prompt-lookup drafting targets.
 # This particular motif is one the tiny f32 llama greedily CONTINUES, so
@@ -387,7 +386,7 @@ def spec_ft_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
         handle = serve.run(
             build_llm_app(
                 EngineConfig(

@@ -40,7 +40,6 @@ from conftest import shutdown_if_setup_fails
 from ray_tpu._private import chaos
 from ray_tpu._private.chaos import Fault, FaultPlan
 
-HTTP_PORT = 18193
 
 VOCAB = 512  # tiny-config vocab: tokens < 256 are bytes, verbatim
 EOS = 0      # NUL never appears in grammar text, so the bit is unambiguous
@@ -605,7 +604,7 @@ def structured_cluster():
 
     ray_tpu.init(num_cpus=8)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT}, grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
         handle = serve.run(
             build_llm_app(
                 EngineConfig(model="llama", model_config=_model_config(),

@@ -15,15 +15,13 @@ import urllib.request
 
 import pytest
 
-from conftest import shutdown_if_setup_fails
+from conftest import serve_http_url, shutdown_if_setup_fails
 
 N_CHUNKS = 4
 CHUNK_GAP_S = 0.8
 # first chunk must land at least this long before the stream completes;
 # the producer tail after chunk 1 is (N_CHUNKS - 1) * CHUNK_GAP_S = 2.4s
 MIN_STREAM_SPREAD_S = 1.0
-
-HTTP_PORT = 18125
 
 
 @pytest.fixture(scope="module")
@@ -33,8 +31,7 @@ def streaming_cluster():
 
     ray_tpu.init(num_cpus=6)
     with shutdown_if_setup_fails():
-        serve.start(http_options={"port": HTTP_PORT},
-                    grpc_options={"port": 0})
+        serve.start(http_options={"port": 0}, grpc_options={"port": 0})
 
         @serve.deployment
         class Decoder:
@@ -138,7 +135,7 @@ def test_non_generator_method_still_unary(streaming_cluster):
 
 def test_http_proxy_streams_sse(streaming_cluster):
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/decode",
+        serve_http_url("/decode"),
         data=json.dumps({"prompt": "sse"}).encode(),
         headers={"Content-Type": "application/json",
                  "Accept": "text/event-stream"},
@@ -163,7 +160,7 @@ def test_http_proxy_streams_chunked_json(streaming_cluster):
     """Without an SSE Accept header the proxy streams newline-delimited
     JSON chunks over chunked transfer encoding."""
     req = urllib.request.Request(
-        f"http://127.0.0.1:{HTTP_PORT}/decode",
+        serve_http_url("/decode"),
         data=json.dumps({"prompt": "nd"}).encode(),
         headers={"Content-Type": "application/json"},
     )

@@ -1,4 +1,4 @@
-"""Dataset → JaxTrainer ingestion (VERDICT #5): streaming_split shard
+"""Dataset → JaxTrainer ingestion: streaming_split shard
 assignment per worker, session.get_dataset_shard, iter_jax_batches feed.
 
 Reference model: python/ray/train/data_parallel_trainer.py:59 (datasets
