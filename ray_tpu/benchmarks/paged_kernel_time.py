@@ -36,21 +36,26 @@ SHAPES = {
     "cell9-smallthinker-sliding": (48, 28, 4, 128, 5000, 16384, 4096),
     "cell4-gpt2": (64, 12, 12, 64, 600, 1024, None),
     "cell5-lfm2": (64, 32, 8, 64, 700, 2560, None),
+    # latent attention over a pool in planes (one 576-wide row a token for
+    # all Hq heads; Hkv and hd unused): decode at the two cells' head counts
+    "cell8-pangu-latent": (128, 128, 1, 576, 3000, 12288, "latent"),
+    "cell10-longcat-latent": (96, 64, 1, 576, 1100, 6144, "latent"),
     "cell1-prefill": (4, 32, 8, 128, 2047, 2048, "prefill"),
     "cell4-prefill": (4, 12, 12, 64, 1023, 1024, "prefill"),
 }
 if os.environ.get("ONLY"):
     SHAPES = {k: v for k, v in SHAPES.items() if k in os.environ["ONLY"].split(",")}
 if rehearse:  # tiny, for the interpreter
-    SHAPES = {k: (2, v[1], v[2], v[3], 100, 320, v[6] and 64)
+    SHAPES = {k: (2, v[1], v[2], v[3], 100, 320,
+                  v[6] if isinstance(v[6], str) else v[6] and 64)
               for k, v in list(SHAPES.items())[:3]}
 bs = 16
 rng = np.random.default_rng(0)
 out_lines = []
 for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
     NB = top // bs
-    prefill = window == "prefill"
-    if prefill:
+    prefill, latent = window == "prefill", window == "latent"
+    if prefill or latent:
         window = None
     ctx = np.clip(rng.lognormal(np.log(mean), 0.5, B).astype(int), 16, top - 1)
     if prefill:
@@ -73,6 +78,14 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
         pos = jnp.broadcast_to(jnp.arange(mean + 1, dtype=jnp.int32), (B, mean + 1))
     attended = np.minimum(ctx + 1, window) if window else ctx + 1
     kv_bytes = int(sum(-(-int(a) // bs) * bs for a in attended)) * Hkv * hd * 2 * 2
+    if latent:  # ONE row a token, key and value: 512 + 64 numbers, in planes
+        kv_bytes //= 2
+        k_pool = jax.random.normal(key, (2, num_blocks, bs, 512), jnp.bfloat16)
+        v_pool = jnp.pad(jax.random.normal(
+            jax.random.fold_in(key, 1), (2, num_blocks, bs, 64), jnp.bfloat16),
+            ((0, 0),) * 3 + ((0, 64),))
+        q = jax.random.normal(jax.random.fold_in(key, 2), (B, 1, Hq, 576), jnp.bfloat16)
+        pos = pos[:, None]
     ref = None
     for variant in variants:
         block_tokens = getattr(pa, "_block_tokens", None)
@@ -83,7 +96,10 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
         try:
             attend = pa.prefill_attention if prefill else pa.decode_attention
             fn = jax.jit(lambda q, k, v, t, p: attend(
-                q, k, v, t, p, backend="pallas", window=window, layer=jnp.int32(1)))
+                q, k, v, t, p, backend="pallas", window=window, layer=jnp.int32(1))
+                if not latent else pa.latent_attention(
+                    q, k, v, t, p, latent_dim=512, scale=192 ** -0.5,
+                    backend="pallas", layer=jnp.int32(1)))
             o = jax.block_until_ready(fn(q, k_pool, v_pool, tables_d, pos))
             times = []
             for _ in range(7):

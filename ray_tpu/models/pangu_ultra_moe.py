@@ -289,10 +289,15 @@ def _rotate(x, cos, sin):
     return rope(x, cos, sin)
 
 
-def _queries_and_row(u, lp, cos, sin, cfg: PanguUltraMoEConfig):
+def _queries_and_row(u, lp, cos, sin, cfg: PanguUltraMoEConfig, *,
+                     q_scale: float | None = None,
+                     c_scale: float | None = None):
     """The projections of the layer's normed input ``u`` [B, S, D]:
     ``(q_nope [B, S, H, N], q_rope [B, S, H, R], c [B, S, C], k_r [B, S,
-    R])``, the last two the token's row as the pool keeps it."""
+    R])``, the last two the token's row as the pool keeps it. ``q_scale``
+    multiplies both parts of every head's query and ``c_scale`` the normed
+    latent (so keys' nope part and values, not ``k_r``):
+    models/longcat_flash.py's two rescalings; None: none."""
     B, S, _ = u.shape
     H, N, R, C = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                   cfg.kv_lora_rank)
@@ -301,6 +306,10 @@ def _queries_and_row(u, lp, cos, sin, cfg: PanguUltraMoEConfig):
     q = (c_q @ lp["mla_w_uq"].astype(cfg.dtype)).reshape(B, S, H, N + R)
     kv = u @ lp["mla_w_dkv"].astype(cfg.dtype)
     c = rms_norm(kv[..., :C], lp["mla_kv_norm"], cfg.norm_eps)
+    if q_scale is not None:
+        q = q * jnp.asarray(q_scale, q.dtype)
+    if c_scale is not None:
+        c = c * jnp.asarray(c_scale, c.dtype)
     k_r = _rotate(kv[..., None, C:], cos, sin)[:, :, 0]
     return q[..., :N], _rotate(q[..., N:], cos, sin), c, k_r
 

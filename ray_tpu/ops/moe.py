@@ -1,11 +1,12 @@
 """Mixture-of-Experts layers: two of them, for two paths.
 
 **The served layer is the dropless one** (``moe_route`` + ``moe_dropless``,
-at the end of this file): a router (sigmoid scores with a selection bias,
-or a softmax over the chosen logits), top-k, the (token, expert) pairs
-sorted by expert, one grouped matrix product over the experts
-(``jax.lax.ragged_dot``), gated experts (SwiGLU, or ReLU-gated), weighted
-combine.
+at the end of this file): a router (sigmoid scores or a softmax over all
+outputs, each with a selection bias, or a softmax over the chosen logits),
+top-k, the (token, expert) pairs sorted by expert, one grouped matrix
+product over the experts (``jax.lax.ragged_dot``), gated experts (SwiGLU,
+or ReLU-gated; ZERO-COMPUTE experts return their input and cost no
+product), weighted combine.
 Every routed pair is computed whatever the routing, so a token's output
 depends on its own row only (batched == solo) and the layer can be checked
 against a plain reference. ``models/lfm2_moe.py`` serves through it.
@@ -139,7 +140,7 @@ def moe_reference_dense(params: dict, x: jax.Array, cfg: MoEConfig) -> jax.Array
 # what the published LFM2-MoE code adds to the sum of the chosen scores
 # before dividing by it (``norm_topk_prob``)
 ROUTE_NORM_EPS = 1e-6
-ROUTE_SCORES = ("sigmoid", "softmax_topk")
+ROUTE_SCORES = ("sigmoid", "softmax", "softmax_topk")
 EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
@@ -151,7 +152,10 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
     ``score="sigmoid"``: ``s = sigmoid(x @ router)``; the ``top_k`` experts
     are chosen by ``s + bias`` (the stored selection bias), but weighted by
     the UNBIASED ``s``, divided by their sum when ``norm_topk``, times
-    ``scale``. ``score="softmax_topk"`` (no bias): the ``top_k`` largest
+    ``scale``. ``score="softmax"``: the same with ``s = softmax(x @
+    router)`` over ALL the router's outputs (models/longcat_flash.py: 768,
+    of which 256 name zero-compute experts; not renormalised there).
+    ``score="softmax_topk"`` (no bias): the ``top_k`` largest
     LOGITS, weighted by a softmax over those k alone, which is the softmax
     over all experts renormalised over the chosen (``norm_topk``; without
     it, the softmax over all at the chosen), times ``scale``. All of
@@ -173,7 +177,8 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
             weights = jnp.exp(chosen - jax.nn.logsumexp(
                 over, axis=-1, keepdims=True))
             return weights * scale, experts.astype(jnp.int32)
-        scores = jax.nn.sigmoid(logits)
+        scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+                  else jax.nn.sigmoid(logits))
         chosen_by = scores if bias is None else scores + bias.astype(
             jnp.float32)
         _, experts = jax.lax.top_k(chosen_by, top_k)
@@ -187,7 +192,8 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
 def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  w_in: jax.Array, w_out: jax.Array, *, dtype,
                  valid: jax.Array | None = None,
-                 held: tuple[int, int] | None = None, act: str = "silu"):
+                 held: tuple[int, int] | None = None, act: str = "silu",
+                 zero_from: int | None = None):
     """The expert layer proper: x [T, D] -> (y [T, D], pairs_by_expert [E]
     int32).
 
@@ -209,10 +215,20 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     with the padding rows, is not computed and not counted; ``y`` is then
     the part of the layer's output that the held experts give, and the
     parts of all the holders add up to the whole layer's. No exchange of
-    rows is made here: that is the caller's, across devices."""
+    rows is made here: that is the caller's, across devices.
+
+    ``zero_from``: ids ``>= zero_from`` name ZERO-COMPUTE experts, which
+    return their input. Such a pair sorts behind every group with the
+    padding rows and costs no product; it adds ``w * x`` in float32, here,
+    whatever is held: a token's zero picks are computed where the token
+    is, so among the holders of one layer they are counted ONCE (by the
+    token's own device), not once a holder. ``held`` keeps its meaning for
+    the ids below ``zero_from``, which are the only ones counted."""
     T, D = x.shape
     k = experts.shape[1]
     E = w_in.shape[0]
+    if zero_from is not None and held is None:
+        held = (0, E)  # every real expert is held: the zero ids are in no group
     with jax.named_scope("moe_gmm"):
         flat = experts.reshape(T * k)
         kept = None  # [T * k]: the pairs some group computes; None: all
@@ -255,4 +271,11 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
             w = jnp.where(valid[:, None], w, 0.0)
         y = jnp.einsum("tkd,tk->td", yk, w,
                        precision=jax.lax.Precision.HIGHEST)
-        return y.astype(x.dtype), sizes
+        if zero_from is None:
+            return y.astype(x.dtype), sizes
+    with jax.named_scope("moe_zero"):
+        w = jnp.where(experts >= zero_from, weights.astype(jnp.float32), 0.0)
+        if valid is not None:
+            w = jnp.where(valid[:, None], w, 0.0)
+        y = y + jnp.sum(w, axis=-1, keepdims=True) * x.astype(jnp.float32)
+    return y.astype(x.dtype), sizes

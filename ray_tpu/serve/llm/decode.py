@@ -120,12 +120,25 @@ def _smallthinker() -> Family:
                   counters=m.smallthinker_counters, state_rows=False)
 
 
+def _longcat_flash() -> Family:
+    from ray_tpu.models import longcat_flash as m
+
+    # no verify step: nothing drafts; the latent family's refusals apply
+    return Family(m.longcat_flash_init, m.longcat_flash_prefill,
+                  m.longcat_flash_decode_step, None,
+                  m.longcat_flash_param_axes, m.longcat_flash_quant_axes,
+                  m.LongCatFlashConfig.tiny,
+                  init_state=m.longcat_flash_init_state,
+                  counters=m.longcat_flash_counters, state_rows=False)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
     "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
     "laguna": _laguna, "evabyte": _evabyte,
     "pangu_ultra_moe": _pangu_ultra_moe, "smallthinker": _smallthinker,
+    "longcat_flash": _longcat_flash,
 }
 
 

@@ -1046,7 +1046,7 @@ class LLMEngine:
         keeps: per-sequence state beside the pool (``lfm2_moe``), tables
         by group of layers (``laguna``), a ring and a table of chunk
         summaries (``evabyte``) or one latent row a token in planes
-        (``pangu_ultra_moe``), each with its reason."""
+        (``pangu_ultra_moe``, ``longcat_flash``), each with its reason."""
         asked = {
             "speculative_k": cfg.speculative_k > 0,
             "host_cache_bytes": cfg.host_cache_bytes > 0,
@@ -1111,9 +1111,8 @@ class LLMEngine:
             (latent, "caches one latent row a token for all heads, in "
                      "planes", {
                 "speculative_k":
-                    "the family has no verify step, and the "
-                    "multi-token-prediction module that would draft is "
-                    "not held",
+                    "the family has no verify step, and nothing that would "
+                    "draft (a multi-token-prediction module) is held",
                 "host_cache_bytes":
                     "the host tier's record (kv_transfer.KVLayout) "
                     "describes a block as n_kv_head x head_dim twice and "

@@ -867,6 +867,155 @@ def test_pangu_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+# the latent kernel's calls in the two cells that hold it: 128 heads
+# (openPangu: 128-row decode over [B, 768], a 1 x 2,048 chunk) and 64
+# heads (LongCat-Flash: 96-row decode and a 1 x 1,024 chunk over [B, 384])
+LATENT_CALLS = {
+    "pangu-decode": (128, 128, 768, 40961, 1, 5),
+    "pangu-chunk": (128, 1, 768, 40961, 2048, 5),
+    "longcat-decode": (64, 96, 384, 16385, 1, 8),
+    "longcat-chunk": (64, 1, 384, 16385, 1024, 8),
+}
+
+
+@pytest.mark.parametrize("call", sorted(LATENT_CALLS))
+def test_latent_attention_compiles(one_chip, call):
+    """``paged_attention_latent`` over a pool in planes ``[layers, blocks,
+    16, 512 | 128]`` at both head counts the cells hold: a tile's rows are
+    queries x heads (decode: 128 or 64 rows; a chunk: 8 queries a tile,
+    1,024 or 512 rows), under the VMEM ``_latent_block`` counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import (
+        _LATENT_Q_BLOCK, _latent_block, paged_latent_attention_pallas,
+    )
+
+    H, B, NB, blocks, S, layers = LATENT_CALLS[call]
+    S_ = functools.partial(_struct, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    fn = functools.partial(paged_latent_attention_pallas, latent_dim=512,
+                           scale=192 ** -0.5, layer=layers - 1,
+                           interpret=False)
+    compiled = jax.jit(fn).lower(
+        S_((B, S, H, 576), bf16), S_((layers, blocks, 16, 512), bf16),
+        S_((layers, blocks, 16, 128), bf16), S_((B, NB), jnp.int32),
+        S_((B, S), jnp.int32)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    rows = min(S, _LATENT_Q_BLOCK) * H
+    pages, vmem = _latent_block(16, 512, 128, rows, NB, bf16, bf16)
+    assert pages >= 8 and vmem < 64 << 20, (pages, vmem)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill",
+                                  "reference"])
+def test_longcat_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The LongCat-Flash cell's step programs as the executor compiles
+    them, at the cell's own shapes: the 96-row decode step and the 1 x
+    1,024 chunk over a table ``[B, 384]`` (the 6,144-token bucket), the
+    fresh prefill over ``[1, 64]``. The pool is two PLANES over EIGHT
+    latent sub-layers for four layers, ``[8, 16385, 16, 512]`` and ``[..,
+    128]`` (2.68 GB together): both are in the program's
+    ``input_output_alias`` and nothing pool-sized is among its
+    temporaries; each of the 4 layers calls ``paged_attention_latent``
+    TWICE and has its two grouped products ONCE; nothing has the context's
+    length as a dimension. The leaves reach their operations under the
+    names the benchmark's readers look for. ``reference``: the reference
+    check's float32 pass over 16 x 2,112 padded tokens fits beside the
+    weights and the pool."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/longcat-flash-omni-ep32-4l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/think-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("longcat_flash")
+    ref = common.load_named("reference", "longcat_flash")
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: ref.init_fn()(jax.random.PRNGKey(0), cfg)))
+    weight_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                       for a in jax.tree.leaves(params))
+    assert abs(weight_bytes - 10.345e9) < 0.01e9, weight_bytes
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    planes = [_struct((cfg.n_kv_layer, engine["num_blocks"], 16, stored),
+                      cfg.dtype, one_chip) for _, _, stored in cfg.kv_planes]
+    assert [p.shape for p in planes] == [(8, 16385, 16, 512),
+                                         (8, 16385, 16, 128)]
+    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    assert abs(pool_bytes - 2.684e9) < 0.001e9
+    if kind == "reference":
+        chk = held["reference_check"]
+        compiled = jax.jit(
+            lambda pr, t, pos: ref.logits_at(pr, t, pos, cfg)).lower(
+            params, i32((chk["requests"], chk["pad_to"])),
+            i32((chk["requests"], chk["new_tokens"]))).compile()
+        mem = compiled.memory_analysis()
+        # beside weights 10.35 GB and the pool 2.68 GB of the chip's 16.9
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        return
+    B = engine["max_batch_size"] if kind == "decode" else 1
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
+    ctx = engine["length_buckets"][-1]
+    chunk = engine["prefill_chunk_tokens"]
+    assert (ctx, chunk, engine["max_prefill_batch"]) == (6144, 1024, 1)
+    fns = decode.DecodeFns("longcat_flash", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        assert B == 96
+        lowered = fns._decode.lower(
+            params, *planes, i32((B,)), i32((B,)), i32((B, ctx // 16)),
+            sample=None, **more)
+    else:
+        nb = ctx // 16 if kind == "prefill_chunk" else chunk // 16
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, *planes, i32((B, chunk)), i32((B,)), i32((B, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # 10.35 GB of weights and the two planes
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.1e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < (0.15e9 if kind == "decode" else 1.6e9), \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"%paged_attention_latent[.\d]* = ", entry)) == 8
+    assert not re.findall(r"%paged_attention[.\d]* = ", entry)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)) == 8
+    # no array anywhere has the context's 6,144 positions as a dimension:
+    # d_model is 6,144 too, so the test is on [.., 64 heads, 6144] scores
+    # and on K or V by head
+    assert not re.search(r"\[\d+,64,6144[\],]", text)
+    if kind == "decode":
+        for needle in ("moe_route_w", "moe_route_bias", "moe_gmm_w_in"):
+            assert re.search(
+                rf"\(.*%params__layers___\d___{needle}__", entry), needle
+        for needle in ("mla_w_uk", "mla_w_uv", "dense_ffn_w_in",
+                       "dense_ffn_w_out"):
+            for half in (0, 1):
+                assert re.search(
+                    rf"%params__layers___\d___sub___{half}___{needle}__",
+                    entry), (needle, half)
+    assert "cross_program_prefetch_index" not in text
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
 def test_smallthinker_step_programs_compile_at_published_widths(
         one_chip, monkeypatch, kind):
@@ -1028,6 +1177,10 @@ PARENTS_TEXT = {
     "evabyte-decode": "6735ace882751da0",
     # untouched by ISSUE 43: planes [5, 40961, 16, 512 | 128], table [128, 768]
     "pangu-decode": "cb7ad74bb4c0b8da",
+    # taken on ISSUE 45's parent 34388d1, before ``moe_route`` and
+    # ``moe_dropless`` gained their third score and third kind of expert:
+    # pool [2, 65537, 16, 512], tables [4, 48, 1024]
+    "smallthinker-decode": "1ac1b5c522f22bc4",
 }
 PARENTS_JAX = "0.9.0"
 
@@ -1063,7 +1216,8 @@ def _cell_program(which, kind, S_):
               "lfm2": "lfm2-24b-a2b-8l",
               "laguna": "laguna-xs.2-ep8-8l",
               "evabyte": "evabyte-6.5b-8l",
-              "pangu": "openpangu-ultra-moe-ep32-5l"}[which]
+              "pangu": "openpangu-ultra-moe-ep32-5l",
+              "smallthinker": "smallthinker-21b-a3b-8l"}[which]
     held = common.load_json(
         os.path.join(root, f"benchmark/configs/{config}.json"))
     cfg = dataclasses.replace(
@@ -1084,7 +1238,8 @@ def _cell_program(which, kind, S_):
         num_blocks, tables = {"laguna": (32769, (4, 64, 1152)),
                               "lfm2": (4097, (64, 160)),
                               "evabyte": (4353, (2, 24, 192)),
-                              "pangu": (40961, (128, 768))}[which]
+                              "pangu": (40961, (128, 768)),
+                              "smallthinker": (65537, (4, 48, 1024))}[which]
         if which != "evabyte":  # it keeps no state beside the pool
             rows = tables[-2]
             more = {"state": jax.tree.map(on_chip, jax.eval_shape(

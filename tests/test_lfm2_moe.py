@@ -676,7 +676,8 @@ def test_unknown_model_name_raises(jax_cpu):
     with pytest.raises(ValueError, match="unknown model family 'mamba'"):
         LLMEngine(EngineConfig(model="mamba"), auto_step=False)
     assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
-                                "llama", "pangu_ultra_moe", "smallthinker"]
+                                "llama", "longcat_flash", "pangu_ultra_moe",
+                                "smallthinker"]
     for name in ("gpt", "llama"):
         assert get_family(name).init_state is None
         assert get_family(name).verify_step is not None

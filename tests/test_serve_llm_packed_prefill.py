@@ -350,6 +350,9 @@ ROWS = {
     "smallthinker": dict(block_size=4, num_blocks=129, max_batch_size=4,
                          prefill_chunk_tokens=16,
                          length_buckets=(16, 32, 64, 128)),
+    "longcat_flash": dict(block_size=4, num_blocks=129, max_batch_size=4,
+                          prefill_chunk_tokens=16,
+                          length_buckets=(16, 32, 64, 128)),
 }
 
 

@@ -166,7 +166,7 @@ def test_route_is_a_softmax_over_the_k_largest_logits(jax_cpu):
     with pytest.raises(ValueError, match="selection bias"):
         moe_route(x, router, jnp.zeros(16), k, score="softmax_topk")
     with pytest.raises(ValueError, match="score must be"):
-        moe_route(x, router, None, k, score="softmax")
+        moe_route(x, router, None, k, score="softmax_all")
 
 
 def test_defaults_give_the_parents_arrays_bit_for_bit(jax_cpu):
@@ -689,12 +689,12 @@ def test_handoff_is_refused_and_a_small_pool_says_why(tiny):
         _engine(cfg, params, prefill_chunk_tokens=None, num_blocks=65)
 
 
-def test_seven_families_are_served_and_named(jax_cpu):
+def test_the_families_are_served_and_named(jax_cpu):
     from ray_tpu.serve.llm import decode
 
     assert sorted(decode.FAMILIES) == [
-        "evabyte", "gpt", "laguna", "lfm2_moe", "llama", "pangu_ultra_moe",
-        "smallthinker"]
+        "evabyte", "gpt", "laguna", "lfm2_moe", "llama", "longcat_flash",
+        "pangu_ultra_moe", "smallthinker"]
     with pytest.raises(ValueError, match="smallthinker"):
         decode.get_family("smallthinker2")
     fam = decode.get_family("smallthinker")
