@@ -6,8 +6,9 @@ cells and two prefill shapes (docs/MICROBENCHMARKS.md, PERF.md PR 43):
 
 ``<tree>`` is the checkout whose ``ray_tpu`` is imported (``.`` or a copy of
 another commit under ``.scratch/``); a variant is ``base`` (the kernel as it
-is) or ``t128`` / ``t256`` / ``t512`` (a few-row tile's block pinned to so
-many tokens, whatever its row: the sweep ``_block_tokens``'s fit came from).
+is) or ``t128`` / ``t256`` / ``t512`` / ``t1024`` (a few-row tile's block
+pinned to so many tokens, whatever its row: the sweep ``_block_tokens``'s fit
+and the latent call's ``_latent_tokens`` came from).
 One JSON line a (shape, variant): the device's kind, the call's
 microseconds by the host's clock around 20 calls in a row, and the share of
 819 GB/s its attended K/V bytes make. ``ONLY=a,b`` keeps those shapes. Off
@@ -40,6 +41,9 @@ SHAPES = {
     # all Hq heads; Hkv and hd unused): decode at the two cells' head counts
     "cell8-pangu-latent": (128, 128, 1, 576, 3000, 12288, "latent"),
     "cell10-longcat-latent": (96, 64, 1, 576, 1100, 6144, "latent"),
+    # a prefill chunk over a latent pool: 8 queries x the heads a tile
+    "cell8-pangu-latent-chunk": (1, 128, 1, 576, 2047, 12288, "latent-chunk"),
+    "cell10-longcat-latent-chunk": (1, 64, 1, 576, 1023, 6144, "latent-chunk"),
     "cell1-prefill": (4, 32, 8, 128, 2047, 2048, "prefill"),
     "cell4-prefill": (4, 12, 12, 64, 1023, 1024, "prefill"),
 }
@@ -54,11 +58,12 @@ rng = np.random.default_rng(0)
 out_lines = []
 for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
     NB = top // bs
-    prefill, latent = window == "prefill", window == "latent"
+    chunk = window == "latent-chunk"
+    prefill, latent = window == "prefill", window in ("latent", "latent-chunk")
     if prefill or latent:
         window = None
     ctx = np.clip(rng.lognormal(np.log(mean), 0.5, B).astype(int), 16, top - 1)
-    if prefill:
+    if prefill or chunk:
         ctx[:] = mean
     need = [-(-int(c + 1) // bs) for c in ctx]
     num_blocks = sum(need) + 1
@@ -86,13 +91,22 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
             ((0, 0),) * 3 + ((0, 64),))
         q = jax.random.normal(jax.random.fold_in(key, 2), (B, 1, Hq, 576), jnp.bfloat16)
         pos = pos[:, None]
+        if chunk:  # the whole prompt as one chunk: causal, a frontier a tile
+            q = jax.random.normal(jax.random.fold_in(key, 2), (B, mean + 1, Hq, 576), jnp.bfloat16)
+            pos = jnp.broadcast_to(jnp.arange(mean + 1, dtype=jnp.int32), (B, mean + 1))
+            kv_bytes //= 2  # a tile attends up to its frontier: half on average
     ref = None
     for variant in variants:
         block_tokens = getattr(pa, "_block_tokens", None)
-        for n in (128, 256, 512):
-            if f"t{n}" in variant:
+        latent_tokens = getattr(pa, "_latent_tokens", None)
+        for n in (128, 256, 512, 1024):
+            if variant == f"t{n}":
                 pa._block_tokens = lambda R, row_bytes=None, n=n: (
                     n if R < pa._MANY_ROWS else 2 * pa._BLOCK_TOKENS)
+                if latent_tokens is not None:  # a latent tile of few rows
+                    pa._latent_tokens = lambda rows, n=n: (
+                        n if rows <= pa._MANY_ROWS else latent_tokens(rows))
+        jax.clear_caches()  # the latent call is behind a jit of its own
         try:
             attend = pa.prefill_attention if prefill else pa.decode_attention
             fn = jax.jit(lambda q, k, v, t, p: attend(
@@ -123,6 +137,8 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
         finally:
             if block_tokens is not None:
                 pa._block_tokens = block_tokens
+            if latent_tokens is not None:
+                pa._latent_tokens = latent_tokens
         print(json.dumps(line), flush=True)
         out_lines.append(line)
 os.makedirs("chiprun_out", exist_ok=True)

@@ -12,9 +12,14 @@ Nothing is ever materialized at the padded context length, no head is ever
 repeated.
 
 ONE kernel serves decode, fresh prefill, chunked prefill at true-position
-offsets and the verify windows: decode is the S = 1 case of the
-multi-token kernel (``paged_attention_pallas`` reshapes and calls
-``paged_prefill_attention_pallas``).
+offsets and the verify windows of every pool of K and V by head: decode is
+the S = 1 case of the multi-token kernel (``paged_attention_pallas``
+reshapes and calls ``paged_prefill_attention_pallas``). A pool in PLANES
+(latent attention: one row a token that every head reads, as key and as
+value) has a kernel body of its own at the end of this file
+(``_latent_attention_kernel``, PR 46): the same table walk and running
+softmax, but ONE head of 64 to 1,024 rows a tile, whose cost on the chip
+was starting its many small page copies in turn with the products.
 
 THE POOL IS READ WHERE IT STANDS: the cached step (models/cached.py) hands
 every entry point here the WHOLE pools and ``layer=``, an int32 scalar that
@@ -205,14 +210,14 @@ def pool_shape(n_layer: int, num_blocks: int, block_size: int, Hkv: int,
     return (n_layer, num_blocks, block_size, Hkv * hd)
 
 
-def _block_tokens(R: int, row_bytes: int | None = None) -> int:
+def _block_tokens(R: int, row_bytes: int) -> int:
     """The tokens a compute block aims at, for a q tile of ``R`` rows a
-    head over rows of ``row_bytes`` a token (see ``_BLOCK_BYTES``; None, the
-    latent planes': the lane tile of scores, two for a tile of many rows)."""
+    head over K (or V) rows of ``row_bytes`` a token (see ``_BLOCK_BYTES``).
+    A pool in planes has its own rule, ``_latent_tokens``."""
     if R >= _MANY_ROWS:
         return 2 * _BLOCK_TOKENS
     tokens = _BLOCK_TOKENS
-    while (row_bytes and tokens < _BLOCK_TOKENS_MOST
+    while (tokens < _BLOCK_TOKENS_MOST
            and 2 * tokens * row_bytes <= _BLOCK_BYTES):
         tokens *= 2
     return tokens
@@ -291,16 +296,12 @@ def _paged_attention_kernel(
                   # v_hbm, o_ref, ...). Then the scratch: (k_buf, v_buf,
                   # sems, m, l, acc).
                   # k_hbm / v_hbm: the whole pool, every layer, in HBM,
-                  # a page [bs, Hkv * hd] at [layer, id] (a latent plane's
-                  # [bs, width]); k_buf / v_buf: the two-slot VMEM scratch
-                  # of a block, [2, P * bs, row]
+                  # a page [bs, Hkv * hd] at [layer, id]; k_buf / v_buf: the
+                  # two-slot VMEM scratch of a block, [2, P * bs, row]
     block_size: int,
     pages: int,
     window: int | None,
     quantized: bool,
-    latent: bool = False,  # a pool in PLANES: k_hbm the latent rows, key
-                           # AND value of every head; v_hbm the key's
-                           # rotary rest; q rows ``[q~ | q_rope]``
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -365,10 +366,7 @@ def _paged_attention_kernel(
     # A page of a visited block that no copy wrote holds an older block's
     # page, or at a call's start nothing yet; the mask zeroes its
     # probabilities, and 0 x V must stay 0.
-    if latent:  # the latent plane is the value
-        k_buf[...] = jnp.zeros_like(k_buf)
-    else:
-        v_buf[...] = jnp.zeros_like(v_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
 
     def step(i, carry):
         # block i's pages fly while block i - 1 computes
@@ -390,37 +388,23 @@ def _paged_attention_kernel(
         # for all heads at once on [Hkv, R, T]. What a head adds to the
         # kernel's text is what every process pays again, for each of its
         # step programs, to trace and lower it.
-        if latent:
-            # ONE [T, C] tile a block for all the query heads (the rows):
-            # the page that was copied once is key and value, the rotary
-            # plane's tile the key's rest: the scores are q~ . c + q_rope
-            # . k_r, one "head" of R rows
-            c = k_buf[slot]
-            k_v = [((c, v_buf[slot]), c)]
-        else:
-            k_v = [
-                ((head(k_buf, slot, h),), head(v_buf, slot, h))
-                for h in range(n_head)
-            ]
+        k_v = [
+            (head(k_buf, slot, h), head(v_buf, slot, h))
+            for h in range(n_head)
+        ]
 
-        def q_parts(h):
-            # [R, hd], pre-scaled; a latent row's query in its two parts
-            if latent:
-                C = k_buf.shape[-1]
-                return q_ref[0, 0, :, :C], q_ref[0, 0, :, C:]
+        def q_head(h):
+            # [R, hd], pre-scaled
             if quantized:
-                return (lax.convert_element_type(q_ref[0, h], jnp.float32),)
-            return (q_ref[0, h],)
+                return lax.convert_element_type(q_ref[0, h], jnp.float32)
+            return q_ref[0, h]
 
         s = jnp.stack([
-            functools.reduce(jnp.add, [
-                lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for q, k in zip(q_parts(h), k_parts)
-            ])
-            for h, (k_parts, _) in enumerate(k_v)
+            lax.dot_general(
+                q_head(h), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h, (k, _) in enumerate(k_v)
         ])                             # [Hkv, R, T]
         if quantized:
             # dequantize the SCORES: q . (k * scale_t) is
@@ -812,17 +796,30 @@ def prefill_attention(
 
 
 # ----------------------------------------------------------------------------
-# A pool in PLANES: latent attention (models/pangu_ultra_moe.py). A token's
-# row is one latent vector that every head reads, as key (with its rotary
-# rest beside it) and as value; the two pools the step carries hold the
-# latent plane and the rotary plane, each stored at whole lanes. The SAME
-# kernel walks the table (``latent=True``): a page of each plane is copied
-# once, the query heads are the rows of one tile, decode is S = 1.
+# A pool in PLANES: latent attention (models/pangu_ultra_moe.py,
+# models/longcat_flash.py). A token's row is one latent vector that every
+# head reads, as key (with its rotary rest beside it) and as value; the two
+# pools the step carries hold the latent plane and the rotary plane, each
+# stored at whole lanes. The call has a kernel body of its OWN (PR 46,
+# ``_latent_attention_kernel``): the table walk is the by-head kernel's (a
+# page of each plane is copied once, none past a row's frontier), but a
+# tile is ONE head of 64 to 1,024 rows (the query heads of its queries;
+# decode is S = 1) whose key is its value, and timed alone on the chip the
+# call was bound by neither its products nor its bytes: starting a page's
+# two small copies holds the kernel's one instruction stream ~35 cycles a
+# copy, in turn with the products (docs/MICROBENCHMARKS.md, PR 46). So a
+# block is long, its copies are started in straight-line text and awaited
+# once a plane, and no tile waits for its first block.
 # ----------------------------------------------------------------------------
 
 # queries a tile of a chunk: with H heads each, ``q_block * H`` rows share
 # every page the tile copies
 _LATENT_Q_BLOCK = 8
+# the tokens a FEW-row tile's block aims at (a decode tile: the heads of one
+# query, 64 or 128 rows): the block's chain costs ~750 cycles whatever it
+# holds, and each block's waits and loop turn come on top: 1,024 tokens
+# measured 30% faster than 256 and 6% faster than 512 at both head counts
+_LATENT_BLOCK_TOKENS = 1024
 
 
 def plane_width(width: int) -> int:
@@ -830,6 +827,15 @@ def plane_width(width: int) -> int:
     of 128, so that a page is whole tiles, rests as written and is copied
     where it stands (the latent 512 as it is; the rotary 64 as 128)."""
     return -(-width // 128) * 128
+
+
+def _latent_tokens(rows: int) -> int:
+    """The tokens a latent compute block aims at, by the tile's rows: a
+    tile of many rows (a chunk's 8 queries x the heads) reuses every
+    latched context tile and pays for rescaling its ``[rows, 512]``
+    accumulator a block: it keeps the two lane tiles of scores a block it
+    has had since PR 39."""
+    return _LATENT_BLOCK_TOKENS if rows <= _MANY_ROWS else 2 * _BLOCK_TOKENS
 
 
 def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
@@ -850,7 +856,250 @@ def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
         ) + 2 * _vmem_bytes((R, p * bs), jnp.float32) + _vmem_bytes(
             (R, Cp), jnp.float32)
 
-    return _fit_pages(need, bs, _block_tokens(R), NB)
+    return _fit_pages(need, bs, _latent_tokens(R), NB)
+
+
+def _latent_attention_kernel(
+    tables_ref,   # scalar prefetch: [B, NB] int32 block tables
+    qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
+    layer_ref,    # scalar prefetch: [1] int32, the pools' layer
+    q_ref,        # [1, R, Cp + Rp]: this (b, q-block)'s rows ``[q~ |
+                  # q_rope]``, pre-scaled; row r = query (r // H), head (r % H)
+    pos_ref,      # [1, R, 1] int32: true position of each row's query
+    c_hbm,        # the latent plane, every layer, in HBM: a page [bs, Cp]
+    r_hbm,        # the rotary plane: a page [bs, Rp]
+    o_ref,        # [1, R, Cp]
+    c_buf,        # [2, P * bs, Cp]: the two-slot scratch of a block
+    r_buf,        # [2, P * bs, Rp]
+    sems,         # one DMA semaphore a slot, shared by the slot's copies
+    m_scr, l_scr,  # [R, 1] float32 running max and sum
+    acc_scr,      # [R, Cp] float32
+    base_ref,     # SMEM [1] int32: the slot this tile's block 0 is in
+    *,
+    block_size: int,
+    pages: int,
+):
+    """One (b, q-block) tile: the rows' running softmax over the blocks of
+    P pages its frontier reaches. The grid runs IN ORDER (both axes
+    "arbitrary") and the scratch lives across it: a tile's block 0 was
+    started under the LAST block of the tile before it (the call's first
+    tile starts its own), so no tile waits for a copy with nothing to
+    compute beside it, and the slots alternate across tiles
+    (``base_ref``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, j = pl.program_id(0), pl.program_id(1)
+    nb, nj = pl.num_programs(0), pl.num_programs(1)
+    layer = layer_ref[0]
+    rows, C = acc_scr.shape
+    n_entries = tables_ref.shape[1]
+    bs, T = block_size, pages * block_size
+    div, lmin, rem = lax.div, lax.min, lax.rem
+
+    def frontier(bb, jj):
+        # the last table entry a tile attends (the page of its frontier:
+        # see ``_paged_attention_kernel``), and its count of blocks
+        last = lmin(div(qmax_ref[bb, jj], bs), n_entries - 1)
+        return last, div(last, pages) + 1
+
+    last, hi = frontier(b, j)
+    # the tile after this one, in the grid's order
+    more_j = j + 1 < nj
+    b2 = jnp.where(more_j, b, lmin(b + 1, nb - 1))
+    j2 = jnp.where(more_j, j + 1, 0)
+    final = jnp.logical_and(b == nb - 1, j == nj - 1)
+    last2, _ = frontier(b2, j2)
+
+    def page_copies(bb, i, p, slot):
+        # page p of a row's block i: ONE copy a plane
+        dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+        src = tables_ref[bb, i * pages + p]
+        return [
+            pltpu.make_async_copy(
+                pool.at[layer, src], buf.at[slot, dst], sems.at[slot])
+            for pool, buf in ((c_hbm, c_buf), (r_hbm, r_buf))
+        ]
+
+    def is_whole(ll, i):
+        # every page of block i lies at or under the last attended one
+        return ll + 1 - i * pages >= pages
+
+    def each_page(bb, i, slot, op, n, unroll=False):
+        # ``op`` ("start" | "wait") on the two copies of block i's pages
+        # 0 .. n - 1
+        def page(p, carry):
+            for copy in page_copies(bb, i, p, slot):
+                getattr(copy, op)()
+            return carry
+
+        lax.fori_loop(0, n, page, 0, unroll=unroll)
+
+    def cut(bb, ll, i, slot, op):
+        # a block the frontier cuts (a tile has one, its last): its
+        # attended pages alone, by a loop of the frontier's bound
+        each_page(bb, i, slot, op, lmin(ll + 1 - i * pages, pages))
+
+    def start(bb, ll, i, slot):
+        whole = is_whole(ll, i)
+        # straight-line text (one traced page, unrolled as it is lowered):
+        # a rolled loop's turn costs each copy ~4 cycles more
+        pl.when(whole)(lambda: each_page(
+            bb, i, slot, "start", pages, unroll=True))
+        pl.when(jnp.logical_not(whole))(
+            lambda: cut(bb, ll, i, slot, "start"))
+
+    def wait(ll, i, slot):
+        whole = is_whole(ll, i)
+
+        @pl.when(whole)
+        def _():
+            # ONE wait a plane for all P pages: a semaphore counts bytes,
+            # and a slot's own size is its P pages'
+            for buf in (c_buf, r_buf):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+
+        pl.when(jnp.logical_not(whole))(
+            lambda: cut(b, ll, i, slot, "wait"))
+
+    def compute(i, slot):
+        # ONE [T, C] tile a block for all the rows: the page that was
+        # copied once is key and value, the rotary plane's tile the key's
+        # rest: the scores are q~ . c + q_rope . k_r
+        c = c_buf[slot]
+        nt = (((1,), (1,)), ((), ()))
+        s = lax.dot_general(
+            q_ref[0, :, :C], c, nt, preferred_element_type=jnp.float32,
+        ) + lax.dot_general(
+            q_ref[0, :, C:], r_buf[slot], nt,
+            preferred_element_type=jnp.float32,
+        )                                                  # [R, T]
+        t = i * T + lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        s = jnp.where(t <= pos_ref[0], s, NEG_INF)
+        m_prev = m_scr[...]                                # [R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # bf16 inputs: exp2 at half precision, matching the flash forward
+        if q_ref.dtype == jnp.bfloat16:
+            p = jnp.exp2((s - m_new).astype(jnp.bfloat16))
+        else:
+            p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(
+            p, axis=1, keepdims=True, dtype=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[...] = m_new
+
+    @pl.when(jnp.logical_and(b == 0, j == 0))
+    def _():
+        # The rows of a slot that no copy of the block wrote hold an older
+        # block's pages, or at a call's start nothing yet; the mask zeroes
+        # their probabilities, and 0 x the latent tile (the value) must
+        # stay 0: zeros once, and after that only pages the tables name.
+        c_buf[...] = jnp.zeros_like(c_buf)
+        base_ref[0] = 0
+        cut(b, last, 0, 0, "start")
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    base = base_ref[0]
+
+    def block(i, carry):
+        slot = rem(base + i, 2)
+        wait(last, i, slot)
+        # the block after this one flies while this one computes: this
+        # tile's next, or block 0 of the tile after it
+        more = i + 1 < hi
+        pl.when(jnp.logical_or(more, jnp.logical_not(final)))(
+            lambda: start(
+                jnp.where(more, b, b2), jnp.where(more, last, last2),
+                jnp.where(more, i + 1, 0), 1 - slot))
+        compute(i, slot)
+        return carry
+
+    lax.fori_loop(0, hi, block, 0)
+    base_ref[0] = rem(base + hi, 2)
+    l = l_scr[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("latent_dim", "scale", "q_block", "interpret"))
+def _latent_call(q, latent_pool, rope_pool, block_tables, positions, layer,
+                 *, latent_dim, scale, q_block, interpret):
+    """``paged_latent_attention_pallas`` behind a jit of its own: a step
+    program calls it once a latent layer with the same shapes, and the
+    inner jit's cache makes the kernel's text traced once a process and
+    lowered once a program, not once a call (a program still holds one
+    ``paged_attention_latent`` custom call a layer)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, H, D = q.shape
+    C, R = latent_dim, D - latent_dim
+    bs, Cp = latent_pool.shape[2:]
+    Rp = rope_pool.shape[3]
+    NB = block_tables.shape[1]
+    q, pos, qb, nqb = _q_tiles(q, positions, q_block)
+    Sp = nqb * qb
+    rows = qb * H
+    q = q * jnp.asarray(scale * LOG2E, q.dtype)
+    # each part of a row padded to its plane's stored width (nothing at
+    # the latent 512; the rotary 64 to 128, against the plane's zeros)
+    qf = jnp.concatenate([
+        jnp.pad(q[..., :C], ((0, 0),) * 3 + ((0, Cp - C),)),
+        jnp.pad(q[..., C:], ((0, 0),) * 3 + ((0, Rp - R),)),
+    ], axis=-1).reshape(B, Sp * H, Cp + Rp)
+    pos_rows = jnp.broadcast_to(
+        pos[:, :, None], (B, Sp, H)).reshape(B, Sp * H, 1)
+    qmax, _ = _frontiers(pos, nqb)
+    pages, vmem = _latent_block(
+        bs, Cp, Rp, rows, NB, q.dtype, latent_pool.dtype)
+
+    def q_map(b, j, *refs):
+        return (b, j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nqb),
+        in_specs=[
+            pl.BlockSpec((1, rows, Cp + Rp), q_map),
+            pl.BlockSpec((1, rows, 1), q_map),
+            # the planes stay in HBM: the kernel copies the pages itself
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, rows, Cp), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, Cp), latent_pool.dtype),
+            pltpu.VMEM((2, pages * bs, Rp), rope_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, Cp), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_attention_kernel, block_size=bs, pages=pages),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sp * H, Cp), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # in order, on one core: a tile starts the next tile's copies
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
+        ),
+        name=LATENT_KERNEL_NAME,
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), qmax, layer, qf, pos_rows,
+      latent_pool, rope_pool)
+    return out.reshape(B, Sp, H, Cp)[:, :S, :, :C]
 
 
 def paged_latent_attention_pallas(
@@ -873,8 +1122,8 @@ def paged_latent_attention_pallas(
     with ``layer`` (one layer's without), ``positions`` ``[B, S]``. Returns
     ``[B, S, H, C]`` in q's dtype.
 
-    The kernel is ``_paged_attention_kernel`` with ``latent=True``, under
-    the name ``paged_attention_latent``: grid ``(B, q_blocks)``, a tile of
+    The kernel is ``_latent_attention_kernel`` under the name
+    ``paged_attention_latent``: grid ``(B, q_blocks)``, a tile of
     ``q_block`` queries x H heads as ROWS over the one shared row a token;
     per compute block one copy of each plane's pages, the latent tile then
     feeds the scores (``q~ . c``, the rotary plane's ``q_rope . k_r``
@@ -882,78 +1131,15 @@ def paged_latent_attention_pallas(
     values, at ``2 H (C + R + C)`` flop a token. A chunk of queries against
     a resident context runs the same kernel: nothing of the context is ever
     expanded by head in HBM."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     if interpret is None:
         interpret = pallas_interpret()
     latent_pool, rope_pool, layer = _as_pools(latent_pool, rope_pool, layer)
-    layer = layer.reshape(1)
-    B, S, H, D = q.shape
-    C, R = latent_dim, D - latent_dim
-    bs, Cp = latent_pool.shape[2:]
-    Rp = rope_pool.shape[3]
-    NB = block_tables.shape[1]
-    q, pos, qb, nqb = _q_tiles(
-        q, positions,
-        q_block if q_block is not None else min(S, _LATENT_Q_BLOCK))
-    Sp = nqb * qb
-    rows = qb * H
-    q = q * jnp.asarray(scale * LOG2E, q.dtype)
-    # each part of a row padded to its plane's stored width (nothing at
-    # the latent 512; the rotary 64 to 128, against the plane's zeros)
-    qf = jnp.concatenate([
-        jnp.pad(q[..., :C], ((0, 0),) * 3 + ((0, Cp - C),)),
-        jnp.pad(q[..., C:], ((0, 0),) * 3 + ((0, Rp - R),)),
-    ], axis=-1).reshape(B, 1, Sp * H, Cp + Rp)
-    pos_rows = jnp.broadcast_to(
-        pos[:, :, None], (B, Sp, H)).reshape(B, Sp * H, 1)
-    qmax, qmin = _frontiers(pos, nqb)
-    pages, vmem = _latent_block(
-        bs, Cp, Rp, rows, NB, q.dtype, latent_pool.dtype)
-
-    def q_map(b, j, *refs):
-        return (b, 0, j, 0)
-
-    def pos_map(b, j, *refs):
-        return (b, j, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, nqb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, Cp + Rp), q_map),
-            pl.BlockSpec((1, rows, 1), pos_map),
-            # the planes stay in HBM: the kernel copies the pages itself
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, Cp), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, Cp), latent_pool.dtype),
-            pltpu.VMEM((2, pages * bs, Rp), rope_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((1, rows, 1), jnp.float32),
-            pltpu.VMEM((1, rows, 1), jnp.float32),
-            pltpu.VMEM((1, rows, Cp), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_attention_kernel, block_size=bs, pages=pages,
-            window=None, quantized=False, latent=True,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, Sp * H, Cp), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
-        ),
-        name=LATENT_KERNEL_NAME,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), qmax, qmin, layer, qf, pos_rows,
-      latent_pool, rope_pool)
-    return out.reshape(B, Sp, H, Cp)[:, :S, :, :C]
+    S = q.shape[1]
+    return _latent_call(
+        q, latent_pool, rope_pool, block_tables, positions,
+        layer.reshape(1), latent_dim=latent_dim, scale=float(scale),
+        q_block=q_block if q_block is not None else min(S, _LATENT_Q_BLOCK),
+        interpret=bool(interpret))
 
 
 def latent_attention(

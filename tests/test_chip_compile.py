@@ -882,8 +882,9 @@ LATENT_CALLS = {
 def test_latent_attention_compiles(one_chip, call):
     """``paged_attention_latent`` over a pool in planes ``[layers, blocks,
     16, 512 | 128]`` at both head counts the cells hold: a tile's rows are
-    queries x heads (decode: 128 or 64 rows; a chunk: 8 queries a tile,
-    1,024 or 512 rows), under the VMEM ``_latent_block`` counts."""
+    queries x heads (decode: 128 or 64 rows over blocks of 1,024 tokens;
+    a chunk: 8 queries a tile, 1,024 or 512 rows over blocks of 256), under
+    the VMEM ``_latent_block`` counts."""
     import jax
     import jax.numpy as jnp
 
@@ -904,7 +905,10 @@ def test_latent_attention_compiles(one_chip, call):
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     rows = min(S, _LATENT_Q_BLOCK) * H
     pages, vmem = _latent_block(16, 512, 128, rows, NB, bf16, bf16)
-    assert pages >= 8 and vmem < 64 << 20, (pages, vmem)
+    # a decode tile (the heads of one query) takes blocks of 1,024 tokens,
+    # a chunk's tile of 8 queries x the heads keeps 256
+    assert pages == (64 if S == 1 else 16), pages
+    assert vmem < (8 << 20 if S == 1 else 16 << 20), vmem
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill",
@@ -1162,9 +1166,12 @@ def test_other_families_programs_are_what_their_functions_compile_to(
 # kernel's pages, the scatter's rows) and through the block a few-row tile
 # aims at; GPT-2's and lfm2's decode through the block alone. What ISSUE 43
 # did NOT touch keeps the text its own PR recorded, and
-# says so here: ``pangu-decode`` (the latent branch and its planes: ISSUE
-# 41's parent d31556f), and the trainer's flash kernels
-# (``test_flash_kernel_names_reach_the_lowered_text``).
+# says so here: the trainer's flash kernels
+# (``test_flash_kernel_names_reach_the_lowered_text``). ISSUE 46 gave the
+# latent call a kernel body of its own and took the ``latent`` branches out
+# of ``_paged_attention_kernel``: ``pangu-decode`` is re-recorded and
+# ``longcat-decode`` added on ITS tree; every by-head text, the kernel's
+# jaxprs among them, is the one recorded before it.
 PARENTS_TEXT = {
     "mistral-decode": "bc061138abf9994d",
     "mistral-prefill": "b496beb461d9093b",
@@ -1175,8 +1182,11 @@ PARENTS_TEXT = {
     "gpt2-decode": "ae8d5f0c0d8e2416",
     "lfm2-decode": "1f57451824aeb915",
     "evabyte-decode": "6735ace882751da0",
-    # untouched by ISSUE 43: planes [5, 40961, 16, 512 | 128], table [128, 768]
-    "pangu-decode": "cb7ad74bb4c0b8da",
+    # re-recorded by ISSUE 46 (the latent call's own kernel body, behind a
+    # jit of its own): planes [5, 40961, 16, 512 | 128], table [128, 768]
+    "pangu-decode": "97b99cac41094171",
+    # new with ISSUE 46: planes [8, 16385, 16, 512 | 128], table [96, 384]
+    "longcat-decode": "f51516e6bdf18da4",
     # taken on ISSUE 45's parent 34388d1, before ``moe_route`` and
     # ``moe_dropless`` gained their third score and third kind of expert:
     # pool [2, 65537, 16, 512], tables [4, 48, 1024]
@@ -1217,6 +1227,7 @@ def _cell_program(which, kind, S_):
               "laguna": "laguna-xs.2-ep8-8l",
               "evabyte": "evabyte-6.5b-8l",
               "pangu": "openpangu-ultra-moe-ep32-5l",
+              "longcat": "longcat-flash-omni-ep32-4l",
               "smallthinker": "smallthinker-21b-a3b-8l"}[which]
     held = common.load_json(
         os.path.join(root, f"benchmark/configs/{config}.json"))
@@ -1239,15 +1250,18 @@ def _cell_program(which, kind, S_):
                               "lfm2": (4097, (64, 160)),
                               "evabyte": (4353, (2, 24, 192)),
                               "pangu": (40961, (128, 768)),
+                              "longcat": (16385, (96, 384)),
                               "smallthinker": (65537, (4, 48, 1024))}[which]
         if which != "evabyte":  # it keeps no state beside the pool
             rows = tables[-2]
             more = {"state": jax.tree.map(on_chip, jax.eval_shape(
                 lambda: fam.init_state(cfg, rows + 1))),
                 "slots": i32((rows,))}
-    if which == "pangu":  # a pool in planes, each at its stored width
-        pools = [S_((cfg.n_layer, num_blocks, 16, stored), cfg.dtype)
-                 for _, _, stored in cfg.kv_planes]
+    if which in ("pangu", "longcat"):
+        # a pool in planes, each at its stored width, over the cache's
+        # layers (longcat: two latent sub-layers a layer)
+        pools = [S_((getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,
+                     stored), cfg.dtype) for _, _, stored in cfg.kv_planes]
     else:
         pool = S_(pool_shape(
             getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,

@@ -328,38 +328,52 @@ def test_write_kv_lands_each_plane_in_its_own_row(jax_cpu):
                                   np.asarray(k_r[0, 0]))
 
 
-def _latent_case(kind, seed=0, H=4, C=16, R=4, bs=4, NB=8, B=2):
-    """q, the two planes with every page OUTSIDE the tables poisoned, the
-    tables and positions of a decode step, a chunk against a resident
-    context, or a fresh prompt."""
+def _latent_walk_case(ctx, S, H, C=16, R=4, bs=16, NB=None, seed=0):
+    """q ``[B, S, H, C + R]``, the two planes, the tables and positions of
+    one row a context of ``ctx`` tokens plus ``S`` queries (a decode step,
+    a chunk against a resident context, a fresh prompt at 0). The pool has
+    twice the pages the tables name; every page OUTSIDE them, block 0
+    among them, is poisoned. ``NB`` None: tables as wide as the longest row
+    needs, in whole 128s."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.paged_attention import plane_width
 
-    S = {"decode": 1, "chunk": 8, "fresh": 11}[kind]
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    blocks = 1 + B * NB
-    lat = jax.random.normal(ks[0], (2, blocks + 3, bs, plane_width(C)))
-    rope = jax.random.normal(ks[1], (2, blocks + 3, bs, plane_width(R)))
+    B = len(ctx)
+    need = [-(-(c + S) // bs) for c in ctx]
+    if NB is None:
+        NB = -(-(max(need) + 2) // 128) * 128
+    blocks = 2 * (1 + sum(need))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lat = jax.random.normal(ks[0], (2, blocks, bs, plane_width(C)))
+    rope = jax.random.normal(ks[1], (2, blocks, bs, plane_width(R)))
     # the planes' padding lanes hold zeros, as the pool's do
     lat = lat.at[..., C:].set(0.0)
     rope = rope.at[..., R:].set(0.0)
     tables = np.zeros((B, NB), np.int32)
     perm = np.random.default_rng(seed).permutation(np.arange(1, blocks))
-    ctx = {"decode": [13, 30], "chunk": [21, 9], "fresh": [0, 0]}[kind]
     pos = np.zeros((B, S), np.int32)
+    at = 0
     for b in range(B):
-        n = -(-(ctx[b] + S) // bs)
-        tables[b, :n] = perm[b * NB: b * NB + n]
+        tables[b, :need[b]] = perm[at: at + need[b]]
+        at += need[b]
         pos[b] = ctx[b] + np.arange(S)
-    poisoned = np.ones(blocks + 3, bool)
+    poisoned = np.ones(blocks, bool)
     poisoned[tables[tables > 0]] = False
     poison = jnp.asarray(poisoned)[None, :, None, None]
     lat = jnp.where(poison, jnp.nan, lat)
     rope = jnp.where(poison, jnp.inf, rope)
     q = jax.random.normal(ks[2], (B, S, H, C + R))
     return q, lat, rope, jnp.asarray(tables), jnp.asarray(pos), C
+
+
+def _latent_case(kind):
+    """A tiny case (pages of 4 tokens, 4 heads, tables of 8 entries): a
+    decode step, a chunk against a resident context, or a fresh prompt."""
+    S = {"decode": 1, "chunk": 8, "fresh": 11}[kind]
+    ctx = {"decode": [13, 30], "chunk": [21, 9], "fresh": [0, 0]}[kind]
+    return _latent_walk_case(ctx, S, H=4, bs=4, NB=8)
 
 
 @pytest.mark.parametrize("kind", ["decode", "chunk", "fresh"])
@@ -387,12 +401,109 @@ def test_latent_kernel_matches_xla_with_every_other_page_poisoned(
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+# where a row's context ends, in blocks of T tokens and pages of 16: the
+# frontier's block is then walked by the loop of the frontier's bound (a
+# part of a block) or as a whole block, and is a tile's first, second or
+# third block
+_ENDS = {
+    "below_one_block": lambda T: T // 4 + 3,
+    "in_a_blocks_first_page": lambda T: T + 5,
+    "in_a_blocks_last_page": lambda T: 2 * T - 3,
+    "at_a_blocks_edge": lambda T: 2 * T - 1,
+}
+
+
+@pytest.mark.parametrize("ends", sorted(_ENDS))
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("heads", [64, 128])
+def test_latent_kernel_walks_whole_and_partial_blocks(jax_cpu, heads, kind,
+                                                      ends):
+    """The latent kernel at BOTH block lengths it chooses by a tile's rows
+    (a decode tile of ``heads`` rows: ``_latent_tokens`` few-row length; a
+    chunk's tile of 8 x ``heads`` rows: the many-row length), at both
+    cells' head counts, == the XLA path, every page outside the tables
+    poisoned. The batch holds the context under test between two others,
+    so a tile's first block is started under the tile BEFORE it, whole or
+    in part, and the slot it lands in alternates with that tile's count of
+    blocks."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import (
+        _LATENT_Q_BLOCK, _latent_tokens, latent_attention,
+    )
+
+    S = 1 if kind == "decode" else _LATENT_Q_BLOCK
+    T = _latent_tokens(S * heads)
+    assert T == (1024 if kind == "decode" else 256)
+    ctx = [T + 17, _ENDS[ends](T), 2 * T]
+    q, lat, rope, tables, pos, C = _latent_walk_case(ctx, S, heads)
+    want = latent_attention(
+        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        latent_dim=C, scale=0.3, backend="xla", layer=1)
+    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+                           scale=0.3, backend="pallas", layer=1)
+    assert got.shape == (*q.shape[:3], C)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(want).max()) > 0.05
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_latent_chunk_of_many_tiles_is_served(jax_cpu):
+    """A chunk of 20 queries is three tiles of 8 (the last padded): each
+    tile has its own frontier, the grid's second axis, and the tile after
+    a row's last is the NEXT row's first."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import latent_attention
+
+    q, lat, rope, tables, pos, C = _latent_walk_case(
+        [250, 0, 300, 5], 20, 64)
+    want = latent_attention(
+        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        latent_dim=C, scale=0.3, backend="xla", layer=1)
+    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+                           scale=0.3, backend="pallas", layer=1)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` of a traced function, the ones inside its
+    nested jits too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
+
+
+def _loop_bodies(jaxpr):
+    """The bodies of every loop in a kernel's jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    if eqn.primitive.name in ("scan", "while"):
+                        found.append(inner)
+                    found += _loop_bodies(inner)
+    return found
+
+
 def test_a_page_is_copied_once_for_keys_and_values(jax_cpu):
-    """The kernel's own text, counted: a compute block starts ONE copy a
-    plane's page (the latent page, the rotary page) and waits for each
-    once; the latent tile then feeds two of the three products (``q~ .
-    c`` and ``p . c``), the rotary tile the third. Nothing copies a page a
-    second time for the values."""
+    """The kernel's own text, counted: wherever a block's copies are
+    started (the call's first block; a whole block, one traced page
+    unrolled as it is lowered; a block the frontier cuts, by a loop of the
+    frontier's bound) a page gets ONE copy a plane (the latent page, the
+    rotary page); a whole block is awaited once a plane, a cut block once a
+    copy. The latent tile then feeds two of the three products (``q~ . c``
+    and ``p . c``), the rotary tile the third, and the block's compute is
+    in the text ONCE. Nothing copies a page a second time for the values."""
     import functools
 
     import jax
@@ -405,13 +516,18 @@ def test_a_page_is_copied_once_for_keys_and_values(jax_cpu):
     jaxpr = jax.make_jaxpr(functools.partial(
         paged_latent_attention_pallas, latent_dim=C, scale=0.3, layer=1,
         interpret=False))(q, lat, rope, tables, pos)
-    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    calls = _pallas_calls(jaxpr.jaxpr)
     assert len(calls) == 1
     assert LATENT_KERNEL_NAME in str(calls[0].params["name"]) \
         or LATENT_KERNEL_NAME in str(calls[0].params)
-    body = str(calls[0].params["jaxpr"])
-    assert body.count("dma_start") == 2, body.count("dma_start")
-    assert body.count("dma_wait") == 2, body.count("dma_wait")
+    kernel = calls[0].params["jaxpr"]
+    body = str(kernel)
+    # three stretches start copies, each a loop over pages: two copies a page
+    starts = [str(loop).count("dma_start") for loop in _loop_bodies(kernel)
+              if "dma_start" in str(loop) and "dot_general" not in str(loop)]
+    assert starts == [2, 2, 2], starts
+    assert body.count("dma_start") == 6, body.count("dma_start")
+    assert body.count("dma_wait") == 4, body.count("dma_wait")
     assert body.count("dot_general") == 3, body.count("dot_general")
 
 
