@@ -40,19 +40,22 @@ def pow2_buckets(lo: int, hi: int) -> tuple[int, ...]:
     return tuple(out)
 
 
-def stepped_buckets(hi: int) -> tuple[int, ...]:
+def stepped_buckets(hi: int, wide: bool = False) -> tuple[int, ...]:
     """Ascending counts covering [1, hi] for a dimension whose padding is
     paid in full (the rows of a packed prefill step, each a q tile of
     tokens: serve/llm/engine.py): every count up to 8, then steps of at
     most a third. A count pads by under 12% on average against a power-of-
     two ladder's 33%, for about twice the programs: 12 up to 20, 8 up
+    to 8. ``wide``: the ladder where a program is dear, every count up to
+    4, then steps of at most a half: 8 up to 16 (1 2 3 4 6 9 13 16), 6 up
     to 8."""
     if hi < 1:
         raise ValueError(f"need hi >= 1, got {hi}")
+    every, step = (4, 2) if wide else (8, 3)
     out = []
     b = 1
     while b < hi:
         out.append(b)
-        b = b + 1 if b < 8 else b + b // 3
+        b = b + 1 if b < every else b + b // step
     out.append(hi)
     return tuple(out)

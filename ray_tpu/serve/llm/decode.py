@@ -35,8 +35,9 @@ class Family:
     counters the step programs keep inside ``state``, read for
     ``stats()``. ``state_rows``: whether ``state`` holds rows a sequence
     (True; a prefix hit, a pause or a handoff would need them at a block's
-    boundary) or only such counters (False: the slots then only tell a
-    step's padding rows from its real ones)."""
+    boundary) or only such counters (False: the cache manager keeps no
+    slot for it, and ``slots`` only tells a step's real rows, 1, from its
+    padding, 0, whether a row is a request or a piece of one)."""
 
     init: Callable
     prefill: Callable

@@ -603,8 +603,11 @@ class LLMEngine:
         else:
             n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
             head_dim = model_cfg.head_dim
-        # one slot per running sequence, and slot 0, the garbage sink
-        slots = cfg.max_batch_size + 1 if self._stateful else 0
+        # one slot per running sequence, and slot 0, the garbage sink:
+        # for state ROWS. Counters alone are no row a sequence: such a
+        # family holds no slot, and its steps are told only which rows are
+        # real (``_slots_buf_locked``)
+        slots = cfg.max_batch_size + 1 if self._state_rows else 0
         self.cache = PagedKVCache(
             KVCacheConfig(
                 # the pool spans the layers that cache K/V: all of them,
@@ -706,7 +709,8 @@ class LLMEngine:
         ) if composed else None
         # A prefill step is filled by TOKENS where the cache manager says
         # a sequence may be split over the rows of one step
-        # (``one_table``): a row is then a PIECE of a prompt, as many
+        # (``one_table``: pages under one table are all it carries, by
+        # heads or in planes): a row is then a PIECE of a prompt, as many
         # tokens as the kernel gives one q tile (or the whole chunk where
         # that is shorter), at its true first position under its
         # sequence's table; a step holds up to ``_piece_rows[-1]`` of
@@ -725,8 +729,19 @@ class LLMEngine:
             top = min(self._length_buckets[-1], model_cfg.max_seq_len)
             chunk = min(cfg.prefill_chunk_tokens or top, top)
             self._piece = min(Q_TILE, chunk)
+            # A rung is a program, made at every start of a process. Over
+            # a layer stack that is UNROLLED (a list of layers:
+            # models/cached.py ``_walk``) its text is as long as the
+            # layers are many: ~1.3 s of set-up a rung and ~18 MB of the
+            # device's memory where a scanned stack's costs a quarter
+            # (PERF.md section 6, PR 47), so such an engine takes the
+            # ladder of wider steps: 3 points of fill for a third fewer
+            # programs.
+            unrolled = any(isinstance(leaf, list)
+                           for leaf in self.executor.params.values())
             self._piece_rows = stepped_buckets(
-                max(-(-chunk // self._piece), cfg.max_prefill_batch))
+                max(-(-chunk // self._piece), cfg.max_prefill_batch),
+                wide=unrolled)
             self._piece_nb = self._table_blocks(self._length_buckets[-1])
         self._lock = threading.RLock()
         self._work = threading.Condition(self._lock)
@@ -2062,7 +2077,7 @@ class LLMEngine:
         logits, and that write lands in a shared hashed block, so it
         always triggers exactly one copy-on-write copy."""
         bs = self.cfg.block_size
-        if self._stateful and not self.cache.free_slots:
+        if self._state_rows and not self.cache.free_slots:
             # every state slot is held (a cancelled row's goes back only
             # when its in-flight step has been reconciled)
             return False
@@ -2244,13 +2259,22 @@ class LLMEngine:
         A request's first token is the id at its LAST piece's row
         (``_InFlight.ids_at``).
 
+        Packed so: K and V by head (``llama``, ``gpt``; plain or
+        quantized) and a pool in planes (``pangu_ultra_moe``,
+        ``longcat_flash``: a layer writes the step's latent rows to the
+        planes before its kernel reads them back through the table, and
+        their ``state`` is counters, no row a sequence: ``slots`` then
+        says BY ROW which rows are real, ``_slots_buf_locked``).
+
         Every other layout keeps a row a request, padded to the longest
-        row's bucket: state slots beside the pool (a piece's short
+        row's bucket, for the reason ``KVCacheConfig.why_not_split``
+        gives: state rows beside the pool (``lfm2_moe``: a piece's short
         convolution needs the piece before it, inside the same step),
-        tables by group (freeing behind a window, a ring composed by
-        position), a pool in planes. There a cold whole prompt takes the
-        program without ``start`` (kind ``prefill``), anything mid-prompt
-        the chunk program at true positions."""
+        tables by group (``laguna``, ``smallthinker``: freeing behind a
+        window), a ring and a slot table composed by position
+        (``evabyte``). There a cold whole prompt takes the program without
+        ``start`` (kind ``prefill``), anything mid-prompt the chunk
+        program at true positions."""
         P = self._piece
         if P and not self._prefill_steps:
             self._warm_pieces_locked()
@@ -2322,7 +2346,7 @@ class LLMEngine:
             lengths = self._scratch_buf("pf_lengths", (B,), np.int32)
             starts = self._scratch_buf("pf_starts", (B,), np.int32)
             tables = self._tables_buf("pf_tables", B, nb)
-            slots = self._slots_buf_locked("pf_slots", batch, B)
+            slots = self._slots_buf_locked("pf_slots", batch, B, first)
             # reused buffers: stale padding rows/columns must be re-zeroed
             # (a stale table row could point at blocks now owned by a LIVE
             # sequence — padding writes must stay on the garbage block)
@@ -2440,7 +2464,11 @@ class LLMEngine:
                 np.zeros((rows, self._piece), np.int32),
                 np.ones((rows,), np.int32), np.zeros((rows,), np.int32),
                 np.zeros((rows, self._piece_nb), np.int32),
-                self._sample_args_locked([], rows), self._ids_width(rows))
+                self._sample_args_locked([], rows), self._ids_width(rows),
+                # slot 0: padding, counted nowhere. An array of its own,
+                # as the others: nothing syncs these launches, so a
+                # staging buffer could be rewritten under one (CPU)
+                np.zeros((rows,), np.int32) if self._stateful else None)
             self._step_begin = obs.clock()
 
     def _ids_width(self, rows: int) -> int | None:
@@ -3129,16 +3157,24 @@ class LLMEngine:
                 r.fsm.stage_verify_masks(mask[i], proposals[i])
         return mask
 
-    def _slots_buf_locked(self, name: str, batch: list,
-                          B: int) -> np.ndarray | None:
-        """[B] int32: each row's state slot, padding rows on slot 0 (the
-        garbage sink); None for a family that keeps no such state."""
+    def _slots_buf_locked(self, name: str, batch: list, B: int,
+                          first: list | None = None) -> np.ndarray | None:
+        """[B] int32, BY ROW: rows ``first[k]`` to ``first[k + 1]`` are
+        request k's (None: row k is; a packed step's rows are pieces).
+        Where the family keeps state rows, each row's state slot; where
+        it keeps only counters, 1 on every real row: all such a step
+        program reads of its slots is which rows are padding (slot 0, the
+        garbage sink, either way). None for a family that keeps no
+        state."""
         if not self._stateful:
             return None
         slots = self._scratch_buf(name, (B,), np.int32)
-        slots[len(batch):] = 0
-        for i, r in enumerate(batch):
-            slots[i] = self.cache.slot(r.id)
+        if first is None:
+            first = range(len(batch) + 1)
+        slots[first[-1]:] = 0
+        for k, r in enumerate(batch):
+            slots[first[k]:first[k + 1]] = (
+                self.cache.slot(r.id) if self._state_rows else 1)
         return slots
 
     def _tables_buf(self, name: str, B: int, nb: int) -> np.ndarray:

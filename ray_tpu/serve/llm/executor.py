@@ -435,7 +435,7 @@ class ModelExecutor:
         return ids
 
     def warm_prefill_chunk(self, tokens, lengths, starts, tables,
-                           sample, ids_width=None) -> None:
+                           sample, ids_width=None, slots=None) -> None:
         """``prefill_chunk`` over padding rows alone (length 1 at position
         0 under an all-zero table: block 0 is the garbage sink), for the
         shape's sake: the program exists afterwards, and ``_warm_feed``'s
@@ -443,10 +443,11 @@ class ModelExecutor:
         its packed ladder so before its first prefill step. No step of the
         scheduler's: under no phase and in no staging counter. The
         arguments are of the kinds a step's are (a jitted call's fast path
-        is keyed by them: ``decode_step``)."""
+        is keyed by them: ``decode_step``); ``slots`` all zero where the
+        family keeps state (slot 0: padding, counted nowhere)."""
         ids, self.cache.k, self.cache.v, self.cache.state = self.fns.prefill(
             self.params, self.cache.k, self.cache.v, tokens, lengths, tables,
-            start=starts, sample=sample, state=self.cache.state)
+            start=starts, sample=sample, state=self.cache.state, slots=slots)
         self._hand_on(ids, sample, ids_width)
 
     def decode_step(self, tokens, positions, tables, sample=None, span=None,
@@ -769,7 +770,8 @@ class ModelExecutor:
         state = None
         if self.cache.state is not None:
             state = {
-                "slots": cfg.state_slots - 1,  # slot 0 is the garbage sink
+                # slot 0 is the garbage sink; 0: counters, no row a sequence
+                "slots": max(cfg.state_slots - 1, 0),
                 "bytes": int(sum(
                     t.size * t.dtype.itemsize
                     for t in jax.tree.leaves(self.cache.state))),

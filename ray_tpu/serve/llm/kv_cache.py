@@ -59,7 +59,11 @@ last one returned, so a step still in flight is ordered before the reuse,
 and a sequence's first chunk starts from zeros whatever its slot held.
 ``prefix_reuse=False`` turns the prefix cache's LOOKUPS off for such a
 family (a hit would need the state as it stood at the block boundary):
-``peek_prefix`` answers as a miss and nothing is content-addressed.
+``peek_prefix`` answers as a miss and nothing is content-addressed. A
+family whose ``state`` holds only counters (decode.py ``Family.state_rows``
+False) keeps no row a sequence and is given no slots: ``state`` rides the
+step programs all the same, and what it is handed as ``slots`` only tells
+a step's real rows (1) from its padding (0).
 
 Tables by group (``groups``): a family whose layers do not all keep the
 same tokens (models/laguna.py: full layers keep every one, sliding layers
@@ -115,7 +119,10 @@ where it stands; ``row_bytes`` says what the layer's mathematics needs,
 1,152 B, ``stored_row_bytes`` what the pool holds, 1,280 B: +11%). One
 table, every layer keeps every token: blocks, reservations, the prefix
 cache, copy-on-write and preemption are what they are for K and V by head,
-since a block's bytes are all they touch. The host tier and the RTKV
+since a block's bytes are all they touch, and a prompt may be split over
+the rows of one prefill step as theirs may (``one_table``: a step's rows
+are written to the planes before the kernel reads them back through the
+table). The host tier and the RTKV
 record describe a block as ``n_kv_head x head_dim`` twice and cannot say
 "planes" yet: the engine refuses them for such a family.
 
@@ -209,7 +216,9 @@ class KVCacheConfig:
     # from the kind.
     quantization: str | None = None
     # Slots of per-sequence state beside the pool, slot 0 (the garbage
-    # sink) included; 0: the family keeps none. See the module docstring.
+    # sink) included; 0: the family keeps no ROW a sequence (counters its
+    # step programs keep in ``state`` ask for none). See the module
+    # docstring.
     state_slots: int = 0
     # False: no block is content-addressed and every prefix lookup misses
     # (a family whose recurrent state a mapped block would not restore).
@@ -255,15 +264,31 @@ class KVCacheConfig:
 
     @property
     def one_table(self) -> bool:
-        """Whether all a sequence carries from token to token is K/V pages
-        under ONE table: no state slot beside the pool (a piece's short
-        convolution would need the piece before it), no tables by group
-        (a window's blocks go back behind a position; a ring and a slot
-        table are composed by position) and no planes. Only then may the
-        scheduler split a sequence over ROWS of one prefill step, each row
-        at its true positions under the same table (engine.py "a prefill
-        step is filled by tokens")."""
-        return not (self.groups or self.planes or self.state_slots)
+        """Whether all a sequence carries from token to token is pages
+        under ONE table, written before they are read, by position. Only
+        then may the scheduler split a sequence over ROWS of one prefill
+        step, each row at its true positions under the same table
+        (engine.py "a prefill step is filled by tokens"). ``why_not_split``
+        gives the reason where it may not."""
+        return self.why_not_split is None
+
+    @property
+    def why_not_split(self) -> str | None:
+        """Why a sequence may NOT be split over rows of one prefill step
+        (None: it may). K and V by head, plain or quantized, and a pool in
+        planes are pages under one table and nothing else; a family whose
+        ``state`` holds counters alone asks for no slots and is not held
+        back by them."""
+        if self.composed:
+            return ("a ring and a slot table, composed into a step's "
+                    "table by position")
+        if self.groups:
+            return ("tables by group: a window's blocks go back behind a "
+                    "position")
+        if self.state_slots:
+            return ("state rows beside the pool: a piece's short "
+                    "convolution needs the piece before it")
+        return None
 
     @property
     def window_chunk(self) -> tuple[int, int] | None:

@@ -783,13 +783,17 @@ def test_evabyte_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill",
+                                  "prefill_packed"])
 def test_pangu_step_programs_compile_at_published_widths(
         one_chip, monkeypatch, kind):
     """The openPangu cell's step programs as the executor compiles them,
     at the cell's own shapes: the 128-row decode step and the 1 x 2,048
     chunk over a table ``[B, 768]`` (the 12,288-token bucket), the fresh
-    prefill over ``[1, 128]``. The pool is two PLANES, ``[5, 40961, 16,
+    prefill over ``[1, 128]``; ``prefill_packed`` (ISSUE 47: what the
+    engine launches now): the chunk program over the packed ladder's top
+    rung, 16 rows of one 128-token q tile under ``[16, 768]``, whose
+    temporaries are no larger than the one-row chunk's. The pool is two PLANES, ``[5, 40961, 16,
     512]`` and ``[.., 128]`` (4.19 GB together): both are in the program's
     ``input_output_alias`` and nothing pool-sized is among its temporaries;
     each of the 5 layers calls ``paged_attention_latent`` once; and nothing
@@ -820,7 +824,8 @@ def test_pangu_step_programs_compile_at_published_widths(
     on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda: init(jax.random.PRNGKey(0), cfg)))
-    B = engine["max_batch_size"] if kind == "decode" else 1
+    B = {"decode": engine["max_batch_size"], "prefill_packed": 16}.get(
+        kind, 1)
     state = jax.tree.map(on_chip, jax.eval_shape(
         lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
     planes = [_struct((cfg.n_layer, engine["num_blocks"], 16, stored),
@@ -838,11 +843,11 @@ def test_pangu_step_programs_compile_at_published_widths(
             params, *planes, i32((B,)), i32((B,)), i32((B, ctx // 16)),
             sample=None, **more)
     else:
-        nb = ctx // 16 if kind == "prefill_chunk" else 128
-        if kind == "prefill_chunk":
+        nb = 128 if kind == "prefill" else ctx // 16
+        if kind != "prefill":
             more["start"] = i32((B,))
         lowered = fns._prefill.lower(
-            params, *planes, i32((B, 2048)), i32((B,)), i32((B, nb)),
+            params, *planes, i32((B, 2048 // B)), i32((B,)), i32((B, nb)),
             sample=None, **more)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
@@ -851,7 +856,10 @@ def test_pangu_step_programs_compile_at_published_widths(
     # 6.82 GB of weights and the two planes
     assert 10.9e9 < mem.argument_size_in_bytes < 11.1e9
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < (0.1e9 if kind == "decode" else 1.6e9), \
+    # a packed step's temporaries (1.19 GB) are under the one-row chunk's
+    # own 1,414,850,560 B
+    assert mem.temp_size_in_bytes < {
+        "decode": 0.1e9, "prefill_packed": 1.4149e9}.get(kind, 1.6e9), \
         mem.temp_size_in_bytes
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
@@ -869,12 +877,16 @@ def test_pangu_step_programs_compile_at_published_widths(
 
 # the latent kernel's calls in the two cells that hold it: 128 heads
 # (openPangu: 128-row decode over [B, 768], a 1 x 2,048 chunk) and 64
-# heads (LongCat-Flash: 96-row decode and a 1 x 1,024 chunk over [B, 384])
+# heads (LongCat-Flash: 96-row decode and a 1 x 1,024 chunk over [B, 384]);
+# ISSUE 47: the chunk as the packed ladder's top rung, rows of one
+# 128-token q tile (16 x 128 and 8 x 128: the same tiles, a row each)
 LATENT_CALLS = {
     "pangu-decode": (128, 128, 768, 40961, 1, 5),
     "pangu-chunk": (128, 1, 768, 40961, 2048, 5),
+    "pangu-packed": (128, 16, 768, 40961, 128, 5),
     "longcat-decode": (64, 96, 384, 16385, 1, 8),
     "longcat-chunk": (64, 1, 384, 16385, 1024, 8),
+    "longcat-packed": (64, 8, 384, 16385, 128, 8),
 }
 
 
@@ -912,13 +924,16 @@ def test_latent_attention_compiles(one_chip, call):
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill",
-                                  "reference"])
+                                  "prefill_packed", "reference"])
 def test_longcat_step_programs_compile_at_published_widths(
         one_chip, monkeypatch, kind):
     """The LongCat-Flash cell's step programs as the executor compiles
     them, at the cell's own shapes: the 96-row decode step and the 1 x
     1,024 chunk over a table ``[B, 384]`` (the 6,144-token bucket), the
-    fresh prefill over ``[1, 64]``. The pool is two PLANES over EIGHT
+    fresh prefill over ``[1, 64]``; ``prefill_packed`` (ISSUE 47: what the
+    engine launches now): the chunk program over the packed ladder's top
+    rung, 8 rows of one 128-token q tile under ``[8, 384]``, whose
+    temporaries are no larger than the one-row chunk's. The pool is two PLANES over EIGHT
     latent sub-layers for four layers, ``[8, 16385, 16, 512]`` and ``[..,
     128]`` (2.68 GB together): both are in the program's
     ``input_output_alias`` and nothing pool-sized is among its
@@ -971,7 +986,8 @@ def test_longcat_step_programs_compile_at_published_widths(
         # beside weights 10.35 GB and the pool 2.68 GB of the chip's 16.9
         assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
         return
-    B = engine["max_batch_size"] if kind == "decode" else 1
+    B = {"decode": engine["max_batch_size"], "prefill_packed": 8}.get(
+        kind, 1)
     state = jax.tree.map(on_chip, jax.eval_shape(
         lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
     ctx = engine["length_buckets"][-1]
@@ -985,18 +1001,21 @@ def test_longcat_step_programs_compile_at_published_widths(
             params, *planes, i32((B,)), i32((B,)), i32((B, ctx // 16)),
             sample=None, **more)
     else:
-        nb = ctx // 16 if kind == "prefill_chunk" else chunk // 16
-        if kind == "prefill_chunk":
+        nb = chunk // 16 if kind == "prefill" else ctx // 16
+        if kind != "prefill":
             more["start"] = i32((B,))
         lowered = fns._prefill.lower(
-            params, *planes, i32((B, chunk)), i32((B,)), i32((B, nb)),
+            params, *planes, i32((B, chunk // B)), i32((B,)), i32((B, nb)),
             sample=None, **more)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     # 10.35 GB of weights and the two planes
     assert 13.0e9 < mem.argument_size_in_bytes < 13.1e9
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < (0.15e9 if kind == "decode" else 1.6e9), \
+    # a packed step's temporaries (0.81 GB) are under the one-row chunk's
+    # own 885,026,304 B
+    assert mem.temp_size_in_bytes < {
+        "decode": 0.15e9, "prefill_packed": 0.8851e9}.get(kind, 1.6e9), \
         mem.temp_size_in_bytes
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
@@ -1171,7 +1190,10 @@ def test_other_families_programs_are_what_their_functions_compile_to(
 # latent call a kernel body of its own and took the ``latent`` branches out
 # of ``_paged_attention_kernel``: ``pangu-decode`` is re-recorded and
 # ``longcat-decode`` added on ITS tree; every by-head text, the kernel's
-# jaxprs among them, is the one recorded before it.
+# jaxprs among them, is the one recorded before it. ISSUE 47 (a latent
+# family's prefill step is packed) changed no program's text and ADDS the
+# two it launches in place of the one-row chunk: ``pangu-packed`` and
+# ``longcat-packed``, the ladders' top rungs.
 PARENTS_TEXT = {
     "mistral-decode": "bc061138abf9994d",
     "mistral-prefill": "b496beb461d9093b",
@@ -1187,6 +1209,10 @@ PARENTS_TEXT = {
     "pangu-decode": "97b99cac41094171",
     # new with ISSUE 46: planes [8, 16385, 16, 512 | 128], table [96, 384]
     "longcat-decode": "f51516e6bdf18da4",
+    # new with ISSUE 47: the chunk program over [16, 128] under [16, 768]
+    # and over [8, 128] under [8, 384], the cells' planes
+    "pangu-packed": "7394274b57d42267",
+    "longcat-packed": "abaaab419b1b83fe",
     # taken on ISSUE 45's parent 34388d1, before ``moe_route`` and
     # ``moe_dropless`` gained their third score and third kind of expert:
     # pool [2, 65537, 16, 512], tables [4, 48, 1024]
@@ -1275,6 +1301,15 @@ def _cell_program(which, kind, S_):
         return fns._decode, (
             params, *pools, i32((rows,)), i32((rows,)), i32(tables)), {
                 "sample": None, **more}
+    if kind == "packed":
+        # the chunk program over the packed ladder's top rung: rows of one
+        # 128-token q tile under the widest context's table
+        rows = {"pangu": 16, "longcat": 8}[which]
+        return fns._prefill, (
+            params, *pools, i32((rows, 128)), i32((rows,)),
+            i32((rows, tables[-1]))), {
+                "sample": None, "start": i32((rows,)),
+                "state": more["state"], "slots": i32((rows,))}
     return fns._prefill, (
         params, *pools, i32((4, 2048)), i32((4,)), i32((4, 128))), {
             "sample": None}

@@ -633,7 +633,7 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     engine = _engine(cfg, params, attention_backend=backend)
     prompts = _prompts([5, 23, 40, 61], seed=3)
     streams = [engine.submit(prompts[0], max_new_tokens=12, temperature=0.0)]
-    engine.step()  # a whole prompt alone: the fresh prefill program
+    engine.step()  # a whole prompt alone: one piece of a packed step
     streams += [engine.submit(p, max_new_tokens=12, temperature=0.0)
                 for p in prompts[1:]]
     _drive(engine, streams)
@@ -644,9 +644,12 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
         rows = logits[len(p) - 1: len(p) + 11]
         deficit = rows.max(-1) - rows[np.arange(12), out]
         assert float(deficit.max()) < 1e-4, deficit
+    # ISSUE 47: a latent pool's prefill steps are packed, cold prompt or
+    # not: the chunk program over the ladder's rungs, no ``prefill`` kind
     kinds = {sig[0] for sig in engine.fns.signatures}
-    assert {"prefill", "prefill_chunk", "decode"} <= kinds
+    assert kinds == {"prefill_chunk", "decode"}
     st = engine.stats()
+    assert st["prefill_steps_packed"] == st["prefill_steps"] > 0
     assert st["kv_used_blocks"] == 0 and st["prefix_reuse"] is True
     assert st["kv_pool"]["kind"] == "latent"
     assert st["kv_pool"]["row_bytes"] == (16 + 4) * 4

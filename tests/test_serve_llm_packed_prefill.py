@@ -337,39 +337,37 @@ def test_a_chunk_cap_bounds_the_step_not_the_row(models, family):
 
 
 # what keeps a row a request, and why the cache manager says so
+_TINY = dict(block_size=4, num_blocks=129, max_batch_size=4,
+             prefill_chunk_tokens=16, length_buckets=(16, 32, 64, 128))
 ROWS = {
-    "lfm2_moe": dict(num_blocks=65, max_batch_size=4),
-    "laguna": dict(block_size=4, num_blocks=129, max_batch_size=4,
-                   prefill_chunk_tokens=16,
-                   length_buckets=(16, 32, 64, 128)),
-    "evabyte": dict(block_size=4, num_blocks=257, max_batch_size=4,
-                    prefill_chunk_tokens=16, length_buckets=(16, 160)),
-    "pangu_ultra_moe": dict(block_size=4, num_blocks=129, max_batch_size=4,
-                            prefill_chunk_tokens=16,
-                            length_buckets=(16, 32, 64, 128)),
-    "smallthinker": dict(block_size=4, num_blocks=129, max_batch_size=4,
-                         prefill_chunk_tokens=16,
-                         length_buckets=(16, 32, 64, 128)),
-    "longcat_flash": dict(block_size=4, num_blocks=129, max_batch_size=4,
-                          prefill_chunk_tokens=16,
-                          length_buckets=(16, 32, 64, 128)),
+    "lfm2_moe": (dict(num_blocks=65, max_batch_size=4), "state rows"),
+    "laguna": (_TINY, "tables by group"),
+    "evabyte": (dict(_TINY, num_blocks=257, length_buckets=(16, 160)),
+                "a ring and a slot table"),
+    "smallthinker": (_TINY, "tables by group"),
 }
+# ... and what is packed besides ``llama`` and ``gpt`` (ISSUE 47): a pool in
+# planes under one table, its ``state`` counters alone
+PLANES = ("longcat_flash", "pangu_ultra_moe")
 
 
 @pytest.mark.parametrize("family", sorted(ROWS))
 def test_other_layouts_keep_a_row_a_request(jax_cpu, family):
-    """State slots beside the pool, tables by group, a ring and a slot
-    table, a pool in planes: ``one_table`` is False, no ladder exists, and
-    a step's rows are its requests padded to the longest row's bucket, as
-    they were; the slots are counted all the same."""
+    """State rows beside the pool, tables by group, a ring and a slot
+    table: ``one_table`` is False for the reason the cache manager gives,
+    no ladder exists, and a step's rows are its requests padded to the
+    longest row's bucket, as they were; the slots are counted all the
+    same."""
     from ray_tpu.serve._shapes import pad_to_bucket
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
 
-    engine = LLMEngine(EngineConfig(model=family, **ROWS[family]),
+    settings, why = ROWS[family]
+    engine = LLMEngine(EngineConfig(model=family, **settings),
                        auto_step=False)
     cache = engine.cache.cfg
     assert not cache.one_table and engine._piece is None
-    assert cache.state_slots or cache.groups or cache.planes
+    assert cache.why_not_split.startswith(why)
+    assert bool(cache.state_slots) == (family in ("lfm2_moe", "laguna"))
     vocab = min(engine.model_cfg.vocab_size, 300)
     prompts = _prompts((5, 23, 12), seed=3, vocab=vocab)
     streams = [engine.submit(p, max_new_tokens=4, temperature=0.0)
@@ -382,6 +380,7 @@ def test_other_layouts_keep_a_row_a_request(jax_cpu, family):
     assert len(flight) == st["prefill_steps"]
     cap = engine.cfg.prefill_chunk_tokens or 10 ** 9
     for r in flight:
+        assert "pieces" not in r
         assert r["bucket_b"] == pad_to_bucket(
             r["batch"], engine._batch_buckets)
         assert r["bucket_len"] in engine._length_buckets
@@ -397,33 +396,94 @@ def test_other_layouts_keep_a_row_a_request(jax_cpu, family):
     engine.shutdown()
 
 
-def test_one_table_is_what_the_cache_manager_says():
+@pytest.mark.parametrize("family", PLANES)
+def test_a_pool_in_planes_under_counters_is_packed(jax_cpu, family):
+    """The latent families at the other layouts' tiny settings: a chunk of
+    16 tokens is shorter than a q tile, so a piece is the chunk, the
+    ladder 1-4 rows, and the three prompts go as 1 + 1 + 1 pieces, then
+    the 23-token prompt's second chunk (tests/test_serve_llm_packed_latent
+    .py holds them to the reference at pieces of 128)."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    engine = LLMEngine(EngineConfig(model=family, **_TINY), auto_step=False)
+    cache = engine.cache.cfg
+    assert cache.planes and cache.one_table and cache.why_not_split is None
+    assert cache.state_slots == 0 and engine.cache.state is not None
+    assert engine._piece == 16 and engine._piece_rows == (1, 2, 3, 4)
+    prompts = _prompts((5, 23, 12), seed=3, vocab=300)
+    streams = [engine.submit(p, max_new_tokens=4, temperature=0.0)
+               for p in prompts]
+    _drive(engine, streams)
+    st = engine.stats()
+    assert st["prefill_steps_packed"] == st["prefill_steps"] == 2
+    flight = [r for r in engine.debug_dump()["steps"]
+              if r["kind"].startswith("prefill")]
+    assert [(r["kind"], r["pieces"], r["bucket_b"], r["bucket_len"])
+            for r in flight] == [("prefill_chunk", 3, 3, 16),
+                                 ("prefill_chunk", 1, 1, 16)]
+    assert st["prefill_slots"] == 4 * 16
+    assert st["prefill_tokens_total"] == 5 + 23 + 12
+    assert st["executor"]["state"]["slots"] == 0
+    engine.shutdown()
+
+
+_PLAIN = dict(n_layer=2, n_kv_head=2, head_dim=8)
+_LATENT = dict(n_layer=2, n_kv_head=1, head_dim=24,
+               planes=(("latent", 16, 128), ("rope", 8, 128)))
+# layout -> (``KVCacheConfig`` arguments, why a sequence may not be split
+# over the rows of one prefill step; None: it may)
+LAYOUTS = {
+    "heads": (_PLAIN, None),
+    "quantized heads": (dict(_PLAIN, quantization="int8"), None),
+    "planes, counters-only state": (_LATENT, None),
+    "state rows": (dict(_PLAIN, state_slots=5),
+                   "state rows beside the pool: a piece's short "
+                   "convolution needs the piece before it"),
+    "sliding groups": (dict(_PLAIN, groups=((None, (0,)), (8, (1,)))),
+                       "tables by group: a window's blocks go back behind "
+                       "a position"),
+    "ring + slots": (dict(_PLAIN, block_size=4, groups=(
+        (("ring", 32), (0, 1)), (("slots", 8), (0, 1)))),
+        "a ring and a slot table, composed into a step's table by "
+        "position"),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_one_table_is_what_the_cache_manager_says(layout):
     from ray_tpu.serve.llm.kv_cache import KVCacheConfig
 
-    plain = dict(n_layer=2, n_kv_head=2, head_dim=8)
-    assert KVCacheConfig(**plain).one_table
-    assert KVCacheConfig(**plain, quantization="int8").one_table
-    assert KVCacheConfig(**plain, host_cache_bytes=1 << 20).one_table
-    assert not KVCacheConfig(**plain, state_slots=5).one_table
-    assert not KVCacheConfig(
-        **plain, groups=((None, (0,)), (8, (1,)))).one_table
-    assert not KVCacheConfig(
-        n_layer=2, n_kv_head=1, head_dim=24,
-        planes=(("latent", 16, 128), ("rope", 8, 128))).one_table
+    settings, why = LAYOUTS[layout]
+    cache = KVCacheConfig(**settings)
+    assert cache.why_not_split == why
+    assert cache.one_table == (why is None)
+    # a host tier changes nothing of it; state rows refuse a pool in planes
+    # as they refuse one by heads
+    if not cache.planes:
+        assert KVCacheConfig(
+            **settings, host_cache_bytes=1 << 20).one_table == (why is None)
+    if not cache.state_slots:
+        assert not KVCacheConfig(**settings, state_slots=3).one_table
 
 
-@pytest.mark.parametrize("hi,want", [
-    (1, (1,)), (4, (1, 2, 3, 4)), (8, (1, 2, 3, 4, 5, 6, 7, 8)),
-    (20, (1, 2, 3, 4, 5, 6, 7, 8, 10, 13, 17, 20)),
-    (64, (1, 2, 3, 4, 5, 6, 7, 8, 10, 13, 17, 22, 29, 38, 50, 64)),
+@pytest.mark.parametrize("hi,wide,want", [
+    (1, False, (1,)), (4, False, (1, 2, 3, 4)),
+    (8, False, (1, 2, 3, 4, 5, 6, 7, 8)),
+    (20, False, (1, 2, 3, 4, 5, 6, 7, 8, 10, 13, 17, 20)),
+    (64, False,
+     (1, 2, 3, 4, 5, 6, 7, 8, 10, 13, 17, 22, 29, 38, 50, 64)),
+    # where a program is dear (an unrolled stack's: ISSUE 47)
+    (1, True, (1,)), (4, True, (1, 2, 3, 4)), (8, True, (1, 2, 3, 4, 6, 8)),
+    (16, True, (1, 2, 3, 4, 6, 9, 13, 16)),
 ])
-def test_stepped_buckets(hi, want):
+def test_stepped_buckets(hi, wide, want):
     from ray_tpu.serve._shapes import pad_to_bucket, stepped_buckets
 
-    ladder = stepped_buckets(hi)
+    ladder = stepped_buckets(hi, wide=wide)
     assert ladder == want
-    # a count pads by at most a third
+    # a count pads by at most a third (a half on the wider ladder)
     for n in range(1, hi + 1):
-        assert n <= pad_to_bucket(n, ladder) <= max(n + 1, n + n // 3 + 1)
+        assert n <= pad_to_bucket(n, ladder) <= max(
+            n + 1, n + n // (2 if wide else 3) + 1)
     with pytest.raises(ValueError):
         stepped_buckets(0)
