@@ -33,7 +33,9 @@ own, in a ``CachedFamily``:
   token that every head reads as key and as value, the pool in planes:
   models/pangu_ultra_moe.py) calls ``attend(q, row, rope, latent=scale)``
   with q ``[B, S, H, C + R]`` and ONE ``row [B, S, C]`` and ``rope [B, S,
-  R]``, gets ``[B, S, H * C]`` back and un-absorbs it itself;
+  R]``, gets ``[B, S, H * C]`` back and un-absorbs it itself. A layer
+  that SELECTS the pages it attends (models/minicpm_sala.py) calls
+  ``attend(q, k, v, select=Selection(...))`` (ops/sparse_select.py);
 - ``final_norm(params, x, cfg)`` and ``head(params, h, cfg)`` (float32
   logits over ``[..., D]``);
 - ``stack``: the key of ``params`` that holds the layers. A tree whose
@@ -71,6 +73,10 @@ from ray_tpu.ops.paged_attention import (
     resolve_backend,
 )
 from ray_tpu.ops.sampling import sample_tokens, verify_tokens
+from ray_tpu.ops.sparse_select import (
+    sparse_decode_attention,
+    sparse_prefill_attention,
+)
 
 
 @dataclass(frozen=True)
@@ -140,7 +146,8 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
 
 
 def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
-                 tables=None, window=None, then=None, latent=None):
+                 tables=None, window=None, then=None, latent=None,
+                 select=None):
     """The cache side of one attention layer, on the WHOLE pools and the
     layer's index in them (an int32 scalar, traced under the scan): the
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
@@ -160,11 +167,21 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     S, R]``, one of each for all heads, written to ``cache_k`` and
     ``cache_v`` (the latent and the rotary plane); ``q`` is ``[B, S, H, C
     + R]`` and what comes back ``[B, S, H * C]``, every kind of step
-    through the one call."""
+    through the one call.
+
+    ``select``: the layer SELECTS the pages it attends
+    (ops/sparse_select.py ``Selection``; models/minicpm_sala.py): K and V
+    are written as ever, then a decode row attends the pages of its list
+    and nothing else of its context (``paged_attention_sparse``), and a
+    chunk's queries switch, each by its own position, between every key
+    and the blocks chosen for it (``sparse_prefill_attention``)."""
     B, S = q.shape[:2]
     backend = cfg.attention_backend
     if tables is None:
         tables = step.block_tables
+    if select is not None:
+        return _attend_selected(
+            step, cache_k, cache_v, layer, q, k, v, tables, backend, select)
     if latent is not None:
         at = step.pos if step.at is None else step.at
         one = step.kind == "decode"  # its rows are written as [B, .]
@@ -219,6 +236,27 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     return attn.reshape(B, S, -1), cache_k, cache_v
 
 
+def _attend_selected(step, cache_k, cache_v, layer, q, k, v, tables, backend,
+                     select):
+    """``attend_layer`` for a layer that selects its pages."""
+    B, S = q.shape[:2]
+    if step.kind == "decode":
+        cache_k, cache_v = write_kv(
+            cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
+            layer=layer)
+        attn = sparse_decode_attention(
+            q[:, 0], cache_k, cache_v, select.pages, select.vpos, layer,
+            backend=backend)
+        return attn.reshape(B, S, -1), cache_k, cache_v
+    cache_k, cache_v = write_kv(
+        cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
+        layer=layer)
+    attn = sparse_prefill_attention(
+        q, cache_k, cache_v, tables, step.pos, step.valid, select.seg_rows,
+        layer, select.cfg, backend=backend)
+    return attn.reshape(B, S, -1), cache_k, cache_v
+
+
 def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
     """``x`` through the stack, every attending layer updating the pools
     where they stand (the step programs donate them: serve/llm/decode.py).
@@ -247,13 +285,14 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
 
     attended = 0  # the pool spans the attending layers only
 
-    def attend(q, k, v, *, group=None, slot=None, window=None, latent=None):
+    def attend(q, k, v, *, group=None, slot=None, window=None, latent=None,
+               select=None):
         nonlocal cache_k, cache_v, attended
         attn, cache_k, cache_v = attend_layer(
             step, cache_k, cache_v, attended if slot is None else slot,
             q, k, v, cfg,
             None if group is None else step.block_tables[group], window,
-            latent=latent)
+            latent=latent, select=select)
         attended += 1
         return attn
 

@@ -608,6 +608,15 @@ class LLMEngine:
         # family holds no slot, and its steps are told only which rows are
         # real (``_slots_buf_locked``)
         slots = cfg.max_batch_size + 1 if self._state_rows else 0
+        # ... and whose ``state`` also holds an array by BLOCK ID (a layer
+        # that selects its pages keeps their compressed keys so)
+        by_block = family.block_state_bytes is not None
+        if by_block:
+            self._check_selected_pages(cfg, *model_cfg.kv_selected_pages)
+        # what the family says of a step from the positions its rows query,
+        # for the step's ``executor.dispatch`` span (decode.py
+        # ``Family.step_attrs``); None: nothing of its own
+        self._step_attrs = family.step_attrs
         self.cache = PagedKVCache(
             KVCacheConfig(
                 # the pool spans the layers that cache K/V: all of them,
@@ -621,6 +630,8 @@ class LLMEngine:
                 host_cache_bytes=cfg.host_cache_bytes,
                 quantization=quant,
                 state_slots=slots,
+                block_state_bytes=(family.block_state_bytes(model_cfg)
+                                   if by_block else 0),
                 # a prefix hit would need the recurrent state as it stood
                 # at the block boundary, or a windowed group's blocks there,
                 # which were given back: no reuse for such a family
@@ -628,8 +639,9 @@ class LLMEngine:
                 groups=groups,
                 planes=planes,
             ),
-            state=(family.init_state(model_cfg, slots)
-                   if self._stateful else None),
+            state=(family.init_state(
+                model_cfg, slots, *([cfg.num_blocks] if by_block else []))
+                if self._stateful else None),
         )
         # What the rows of a prefill step hold past their reservations
         # while a chunk is written (kv_cache.py ``prefill_room``), set
@@ -1054,11 +1066,33 @@ class LLMEngine:
                 f"one window and summarises whole chunks; it is {cap}")
 
     @staticmethod
+    def _check_selected_pages(cfg: EngineConfig, block: int,
+                              segment: int) -> None:
+        """A family whose attention selects its pages by blocks of
+        ``block`` keys (the model config's ``kv_selected_pages``): a page
+        of the cache is a selection block, and a prefill step starts on a
+        whole ``segment`` of compressed keys (ops/sparse_select.py)."""
+        if cfg.block_size != block:
+            raise ValueError(
+                f"model {cfg.model!r} selects the blocks of {block} keys "
+                f"it attends, and a page of the cache is such a block: "
+                f"block_size must be {block}, it is {cfg.block_size}")
+        chunk = cfg.prefill_chunk_tokens
+        if chunk and chunk % block:
+            raise ValueError(
+                f"model {cfg.model!r} keeps a sum of keys a segment of "
+                f"{segment} tokens, set by the step that writes the "
+                f"segment's first token: prefill_chunk_tokens must be "
+                f"whole blocks of {block}, it is {chunk}")
+
+    @staticmethod
     def _refuse_for_state(cfg: EngineConfig, quant, stateful: bool,
                           grouped: bool, composed: bool = False,
                           latent: bool = False) -> None:
         """Raise for each option that cannot yet carry what the family
-        keeps: per-sequence state beside the pool (``lfm2_moe``), tables
+        keeps: per-sequence state beside the pool (``lfm2_moe``: a short
+        convolution's rows; ``minicpm_sala``: a matrix a head of lightning
+        state, and its compressed keys by block id), tables
         by group of layers (``laguna``), a ring and a table of chunk
         summaries (``evabyte``) or one latent row a token in planes
         (``pangu_ultra_moe``, ``longcat_flash``), each with its reason."""
@@ -1082,11 +1116,12 @@ class LLMEngine:
                     "a paused stream's state slot is not demoted with its "
                     "blocks",
                 "quantization":
-                    "the family's expert and conv weights have no "
-                    "quantized path",
+                    "the weights of the layers that keep the state (expert "
+                    "and conv; lightning) have no quantized path, and a "
+                    "quantized pool has no plane for compressed keys",
                 "tp/fsdp/mesh":
                     "ShardedExecutor has no expert axis and does not place "
-                    "the state arrays"}),
+                    "the state arrays (nor split a state's heads)"}),
             (grouped, "keeps its K/V in tables by group of layers", {
                 "speculative_k":
                     "a rejected window may reach behind blocks a windowed "
@@ -1517,6 +1552,11 @@ class LLMEngine:
                 # keeps none), and whether a prefix hit can be reused
                 "state_slots": self.cache.used_slots,
                 "state_slots_high_water": cs.state_slots_high_water,
+                # what the blocks in use hold in a third plane by block id
+                # (a selecting family's compressed keys; 0 for the others)
+                "kv_compressed_key_bytes": (
+                    self.cache.used_blocks
+                    * self.cache.cfg.block_state_bytes),
                 "prefix_reuse": self.cache.cfg.prefix_reuse,
                 "prefix_reuse_why_not": self._prefix_reuse_why,
                 # a composed family's steps: the chunk summaries they
@@ -2269,7 +2309,8 @@ class LLMEngine:
         Every other layout keeps a row a request, padded to the longest
         row's bucket, for the reason ``KVCacheConfig.why_not_split``
         gives: state rows beside the pool (``lfm2_moe``: a piece's short
-        convolution needs the piece before it, inside the same step),
+        convolution needs the piece before it, inside the same step;
+        ``minicpm_sala``: a piece's lightning state likewise),
         tables by group (``laguna``, ``smallthinker``: freeing behind a
         window), a ring and a slot table composed by position
         (``evabyte``). There a cold whole prompt takes the program without
@@ -2374,6 +2415,10 @@ class LLMEngine:
         span = {"kind": kind, "seq": self._launched + 1,
                 "qk_pairs": sum(n * r.prefill_done + n * (n + 1) // 2
                                 for r, n in zip(batch, ns))}
+        if self._step_attrs is not None:
+            span.update(self._step_attrs(
+                self.model_cfg, "prefill",
+                [(r.prefill_done, n) for r, n in zip(batch, ns)]))
         if self._kv_ring:
             W, C = self._kv_ring
             # what the step's summarise call is handed a layer: every
@@ -2704,6 +2749,11 @@ class LLMEngine:
             self._decode_rows_past_window += rows_past_window
         if self._kv_ring:
             kv["kv_chunks"] = kv_chunks
+        if self._step_attrs is not None:
+            # each row queries the position of its in-flight token
+            kv.update(self._step_attrs(
+                self.model_cfg, "decode",
+                [(r.total_len + r.inflight - 1, 1) for r in batch]))
         span = {"kind": "decode", "seq": self._launched + 1, **kv}
         if self._kv_ring:
             span["eva_chunks"] = B  # each row's current chunk, read back

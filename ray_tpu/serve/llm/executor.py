@@ -740,12 +740,23 @@ class ModelExecutor:
     def counter_state(self):
         """The device arrays ``read_counters`` reads, as they stand now
         (immutable, never donated: a later step leaves this reference
-        whole), or None for a family that keeps no counters. Costs
-        nothing: the engine takes it while it holds its lock."""
+        whole; for a family whose steps donate ``state``, decode.py
+        ``Family.donated_state_counters``, copies of the counters' few
+        words), or None for a family that keeps no counters. Costs
+        nothing, or one small dispatch: the engine takes it while it
+        holds its lock."""
         from ray_tpu.serve.llm.decode import get_family
 
-        if get_family(self.family).counters is None:
+        fam = get_family(self.family)
+        if fam.counters is None:
             return None
+        if fam.donated_state_counters is not None:
+            # the next step DONATES ``state``: copies of the counters'
+            # leaves, enqueued behind the step in flight
+            import jax.numpy as jnp
+
+            return {name: jnp.copy(self.cache.state[name])
+                    for name in fam.donated_state_counters}
         return self.cache.state
 
     def read_counters(self, state) -> dict:

@@ -65,6 +65,17 @@ False) keeps no row a sequence and is given no slots: ``state`` rides the
 step programs all the same, and what it is handed as ``slots`` only tells
 a step's real rows (1) from its padding (0).
 
+A third plane by block id (``block_state_bytes > 0``): a family whose
+attention SELECTS its pages (models/minicpm_sala.py) keeps, for every
+block of K, a few rows of compressed keys (ops/sparse_select.py: the sums
+of the block's segments, ``segments`` rows of ``n_kv_head x head_dim``
+float32 a layer). They live in the family's ``state`` under the SAME block
+ids as K and V: written by the step that writes the block's tokens, read
+through the same table, and given back with the block because nothing but
+its id names them (a segment's first token SETS its row, so a reused block
+needs no clearing and no quarantine beyond the block's own). The manager
+only counts them (``debug_snapshot()["compressed_key_bytes"]``).
+
 Tables by group (``groups``): a family whose layers do not all keep the
 same tokens (models/laguna.py: full layers keep every one, sliding layers
 the last ``window``) names GROUPS of layers, each of ``n_layer`` layers, so
@@ -220,6 +231,13 @@ class KVCacheConfig:
     # step programs keep in ``state`` ask for none). See the module
     # docstring.
     state_slots: int = 0
+    # Bytes the family's ``state`` holds a BLOCK ID beside K and V (the
+    # compressed keys of a layer that selects its pages: a third plane
+    # addressed by the ids this manager hands out, so it needs no
+    # allocator of its own and a block's rows go back with the block). 0:
+    # nothing. Only counted here (``debug_snapshot``); the array is the
+    # family's (decode.py ``Family.block_state_bytes``).
+    block_state_bytes: int = 0
     # False: no block is content-addressed and every prefix lookup misses
     # (a family whose recurrent state a mapped block would not restore).
     prefix_reuse: bool = True
@@ -1374,6 +1392,9 @@ class PagedKVCache:
             "live_sequences": len(self._tables),
             "state_slots": self.used_slots,
             "state_slots_high_water": s.state_slots_high_water,
+            # what the blocks in use hold in the family's plane by block id
+            "compressed_key_bytes": (
+                self.used_blocks * self.cfg.block_state_bytes),
             "groups": self.group_report(),
             "window_blocks_taken": s.window_blocks_taken,
             "window_blocks_freed": s.window_blocks_freed,

@@ -1438,3 +1438,98 @@ def test_warmup_trace_time_walks_a_cells_warm_up(topo, monkeypatch, cell):
     assert 0 < m["kernel_trace_s"] < m["trace_s"], m
     assert m["trace_s"] + m["lower_s"] < m["warm_up_s"], m
     assert not m["on_chip"] and "v5" in m["device"], m
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_minicpm_sala_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The MiniCPM-SALA cell's step programs as the executor compiles them,
+    at the cell's own shapes: the decode step at the cell's rows and the 2,048-token
+    chunk against the 832-entry table of the 53,248-token bucket, the fresh
+    prefill over 32 entries. The pool is lane-dense ``[2, 24577, 64, 256]``
+    (3.22 GB, K and V together): both arrays are in the program's
+    ``input_output_alias``; ``state`` (the lightning slots' 0.42 GB, the
+    compressed keys' 0.20 GB) is donated too (decode.py ``Family.
+    donated_state_counters``): the lightning kernel updates a row's state
+    where it stands. The decode step calls ``paged_attention_sparse`` in
+    both selecting layers, ``lightning_step`` in the six lightning layers
+    and no dense paged kernel; a prefill program holds, for each selecting
+    layer, the dense paged kernel (the branch of a chunk below
+    ``dense_len``) beside ``paged_attention_select``. Temporaries are what
+    longdoc-closed.json ``engine_why`` says: under a third of a GB for
+    decode, under half a GB for a chunk at the widest table."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/minicpm-sala-8l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/longdoc-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("minicpm_sala")
+    init = common.load_named("reference", "minicpm_sala").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, engine["max_batch_size"] + 1,
+                               engine["num_blocks"])))
+    assert state["lightning"].shape == (
+        6, engine["max_batch_size"] + 1, 32, 128, 128)
+    assert state["ckeys"].shape == (2, 24577, 4, 256)
+    pool = _struct(pool_shape(cfg.n_kv_layer, engine["num_blocks"],
+                              engine["block_size"], cfg.n_kv_head,
+                              cfg.head_dim), cfg.dtype, one_chip)
+    assert pool.shape == (2, 24577, 64, 256)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    ctx = engine["length_buckets"][-1]
+    B = engine["max_batch_size"] if kind == "decode" else 1
+    assert (ctx, engine["prefill_chunk_tokens"]) == (53248, 2048)
+    assert B in (1, engine["batch_buckets"][-1])
+    fns = decode.DecodeFns("minicpm_sala", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        lowered = fns._decode.lower(
+            params, pool, pool, i32((B,)), i32((B,)), i32((B, ctx // 64)),
+            sample=None, **more)
+    else:
+        nb = ctx // 64 if kind == "prefill_chunk" else 2048 // 64
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, pool, pool, i32((B, 2048)), i32((B,)), i32((B, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * math.prod(pool.shape) * 2
+    assert abs(pool_bytes - 3.221e9) < 0.001e9
+    # 5.64 GB of weights, the pool and the state (2.10 MB a slot a
+    # lightning layer + the compressed keys' 0.20 GB)
+    state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert abs(mem.argument_size_in_bytes
+               - (5.642e9 + pool_bytes + state_bytes)) < 0.02e9
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes - 1e6
+    assert mem.temp_size_in_bytes < (0.33e9 if kind == "decode" else 0.5e9), \
+        mem.temp_size_in_bytes
+    text = compiled.as_text()
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+    calls = (count("paged_attention_sparse"), count("lightning_step"),
+             count("paged_attention_select"), count("paged_attention"))
+    assert calls == ((2, 6, 0, 0) if kind == "decode" else (0, 0, 2, 2)), \
+        calls
+    assert "cross_program_prefetch_index" not in text
+    entry = text[text.index("ENTRY"):]
+    assert re.search(r"%state__ckeys__", entry)
+    assert re.search(r"%params__layers___1___lightning_wq__", entry)

@@ -169,6 +169,10 @@ FAMILIES = {
     "pangu_ultra_moe": dict(block_size=4, num_blocks=129,
                             prefill_chunk_tokens=16,
                             length_buckets=(16, 32, 64, 128)),
+    # a page of the cache IS the selection's block (8 in the tiny preset)
+    "minicpm_sala": dict(block_size=8, num_blocks=129,
+                         prefill_chunk_tokens=16,
+                         length_buckets=(16, 32, 64, 128)),
 }
 
 

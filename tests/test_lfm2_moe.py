@@ -676,8 +676,8 @@ def test_unknown_model_name_raises(jax_cpu):
     with pytest.raises(ValueError, match="unknown model family 'mamba'"):
         LLMEngine(EngineConfig(model="mamba"), auto_step=False)
     assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
-                                "llama", "longcat_flash", "pangu_ultra_moe",
-                                "smallthinker"]
+                                "llama", "longcat_flash", "minicpm_sala",
+                                "pangu_ultra_moe", "smallthinker"]
     for name in ("gpt", "llama"):
         assert get_family(name).init_state is None
         assert get_family(name).verify_step is not None
@@ -685,6 +685,10 @@ def test_unknown_model_name_raises(jax_cpu):
     assert get_family("laguna").verify_step is None
     assert get_family("evabyte").verify_step is None
     assert get_family("pangu_ultra_moe").verify_step is None
+    assert get_family("minicpm_sala").verify_step is None
+    # the one family whose steps donate ``state`` (a matrix a head a slot)
+    assert [n for n in FAMILIES
+            if get_family(n).donated_state_counters] == ["minicpm_sala"]
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])

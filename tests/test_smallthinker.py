@@ -694,7 +694,7 @@ def test_the_families_are_served_and_named(jax_cpu):
 
     assert sorted(decode.FAMILIES) == [
         "evabyte", "gpt", "laguna", "lfm2_moe", "llama", "longcat_flash",
-        "pangu_ultra_moe", "smallthinker"]
+        "minicpm_sala", "pangu_ultra_moe", "smallthinker"]
     with pytest.raises(ValueError, match="smallthinker"):
         decode.get_family("smallthinker2")
     fam = decode.get_family("smallthinker")
