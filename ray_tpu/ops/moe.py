@@ -4,9 +4,19 @@
 at the end of this file): a router (sigmoid scores or a softmax over all
 outputs, each with a selection bias, or a softmax over the chosen logits),
 top-k, the (token, expert) pairs sorted by expert, one grouped matrix
-product over the experts (``jax.lax.ragged_dot``), gated experts (SwiGLU,
-or ReLU-gated; ZERO-COMPUTE experts return their input and cost no
-product), weighted combine.
+product over the experts, gated experts (SwiGLU, or ReLU-gated;
+ZERO-COMPUTE experts return their input and cost no product), weighted
+combine. The grouped product has TWO FORMS, chosen by ``gmm_form`` from
+the shapes a step is traced with (no option, no model's name):
+``few_rows``, the repo's Pallas kernel ``moe_gmm_few_rows`` (tiles of 128
+sorted rows stand still, each expert that met a row streams past them
+once a tile, gate | up | activation | down in one call: every decode step
+and every prefill step of the five expert cells), and ``ragged``, XLA's
+``jax.lax.ragged_dot`` twice (widths that are no whole lane tiles, and
+steps wider than any that was timed: ``_FEW_PAIRS_AN_EXPERT``). XLA's
+kernel picks its row tile from m = T x k and pays a whole tile's pass a
+group, rows of no group included: 36% of HBM's peak at m = 1,024 where the
+same groups at m = 128 read 65% (docs/MICROBENCHMARKS.md, PR 49).
 Every routed pair is computed whatever the routing, so a token's output
 depends on its own row only (batched == solo) and the layer can be checked
 against a plain reference. ``models/lfm2_moe.py`` serves through it.
@@ -24,13 +34,16 @@ expert parallelism (SURVEY.md section 2.4 EP row: absent).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import pallas_interpret
 from ray_tpu.ops.layers import gelu
+from ray_tpu.ops.paged_attention import _VMEM_DEFAULT
 
 
 @dataclass(frozen=True)
@@ -189,6 +202,247 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
         return weights * scale, experts.astype(jnp.int32)
 
 
+# ----------------------------------------------------------------------------
+# The grouped product with the rows standing still: ``few_rows``.
+# ----------------------------------------------------------------------------
+
+GMM_KERNEL_NAME = "moe_gmm_few_rows"
+GMM_FORMS = ("ragged", "few_rows")
+_FEW_ROWS_TILE = 128
+_FEW_WEIGHT_TILE_BYTES = 2 * 1024 * 1024
+# the widest step timed: pairs (held or not) an expert the weights hold
+_FEW_PAIRS_AN_EXPERT = 2048
+
+
+def _tile_rows(rows: int, row_bytes: int, target: int) -> int:
+    """Rows of a weight tile: the largest multiple of 128 that divides
+    ``rows`` and keeps the tile within ``target`` bytes (128 where none
+    does: a tile is never less than a lane tile deep)."""
+    best = 128
+    for n in range(128, rows + 1, 128):
+        if rows % n == 0 and n * row_bytes <= target:
+            best = n
+    return best
+
+
+def _work_items(sizes, tm: int, n_rt: int, items: int):
+    """The kernel's lists, from the groups' sizes [E] int32: for every group
+    with rows, one item a tile of ``tm`` sorted rows it reaches (of
+    ``n_rt``), in order: ``(group, row tile, the group's first row, its
+    end)`` each [items] int32, and how many items there are. ``items`` is
+    the lists' length (every group, and a tile's edge more for each that a
+    group can cross); past the count they hold group 0 and no rows, and the
+    grid does not go there. Sums over masks, no scan, search or gather:
+    eight small operations on the device where the plain form (two
+    ``cumsum``, a ``searchsorted``, four gathers) compiled to twenty, 15 us
+    of laguna's 288 us call (docs/MICROBENCHMARKS.md, PR 49)."""
+    E = sizes.shape[0]
+    g = jnp.arange(E, dtype=jnp.int32)
+    upto = g[None, :] <= g[:, None]  # [E, E]: group j stands at or before g
+    ends = jnp.sum(jnp.where(upto, sizes[None, :], 0), axis=1)
+    starts = ends - sizes
+    first = starts // tm
+    reach = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    item_end = jnp.sum(jnp.where(upto, reach[None, :], 0), axis=1)
+    item_start = item_end - reach
+    w = jnp.arange(items, dtype=jnp.int32)
+    # [items, E]: item w is one of group g's (at most one g a row)
+    mine = (item_start[None, :] <= w[:, None]) & (w[:, None] < item_end[None, :])
+
+    def pick(v):
+        return jnp.sum(jnp.where(mine, v[None, :], 0), axis=1)
+
+    til = jnp.clip(pick(first - item_start) + w, 0, n_rt - 1)
+    return pick(g), til, pick(starts), pick(ends), item_end[-1]
+
+
+def _moe_gmm_few_rows_kernel(grp, til, lo, hi, tot, x_ref, w_in_ref,
+                             w_out_ref, y_ref, h_scr, g_scr, *, n_in, n_out,
+                             tm, F, tf, act):
+    """One work item (an expert x a tile of ``tm`` sorted rows that holds
+    rows of its group) a step of grid axis 0; axis 1 walks the expert's
+    matrices: ``n_in`` row tiles of ``w_in`` [td, 2F] into ``h_scr`` (the
+    gate and up products, float32), the activation once into ``g_scr`` with
+    the rows of OTHER groups zeroed, then ``n_out`` row tiles of ``w_out``
+    [tf, D] into the output's row tile, which consecutive items of one tile
+    share (zeroed rows add nothing there)."""
+    from jax.experimental import pallas as pl
+
+    w, t = pl.program_id(0), pl.program_id(1)
+    real = tot[0] > 0  # a grid has one step even where no group has a row
+
+    @pl.when(real & (t < n_in))
+    def _():
+        prod = jnp.dot(x_ref[...], w_in_ref[0],
+                       preferred_element_type=jnp.float32)
+
+        @pl.when(t == 0)
+        def _():
+            h_scr[...] = prod
+
+        @pl.when(t > 0)
+        def _():
+            h_scr[...] += prod
+
+    @pl.when(real & (t == n_in - 1))
+    def _():
+        h = h_scr[...]
+        rows = til[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mine = (rows >= lo[w]) & (rows < hi[w])
+        gated = jnp.where(
+            mine, EXPERT_ACTS[act](h[:, :F]) * h[:, F:], 0.0
+        ).astype(g_scr.dtype)
+        for j in range(n_out):
+            g_scr[j] = gated[:, j * tf:(j + 1) * tf]
+
+    @pl.when(real & (t >= n_in))
+    def _():
+        j = t - n_in
+        prod = jnp.dot(g_scr[j], w_out_ref[0],
+                       preferred_element_type=jnp.float32)
+        opens = (j == 0) & ((w == 0) | (til[jnp.maximum(w - 1, 0)] != til[w]))
+
+        @pl.when(opens)
+        def _():
+            y_ref[...] = prod
+
+        @pl.when(jnp.logical_not(opens))
+        def _():
+            y_ref[...] += prod
+
+
+@functools.partial(
+    jax.jit, static_argnames=("act", "tm", "tile_bytes", "interpret"))
+def _moe_gmm_few_rows_call(xs, w_in, w_out, sizes, *, act, tm, tile_bytes,
+                           interpret):
+    """``moe_gmm_few_rows`` behind a jit of its own: a step program calls it
+    once an expert layer with the same shapes, and the inner jit's cache
+    makes the kernel's text traced once a process and lowered once a
+    program, not once a layer (ops/paged_attention.py ``_latent_call``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, D = xs.shape
+    E, F = w_out.shape[:2]
+    itemsize = jnp.dtype(xs.dtype).itemsize
+    td = _tile_rows(D, 2 * F * itemsize, tile_bytes)
+    tf = _tile_rows(F, D * itemsize, tile_bytes)
+    n_in, n_out = D // td, F // tf
+    n_rt = -(-m // tm)
+    xs = jnp.pad(xs, ((0, n_rt * tm - m), (0, 0)))
+    grp, til, lo, hi, total = _work_items(
+        sizes, tm, n_rt, min(E, m) + n_rt - 1)
+
+    def x_map(w, t, grp, til, lo, hi, tot):
+        return (til[w], jnp.minimum(t, n_in - 1))
+
+    def w_in_map(w, t, grp, til, lo, hi, tot):
+        return (grp[w], jnp.minimum(t, n_in - 1), 0)
+
+    def w_out_map(w, t, grp, til, lo, hi, tot):
+        # while an item's ``w_in`` streams, the item BEFORE's last tile
+        # stays: the first tile of this item's ``w_out`` is then copied
+        # under the last ``w_in`` product, not beside the first
+        ahead = (t < n_in) & (w > 0)
+        return (jnp.where(ahead, grp[jnp.maximum(w - 1, 0)], grp[w]),
+                jnp.where(ahead, n_out - 1, jnp.maximum(t - n_in, 0)), 0)
+
+    def y_map(w, t, grp, til, lo, hi, tot):
+        return (til[w], 0)
+
+    vmem = 2 * (tm * td + td * 2 * F + tf * D) * itemsize \
+        + 2 * tm * D * 4 + 2 * tm * 2 * F * 4 + tm * F * itemsize
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        # as many items as there are: the bound is the lists' length only
+        grid=(jnp.maximum(total, 1), n_in + n_out),
+        in_specs=[
+            pl.BlockSpec((tm, td), x_map),
+            pl.BlockSpec((1, td, 2 * F), w_in_map),
+            pl.BlockSpec((1, tf, D), w_out_map),
+        ],
+        out_specs=pl.BlockSpec((tm, D), y_map),
+        scratch_shapes=[
+            pltpu.VMEM((tm, 2 * F), jnp.float32),
+            pltpu.VMEM((n_out, tm, tf), xs.dtype),
+        ],
+    )
+    ys = pl.pallas_call(
+        functools.partial(
+            _moe_gmm_few_rows_kernel, n_in=n_in, n_out=n_out, tm=tm, F=F,
+            tf=tf, act=act),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_rt * tm, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            # in order, on one core: items of one row tile share its block
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
+        ),
+        name=GMM_KERNEL_NAME,
+        interpret=interpret,
+    )(grp, til, lo, hi, total.reshape(1), xs, w_in, w_out)
+    return ys[:m]
+
+
+def moe_gmm_few_rows(xs: jax.Array, w_in: jax.Array, w_out: jax.Array,
+                     sizes: jax.Array, *, act: str = "silu",
+                     interpret: bool | None = None) -> jax.Array:
+    """The two grouped products and the activation between them as ONE
+    Pallas kernel, made for groups of few rows (a group of many costs a
+    read of its expert a 128 rows): xs [m, D] sorted by group (group
+    g owns rows ``sum(sizes[:g]) .. + sizes[g]``; rows past the last group
+    belong to none), ``w_in`` [E, D, 2F], ``w_out`` [E, F, D], all of one
+    dtype -> ys [m, D] float32, a group's rows through its expert; the rows
+    of no group hold whatever was there (the caller masks them, as after
+    ``ragged_dot``).
+
+    The rows stand still and the weights stream: each expert that met a
+    row is read ONCE for each tile of ``_FEW_ROWS_TILE`` sorted rows its
+    group reaches (one, but for a group across a tile's edge), in tiles of
+    whole rows of the stored matrices (``_FEW_WEIGHT_TILE_BYTES``: each one
+    contiguous copy), the next tile, the next expert's first included,
+    copied under the current one's product by the pipeline; an expert with
+    no row is never in the list. ``h`` (gate | up, float32) and the
+    activation stay in fast memory."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    return _moe_gmm_few_rows_call(
+        xs, w_in, w_out, sizes.astype(jnp.int32), act=act,
+        tm=_FEW_ROWS_TILE, tile_bytes=_FEW_WEIGHT_TILE_BYTES,
+        interpret=bool(interpret))
+
+
+def gmm_form(pairs: int, experts: int, d_model: int, d_expert: int) -> str:
+    """Which form ``moe_dropless`` gives its grouped product, from the
+    shapes it is traced with: ``pairs`` = T x k rows, the ``experts`` the
+    weights hold, their widths. ``few_rows`` wherever the kernel can tile
+    the widths (whole lane tiles) and the step lies inside what was timed
+    (docs/MICROBENCHMARKS.md, PR 49: the layer alone on a v5e, ``ragged``
+    -> ``few_rows`` in us): the five cells' decode steps 2,496 -> 1,695
+    (lfm2, 4 pairs an expert), 567 -> 286 (laguna, 16 of which 2 held),
+    2,548 -> 1,187 (openPangu, 128 of which 5 held), 1,431 -> 1,133
+    (smallthinker, 4.5), 1,422 -> 1,211 (LongCat, 72 of which 1.6 held),
+    and their prefill steps up to 2,048 pairs an expert (openPangu's
+    2,048-token chunk 5,979 -> 4,316; 512 held rows an expert a tie, -5%;
+    768 the one loss, +2%). Past that nothing was timed and XLA's kernel
+    stays."""
+    if d_model % 128 or d_expert % 128:
+        return "ragged"
+    return ("few_rows" if pairs <= _FEW_PAIRS_AN_EXPERT * experts
+            else "ragged")
+
+
+def step_gmm_form(cfg, rows: int) -> str:
+    """The form the grouped product of a step program of ``rows`` tokens
+    takes under an expert family's config (``top_k``, ``d_model``,
+    ``d_expert``, ``n_held`` or ``num_experts``): what ``moe_dropless``
+    decides when that program is traced, for ``stats()["moe_gmm_form"]``
+    and the decode flight record (decode.py ``Family.gmm_form``)."""
+    return gmm_form(rows * cfg.top_k,
+                    getattr(cfg, "n_held", cfg.num_experts), cfg.d_model,
+                    cfg.d_expert)
+
+
 def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  w_in: jax.Array, w_out: jax.Array, *, dtype,
                  valid: jax.Array | None = None,
@@ -201,8 +455,9 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     first), ``w_out`` [E, F, D]: an expert is ``(act(x gate) * (x up))
     down``, ``act`` ``silu`` (SwiGLU) or ``relu``. The T * k (token,
     expert) pairs are sorted by expert and met by ONE grouped product each way
-    (``jax.lax.ragged_dot`` with the per-expert counts as group sizes:
-    products in ``dtype``, float32 accumulation), so any routing is
+    (``gmm_form``: the kernel ``moe_gmm_few_rows``, or ``jax.lax.ragged_dot``
+    twice, with the per-expert counts as group sizes; either way products
+    in ``dtype``, float32 accumulation), so any routing is
     computed whole, all tokens on one expert included: no capacity, no
     drop. ``valid`` [T] bool marks the real rows of a bucketed batch:
     pairs of padding rows sort behind every expert's, belong to no group,
@@ -246,16 +501,20 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
         xs = x.astype(dtype)[order // k]
-        h = jax.lax.ragged_dot(
-            xs, w_in.astype(dtype), sizes,
-            preferred_element_type=jnp.float32,
-        )
-        gate, up = jnp.split(h, 2, axis=-1)
-        gated = (EXPERT_ACTS[act](gate) * up).astype(dtype)
-        ys = jax.lax.ragged_dot(
-            gated, w_out.astype(dtype), sizes,
-            preferred_element_type=jnp.float32,
-        )
+        if gmm_form(T * k, E, D, w_out.shape[1]) == "few_rows":
+            ys = moe_gmm_few_rows(
+                xs, w_in.astype(dtype), w_out.astype(dtype), sizes, act=act)
+        else:
+            h = jax.lax.ragged_dot(
+                xs, w_in.astype(dtype), sizes,
+                preferred_element_type=jnp.float32,
+            )
+            gate, up = jnp.split(h, 2, axis=-1)
+            gated = (EXPERT_ACTS[act](gate) * up).astype(dtype)
+            ys = jax.lax.ragged_dot(
+                gated, w_out.astype(dtype), sizes,
+                preferred_element_type=jnp.float32,
+            )
         # back to (token, choice) order, then the weighted sum over choices
         back = jnp.zeros((T * k,), jnp.int32).at[order].set(
             jnp.arange(T * k, dtype=jnp.int32))

@@ -46,6 +46,10 @@ class Family:
     adds to a step's ``executor.dispatch`` span from the positions its
     real rows query (``rows``: ``(first position, tokens)`` a row; ``kind``
     "prefill" or "decode"), e.g. how many of them select their pages.
+    ``gmm_form`` (``(model_cfg, rows) -> str``, an expert family's): the
+    form the grouped expert product takes in a step program of ``rows``
+    tokens (ops/moe.py ``step_gmm_form``), for ``stats()`` and the decode
+    flight record.
     ``donated_state_counters``: the family's step programs donate
     ``state`` as they donate the pools (``_jit_named``), and these are the
     leaves a ``counter_state()`` must copy out while they stand."""
@@ -62,6 +66,7 @@ class Family:
     state_rows: bool = True
     block_state_bytes: Callable | None = None
     step_attrs: Callable | None = None
+    gmm_form: Callable | None = None
     # the leaves of ``state`` that ``counters`` reads, for a family whose
     # step programs DONATE ``state`` (its arrays are hundreds of MB that a
     # step updates where they stand: a lightning state a slot, compressed
@@ -87,23 +92,25 @@ def _llama() -> Family:
 
 def _lfm2_moe() -> Family:
     from ray_tpu.models import lfm2_moe as m
+    from ray_tpu.ops.moe import step_gmm_form
 
     # no verify step: rejected drafts would need the conv state rolled back
     return Family(m.lfm2_moe_init, m.lfm2_moe_prefill,
                   m.lfm2_moe_decode_step, None, m.lfm2_moe_param_axes,
                   m.lfm2_moe_quant_axes, m.Lfm2MoeConfig.tiny,
                   init_state=m.lfm2_moe_init_state,
-                  counters=m.lfm2_moe_counters)
+                  counters=m.lfm2_moe_counters, gmm_form=step_gmm_form)
 
 
 def _laguna() -> Family:
     from ray_tpu.models import laguna as m
+    from ray_tpu.ops.moe import step_gmm_form
 
     # no verify step: a rejected window may reach behind freed blocks
     return Family(m.laguna_init, m.laguna_prefill, m.laguna_decode_step,
                   None, m.laguna_param_axes, m.laguna_quant_axes,
                   m.LagunaConfig.tiny, init_state=m.laguna_init_state,
-                  counters=m.laguna_counters)
+                  counters=m.laguna_counters, gmm_form=step_gmm_form)
 
 
 def _evabyte() -> Family:
@@ -117,6 +124,7 @@ def _evabyte() -> Family:
 
 def _pangu_ultra_moe() -> Family:
     from ray_tpu.models import pangu_ultra_moe as m
+    from ray_tpu.ops.moe import step_gmm_form
 
     # no verify step: nothing drafts (the prediction module is not held)
     return Family(m.pangu_ultra_moe_init, m.pangu_ultra_moe_prefill,
@@ -124,11 +132,13 @@ def _pangu_ultra_moe() -> Family:
                   m.pangu_ultra_moe_param_axes, m.pangu_ultra_moe_quant_axes,
                   m.PanguUltraMoEConfig.tiny,
                   init_state=m.pangu_ultra_moe_init_state,
-                  counters=m.pangu_ultra_moe_counters, state_rows=False)
+                  counters=m.pangu_ultra_moe_counters, state_rows=False,
+                  gmm_form=step_gmm_form)
 
 
 def _smallthinker() -> Family:
     from ray_tpu.models import smallthinker as m
+    from ray_tpu.ops.moe import step_gmm_form
 
     # no verify step: a rejected window may reach behind freed blocks
     return Family(m.smallthinker_init, m.smallthinker_prefill,
@@ -136,11 +146,13 @@ def _smallthinker() -> Family:
                   m.smallthinker_param_axes, m.smallthinker_quant_axes,
                   m.SmallThinkerConfig.tiny,
                   init_state=m.smallthinker_init_state,
-                  counters=m.smallthinker_counters, state_rows=False)
+                  counters=m.smallthinker_counters, state_rows=False,
+                  gmm_form=step_gmm_form)
 
 
 def _longcat_flash() -> Family:
     from ray_tpu.models import longcat_flash as m
+    from ray_tpu.ops.moe import step_gmm_form
 
     # no verify step: nothing drafts; the latent family's refusals apply
     return Family(m.longcat_flash_init, m.longcat_flash_prefill,
@@ -148,7 +160,8 @@ def _longcat_flash() -> Family:
                   m.longcat_flash_param_axes, m.longcat_flash_quant_axes,
                   m.LongCatFlashConfig.tiny,
                   init_state=m.longcat_flash_init_state,
-                  counters=m.longcat_flash_counters, state_rows=False)
+                  counters=m.longcat_flash_counters, state_rows=False,
+                  gmm_form=step_gmm_form)
 
 
 def _minicpm_sala() -> Family:

@@ -105,6 +105,7 @@ Failure semantics (docs/SERVING_LLM.md "Failure semantics"):
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import math
@@ -617,6 +618,10 @@ class LLMEngine:
         # for the step's ``executor.dispatch`` span (decode.py
         # ``Family.step_attrs``); None: nothing of its own
         self._step_attrs = family.step_attrs
+        # the form an expert family's grouped product takes in a program
+        # of so many rows (``Family.gmm_form``; None: no expert layer)
+        self._gmm_form = (None if family.gmm_form is None else
+                          functools.partial(family.gmm_form, model_cfg))
         self.cache = PagedKVCache(
             KVCacheConfig(
                 # the pool spans the layers that cache K/V: all of them,
@@ -1564,6 +1569,13 @@ class LLMEngine:
                 **{f"eva_{name}": n
                    for name, n in self._eva_counts.items()},
                 "num_compiled_shapes": self.fns.num_compiled_shapes,
+                # the grouped expert product's form (ops/moe.py
+                # ``gmm_form``) by step program this engine has run:
+                # ``<kind>@<rows>[x<tokens a row>]``
+                **({} if self._gmm_form is None else {"moe_gmm_form": {
+                    f"{kind}@{'x'.join(map(str, shape))}":
+                        self._gmm_form(math.prod(shape))
+                    for kind, shape, _ in sorted(self.fns.signatures)}}),
                 "rejected_total": self._rejected_total,
                 "cancelled_total": self._cancelled_total,
                 "deadline_exceeded_total": self._deadline_total,
@@ -2777,6 +2789,8 @@ class LLMEngine:
         self._account_step_locked(
             "decode", dt, t0_wall, emitted, batch=len(batch), bucket_b=B,
             bucket_len=ctx, nb=nb, tokens=emitted, **kv,
+            **({} if self._gmm_form is None
+               else {"gmm_form": self._gmm_form(B)}),
             steady=steady, remapped=remapped,
             trace_ids=self._trace_ids_locked(batch),
         )

@@ -600,13 +600,21 @@ def _lfm2_cell_shapes(one_chip):
     return cfg, params, pool, state, i32
 
 
+def _gmm_calls(entry):
+    """The grouped expert product's calls in a compiled program's entry
+    computation: ``moe_gmm_few_rows`` (ops/moe.py; ISSUE 49), one call an
+    expert layer with both of its matrices among the operands."""
+    return re.findall(r"%moe_gmm_few_rows[.\d]* = [^\n]*", entry)
+
+
 def test_lfm2_moe_decode_step_compiles_at_published_widths(
         one_chip, monkeypatch):
     """The lfm2_moe cell's 64-row decode step, as the executor compiles it:
     it fits the chip beside nothing else (9.4 GB), the grouped expert
-    product is XLA's ``ragged-dot`` kernel fed the experts' matrices AS
-    STORED (no operation produces an expert-sized array: no cast, no
-    relayout of 1.2 GB a layer), the paged kernel is there at GQA heads of
+    product is the ``moe_gmm_few_rows`` kernel (ISSUE 49; XLA's
+    ``ragged-dot`` before), one call an expert layer, fed the experts'
+    matrices AS STORED (no operation produces an expert-sized array: no
+    cast, no relayout of 1.2 GB a layer), the paged kernel is there at GQA heads of
     64, and the expert layer's and the convolution's weights reach their
     operations under the names the benchmark's readers look for."""
     import jax
@@ -630,11 +638,13 @@ def test_lfm2_moe_decode_step_compiles_at_published_widths(
     assert total < 11e9, total
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
-    # two grouped products in each of six expert layers, on the stored leaves
-    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
-    assert len(calls) == 12
-    assert all(re.search(r"%params__layers___\d___moe_gmm_w_(in|out)__", c)
-               for c in calls)
+    # one grouped product (both matrices) in each of six expert layers, on
+    # the stored leaves
+    calls = _gmm_calls(entry)
+    assert len(calls) == 6
+    assert all(re.search(rf"%params__layers___\d___moe_gmm_w_{w}__", c)
+               for c in calls for w in ("in", "out"))
+    assert "ragged-dot" not in entry
     produced = [ln for ln in entry.splitlines()
                 if re.search(r"= \w+\[64,(2048|1536),(3072|2048)\]", ln)
                 and " parameter(" not in ln]
@@ -698,12 +708,12 @@ def test_laguna_decode_step_compiles_at_published_widths(
     full = len(re.findall(r"%paged_attention[.\d]* = ", entry))
     sliding = len(re.findall(r"%paged_attention_window[.\d]* = ", entry))
     assert (full, sliding) == (2, 6), (full, sliding)
-    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
-    assert len(calls) == 14  # two grouped products in each of 7 layers
-    # gate/up as stored; the smaller down matrices (32 MB a layer) the
-    # compiler may bring into fast memory in slices first
-    assert sum(bool(re.search(r"%params__layers___\d___moe_gmm_w_in__", c))
-               for c in calls) == 7
+    calls = _gmm_calls(entry)
+    assert len(calls) == 7  # one grouped product in each of 7 layers
+    # both matrices as stored: the kernel copies its own tiles
+    assert all(re.search(rf"%params__layers___\d___moe_gmm_w_{w}__", c)
+               for c in calls for w in ("in", "out"))
+    assert "ragged-dot" not in entry
     produced = [ln for ln in entry.splitlines()
                 if re.search(r"= \w+\[32,(2048|512),(1024|2048)\]", ln)
                 and " parameter(" not in ln
@@ -865,6 +875,11 @@ def test_pangu_step_programs_compile_at_published_widths(
     entry = text[text.index("ENTRY"):]
     assert len(re.findall(r"%paged_attention_latent[.\d]* = ", entry)) == 5
     assert not re.findall(r"%paged_attention[.\d]* = ", entry)
+    # the four expert layers' grouped product, both matrices as stored
+    calls = _gmm_calls(entry)
+    assert len(calls) == 4 and "ragged-dot" not in entry
+    assert all(re.search(rf"%params__layers___\d___moe_gmm_w_{w}__", c)
+               for c in calls for w in ("in", "out"))
     # no array anywhere has the context's 12,288 positions as a dimension
     assert not re.search(r"[\[,]12288[\],]", text)
     if kind == "decode":
@@ -1021,7 +1036,8 @@ def test_longcat_step_programs_compile_at_published_widths(
     entry = text[text.index("ENTRY"):]
     assert len(re.findall(r"%paged_attention_latent[.\d]* = ", entry)) == 8
     assert not re.findall(r"%paged_attention[.\d]* = ", entry)
-    assert len(re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)) == 8
+    assert len(_gmm_calls(entry)) == 4  # one a double layer, every kind
+    assert "ragged-dot" not in entry
     # no array anywhere has the context's 6,144 positions as a dimension:
     # d_model is 6,144 too, so the test is on [.., 64 heads, 6144] scores
     # and on K or V by head
@@ -1115,8 +1131,9 @@ def test_smallthinker_step_programs_compile_at_published_widths(
     full = len(re.findall(r"%paged_attention[.\d]* = ", entry))
     sliding = len(re.findall(r"%paged_attention_window[.\d]* = ", entry))
     assert (full, sliding) == (2, 6), (full, sliding)
-    calls = re.findall(r"%ragged-dot-none[.\d]* = [^\n]*", entry)
-    assert len(calls) == 16  # two grouped products in each of 8 layers
+    calls = _gmm_calls(entry)
+    assert len(calls) == 8  # one grouped product in each of 8 layers
+    assert "ragged-dot" not in entry
     if kind == "decode":
         for needle in ("moe_route_w", "moe_gmm_w_in"):
             assert re.search(
@@ -1197,26 +1214,26 @@ def test_other_families_programs_are_what_their_functions_compile_to(
 PARENTS_TEXT = {
     "mistral-decode": "bc061138abf9994d",
     "mistral-prefill": "b496beb461d9093b",
-    "laguna-decode": "fb6e307deb35fcc3",
     "kernel-decode": "0a60dc1dcd26269e",
     "kernel-prefill": "3eaf0087f777f8e6",
     "kernel-window": "fb1235e986671dce",
     "gpt2-decode": "ae8d5f0c0d8e2416",
-    "lfm2-decode": "1f57451824aeb915",
     "evabyte-decode": "6735ace882751da0",
-    # re-recorded by ISSUE 46 (the latent call's own kernel body, behind a
-    # jit of its own): planes [5, 40961, 16, 512 | 128], table [128, 768]
-    "pangu-decode": "97b99cac41094171",
-    # new with ISSUE 46: planes [8, 16385, 16, 512 | 128], table [96, 384]
-    "longcat-decode": "f51516e6bdf18da4",
-    # new with ISSUE 47: the chunk program over [16, 128] under [16, 768]
-    # and over [8, 128] under [8, 384], the cells' planes
-    "pangu-packed": "7394274b57d42267",
-    "longcat-packed": "abaaab419b1b83fe",
-    # taken on ISSUE 45's parent 34388d1, before ``moe_route`` and
-    # ``moe_dropless`` gained their third score and third kind of expert:
+    # re-recorded by ISSUE 49 (the grouped expert product is the kernel
+    # ``moe_gmm_few_rows``, one call an expert layer, in every step program
+    # of the five expert families; the seven others above are as they were)
+    "laguna-decode": "85a1e51ebd241889",
+    "lfm2-decode": "4fca8ec5f3634977",
+    # planes [5, 40961, 16, 512 | 128], table [128, 768]
+    "pangu-decode": "999646688f5b9262",
+    # planes [8, 16385, 16, 512 | 128], table [96, 384]
+    "longcat-decode": "b8ef72aeeb4e03f0",
+    # the chunk program over [16, 128] under [16, 768] and over [8, 128]
+    # under [8, 384], the cells' planes (ISSUE 47)
+    "pangu-packed": "e66d4d805ce7769a",
+    "longcat-packed": "b2d3d4f98bddc82f",
     # pool [2, 65537, 16, 512], tables [4, 48, 1024]
-    "smallthinker-decode": "1ac1b5c522f22bc4",
+    "smallthinker-decode": "1229fb1663ecaf6f",
 }
 PARENTS_JAX = "0.9.0"
 
@@ -1366,9 +1383,11 @@ def test_step_programs_compile_to_the_recorded_text(one_chip, monkeypatch,
     assert got == PARENTS_TEXT[case], got
 
 
-def _kernel_products(jaxpr):
+def _kernel_products(jaxpr, named="paged_attention"):
     """The count of ``dot_general`` in the body of every ``pallas_call`` of
-    a traced program, one entry a call (a scanned stack's one layer once)."""
+    a traced program whose name holds ``named`` (the paged kernels; the
+    expert layer's ``moe_gmm_few_rows`` is none of them), one entry a call
+    (a scanned stack's one layer once)."""
     calls = []
 
     def walk(j, inside):
@@ -1382,7 +1401,8 @@ def _kernel_products(jaxpr):
                     if not hasattr(sub, "eqns"):
                         continue
                     if eqn.primitive.name == "pallas_call":
-                        calls.append(walk(sub, True))
+                        if named in eqn.params["name"]:
+                            calls.append(walk(sub, True))
                     else:
                         n += walk(sub, inside)
         return n
