@@ -1,0 +1,10 @@
+"""Device time of the PREFILL programs by the program's own names, as a share of
+those programs' device time in the slice: ``attn_proj`` + ``ffn``: the layers'
+dense products with their norms and residuals (``benchmark/scope_reduce.py``).
+Nothing where no prefill program ran in the slice, the part took no time, or
+under 90% of busy time is named."""
+from benchmark import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.share_pct(ctx, "matmul", kind="prefill")

@@ -1,0 +1,11 @@
+"""Device time of the PREFILL programs by the program's own names, as a share of
+those programs' device time in the slice: ``moe_route`` + ``moe_move`` +
+``moe_zero``, what stands around the grouped product (router, sort, gathers,
+combine): PERF.md 7 (ag) (``benchmark/scope_reduce.py``). Nothing where no
+prefill program ran in the slice, the part took no time, or under 90% of busy
+time is named."""
+from benchmark import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.share_pct(ctx, "moe_move", kind="prefill")

@@ -55,6 +55,13 @@ own, in a ``CachedFamily``:
 Every step takes ``state=None, slots=None`` by keyword and returns ``(out,
 cache_k', cache_v', state')``: None is an empty pytree to ``jax.jit``, so
 a family without state has neither among its program's parameters.
+
+The step NAMES its parts (``jax.named_scope``; the vocabulary is
+serve/llm/obs.py ``SCOPES``): ``embed``, ``layer_stack``, ``attn_cache``,
+``attn_kernel``, ``head``, ``sample`` and ``counters`` here, for every
+family at once; ``attn_proj`` and ``ffn`` in a family's ``layer``, deeper
+names in ops/. A name is metadata of the compiled program and costs a
+served step nothing; ``DecodeFns.program_scopes()`` reads it back.
 """
 from __future__ import annotations
 
@@ -185,28 +192,35 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     if latent is not None:
         at = step.pos if step.at is None else step.at
         one = step.kind == "decode"  # its rows are written as [B, .]
-        cache_k, cache_v = write_kv(
-            cache_k, cache_v, k[:, 0] if one else k, v[:, 0] if one else v,
-            at[:, 0] if one else at, tables, valid=step.valid, layer=layer)
-        attn = latent_attention(
-            q, cache_k, cache_v, tables,
-            at if step.valid is None else jnp.where(step.valid, at, 0),
-            latent_dim=k.shape[-1], scale=latent, backend=backend,
-            layer=layer)
-        return attn.reshape(B, S, -1), cache_k, cache_v
+        with jax.named_scope("attn_cache"):
+            cache_k, cache_v = write_kv(
+                cache_k, cache_v, k[:, 0] if one else k,
+                v[:, 0] if one else v, at[:, 0] if one else at, tables,
+                valid=step.valid, layer=layer)
+        with jax.named_scope("attn_kernel"):
+            attn = latent_attention(
+                q, cache_k, cache_v, tables,
+                at if step.valid is None else jnp.where(step.valid, at, 0),
+                latent_dim=k.shape[-1], scale=latent, backend=backend,
+                layer=layer)
+            return attn.reshape(B, S, -1), cache_k, cache_v
     if step.kind == "decode":
         at = step.rows if step.at is None else step.at[:, 0]
-        cache_k, cache_v = write_kv(
-            cache_k, cache_v, k[:, 0], v[:, 0], at, tables, layer=layer)
+        with jax.named_scope("attn_cache"):
+            cache_k, cache_v = write_kv(
+                cache_k, cache_v, k[:, 0], v[:, 0], at, tables, layer=layer)
         if then is not None:
             cache_k, cache_v = then(cache_k, cache_v, layer)
-        attn = decode_attention(
-            q[:, 0], cache_k, cache_v, tables, at, backend=backend,
-            layer=layer, window=window)
-        return attn.reshape(B, S, -1), cache_k, cache_v
+        with jax.named_scope("attn_kernel"):
+            attn = decode_attention(
+                q[:, 0], cache_k, cache_v, tables, at, backend=backend,
+                layer=layer, window=window)
+            return attn.reshape(B, S, -1), cache_k, cache_v
     at = step.pos if step.at is None else step.at
-    cache_k, cache_v = write_kv(
-        cache_k, cache_v, k, v, at, tables, valid=step.valid, layer=layer)
+    with jax.named_scope("attn_cache"):
+        cache_k, cache_v = write_kv(
+            cache_k, cache_v, k, v, at, tables, valid=step.valid,
+            layer=layer)
     if then is not None:
         cache_k, cache_v = then(cache_k, cache_v, layer)
     # The fresh-prompt shortcut attends over the UNQUANTIZED just-computed
@@ -217,23 +231,24 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     # the first prefill saw the same quantized values. Under pallas the
     # fused kernel reads the just-written pool (the padded context never
     # exists in HBM).
-    if (
-        step.kind == "fresh"
-        and window is None  # the shortcut's mask is causal, no more
-        and cfg.quantization is None
-        and resolve_backend(backend) != "pallas"
-    ):
-        attn = mha_reference(  # repeats GQA kv heads internally
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True,
-        ).transpose(0, 2, 1, 3)
-    else:
-        attn = prefill_attention(
-            q, cache_k, cache_v, tables, jnp.where(step.valid, at, 0),
-            backend=backend, layer=layer, window=window)
-    return attn.reshape(B, S, -1), cache_k, cache_v
+    with jax.named_scope("attn_kernel"):
+        if (
+            step.kind == "fresh"
+            and window is None  # the shortcut's mask is causal, no more
+            and cfg.quantization is None
+            and resolve_backend(backend) != "pallas"
+        ):
+            attn = mha_reference(  # repeats GQA kv heads internally
+                q.transpose(0, 2, 1, 3),
+                k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3),
+                causal=True,
+            ).transpose(0, 2, 1, 3)
+        else:
+            attn = prefill_attention(
+                q, cache_k, cache_v, tables, jnp.where(step.valid, at, 0),
+                backend=backend, layer=layer, window=window)
+        return attn.reshape(B, S, -1), cache_k, cache_v
 
 
 def _attend_selected(step, cache_k, cache_v, layer, q, k, v, tables, backend,
@@ -241,20 +256,24 @@ def _attend_selected(step, cache_k, cache_v, layer, q, k, v, tables, backend,
     """``attend_layer`` for a layer that selects its pages."""
     B, S = q.shape[:2]
     if step.kind == "decode":
+        with jax.named_scope("attn_cache"):
+            cache_k, cache_v = write_kv(
+                cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
+                layer=layer)
+        with jax.named_scope("attn_kernel"):
+            attn = sparse_decode_attention(
+                q[:, 0], cache_k, cache_v, select.pages, select.vpos, layer,
+                backend=backend)
+            return attn.reshape(B, S, -1), cache_k, cache_v
+    with jax.named_scope("attn_cache"):
         cache_k, cache_v = write_kv(
-            cache_k, cache_v, k[:, 0], v[:, 0], step.rows, tables,
+            cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
             layer=layer)
-        attn = sparse_decode_attention(
-            q[:, 0], cache_k, cache_v, select.pages, select.vpos, layer,
-            backend=backend)
+    with jax.named_scope("attn_kernel"):
+        attn = sparse_prefill_attention(
+            q, cache_k, cache_v, tables, step.pos, step.valid,
+            select.seg_rows, layer, select.cfg, backend=backend)
         return attn.reshape(B, S, -1), cache_k, cache_v
-    cache_k, cache_v = write_kv(
-        cache_k, cache_v, k, v, step.pos, tables, valid=step.valid,
-        layer=layer)
-    attn = sparse_prefill_attention(
-        q, cache_k, cache_v, tables, step.pos, step.valid, select.seg_rows,
-        layer, select.cfg, backend=backend)
-    return attn.reshape(B, S, -1), cache_k, cache_v
 
 
 def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
@@ -278,9 +297,12 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
             x, state = fam.layer(x, lp, attend, step, state, cfg)
             return (x, state, *kv), None
 
-        (x, state, cache_k, cache_v), _ = jax.lax.scan(
-            body, (x, state, cache_k, cache_v),
-            (layers, jnp.arange(cache_k.shape[0], dtype=jnp.int32)))
+        # the loop's own operations (a layer's weights sliced out of the
+        # stack) are ``layer_stack``; the layers' lie deeper
+        with jax.named_scope("layer_stack"):
+            (x, state, cache_k, cache_v), _ = jax.lax.scan(
+                body, (x, state, cache_k, cache_v),
+                (layers, jnp.arange(cache_k.shape[0], dtype=jnp.int32)))
         return x, cache_k, cache_v, state
 
     attended = 0  # the pool spans the attending layers only
@@ -304,37 +326,42 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
 def _step(fam, kind, params, cache_k, cache_v, tokens, rows, block_tables,
           cfg, *, start=None, draft_len=None, sample=None, state=None,
           slots=None):
-    step = _plan(kind, tokens, rows, block_tables, start, draft_len, slots)
-    if fam.place is not None:
-        step = step._replace(at=fam.place(step.pos, cfg))
-    x, aux = fam.embed(params, tokens, step, cfg)
-    step = step._replace(aux=aux)
-    work = state if fam.open_state is None else fam.open_state(
-        state, step, cfg)
+    with jax.named_scope("embed"):
+        step = _plan(
+            kind, tokens, rows, block_tables, start, draft_len, slots)
+        if fam.place is not None:
+            step = step._replace(at=fam.place(step.pos, cfg))
+        x, aux = fam.embed(params, tokens, step, cfg)
+        step = step._replace(aux=aux)
+        work = state if fam.open_state is None else fam.open_state(
+            state, step, cfg)
     x, cache_k, cache_v, work = _walk(
         fam, x, params[fam.stack], cache_k, cache_v, step, work, cfg)
-    state = work if fam.close_state is None else fam.close_state(
-        state, work, step, cfg)
-    # the rows that reach the head: a decode step's one, a prompt's last
-    # real token, every column of a verify window
-    if kind == "decode":
-        x = x[:, 0]
-    h = fam.final_norm(params, x, cfg)
-    if kind in ("fresh", "chunk"):
-        h = h[jnp.arange(tokens.shape[0]), rows - 1]
-    logits = fam.head(params, h, cfg)
-    if sample is None:
-        out = logits
-    elif kind == "verify":
-        out = verify_tokens(logits, rows, tokens, draft_len, sample)
-    else:
-        # the new token lands right after the row's last real one
+    with jax.named_scope("counters"):
+        state = work if fam.close_state is None else fam.close_state(
+            state, work, step, cfg)
+    with jax.named_scope("head"):
+        # the rows that reach the head: a decode step's one, a prompt's
+        # last real token, every column of a verify window
         if kind == "decode":
-            new_pos = rows + 1
+            x = x[:, 0]
+        h = fam.final_norm(params, x, cfg)
+        if kind in ("fresh", "chunk"):
+            h = h[jnp.arange(tokens.shape[0]), rows - 1]
+        logits = fam.head(params, h, cfg)
+    if sample is None:
+        return logits, cache_k, cache_v, state
+    with jax.named_scope("sample"):
+        if kind == "verify":
+            out = verify_tokens(logits, rows, tokens, draft_len, sample)
         else:
-            new_pos = (rows if start is None else start + rows).astype(
-                jnp.int32)
-        out = sample_tokens(logits, new_pos, sample)
+            # the new token lands right after the row's last real one
+            if kind == "decode":
+                new_pos = rows + 1
+            else:
+                new_pos = (rows if start is None else start + rows).astype(
+                    jnp.int32)
+            out = sample_tokens(logits, new_pos, sample)
     return out, cache_k, cache_v, state
 
 

@@ -265,7 +265,8 @@ def _cached_embed(params, tokens, step, cfg: EvaByteConfig):
 
 def _cached_layer(x, bp, attend, step, state, cfg: EvaByteConfig):
     cos, sin, at = step.aux
-    q, k, v = _qkv(x, bp, cos, sin, cfg, positions=at)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _qkv(x, bp, cos, sin, cfg, positions=at)
 
     def summarise(cache_k, cache_v, layer):
         """The summaries of the chunks this step completes, into the
@@ -284,9 +285,11 @@ def _cached_layer(x, bp, attend, step, state, cfg: EvaByteConfig):
             start=step.pos[:, 0], lengths=step.rows, chunk=cfg.chunk_size,
             **common)
 
-    attn = attend(q, k, v, group=0, then=summarise)
-    y = x + (attn @ bp["wo"].astype(cfg.dtype)).astype(jnp.float32)
-    return _ffn(y, bp, cfg), state
+    with jax.named_scope("attn_proj"):
+        attn = attend(q, k, v, group=0, then=summarise)
+        y = x + (attn @ bp["wo"].astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("ffn"):
+        return _ffn(y, bp, cfg), state
 
 
 def _final_norm(params, x, cfg: EvaByteConfig):

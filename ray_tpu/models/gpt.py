@@ -359,8 +359,10 @@ def _cached_embed(params, tokens, step, cfg: GPTConfig):
 
 
 def _cached_layer(x, bp, attend, step, state, cfg: GPTConfig):
-    x = _attn_residual(x, attend(*_attn_qkv(x, bp, cfg)), bp, cfg)
-    return _mlp_residual(x, bp, cfg), state
+    with jax.named_scope("attn_proj"):
+        x = _attn_residual(x, attend(*_attn_qkv(x, bp, cfg)), bp, cfg)
+    with jax.named_scope("ffn"):
+        return _mlp_residual(x, bp, cfg), state
 
 
 def _final_norm(params, x, cfg: GPTConfig):

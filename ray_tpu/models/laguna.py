@@ -400,8 +400,9 @@ def _ffn(x, lp, cfg: LagunaConfig, valid):
     y, sizes = moe_dropless(
         flat, weights, experts, lp["moe_gmm_w_in"], lp["moe_gmm_w_out"],
         dtype=cfg.dtype, valid=valid.reshape(B * S), held=cfg.experts_held)
-    shared = _swiglu(h, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
-                     cfg.dtype)
+    with jax.named_scope("moe_shared"):
+        shared = _swiglu(h, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
+                         cfg.dtype)
     return x + shared + y.reshape(B, S, D), sizes
 
 
@@ -483,11 +484,13 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: LagunaConfig):
     i = work["layer"]
     kind = cfg.layer_types[i]
     group, slot, window = cfg.kv_layout[i]
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(h, lp, kind, step.aux, cfg)
-    attn = attend(q, k, v, group=group, slot=slot, window=window)
-    x = _attn_out(x, h, attn, lp, kind, cfg)
-    x, sizes = _ffn(x, lp, cfg, work["routed"])
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = _qkv(h, lp, kind, step.aux, cfg)
+        attn = attend(q, k, v, group=group, slot=slot, window=window)
+        x = _attn_out(x, h, attn, lp, kind, cfg)
+    with jax.named_scope("ffn"):
+        x, sizes = _ffn(x, lp, cfg, work["routed"])
     work = {**work, "layer": i + 1}
     if sizes is not None:
         work["sizes"] = [*work["sizes"], sizes]

@@ -404,32 +404,42 @@ def _open_state(state: dict, step, cfg: Lfm2MoeConfig) -> dict:
             "routed": routed}
 
 
-def _cached_layer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
+def _mixer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
+    """The layer's first half on x [B, S, D]: norm, the gated short
+    convolution over the slot's rows or attention over the pool, the
+    residual. Returns (x', work')."""
     h = rms_norm(x, lp["op_norm"], cfg.norm_eps)
-    if "short_conv_in" in lp:
-        conv, ci, slots = work["conv"], work["conv_done"], step.slots
-        decode = step.kind == "decode"  # one row a sequence: [B, D]
-        b, c, u = jnp.split(
-            (h[:, 0] if decode else h) @ lp["short_conv_in"].astype(cfg.dtype),
-            3, axis=-1)
-        if decode:
-            y, after = short_conv_decode(b * u, c, lp["short_conv_w"],
-                                         conv[ci, slots])
-        else:
-            before = None
-            if step.kind != "fresh":
-                before = jnp.where((step.start > 0)[:, None, None],
-                                   conv[ci, slots], 0)
-            y, after = short_conv_prefill(b * u, c, lp["short_conv_w"],
-                                          before, step.rows)
-        work = {**work, "conv_done": ci + 1,
-                "conv": conv.at[ci, slots].set(after.astype(conv.dtype))}
-        y = y @ lp["short_conv_out"].astype(cfg.dtype)
-        x = x + (y[:, None] if decode else y)
-    else:
+    if "short_conv_in" not in lp:
         q, k, v = _qkv(h, lp, *step.aux, cfg)
-        x = x + attend(q, k, v) @ lp["wo"].astype(cfg.dtype)
-    x, sizes = _ffn(x, lp, cfg, work["routed"])
+        return x + attend(q, k, v) @ lp["wo"].astype(cfg.dtype), work
+    conv, ci, slots = work["conv"], work["conv_done"], step.slots
+    decode = step.kind == "decode"  # one row a sequence: [B, D]
+    b, c, u = jnp.split(
+        (h[:, 0] if decode else h) @ lp["short_conv_in"].astype(cfg.dtype),
+        3, axis=-1)
+    if decode:
+        y, after = short_conv_decode(b * u, c, lp["short_conv_w"],
+                                     conv[ci, slots])
+    else:
+        before = None
+        if step.kind != "fresh":
+            before = jnp.where((step.start > 0)[:, None, None],
+                               conv[ci, slots], 0)
+        y, after = short_conv_prefill(b * u, c, lp["short_conv_w"],
+                                      before, step.rows)
+    work = {**work, "conv_done": ci + 1,
+            "conv": conv.at[ci, slots].set(after.astype(conv.dtype))}
+    y = y @ lp["short_conv_out"].astype(cfg.dtype)
+    return x + (y[:, None] if decode else y), work
+
+
+def _cached_layer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
+    # ``attn_proj``: the layer's mixer half, whichever mixer it is (the
+    # convolution itself is ``short_conv``, the cache side ``attn_*``)
+    with jax.named_scope("attn_proj"):
+        x, work = _mixer(x, lp, attend, step, work, cfg)
+    with jax.named_scope("ffn"):
+        x, sizes = _ffn(x, lp, cfg, work["routed"])
     if sizes is not None:
         work = {**work, "sizes": [*work["sizes"], sizes]}
     return x, work

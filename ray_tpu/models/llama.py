@@ -404,9 +404,11 @@ def _cached_embed(params, tokens, step, cfg: LlamaConfig):
 
 def _cached_layer(x, bp, attend, step, state, cfg: LlamaConfig):
     cos, sin, at = step.aux
-    q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=at)
-    x = x + attend(q, kk, vv) @ bp["wo"].astype(cfg.dtype)
-    x, _ = _ffn_residual(x, bp, cfg)
+    with jax.named_scope("attn_proj"):
+        q, kk, vv = _attn_qkv(x, bp, cos, sin, cfg, positions=at)
+        x = x + attend(q, kk, vv) @ bp["wo"].astype(cfg.dtype)
+    with jax.named_scope("ffn"):
+        x, _ = _ffn_residual(x, bp, cfg)
     return x, state
 
 

@@ -366,13 +366,19 @@ def _layer(x, lp, attention, cfg: LongCatFlashConfig, valid):
     FIRST half's normed output and lands behind the second half (the
     shortcut). Returns (x', sizes, zero) as ``_routed``."""
     first, second = lp["sub"]
-    x = x + attention(rms_norm(x, first["attn_norm"], cfg.norm_eps), first)
-    h = rms_norm(x, first["ffn_norm"], cfg.norm_eps)
-    s, sizes, zero = _routed(h, lp, cfg, valid)
-    x = x + _dense_ffn(h, first, cfg)
-    x = x + attention(rms_norm(x, second["attn_norm"], cfg.norm_eps), second)
-    h = rms_norm(x, second["ffn_norm"], cfg.norm_eps)
-    return x + _dense_ffn(h, second, cfg) + s, sizes, zero
+    with jax.named_scope("attn_proj"):
+        x = x + attention(
+            rms_norm(x, first["attn_norm"], cfg.norm_eps), first)
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, first["ffn_norm"], cfg.norm_eps)
+        s, sizes, zero = _routed(h, lp, cfg, valid)
+        x = x + _dense_ffn(h, first, cfg)
+    with jax.named_scope("attn_proj"):
+        x = x + attention(
+            rms_norm(x, second["attn_norm"], cfg.norm_eps), second)
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, second["ffn_norm"], cfg.norm_eps)
+        return x + _dense_ffn(h, second, cfg) + s, sizes, zero
 
 
 def longcat_flash_forward(params: dict, tokens: jax.Array,

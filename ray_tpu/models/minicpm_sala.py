@@ -387,7 +387,8 @@ def _lightning_mixer(h, lp, step, work: dict, cfg: MiniCPMSALAConfig):
             q[:, 0], k[:, 0], v[:, 0], states, li, slots, slopes, scale)
         o = o[:, None]
     else:
-        before = states[li, slots]
+        with jax.named_scope("attn_cache"):  # the slots' rows, read
+            before = states[li, slots]
         if step.kind == "decode":
             o, after = lightning_step(q[:, 0], k[:, 0], v[:, 0], before,
                                       slopes, scale)
@@ -402,7 +403,8 @@ def _lightning_mixer(h, lp, step, work: dict, cfg: MiniCPMSALAConfig):
                                    before, 0.0)
             o, after = lightning_chunk(q, k, v, before, step.rows, slopes,
                                        scale)
-        states = states.at[li, slots].set(after)
+        with jax.named_scope("attn_cache"):  # ... and written back
+            states = states.at[li, slots].set(after)
     work = {**work, "lightning_done": li + 1, "lightning": states}
     o = rms_norm(o.reshape(B, S, H * hd), lp["lightning_out_norm"],
                  cfg.norm_eps)
@@ -445,14 +447,16 @@ def _sparse_mixer(h, lp, attend, step, work: dict, cfg: MiniCPMSALAConfig):
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: MiniCPMSALAConfig):
     a = jnp.asarray(cfg.residual_scale, cfg.dtype)
-    h = rms_norm(x, lp["mixer_norm"], cfg.norm_eps)
-    if "lightning_wq" in lp:
-        y, work = _lightning_mixer(h, lp, step, work, cfg)
-    else:
-        y, work = _sparse_mixer(h, lp, attend, step, work, cfg)
-    x = x + a * y
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    x = x + a * _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype)
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, lp["mixer_norm"], cfg.norm_eps)
+        if "lightning_wq" in lp:
+            y, work = _lightning_mixer(h, lp, step, work, cfg)
+        else:
+            y, work = _sparse_mixer(h, lp, attend, step, work, cfg)
+        x = x + a * y
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+        x = x + a * _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype)
     return x, {**work, "layer": work["layer"] + 1}
 
 

@@ -353,8 +353,10 @@ def _ffn(x, lp, cfg: PanguUltraMoEConfig, valid):
             flat, weights, experts, lp["moe_gmm_w_in"], lp["moe_gmm_w_out"],
             dtype=cfg.dtype, valid=valid.reshape(B * S),
             held=cfg.experts_held)
-        out = _swiglu(z, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
-                      cfg.dtype) + y.reshape(B, S, D)
+        with jax.named_scope("moe_shared"):
+            shared = _swiglu(z, lp["moe_shared_w_in"],
+                             lp["moe_shared_w_out"], cfg.dtype)
+        out = shared + y.reshape(B, S, D)
     return x + rms_norm(out, lp["ffn_post_norm"], cfg.norm_eps), sizes
 
 
@@ -423,13 +425,16 @@ def _cached_embed(params, tokens, step, cfg: PanguUltraMoEConfig):
 
 def _cached_layer(x, lp, attend, step, work: dict,
                   cfg: PanguUltraMoEConfig):
-    u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q_nope, q_rope, c, k_r = _queries_and_row(u, lp, *step.aux, cfg)
-    q = jnp.concatenate([_absorb(q_nope, lp, cfg), q_rope], axis=-1)
-    o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
-    heads = _unabsorb(
-        o.reshape(*o.shape[:2], cfg.n_head, cfg.kv_lora_rank), lp, cfg)
-    x, sizes = _ffn(_attn_out(x, heads, lp, cfg), lp, cfg, work["routed"])
+    with jax.named_scope("attn_proj"):
+        u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q_nope, q_rope, c, k_r = _queries_and_row(u, lp, *step.aux, cfg)
+        q = jnp.concatenate([_absorb(q_nope, lp, cfg), q_rope], axis=-1)
+        o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
+        heads = _unabsorb(
+            o.reshape(*o.shape[:2], cfg.n_head, cfg.kv_lora_rank), lp, cfg)
+        x = _attn_out(x, heads, lp, cfg)
+    with jax.named_scope("ffn"):
+        x, sizes = _ffn(x, lp, cfg, work["routed"])
     work = {**work, "layer": work["layer"] + 1}
     if sizes is not None:
         work["sizes"] = [*work["sizes"], sizes]

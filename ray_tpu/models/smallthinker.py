@@ -338,13 +338,15 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: SmallThinkerConfig):
     i = work["layer"]
     kind = cfg.layer_types[i]
     group, slot, window = cfg.kv_layout[i]
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    route = _route(x, h, lp, cfg)  # before attention, from the input
-    q, k, v = _qkv(h, lp, kind, step.aux, cfg)
-    attn = attend(q, k, v, group=group, slot=slot,
-                  window=None if window is None else window_keys(cfg))
-    x = x + attn @ lp["wo"].astype(cfg.dtype)
-    x, sizes = _experts(x, lp, route, cfg, work["routed"])
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        route = _route(x, h, lp, cfg)  # before attention, from the input
+        q, k, v = _qkv(h, lp, kind, step.aux, cfg)
+        attn = attend(q, k, v, group=group, slot=slot,
+                      window=None if window is None else window_keys(cfg))
+        x = x + attn @ lp["wo"].astype(cfg.dtype)
+    with jax.named_scope("ffn"):
+        x, sizes = _experts(x, lp, route, cfg, work["routed"])
     return x, {**work, "layer": i + 1, "sizes": [*work["sizes"], sizes]}
 
 

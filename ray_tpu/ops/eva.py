@@ -113,14 +113,18 @@ def write_prefill_summaries(cache_k, cache_v, k, v, phi, mu, *, layer, start,
     block. Returns the pools."""
     B, S, H, hd = k.shape
     n = S // chunk
-    k_c, v_c = chunk_summaries(
-        k[:, :n * chunk].reshape(B, n, chunk, H, hd),
-        v[:, :n * chunk].reshape(B, n, chunk, H, hd), phi, mu,
-        backend=backend)
-    cols = jnp.arange(n, dtype=lengths.dtype)[None, :]
-    return write_kv(
-        cache_k, cache_v, k_c, v_c, start[:, None] // chunk + cols,
-        summaries, valid=(cols + 1) * chunk <= lengths[:, None], layer=layer)
+    # the kernel's name covers its call; the scope also what stands around
+    # it (the chunks' reshape, the summaries' scatter into the pools)
+    with jax.named_scope(KERNEL_NAME):
+        k_c, v_c = chunk_summaries(
+            k[:, :n * chunk].reshape(B, n, chunk, H, hd),
+            v[:, :n * chunk].reshape(B, n, chunk, H, hd), phi, mu,
+            backend=backend)
+        cols = jnp.arange(n, dtype=lengths.dtype)[None, :]
+        return write_kv(
+            cache_k, cache_v, k_c, v_c, start[:, None] // chunk + cols,
+            summaries, valid=(cols + 1) * chunk <= lengths[:, None],
+            layer=layer)
 
 
 def write_decode_summaries(cache_k, cache_v, phi, mu, *, layer, t, at, ring,
@@ -134,13 +138,14 @@ def write_decode_summaries(cache_k, cache_v, phi, mu, *, layer, t, at, ring,
     pools."""
     B = t.shape[0]
     H, hd = phi.shape
-    first = at - t % chunk  # the chunk's first slot, in table coordinates
-    blk, slot = physical_slots(
-        first[:, None] + jnp.arange(chunk, dtype=t.dtype), ring,
-        cache_k.shape[2])
-    k_c, v_c = chunk_summaries(
-        cache_k[layer, blk, slot].reshape(B, chunk, H, hd),
-        cache_v[layer, blk, slot].reshape(B, chunk, H, hd), phi, mu,
-        backend=backend)
-    return write_kv(cache_k, cache_v, k_c, v_c, t // chunk, summaries,
-                    valid=t % chunk == chunk - 1, layer=layer)
+    with jax.named_scope(KERNEL_NAME):  # the ring's gather is the larger part
+        first = at - t % chunk  # the chunk's first slot, in table coordinates
+        blk, slot = physical_slots(
+            first[:, None] + jnp.arange(chunk, dtype=t.dtype), ring,
+            cache_k.shape[2])
+        k_c, v_c = chunk_summaries(
+            cache_k[layer, blk, slot].reshape(B, chunk, H, hd),
+            cache_v[layer, blk, slot].reshape(B, chunk, H, hd), phi, mu,
+            backend=backend)
+        return write_kv(cache_k, cache_v, k_c, v_c, t // chunk, summaries,
+                        valid=t % chunk == chunk - 1, layer=layer)

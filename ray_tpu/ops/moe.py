@@ -484,7 +484,10 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     E = w_in.shape[0]
     if zero_from is not None and held is None:
         held = (0, E)  # every real expert is held: the zero ids are in no group
-    with jax.named_scope("moe_gmm"):
+    # ``moe_move``: what stands between the router and the grouped product
+    # and behind it (the sort, the rows' gather, the way back, the weighted
+    # combine); ``moe_gmm``: the grouped product alone
+    with jax.named_scope("moe_move"):
         flat = experts.reshape(T * k)
         kept = None  # [T * k]: the pairs some group computes; None: all
         if held is not None:
@@ -501,6 +504,7 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
         xs = x.astype(dtype)[order // k]
+    with jax.named_scope("moe_gmm"):
         if gmm_form(T * k, E, D, w_out.shape[1]) == "few_rows":
             ys = moe_gmm_few_rows(
                 xs, w_in.astype(dtype), w_out.astype(dtype), sizes, act=act)
@@ -515,6 +519,7 @@ def moe_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
                 gated, w_out.astype(dtype), sizes,
                 preferred_element_type=jnp.float32,
             )
+    with jax.named_scope("moe_move"):
         # back to (token, choice) order, then the weighted sum over choices
         back = jnp.zeros((T * k,), jnp.int32).at[order].set(
             jnp.arange(T * k, dtype=jnp.int32))

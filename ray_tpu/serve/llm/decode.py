@@ -7,12 +7,19 @@ family's file), jits them once with the K/V pools donated
 called with. Because jit caches by shape, the signature
 set size IS the number of compiled programs — the engine exposes it so
 tests (and ops dashboards) can assert the bucketing keeps it bounded.
+
+The record of a signature (``_programs``, process-wide like the jitted
+wrappers) also says what its first call cost and keeps the call's abstract
+arguments, from which ``program_scopes()`` can say, long after, which part
+of a layer each instruction of the compiled program belongs to.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
 from typing import Callable
+
+from ray_tpu.serve.llm import obs
 
 
 @dataclass(frozen=True)
@@ -283,7 +290,27 @@ _with_stack_room.__code__ = _with_stack_room.__code__.replace(
     co_stacksize=1 << 16)
 
 
+# THE registry of step programs, process-wide beside ``_jit_cache`` and
+# under its keys: ``{signature: record}`` for every (kind, tokens shape,
+# tables shape) a wrapper set has been called with, by any engine of the
+# process. A record is ``{"name", "first_call_s", "calls", "fn", "args",
+# "scopes"}``: the program's name in a compiler dump or a profiler trace
+# (``jit_<family>_<step>``); the seconds the process's first call of it took
+# on ``obs.clock`` (trace, compile or the read of the persistent cache,
+# launch: what a warm-up pays, or a window that meets a shape the warm-up
+# missed); the FIRST calls taken so far (one for every ``DecodeFns`` that
+# reached the signature: a later one compiles nothing, but may rebuild the
+# wrapper's fast path); the jitted wrapper and the first call's abstract
+# arguments, which hold no device buffer; and the instruction map once
+# ``program_scopes()`` has read it. It outlives the engines.
+_programs: dict[tuple, dict[tuple, dict]] = {}
+# how many programs ``program_scopes()`` has lowered, ever: a served
+# request lowers none (tests/test_serve_llm_program_scopes.py)
+lowerings = 0
+
+
 def _jitted(family: str, model_cfg, platform):
+    """``(registry of its programs, init, prefill, decode, verify)``."""
     # platforms that get the same settings share their wrappers
     options = _compiler_options(platform)
     key = (family, model_cfg, tuple(sorted((options or {}).items())))
@@ -295,7 +322,92 @@ def _jitted(family: str, model_cfg, platform):
             None if fn is None else _jit_named(fn, model_cfg, options, donate)
             for fn in (fam.prefill, fam.decode_step, fam.verify_step)))
         _jit_cache[key] = hit
-    return hit
+    return (_programs.setdefault(key, {}), *hit)
+
+
+def _abstract(tree):
+    """``tree`` with every array a ``jax.ShapeDtypeStruct`` (shape, dtype,
+    where it lives): what ``jit(...).lower`` needs, and no buffer. An
+    array on ONE device keeps no sharding: the call itself lowers it as
+    unspecified, and a ``ShapeDtypeStruct`` that names the device lowers
+    to another module (``sdy.sharding`` on every argument), which the
+    persistent compile cache does not know (probed on the chip, PR 50: 40
+    programs compiled again, 380 s, where they are read in seconds)."""
+    import jax
+
+    def leaf(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        sharding = getattr(x, "sharding", None)
+        if sharding is not None and len(sharding.device_set) == 1:
+            sharding = None
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding,
+            weak_type=getattr(x, "weak_type", False))
+
+    return jax.tree.map(leaf, tree)
+
+
+def _compiled_text(rec: dict) -> str:
+    """The program's compiled text, lowered again from what its first call
+    kept. The compile is a read of the persistent cache where the first
+    call wrote (or found) an entry, and THAT can hand back stale names:
+    JAX's cache key leaves an instruction's metadata out, so an executable
+    that a checkout from before the names wrote is found under this one's
+    key, with its own ``op_name``s. A text that does not name ``embed``
+    and ``head``, the two parts every step program has, is that (a tree
+    from before PR 50 named some parts of some families): it is compiled
+    once more under a key that holds the metadata (a real compile, dear,
+    once: the entry it writes is found by the next process). JAX also
+    remembers (module, options) -> executable in the process, so that
+    second compile carries an option the first did not:
+    ``xla_dump_hlo_module_re``, which does nothing while no dump is asked
+    for and is part of no persistent key. A cache from before a LATER
+    change of the vocabulary is not seen here: clear ``.jax_cache`` when
+    names change."""
+    import jax
+
+    global lowerings
+    lowerings += 1
+    args, kwargs = rec["args"]
+    lowered = rec["fn"].lower(*args, **kwargs)
+    text = lowered.compile().as_text()
+    if "/embed/" in text and "/head/" in text:
+        return text
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return lowered.compile(compiler_options={
+            "xla_dump_hlo_module_re": "scopes"}).as_text()
+    finally:
+        jax.config.update(flag, was)
+
+
+def _scopes_of(registry: dict, only=None) -> dict:
+    out = {}
+    for sig, rec in sorted(registry.items()):
+        if only is not None and rec["name"] not in only:
+            continue
+        if rec["scopes"] is None:
+            rec["scopes"] = obs.scope_map(_compiled_text(rec))
+        out[obs.shape_key(sig)] = {
+            "name": rec["name"], "scopes": rec["scopes"]}
+    return out
+
+
+def program_scopes(only=None) -> dict:
+    """Every step program this PROCESS has run, of every family and
+    configuration: ``{"<name> <shape key>": {"name", "scopes"}}``, the
+    entries ``DecodeFns.program_scopes`` gives for one (`` #<n>`` behind a
+    key that a second configuration of one family brings again). What a
+    benchmark calls after its engines have shut down."""
+    out = {}
+    for n, registry in enumerate(_programs.values()):
+        for key, held in _scopes_of(registry, only).items():
+            key = f"{held['name']} {key}"
+            out[f"{key} #{n}" if key in out else key] = held
+    return out
 
 
 class DecodeFns:
@@ -316,25 +428,53 @@ class DecodeFns:
         get_family(family)  # raises on a family that is not served
         self.family = family
         self.model_cfg = model_cfg
-        self.init, self._prefill, self._decode, self._verify = _jitted(
-            family, model_cfg, platform
-        )
-        self._signatures: set[tuple] = set()
+        (self._programs, self.init, self._prefill, self._decode,
+         self._verify) = _jitted(family, model_cfg, platform)
+        # the signatures THIS instance has called, each with its record of
+        # ``_programs`` (the one registry: ``signatures`` and
+        # ``num_compiled_shapes`` below are views of it)
+        self._signatures: dict[tuple, dict] = {}
+        # the weights' abstract tree, made once an instance
+        self._abstract_params = None
         # called with (kind, tokens_shape, tables_shape) the first time
-        # THIS instance sees a signature — the engine hangs its
-        # compile-event counter here (jitted programs are process-shared,
-        # so per-instance first-use is the per-engine compile event)
+        # THIS instance sees a signature, BEFORE the call — the engine
+        # hangs its compile-event counter here (jitted programs are
+        # process-shared, so per-instance first-use is the per-engine
+        # compile event). A dict it returns (the engine's flight record)
+        # gets ``ms``, what the call took, as the call returns
         self.on_new_signature = None
 
     def _call(self, fn, sig: tuple, *args, **kwargs):
         """``fn(*args, **kwargs)``, the signature noted. The first call of
-        a signature is the one that may trace: it gets stack room."""
+        a signature is the one that may trace: it gets stack room, and is
+        the only one that is timed or kept."""
         if sig in self._signatures:
             return fn(*args, **kwargs)
-        self._signatures.add(sig)
-        if self.on_new_signature is not None:
-            self.on_new_signature(sig)
-        return _with_stack_room(fn, args, kwargs)
+        note = (None if self.on_new_signature is None
+                else self.on_new_signature(sig))
+        t0 = obs.clock()
+        try:
+            return _with_stack_room(fn, args, kwargs)
+        finally:
+            seconds = obs.clock() - t0
+            self._first_call(fn, sig, args, kwargs, seconds)
+            if isinstance(note, dict):
+                note["ms"] = round(seconds * 1000.0, 3)
+
+    def _first_call(self, fn, sig, args, kwargs, seconds: float) -> None:
+        rec = self._programs.get(sig)
+        if rec is None:
+            if self._abstract_params is None and args:
+                self._abstract_params = _abstract(args[0])
+            rec = self._programs[sig] = {
+                "name": f"jit_{getattr(fn, '__name__', 'unknown')}",
+                "first_call_s": seconds, "calls": 0, "fn": fn,
+                "args": ((self._abstract_params, *_abstract(args[1:])),
+                         _abstract(kwargs)),
+                "scopes": None,
+            }
+        rec["calls"] += 1
+        self._signatures[sig] = rec
 
     def prefill(
         self, params, cache_k, cache_v, tokens, lengths, block_tables,
@@ -392,3 +532,28 @@ class DecodeFns:
     @property
     def signatures(self) -> frozenset:
         return frozenset(self._signatures)
+
+    def programs(self) -> dict:
+        """``{shape key: {"name", "first_call_s", "calls"}}`` for every
+        step program of this family and configuration that the PROCESS has
+        run (an engine warmed by another's calls has them all): the
+        program's name in a trace, the seconds its first call took
+        (trace, compile or cache read, launch) and the first calls taken
+        so far. A dict copy: cheap, no device, no jax."""
+        return {obs.shape_key(sig): {
+            "name": rec["name"], "first_call_s": rec["first_call_s"],
+            "calls": rec["calls"]} for sig, rec in self._programs.items()}
+
+    def program_scopes(self, only=None) -> dict:
+        """``{shape key: {"name", "scopes": {instruction: (scope, result
+        type, mixed)}}}``: which part of a layer (``obs.SCOPES``) each
+        instruction of each program of ``programs()`` belongs to, read
+        from the compiled text (``obs.scope_map``). LAZY and dear: every
+        program is lowered again from its first call's abstract arguments
+        and compiled (a read of the persistent compile cache where the
+        first call wrote one; seconds a program of an unrolled stack), once
+        a process. Nothing in the engine calls it. ``only``: program names
+        (``jit_llama_prefill``) to keep to. An operator's use: take a
+        profiler trace of a replica, call this, and book each ``XLA Ops``
+        event of the trace (named by its instruction) to its part."""
+        return _scopes_of(self._programs, only)

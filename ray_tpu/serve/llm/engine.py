@@ -1541,7 +1541,6 @@ class LLMEngine:
                 "preemptions_total": self._preempted_total,
                 "preempt_exhausted": self._preempt_exhausted,
                 "kv_used_blocks": self.cache.used_blocks,
-                "kv_utilization": self.cache.utilization,
                 "kv_high_water_blocks": cs.high_water_blocks,
                 # tables by group of layers ([] for one table): a group's
                 # window, layers, blocks held now and at most; the blocks
@@ -1569,6 +1568,11 @@ class LLMEngine:
                 **{f"eva_{name}": n
                    for name, n in self._eva_counts.items()},
                 "num_compiled_shapes": self.fns.num_compiled_shapes,
+                # every step program of this family and configuration the
+                # PROCESS has run, by shape key: its name in a trace, the
+                # seconds its first call took (trace, compile or cache
+                # read, launch) and the first calls taken so far
+                "programs": self.fns.programs(),
                 # the grouped expert product's form (ops/moe.py
                 # ``gmm_form``) by step program this engine has run:
                 # ``<kind>@<rows>[x<tokens a row>]``
@@ -1677,6 +1681,14 @@ class LLMEngine:
         kept as an engine attribute for tests/dashboards that predate
         the executor seam."""
         return self.executor.fns
+
+    def program_scopes(self, only=None) -> dict:
+        """Which part of a layer (``obs.SCOPES``) each instruction of each
+        step program belongs to (``DecodeFns.program_scopes``): what turns
+        a profiler trace of this replica into device time by part. Dear
+        (every program is lowered again), lazy, and never called by the
+        engine; it answers after ``shutdown()`` too."""
+        return self.fns.program_scopes(only)
 
     @property
     def params(self):
@@ -3616,16 +3628,19 @@ class LLMEngine:
         self._flight_prev["promote"] = cs.promoted_blocks
         self._flight.record(rec)
 
-    def _on_new_signature(self, sig: tuple) -> None:
+    def _on_new_signature(self, sig: tuple) -> dict:
         """DecodeFns hook: a shape this engine has not run before — i.e.
         a compile event (programs are process-shared; this counts first
         use per engine). Tagged by shape key; also marked in the flight
-        ring so a latency spike next to a compile explains itself."""
+        ring so a latency spike next to a compile explains itself. The
+        mark is made BEFORE the call (a watchdog's dump of a step wedged
+        in a compile holds it) and handed back: ``DecodeFns`` writes
+        ``ms``, what the first call took, into it as the call returns."""
         key = obs.shape_key(sig)
         self._m_compile.inc(tags={"shape": key})
-        self._flight.record(
-            {"kind": "compile", "ts": obs.wall(), "shape": key}
-        )
+        rec = {"kind": "compile", "ts": obs.wall(), "shape": key}
+        self._flight.record(rec)
+        return rec
 
     def _dump(self, reason: str, *, path: str | None = None,
               lock_free: bool = False) -> str | None:

@@ -25,6 +25,7 @@ import gc
 import json
 import logging
 import os
+import re
 import tempfile
 import threading
 import time
@@ -292,6 +293,194 @@ def shape_key(sig: tuple) -> str:
         f"{kind}:{'x'.join(str(d) for d in tok)}:"
         f"{'x'.join(str(d) for d in tbl)}"
     )
+
+
+# ---- the program's own names for the parts of a step ------------------
+#
+# THE vocabulary: every ``jax.named_scope`` of a step program (models/
+# cached.py, the families' ``layer``, ops/) takes its name from here, and
+# nothing else reads as a part. A scope is metadata of the compiled
+# program (``op_name="jit(llama_prefill)/.../attn_proj/dot_general"``): it
+# costs a trace its string and a served step nothing, and it is NOT in a
+# profiler trace (an ``XLA Ops`` event is the instruction's text without
+# its metadata), which is why ``DecodeFns.program_scopes()`` maps
+# instruction names to scopes from the compiled text. Scopes nest, and an
+# instruction belongs to the INNERMOST one that is listed here:
+#
+# - ``embed``: the token (and position) embedding, and what a step
+#   prepares per token before its layers: positions, masks, rotary rows,
+#   the working form of ``state``;
+# - ``layer_stack``: a scanned stack's own loop: a layer's weights sliced
+#   out of the stacked tree, the loop's counter (the layers' own
+#   operations lie deeper, under the names below);
+# - ``attn_proj``: an attention layer around its cache side: the norm
+#   before it, the q / k / v / o products, rotary, QK-norm, a gate a
+#   head, a latent layer's down / up and absorb products, the residual;
+# - ``attn_cache``: the scatter of the step's new rows into the pool;
+# - ``attn_kernel``: the paged / latent / window / sparse attention call
+#   and the relayout around it. Inside it ``sparse_select`` (the
+#   selection of a selecting layer's pages) and
+#   ``sparse_prefill_attention`` (its chunk attention) keep their names;
+# - ``eva_summarize``: a composed family's chunk summaries (ops/eva.py),
+#   written between ``attn_cache`` and ``attn_kernel``;
+# - ``short_conv``, ``lightning_step``, ``lightning_chunk``: the mixers
+#   that stand where attention would (their in / out products lie under
+#   ``attn_proj``);
+# - ``ffn``: the feed-forward half of a layer: its norm, the dense MLP,
+#   the residual. Inside it ``dense_ffn`` (a double layer's SwiGLUs),
+#   ``moe_route`` (router product, scores, top-k), ``moe_move`` (what
+#   stands between router and grouped product and behind it: the sort,
+#   the gathers, the weighted combine), ``moe_gmm`` (the grouped expert
+#   product), ``moe_zero`` (zero-compute experts) and ``moe_shared`` (the
+#   shared expert);
+# - ``head``: the final norm, the gather of the rows that reach the head,
+#   the head's product; ``sample``: the sampling / verify epilogue;
+# - ``counters``: the counter words a family's programs keep in ``state``.
+SCOPES = (
+    "embed", "layer_stack", "attn_proj", "attn_cache", "attn_kernel",
+    "sparse_select", "sparse_prefill_attention", "eva_summarize",
+    "short_conv", "lightning_step", "lightning_chunk", "ffn", "dense_ffn",
+    "moe_route", "moe_move", "moe_gmm", "moe_zero", "moe_shared", "head",
+    "sample", "counters",
+)
+# an instruction under none of them
+UNNAMED = "unnamed"
+
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# a printed type's layouts and the ``/*index=5*/`` marks of a long tuple
+_LAYOUT = re.compile(r"\{[^{}]*\}|/\*[^*]*\*/")
+_CALLED = re.compile(r"\b(calls|to_apply)=%([\w.\-]+)")
+_REF = re.compile(r"%[\w.\-]+")
+
+
+def scope_of(op_name: str) -> str:
+    """The innermost component of an instruction's ``op_name`` that is a
+    name of ``SCOPES``; ``UNNAMED`` where none is."""
+    for part in reversed(op_name.split("/")):
+        if part in SCOPES:
+            return part
+    return UNNAMED
+
+
+def instruction_key(line: str) -> tuple[str, str] | None:
+    """``(name, result type)`` of one instruction's text: a line of a
+    compiled program (``%fusion.3 = bf16[64,4096]{1,0:T(8,128)} fusion(
+    %p), kind=kLoop, ..., metadata={...}``) or a device event's name in a
+    profiler trace, which is that line with the operands' types written
+    out and no metadata. The type is taken without its layouts (``bf16[64,
+    4096]``; a tuple's in its parentheses): the two printers agree on
+    shapes. None where the text is no instruction."""
+    line = line.strip()
+    if line.startswith("ROOT "):
+        line = line[5:]
+    name, eq, rest = line.partition(" = ")
+    if not eq or not name.startswith("%"):
+        return None
+    if rest.startswith("("):  # a tuple: up to the parenthesis that closes it
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        kind = rest[:i + 1]
+    else:
+        kind = rest.split(" ", 1)[0]
+    return name[1:], _LAYOUT.sub("", kind)
+
+
+def hlo_computations(text: str) -> dict[str, list[str]]:
+    """``{computation name: its instructions' lines}`` of a compiled
+    program's text."""
+    bodies: dict[str, list[str]] = {}
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            # "%name (params) -> type {" or "ENTRY %name (...) -> ... {"
+            if line.endswith("{") and "->" in line and (
+                    line.startswith("%") or line.startswith("ENTRY ")):
+                head = line.removeprefix("ENTRY ")
+                current = bodies.setdefault(head.split(" ", 1)[0][1:], [])
+        elif line.startswith("}"):
+            current = None
+        else:
+            current.append(line)
+    return bodies
+
+
+def scope_map(text: str) -> dict[str, tuple[str, str, bool]]:
+    """``{instruction name: (scope, result type, mixed)}`` of one compiled
+    program's text (``compiled.as_text()``), one entry for every
+    instruction that can run as a device event of its own: those of the
+    entry computation, of ``while`` bodies and conditions and of
+    ``conditional`` branches. What a fusion holds runs as the ONE fusion,
+    so a fused computation's instructions have no entry; the fusion is
+    named by its own metadata (the compiler gives it its root's), or, where
+    that names no scope, by the one scope its fused instructions agree on;
+    ``mixed`` says that they name more than one (a norm fused into the
+    product behind it: the fusion's time is then booked whole to the scope
+    it carries). An instruction the COMPILER added carries no ``op_name``
+    of the program's (none at all: a copy that relays an operand out, a
+    prefetch's ``copy-start`` / ``copy-done``; or a bare ``reduce_window_sum``
+    with no ``jit(...)/`` path before it): it takes the scope its users in the computation
+    agree on, else the one its operands agree on; one that has an
+    ``op_name`` under no scope stays ``UNNAMED``: the program did not name
+    it. Instruction names are unique in a module. A pure function: no
+    jax, no device."""
+    bodies = hlo_computations(text)
+    fused: set[str] = set()
+    for lines in bodies.values():
+        for line in lines:
+            for how, callee in _CALLED.findall(line):
+                if how == "to_apply" or " fusion(" in line:
+                    fused.add(callee)
+
+    def own(line: str) -> str:
+        m = _OP_NAME.search(line)
+        return scope_of(m.group(1)) if m else UNNAMED
+
+    out: dict[str, tuple[str, str, bool]] = {}
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        named: dict[str, list] = {}    # name -> [scope, type, mixed]
+        added: dict[str, list] = {}    # the compiler's own -> operands
+        for line in lines:
+            key = instruction_key(line)
+            if key is None:
+                continue
+            scope, mixed = own(line), False
+            callee = _CALLED.search(line) if " fusion(" in line else None
+            if callee is not None:
+                inside = {own(ln) for ln in bodies.get(callee.group(2), ())
+                          if "metadata={" in ln} - {UNNAMED}
+                mixed = len(inside | ({scope} - {UNNAMED})) > 1
+                if scope == UNNAMED and len(inside) == 1:
+                    scope = next(iter(inside))
+            named[key[0]] = [scope, key[1], mixed]
+            op_name = _OP_NAME.search(line)
+            if scope == UNNAMED and not (
+                    op_name and op_name.group(1).startswith("jit(")):
+                added[key[0]] = [
+                    n[1:] for n in _REF.findall(line.split(" = ", 1)[1])]
+        users: dict[str, list] = {}
+        for line in lines if added else ():
+            key = instruction_key(line)
+            if key is not None:
+                for n in _REF.findall(line.split(" = ", 1)[1]):
+                    if n[1:] in added:
+                        users.setdefault(n[1:], []).append(key[0])
+        for _ in range(3 if added else 0):  # a copy of a copy of a ...
+            for name, operands in added.items():
+                if named[name][0] != UNNAMED:
+                    continue
+                for around in (users.get(name, ()), operands):
+                    agreed = {named[n][0] for n in around
+                              if n in named} - {UNNAMED}
+                    if len(agreed) == 1:
+                        named[name][0] = next(iter(agreed))
+                        break
+        out.update((k, tuple(v)) for k, v in named.items())
+    return out
 
 
 class FlightRecorder:
