@@ -44,6 +44,11 @@ SHAPES = {
     # a prefill chunk over a latent pool: 8 queries x the heads a tile
     "cell8-pangu-latent-chunk": (1, 128, 1, 576, 2047, 12288, "latent-chunk"),
     "cell10-longcat-latent-chunk": (1, 64, 1, 576, 1023, 6144, "latent-chunk"),
+    # the same chunk in the EXPANDED form (ops/latent_prefill.py: keys and
+    # values by head through ``flash_fwd``, what a prefill step runs since
+    # ISSUE 51), its up-projections included, beside the absorbed call
+    "cell8-pangu-expanded-chunk": (1, 128, 1, 576, 2047, 12288, "expanded-chunk"),
+    "cell10-longcat-expanded-chunk": (1, 64, 1, 576, 1023, 6144, "expanded-chunk"),
     "cell1-prefill": (4, 32, 8, 128, 2047, 2048, "prefill"),
     "cell4-prefill": (4, 12, 12, 64, 1023, 1024, "prefill"),
 }
@@ -58,8 +63,10 @@ rng = np.random.default_rng(0)
 out_lines = []
 for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
     NB = top // bs
-    chunk = window == "latent-chunk"
-    prefill, latent = window == "prefill", window in ("latent", "latent-chunk")
+    expanded = window == "expanded-chunk"
+    chunk = window == "latent-chunk" or expanded
+    prefill = window == "prefill"
+    latent = window in ("latent", "latent-chunk", "expanded-chunk")
     if prefill or latent:
         window = None
     ctx = np.clip(rng.lognormal(np.log(mean), 0.5, B).astype(int), 16, top - 1)
@@ -95,6 +102,12 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
             q = jax.random.normal(jax.random.fold_in(key, 2), (B, mean + 1, Hq, 576), jnp.bfloat16)
             pos = jnp.broadcast_to(jnp.arange(mean + 1, dtype=jnp.int32), (B, mean + 1))
             kv_bytes //= 2  # a tile attends up to its frontier: half on average
+        if expanded:  # q as projected, the chunk's own rows, W_uk and W_uv
+            rnd = lambda i, *shape: jax.random.normal(
+                jax.random.fold_in(key, i), shape, jnp.bfloat16)
+            q = q[..., :192]
+            own = (rnd(3, B, mean + 1, 512), rnd(4, B, mean + 1, 64),
+                   rnd(5, 512, Hq, 128), rnd(6, 512, Hq, 128))
     ref = None
     for variant in variants:
         block_tokens = getattr(pa, "_block_tokens", None)
@@ -114,6 +127,11 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
                 if not latent else pa.latent_attention(
                     q, k, v, t, p, latent_dim=512, scale=192 ** -0.5,
                     backend="pallas", layer=jnp.int32(1)))
+            if expanded:
+                from ray_tpu.ops.latent_prefill import expanded_prefill_attention
+                fn = jax.jit(lambda q, k, v, t, p: expanded_prefill_attention(
+                    q, *own[:2], k, v, t, p >= 0, None, *own[2:],
+                    scale=192 ** -0.5, backend="pallas", layer=jnp.int32(1)))
             o = jax.block_until_ready(fn(q, k_pool, v_pool, tables_d, pos))
             times = []
             for _ in range(7):

@@ -32,10 +32,10 @@ own, in a ``CachedFamily``:
   attending layer's ordinal, full attention. A LATENT layer (one row a
   token that every head reads as key and as value, the pool in planes:
   models/pangu_ultra_moe.py) calls ``attend(q, row, rope, latent=scale)``
-  with q ``[B, S, H, C + R]`` and ONE ``row [B, S, C]`` and ``rope [B, S,
-  R]``, gets ``[B, S, H * C]`` back and un-absorbs it itself. A layer
-  that SELECTS the pages it attends (models/minicpm_sala.py) calls
-  ``attend(q, k, v, select=Selection(...))`` (ops/sparse_select.py);
+  with ONE ``row [B, S, C]`` and ``rope [B, S, R]``: q absorbed ``[B, S,
+  H, C + R]`` for ``[B, S, H * C]`` back (decode), or with ``up=(W_uk,
+  W_uv)`` q as projected for the heads' outputs (prefill). A layer that
+  SELECTS (models/minicpm_sala.py): ``attend(q, k, v, select=...)``;
 - ``final_norm(params, x, cfg)`` and ``head(params, h, cfg)`` (float32
   logits over ``[..., D]``);
 - ``stack``: the key of ``params`` that holds the layers. A tree whose
@@ -154,7 +154,7 @@ def _plan(kind, tokens, rows, block_tables, start, draft_len, slots) -> Step:
 
 def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
                  tables=None, window=None, then=None, latent=None,
-                 select=None):
+                 select=None, up=None):
     """The cache side of one attention layer, on the WHOLE pools and the
     layer's index in them (an int32 scalar, traced under the scan): the
     chunk's K/V rows are scattered into the pools at ``[layer, blk,
@@ -173,8 +173,8 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     token's latent row ``[B, S, C]`` and ``v`` the key's rotary rest ``[B,
     S, R]``, one of each for all heads, written to ``cache_k`` and
     ``cache_v`` (the latent and the rotary plane); ``q`` is ``[B, S, H, C
-    + R]`` and what comes back ``[B, S, H * C]``, every kind of step
-    through the one call.
+    + R]`` and what comes back ``[B, S, H * C]``; with ``up`` the
+    expanded form of a prefill step (``_attend_latent``).
 
     ``select``: the layer SELECTS the pages it attends
     (ops/sparse_select.py ``Selection``; models/minicpm_sala.py): K and V
@@ -198,11 +198,11 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
                 v[:, 0] if one else v, at[:, 0] if one else at, tables,
                 valid=step.valid, layer=layer)
         with jax.named_scope("attn_kernel"):
-            attn = latent_attention(
-                q, cache_k, cache_v, tables,
-                at if step.valid is None else jnp.where(step.valid, at, 0),
-                latent_dim=k.shape[-1], scale=latent, backend=backend,
-                layer=layer)
+            # ``up``: the layer hands q NOT absorbed and its W_uk, W_uv: a
+            # prefill step attends in the expanded form (at the file's
+            # end: no line of the other branches' call chains moves)
+            attn = _attend_latent(step, cache_k, cache_v, layer, q, k, v,
+                                  tables, at, backend, latent, up)
             return attn.reshape(B, S, -1), cache_k, cache_v
     if step.kind == "decode":
         at = step.rows if step.at is None else step.at[:, 0]
@@ -308,13 +308,13 @@ def _walk(fam, x, layers, cache_k, cache_v, step, state, cfg):
     attended = 0  # the pool spans the attending layers only
 
     def attend(q, k, v, *, group=None, slot=None, window=None, latent=None,
-               select=None):
+               select=None, up=None):
         nonlocal cache_k, cache_v, attended
         attn, cache_k, cache_v = attend_layer(
             step, cache_k, cache_v, attended if slot is None else slot,
             q, k, v, cfg,
             None if group is None else step.block_tables[group], window,
-            latent=latent, select=select)
+            latent=latent, select=select, up=up)
         attended += 1
         return attn
 
@@ -438,3 +438,26 @@ def steps(fam: CachedFamily):
     for fn in (prefill, decode_step, verify_step):
         fn.__name__ = fn.__qualname__ = f"{fam.name}_{fn.__name__}"
     return prefill, decode_step, verify_step
+
+
+def _attend_latent(step, cache_k, cache_v, layer, q, k, v, tables, at,
+                   backend, scale, up):
+    """A latent layer's attention over the planes its rows were just
+    written to. Without ``up`` the ABSORBED form, every kind of step
+    through the one call (``latent_attention``: q ``[B, S, H, C + R]``,
+    ``[B, S, H, C]`` back, which the layer un-absorbs). With ``up = (W_uk
+    [C, H, N], W_uv [C, H, V])`` the EXPANDED form of a prefill step
+    (ops/latent_prefill.py: q ``[B, S, H, N + R]`` as projected, the heads'
+    outputs ``[B, S, H * V]`` back): the step's own keys in hand, the
+    resident prefix block by block from the planes."""
+    if up is None:
+        return latent_attention(
+            q, cache_k, cache_v, tables,
+            at if step.valid is None else jnp.where(step.valid, at, 0),
+            latent_dim=k.shape[-1], scale=scale, backend=backend,
+            layer=layer)
+    from ray_tpu.ops.latent_prefill import expanded_prefill_attention
+
+    return expanded_prefill_attention(
+        q, k, v, cache_k, cache_v, tables, step.valid, step.start, *up,
+        scale=scale, backend=backend, layer=layer)

@@ -26,10 +26,12 @@ each time; for layer ``l`` with input ``x``::
 The two rescalings (``mla_scale_q_lora``, ``mla_scale_kv_lora``) multiply
 ``q`` (both parts) and the NORMED ``c`` (so the keys' nope part and the
 values, not ``k_r``). The pool caches ``[c | k_r]`` with ``c`` ALREADY
-rescaled and the cached step computes the ABSORBED form (models/
-pangu_ultra_moe.py: ``_absorb`` before ``attend(..., latent=scale)``,
-``_unabsorb`` after; this file imports them, there is one copy);
-``longcat_flash_forward`` (no cache) computes the EXPANDED form.
+rescaled and the cached step computes the form its kind wants (models/
+pangu_ultra_moe.py ``_cached_heads``: a decode step the ABSORBED form,
+``_absorb`` before ``attend(..., latent=scale)``, ``_unabsorb`` after; a
+prefill step the EXPANDED one, ``attend(..., up=)``; this file imports
+them, there is one copy); ``longcat_flash_forward`` (no cache) computes
+the EXPANDED form.
 
 ``MoE(h)``: ``p = softmax(h W_r)`` over ALL ``num_experts +
 num_zero_experts`` outputs (512 + 256), float32 at the highest precision;
@@ -80,12 +82,14 @@ from ray_tpu.models.lfm2_moe import _count_add, _swiglu, count_value
 from ray_tpu.models.pangu_ultra_moe import (
     QK_GAIN,
     _absorb,
+    _cached_heads,
     _final_norm,
     _head,
     _queries_and_row,
     _rotary_at,
     _unabsorb,
     expanded_attention,
+    step_attrs,
 )
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import moe_dropless, moe_route
@@ -419,12 +423,9 @@ def _open_state(state: dict, step, cfg: LongCatFlashConfig) -> dict:
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: LongCatFlashConfig):
     def attention(u, sp):
-        q_nope, q_rope, c, k_r = _rows(u, sp, *step.aux, cfg)
-        q = jnp.concatenate([_absorb(q_nope, sp, cfg), q_rope], axis=-1)
         # the pool's layer is the attending call's ordinal: 2 l + j
-        o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
-        heads = _unabsorb(
-            o.reshape(*o.shape[:2], cfg.n_head, cfg.kv_lora_rank), sp, cfg)
+        heads = _cached_heads(
+            *_rows(u, sp, *step.aux, cfg), sp, attend, step, cfg)
         return heads @ sp["mla_w_o"].astype(cfg.dtype)
 
     x, sizes, zero = _layer(x, lp, attention, cfg, work["routed"])

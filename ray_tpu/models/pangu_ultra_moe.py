@@ -19,13 +19,16 @@ at position ``t`` is::
     x' = y + RMSNorm(ffn(z))              # ... each sub-layer too
 
 ``[c | k_r]`` (C + R numbers) is ALL a layer caches of a token, one row
-for every head. The cached step computes the ABSORBED form: attention of
-the heads over one shared row whose key is the row and whose value is its
+for every head. The cached step computes the form its KIND wants
+(``_cached_heads``). A DECODE step the ABSORBED form: attention of the
+heads over one shared row whose key is the row and whose value is its
 first C numbers (models/cached.py ``attend(..., latent=s)``,
 ops/paged_attention.py ``latent_attention``; ``_absorb`` before it,
-``_unabsorb`` after); ``pangu_ultra_moe_forward`` (no cache) computes the
-EXPANDED form, and the two are the same numbers
-(tests/test_pangu_ultra_moe.py). ``ffn``: SwiGLU ``d_mlp`` on the first
+``_unabsorb`` after). A PREFILL step the EXPANDED form (``attend(...,
+latent=s, up=(W_uk, W_uv))``, ops/latent_prefill.py: a key's
+up-projection is shared by the step's many queries, at 3.4 x fewer
+operations a pair), as ``pangu_ultra_moe_forward`` (no cache) does; the
+two are the same numbers (tests/test_pangu_ultra_moe.py). ``ffn``: SwiGLU ``d_mlp`` on the first
 ``num_dense_layers`` layers, then the shared expert plus ``moe_route``
 without a selection bias (sigmoid scores, the ``top_k`` largest divided by
 their sum, times ``routed_scaling_factor``) over ``moe_dropless``. Final
@@ -65,6 +68,7 @@ from ray_tpu.models.laguna import (
 )
 from ray_tpu.models.lfm2_moe import _swiglu
 from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.ops.latent_prefill import prefix_blocks
 from ray_tpu.ops.layers import rms_norm, rope
 from ray_tpu.ops.moe import moe_dropless, moe_route
 from ray_tpu.ops.paged_attention import plane_width
@@ -423,15 +427,47 @@ def _cached_embed(params, tokens, step, cfg: PanguUltraMoEConfig):
     return x, _rotary_at(step.pos, cfg)
 
 
+def _cached_heads(q_nope, q_rope, c, k_r, lp, attend, step,
+                  cfg: PanguUltraMoEConfig):
+    """The heads' outputs ``[B, S, H * V]`` through the cache, in the form
+    the KIND of step wants (models/cached.py ``_attend_latent``). A decode
+    row reads one shared row a token for all heads: the ABSORBED form
+    (``W_uk`` into the query, ``W_uv`` out of the result). A prefill
+    step's many queries share each key's up-projection: the EXPANDED form,
+    the queries as projected and the two matrices by head handed on."""
+    C, H = cfg.kv_lora_rank, cfg.n_head
+    if step.kind == "decode":
+        q = jnp.concatenate([_absorb(q_nope, lp, cfg), q_rope], axis=-1)
+        o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
+        return _unabsorb(o.reshape(*o.shape[:2], H, C), lp, cfg)
+    return attend(
+        jnp.concatenate([q_nope, q_rope], axis=-1), c, k_r,
+        latent=cfg.softmax_scale,
+        up=tuple(lp[w].astype(cfg.dtype).reshape(C, H, -1)
+                 for w in ("mla_w_uk", "mla_w_uv")))
+
+
+def step_attrs(cfg, kind: str, rows: list) -> dict:
+    """What a step's ``executor.dispatch`` span says of the form its
+    latent layers attended in (decode.py ``Family.step_attrs``; ``rows``
+    ``[(first position, tokens)]`` a request): ``expanded_pairs``, the
+    (query, key) pairs that went through the expanded form (every pair of
+    a prefill step, none of a decode step), and a prefill step's
+    ``prefix_blocks``, the key blocks of resident prefixes it up-projected
+    a layer."""
+    if kind == "decode":
+        return {"expanded_pairs": 0}
+    return {"expanded_pairs": sum(n * first + n * (n + 1) // 2
+                                  for first, n in rows),
+            "prefix_blocks": sum(prefix_blocks(first) for first, _ in rows)}
+
+
 def _cached_layer(x, lp, attend, step, work: dict,
                   cfg: PanguUltraMoEConfig):
     with jax.named_scope("attn_proj"):
         u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q_nope, q_rope, c, k_r = _queries_and_row(u, lp, *step.aux, cfg)
-        q = jnp.concatenate([_absorb(q_nope, lp, cfg), q_rope], axis=-1)
-        o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
-        heads = _unabsorb(
-            o.reshape(*o.shape[:2], cfg.n_head, cfg.kv_lora_rank), lp, cfg)
+        heads = _cached_heads(
+            *_queries_and_row(u, lp, *step.aux, cfg), lp, attend, step, cfg)
         x = _attn_out(x, heads, lp, cfg)
     with jax.named_scope("ffn"):
         x, sizes = _ffn(x, lp, cfg, work["routed"])

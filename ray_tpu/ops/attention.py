@@ -129,13 +129,16 @@ def _bdot(a, b, contract, batch=((0,), (0,)), out=jnp.float32):
 
 
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref,  # [g, block_q, D], [g, block_kv, D], [g, block_kv, D]
-    o_ref,                # [g, block_q, D]
+    q_ref, k_ref, v_ref,  # [g, block_q, D], [g, block_kv, D], [g, block_kv, Dv]
+    o_ref,                # [g, block_q, Dv]
     *rest,                # optional lse_ref [g, block_q, 128], then scratch
     causal: bool,
     block_q: int,
     block_kv: int,
     save_lse: bool,
+    seg=None,             # (q_seg_ref, k_seg_ref, q_span_ref, k_span_ref)
+    carry=None,           # (o_ref [g, block_q, Dv], lse_ref [g, block_q, 128])
+    halve_diagonal=False,
 ):
     from jax.experimental import pallas as pl
 
@@ -150,41 +153,95 @@ def _flash_fwd_kernel(
 
     @pl.when(kv_idx == 0)
     def _init():
+        if carry is not None:
+            # go on from an earlier call over OTHER keys: its normalised
+            # output and log-sum-exp are a state (max, sum 1, accumulator)
+            m_scr[:] = carry[1][:]
+            l_scr[:] = jnp.ones_like(l_scr)
+            acc_scr[:] = carry[0][:].astype(acc_scr.dtype)
+            return
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute(masked: bool):
+    def _compute(masked: bool, part=None):
         # storage-dtype matmul operands: bf16 x bf16 -> f32 runs the MXU at
         # full rate. q arrives pre-scaled by softmax_scale * log2(e), so
         # the softmax is base-2 and needs no per-element rescale.
-        q = q_ref[:]                               # [g, bq, D]
-        k = k_ref[:]                               # [g, bkv, D]
-        v = v_ref[:]                               # [g, bkv, D]
+        # ``part``: (first row, rows, first key, keys) of the block, static:
+        # the update is made over that corner of it alone
+        r0, nr, k0, nk = part or (0, block_q, 0, block_kv)
+        rows = slice(None) if part is None else slice(r0, r0 + nr)
+        keys = slice(None) if part is None else slice(k0, k0 + nk)
+        q = q_ref[:, rows]                         # [g, bq, D]
+        k = k_ref[:, keys]                         # [g, bkv, D]
+        v = v_ref[:, keys]                         # [g, bkv, Dv]
         s = _bdot(q, k, ((2,), (2,)))              # [g, bq, bkv] f32
-        if masked:
+        if masked and seg is not None:
+            # a pair is allowed where both tokens carry one segment id
+            # (padding carries a negative one, a query's unlike a key's);
+            # ONE [bq, bkv] mask for the group, the diagonal in it
+            allowed = seg[0][rows] == seg[1][:, keys]
+            if causal:
+                at = lambda first, axis: (
+                    first + jax.lax.broadcasted_iota(
+                        jnp.int32, allowed.shape, axis))
+                allowed &= (at(q_idx * block_q + r0, 0)
+                            >= at(kv_idx * block_kv + k0, 1))
+            s = jnp.where(allowed[None], s, NEG_INF)
+        elif masked:
             s = _mask_scores(s, q_idx, kv_idx, block_q, block_kv)
 
-        m_prev = m_scr[:, :, :1]                   # [g, bq, 1]
+        m_prev = m_scr[:, rows, :1]                # [g, bq, 1]
         m_cur = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
+        # a row that has met no allowed key yet stands at NEG_INF: its
+        # masked scores must come out as probability 0, not exp2(0)
+        m_ref = m_new if seg is None else jnp.where(
+            m_new == NEG_INF, 0.0, m_new)
         # bf16 inputs: run the exp2 at half precision (2x VPU throughput);
         # the probabilities feed a bf16 matmul + an f32 row sum either way
         if q.dtype == jnp.bfloat16:
-            p = jnp.exp2((s - m_new).astype(jnp.bfloat16))
+            p = jnp.exp2((s - m_ref).astype(jnp.bfloat16))
         else:
-            p = jnp.exp2(s - m_new)
+            p = jnp.exp2(s - m_ref)
         alpha = jnp.exp2(m_prev - m_new)           # [g, bq, 1]
-        l_new = alpha * l_scr[:, :, :1] + jnp.sum(
+        l_new = alpha * l_scr[:, rows, :1] + jnp.sum(
             p, axis=2, keepdims=True, dtype=jnp.float32
         )
-        acc_scr[:] = acc_scr[:] * alpha + _bdot(
+        acc_scr[:, rows] = acc_scr[:, rows] * alpha + _bdot(
             p.astype(v.dtype), v, ((2,), (1,))
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[:, rows] = jnp.broadcast_to(m_new, (*m_new.shape[:2], 128))
+        l_scr[:, rows] = jnp.broadcast_to(l_new, (*l_new.shape[:2], 128))
 
-    if causal:
+    if seg is not None:
+        # by block, from the blocks' spans of segment ids (lowest, padding
+        # included; highest): nothing where no id can be shared. An
+        # executed block is always masked: the ONE mask a group costs no
+        # time that shows, and a second, unmasked body doubles the
+        # kernel's text, which rests on the device a call a layer a
+        # program (docs/MICROBENCHMARKS.md, PR 51)
+        q_lo, q_hi = seg[2][q_idx, 0], seg[2][q_idx, 1]
+        k_lo, k_hi = seg[3][kv_idx, 0], seg[3][kv_idx, 1]
+        executed = (k_hi >= jnp.maximum(q_lo, 0)) & (
+            q_hi >= jnp.maximum(k_lo, 0))
+        if causal:
+            executed &= _causal_regimes(q_idx, kv_idx, block_q, block_kv)[0]
+
+        @pl.when(executed)
+        def _():
+            if not halve_diagonal:
+                return _compute(masked=True)
+            # the call's ONE block stands on the diagonal: its upper right
+            # quarter holds no allowed pair, so the block is two updates,
+            # every row over the first half of the keys and the later half
+            # of the rows over the second, three quarters of the products
+            # (worth it from blocks of ~1,536 up)
+            half = block_q // 2
+            _compute(True, (0, block_q, 0, half))
+            _compute(True, (half, half, half, half))
+    elif causal:
         executed, fully_below = _causal_regimes(q_idx, kv_idx, block_q, block_kv)
 
         @pl.when(executed & jnp.logical_not(fully_below))
@@ -210,24 +267,61 @@ def _flash_fwd_kernel(
             )
 
 
+def _flash_fwd_segmented(q_span, k_span, q_ref, k_ref, v_ref, q_seg, k_seg,
+                         *rest, carried, **static):
+    """``_flash_fwd_kernel`` as a call with segment ids hands it its
+    references: the blocks' spans (scalar prefetch) lead, the ids follow
+    the values, then an earlier call's output and log-sum-exp."""
+    carry, rest = (rest[:2], rest[2:]) if carried else (None, rest)
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, carry=carry,
+                      seg=(q_seg, k_seg, q_span, k_span), **static)
+
+
+def _seg_spans(seg, block):
+    """[blocks, 2] int32: each block's lowest and highest segment id."""
+    by_block = seg.reshape(-1, block)
+    return jnp.stack([by_block.min(axis=1), by_block.max(axis=1)], axis=1)
+
+
 def _flash_forward(
-    q, k, v, *, causal, scale, block_q, block_kv, interpret, save_lse=False
+    q, k, v, *, causal, scale, block_q, block_kv, interpret, save_lse=False,
+    q_seg=None, k_seg=None, carry=None, halve_diagonal=False,
 ):
+    """q ``[B, H, S, D]`` against k ``[B, H, Sk, D]`` and v ``[B, H, Sk,
+    Dv]`` (a value may be narrower than a key; ``Sk != S`` without
+    ``causal``): ``[B, H, S, Dv]`` in q's dtype, with ``save_lse`` the
+    base-2 log-sum-exp ``[B, H, S]`` float32 too.
+
+    The serving call gives ``q_seg [S]`` / ``k_seg [Sk]`` int32, one for
+    every (batch, head): a query attends the keys that carry ITS id (and,
+    under ``causal``, stand at or before its index); a negative id, a
+    query's unlike a key's, marks padding, which attends and is attended
+    by nothing: such a query's output is 0 and its log-sum-exp
+    ``NEG_INF``. Blocks whose ids cannot meet are skipped. Such a call
+    returns ``(o float32, the log-sum-exp as the kernel keeps it, [B, H,
+    S, 128] lane-broadcast)`` and takes the same pair as ``carry``: an
+    earlier call's result over OTHER keys, which this one goes on from
+    (its buffers are reused), so a context is attended block by block
+    with no pass between the calls. ``halve_diagonal`` (a causal call
+    of ONE block; on the chip of whole 256s, so that a half is whole
+    lanes): the block leaves its upper right quarter out."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, heads, seq_len, head_dim = q.shape
+    kv_len, v_dim = k.shape[2], v.shape[3]
+    assert kv_len == seq_len or not causal, (seq_len, kv_len)
     block_q = _clamp_block(block_q, seq_len)
-    block_kv = _clamp_block(block_kv, seq_len)
+    block_kv = _clamp_block(block_kv, kv_len)
     bh = batch * heads
     g = _pick_group(bh, block_q, block_kv)
     # fold softmax scale AND log2(e) into q once (O(S*D)) — the kernels
     # compute a base-2 softmax with no per-score rescale pass
     qf = (q * jnp.asarray(scale * LOG2E, q.dtype)).reshape(bh, seq_len, head_dim)
-    kf = k.reshape(bh, seq_len, head_dim)
-    vf = v.reshape(bh, seq_len, head_dim)
+    kf = k.reshape(bh, kv_len, head_dim)
+    vf = v.reshape(bh, kv_len, v_dim)
 
-    grid = (bh // g, seq_len // block_q, seq_len // block_kv)
+    grid = (bh // g, seq_len // block_q, kv_len // block_kv)
     kernel = functools.partial(
         _flash_fwd_kernel,
         causal=causal,
@@ -235,42 +329,68 @@ def _flash_forward(
         block_kv=block_kv,
         save_lse=save_lse,
     )
-    out_specs = [
-        pl.BlockSpec((g, block_q, head_dim), lambda b, i, j: (b, i, 0)),
-    ]
-    out_shapes = [jax.ShapeDtypeStruct((bh, seq_len, head_dim), q.dtype)]
+    serving = q_seg is not None
+    row = lambda width: pl.BlockSpec(
+        (g, block_q, width), lambda b, i, j, *_: (b, i, 0))
+    out_specs = [row(v_dim)]
+    out_shapes = [jax.ShapeDtypeStruct(
+        (bh, seq_len, v_dim), jnp.float32 if serving else q.dtype)]
     if save_lse:
         # lane-broadcast [bh, S, 128] rather than [bh, S]: a 2D output
         # violates Mosaic's (8,128) output-tile constraint; 128 copies of
         # a f32 scalar per row is ~64 bytes/token of extra HBM — noise
-        out_specs.append(
-            pl.BlockSpec((g, block_q, 128), lambda b, i, j: (b, i, 0))
-        )
+        out_specs.append(row(128))
         out_shapes.append(jax.ShapeDtypeStruct((bh, seq_len, 128), jnp.float32))
+    in_specs = [
+        row(head_dim),
+        pl.BlockSpec((g, block_kv, head_dim), lambda b, i, j, *_: (b, j, 0)),
+        pl.BlockSpec((g, block_kv, v_dim), lambda b, i, j, *_: (b, j, 0)),
+    ]
+    operands, spans, aliases = [qf, kf, vf], [], {}
+    if serving:
+        assert save_lse
+        assert not halve_diagonal or (
+            causal and grid[1:] == (1, 1) and block_q % 2 == 0)
+        kernel = functools.partial(
+            _flash_fwd_segmented, carried=carry is not None,
+            halve_diagonal=halve_diagonal, **kernel.keywords)
+        in_specs += [
+            pl.BlockSpec((block_q, 1), lambda b, i, j, *_: (i, 0)),
+            pl.BlockSpec((1, block_kv), lambda b, i, j, *_: (0, j)),
+        ]
+        operands += [q_seg.reshape(seq_len, 1), k_seg.reshape(1, kv_len)]
+        spans = [_seg_spans(q_seg, block_q), _seg_spans(k_seg, block_kv)]
+        if carry is not None:
+            in_specs += [row(v_dim), row(128)]
+            operands += [carry[0].reshape(bh, seq_len, v_dim),
+                         carry[1].reshape(bh, seq_len, 128)]
+            aliases = {len(spans) + 5: 0, len(spans) + 6: 1}
     result = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((g, block_q, head_dim), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((g, block_kv, head_dim), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((g, block_kv, head_dim), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(spans),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((g, block_q, 128), jnp.float32),
+                pltpu.VMEM((g, block_q, 128), jnp.float32),
+                pltpu.VMEM((g, block_q, v_dim), jnp.float32),
+            ],
+        ),
         out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((g, block_q, 128), jnp.float32),
-            pltpu.VMEM((g, block_q, 128), jnp.float32),
-            pltpu.VMEM((g, block_q, head_dim), jnp.float32),
-        ],
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         name="flash_fwd",
         interpret=interpret,
-    )(qf, kf, vf)
+    )(*spans, *operands)
     out, lse = (result[0], result[1]) if save_lse else (result[0], None)
-    out = out.reshape(batch, heads, seq_len, head_dim)
+    out = out.reshape(batch, heads, seq_len, v_dim)
+    if serving:
+        return out, lse.reshape(batch, heads, seq_len, 128)
     if save_lse:
         return out, lse.reshape(batch, heads, seq_len, 128)[..., 0]
     return out

@@ -140,7 +140,7 @@ def _pangu_ultra_moe() -> Family:
                   m.PanguUltraMoEConfig.tiny,
                   init_state=m.pangu_ultra_moe_init_state,
                   counters=m.pangu_ultra_moe_counters, state_rows=False,
-                  gmm_form=step_gmm_form)
+                  step_attrs=m.step_attrs, gmm_form=step_gmm_form)
 
 
 def _smallthinker() -> Family:
@@ -168,7 +168,7 @@ def _longcat_flash() -> Family:
                   m.LongCatFlashConfig.tiny,
                   init_state=m.longcat_flash_init_state,
                   counters=m.longcat_flash_counters, state_rows=False,
-                  gmm_form=step_gmm_form)
+                  step_attrs=m.step_attrs, gmm_form=step_gmm_form)
 
 
 def _minicpm_sala() -> Family:

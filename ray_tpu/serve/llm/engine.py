@@ -800,6 +800,12 @@ class LLMEngine:
         # padding included; the launches that were packed (``_piece``)
         self._prefill_slots = 0
         self._prefill_steps_packed = 0
+        # the prefill launches' dispatch spans added up: the (query, key)
+        # pairs their attention covered, and of a latent family (its
+        # ``step_attrs``) those that went through the EXPANDED form and
+        # the key blocks of resident prefixes up-projected a layer
+        self._prefill_pairs = {
+            "qk_pairs": 0, "expanded_pairs": 0, "prefix_blocks": 0}
         # "prefill" | "decode" | None — drives prefill/decode alternation
         # and gives tests a step-order trace.
         self.last_step_kind: str | None = None
@@ -1601,6 +1607,7 @@ class LLMEngine:
                 # and the launches whose rows were pieces of prompts
                 "prefill_slots": self._prefill_slots,
                 "prefill_steps_packed": self._prefill_steps_packed,
+                **self._prefill_pairs,
                 "prefix_hit_rate": hit / max(1, hit + computed),
                 "host_sync_seconds_total": round(
                     self._sync_seconds_total, 6
@@ -2464,6 +2471,8 @@ class LLMEngine:
             )
         self._prefill_slots += B * S
         self._prefill_steps_packed += bool(P)
+        for name in self._prefill_pairs:
+            self._prefill_pairs[name] += span.get(name, 0)
         self._prefill_steps += 1
         # The host's view moves on AT THE LAUNCH: the chunk is as good as
         # written (whatever touches these blocks next is a later program
