@@ -175,6 +175,25 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
     it in float32 at the highest matmul precision, whatever ``x`` is: a
     near-tie between the k-th and the (k+1)-th score is the one place where
     rounding changes WHICH weights a token meets."""
+    return _route(x, router, bias, top_k, norm_topk, scale, score, None)[:2]
+
+
+def moe_route_grouped(x: jax.Array, router: jax.Array,
+                      bias: jax.Array | None, top_k: int,
+                      groups: tuple[int, int], *, norm_topk: bool = True,
+                      scale: float = 1.0):
+    """``moe_route``'s sigmoid scores under DeepSeek-V3's group-limited
+    selection (models/ling_hybrid.py) -> (weights [T, k] f32, experts
+    [T, k] int32, the groups that stayed [T, n_group] bool).
+    ``groups = (n_group, topk_group)``: the experts are ``n_group`` runs of
+    equal length, a group's score is the sum of its two largest
+    ``s + bias``, the ``topk_group`` best groups stay and the ``top_k`` are
+    taken among theirs; the weights as ``moe_route``'s."""
+    return _route(x, router, bias, top_k, norm_topk, scale, "sigmoid", groups)
+
+
+def _route(x, router, bias, top_k, norm_topk, scale, score, groups):
+    """(weights, experts, the groups that stayed or None)."""
     if score not in ROUTE_SCORES:
         raise ValueError(f"score must be one of {ROUTE_SCORES}, got {score!r}")
     with jax.named_scope("moe_route"):
@@ -189,17 +208,44 @@ def moe_route(x: jax.Array, router: jax.Array, bias: jax.Array | None,
             over = chosen if norm_topk else logits
             weights = jnp.exp(chosen - jax.nn.logsumexp(
                 over, axis=-1, keepdims=True))
-            return weights * scale, experts.astype(jnp.int32)
+            return weights * scale, experts.astype(jnp.int32), None
         scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
                   else jax.nn.sigmoid(logits))
         chosen_by = scores if bias is None else scores + bias.astype(
             jnp.float32)
+        stays = None
+        if groups is not None:
+            chosen_by, stays = _keep_groups(chosen_by, *groups)
         _, experts = jax.lax.top_k(chosen_by, top_k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk:
             weights = weights / (
                 jnp.sum(weights, axis=-1, keepdims=True) + ROUTE_NORM_EPS)
-        return weights * scale, experts.astype(jnp.int32)
+        return weights * scale, experts.astype(jnp.int32), stays
+
+
+def _keep_groups(chosen_by: jax.Array, n_group: int, topk_group: int):
+    """(``chosen_by`` [T, E] with the experts of every group but a
+    token's ``topk_group`` best at -inf, those groups [T, n_group] bool);
+    a group's score: the sum of its two largest entries."""
+    T, E = chosen_by.shape
+    if score_groups_bad(E, n_group, topk_group):
+        raise ValueError(
+            f"groups ({n_group}, {topk_group}) do not cut {E} experts into "
+            "equal groups of two or more, of which some stay")
+    by_group = chosen_by.reshape(T, n_group, E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)          # [T, kept]
+    stays = jnp.any(
+        kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    return jnp.where(
+        stays[:, :, None], by_group, -jnp.inf).reshape(T, E), stays
+
+
+def score_groups_bad(experts: int, n_group: int, topk_group: int) -> bool:
+    """Whether ``(n_group, topk_group)`` is no grouping of ``experts``."""
+    return (n_group < 1 or experts % n_group or experts // n_group < 2
+            or not 0 < topk_group <= n_group)
 
 
 # ----------------------------------------------------------------------------

@@ -1134,12 +1134,21 @@ def paged_latent_attention_pallas(
     if interpret is None:
         interpret = pallas_interpret()
     latent_pool, rope_pool, layer = _as_pools(latent_pool, rope_pool, layer)
-    S = q.shape[1]
-    return _latent_call(
-        q, latent_pool, rope_pool, block_tables, positions,
-        layer.reshape(1), latent_dim=latent_dim, scale=float(scale),
+    B, S = q.shape[:2]
+    call = functools.partial(
+        _latent_call, layer=layer.reshape(1), latent_dim=latent_dim,
+        scale=float(scale),
         q_block=q_block if q_block is not None else min(S, _LATENT_Q_BLOCK),
         interpret=bool(interpret))
+    # the rows' tables ride in scalar memory: a batch whose tables pass
+    # what fits there goes in groups of rows, a call a group
+    rows = _latent_rows_a_call(B, block_tables.shape[1])
+    if rows == B:
+        return call(q, latent_pool, rope_pool, block_tables, positions)
+    return jnp.concatenate([
+        call(q[i:i + rows], latent_pool, rope_pool,
+             block_tables[i:i + rows], positions[i:i + rows])
+        for i in range(0, B, rows)])
 
 
 def latent_attention(
@@ -1179,3 +1188,22 @@ def latent_attention(
 # here so that no line of the kernel's call chain moves: a compiled step
 # program is found in the persistent cache by its callers' line numbers.
 Q_TILE = 128
+
+
+# Bytes of block tables one call of the latent kernel prefetches into
+# scalar memory (1 MB on a v5e, of which the compiler keeps some): 128
+# rows of 768 entries (cells 8 and 10) are 393 KB; 128 rows of 2,560
+# (models/ling_hybrid.py's cell: contexts to 40,960 tokens in pages of 16)
+# would be 1.31 MB and are refused by the compiler, so they go as two
+# calls of 64 rows.
+_LATENT_TABLE_SMEM = 768 * 1024
+
+
+def _latent_rows_a_call(B: int, NB: int) -> int:
+    """Rows of a batch one call of the latent kernel takes: all of them
+    where their tables fit scalar memory, else the largest divisor of
+    ``B`` whose tables do."""
+    rows = B
+    while rows > 1 and rows * NB * 4 > _LATENT_TABLE_SMEM:
+        rows = next(r for r in range(rows - 1, 0, -1) if B % r == 0)
+    return rows

@@ -14,6 +14,11 @@ take that state as ``[B, K-1, D]`` (oldest row first) and return the next.
 
 Plain ``jax.numpy``: K is 3, so the filter is three shifted multiplies
 that XLA fuses with the gate; float32 inside, the caller's dtype out.
+
+The PLAIN form (``gate=None``; models/ling_hybrid.py's KDA layers: width
+4 over ``[q | k | v]``, then SiLU): the same filter and the same state,
+with ``act`` on ``z`` where the gated form multiplies by ``C``, under the
+caller's ``scope``.
 """
 from __future__ import annotations
 
@@ -21,16 +26,31 @@ import jax
 import jax.numpy as jnp
 
 
-def short_conv_prefill(v: jax.Array, gate: jax.Array, w: jax.Array,
-                       state: jax.Array | None, lengths: jax.Array):
+def _named(scope: str | None):
+    """The form's named scope: ``short_conv``, or the caller's (a name of
+    serve/llm/obs.py ``SCOPES``)."""
+    return jax.named_scope("short_conv") if scope is None \
+        else jax.named_scope(scope)
+
+
+def _finish(z, gate, act):
+    """``z`` float32 through the gate or the activation of the form."""
+    if gate is not None:
+        z = gate.astype(jnp.float32) * z
+    return z if act is None else act(z)
+
+
+def short_conv_prefill(v: jax.Array, gate: jax.Array | None, w: jax.Array,
+                       state: jax.Array | None, lengths: jax.Array, *,
+                       act=None, scope: str | None = None):
     """A chunk of positions: ``v``, ``gate`` [B, S, D], filter ``w`` [K, D],
     ``state`` [B, K-1, D] holding the rows before the chunk (None: the
     chunk starts the sequence, zeros), ``lengths`` [B] the valid columns.
     Returns (``gate * z`` [B, S, D], the state after column
-    ``lengths - 1`` [B, K-1, D])."""
+    ``lengths - 1`` [B, K-1, D]). ``gate=None``: the plain form, ``act(z)``."""
     B, S, D = v.shape
     K = w.shape[0]
-    with jax.named_scope("short_conv"):
+    with _named(scope):
         if state is None:
             state = jnp.zeros((B, K - 1, D), v.dtype)
         ext = jnp.concatenate([state.astype(v.dtype), v], axis=1)
@@ -41,17 +61,18 @@ def short_conv_prefill(v: jax.Array, gate: jax.Array, w: jax.Array,
         # is column lengths + i of ``ext``
         cols = lengths[:, None] + jnp.arange(K - 1, dtype=lengths.dtype)
         nxt = jnp.take_along_axis(ext, cols[:, :, None], axis=1)
-        return (gate.astype(jnp.float32) * z).astype(v.dtype), nxt
+        return _finish(z, gate, act).astype(v.dtype), nxt
 
 
-def short_conv_decode(v: jax.Array, gate: jax.Array, w: jax.Array,
-                      state: jax.Array):
+def short_conv_decode(v: jax.Array, gate: jax.Array | None, w: jax.Array,
+                      state: jax.Array, *, act=None,
+                      scope: str | None = None):
     """One position: ``v``, ``gate`` [B, D], ``state`` [B, K-1, D].
     Returns (``gate * z`` [B, D], the next state)."""
-    with jax.named_scope("short_conv"):
+    with _named(scope):
         ext = jnp.concatenate([state.astype(v.dtype), v[:, None]], axis=1)
         w32 = w.astype(jnp.float32)
         # the same three products in the same order as the chunked form
         z = sum(ext[:, j].astype(jnp.float32) * w32[j]
                 for j in range(w.shape[0]))
-        return (gate.astype(jnp.float32) * z).astype(v.dtype), ext[:, 1:]
+        return _finish(z, gate, act).astype(v.dtype), ext[:, 1:]

@@ -187,6 +187,22 @@ def _minicpm_sala() -> Family:
                   donated_state_counters=m.COUNTER_LEAVES)
 
 
+def _ling_hybrid() -> Family:
+    from ray_tpu.models import ling_hybrid as m
+    from ray_tpu.ops.moe import step_gmm_form
+
+    # no verify step: rejected drafts would need the KDA state (a matrix
+    # a head a sequence, and the convolution's rows) rolled back
+    return Family(m.ling_hybrid_init, m.ling_hybrid_prefill,
+                  m.ling_hybrid_decode_step, None,
+                  m.ling_hybrid_param_axes, m.ling_hybrid_quant_axes,
+                  m.LingHybridConfig.tiny,
+                  init_state=m.ling_hybrid_init_state,
+                  counters=m.ling_hybrid_counters,
+                  step_attrs=m.step_attrs, gmm_form=step_gmm_form,
+                  donated_state_counters=m.COUNTER_LEAVES)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
@@ -194,6 +210,7 @@ FAMILIES: dict[str, Callable[[], Family]] = {
     "laguna": _laguna, "evabyte": _evabyte,
     "pangu_ultra_moe": _pangu_ultra_moe, "smallthinker": _smallthinker,
     "longcat_flash": _longcat_flash, "minicpm_sala": _minicpm_sala,
+    "ling_hybrid": _ling_hybrid,
 }
 
 

@@ -1103,7 +1103,10 @@ class LLMEngine:
         """Raise for each option that cannot yet carry what the family
         keeps: per-sequence state beside the pool (``lfm2_moe``: a short
         convolution's rows; ``minicpm_sala``: a matrix a head of lightning
-        state, and its compressed keys by block id), tables
+        state, and its compressed keys by block id; ``ling_hybrid``: a
+        matrix a head of KDA state and a convolution's rows, BESIDE a pool
+        in planes: it meets the state's list first, which refuses every
+        option the planes' list does and preemption besides), tables
         by group of layers (``laguna``), a ring and a table of chunk
         summaries (``evabyte``) or one latent row a token in planes
         (``pangu_ultra_moe``, ``longcat_flash``), each with its reason."""
@@ -1128,7 +1131,7 @@ class LLMEngine:
                     "blocks",
                 "quantization":
                     "the weights of the layers that keep the state (expert "
-                    "and conv; lightning) have no quantized path, and a "
+                    "and conv; lightning; kda) have no quantized path, and a "
                     "quantized pool has no plane for compressed keys",
                 "tp/fsdp/mesh":
                     "ShardedExecutor has no expert axis and does not place "
@@ -1562,6 +1565,9 @@ class LLMEngine:
                 # keeps none), and whether a prefix hit can be reused
                 "state_slots": self.cache.used_slots,
                 "state_slots_high_water": cs.state_slots_high_water,
+                # bytes ``state`` holds on the device, every slot and
+                # counter (a KDA or lightning family's matrices a slot)
+                "state_bytes": self.executor.state_bytes(),
                 # what the blocks in use hold in a third plane by block id
                 # (a selecting family's compressed keys; 0 for the others)
                 "kv_compressed_key_bytes": (

@@ -770,22 +770,26 @@ class ModelExecutor:
             return {}
         return get_family(self.family).counters(state)
 
+    def state_bytes(self) -> int:
+        """Bytes ``state`` holds on the device, every slot and counter (0
+        for a family that keeps none)."""
+        import jax
+
+        return int(sum(t.size * t.dtype.itemsize
+                       for t in jax.tree.leaves(self.cache.state)))
+
     def _state_report(self) -> dict:
         """What the family holds per sequence: how many layers the paged
         pool spans, the state slots beside it (None: the pool is all),
         whether a prefix hit can be reused, and the groups of layers that
         have a table each, where the family names them."""
-        import jax
-
         cfg = self.cache.cfg
         state = None
         if self.cache.state is not None:
             state = {
                 # slot 0 is the garbage sink; 0: counters, no row a sequence
                 "slots": max(cfg.state_slots - 1, 0),
-                "bytes": int(sum(
-                    t.size * t.dtype.itemsize
-                    for t in jax.tree.leaves(self.cache.state))),
+                "bytes": self.state_bytes(),
                 "arrays": {k: list(v.shape)
                            for k, v in self.cache.state.items()},
             }

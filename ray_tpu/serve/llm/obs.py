@@ -325,7 +325,11 @@ def shape_key(sig: tuple) -> str:
 #   written between ``attn_cache`` and ``attn_kernel``;
 # - ``short_conv``, ``lightning_step``, ``lightning_chunk``: the mixers
 #   that stand where attention would (their in / out products lie under
-#   ``attn_proj``);
+#   ``attn_proj``); a KDA layer's parts (models/ling_hybrid.py):
+#   ``kda_conv`` (the convolution over ``[q | k | v]``, SiLU, the L2
+#   norms), ``kda_gate`` (the decay a channel, ``beta``), ``kda_step`` /
+#   ``kda_chunk`` (the delta rule, ops/kda.py), ``kda_out`` (the norm a
+#   head and the output gate);
 # - ``ffn``: the feed-forward half of a layer: its norm, the dense MLP,
 #   the residual. Inside it ``dense_ffn`` (a double layer's SwiGLUs),
 #   ``moe_route`` (router product, scores, top-k), ``moe_move`` (what
@@ -339,7 +343,8 @@ def shape_key(sig: tuple) -> str:
 SCOPES = (
     "embed", "layer_stack", "attn_proj", "attn_cache", "attn_kernel",
     "sparse_select", "sparse_prefill_attention", "eva_summarize",
-    "short_conv", "lightning_step", "lightning_chunk", "ffn", "dense_ffn",
+    "short_conv", "lightning_step", "lightning_chunk", "kda_conv",
+    "kda_gate", "kda_step", "kda_chunk", "kda_out", "ffn", "dense_ffn",
     "moe_route", "moe_move", "moe_gmm", "moe_zero", "moe_shared", "head",
     "sample", "counters",
 )

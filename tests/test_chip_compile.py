@@ -1609,3 +1609,99 @@ def test_minicpm_sala_step_programs_compile_at_published_widths(
     entry = text[text.index("ENTRY"):]
     assert re.search(r"%state__ckeys__", entry)
     assert re.search(r"%params__layers___1___lightning_wq__", entry)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_ling_hybrid_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The Ling-3.0-flash cell's step programs as the executor compiles
+    them, at the cell's own shapes: the 128-row decode step and the
+    2,048-token chunk against the 2,560-entry table of the 40,960-token
+    bucket, the fresh prefill over 128 entries. The pool is ONE latent
+    layer in two planes (``[1, 73729, 16, 512]`` and ``[.., 128]``: 1.51
+    GB), both in the program's ``input_output_alias``; ``state`` (129
+    slots of six KDA layers' matrices and convolution rows: 1.68 GB) is
+    donated too: the kernel ``kda_step`` updates a row's state where it
+    stands. The decode step calls ``kda_step`` in the six KDA layers and
+    the latent kernel twice (64 rows a call: 128 tables of 2,560 entries
+    pass the chip's scalar memory); a prefill program holds no ``kda_step`` (the
+    chunked form is XLA's, under the scope ``kda_chunk``) and attends in
+    the expanded form through ``flash_fwd``. All of it inside the chip's
+    16 GB with the weights' 5.73 GB."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/ling-3.0-flash-ep8-7l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/reason-long-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("ling_hybrid")
+    init = common.load_named("reference", "ling_hybrid").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    slots = engine["max_batch_size"] + 1
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, slots)))
+    assert state["kda"].shape == (6, 129, 32, 128, 128)
+    assert state["conv"].shape == (6, 129, 3, 12288)
+    planes = [_struct((cfg.n_kv_layer, engine["num_blocks"],
+                       engine["block_size"], stored), cfg.dtype, one_chip)
+              for _, _, stored in cfg.kv_planes]
+    assert [p.shape for p in planes] == [(1, 73729, 16, 512),
+                                         (1, 73729, 16, 128)]
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    ctx = engine["length_buckets"][-1]
+    B = engine["max_batch_size"] if kind == "decode" else 1
+    assert (ctx, engine["prefill_chunk_tokens"], B) in (
+        (40960, 2048, 128), (40960, 2048, 1))
+    fns = decode.DecodeFns("ling_hybrid", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        lowered = fns._decode.lower(
+            params, *planes, i32((B,)), i32((B,)), i32((B, ctx // 16)),
+            sample=None, **more)
+    else:
+        nb = ctx // 16 if kind == "prefill_chunk" else 2048 // 16
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, *planes, i32((B, 2048)), i32((B,)), i32((B, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    assert abs(pool_bytes - 1.510e9) < 0.001e9
+    state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert abs(state_bytes - 1.680e9) < 0.001e9
+    assert abs(mem.argument_size_in_bytes
+               - (5.733e9 + pool_bytes + state_bytes)) < 0.02e9
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes - 1e6
+    print(kind, "temp", mem.temp_size_in_bytes, "code",
+          mem.generated_code_size_in_bytes)
+    assert mem.temp_size_in_bytes < (0.3e9 if kind == "decode" else 1.6e9), \
+        mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 16e9
+    text = compiled.as_text()
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+    calls = (count("kda_step"), count("paged_attention_latent"),
+             count("flash_fwd") > 0)
+    assert calls == ((6, 2, False) if kind == "decode"
+                     else (0, 0, True)), calls
+    assert "cross_program_prefetch_index" not in text
+    entry = text[text.index("ENTRY"):]
+    assert re.search(r"%state__kda__", entry)
+    assert re.search(r"%params__layers___1___kda_w_qkv__", entry)

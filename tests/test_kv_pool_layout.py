@@ -173,6 +173,10 @@ FAMILIES = {
     "minicpm_sala": dict(block_size=8, num_blocks=129,
                          prefill_chunk_tokens=16,
                          length_buckets=(16, 32, 64, 128)),
+    # planes AND state rows: one latent layer of four writes the pool
+    "ling_hybrid": dict(block_size=8, num_blocks=129,
+                        prefill_chunk_tokens=16,
+                        length_buckets=(16, 32, 64, 128)),
 }
 
 

@@ -676,8 +676,9 @@ def test_unknown_model_name_raises(jax_cpu):
     with pytest.raises(ValueError, match="unknown model family 'mamba'"):
         LLMEngine(EngineConfig(model="mamba"), auto_step=False)
     assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
-                                "llama", "longcat_flash", "minicpm_sala",
-                                "pangu_ultra_moe", "smallthinker"]
+                                "ling_hybrid", "llama", "longcat_flash",
+                                "minicpm_sala", "pangu_ultra_moe",
+                                "smallthinker"]
     for name in ("gpt", "llama"):
         assert get_family(name).init_state is None
         assert get_family(name).verify_step is not None
@@ -686,9 +687,10 @@ def test_unknown_model_name_raises(jax_cpu):
     assert get_family("evabyte").verify_step is None
     assert get_family("pangu_ultra_moe").verify_step is None
     assert get_family("minicpm_sala").verify_step is None
-    # the one family whose steps donate ``state`` (a matrix a head a slot)
-    assert [n for n in FAMILIES
-            if get_family(n).donated_state_counters] == ["minicpm_sala"]
+    assert get_family("ling_hybrid").verify_step is None
+    # the families whose steps donate ``state`` (a matrix a head a slot)
+    assert [n for n in FAMILIES if get_family(n).donated_state_counters] \
+        == ["minicpm_sala", "ling_hybrid"]
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])

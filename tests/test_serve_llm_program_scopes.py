@@ -19,10 +19,11 @@ SETTINGS = {
     "evabyte": dict(block_size=4, num_blocks=257, max_batch_size=4,
                     prefill_chunk_tokens=16, length_buckets=(16, 160)),
     "minicpm_sala": dict(block_size=8, num_blocks=129, max_batch_size=4),
+    "ling_hybrid": dict(block_size=8, num_blocks=129, max_batch_size=4),
 }
 FAMILIES = ("gpt", "llama", "lfm2_moe", "laguna", "evabyte",
             "pangu_ultra_moe", "smallthinker", "longcat_flash",
-            "minicpm_sala")
+            "minicpm_sala", "ling_hybrid")
 HEAVY = re.compile(r" (dot|convolution|ragged-dot|custom-call)\(")
 
 
