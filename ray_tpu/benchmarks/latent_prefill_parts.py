@@ -11,9 +11,9 @@ shapes (docs/MICROBENCHMARKS.md, PERF.md PR 51):
 - ``expand``: the up-projection of one block of rows ``[T, 512 | 64]`` to
   keys and values by head;
 - ``join``: what stands around the ABSORBED kernel's call in a chunk
-  program: ``[q~ | q_rope]`` joined, scaled, padded to the planes' stored
-  widths and laid out as the kernel's rows ``[T * H, 640]``;
-- ``expanded`` / ``absorbed``: the whole call of each form over the planes,
+  program: ``[q~ | q_rope]`` joined, scaled, each part padded to its stored
+  width and laid out as the kernel's rows ``[T * H, 640]``;
+- ``expanded`` / ``absorbed``: the whole call of each form over the pool,
   a first chunk (nothing resident) and a second (one chunk resident), in
   the packed form the engine launches (rows of 128 tokens under one table;
   ``ROWS=``: other rungs of the ladder than the top one).
@@ -30,7 +30,7 @@ import jax, jax.numpy as jnp
 
 from ray_tpu.ops import latent_prefill as lp
 from ray_tpu.ops.attention import _flash_forward
-from ray_tpu.ops.paged_attention import latent_attention
+from ray_tpu.ops.paged_attention import latent_attention, latent_row
 
 rehearse = bool(os.environ.get("REHEARSE"))
 device = jax.devices()[0]
@@ -121,8 +121,7 @@ for cell, (H, T, NB) in CELLS.items():
         say(cell, "join", f"{T}x{H}x640", t)
     if "expanded" in only or "absorbed" in only:
         num_blocks = 2 * T // bs + 1
-        pool_c = rnd(10, 2, num_blocks, bs, C)
-        pool_r = jnp.pad(rnd(11, 2, num_blocks, bs, R), ((0, 0),) * 3 + ((0, 128 - R),))
+        pool = latent_row(rnd(10, 2, num_blocks, bs, C), rnd(11, 2, num_blocks, bs, R))
         table = jnp.zeros((NB,), jnp.int32).at[:2 * T // bs].set(
             jnp.arange(1, 2 * T // bs + 1, dtype=jnp.int32))
         layer = jnp.int32(1)
@@ -137,14 +136,14 @@ for cell, (H, T, NB) in CELLS.items():
                 pairs = n * first + n * (n + 1) // 2
                 if "expanded" in only:
                     fn = jax.jit(lambda q, c, r, start: lp.expanded_prefill_attention(
-                        q, c, r, pool_c, pool_r, tables, valid, start, w_uk, w_uv,
+                        q, c, r, pool, tables, valid, start, w_uk, w_uv,
                         scale=192 ** -0.5, backend="pallas", layer=layer))
                     t, _ = timed(fn, rnd(12, rows, P, H, N + R), rnd(13, rows, P, C),
                                  rnd(14, rows, P, R), start)
                     say(cell, "expanded", f"{name}-{rows}x{P}", t, pairs=pairs)
                 if "absorbed" in only:
                     fn = jax.jit(lambda q, pos: latent_attention(
-                        q, pool_c, pool_r, tables, pos, latent_dim=C,
+                        q, pool, tables, pos, latent_dim=C,
                         scale=192 ** -0.5, backend="pallas", layer=layer))
                     t, _ = timed(fn, rnd(15, rows, P, H, C + R), pos)
                     say(cell, "absorbed", f"{name}-{rows}x{P}", t, pairs=pairs)

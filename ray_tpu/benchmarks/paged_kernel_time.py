@@ -41,6 +41,9 @@ SHAPES = {
     # all Hq heads; Hkv and hd unused): decode at the two cells' head counts
     "cell8-pangu-latent": (128, 128, 1, 576, 3000, 12288, "latent"),
     "cell10-longcat-latent": (96, 64, 1, 576, 1100, 6144, "latent"),
+    # cell 12's one latent layer: 32 heads, contexts to 40,960 (two calls of
+    # 64 rows: ``_latent_rows_a_call``)
+    "cell12-ling-latent": (128, 32, 1, 576, 5000, 40960, "latent"),
     # a prefill chunk over a latent pool: 8 queries x the heads a tile
     "cell8-pangu-latent-chunk": (1, 128, 1, 576, 2047, 12288, "latent-chunk"),
     "cell10-longcat-latent-chunk": (1, 64, 1, 576, 1023, 6144, "latent-chunk"),
@@ -96,6 +99,9 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
         v_pool = jnp.pad(jax.random.normal(
             jax.random.fold_in(key, 1), (2, num_blocks, bs, 64), jnp.bfloat16),
             ((0, 0),) * 3 + ((0, 64),))
+        if hasattr(pa, "latent_row"):  # ONE plane since ISSUE 53: the same
+            # numbers, a row [c | k_rope | zeros]; two planes in a parent's tree
+            k_pool, v_pool = jnp.concatenate([k_pool, v_pool], -1), None
         q = jax.random.normal(jax.random.fold_in(key, 2), (B, 1, Hq, 576), jnp.bfloat16)
         pos = pos[:, None]
         if chunk:  # the whole prompt as one chunk: causal, a frontier a tile
@@ -122,22 +128,24 @@ for name, (B, Hq, Hkv, hd, mean, top, window) in SHAPES.items():
         jax.clear_caches()  # the latent call is behind a jit of its own
         try:
             attend = pa.prefill_attention if prefill else pa.decode_attention
-            fn = jax.jit(lambda q, k, v, t, p: attend(
-                q, k, v, t, p, backend="pallas", window=window, layer=jnp.int32(1))
+            # the pools: K and V, a parent's two planes, or the one plane
+            pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+            fn = jax.jit(lambda q, t, p, *kv: attend(
+                q, *kv, t, p, backend="pallas", window=window, layer=jnp.int32(1))
                 if not latent else pa.latent_attention(
-                    q, k, v, t, p, latent_dim=512, scale=192 ** -0.5,
+                    q, *kv, t, p, latent_dim=512, scale=192 ** -0.5,
                     backend="pallas", layer=jnp.int32(1)))
             if expanded:
                 from ray_tpu.ops.latent_prefill import expanded_prefill_attention
-                fn = jax.jit(lambda q, k, v, t, p: expanded_prefill_attention(
-                    q, *own[:2], k, v, t, p >= 0, None, *own[2:],
+                fn = jax.jit(lambda q, t, p, *kv: expanded_prefill_attention(
+                    q, *own[:2], *kv, t, p >= 0, None, *own[2:],
                     scale=192 ** -0.5, backend="pallas", layer=jnp.int32(1)))
-            o = jax.block_until_ready(fn(q, k_pool, v_pool, tables_d, pos))
+            o = jax.block_until_ready(fn(q, tables_d, pos, *pools))
             times = []
             for _ in range(7):
                 t0 = time.perf_counter()
                 for _ in range(20):
-                    o = fn(q, k_pool, v_pool, tables_d, pos)
+                    o = fn(q, tables_d, pos, *pools)
                 jax.block_until_ready(o)
                 times.append((time.perf_counter() - t0) / 20)
             t = statistics.median(times)

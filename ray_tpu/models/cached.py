@@ -168,13 +168,13 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
     attention over the last ``window`` positions; ``then``: what else the
     layer writes into the pools, after its K/V and before it attends.
 
-    ``latent``: the softmax scale of a LATENT layer over a pool in planes
-    (ops/paged_attention.py ``latent_attention``): ``k`` is then the
-    token's latent row ``[B, S, C]`` and ``v`` the key's rotary rest ``[B,
-    S, R]``, one of each for all heads, written to ``cache_k`` and
-    ``cache_v`` (the latent and the rotary plane); ``q`` is ``[B, S, H, C
-    + R]`` and what comes back ``[B, S, H * C]``; with ``up`` the
-    expanded form of a prefill step (``_attend_latent``).
+    ``latent``: the softmax scale of a LATENT layer over a pool in one
+    plane (ops/paged_attention.py ``latent_attention``): ``k`` is then the
+    token's latent vector ``[B, S, C]`` and ``v`` the key's rotary rest
+    ``[B, S, R]``, one of each for all heads, written to ``cache_k`` as
+    ONE row ``[k | v]`` (``cache_v`` is None); ``q`` is ``[B, S, H, C +
+    R]`` and what comes back ``[B, S, H * C]``; with ``up`` the expanded
+    form of a prefill step (``_attend_latent``).
 
     ``select``: the layer SELECTS the pages it attends
     (ops/sparse_select.py ``Selection``; models/minicpm_sala.py): K and V
@@ -201,8 +201,8 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
             # ``up``: the layer hands q NOT absorbed and its W_uk, W_uv: a
             # prefill step attends in the expanded form (at the file's
             # end: no line of the other branches' call chains moves)
-            attn = _attend_latent(step, cache_k, cache_v, layer, q, k, v,
-                                  tables, at, backend, latent, up)
+            attn = _attend_latent(step, cache_k, layer, q, k, v, tables,
+                                  at, backend, latent, up)
             return attn.reshape(B, S, -1), cache_k, cache_v
     if step.kind == "decode":
         at = step.rows if step.at is None else step.at[:, 0]
@@ -440,24 +440,24 @@ def steps(fam: CachedFamily):
     return prefill, decode_step, verify_step
 
 
-def _attend_latent(step, cache_k, cache_v, layer, q, k, v, tables, at,
-                   backend, scale, up):
-    """A latent layer's attention over the planes its rows were just
+def _attend_latent(step, pool, layer, q, k, v, tables, at, backend, scale,
+                   up):
+    """A latent layer's attention over the pool its rows were just
     written to. Without ``up`` the ABSORBED form, every kind of step
     through the one call (``latent_attention``: q ``[B, S, H, C + R]``,
     ``[B, S, H, C]`` back, which the layer un-absorbs). With ``up = (W_uk
     [C, H, N], W_uv [C, H, V])`` the EXPANDED form of a prefill step
     (ops/latent_prefill.py: q ``[B, S, H, N + R]`` as projected, the heads'
     outputs ``[B, S, H * V]`` back): the step's own keys in hand, the
-    resident prefix block by block from the planes."""
+    resident prefix block by block from the pool."""
     if up is None:
         return latent_attention(
-            q, cache_k, cache_v, tables,
+            q, pool, tables,
             at if step.valid is None else jnp.where(step.valid, at, 0),
             latent_dim=k.shape[-1], scale=scale, backend=backend,
             layer=layer)
     from ray_tpu.ops.latent_prefill import expanded_prefill_attention
 
     return expanded_prefill_attention(
-        q, k, v, cache_k, cache_v, tables, step.valid, step.start, *up,
+        q, k, v, pool, tables, step.valid, step.start, *up,
         scale=scale, backend=backend, layer=layer)

@@ -209,7 +209,8 @@ class LingHybridConfig:
     @property
     def kv_planes(self) -> tuple[tuple[str, int, int], ...]:
         """What a latent layer caches of a token: ``(name, width, stored
-        width)`` a plane (models/pangu_ultra_moe.py ``kv_planes``)."""
+        width)`` a part of its row (models/pangu_ultra_moe.py
+        ``kv_planes``)."""
         return (
             ("latent", self.kv_lora_rank, plane_width(self.kv_lora_rank)),
             ("rope", self.qk_rope_head_dim,

@@ -406,7 +406,7 @@ def longcat_flash_forward(params: dict, tokens: jax.Array,
 
 # ----------------------------------------------------------------------------
 # Cached inference paths (serve/llm engine): what models/cached.py's one
-# step needs of this family. The pools are the latent and the rotary plane
+# step needs of this family. The pool is one plane of latent rows
 # (``kv_planes``) over ``2 * n_layer`` sub-layers, one table for all. Rows
 # in slot 0 are padding: routed nowhere, counted nowhere.
 # ----------------------------------------------------------------------------

@@ -42,12 +42,13 @@ Same conventions as models/laguna.py (a LIST of per-layer trees, float32
 masters, activations in ``cfg.dtype``, ``experts_held``, the counters in
 ``state``: laguna's very functions) with what this family forces:
 
-- THE POOL IS IN PLANES (``kv_planes``): ``cache_k`` holds the latent
-  plane ``[n_layer, num_blocks, block_size, C]`` and ``cache_v`` the
-  rotary plane ``[.., R]``, each stored at whole lanes
-  (ops/paged_attention.py ``plane_width``: the rotary 64 as 128, its rest
-  zeros). The cache manager, the executor's report and the refusals read
-  ``kv_planes``; nothing here is ``(n_kv_head, head_dim)``.
+- THE POOL IS ONE PLANE (``kv_planes``): ``cache_k`` holds a token's row
+  ``[c | k_rope]`` for all heads, ``[n_layer, num_blocks, block_size,
+  640]``, each part stored at whole lanes (ops/paged_attention.py
+  ``latent_row``: the latent 512, the rotary 64 as 128, its rest zeros),
+  so a page is one contiguous copy; ``cache_v`` is None. The cache
+  manager, the executor's report and the refusals read ``kv_planes``;
+  nothing here is ``(n_kv_head, head_dim)``.
 - ``vocab_size`` is what THIS device holds of the vocabulary: embedding,
   head, logits and sampling are over it.
 """
@@ -153,9 +154,9 @@ class PanguUltraMoEConfig:
     @property
     def kv_planes(self) -> tuple[tuple[str, int, int], ...]:
         """THE description of what this family caches of a token in a
-        layer: ``(name, width, stored width)`` a plane, in the order of
-        the step's two pools. ONE row for all heads: the latent vector
-        (key and value) and the key's rotary rest."""
+        layer: ``(name, width, stored width)`` a PART of its row, in the
+        row's order. ONE row for all heads in the one pool: the latent
+        vector (key and value) and the key's rotary rest beside it."""
         return (
             ("latent", self.kv_lora_rank, plane_width(self.kv_lora_rank)),
             ("rope", self.qk_rope_head_dim,
@@ -416,7 +417,7 @@ def pangu_ultra_moe_forward(params: dict, tokens: jax.Array,
 
 # ----------------------------------------------------------------------------
 # Cached inference paths (serve/llm engine): what models/cached.py's one
-# step needs of this family. The pools are the latent and the rotary plane
+# step needs of this family. The pool is one plane of latent rows
 # (``kv_planes``), one table for all layers. Rows in slot 0 are padding:
 # routed nowhere, counted nowhere (``state`` holds only the counters).
 # ----------------------------------------------------------------------------

@@ -106,15 +106,18 @@ def write_kv(
         slot = jnp.where(valid, slot, 0)
     at = (blk, slot) if layer is None else (layer, blk, slot)
 
-    def rows(x, pool=k_layer):
-        # in the pool's own row; the two pools of a cache in PLANES (a
-        # latent row and its rotary part) have rows of their own widths,
-        # and a plane stored wider than its row (whole lanes) gets zeros
-        row = pool.shape[lead + 1:]
-        if len(row) == 1 and x.shape[-1] < row[0] and x.ndim == blk.ndim + 1:
-            x = jnp.pad(x, ((0, 0),) * blk.ndim + ((0, row[0] - x.shape[-1]),))
-        return x.reshape(*blk.shape, *row)
+    def rows(x):
+        # in the pool's own row
+        return x.reshape(*blk.shape, *k_layer.shape[lead + 1:])
 
+    if v_layer is None:
+        # a pool in ONE plane (latent attention): ``k`` is the token's
+        # latent vector and ``v`` its key's rotary rest, one of each for
+        # all heads, and its row ``[k | v]``, each part at whole lanes
+        from ray_tpu.ops.paged_attention import latent_row
+
+        return k_layer.at[at].set(
+            rows(latent_row(k, v).astype(k_layer.dtype))), None
     if isinstance(k_layer, QuantizedKV):
         kind = "int8" if k_layer.data.dtype == jnp.int8 else "fp8"
         kq, ks = quantize_kv(k, kind)
@@ -125,7 +128,7 @@ def write_kv(
             v_layer.data.at[at].set(rows(vq)), v_layer.scale.at[at].set(vs))
         return k_layer, v_layer
     k_layer = k_layer.at[at].set(rows(k.astype(k_layer.dtype)))
-    v_layer = v_layer.at[at].set(rows(v.astype(v_layer.dtype), v_layer))
+    v_layer = v_layer.at[at].set(rows(v.astype(v_layer.dtype)))
     return k_layer, v_layer
 
 
@@ -148,7 +151,7 @@ def gather_kv(
     Bs = k_layer.shape[1]
 
     def context(layer):
-        # None: each layer's own last axis (two planes differ in it)
+        # None: the layer's own last axis (one stored by heads)
         hd = layer.shape[-1] if head_dim is None else head_dim
         if not isinstance(layer, QuantizedKV):
             return layer[block_tables].reshape(B, NB * Bs, -1, hd)
@@ -402,31 +405,32 @@ def paged_attention(
 
 def paged_latent_attention(
     q: jax.Array,
-    latent_layer: jax.Array,
-    rope_layer: jax.Array,
+    layer: jax.Array,
     block_tables: jax.Array,
     positions: jax.Array,
     *,
     latent_dim: int,
     scale: float,
 ) -> jax.Array:
-    """Latent (absorbed multi-head latent) attention over a cache in
-    PLANES, the XLA formulation: every query head attends ONE row a token,
+    """Latent (absorbed multi-head latent) attention over a cache in ONE
+    plane, the XLA formulation: every query head attends ONE row a token,
     whose key is ``[latent | rotary]`` and whose value is the latent part.
 
     q ``[B, S, H, C + R]`` (``[q~ | q_rope]``, the up-projection of the
-    keys absorbed into the query; ``C = latent_dim``), ``latent_layer``
-    ``[num_blocks, block_size, >= C]``, ``rope_layer`` ``[num_blocks,
-    block_size, >= R]`` (a plane is stored at whole lanes: what lies past
-    its width is not read), ``positions`` ``[B, S]`` the queries' true
-    positions, their own rows already written. Returns ``[B, S, H, C]`` in
-    q's dtype: the probabilities' sum of latent rows, which the layer
-    un-absorbs. The context is gathered once (``gather_kv``) and feeds
-    both products."""
+    keys absorbed into the query; ``C = latent_dim``), ``layer``
+    ``[num_blocks, block_size, latent_row_width(C, R)]`` (each part of a
+    row is stored at whole lanes: what lies past its width is not read),
+    ``positions`` ``[B, S]`` the queries' true positions, their own rows
+    already written. Returns ``[B, S, H, C]`` in q's dtype: the
+    probabilities' sum of latent rows, which the layer un-absorbs. The
+    context is gathered once and feeds both products."""
+    from ray_tpu.ops.paged_attention import latent_parts
+
     C = latent_dim
     R = q.shape[-1] - C
-    latent, rope = gather_kv(latent_layer, rope_layer, block_tables)
-    latent, rope = latent[:, :, 0, :C], rope[:, :, 0, :R]  # [B, T, .]
+    B, NB = block_tables.shape
+    latent, rope = latent_parts(
+        layer[block_tables].reshape(B, NB * layer.shape[1], -1), C, R)
     logits = (
         jnp.einsum("bshc,btc->bsht", q[..., :C], latent,
                    preferred_element_type=jnp.float32)

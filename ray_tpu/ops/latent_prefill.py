@@ -11,7 +11,7 @@ head cost ``2 H (N + R + V)`` a pair, 3.4 x fewer at the published widths
 (512 / 64 / 128 / 128), once a key's up-projection is shared by a few
 hundred queries.
 
-What a step does a layer, AFTER its rows were written to the planes:
+What a step does a layer, AFTER its rows were written to the pool:
 
 1. **its own keys, in hand**: the step's ``T = B x S`` token slots
    flattened (a sequence's pieces are consecutive rows in position order:
@@ -21,7 +21,7 @@ What a step does a layer, AFTER its rows were written to the planes:
    sequence; padding carries a negative id and attends and is attended by
    nothing real);
 2. **the resident prefix** of each sequence in the step (positions before
-   its first token here): read from the planes through the table in blocks
+   its first token here): read from the pool through the table in blocks
    of ``PREFIX_BLOCK`` keys, each up-projected once and attended by that
    sequence's queries (the others' blocks are skipped by their ids), masked
    by the prefix's length alone, each call going on from the one before
@@ -43,7 +43,9 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import (
     LOG2E, NEG_INF, _flash_forward, pallas_interpret,
 )
-from ray_tpu.ops.paged_attention import _as_pools, resolve_backend
+from ray_tpu.ops.paged_attention import (
+    _as_pools, latent_parts, resolve_backend,
+)
 
 # keys of a resident prefix up-projected and attended at a time (about a
 # chunk of cell 8: K and V of one block are 168 MB at 128 heads)
@@ -138,9 +140,8 @@ def _expand(c, k_r, w_uk, w_uv):
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "block", "tiles", "pallas", "interpret"))
-def _expanded_call(q, c, k_r, valid, start, latent_pool, rope_pool,
-                   block_tables, layer, w_uk, w_uv, *, scale, block, tiles,
-                   pallas, interpret):
+def _expanded_call(q, c, k_r, valid, start, pool, block_tables, layer, w_uk,
+                   w_uv, *, scale, block, tiles, pallas, interpret):
     """``expanded_prefill_attention`` behind a jit of its own: an unrolled
     stack calls it once a latent layer with the same shapes, and the inner
     jit's cache makes it traced once a process (``_latent_call``)."""
@@ -168,7 +169,7 @@ def _expanded_call(q, c, k_r, valid, start, latent_pool, rope_pool,
 
     # the resident prefixes, block by block: trips[b] blocks for the
     # sequence that row b opens, none for its later rows
-    bs = latent_pool.shape[2]
+    bs = pool.shape[2]
     pages = block // bs
     trips = jnp.where(fresh, prefix_blocks(start, block), 0)
     ends = jnp.cumsum(trips)
@@ -182,8 +183,8 @@ def _expanded_call(q, c, k_r, valid, start, latent_pool, rope_pool,
         j = i - (ends[b] - trips[b])
         with jax.named_scope("attn_cache"):
             page = jax.lax.dynamic_slice(tables[b], (j * pages,), (pages,))
-            c_j = latent_pool[layer[0], page].reshape(block, -1)[:, :C]
-            r_j = rope_pool[layer[0], page].reshape(block, -1)[:, :R]
+            c_j, r_j = latent_parts(
+                pool[layer[0], page].reshape(block, -1), C, R)
         at = j * block + jnp.arange(block, dtype=jnp.int32)
         return attend(
             qh, *_expand(c_j, r_j, w_uk, w_uv), q_seg,
@@ -201,8 +202,7 @@ def expanded_prefill_attention(
     q: jax.Array,
     c: jax.Array,
     k_r: jax.Array,
-    latent_pool: jax.Array,
-    rope_pool: jax.Array,
+    pool: jax.Array,
     block_tables: jax.Array,
     valid: jax.Array,
     start: jax.Array | None,
@@ -216,21 +216,22 @@ def expanded_prefill_attention(
     """The heads' outputs ``[B, S, H * V]`` of a prefill step's latent
     layer. ``q [B, S, H, N + R]`` (``[q_nope | q_rope]``, NOT absorbed),
     the step's own rows ``c [B, S, C]`` and ``k_r [B, S, R]`` (already
-    written to the planes), ``valid [B, S]`` its real tokens, ``start
+    written to the pool), ``valid [B, S]`` its real tokens, ``start
     [B]`` each row's true first position (None: 0, nothing resident),
-    ``block_tables [B, NB]``, the planes ``[n_layer, num_blocks,
-    block_size, plane_width(.)]`` with ``layer`` (one layer's without),
+    ``block_tables [B, NB]``, the pool ``[n_layer, num_blocks,
+    block_size, latent_row_width(C, R)]`` with ``layer`` (one layer's
+    without),
     ``w_uk [C, H, N]`` and ``w_uv [C, H, V]`` in q's dtype."""
     pallas = resolve_backend(backend) == "pallas"
-    latent_pool, rope_pool, layer = _as_pools(latent_pool, rope_pool, layer)
+    pool, _, layer = _as_pools(pool, None, layer)
     B, NB = block_tables.shape
-    bs = latent_pool.shape[2]
+    bs = pool.shape[2]
     # whole pages, and no more than the table holds
     block = min(PREFIX_BLOCK, NB * bs) // bs * bs
     if start is None:
         start = jnp.zeros((B,), jnp.int32)
     return _expanded_call(
-        q, c, k_r, valid, start.astype(jnp.int32), latent_pool, rope_pool,
+        q, c, k_r, valid, start.astype(jnp.int32), pool,
         block_tables.astype(jnp.int32), layer.reshape(1), w_uk, w_uv,
         scale=float(scale), block=block,
         tiles=(_TILE, _ONE_BLOCK, _HALVE, _BLOCK), pallas=pallas,

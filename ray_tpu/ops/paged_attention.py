@@ -796,20 +796,22 @@ def prefill_attention(
 
 
 # ----------------------------------------------------------------------------
-# A pool in PLANES: latent attention (models/pangu_ultra_moe.py,
-# models/longcat_flash.py). A token's row is one latent vector that every
-# head reads, as key (with its rotary rest beside it) and as value; the two
-# pools the step carries hold the latent plane and the rotary plane, each
-# stored at whole lanes. The call has a kernel body of its OWN (PR 46,
-# ``_latent_attention_kernel``): the table walk is the by-head kernel's (a
-# page of each plane is copied once, none past a row's frontier), but a
-# tile is ONE head of 64 to 1,024 rows (the query heads of its queries;
-# decode is S = 1) whose key is its value, and timed alone on the chip the
-# call was bound by neither its products nor its bytes: starting a page's
-# two small copies holds the kernel's one instruction stream ~35 cycles a
-# copy, in turn with the products (docs/MICROBENCHMARKS.md, PR 46). So a
-# block is long, its copies are started in straight-line text and awaited
-# once a plane, and no tile waits for its first block.
+# A pool in ONE PLANE: latent attention (models/pangu_ultra_moe.py,
+# models/longcat_flash.py, models/ling_hybrid.py). A token's row is one
+# latent vector that every head reads, as key (with its rotary rest beside
+# it) and as value; the ONE pool the step carries holds the row ``[c |
+# k_rope]``, each part stored at whole lanes (``latent_row``). The call has
+# a kernel body of its OWN (PR 46, ``_latent_attention_kernel``): the table
+# walk is the by-head kernel's (a page is copied once, none past a row's
+# frontier), but a tile is ONE head of 32 to 1,024 rows (the query heads of
+# its queries; decode is S = 1) whose key is its value, and timed alone on
+# the chip the call was bound by neither its products nor its bytes:
+# STARTING a copy holds the kernel's one instruction stream ~35 cycles, in
+# turn with the products (docs/MICROBENCHMARKS.md, PR 46). So a block is
+# long, its copies are started in straight-line text and awaited once, no
+# tile waits for its first block, and a page is ONE copy: the latent and
+# the rotary part rested in two arrays until PR 53 (two copies a page:
+# 2,125 -> 1,620 us a call at cell 8's shape, docs/MICROBENCHMARKS.md).
 # ----------------------------------------------------------------------------
 
 # queries a tile of a chunk: with H heads each, ``q_block * H`` rows share
@@ -823,10 +825,33 @@ _LATENT_BLOCK_TOKENS = 1024
 
 
 def plane_width(width: int) -> int:
-    """What a plane of ``width`` numbers a token is STORED at: whole lanes
-    of 128, so that a page is whole tiles, rests as written and is copied
-    where it stands (the latent 512 as it is; the rotary 64 as 128)."""
+    """What a part of ``width`` numbers of a token's row is STORED at:
+    whole lanes of 128, so that a page is whole tiles, rests as written,
+    is copied where it stands and is read by lane-aligned views (the
+    latent 512 as it is; the rotary 64 as 128)."""
     return -(-width // 128) * 128
+
+
+def latent_row_width(latent: int, rope: int) -> int:
+    """The stored width of a latent family's row ``[c | k_rope]``: each
+    part at whole lanes (512 + 64 numbers as 640)."""
+    return plane_width(latent) + plane_width(rope)
+
+
+def latent_row(c: jax.Array, k_rope: jax.Array) -> jax.Array:
+    """A token's row as the plane stores it, ``[c | k_rope]`` along the
+    last axis with zeros behind each part up to whole lanes."""
+    def stored(x):
+        return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                       + ((0, plane_width(x.shape[-1]) - x.shape[-1]),))
+
+    return jnp.concatenate([stored(c), stored(k_rope)], axis=-1)
+
+
+def latent_parts(rows: jax.Array, latent: int, rope: int):
+    """``(c, k_rope)`` of stored rows: ``latent_row`` read back."""
+    at = plane_width(latent)
+    return rows[..., :latent], rows[..., at:at + rope]
 
 
 def _latent_tokens(rows: int) -> int:
@@ -838,11 +863,11 @@ def _latent_tokens(rows: int) -> int:
     return _LATENT_BLOCK_TOKENS if rows <= _MANY_ROWS else 2 * _BLOCK_TOKENS
 
 
-def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
-    """``(P, vmem_bytes)`` as ``_compute_block``, for pages of two planes
-    ``[bs, Cp]`` and ``[bs, Rp]`` and a tile of R rows."""
+def _latent_block(bs, W, Cp, R, NB, q_dtype, kv_dtype):
+    """``(P, vmem_bytes)`` as ``_compute_block``, for pages ``[bs, W]`` of
+    the one plane (``Cp`` of them the latent part) and a tile of R rows."""
     fixed = (
-        2 * _vmem_bytes((R, Cp + Rp), q_dtype)      # q, double-buffered
+        2 * _vmem_bytes((R, W), q_dtype)            # q, double-buffered
         + 2 * _vmem_bytes((R, Cp), q_dtype)         # out
         + 2 * _vmem_bytes((R, 1), jnp.int32)
         + 2 * _vmem_bytes((R, 1), jnp.float32)      # running max and sum
@@ -850,11 +875,9 @@ def _latent_block(bs, Cp, Rp, R, NB, q_dtype, kv_dtype):
     )
 
     def need(p):
-        return fixed + 2 * (
-            _vmem_bytes((p * bs, Cp), kv_dtype)
-            + _vmem_bytes((p * bs, Rp), kv_dtype)
-        ) + 2 * _vmem_bytes((R, p * bs), jnp.float32) + _vmem_bytes(
-            (R, Cp), jnp.float32)
+        return (fixed + 2 * _vmem_bytes((p * bs, W), kv_dtype)
+                + 2 * _vmem_bytes((R, p * bs), jnp.float32)
+                + _vmem_bytes((R, Cp), jnp.float32))
 
     return _fit_pages(need, bs, _latent_tokens(R), NB)
 
@@ -863,14 +886,13 @@ def _latent_attention_kernel(
     tables_ref,   # scalar prefetch: [B, NB] int32 block tables
     qmax_ref,     # scalar prefetch: [B, nqb] int32 frontier per q-block
     layer_ref,    # scalar prefetch: [1] int32, the pools' layer
-    q_ref,        # [1, R, Cp + Rp]: this (b, q-block)'s rows ``[q~ |
-                  # q_rope]``, pre-scaled; row r = query (r // H), head (r % H)
+    q_ref,        # [1, R, W]: this (b, q-block)'s rows ``[q~ | q_rope]``,
+                  # pre-scaled; row r = query (r // H), head (r % H)
     pos_ref,      # [1, R, 1] int32: true position of each row's query
-    c_hbm,        # the latent plane, every layer, in HBM: a page [bs, Cp]
-    r_hbm,        # the rotary plane: a page [bs, Rp]
+    kv_hbm,       # the plane, every layer, in HBM: a page [bs, W] of rows
+                  # ``[c (Cp) | k_rope, zeros behind]``
     o_ref,        # [1, R, Cp]
-    c_buf,        # [2, P * bs, Cp]: the two-slot scratch of a block
-    r_buf,        # [2, P * bs, Rp]
+    kv_buf,       # [2, P * bs, W]: the two-slot scratch of a block
     sems,         # one DMA semaphore a slot, shared by the slot's copies
     m_scr, l_scr,  # [R, 1] float32 running max and sum
     acc_scr,      # [R, Cp] float32
@@ -911,26 +933,22 @@ def _latent_attention_kernel(
     final = jnp.logical_and(b == nb - 1, j == nj - 1)
     last2, _ = frontier(b2, j2)
 
-    def page_copies(bb, i, p, slot):
-        # page p of a row's block i: ONE copy a plane
+    def page_copy(bb, i, p, slot):
+        # page p of a row's block i: ONE copy, the page as it rests
         dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
         src = tables_ref[bb, i * pages + p]
-        return [
-            pltpu.make_async_copy(
-                pool.at[layer, src], buf.at[slot, dst], sems.at[slot])
-            for pool, buf in ((c_hbm, c_buf), (r_hbm, r_buf))
-        ]
+        return pltpu.make_async_copy(
+            kv_hbm.at[layer, src], kv_buf.at[slot, dst], sems.at[slot])
 
     def is_whole(ll, i):
         # every page of block i lies at or under the last attended one
         return ll + 1 - i * pages >= pages
 
     def each_page(bb, i, slot, op, n, unroll=False):
-        # ``op`` ("start" | "wait") on the two copies of block i's pages
+        # ``op`` ("start" | "wait") on the copies of block i's pages
         # 0 .. n - 1
         def page(p, carry):
-            for copy in page_copies(bb, i, p, slot):
-                getattr(copy, op)()
+            getattr(page_copy(bb, i, p, slot), op)()
             return carry
 
         lax.fori_loop(0, n, page, 0, unroll=unroll)
@@ -954,25 +972,24 @@ def _latent_attention_kernel(
 
         @pl.when(whole)
         def _():
-            # ONE wait a plane for all P pages: a semaphore counts bytes,
-            # and a slot's own size is its P pages'
-            for buf in (c_buf, r_buf):
-                pltpu.make_async_copy(
-                    buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+            # ONE wait for all P pages: a semaphore counts bytes, and a
+            # slot's own size is its P pages'
+            pltpu.make_async_copy(
+                kv_buf.at[slot], kv_buf.at[slot], sems.at[slot]).wait()
 
         pl.when(jnp.logical_not(whole))(
             lambda: cut(b, ll, i, slot, "wait"))
 
     def compute(i, slot):
-        # ONE [T, C] tile a block for all the rows: the page that was
-        # copied once is key and value, the rotary plane's tile the key's
-        # rest: the scores are q~ . c + q_rope . k_r
-        c = c_buf[slot]
+        # ONE [T, W] tile a block for all the rows, read by whole lanes:
+        # its first C are key and value, the rest the key's rotary part:
+        # the scores are q~ . c + q_rope . k_r
+        c = kv_buf[slot, :, :C]
         nt = (((1,), (1,)), ((), ()))
         s = lax.dot_general(
             q_ref[0, :, :C], c, nt, preferred_element_type=jnp.float32,
         ) + lax.dot_general(
-            q_ref[0, :, C:], r_buf[slot], nt,
+            q_ref[0, :, C:], kv_buf[slot, :, C:], nt,
             preferred_element_type=jnp.float32,
         )                                                  # [R, T]
         t = i * T + lax.broadcasted_iota(jnp.int32, (rows, T), 1)
@@ -999,7 +1016,7 @@ def _latent_attention_kernel(
         # block's pages, or at a call's start nothing yet; the mask zeroes
         # their probabilities, and 0 x the latent tile (the value) must
         # stay 0: zeros once, and after that only pages the tables name.
-        c_buf[...] = jnp.zeros_like(c_buf)
+        kv_buf[...] = jnp.zeros_like(kv_buf)
         base_ref[0] = 0
         cut(b, last, 0, 0, "start")
 
@@ -1030,7 +1047,7 @@ def _latent_attention_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("latent_dim", "scale", "q_block", "interpret"))
-def _latent_call(q, latent_pool, rope_pool, block_tables, positions, layer,
+def _latent_call(q, pool, block_tables, positions, layer,
                  *, latent_dim, scale, q_block, interpret):
     """``paged_latent_attention_pallas`` behind a jit of its own: a step
     program calls it once a latent layer with the same shapes, and the
@@ -1042,24 +1059,20 @@ def _latent_call(q, latent_pool, rope_pool, block_tables, positions, layer,
 
     B, S, H, D = q.shape
     C, R = latent_dim, D - latent_dim
-    bs, Cp = latent_pool.shape[2:]
-    Rp = rope_pool.shape[3]
+    bs, W = pool.shape[2:]
+    Cp = plane_width(C)
     NB = block_tables.shape[1]
     q, pos, qb, nqb = _q_tiles(q, positions, q_block)
     Sp = nqb * qb
     rows = qb * H
     q = q * jnp.asarray(scale * LOG2E, q.dtype)
-    # each part of a row padded to its plane's stored width (nothing at
-    # the latent 512; the rotary 64 to 128, against the plane's zeros)
-    qf = jnp.concatenate([
-        jnp.pad(q[..., :C], ((0, 0),) * 3 + ((0, Cp - C),)),
-        jnp.pad(q[..., C:], ((0, 0),) * 3 + ((0, Rp - R),)),
-    ], axis=-1).reshape(B, Sp * H, Cp + Rp)
+    # each part of a row padded as the plane's row is (nothing at the
+    # latent 512; the rotary 64 to 128, against the row's zeros)
+    qf = latent_row(q[..., :C], q[..., C:]).reshape(B, Sp * H, W)
     pos_rows = jnp.broadcast_to(
         pos[:, :, None], (B, Sp, H)).reshape(B, Sp * H, 1)
     qmax, _ = _frontiers(pos, nqb)
-    pages, vmem = _latent_block(
-        bs, Cp, Rp, rows, NB, q.dtype, latent_pool.dtype)
+    pages, vmem = _latent_block(bs, W, Cp, rows, NB, q.dtype, pool.dtype)
 
     def q_map(b, j, *refs):
         return (b, j, 0)
@@ -1068,16 +1081,14 @@ def _latent_call(q, latent_pool, rope_pool, block_tables, positions, layer,
         num_scalar_prefetch=3,
         grid=(B, nqb),
         in_specs=[
-            pl.BlockSpec((1, rows, Cp + Rp), q_map),
+            pl.BlockSpec((1, rows, W), q_map),
             pl.BlockSpec((1, rows, 1), q_map),
-            # the planes stay in HBM: the kernel copies the pages itself
-            pl.BlockSpec(memory_space=pl.ANY),
+            # the plane stays in HBM: the kernel copies the pages itself
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, rows, Cp), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, Cp), latent_pool.dtype),
-            pltpu.VMEM((2, pages * bs, Rp), rope_pool.dtype),
+            pltpu.VMEM((2, pages * bs, W), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -1097,15 +1108,13 @@ def _latent_call(q, latent_pool, rope_pool, block_tables, positions, layer,
         ),
         name=LATENT_KERNEL_NAME,
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), qmax, layer, qf, pos_rows,
-      latent_pool, rope_pool)
+    )(block_tables.astype(jnp.int32), qmax, layer, qf, pos_rows, pool)
     return out.reshape(B, Sp, H, Cp)[:, :S, :, :C]
 
 
 def paged_latent_attention_pallas(
     q: jax.Array,
-    latent_pool: jax.Array,
-    rope_pool: jax.Array,
+    pool: jax.Array,
     block_tables: jax.Array,
     positions: jax.Array,
     *,
@@ -1115,25 +1124,25 @@ def paged_latent_attention_pallas(
     q_block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Latent attention straight off a pool in planes: same contract as
+    """Latent attention straight off a pool in one plane: same contract as
     ``ops/kv_cache.paged_latent_attention``. q ``[B, S, H, C + R]``
-    (``[q~ | q_rope]``, ``C = latent_dim``), the pools ``[n_layer,
-    num_blocks, block_size, plane_width(C)]`` and ``[.., plane_width(R)]``
-    with ``layer`` (one layer's without), ``positions`` ``[B, S]``. Returns
-    ``[B, S, H, C]`` in q's dtype.
+    (``[q~ | q_rope]``, ``C = latent_dim``), the pool ``[n_layer,
+    num_blocks, block_size, latent_row_width(C, R)]`` with ``layer`` (one
+    layer's without), ``positions`` ``[B, S]``. Returns ``[B, S, H, C]``
+    in q's dtype.
 
     The kernel is ``_latent_attention_kernel`` under the name
     ``paged_attention_latent``: grid ``(B, q_blocks)``, a tile of
     ``q_block`` queries x H heads as ROWS over the one shared row a token;
-    per compute block one copy of each plane's pages, the latent tile then
-    feeds the scores (``q~ . c``, the rotary plane's ``q_rope . k_r``
-    added) and the values (``p . c``). So a page is read once for keys and
-    values, at ``2 H (C + R + C)`` flop a token. A chunk of queries against
-    a resident context runs the same kernel: nothing of the context is ever
+    per compute block ONE copy a page, the tile's latent lanes then feed
+    the scores (``q~ . c``, the rotary lanes' ``q_rope . k_r`` added) and
+    the values (``p . c``). So a page is read once for keys and values, at
+    ``2 H (C + R + C)`` flop a token. A chunk of queries against a
+    resident context runs the same kernel: nothing of the context is ever
     expanded by head in HBM."""
     if interpret is None:
         interpret = pallas_interpret()
-    latent_pool, rope_pool, layer = _as_pools(latent_pool, rope_pool, layer)
+    pool, _, layer = _as_pools(pool, None, layer)
     B, S = q.shape[:2]
     call = functools.partial(
         _latent_call, layer=layer.reshape(1), latent_dim=latent_dim,
@@ -1144,17 +1153,16 @@ def paged_latent_attention_pallas(
     # what fits there goes in groups of rows, a call a group
     rows = _latent_rows_a_call(B, block_tables.shape[1])
     if rows == B:
-        return call(q, latent_pool, rope_pool, block_tables, positions)
+        return call(q, pool, block_tables, positions)
     return jnp.concatenate([
-        call(q[i:i + rows], latent_pool, rope_pool,
-             block_tables[i:i + rows], positions[i:i + rows])
+        call(q[i:i + rows], pool, block_tables[i:i + rows],
+             positions[i:i + rows])
         for i in range(0, B, rows)])
 
 
 def latent_attention(
     q: jax.Array,
-    latent_pool: jax.Array,
-    rope_pool: jax.Array,
+    pool: jax.Array,
     block_tables: jax.Array,
     positions: jax.Array,
     *,
@@ -1163,19 +1171,19 @@ def latent_attention(
     backend: str = "auto",
     layer: jax.Array | int | None = None,
 ) -> jax.Array:
-    """Backend dispatcher for latent attention over a pool in planes, the
-    one entry point of the cached step's latent layers, every kind of
+    """Backend dispatcher for latent attention over a pool in one plane,
+    the one entry point of the cached step's latent layers, every kind of
     step: q ``[B, S, H, C + R]`` at true ``positions`` ``[B, S]`` (decode
     is S = 1), ``[B, S, H, C]`` back. "pallas": the kernel above; "xla":
     ``ops/kv_cache.paged_latent_attention`` through ``gather_kv``."""
     if resolve_backend(backend) == "pallas":
         return paged_latent_attention_pallas(
-            q, latent_pool, rope_pool, block_tables, positions,
+            q, pool, block_tables, positions,
             latent_dim=latent_dim, scale=scale, layer=layer)
     from ray_tpu.ops.kv_cache import paged_latent_attention
 
     return paged_latent_attention(
-        q, *_at_layer(latent_pool, rope_pool, layer), block_tables,
+        q, pool if layer is None else pool[layer], block_tables,
         positions, latent_dim=latent_dim, scale=scale)
 
 

@@ -589,7 +589,7 @@ class LLMEngine:
         # summaries that every layer reads, COMPOSED into a step's table
         # (kv_cache.py "A ring and a table of slots"): its own refusals
         composed = is_composed(groups)
-        # ... or caches ONE row a token for all heads, in planes
+        # ... or caches ONE row a token for all heads, in one plane
         # (``kv_planes``: kv_cache.py "A pool in planes"): what describes a
         # block by heads, or splits the pool along them, is refused
         planes = tuple(getattr(model_cfg, "kv_planes", ()))
@@ -599,7 +599,7 @@ class LLMEngine:
         # why no prompt prefix is reused (None: it is), for ``stats()``
         self._prefix_reuse_why = self._no_prefix_reuse(
             self._state_rows, bool(groups), composed)
-        if planes:  # one row of the planes' widths, no head axis
+        if planes:  # one row of its parts' widths, no head axis
             n_kv, head_dim = 1, sum(width for _, width, _ in planes)
         else:
             n_kv = getattr(model_cfg, "n_kv_head", None) or model_cfg.n_head
@@ -1557,7 +1557,7 @@ class LLMEngine:
                 # window while their sequence lived
                 "kv_groups": self.cache.group_report(),
                 # what a token's row in the pool is: K and V by head, or a
-                # latent family's planes, and its bytes
+                # latent family's one plane, and its bytes
                 "kv_pool": self.cache.cfg.describe_pool(),
                 "kv_window_blocks_taken": cs.window_blocks_taken,
                 "kv_window_blocks_freed": cs.window_blocks_freed,
@@ -2339,7 +2339,7 @@ class LLMEngine:
         Packed so: K and V by head (``llama``, ``gpt``; plain or
         quantized) and a pool in planes (``pangu_ultra_moe``,
         ``longcat_flash``: a layer writes the step's latent rows to the
-        planes before its kernel reads them back through the table, and
+        pool before its kernel reads them back through the table, and
         their ``state`` is counters, no row a sequence: ``slots`` then
         says BY ROW which rows are real, ``_slots_buf_locked``).
 

@@ -797,12 +797,11 @@ class ModelExecutor:
         report = {"kv_layers": cfg.n_layer,
                   "kv_pool_shape": list(self.cache.k.shape), "state": state,
                   "prefix_reuse": cfg.prefix_reuse,
-                  # what a token's row is (kind "latent": planes, then
-                  # ``kv_pool_shape`` is plane 0's and ``shapes`` has both)
+                  # what a token's row is (kind "latent": ONE plane, the
+                  # one array the step programs carry)
                   "kv_pool": cfg.describe_pool()}
         if cfg.planes:
-            report["kv_pool"]["shapes"] = [
-                list(self.cache.k.shape), list(self.cache.v.shape)]
+            report["kv_pool"]["shapes"] = [list(self.cache.k.shape)]
         if cfg.groups:
             # tables by group: ``kv_layers`` is then a GROUP's layers (the
             # pool's layer axis; all the model's under a ring and a slot

@@ -118,21 +118,24 @@ models/pangu_ultra_moe.py: a 512-wide latent vector, key and value at
 once, and the key's 64-wide rotary rest) says so in ONE description,
 ``(name, width, stored width)`` a plane, that the family's config owns
 (``kv_planes``) and this manager, ``ops/kv_cache.py write_kv``, the
-executor's report and the refusals read. The two arrays the step programs
-carry and donate are then the two planes,
+executor's report and the refusals read. The parts of a row rest side by
+side in ONE plane, and the step programs carry and donate ONE array,
 
-    k: [n_layer, num_blocks, block_size, stored width of plane 0]
-    v: [n_layer, num_blocks, block_size, stored width of plane 1]
+    k: [n_layer, num_blocks, block_size, the parts' stored widths]
+    v: None
 
-each stored at whole lanes of 128 (the rotary 64 as 128, zeros behind: a
-page is then whole tiles, rests as written and is copied by the kernel
-where it stands; ``row_bytes`` says what the layer's mathematics needs,
-1,152 B, ``stored_row_bytes`` what the pool holds, 1,280 B: +11%). One
+a token's row ``[c | k_rope]`` with each part at whole lanes of 128 (the
+rotary 64 as 128, zeros behind: ops/paged_attention.py ``latent_row``), so
+a page is whole tiles, rests as written and is ONE contiguous copy of the
+kernel's where it stands, read there by lane-aligned views (two planes
+were two copies a page, and starting a copy is what bound the kernel: PR
+53); ``row_bytes`` says what the layer's mathematics needs, 1,152 B,
+``stored_row_bytes`` what the pool holds, 1,280 B: +11%). One
 table, every layer keeps every token: blocks, reservations, the prefix
 cache, copy-on-write and preemption are what they are for K and V by head,
 since a block's bytes are all they touch, and a prompt may be split over
 the rows of one prefill step as theirs may (``one_table``: a step's rows
-are written to the planes before the kernel reads them back through the
+are written to the pool before the kernel reads them back through the
 table). The host tier and the RTKV
 record describe a block as ``n_kv_head x head_dim`` twice and cannot say
 "planes" yet: the engine refuses them for such a family.
@@ -246,17 +249,18 @@ class KVCacheConfig:
     # the model's layer indices (for reports). Empty: one table for all
     # layers, as ever. See the module docstring.
     groups: tuple = ()
-    # A pool in planes: ``(name, width, stored width)`` of each of the two
-    # arrays, from the family's ``kv_planes``. Empty: K and V by head,
-    # ``n_kv_head x head_dim`` each. See the module docstring.
+    # A pool in planes: ``(name, width, stored width)`` of each PART of a
+    # row of the one array, in the row's order, from the family's
+    # ``kv_planes``. Empty: K and V by head, ``n_kv_head x head_dim``
+    # each. See the module docstring.
     planes: tuple = ()
 
     def __post_init__(self):
         if self.planes and (
-                len(self.planes) != 2 or self.quantization is not None
+                self.quantization is not None
                 or self.host_cache_bytes or self.groups):
             raise ValueError(
-                "a pool in planes is the step programs' two arrays, plain, "
+                "a pool in planes is the step programs' ONE array, plain, "
                 "under one table and without a host tier; got "
                 f"planes={self.planes}, quantization={self.quantization}, "
                 f"host_cache_bytes={self.host_cache_bytes}, "
@@ -329,7 +333,7 @@ class KVCacheConfig:
     def row_bytes(self) -> int:
         """Bytes of pool data a token costs ONE layer by the widths of
         what is cached (scale planes of a quantized pool apart): K and V
-        by head, or the planes' widths."""
+        by head, or the widths of a row's parts."""
         widths = (sum(width for _, width, _ in self.planes) if self.planes
                   else 2 * self.n_kv_head * self.head_dim)
         return widths * self._itemsize()
@@ -348,16 +352,25 @@ class KVCacheConfig:
 
     def describe_pool(self) -> dict:
         """What a token's row in the pool is, for ``describe()`` and
-        ``stats()``: its kind, the planes where it has them, and the bytes
-        above."""
+        ``stats()``: its kind, the ONE plane of a latent family (its row's
+        parts inside it), the copies a page costs the kernel, and the
+        bytes above."""
         out = {"kind": "latent" if self.planes else "heads",
                "row_bytes": self.row_bytes,
                "stored_row_bytes": self.stored_row_bytes,
-               "block_bytes": self.block_bytes}
+               "block_bytes": self.block_bytes,
+               # copies the attention kernel starts a page: one array's
+               # page, or K's and V's
+               "page_copies": 1 if self.planes else 2}
         if self.planes:
-            out["planes"] = [
-                {"name": name, "width": width, "stored_width": stored}
-                for name, width, stored in self.planes]
+            # ONE plane, a row's parts side by side in it
+            names, widths, stored = zip(*self.planes)
+            out["planes"] = [{
+                "name": "+".join(names), "width": sum(widths),
+                "stored_width": sum(stored),
+                "parts": [{"name": name, "width": width,
+                           "stored_width": at}
+                          for name, width, at in self.planes]}]
         return out
 
     def blocks_for(self, num_tokens: int) -> int:
@@ -543,10 +556,11 @@ class PagedKVCache:
         shape = self.pool_shape()
         scales = shape[:3] + (cfg.n_kv_head,)
         if cfg.planes:
-            # the two arrays are the two planes, a token's row in each
-            self.k, self.v = (
-                jnp.zeros(shape[:3] + (stored,), dtype)
-                for _, _, stored in cfg.planes)
+            # ONE array, a token's row for all heads (its parts side by
+            # side at their stored widths); no second pool
+            self.k, self.v = jnp.zeros(
+                shape[:3] + (sum(at for _, _, at in cfg.planes),),
+                dtype), None
         elif cfg.quantization is not None:
             from ray_tpu.ops.quantization import (
                 QuantizedKV,

@@ -793,6 +793,35 @@ def test_evabyte_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
+def _one_plane(cfg, layers, num_blocks, sharding):
+    """``[pool, None]``: a latent family's pool as the cache manager
+    builds it since ISSUE 53, ONE plane whose rows hold the parts of
+    ``kv_planes`` side by side, and no second array."""
+    return [_struct((layers, num_blocks, 16,
+                     sum(at for _, _, at in cfg.kv_planes)), cfg.dtype,
+                    sharding), None]
+
+
+def _holds_one_pool(text, shape):
+    """The compiled program takes ONE pool (``%cache_k``: no ``%cache_v``),
+    resting in the order written and donated (it is in the program's
+    ``input_output_alias``: updated where it stands), and no array of any
+    other shape anywhere in the program spans the pool's blocks."""
+    entry = text[text.index("ENTRY"):]
+    pools = re.findall(
+        r"%cache_[kv][.\d]* = (\w+\[[\d,]+\])\{([\d,]+)[^\n]*? "
+        r"parameter\((\d+)\)", entry)
+    pool = "bf16[" + ",".join(map(str, shape)) + "]"
+    assert [(p, layout) for p, layout, _ in pools] == [(pool, "3,2,1,0")], \
+        pools
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert int(pools[0][2]) in set(
+        map(int, re.findall(r"\}: \((\d+),", alias))), alias
+    spans = set(re.findall(rf"\w+\[(?:\d+,)*{shape[1]}(?:,\d+)*\]", text))
+    # (a pool of ONE layer is also seen without its layer axis: a bitcast)
+    assert spans <= {pool, pool.replace("[1,", "[")} and pool in spans, spans
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill",
                                   "prefill_packed"])
 def test_pangu_step_programs_compile_at_published_widths(
@@ -803,10 +832,11 @@ def test_pangu_step_programs_compile_at_published_widths(
     prefill over ``[1, 128]``; ``prefill_packed`` (ISSUE 47: what the
     engine launches now): the chunk program over the packed ladder's top
     rung, 16 rows of one 128-token q tile under ``[16, 768]``, whose
-    temporaries are no larger than the one-row chunk's. The pool is two
-    PLANES, ``[5, 40961, 16, 512]`` and ``[.., 128]`` (4.19 GB together):
-    both are in the program's ``input_output_alias`` and nothing pool-sized
-    is among its temporaries; each of the 5 layers of a DECODE step calls
+    temporaries are no larger than the one-row chunk's. The pool is ONE
+    PLANE, ``[5, 40961, 16, 640]`` (4.19 GB; ISSUE 53: a page is one copy),
+    the program carries no second pool-sized array, the one is in its
+    ``input_output_alias`` and nothing pool-sized is among its
+    temporaries; each of the 5 layers of a DECODE step calls
     ``paged_attention_latent`` once, each of a PREFILL step (ISSUE 51: the
     expanded form) ``flash_fwd`` once over its own keys and once inside
     the loop over its resident prefix; and nothing in the program has the
@@ -841,10 +871,8 @@ def test_pangu_step_programs_compile_at_published_widths(
         kind, 1)
     state = jax.tree.map(on_chip, jax.eval_shape(
         lambda: fam.init_state(cfg, engine["max_batch_size"] + 1)))
-    planes = [_struct((cfg.n_layer, engine["num_blocks"], 16, stored),
-                      cfg.dtype, one_chip) for _, _, stored in cfg.kv_planes]
-    assert [p.shape for p in planes] == [(5, 40961, 16, 512),
-                                         (5, 40961, 16, 128)]
+    planes = _one_plane(cfg, cfg.n_layer, engine["num_blocks"], one_chip)
+    assert planes[0].shape == (5, 40961, 16, 640) and planes[1] is None
     i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
     ctx = engine["length_buckets"][-1]
     assert ctx == 12288 and engine["prefill_chunk_tokens"] == 2048
@@ -864,11 +892,12 @@ def test_pangu_step_programs_compile_at_published_widths(
             sample=None, **more)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    pool_bytes = math.prod(planes[0].shape) * 2
     assert abs(pool_bytes - 4.194e9) < 0.001e9
-    # 6.82 GB of weights and the two planes
+    # 6.82 GB of weights and the one plane
     assert 10.9e9 < mem.argument_size_in_bytes < 11.1e9
     assert mem.alias_size_in_bytes >= pool_bytes
+    _holds_one_pool(compiled.as_text(), planes[0].shape)
     # ISSUE 51: a packed step's temporaries in the expanded form (K and V
     # of the step's own 2,048 tokens and of ONE prefix block by head, a
     # float32 output and log-sum-exp carried from call to call) are no
@@ -904,12 +933,14 @@ def test_pangu_step_programs_compile_at_published_widths(
     assert "cross_program_prefetch_index" not in text
 
 
-# the latent kernel's calls in the two cells that hold it: 128 heads
-# (openPangu: 128-row decode over [B, 768], a 1 x 2,048 chunk) and 64
-# heads (LongCat-Flash: 96-row decode and a 1 x 1,024 chunk over [B, 384]);
+# the latent kernel's calls in the three cells that hold it: 128 heads
+# (openPangu: 128-row decode over [B, 768], a 1 x 2,048 chunk), 64
+# heads (LongCat-Flash: 96-row decode and a 1 x 1,024 chunk over [B, 384])
+# and 32 (Ling-3.0-flash: one layer, 64 rows a call over [B, 2560]);
 # ISSUE 47: the chunk as the packed ladder's top rung, rows of one
 # 128-token q tile (16 x 128 and 8 x 128: the same tiles, a row each)
 LATENT_CALLS = {
+    "ling-decode": (32, 64, 2560, 73729, 1, 1),
     "pangu-decode": (128, 128, 768, 40961, 1, 5),
     "pangu-chunk": (128, 1, 768, 40961, 2048, 5),
     "pangu-packed": (128, 16, 768, 40961, 128, 5),
@@ -921,11 +952,12 @@ LATENT_CALLS = {
 
 @pytest.mark.parametrize("call", sorted(LATENT_CALLS))
 def test_latent_attention_compiles(one_chip, call):
-    """``paged_attention_latent`` over a pool in planes ``[layers, blocks,
-    16, 512 | 128]`` at both head counts the cells hold: a tile's rows are
-    queries x heads (decode: 128 or 64 rows over blocks of 1,024 tokens;
-    a chunk: 8 queries a tile, 1,024 or 512 rows over blocks of 256), under
-    the VMEM ``_latent_block`` counts."""
+    """``paged_attention_latent`` over a pool in one plane ``[layers,
+    blocks, 16, 640]`` (ISSUE 53: a page is ONE copy) at the three head
+    counts the cells hold: a tile's rows are queries x heads (decode: 128,
+    64 or 32 rows over blocks of 1,024 tokens; a chunk: 8 queries a tile,
+    1,024 or 512 rows over blocks of 256), under the VMEM ``_latent_block``
+    counts."""
     import jax
     import jax.numpy as jnp
 
@@ -940,12 +972,11 @@ def test_latent_attention_compiles(one_chip, call):
                            scale=192 ** -0.5, layer=layers - 1,
                            interpret=False)
     compiled = jax.jit(fn).lower(
-        S_((B, S, H, 576), bf16), S_((layers, blocks, 16, 512), bf16),
-        S_((layers, blocks, 16, 128), bf16), S_((B, NB), jnp.int32),
-        S_((B, S), jnp.int32)).compile()
+        S_((B, S, H, 576), bf16), S_((layers, blocks, 16, 640), bf16),
+        S_((B, NB), jnp.int32), S_((B, S), jnp.int32)).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     rows = min(S, _LATENT_Q_BLOCK) * H
-    pages, vmem = _latent_block(16, 512, 128, rows, NB, bf16, bf16)
+    pages, vmem = _latent_block(16, 640, 512, rows, NB, bf16, bf16)
     # a decode tile (the heads of one query) takes blocks of 1,024 tokens,
     # a chunk's tile of 8 queries x the heads keeps 256
     assert pages == (64 if S == 1 else 16), pages
@@ -962,11 +993,11 @@ def test_longcat_step_programs_compile_at_published_widths(
     fresh prefill over ``[1, 64]``; ``prefill_packed`` (ISSUE 47: what the
     engine launches now): the chunk program over the packed ladder's top
     rung, 8 rows of one 128-token q tile under ``[8, 384]``, whose
-    temporaries are no larger than the one-row chunk's. The pool is two
-    PLANES over EIGHT latent sub-layers for four layers, ``[8, 16385, 16,
-    512]`` and ``[.., 128]`` (2.68 GB together): both are in the program's
-    ``input_output_alias`` and nothing pool-sized is among its
-    temporaries; each of the 4 layers attends TWICE (a decode step
+    temporaries are no larger than the one-row chunk's. The pool is ONE
+    PLANE over EIGHT latent sub-layers for four layers, ``[8, 16385, 16,
+    640]`` (2.68 GB; ISSUE 53), the only pool-sized array the program
+    carries: it is in the program's ``input_output_alias`` and nothing
+    pool-sized is among its temporaries; each of the 4 layers attends TWICE (a decode step
     through ``paged_attention_latent``, a prefill step, ISSUE 51, through
     ``flash_fwd``: its own keys, and the loop over its prefix) and has its
     two grouped products ONCE; nothing has the context's
@@ -1001,11 +1032,9 @@ def test_longcat_step_programs_compile_at_published_widths(
                        for a in jax.tree.leaves(params))
     assert abs(weight_bytes - 10.345e9) < 0.01e9, weight_bytes
     i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
-    planes = [_struct((cfg.n_kv_layer, engine["num_blocks"], 16, stored),
-                      cfg.dtype, one_chip) for _, _, stored in cfg.kv_planes]
-    assert [p.shape for p in planes] == [(8, 16385, 16, 512),
-                                         (8, 16385, 16, 128)]
-    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    planes = _one_plane(cfg, cfg.n_kv_layer, engine["num_blocks"], one_chip)
+    assert planes[0].shape == (8, 16385, 16, 640) and planes[1] is None
+    pool_bytes = math.prod(planes[0].shape) * 2
     assert abs(pool_bytes - 2.684e9) < 0.001e9
     if kind == "reference":
         chk = held["reference_check"]
@@ -1040,9 +1069,10 @@ def test_longcat_step_programs_compile_at_published_widths(
             sample=None, **more)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    # 10.35 GB of weights and the two planes
+    # 10.35 GB of weights and the one plane
     assert 13.0e9 < mem.argument_size_in_bytes < 13.1e9
     assert mem.alias_size_in_bytes >= pool_bytes
+    _holds_one_pool(compiled.as_text(), planes[0].shape)
     # ISSUE 51: a packed step's temporaries in the expanded form are the
     # absorbed form's 0.81 GB (805,874,688 B: the peak stands in the
     # layer's feed-forward half, which the compiler schedules within a
@@ -1232,7 +1262,8 @@ def test_other_families_programs_are_what_their_functions_compile_to(
 # jaxprs among them, is the one recorded before it. ISSUE 47 (a latent
 # family's prefill step is packed) changed no program's text and ADDS the
 # two it launches in place of the one-row chunk: ``pangu-packed`` and
-# ``longcat-packed``, the ladders' top rungs.
+# ``longcat-packed``, the ladders' top rungs. ISSUE 53 (the latent pool one
+# plane) re-recorded the four latent programs on ITS tree and no other.
 # ISSUE 50 (the step programs name their parts: ``jax.named_scope``, which
 # is metadata and stripped here) left the ten programs of unrolled stacks to
 # the letter, and moved the four of SCANNED stacks in their instructions'
@@ -1256,17 +1287,20 @@ PARENTS_TEXT = {
     # of the five expert families; the seven others above are as they were)
     "laguna-decode": "85a1e51ebd241889",
     "lfm2-decode": "4fca8ec5f3634977",
-    # planes [5, 40961, 16, 512 | 128], table [128, 768]
-    "pangu-decode": "999646688f5b9262",
-    # planes [8, 16385, 16, 512 | 128], table [96, 384]
-    "longcat-decode": "b8ef72aeeb4e03f0",
+    # re-recorded by ISSUE 53 (a latent family's pool is ONE plane, a page
+    # one copy: the four programs below carry one pool-sized array and the
+    # latent kernel one HBM operand; the ten others here are to the letter
+    # the texts PR 52's tree compiled to, which is what this test asserts)
+    # plane [5, 40961, 16, 640], table [128, 768]
+    "pangu-decode": "6d439f4165277305",
+    # plane [8, 16385, 16, 640], table [96, 384]
+    "longcat-decode": "22d3142dbc515154",
     # the chunk program over [16, 128] under [16, 768] and over [8, 128]
-    # under [8, 384], the cells' planes (ISSUE 47); re-recorded by ISSUE
-    # 51: a prefill step attends in the expanded form (``flash_fwd`` over
-    # its own keys, a loop over its resident prefix); the decode programs
-    # above are as they were
-    "pangu-packed": "54a067f3a73766f7",
-    "longcat-packed": "4ee9558a61934188",
+    # under [8, 384], the cells' planes (ISSUE 47; ISSUE 51: a prefill step
+    # attends in the expanded form, ``flash_fwd`` over its own keys, a loop
+    # over its resident prefix, whose blocks are read from the one plane)
+    "pangu-packed": "243c3be3a2a30f13",
+    "longcat-packed": "76e154c31d23b474",
     # pool [2, 65537, 16, 512], tables [4, 48, 1024]
     "smallthinker-decode": "1229fb1663ecaf6f",
 }
@@ -1354,10 +1388,10 @@ def _cell_program(which, kind, S_):
                 lambda: fam.init_state(cfg, rows + 1))),
                 "slots": i32((rows,))}
     if which in ("pangu", "longcat"):
-        # a pool in planes, each at its stored width, over the cache's
-        # layers (longcat: two latent sub-layers a layer)
+        # a pool in ONE plane over the cache's layers (longcat: two latent
+        # sub-layers a layer), and no second pool
         pools = [S_((getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,
-                     stored), cfg.dtype) for _, _, stored in cfg.kv_planes]
+                     sum(at for _, _, at in cfg.kv_planes)), cfg.dtype), None]
     else:
         pool = S_(pool_shape(
             getattr(cfg, "n_kv_layer", cfg.n_layer), num_blocks, 16,
@@ -1618,8 +1652,8 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
     them, at the cell's own shapes: the 128-row decode step and the
     2,048-token chunk against the 2,560-entry table of the 40,960-token
     bucket, the fresh prefill over 128 entries. The pool is ONE latent
-    layer in two planes (``[1, 73729, 16, 512]`` and ``[.., 128]``: 1.51
-    GB), both in the program's ``input_output_alias``; ``state`` (129
+    layer in one plane (``[1, 73729, 16, 640]``: 1.51 GB; ISSUE 53), in
+    the program's ``input_output_alias``; ``state`` (129
     slots of six KDA layers' matrices and convolution rows: 1.68 GB) is
     donated too: the kernel ``kda_step`` updates a row's state where it
     stands. The decode step calls ``kda_step`` in the six KDA layers and
@@ -1656,11 +1690,9 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
         lambda: fam.init_state(cfg, slots)))
     assert state["kda"].shape == (6, 129, 32, 128, 128)
     assert state["conv"].shape == (6, 129, 3, 12288)
-    planes = [_struct((cfg.n_kv_layer, engine["num_blocks"],
-                       engine["block_size"], stored), cfg.dtype, one_chip)
-              for _, _, stored in cfg.kv_planes]
-    assert [p.shape for p in planes] == [(1, 73729, 16, 512),
-                                         (1, 73729, 16, 128)]
+    assert engine["block_size"] == 16
+    planes = _one_plane(cfg, cfg.n_kv_layer, engine["num_blocks"], one_chip)
+    assert planes[0].shape == (1, 73729, 16, 640) and planes[1] is None
     i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
     ctx = engine["length_buckets"][-1]
     B = engine["max_batch_size"] if kind == "decode" else 1
@@ -1681,8 +1713,9 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
             sample=None, **more)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    pool_bytes = sum(math.prod(p.shape) * 2 for p in planes)
+    pool_bytes = math.prod(planes[0].shape) * 2
     assert abs(pool_bytes - 1.510e9) < 0.001e9
+    _holds_one_pool(compiled.as_text(), planes[0].shape)
     state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
                       for a in jax.tree.leaves(state))
     assert abs(state_bytes - 1.680e9) < 0.001e9

@@ -183,8 +183,8 @@ FAMILIES = {
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_every_familys_pool_is_lane_dense(jax_cpu, family):
     """One stored layout: a token's heads one row in every family's pool
-    (a latent family's planes are rows already), and ``describe()`` says
-    so."""
+    (a latent family's ONE plane holds its row's parts side by side, and
+    there is no second pool), and ``describe()`` says so."""
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
 
     eng = LLMEngine(EngineConfig(model=family, max_batch_size=4,
@@ -192,13 +192,18 @@ def test_every_familys_pool_is_lane_dense(jax_cpu, family):
     cache = eng.cache.cfg
     described = eng.executor.describe()
     assert described["kv_pool_shape"] == list(eng.cache.k.shape)
-    assert eng.cache.k.ndim == eng.cache.v.ndim == 4
+    assert eng.cache.k.ndim == 4
     if cache.planes:
-        assert eng.cache.k.shape[3] == cache.planes[0][2]
+        assert eng.cache.v is None
+        assert eng.cache.k.shape[3] == sum(at for _, _, at in cache.planes)
+        pool = described["kv_pool"]
+        assert pool["page_copies"] == 1 and len(pool["planes"]) == 1
+        assert pool["shapes"] == [list(eng.cache.k.shape)]
     else:
-        assert eng.cache.k.shape == (
+        assert eng.cache.v.shape == eng.cache.k.shape == (
             cache.n_layer, cache.num_blocks, cache.block_size,
             cache.n_kv_head * cache.head_dim)
+        assert described["kv_pool"]["page_copies"] == 2
     assert eng.stats()["executor"]["kv_pool_shape"] == list(eng.cache.k.shape)
     eng.shutdown()
 

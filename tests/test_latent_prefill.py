@@ -210,19 +210,21 @@ STEPS = {
 
 
 def _planes_and_step(step, dtype, seed=0):
-    """Planes full of finite garbage, each sequence's context (resident
-    prefix and this step's rows) written under its own pages."""
+    """A pool (ONE plane: rows ``[c | k_rope]``, each part at whole lanes)
+    full of finite garbage, each sequence's context (resident prefix and
+    this step's rows) written under its own pages."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.paged_attention import plane_width
+    from ray_tpu.ops.paged_attention import latent_row_width, plane_width
 
     S, rows = STEPS[step]
     B = len(rows)
     rng = np.random.default_rng(seed)
     blocks = 1 + 4 * NB
-    pool_c = rng.normal(size=(2, blocks, BS, plane_width(C))).astype(np.float32)
-    pool_r = rng.normal(size=(2, blocks, BS, plane_width(R))).astype(np.float32)
+    pool = rng.normal(
+        size=(2, blocks, BS, latent_row_width(C, R))).astype(np.float32)
+    pool_c, pool_r = pool[..., :plane_width(C)], pool[..., plane_width(C):]
     pool_c[..., C:] = 0
     pool_r[..., R:] = 0
     c = rng.normal(size=(B, S, C)).astype(np.float32)
@@ -245,7 +247,7 @@ def _planes_and_step(step, dtype, seed=0):
     return dict(
         q_nope=jax.random.normal(ks[0], (B, S, H, N), dtype),
         q_rope=jax.random.normal(ks[1], (B, S, H, R), dtype),
-        c=as_(c), k_r=as_(k_r), pool_c=as_(pool_c), pool_r=as_(pool_r),
+        c=as_(c), k_r=as_(k_r), pool=as_(pool),
         tables=jnp.asarray(tables), start=jnp.asarray(start),
         valid=jnp.asarray(valid),
         w_uk=jax.random.normal(ks[2], (C, H, N), dtype) * 0.3,
@@ -267,7 +269,7 @@ def _absorbed(a, scale):
         f32(a["q_rope"])], axis=-1)
     pos = a["start"][:, None] + jnp.arange(S, dtype=jnp.int32)[None]
     o = latent_attention(
-        q, f32(a["pool_c"]), f32(a["pool_r"]), a["tables"],
+        q, f32(a["pool"]), a["tables"],
         jnp.where(a["valid"], pos, 0), latent_dim=C, scale=scale,
         backend="xla", layer=1)
     return jnp.einsum("bshc,chv->bshv", o, f32(a["w_uv"])).reshape(B, S, -1)
@@ -298,7 +300,7 @@ def test_expanded_prefill_is_the_absorbed_call_on_the_same_planes(
     scale = (N + R) ** -0.5
     got = lp.expanded_prefill_attention(
         jnp.concatenate([a["q_nope"], a["q_rope"]], axis=-1), a["c"],
-        a["k_r"], a["pool_c"], a["pool_r"], a["tables"], a["valid"],
+        a["k_r"], a["pool"], a["tables"], a["valid"],
         a["start"], a["w_uk"], a["w_uv"], scale=scale, backend=backend,
         layer=1)
     B, S = a["valid"].shape
@@ -388,8 +390,8 @@ def test_a_familys_chunk_program_in_both_forms(models, monkeypatch, family,
             monkeypatch.setattr(
                 m, "_cached_heads", lambda *args: heads(
                     *args[:6], args[6]._replace(kind="decode"), args[7]))
-        k, v = (jnp.zeros((layers, 1 + 2 * NB, bs, stored))
-                for _, _, stored in cfg.kv_planes)
+        k, v = jnp.zeros((layers, 1 + 2 * NB, bs, sum(
+            stored for _, _, stored in cfg.kv_planes))), None
         state = fam.init_state(cfg, 2)
         tables = 1 + np.arange(2 * NB, dtype=np.int32).reshape(2, NB)
         with jax.default_matmul_precision("highest"):

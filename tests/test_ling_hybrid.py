@@ -78,8 +78,9 @@ def _drive(engine, streams, limit=4000):
 def _pools(cfg, blocks):
     import jax.numpy as jnp
 
-    return [jnp.zeros((cfg.n_kv_layer, blocks, BS, stored), cfg.dtype)
-            for _, _, stored in cfg.kv_planes]
+    # ONE plane (a row's parts side by side), no second pool
+    return jnp.zeros((cfg.n_kv_layer, blocks, BS, sum(
+        stored for _, _, stored in cfg.kv_planes)), cfg.dtype), None
 
 
 def _serve_logits(cfg, params, prompt, new, chunk=None, slot=1, state=None,
@@ -338,6 +339,17 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, ref):
 # -------------------------------------------------------------- engine
 
 
+# what the engine below decoded over the pool in TWO planes (the tree PR 53
+# started from; the interpreter's four are the first of each): one plane
+# moves no token
+_TWO_PLANES_DECODED = [
+    [312, 449, 238, 320, 352, 342, 21, 213], [91, 47, 5, 10],
+    [33, 229, 47, 156, 197, 110, 264, 400],
+    [204, 112, 494, 343, 35, 473, 111, 344], [130, 111, 160],
+    [84, 123, 268, 101, 60, 435],
+]
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_engine_streams_match_the_reference_and_solo(tiny, ref, backend):
     """Rows that join and leave under continuous batching (six requests,
@@ -357,6 +369,7 @@ def test_engine_streams_match_the_reference_and_solo(tiny, ref, backend):
                for p, n in zip(prompts, news)]
     _drive(engine, streams)
     outs = [list(s) for s in streams]
+    assert outs == [was[:n] for was, n in zip(_TWO_PLANES_DECODED, news)]
     for p, o in zip(prompts, outs):
         seq = p + o
         logits = np.asarray(ref.logits(params, jnp.asarray([seq[:-1]]),
@@ -427,7 +440,10 @@ def test_counters_stats_and_step_attrs(tiny):
     assert desc["state"]["arrays"]["kda"] == [3, 5, 4, 16, 16]
     assert desc["state"]["arrays"]["conv"] == [3, 5, 3, 192]
     assert desc["kv_pool"]["kind"] == "latent" and desc["kv_layers"] == 1
-    assert [p["name"] for p in desc["kv_pool"]["planes"]] == ["latent", "rope"]
+    (plane,) = desc["kv_pool"]["planes"]
+    assert [p["name"] for p in plane["parts"]] == ["latent", "rope"]
+    assert plane["name"] == "latent+rope"
+    assert desc["kv_pool"]["page_copies"] == 1
     assert stats["state_bytes"] == desc["state"]["bytes"] > 3 * 5 * 4 * 256 * 4
     assert stats["kv_pool"]["kind"] == "latent"
     assert set(stats["moe_gmm_form"].values()) == {"ragged"}

@@ -89,7 +89,7 @@ def _drive(engine, streams, limit=4000):
 
 def test_tiny_preset_and_published_widths(jax_cpu):
     """The published configuration: 28 double layers = 56 cache layers, a
-    row of 512 + 64 numbers in two planes, 768 router outputs of which 256
+    row of 512 + 64 numbers (two parts of one plane), 768 router outputs of which 256
     compute nothing, the two rescalings 2 and 3.46."""
     from ray_tpu.models.longcat_flash import LongCatFlashConfig
 
@@ -507,21 +507,21 @@ def test_the_four_holders_parts_add_up_to_the_uncut_layer(tiny, ref):
 
 
 def _latent_case(kind, seed=0, H=3, C=16, R=4, bs=4, NB=8, B=2):
-    """q at an ODD head count, the two planes with every page OUTSIDE the
-    tables poisoned, the tables and positions of a decode step or a chunk
+    """q at an ODD head count, the pool (one plane, rows ``[c | k_rope]``
+    at whole lanes) with every page OUTSIDE the tables poisoned, the tables and positions of a decode step or a chunk
     against a resident context."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.paged_attention import plane_width
+    from ray_tpu.ops.paged_attention import latent_row_width, plane_width
 
     S = {"decode": 1, "chunk": 8}[kind]
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     blocks = 1 + B * NB
-    lat = jax.random.normal(ks[0], (4, blocks + 3, bs, plane_width(C)))
-    rope = jax.random.normal(ks[1], (4, blocks + 3, bs, plane_width(R)))
-    lat = lat.at[..., C:].set(0.0)
-    rope = rope.at[..., R:].set(0.0)
+    pool = jax.random.normal(
+        ks[0], (4, blocks + 3, bs, latent_row_width(C, R)))
+    Cp = plane_width(C)
+    pool = pool.at[..., C:Cp].set(0.0).at[..., Cp + R:].set(0.0)
     tables = np.zeros((B, NB), np.int32)
     perm = np.random.default_rng(seed).permutation(np.arange(1, blocks))
     ctx = {"decode": [13, 30], "chunk": [21, 9]}[kind]
@@ -533,10 +533,10 @@ def _latent_case(kind, seed=0, H=3, C=16, R=4, bs=4, NB=8, B=2):
     poisoned = np.ones(blocks + 3, bool)
     poisoned[tables[tables > 0]] = False
     poison = jnp.asarray(poisoned)[None, :, None, None]
-    lat = jnp.where(poison, jnp.nan, lat)
-    rope = jnp.where(poison, jnp.inf, rope)
+    lanes = jnp.arange(pool.shape[-1]) < Cp  # NaN in c's lanes, inf behind
+    pool = jnp.where(poison, jnp.where(lanes, jnp.nan, jnp.inf), pool)
     q = jax.random.normal(ks[2], (B, S, H, C + R))
-    return q, lat, rope, jnp.asarray(tables), jnp.asarray(pos), C
+    return q, pool, jnp.asarray(tables), jnp.asarray(pos), C
 
 
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
@@ -551,11 +551,11 @@ def test_latent_kernel_at_an_odd_head_count_with_pages_poisoned(
 
     from ray_tpu.ops.paged_attention import latent_attention
 
-    q, lat, rope, tables, pos, C = _latent_case(kind, H=heads)
+    q, pool, tables, pos, C = _latent_case(kind, H=heads)
     want = latent_attention(
-        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        q, pool.at[:, 0].set(0.0), tables, pos,
         latent_dim=C, scale=0.3, backend="xla", layer=3)
-    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+    got = latent_attention(q, pool, tables, pos, latent_dim=C,
                            scale=0.3, backend="pallas", layer=3)
     assert got.shape == (*q.shape[:3], C)
     assert bool(jnp.isfinite(got).all())
@@ -586,8 +586,8 @@ def test_cached_steps_match_the_reference_logits(tiny, ref, backend):
     tokens = np.asarray(jax.random.randint(
         jax.random.PRNGKey(10), (40,), 1, cfg.vocab_size))
     want = np.asarray(ref.logits(params, jnp.asarray(tokens[None]), cfg))[0]
-    k, v = (jnp.zeros((cfg.n_kv_layer, 1 + NB, bs, stored))
-            for _, _, stored in cfg.kv_planes)
+    k, v = jnp.zeros((cfg.n_kv_layer, 1 + NB, bs, sum(
+        stored for _, _, stored in cfg.kv_planes))), None
     assert k.shape[0] == 4 == 2 * cfg.n_layer
     state = longcat_flash_init_state(cfg, 2)
     slots = jnp.ones((1,), jnp.int32)
@@ -616,8 +616,21 @@ def test_cached_steps_match_the_reference_logits(tiny, ref, backend):
     for a in range(4):
         for b in range(a + 1, 4):
             assert float(np.abs(rows[a] - rows[b]).max()) > 1e-2
-    assert float(jnp.abs(k[..., 16:]).max()) == 0.0
-    assert float(jnp.abs(v[..., 4:]).max()) == 0.0
+    # one row ``[c | k_rope]`` a token, zeros in the padding, no second pool
+    assert v is None and k.shape[-1] == 256
+    assert float(jnp.abs(k[:, 1:11, :, 128:132]).min()) > 0
+    assert float(jnp.abs(k[..., 16:128]).max()) == 0.0
+    assert float(jnp.abs(k[..., 132:]).max()) == 0.0
+
+
+# what the engine below decoded over the pool in TWO planes (the tree PR 53
+# started from, both backends): one plane moves no token
+_TWO_PLANES_DECODED = [
+    [58, 58, 24, 46, 61, 46, 61, 58, 24, 46, 61, 58],
+    [9, 43, 32, 44, 32, 55, 24, 19, 1, 23, 38, 25],
+    [23, 1, 24, 2, 1, 10, 36, 26, 41, 45, 30, 29],
+    [56, 53, 6, 12, 44, 15, 23, 26, 25, 30, 56, 36],
+]
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
@@ -637,9 +650,10 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     streams += [engine.submit(p, max_new_tokens=12, temperature=0.0)
                 for p in prompts[1:]]
     _drive(engine, streams)
-    for p, s in zip(prompts, streams):
+    for p, s, was in zip(prompts, streams, _TWO_PLANES_DECODED):
         out = list(s)
         assert len(out) == 12 and max(out) < VOCAB_HELD
+        assert out == was
         logits = np.asarray(ref.logits(params, jnp.asarray([p + out]), cfg))[0]
         rows = logits[len(p) - 1: len(p) + 11]
         deficit = rows.max(-1) - rows[np.arange(12), out]
@@ -656,7 +670,10 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     described = st["executor"]
     assert described["attention_backend"] == backend
     assert described["kv_layers"] == 4 == 2 * cfg.n_layer
-    assert described["kv_pool"]["shapes"] == [[4, 129, 4, 128]] * 2
+    assert described["kv_pool"]["shapes"] == [[4, 129, 4, 256]]
+    assert described["kv_pool"]["page_copies"] == 1
+    assert len(described["kv_pool"]["planes"]) == 1
+    assert engine.cache.v is None
     assert "kv_groups" not in described
     engine.shutdown()
 

@@ -86,8 +86,8 @@ def _drive(engine, streams, limit=4000):
 
 def test_tiny_preset_and_published_planes(jax_cpu):
     """The tiny preset is the issue's, and at the published widths a
-    token's row is 512 + 64 numbers in two planes, the rotary one stored
-    at a whole lane tile."""
+    token's row is 512 + 64 numbers, two parts of ONE plane, the rotary
+    one stored at a whole lane tile."""
     from ray_tpu.models.pangu_ultra_moe import PanguUltraMoEConfig
 
     t = PanguUltraMoEConfig.tiny()
@@ -107,8 +107,9 @@ def test_tiny_preset_and_published_planes(jax_cpu):
 def test_a_blocks_bytes_at_the_published_widths(jax_cpu):
     """Reckoned, not allocated: 1,152 B a token a layer by the widths of
     what is cached, 92,160 B a block id over 5 layers; as STORED (the
-    rotary plane at 128 lanes) 1,280 B and 102,400 B, +11%. By head the
-    same token would be 81,920 B."""
+    rotary part at 128 lanes) 1,280 B and 102,400 B, +11%: ONE plane of
+    576 numbers stored as 640, a page one copy. By head the same token
+    would be 81,920 B."""
     import jax.numpy as jnp
 
     from ray_tpu.models.pangu_ultra_moe import PanguUltraMoEConfig
@@ -121,13 +122,21 @@ def test_a_blocks_bytes_at_the_published_widths(jax_cpu):
     assert kv.row_bytes == 1152
     assert kv.block_size * kv.n_layer * kv.row_bytes == 92160
     assert kv.stored_row_bytes == 1280 and kv.block_bytes == 102400
-    assert kv.describe_pool()["kind"] == "latent"
+    pool = kv.describe_pool()
+    assert pool["kind"] == "latent" and pool["page_copies"] == 1
+    assert pool["stored_row_bytes"] == 1280
+    (plane,) = pool["planes"]
+    assert (plane["name"], plane["width"], plane["stored_width"]) == (
+        "latent+rope", 576, 640)
+    assert [(p["name"], p["width"], p["stored_width"])
+            for p in plane["parts"]] == [
+                ("latent", 512, 512), ("rope", 64, 128)]
     by_head = KVCacheConfig(n_layer=5, n_kv_head=128, head_dim=160,
                             dtype=jnp.bfloat16)  # (192 + 128) / 2 a head
     assert by_head.row_bytes == 128 * (192 + 128) * 2 == 81920
     assert by_head.describe_pool() == {
         "kind": "heads", "row_bytes": 81920, "stored_row_bytes": 81920,
-        "block_bytes": 16 * 5 * 81920}
+        "block_bytes": 16 * 5 * 81920, "page_copies": 2}
     with pytest.raises(ValueError, match="planes"):
         KVCacheConfig(n_layer=5, n_kv_head=1, head_dim=576,
                       planes=pub.kv_planes, quantization="int8")
@@ -299,38 +308,53 @@ def test_the_sliced_heads_logits_are_the_whole_heads_first_rows(tiny, ref):
 # --------------------------------------------- the pool in planes, the kernel
 
 
-def test_write_kv_lands_each_plane_in_its_own_row(jax_cpu):
-    """``write_kv`` over two planes of different widths: the latent row as
-    it is, the rotary rest padded with zeros to its stored width."""
+def test_write_kv_lands_a_token_as_one_row(jax_cpu):
+    """``write_kv`` over the one plane: a token's row reads back as ``[c |
+    k_rope | zeros]``, each part at its stored width (tiny: 16 as 128, 4 as
+    128; published: 512 | 64 | 64 zeros), nothing else touched."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.kv_cache import write_kv
+    from ray_tpu.ops.paged_attention import (
+        latent_parts, latent_row, latent_row_width,
+    )
 
-    lat = jnp.full((2, 5, 4, 16), -1.0)
-    rope = jnp.full((2, 5, 4, 8), -1.0)
-    c = jnp.arange(2 * 3 * 16, dtype=jnp.float32).reshape(2, 3, 16)
+    assert latent_row_width(16, 4) == 256 and latent_row_width(512, 64) == 640
+    pool = jnp.full((2, 5, 4, 256), -1.0)
+    c = jnp.arange(2 * 3 * 16, dtype=jnp.float32).reshape(2, 3, 16) + 1
     k_r = jnp.arange(2 * 3 * 4, dtype=jnp.float32).reshape(2, 3, 4) + 100
     tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     pos = jnp.asarray([[3, 4, 5], [0, 1, 2]], jnp.int32)
     valid = jnp.asarray([[True, True, False], [True, True, True]])
-    lat, rope = write_kv(lat, rope, c, k_r, pos, tables, valid=valid, layer=1)
-    assert float(lat[0].max()) == -1.0  # the other layer is untouched
-    np.testing.assert_array_equal(np.asarray(lat[1, 1, 3]), np.asarray(c[0, 0]))
-    np.testing.assert_array_equal(np.asarray(lat[1, 2, 0]), np.asarray(c[0, 1]))
-    np.testing.assert_array_equal(np.asarray(rope[1, 3, 2, :4]),
-                                  np.asarray(k_r[1, 2]))
-    assert float(jnp.abs(rope[1, 3, 2, 4:]).max()) == 0.0  # the padding
-    assert float(lat[1, 2, 1].max()) == -1.0  # the masked token went to 0
+    pool, none = write_kv(pool, None, c, k_r, pos, tables, valid=valid,
+                          layer=1)
+    assert none is None
+    assert float(pool[0].max()) == -1.0  # the other layer is untouched
+    row = np.asarray(pool[1, 3, 2])
+    np.testing.assert_array_equal(row[:16], np.asarray(c[1, 2]))
+    np.testing.assert_array_equal(row[128:132], np.asarray(k_r[1, 2]))
+    assert not row[16:128].any() and not row[132:].any()  # the padding
+    np.testing.assert_array_equal(row, np.asarray(latent_row(c, k_r)[1, 2]))
+    got_c, got_r = latent_parts(pool[1, 1, 3], 16, 4)
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(c[0, 0]))
+    np.testing.assert_array_equal(np.asarray(got_r), np.asarray(k_r[0, 0]))
+    np.testing.assert_array_equal(
+        np.asarray(pool[1, 2, 0, :16]), np.asarray(c[0, 1]))
+    assert float(pool[1, 2, 1].max()) == -1.0  # the masked token went to 0
     # decode: one row a sequence
-    lat, rope = write_kv(lat, rope, c[:, 0], k_r[:, 0],
-                         jnp.asarray([6, 3], jnp.int32), tables, layer=0)
-    np.testing.assert_array_equal(np.asarray(rope[0, 2, 2, :4]),
+    pool, _ = write_kv(pool, None, c[:, 0], k_r[:, 0],
+                       jnp.asarray([6, 3], jnp.int32), tables, layer=0)
+    np.testing.assert_array_equal(np.asarray(pool[0, 2, 2, 128:132]),
                                   np.asarray(k_r[0, 0]))
+    # at the published widths a row is 512 | 64 | 64 zeros
+    wide = np.asarray(latent_row(jnp.ones((1, 512)), jnp.ones((1, 64))))
+    assert wide.shape == (1, 640)
+    assert wide[0, :576].all() and not wide[0, 576:].any()
 
 
 def _latent_walk_case(ctx, S, H, C=16, R=4, bs=16, NB=None, seed=0):
-    """q ``[B, S, H, C + R]``, the two planes, the tables and positions of
-    one row a context of ``ctx`` tokens plus ``S`` queries (a decode step,
+    """q ``[B, S, H, C + R]``, the pool (ONE plane: rows ``[c | k_rope]``,
+    each part at whole lanes), the tables and positions of one row a context of ``ctx`` tokens plus ``S`` queries (a decode step,
     a chunk against a resident context, a fresh prompt at 0). The pool has
     twice the pages the tables name; every page OUTSIDE them, block 0
     among them, is poisoned. ``NB`` None: tables as wide as the longest row
@@ -338,7 +362,7 @@ def _latent_walk_case(ctx, S, H, C=16, R=4, bs=16, NB=None, seed=0):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.paged_attention import plane_width
+    from ray_tpu.ops.paged_attention import latent_row_width, plane_width
 
     B = len(ctx)
     need = [-(-(c + S) // bs) for c in ctx]
@@ -346,11 +370,10 @@ def _latent_walk_case(ctx, S, H, C=16, R=4, bs=16, NB=None, seed=0):
         NB = -(-(max(need) + 2) // 128) * 128
     blocks = 2 * (1 + sum(need))
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    lat = jax.random.normal(ks[0], (2, blocks, bs, plane_width(C)))
-    rope = jax.random.normal(ks[1], (2, blocks, bs, plane_width(R)))
-    # the planes' padding lanes hold zeros, as the pool's do
-    lat = lat.at[..., C:].set(0.0)
-    rope = rope.at[..., R:].set(0.0)
+    pool = jax.random.normal(ks[0], (2, blocks, bs, latent_row_width(C, R)))
+    # the row's padding lanes hold zeros, as the pool's do
+    Cp = plane_width(C)
+    pool = pool.at[..., C:Cp].set(0.0).at[..., Cp + R:].set(0.0)
     tables = np.zeros((B, NB), np.int32)
     perm = np.random.default_rng(seed).permutation(np.arange(1, blocks))
     pos = np.zeros((B, S), np.int32)
@@ -362,10 +385,10 @@ def _latent_walk_case(ctx, S, H, C=16, R=4, bs=16, NB=None, seed=0):
     poisoned = np.ones(blocks, bool)
     poisoned[tables[tables > 0]] = False
     poison = jnp.asarray(poisoned)[None, :, None, None]
-    lat = jnp.where(poison, jnp.nan, lat)
-    rope = jnp.where(poison, jnp.inf, rope)
+    lanes = jnp.arange(pool.shape[-1]) < Cp  # NaN in c's lanes, inf behind
+    pool = jnp.where(poison, jnp.where(lanes, jnp.nan, jnp.inf), pool)
     q = jax.random.normal(ks[2], (B, S, H, C + R))
-    return q, lat, rope, jnp.asarray(tables), jnp.asarray(pos), C
+    return q, pool, jnp.asarray(tables), jnp.asarray(pos), C
 
 
 def _latent_case(kind):
@@ -387,13 +410,13 @@ def test_latent_kernel_matches_xla_with_every_other_page_poisoned(
 
     from ray_tpu.ops.paged_attention import latent_attention
 
-    q, lat, rope, tables, pos, C = _latent_case(kind)
+    q, pool, tables, pos, C = _latent_case(kind)
     # the XLA path gathers a table's padding entries (block 0) and masks
     # them: give IT a clean block 0; the kernel gets the poisoned one
     want = latent_attention(
-        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        q, pool.at[:, 0].set(0.0), tables, pos,
         latent_dim=C, scale=0.3, backend="xla", layer=1)
-    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+    got = latent_attention(q, pool, tables, pos, latent_dim=C,
                            scale=0.3, backend="pallas", layer=1)
     assert got.shape == (*q.shape[:3], C)
     assert bool(jnp.isfinite(got).all())
@@ -415,12 +438,12 @@ _ENDS = {
 
 @pytest.mark.parametrize("ends", sorted(_ENDS))
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
-@pytest.mark.parametrize("heads", [64, 128])
+@pytest.mark.parametrize("heads", [32, 64, 128])
 def test_latent_kernel_walks_whole_and_partial_blocks(jax_cpu, heads, kind,
                                                       ends):
     """The latent kernel at BOTH block lengths it chooses by a tile's rows
     (a decode tile of ``heads`` rows: ``_latent_tokens`` few-row length; a
-    chunk's tile of 8 x ``heads`` rows: the many-row length), at both
+    chunk's tile of 8 x ``heads`` rows: the many-row length), at the three
     cells' head counts, == the XLA path, every page outside the tables
     poisoned. The batch holds the context under test between two others,
     so a tile's first block is started under the tile BEFORE it, whole or
@@ -436,16 +459,71 @@ def test_latent_kernel_walks_whole_and_partial_blocks(jax_cpu, heads, kind,
     T = _latent_tokens(S * heads)
     assert T == (1024 if kind == "decode" else 256)
     ctx = [T + 17, _ENDS[ends](T), 2 * T]
-    q, lat, rope, tables, pos, C = _latent_walk_case(ctx, S, heads)
+    q, pool, tables, pos, C = _latent_walk_case(ctx, S, heads)
     want = latent_attention(
-        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        q, pool.at[:, 0].set(0.0), tables, pos,
         latent_dim=C, scale=0.3, backend="xla", layer=1)
-    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+    got = latent_attention(q, pool, tables, pos, latent_dim=C,
                            scale=0.3, backend="pallas", layer=1)
     assert got.shape == (*q.shape[:3], C)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(want).max()) > 0.05
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("heads", [32, 64, 128])
+def test_one_plane_kernel_equals_the_float32_reference(jax_cpu, heads, kind):
+    """The one-plane kernel == ``ops/kv_cache.paged_latent_attention`` in
+    float32 at the three cells' head counts, decode rows and a chunk tile,
+    at the PUBLISHED row (512 | 64 | 64 zeros) in pages of 16, over a batch
+    whose tables hold: a row whose last block the frontier cuts, a padding
+    row (every entry 0, position 0: it reads block 0 and is dropped), and
+    two rows that SHARE their first block (a common prefix; the second
+    goes on in a block of its own)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.kv_cache import paged_latent_attention, write_kv
+    from ray_tpu.ops.paged_attention import latent_attention, latent_row_width
+
+    C, R, bs, NB = 512, 64, 16, 8
+    S = 1 if kind == "decode" else 8
+    ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+    tables = np.zeros((4, NB), np.int32)
+    tables[0, :5] = [7, 3, 9, 2, 5]      # 70 tokens: the fifth block is cut
+    tables[2, :2] = [4, 6]               # rows 2 and 3 share block 4
+    tables[3, :2] = [4, 8]
+    first = np.asarray([70 - S, 0, 30 - S, 24 - S])  # row 1: padding
+    pool = jnp.zeros((2, 10, bs, latent_row_width(C, R)))
+    assert pool.shape[-1] == 640
+    for b, n in ((0, 70), (2, 30), (3, 24)):
+        # row 3 rewrites the shared block's first 16 rows with row 2's own
+        src = 2 if b == 3 else b
+        c = jax.random.normal(jax.random.fold_in(ks[0], src), (1, 70, C))
+        k_r = jax.random.normal(jax.random.fold_in(ks[1], src), (1, 70, R))
+        at = jnp.arange(16 if b == 3 else n)[None]
+        pool, _ = write_kv(pool, None, c[:, :at.shape[1]],
+                           k_r[:, :at.shape[1]], at,
+                           jnp.asarray(tables[b:b + 1]), layer=1)
+        if b == 3:  # its own tokens behind the shared prefix
+            c = jax.random.normal(jax.random.fold_in(ks[0], 3), (1, 8, C))
+            k_r = jax.random.normal(jax.random.fold_in(ks[1], 3), (1, 8, R))
+            pool, _ = write_kv(pool, None, c, k_r, 16 + jnp.arange(8)[None],
+                               jnp.asarray(tables[3:]), layer=1)
+    q = jax.random.normal(ks[2], (4, S, heads, C + R))
+    pos = jnp.asarray(first[:, None] + np.arange(S)[None], jnp.int32)
+    pos = pos.at[1].set(0)
+    want = paged_latent_attention(
+        q, pool[1], jnp.asarray(tables), pos, latent_dim=C, scale=0.07)
+    got = latent_attention(q, pool, jnp.asarray(tables), pos, latent_dim=C,
+                           scale=0.07, backend="pallas", layer=1)
+    assert got.shape == (4, S, heads, C) and got.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all())
+    real = np.asarray([0, 2, 3])
+    assert float(jnp.abs(want[real]).max()) > 0.1
+    np.testing.assert_allclose(
+        np.asarray(got)[real], np.asarray(want)[real], atol=3e-5)
 
 
 def test_a_latent_chunk_of_many_tiles_is_served(jax_cpu):
@@ -456,12 +534,12 @@ def test_a_latent_chunk_of_many_tiles_is_served(jax_cpu):
 
     from ray_tpu.ops.paged_attention import latent_attention
 
-    q, lat, rope, tables, pos, C = _latent_walk_case(
+    q, pool, tables, pos, C = _latent_walk_case(
         [250, 0, 300, 5], 20, 64)
     want = latent_attention(
-        q, lat.at[:, 0].set(0.0), rope.at[:, 0].set(0.0), tables, pos,
+        q, pool.at[:, 0].set(0.0), tables, pos,
         latent_dim=C, scale=0.3, backend="xla", layer=1)
-    got = latent_attention(q, lat, rope, tables, pos, latent_dim=C,
+    got = latent_attention(q, pool, tables, pos, latent_dim=C,
                            scale=0.3, backend="pallas", layer=1)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
@@ -499,11 +577,12 @@ def test_a_page_is_copied_once_for_keys_and_values(jax_cpu):
     """The kernel's own text, counted: wherever a block's copies are
     started (the call's first block; a whole block, one traced page
     unrolled as it is lowered; a block the frontier cuts, by a loop of the
-    frontier's bound) a page gets ONE copy a plane (the latent page, the
-    rotary page); a whole block is awaited once a plane, a cut block once a
-    copy. The latent tile then feeds two of the three products (``q~ . c``
-    and ``p . c``), the rotary tile the third, and the block's compute is
-    in the text ONCE. Nothing copies a page a second time for the values."""
+    frontier's bound) a page gets ONE copy (the pool is one plane: a page
+    ``[c | k_rope]`` is contiguous); a whole block is awaited once, a cut
+    block once a copy. The tile's latent lanes then feed two of the three
+    products (``q~ . c`` and ``p . c``), its rotary lanes the third, and the
+    block's compute is in the text ONCE. Nothing copies a page a second
+    time for the values, and no program holds a second pool."""
     import functools
 
     import jax
@@ -512,22 +591,25 @@ def test_a_page_is_copied_once_for_keys_and_values(jax_cpu):
         LATENT_KERNEL_NAME, paged_latent_attention_pallas,
     )
 
-    q, lat, rope, tables, pos, C = _latent_case("chunk")
+    q, pool, tables, pos, C = _latent_case("chunk")
     jaxpr = jax.make_jaxpr(functools.partial(
         paged_latent_attention_pallas, latent_dim=C, scale=0.3, layer=1,
-        interpret=False))(q, lat, rope, tables, pos)
+        interpret=False))(q, pool, tables, pos)
     calls = _pallas_calls(jaxpr.jaxpr)
     assert len(calls) == 1
     assert LATENT_KERNEL_NAME in str(calls[0].params["name"]) \
         or LATENT_KERNEL_NAME in str(calls[0].params)
     kernel = calls[0].params["jaxpr"]
     body = str(kernel)
-    # three stretches start copies, each a loop over pages: two copies a page
+    # three stretches start copies, each a loop over pages: ONE copy a page
     starts = [str(loop).count("dma_start") for loop in _loop_bodies(kernel)
               if "dma_start" in str(loop) and "dot_general" not in str(loop)]
-    assert starts == [2, 2, 2], starts
-    assert body.count("dma_start") == 6, body.count("dma_start")
-    assert body.count("dma_wait") == 4, body.count("dma_wait")
+    assert starts == [1, 1, 1], starts
+    assert body.count("dma_start") == 3, body.count("dma_start")
+    assert body.count("dma_wait") == 2, body.count("dma_wait")
+    # the call's operands past the three scalar-prefetch words and q and
+    # its positions: ONE pool
+    assert len(calls[0].invars) == 6, calls[0].invars
     assert body.count("dot_general") == 3, body.count("dot_general")
 
 
@@ -564,8 +646,8 @@ def test_cached_steps_match_the_reference_logits(tiny, ref, backend):
     tokens = np.asarray(jax.random.randint(
         jax.random.PRNGKey(10), (40,), 1, cfg.vocab_size))
     want = np.asarray(ref.logits(params, jnp.asarray(tokens[None]), cfg))[0]
-    k, v = (jnp.zeros((cfg.n_layer, 1 + NB, bs, stored))
-            for _, _, stored in cfg.kv_planes)
+    k, v = jnp.zeros((cfg.n_layer, 1 + NB, bs, sum(
+        stored for _, _, stored in cfg.kv_planes))), None
     state = pangu_ultra_moe_init_state(cfg, 2)
     slots = jnp.ones((1,), jnp.int32)
     tables = jnp.asarray(1 + np.arange(NB, dtype=np.int32)[None])
@@ -587,18 +669,32 @@ def test_cached_steps_match_the_reference_logits(tiny, ref, backend):
                 jnp.asarray([pos]), tables, cfg, state=state, slots=slots)
             np.testing.assert_allclose(
                 np.asarray(out)[0], want[pos], atol=1e-4)
-    # all a layer kept of a token: its row, and zeros in the padding
+    # all a layer kept of a token: its ONE row ``[c | k_rope]``, zeros in
+    # the padding, and no second pool
+    assert v is None and k.shape[-1] == 256
     assert float(jnp.abs(k[:, 1:11, :, :16]).min()) > 0
-    assert float(jnp.abs(k[..., 16:]).max()) == 0.0
-    assert float(jnp.abs(v[..., 4:]).max()) == 0.0
+    assert float(jnp.abs(k[:, 1:11, :, 128:132]).min()) > 0
+    assert float(jnp.abs(k[..., 16:128]).max()) == 0.0
+    assert float(jnp.abs(k[..., 132:]).max()) == 0.0
+
+
+# what the engine below decoded over the pool in TWO planes (the tree PR 53
+# started from, both backends): one plane moves no token
+_TWO_PLANES_DECODED = [
+    [25, 21, 12, 44, 7, 45, 26, 21, 9, 63, 4, 58],
+    [57, 57, 56, 35, 32, 35, 48, 36, 61, 61, 17, 35],
+    [35, 31, 34, 35, 36, 36, 48, 5, 50, 35, 15, 32],
+    [20, 25, 35, 54, 32, 54, 32, 57, 31, 48, 25, 42],
+]
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     """``EngineConfig(model="pangu_ultra_moe")`` through the normal path:
     prompts shorter and longer than a chunk, greedy tokens the reference's
-    own at every position (its logit within 1e-4 of the largest), the pool
-    reported in planes, nothing held at the end."""
+    own at every position (its logit within 1e-4 of the largest) and the
+    ones the two-plane pool decoded, the pool reported as one plane,
+    nothing held at the end."""
     import jax.numpy as jnp
 
     cfg, params = tiny
@@ -609,9 +705,10 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     streams += [engine.submit(p, max_new_tokens=12, temperature=0.0)
                 for p in prompts[1:]]
     _drive(engine, streams)
-    for p, s in zip(prompts, streams):
+    for p, s, was in zip(prompts, streams, _TWO_PLANES_DECODED):
         out = list(s)
         assert len(out) == 12 and max(out) < VOCAB_HELD
+        assert out == was
         logits = np.asarray(ref.logits(params, jnp.asarray([p + out]), cfg))[0]
         rows = logits[len(p) - 1: len(p) + 11]
         deficit = rows.max(-1) - rows[np.arange(12), out]
@@ -627,11 +724,15 @@ def test_engine_serves_through_the_latent_pool(tiny, ref, backend):
     assert st["kv_pool"]["row_bytes"] == (16 + 4) * 4
     described = st["executor"]
     assert described["attention_backend"] == backend
-    assert described["kv_pool_shape"] == [3, 129, 4, 128]
-    assert described["kv_pool"]["shapes"] == [[3, 129, 4, 128]] * 2
-    assert [p["name"] for p in described["kv_pool"]["planes"]] == [
-        "latent", "rope"]
-    assert [p["width"] for p in described["kv_pool"]["planes"]] == [16, 4]
+    assert described["kv_pool_shape"] == [3, 129, 4, 256]
+    assert described["kv_pool"]["shapes"] == [[3, 129, 4, 256]]
+    assert described["kv_pool"]["page_copies"] == 1
+    (plane,) = described["kv_pool"]["planes"]
+    assert (plane["name"], plane["width"], plane["stored_width"]) == (
+        "latent+rope", 20, 256)
+    assert [p["name"] for p in plane["parts"]] == ["latent", "rope"]
+    assert [p["width"] for p in plane["parts"]] == [16, 4]
+    assert engine.cache.v is None
     assert described["kv_layers"] == 3 and "kv_groups" not in described
     engine.shutdown()
 

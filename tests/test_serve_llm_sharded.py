@@ -109,7 +109,8 @@ def test_sharded_executor_shards_kv_pool_head_axis(jax_cpu):
                               # K and V by head: 2 x 2 heads x 16 x 4 B
                               "kv_pool": {"kind": "heads", "row_bytes": 256,
                                           "stored_row_bytes": 256,
-                                          "block_bytes": 4096},
+                                          "block_bytes": 4096,
+                                          "page_copies": 2},
                               "state": None,
                               "prefix_reuse": True,
                               "speculative": None}
@@ -137,7 +138,8 @@ def test_single_device_default_unchanged(jax_cpu):
                                        "kv_pool": {"kind": "heads",
                                                    "row_bytes": 256,
                                                    "stored_row_bytes": 256,
-                                                   "block_bytes": 4096},
+                                                   "block_bytes": 4096,
+                                                   "page_copies": 2},
                                        "state": None,
                                        "prefix_reuse": True,
                                        "speculative": None}
