@@ -1,18 +1,18 @@
 """The cached (serving) step of every family, written once.
 
-A served family runs three kinds of step against the paged K/V pool
+A served family runs these kinds of step against the paged K/V pool
 (ops/kv_cache.py; serve/llm drives them): ``prefill`` of a right-padded
-prompt chunk, ``decode_step`` of one token a row, ``verify_step`` of a
-speculative window. They are ONE step: tokens ``[B, S]`` at per-row true
+prompt chunk, ``decode_step`` of one token a row (an autoregressive family)
+or of one BLOCK a row (``block_steps``, at the file's end), ``verify_step``
+of a speculative window. They are ONE step: tokens ``[B, S]`` at true
 positions, every layer writing the chunk's K/V rows into the pool at its
 own layer index and attending over the paged context there, then the head
 on some of the rows and a sampling epilogue. The pool is a buffer the step
 OWNS: the step programs donate it (serve/llm/decode.py ``_jit_named``),
 each layer scatters B x S rows into it where it stands and the kernel
 reads the whole pool at a layer index (``attend_layer``). This file owns
-that step; a family's file
-(models/gpt.py, llama.py, lfm2_moe.py) holds only what is the family's
-own, in a ``CachedFamily``:
+that step; a family's file (models/gpt.py, llama.py, lfm2_moe.py) holds
+only what is the family's own, in a ``CachedFamily``:
 
 - ``embed(params, tokens, step, cfg) -> (x [B, S, D], aux)``: the token
   (and position) embedding, its table lookups through ``step.take``, and
@@ -79,7 +79,7 @@ from ray_tpu.ops.paged_attention import (
     prefill_attention,
     resolve_backend,
 )
-from ray_tpu.ops.sampling import sample_tokens, verify_tokens
+from ray_tpu.ops.sampling import sample_tokens, unmask_tokens, verify_tokens
 from ray_tpu.ops.sparse_select import (
     sparse_decode_attention,
     sparse_prefill_attention,
@@ -246,7 +246,7 @@ def attend_layer(step: Step, cache_k, cache_v, layer, q, k, v, cfg,
             ).transpose(0, 2, 1, 3)
         else:
             attn = prefill_attention(
-                q, cache_k, cache_v, tables, jnp.where(step.valid, at, 0),
+                q, cache_k, cache_v, tables, _query_limits(step, at, cfg),
                 backend=backend, layer=layer, window=window)
         return attn.reshape(B, S, -1), cache_k, cache_v
 
@@ -461,3 +461,104 @@ def _attend_latent(step, pool, layer, q, k, v, tables, at, backend, scale,
     return expanded_prefill_attention(
         q, k, v, pool, tables, step.valid, step.start, *up,
         scale=scale, backend=backend, layer=layer)
+
+
+# ----------------------------------------------------------------------------
+# A family that generates by diffusion over BLOCKS (models/sdar_moe.py):
+# attention is full inside a block of ``cfg.block_length`` positions and
+# causal from block to block, and a decode step carries a block a row.
+# Appended here, every line above as it was: no line of another family's
+# kernel call chain moves.
+# ----------------------------------------------------------------------------
+
+
+def _query_limits(step, at, cfg):
+    """The last position each query of a prompt-kind step attends, ``[B,
+    S]``, padding columns at 0: its own (causal), or under the kinds of a
+    block family the last of its BLOCK. The paged kernel masks a key by
+    its query's limit (``prefill_attention``'s ``positions``), so the
+    block mask costs no kernel of its own; every query of a block pass has
+    one limit, which is the decode kernel's situation at ``block_length``
+    times the query rows a K/V head."""
+    if step.kind in BLOCK_KINDS:
+        at = at - at % cfg.block_length + (cfg.block_length - 1)
+    return jnp.where(step.valid, at, 0)
+
+
+# ``Step.kind`` of a block family: a prompt chunk under the block mask, and
+# a pass over one block a row. Neither is ``fresh`` (whose shortcut's mask
+# is causal and no more) nor ``decode`` (one token a row)
+BLOCK_KINDS = ("block_chunk", "block")
+
+
+def _block_step(fam, kind, params, cache_k, cache_v, tokens, rows,
+                block_tables, cfg, *, start, sample, state, slots):
+    """``_step`` for a block family. ``block_chunk``: a prompt's whole
+    blocks ``tokens [B, S]`` at ``start``, ``rows`` real tokens a row;
+    their K/V is written and NOTHING is chosen (a block family has no
+    token to give before its first block is denoised): ``out`` is zeros
+    ``[B]`` int32, or with ``sample=None`` the logits of every position
+    ``[B, S, V]``. ``block``: one pass over a block a row, ``tokens [B, W
+    + 1]`` the block's ids and the bits of its masked positions, ``rows``
+    the blocks' first positions: the block's K/V rows are (re)written,
+    all W queries attend up to the block's end, the head runs on all W
+    positions and the epilogue fills what the row's schedule says
+    (ops/sampling.py ``unmask_tokens``): ``out [B, W + 1]``, the row's
+    next ids and bits, or with ``sample=None`` the logits ``[B, W, V]``."""
+    W = cfg.block_length
+    masked = None
+    if kind == "block":
+        tokens, masked = tokens[:, :W], tokens[:, W]
+        start, rows = rows, jnp.full_like(rows, W)
+    elif start is None:
+        start = jnp.zeros_like(rows)
+    with jax.named_scope("embed"):
+        step = _plan("chunk", tokens, rows, block_tables, start, None,
+                     slots)._replace(kind=kind)
+        x, aux = fam.embed(params, tokens, step, cfg)
+        step = step._replace(aux=aux)
+        work = fam.open_state(state, step, cfg)
+    x, cache_k, cache_v, work = _walk(
+        fam, x, params[fam.stack], cache_k, cache_v, step, work, cfg)
+    with jax.named_scope("counters"):
+        state = fam.close_state(state, work, step, cfg)
+    if kind == "block_chunk" and sample is not None:
+        return jnp.zeros(rows.shape, jnp.int32), cache_k, cache_v, state
+    with jax.named_scope("head"):
+        logits = fam.head(params, fam.final_norm(params, x, cfg), cfg)
+    if sample is None:
+        return logits, cache_k, cache_v, state
+    with jax.named_scope("sample"):
+        out = unmask_tokens(
+            logits, tokens, masked, step.pos, sample, cfg.mask_token_id,
+            cfg.confidence_threshold)
+    return out, cache_k, cache_v, state
+
+
+def block_steps(fam: CachedFamily):
+    """``steps`` for a family that generates by diffusion over blocks:
+    ``(prefill, decode_step)`` under the names ``<fam.name>_prefill`` /
+    ``_decode_step`` and with the arguments ``steps`` gives them
+    (``_block_step`` says what differs: ``decode_step``'s ``tokens`` are
+    ``[B, block_length + 1]`` at the blocks' first ``positions``). No
+    verify step: there is nothing to draft for. The family's layers are a
+    LIST with ``open_state`` / ``close_state``."""
+
+    def prefill(params, cache_k, cache_v, tokens, lengths, block_tables,
+                cfg, start=None, sample=None, *, state=None, slots=None):
+        return _block_step(
+            fam, "block_chunk", params, cache_k, cache_v, tokens, lengths,
+            block_tables, cfg, start=start, sample=sample, state=state,
+            slots=slots)
+
+    def decode_step(params, cache_k, cache_v, tokens, positions,
+                    block_tables, cfg, sample=None, *, state=None,
+                    slots=None):
+        return _block_step(
+            fam, "block", params, cache_k, cache_v, tokens, positions,
+            block_tables, cfg, start=None, sample=sample, state=state,
+            slots=slots)
+
+    for fn in (prefill, decode_step):
+        fn.__name__ = fn.__qualname__ = f"{fam.name}_{fn.__name__}"
+    return prefill, decode_step

@@ -197,3 +197,110 @@ def verify_tokens(
     return jnp.concatenate(
         [committed[:, None].astype(jnp.int32), tgt], axis=1
     )
+
+
+# ----------------------------------------------------------------------------
+# Choose-and-unmask: the epilogue of a family that generates by diffusion
+# over blocks (models/sdar_moe.py). A pass yields logits at ALL of a block's
+# positions; which of the still-masked ones take their token is the row's
+# schedule.
+# ----------------------------------------------------------------------------
+
+# how a pass chooses the masked positions it fills; a row's is data (its
+# index here), so rows of one batch may differ
+REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+
+def fill_counts(block_length: int, steps: int | None) -> tuple[int, ...]:
+    """How many positions each of a block's ``steps`` passes fills:
+    ``block_length // steps``, a remainder going to the first passes (the
+    routine's transfer schedule; None: as many steps as the block is
+    long)."""
+    steps = block_length if steps is None else steps
+    base, rem = divmod(block_length, steps)
+    return tuple(base + (s < rem) for s in range(steps))
+
+
+def pass_fills(masked: int, counts: tuple[int, ...]) -> list[int]:
+    """The fills of the passes a block with ``masked`` masked positions
+    takes under ``counts``, until none is left (a first block whose head is
+    the prompt's tail takes fewer); the commit pass follows them."""
+    fills = []
+    for n in counts:
+        if masked <= 0:
+            break
+        fills.append(min(n, masked))
+        masked -= fills[-1]
+    return fills
+
+
+def unmask_tokens(
+    logits: jax.Array,
+    ids: jax.Array,
+    masked: jax.Array,
+    positions: jax.Array,
+    sample: dict,
+    mask_token_id: int,
+    threshold: float,
+) -> jax.Array:
+    """One denoising pass's choice, for every row of a batch at once.
+
+    ``logits`` [B, W, V] f32 at the W positions of each row's block
+    (absolute ``positions`` [B, W]); ``ids`` [B, W] what the block holds;
+    ``masked`` [B] int32, bit j set where position j is still masked (a
+    bit a position, the caller's knowledge: an id equal to the mask's is
+    an ordinary token). ``sample``: the leaves of ``sample_tokens`` a ROW,
+    and the row's schedule for this pass: ``fill`` [B] int32, how many
+    masked positions to fill; ``remasking`` [B] int32, an index of
+    ``REMASKING``. ``mask_token_id`` and ``threshold`` are the model
+    configuration's, constants of the program.
+
+    A position's token is ``sample_tokens`` of the logits AT it (greedy:
+    their argmax; keyed by its absolute position otherwise); its
+    confidence the largest softmax probability there. Chosen are, among
+    the masked: ``sequential`` the first ``fill``, left to right;
+    ``low_confidence_static`` the ``fill`` of highest confidence (ties to
+    the left); ``low_confidence_dynamic`` those, and every one whose
+    confidence passes ``threshold``. Returns ``[B, W + 1]`` int32: the
+    block's ids with the chosen filled, then the bits still masked. A row
+    that came with NO masked position ran its commit pass: what it gets
+    back is its NEXT block, all ``mask_token_id`` and every bit set."""
+    B, W, V = logits.shape
+    offs = jnp.arange(W, dtype=jnp.int32)
+    is_masked = ((masked[:, None] >> offs[None, :]) & 1) != 0
+    rows = {k: sample[k] for k in ("seeds", "temperature", "top_k", "top_p")}
+    greedy_rows = (rows["temperature"] <= 0.0) | (rows["top_k"] == 1)
+
+    def drawn(_):
+        # one position of every row at a time: the sort a draw takes is
+        # then a decode step's [B, V], not W times that
+        return jax.lax.map(
+            lambda j: sample_tokens(
+                jax.lax.dynamic_index_in_dim(logits, j, 1, keepdims=False),
+                jax.lax.dynamic_index_in_dim(positions, j, 1, keepdims=False),
+                rows),
+            offs).T
+
+    toks = jax.lax.cond(
+        jnp.all(greedy_rows),
+        lambda _: jnp.argmax(logits, axis=-1).astype(jnp.int32), drawn, None)
+    confidence = jnp.exp(
+        jnp.max(logits, axis=-1) - jax.nn.logsumexp(logits, axis=-1))
+    mode = sample["remasking"][:, None]
+    order = jnp.where(mode == 0, -offs[None, :].astype(jnp.float32),
+                      confidence)
+    order = jnp.where(is_masked, order, -jnp.inf)
+    # a position's rank among its row's: how many go before it
+    before = (order[:, None, :] > order[:, :, None]) | (
+        (order[:, None, :] == order[:, :, None])
+        & (offs[None, None, :] < offs[None, :, None]))
+    rank = jnp.sum(before, axis=-1)
+    chosen = is_masked & (rank < sample["fill"][:, None])
+    chosen |= is_masked & (mode == 2) & (confidence > threshold)
+    still = jnp.sum(
+        jnp.where(is_masked & ~chosen, 1 << offs[None, :], 0), axis=-1)
+    commit = (masked == 0)[:, None]
+    out = jnp.where(commit, mask_token_id, jnp.where(chosen, toks, ids))
+    bits = jnp.where(commit[:, 0], (1 << W) - 1, still)
+    return jnp.concatenate(
+        [out.astype(jnp.int32), bits[:, None].astype(jnp.int32)], axis=1)

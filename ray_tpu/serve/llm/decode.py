@@ -29,9 +29,14 @@ class Family:
     means one loader in ``FAMILIES`` below.
 
     ``init`` / ``prefill`` / ``decode_step`` / ``verify_step`` (None: the
-    family has no verify step) are the model's functions, the three
+    family has no verify step) are the model's functions, the
     steps of models/cached.py: each takes ``state=None, slots=None`` by
-    keyword and returns ``(out, cache_k, cache_v, state)``. ``param_axes``
+    keyword and returns ``(out, cache_k, cache_v, state)``. A decode step
+    yields one token a row, but for a family that generates by diffusion
+    over blocks (``block_steps`` True: its ``decode_step`` is one PASS over
+    a block of ``model_cfg.block_length`` positions a row, ids and masked
+    bits ``[B, block_length + 1]`` in and out, and its ``prefill`` chooses
+    no token; models/cached.py ``block_steps``). ``param_axes``
     and ``quant_axes`` map a model config to trees matching ``init``'s
     output; ``default_config()`` is the tiny config an engine built
     without one gets. ``init_state`` is None for a family whose only
@@ -79,6 +84,13 @@ class Family:
     # step updates where they stand: a lightning state a slot, compressed
     # keys a block id); None: ``state`` is not donated
     donated_state_counters: tuple | None = None
+    # the family generates by diffusion over blocks: a decode step is a
+    # pass over a block a row (serve/llm/engine.py ``_decode_blocks_locked``).
+    # Its model config says ``block_length``, ``mask_token_id`` and the
+    # defaults a request may override, ``denoising_steps`` / ``remasking``;
+    # the schedule itself is ops/sampling.py's (``fill_counts``,
+    # ``pass_fills``), no model file's.
+    block_steps: bool = False
 
 
 def _gpt() -> Family:
@@ -203,6 +215,20 @@ def _ling_hybrid() -> Family:
                   donated_state_counters=m.COUNTER_LEAVES)
 
 
+def _sdar_moe() -> Family:
+    from ray_tpu.models import sdar_moe as m
+    from ray_tpu.ops.moe import step_gmm_form
+
+    # no verify step: there is nothing to draft for (a pass fills a
+    # block's positions in any order)
+    return Family(m.sdar_moe_init, m.sdar_moe_prefill,
+                  m.sdar_moe_decode_step, None, m.sdar_moe_param_axes,
+                  m.sdar_moe_quant_axes, m.SdarMoeConfig.tiny,
+                  init_state=m.sdar_moe_init_state,
+                  counters=m.sdar_moe_counters, state_rows=False,
+                  gmm_form=step_gmm_form, block_steps=True)
+
+
 # THE registry of served families (``EngineConfig.model`` names a key);
 # each entry imports its model file when it is first asked for
 FAMILIES: dict[str, Callable[[], Family]] = {
@@ -210,7 +236,7 @@ FAMILIES: dict[str, Callable[[], Family]] = {
     "laguna": _laguna, "evabyte": _evabyte,
     "pangu_ultra_moe": _pangu_ultra_moe, "smallthinker": _smallthinker,
     "longcat_flash": _longcat_flash, "minicpm_sala": _minicpm_sala,
-    "ling_hybrid": _ling_hybrid,
+    "ling_hybrid": _ling_hybrid, "sdar_moe": _sdar_moe,
 }
 
 
@@ -372,7 +398,8 @@ def _compiled_text(rec: dict) -> str:
     JAX's cache key leaves an instruction's metadata out, so an executable
     that a checkout from before the names wrote is found under this one's
     key, with its own ``op_name``s. A text that does not name ``embed``
-    and ``head``, the two parts every step program has, is that (a tree
+    and ``head``, the two parts every step program has (but a block
+    family's prefill, which chooses no token: ``headless``), is that (a tree
     from before PR 50 named some parts of some families): it is compiled
     once more under a key that holds the metadata (a real compile, dear,
     once: the entry it writes is found by the next process). JAX also
@@ -389,7 +416,7 @@ def _compiled_text(rec: dict) -> str:
     args, kwargs = rec["args"]
     lowered = rec["fn"].lower(*args, **kwargs)
     text = lowered.compile().as_text()
-    if "/embed/" in text and "/head/" in text:
+    if "/embed/" in text and ("/head/" in text or rec.get("headless")):
         return text
     flag = "jax_compilation_cache_include_metadata_in_key"
     was = getattr(jax.config, flag)
@@ -489,6 +516,9 @@ class DecodeFns:
                 "args": ((self._abstract_params, *_abstract(args[1:])),
                          _abstract(kwargs)),
                 "scopes": None,
+                # a block family's prompt chunk runs no head
+                "headless": (sig[0] != "decode"
+                             and get_family(self.family).block_steps),
             }
         rec["calls"] += 1
         self._signatures[sig] = rec

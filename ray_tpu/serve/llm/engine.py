@@ -6,7 +6,12 @@ one batched prefill CHUNK (new admissions, or the next slice of a long
 prompt) or one batched decode step over all running sequences — new
 requests join the decode batch at the step after their prefill completes,
 finished sequences leave it the step they complete, and their KV blocks
-return to the pool immediately.
+return to the pool immediately. A decode step yields ONE token a row for
+an autoregressive family; for a family that generates by diffusion over
+blocks (``sdar_moe``) it is one PASS over a block a row, a reconciled step
+emits 0 or up to a block's tokens a row, and prefill emits none
+(``_decode_blocks_locked``; what follows says "token" where such a family
+has a block).
 
 Two serving-throughput optimizations sit on top of PR 1/2's engine:
 
@@ -202,8 +207,25 @@ class SamplingParams:
     # preemption (batch pauses first) and class-aware shedding. Never
     # changes tokens — only scheduling order.
     priority: str = "default"
+    # a family that generates by diffusion over blocks (models/sdar_moe.py):
+    # the passes that fill a block and the order they fill it in (one of
+    # ops/sampling.py ``REMASKING``); None: the model configuration's.
+    # Quality against steps is the knob such a family's users turn.
+    # Refused for a family that yields a token a step.
+    denoising_steps: int | None = None
+    remasking: str | None = None
 
     def __post_init__(self):
+        if self.denoising_steps is not None and self.denoising_steps < 1:
+            raise ValueError(
+                f"denoising_steps must be >= 1, got {self.denoising_steps}")
+        if self.remasking is not None:
+            from ray_tpu.ops.sampling import REMASKING
+
+            if self.remasking not in REMASKING:
+                raise ValueError(
+                    f"remasking must be one of {REMASKING}, got "
+                    f"{self.remasking!r}")
         if self.priority not in _PRIORITIES:
             raise ValueError(
                 f"priority must be one of {_PRIORITIES}, "
@@ -397,6 +419,9 @@ class _Request:
         # (prompt + generated) to re-prefill on resume; the park
         # timestamp; how many times this stream has been paused
         "pending_resume", "preempted_clock", "preempt_count",
+        # generation by diffusion over blocks: the row's block and its
+        # place in the block's schedule (``_BlockRow``), else None
+        "blk",
     )
 
     def __init__(self, req_id, prompt, sampling: SamplingParams,
@@ -438,6 +463,7 @@ class _Request:
         self.pending_resume: list[int] | None = None
         self.preempted_clock: float | None = None
         self.preempt_count = 0
+        self.blk: _BlockRow | None = None
         self.deadline = (
             time.monotonic() + sampling.deadline_s
             if sampling.deadline_s is not None
@@ -454,6 +480,47 @@ class _Request:
         prompt + generated-so-far when resuming from preemption."""
         return (self.pending_resume if self.pending_resume is not None
                 else self.prompt)
+
+
+class _BlockRow:
+    """A row of a family that generates by diffusion over blocks: where
+    its block is and how far the block's schedule has been LAUNCHED (the
+    host's view moves on at a launch, as a prefill's does).
+
+    ``counts``: positions a pass fills (ops/sampling.py ``fill_counts``,
+    the request's steps or the model's); ``mode``: the index of its order
+    in ``REMASKING`` there.
+    ``start``: the first position of the block the next pass works on: the
+    committed frontier, in whole blocks; it moves by a block, at the
+    launch of a commit pass. ``lead``: the block's leading positions that
+    are the prompt's tail (the first block's ``len(prompt) % W``).
+    ``fills`` / ``p``: what each denoising pass of this block fills under
+    a static order (``whole``: of a block that is all masks), and the
+    passes launched for it (``p == len(fills)``: the commit is next). ``x``: the block's ids and the bits of its masked
+    positions ``[W + 1]`` as the LAST RECONCILED pass gave them back (at
+    first: the prompt's tail, then masks): what a pass is fed from where
+    the row rides no pass in flight, and what a commit's reconcile emits.
+    ``due``: the tokens the commits launched so far deliver."""
+
+    __slots__ = ("counts", "mode", "start", "lead", "fills", "whole", "p",
+                 "x", "due")
+
+    def __init__(self, prompt: list, counts: tuple, mode: int, width: int,
+                 mask_id: int, pass_fills):
+        self.counts, self.mode = counts, mode
+        self.lead = len(prompt) % width
+        self.start = len(prompt) - self.lead
+        self.whole = pass_fills(width, counts)  # a later block's
+        self.fills = pass_fills(width - self.lead, counts)
+        self.p = 0
+        self.x = (prompt[self.start:] + [mask_id] * (width - self.lead)
+                  + [sum(1 << o for o in range(self.lead, width))])
+        self.due = 0
+
+    @property
+    def dynamic(self) -> bool:
+        """The order under which the DEVICE says when a block is full."""
+        return self.mode == 2
 
 
 class _StepTokens:
@@ -475,7 +542,9 @@ class _StepTokens:
 class _InFlight:
     """One launched-but-unsynced step program: its on-device sampled ids
     ``[B]`` int32 (row i belongs to ``batch[i]``; padding rows are
-    garbage), the exact batch list it was launched over, and ``seq``, its
+    garbage; a block family's pass: ``[B, W + 1]``, a block's ids and the
+    bits of its masked positions, with ``passes`` saying what each row's
+    pass was), the exact batch list it was launched over, and ``seq``, its
     number among the step programs this engine launched (what a sync's
     ``lag`` and the block quarantine's fence are counted in). A decode
     step needs no more; a prefill step also carries what its reconcile
@@ -497,6 +566,9 @@ class _InFlight:
     fields: dict | None = None  # prefill: the flight record's shape fields
     index: dict | None = None   # request -> the row of its id, on demand
     ids_at: list | None = None  # packed prefill: the rows of ``batch``'s ids
+    # a block family's decode step: (commit pass?, the block's leading
+    # prompt positions, the tokens its commit delivers) a row
+    passes: list | None = None
 
     def row_of(self, r) -> int:
         if self.index is None:
@@ -593,9 +665,15 @@ class LLMEngine:
         # (``kv_planes``: kv_cache.py "A pool in planes"): what describes a
         # block by heads, or splits the pool along them, is refused
         planes = tuple(getattr(model_cfg, "kv_planes", ()))
+        # ... or generates by diffusion over BLOCKS (decode.py
+        # ``Family.block_steps``): a decode step is a pass over a block of
+        # this many positions a row (0: a token a row a step)
+        self._block_len = model_cfg.block_length if family.block_steps else 0
         self._refuse_for_state(
             cfg, quant, self._state_rows, bool(groups) and not composed,
-            composed, bool(planes))
+            composed, bool(planes), bool(self._block_len))
+        if self._block_len:
+            self._check_whole_blocks(cfg, self._block_len)
         # why no prompt prefix is reused (None: it is), for ``stats()``
         self._prefix_reuse_why = self._no_prefix_reuse(
             self._state_rows, bool(groups), composed)
@@ -1039,6 +1117,27 @@ class LLMEngine:
         self.executor.on_new_signature = self._on_new_signature
         self.executor.phases = self._step_phases
         self.executor.spans = self._host_spans
+        # ---- generation by diffusion over blocks ----
+        # row-passes launched, those that were commits; blocks committed,
+        # their tokens that reached a stream, and those generated and not
+        # delivered (a last block's tail, what follows an EOS)
+        self._block_counts = dict.fromkeys(
+            ("block_passes", "block_passes_commit", "blocks_committed",
+             "block_tokens_committed", "block_tokens_cut"), 0)
+        if self._block_len:
+            from ray_tpu.ops.sampling import (
+                REMASKING, fill_counts, pass_fills)
+
+            # the schedule's two functions and the orders' names, for
+            # ``_block_row`` (a submit imports nothing)
+            self._schedule = (fill_counts, pass_fills, REMASKING)
+            # Whether a family steps by blocks is settled HERE, once: such
+            # an engine takes its own decode step, budget rule and emit
+            # pass, and every other engine runs the lines it ran before
+            # there was one.
+            self._decode_locked = self._decode_blocks_locked
+            self._eligible_locked = self._eligible_blocks_locked
+            self._emit_decoded_locked = self._emit_blocks_locked
         obs.gc_watch.acquire()  # last: nothing above may raise past it
 
     @staticmethod
@@ -1077,6 +1176,23 @@ class LLMEngine:
                 f"one window and summarises whole chunks; it is {cap}")
 
     @staticmethod
+    def _check_whole_blocks(cfg: EngineConfig, width: int) -> None:
+        """A family that generates by blocks of ``width`` positions: a
+        page of the cache and a prompt's chunk are whole blocks, so that a
+        page's K/V depends on no token past the page (content-addressed
+        reuse stays sound at page boundaries) and a chunk starts and ends
+        on a block's edge."""
+        for name, value in (("block_size", cfg.block_size),
+                            ("prefill_chunk_tokens",
+                             cfg.prefill_chunk_tokens)):
+            if value and value % width:
+                raise ValueError(
+                    f"model {cfg.model!r} attends by whole blocks of "
+                    f"{width} positions and commits its K/V a block at a "
+                    f"time: {name} must be a multiple of {width}, it is "
+                    f"{value}")
+
+    @staticmethod
     def _check_selected_pages(cfg: EngineConfig, block: int,
                               segment: int) -> None:
         """A family whose attention selects its pages by blocks of
@@ -1099,7 +1215,8 @@ class LLMEngine:
     @staticmethod
     def _refuse_for_state(cfg: EngineConfig, quant, stateful: bool,
                           grouped: bool, composed: bool = False,
-                          latent: bool = False) -> None:
+                          latent: bool = False,
+                          blocks: bool = False) -> None:
         """Raise for each option that cannot yet carry what the family
         keeps: per-sequence state beside the pool (``lfm2_moe``: a short
         convolution's rows; ``minicpm_sala``: a matrix a head of lightning
@@ -1109,7 +1226,8 @@ class LLMEngine:
         option the planes' list does and preemption besides), tables
         by group of layers (``laguna``), a ring and a table of chunk
         summaries (``evabyte``) or one latent row a token in planes
-        (``pangu_ultra_moe``, ``longcat_flash``), each with its reason."""
+        (``pangu_ultra_moe``, ``longcat_flash``), or generation by
+        diffusion over blocks (``sdar_moe``), each with its reason."""
         asked = {
             "speculative_k": cfg.speculative_k > 0,
             "host_cache_bytes": cfg.host_cache_bytes > 0,
@@ -1189,6 +1307,20 @@ class LLMEngine:
                     "ShardedExecutor splits the pool along its head axis "
                     "and one shared row has none; it has no expert axis "
                     "either"}),
+            (blocks, "generates by diffusion over blocks", {
+                "speculative_k":
+                    "there is nothing to draft for: a pass fills a "
+                    "block's positions in any order and leaves no "
+                    "next-token distribution to check, and the family has "
+                    "no verify step",
+                "preemption":
+                    "a paused row's provisional block and its place in "
+                    "the block's schedule are not parked with its "
+                    "committed chain",
+                "quantization":
+                    "the expert weights have no quantized path",
+                "tp/fsdp/mesh":
+                    "ShardedExecutor has no expert axis"}),
         ):
             for option, reason in why.items():
                 if keeps and asked[option]:
@@ -1272,8 +1404,15 @@ class LLMEngine:
         # cursor OUTSIDE the scheduler lock — compile is submit-path
         # work, and a bad grammar is the client's error (GrammarError is
         # a ValueError -> the proxies answer 400, never 500)
+        blk = self._block_row(prompt, sampling)
         fsm = None
         spec = structured.parse_response_format(sampling.structured)
+        if spec is not None and blk is not None:
+            raise ValueError(
+                f"model {self.cfg.model!r} generates by diffusion over "
+                "blocks and cannot be served with a grammar: a grammar's "
+                "cursor advances a token at a time, left to right, and a "
+                "pass fills a block's positions in any order")
         if spec is not None:
             dfa = structured.compile_grammar(
                 spec, self.model_cfg.vocab_size, self.cfg.eos_id
@@ -1313,6 +1452,7 @@ class LLMEngine:
                 )
             req = _Request(self._next_id, prompt, sampling, trace_ctx)
             req.fsm = fsm
+            req.blk = blk
             self._next_id += 1
             req.submitted_clock = obs.clock()
             self._tl(req, "received", ts=received)
@@ -1325,6 +1465,40 @@ class LLMEngine:
         if self._auto_step:
             self._ensure_thread()
         return TokenStream(req)
+
+    def _routed_dims(self, kind: str, ids_shape: tuple) -> tuple:
+        """``(rows[, tokens a row])`` a step program routes through its
+        expert layers, for ``stats()["moe_gmm_form"]``: the shape of its
+        ids, but a block pass's, which is a block a row whatever words ride
+        beside the ids."""
+        if kind == "decode" and self._block_len:
+            return (ids_shape[0], self._block_len)
+        return tuple(ids_shape)
+
+    def _block_row(self, prompt: list,
+                   sampling: SamplingParams) -> "_BlockRow | None":
+        """The request's block and schedule where the family generates by
+        diffusion over blocks (the request's steps and order, else the
+        model configuration's); None for a family that yields a token a
+        step, which refuses those settings by name."""
+        asked = {name: getattr(sampling, name) for name in (
+            "denoising_steps", "remasking")}
+        if not self._block_len:
+            for name, value in asked.items():
+                if value is not None:
+                    raise ValueError(
+                        f"model {self.cfg.model!r} yields one token a "
+                        f"sequence a step and has no {name}: that is a "
+                        "setting of a family that generates by diffusion "
+                        "over blocks")
+            return None
+        cfg = {name: getattr(self.model_cfg, name) if value is None
+               else value for name, value in asked.items()}
+        fill_counts, pass_fills, orders = self._schedule
+        return _BlockRow(
+            prompt, fill_counts(self._block_len, cfg["denoising_steps"]),
+            orders.index(cfg["remasking"]), self._block_len,
+            self.model_cfg.mask_token_id, pass_fills)
 
     def generate(
         self,
@@ -1589,9 +1763,11 @@ class LLMEngine:
                 # ``gmm_form``) by step program this engine has run:
                 # ``<kind>@<rows>[x<tokens a row>]``
                 **({} if self._gmm_form is None else {"moe_gmm_form": {
-                    f"{kind}@{'x'.join(map(str, shape))}":
-                        self._gmm_form(math.prod(shape))
-                    for kind, shape, _ in sorted(self.fns.signatures)}}),
+                    f"{kind}@{'x'.join(map(str, dims))}":
+                        self._gmm_form(math.prod(dims))
+                    for kind, dims in sorted(
+                        (kind, self._routed_dims(kind, shape))
+                        for kind, shape, _ in self.fns.signatures)}}),
                 "rejected_total": self._rejected_total,
                 "cancelled_total": self._cancelled_total,
                 "deadline_exceeded_total": self._deadline_total,
@@ -1638,6 +1814,11 @@ class LLMEngine:
                 "decode_rows_past_window": self._decode_rows_past_window,
                 "prefill_steps": self._prefill_steps,
                 "prefill_syncs_deferred": self._prefill_syncs_deferred,
+                # generation by diffusion over blocks (0 for a family that
+                # yields a token a step): row-passes launched and those
+                # that were commits; blocks committed, their tokens that
+                # reached a stream, and those generated and cut
+                **self._block_counts,
                 "phases": {
                     kind: {name: list(rec) for name, rec in table.items()}
                     for kind, table in self._phases.items()
@@ -2196,8 +2377,10 @@ class LLMEngine:
             )
             req.drawn_blocks += hit_tokens // bs
             # a full-chain hit still recomputes the LAST token (a 1-token
-            # chunk) so the engine has logits to sample from
-            req.prefill_done = min(hit_tokens, len(toks) - 1)
+            # chunk) so the engine has logits to sample from (a block
+            # family's prefill chooses no token: nothing is recomputed)
+            req.prefill_done = min(
+                hit_tokens, len(toks) - (0 if self._block_len else 1))
             req.cached_tokens = req.prefill_done
             if req.trace_ctx:
                 # host->device promotions staged for THIS admission show
@@ -2248,7 +2431,13 @@ class LLMEngine:
                 self._waiting_blocks -= self.cache.cfg.request_blocks(
                     len(req.prompt) + req.sampling.max_new_tokens
                 )
-                self._prefilling.append(req)
+                if req.prefill_done < self._prefill_end(req):
+                    self._prefilling.append(req)
+                else:
+                    # a block family's prompt of less than one block, or
+                    # one whose whole blocks are all resident: nothing to
+                    # prefill, its first block is next
+                    self._running.append(req)
                 admitted += 1
                 idx += 1
                 wait = obs.clock() - req.submitted_clock
@@ -2267,6 +2456,14 @@ class LLMEngine:
                 head.skips += 1  # someone was admitted past the head
             self._m_queue.set(len(self._waiting))
         return admitted
+
+    def _prefill_end(self, r: _Request) -> int:
+        """How many of the row's tokens prefill makes resident: all of
+        them, or for a family that generates by blocks the prompt's WHOLE
+        blocks (the partial last one belongs to the first generated
+        block)."""
+        n = len(r.prefill_tokens)
+        return n - n % self._block_len if self._block_len else n
 
     def _table_for(self, r: _Request, nb: int, pos: int = 0) -> np.ndarray:
         """Host block table for one request, rebuilt only when a block was
@@ -2360,7 +2557,7 @@ class LLMEngine:
         batch, ns = [], []
         room = self._piece_rows[-1] if P else 0  # rows left in the step
         for r in self._prefilling[: self.cfg.max_prefill_batch]:
-            remaining = len(r.prefill_tokens) - r.prefill_done
+            remaining = self._prefill_end(r) - r.prefill_done
             n = remaining if cap is None else min(remaining, cap)
             if P:
                 n = min(n, room * P)
@@ -2473,7 +2670,9 @@ class LLMEngine:
         else:
             toks_dev = self.executor.prefill_chunk(
                 tokens, lengths, starts, tables, sample=sample, span=span,
-                slots=slots, ids_width=self._ids_width(B) if P else None,
+                slots=slots, ids_width=(
+                    self._ids_width(B) if P and not self._block_len
+                    else None),
             )
         self._prefill_slots += B * S
         self._prefill_steps_packed += bool(P)
@@ -2494,7 +2693,7 @@ class LLMEngine:
             r.prefill_done += n
             r.inflight += 1
             self._prefill_tokens_total += n
-            final = r.prefill_done >= len(toks)
+            final = r.prefill_done >= self._prefill_end(r)
             rows.append((n, toks, r.prefill_done, final))
             if final:
                 self._prefilling.remove(r)
@@ -2548,7 +2747,8 @@ class LLMEngine:
                 np.zeros((rows, self._piece), np.int32),
                 np.ones((rows,), np.int32), np.zeros((rows,), np.int32),
                 np.zeros((rows, self._piece_nb), np.int32),
-                self._sample_args_locked([], rows), self._ids_width(rows),
+                self._sample_args_locked([], rows),
+                None if self._block_len else self._ids_width(rows),
                 # slot 0: padding, counted nowhere. An array of its own,
                 # as the others: nothing syncs these launches, so a
                 # staging buffer could be rewritten under one (CPU)
@@ -2836,10 +3036,13 @@ class LLMEngine:
 
     def _reconcile_locked(self, rec: _InFlight) -> int:
         """Collapse the dispatch lag for one step program in flight, the
-        OLDEST: sync its sampled ids (THE O(batch) int32 transfer), flush
-        the block quarantine up to it (a completed sync proves this and
-        every earlier dispatch executed, so blocks freed while none newer
-        was in flight are safe to reuse), then emit/retire per row. Rows
+        OLDEST: sync its sampled ids (THE O(batch) int32 transfer: an id a
+        row, or a block family's block of ids and its masked bits a row),
+        flush the block quarantine up to it (a completed sync proves this
+        and every earlier dispatch executed, so blocks freed while none
+        newer was in flight are safe to reuse), then emit/retire per row
+        (one token a row of an autoregressive family's decode step; 0 or
+        up to a block's of a block family's pass). Rows
         that terminated after the dispatch (EOS raced the lag, cancel,
         deadline, failover) drop their speculative token here and release
         their blocks — exactly once, via the inflight-guarded release. A
@@ -2881,6 +3084,8 @@ class LLMEngine:
         return emitted
 
     def _emit_decoded_locked(self, rec: _InFlight, toks) -> int:
+        """An autoregressive family's decode step: ONE id a row (a block
+        family's pass: ``_emit_blocks_locked``, bound in ``__init__``)."""
         book = _StepTokens()
         for r, tok in zip(rec.batch, toks.tolist()):
             r.inflight -= 1
@@ -2913,7 +3118,7 @@ class LLMEngine:
             if r.done:
                 # cancelled or expired with the chunk in flight
                 self._release_blocks_locked(r)
-            elif final:
+            elif final and not self._block_len:
                 # the model samples from last-VALID-token logits per
                 # row — for the final chunk that is the last prompt
                 # token (or, resuming, the last already-emitted token:
@@ -2921,6 +3126,203 @@ class LLMEngine:
                 # byte-identically)
                 self._emit_token_locked(r, tok, book)
         self._book_tokens_locked(book)
+
+    # ------- generation by diffusion over blocks (models/sdar_moe.py) -------
+
+    def _eligible_blocks_locked(self) -> list[_Request]:
+        """``_eligible_locked`` where a step carries a block a row: the
+        rows whose launched commits do not yet deliver all they may. A
+        row whose last commit is in flight is not launched again; one
+        that meets EOS inside a block is known only at that commit's
+        reconcile, and the pass launched behind it is wasted."""
+        return [r for r in self._running
+                if r.blk.due < r.sampling.max_new_tokens]
+
+    def _decode_blocks_locked(self) -> None:
+        """``_decode_locked`` for a family that generates by diffusion
+        over blocks: ONE pass of the family's decode program over a block
+        of ``W`` positions a row. A row is in one of its block's
+        denoising passes or in the commit pass; which, and how many
+        positions the pass fills, the HOST knows from the row's schedule
+        (``_BlockRow``) without reading the device, so the dispatch lag
+        stays: pass N + 1 is launched behind pass N with the rows' ids
+        and masked bits taken where they are, in pass N's on-device
+        result (the same rows in the same order: that array itself; rows
+        joined or left: one gather, ``executor.feed_rows``) or on the host
+        (a row whose prefill has just ended, or whose last pass has been
+        reconciled). The program rewrites the block's ``W`` K/V rows at
+        ``[start, start + W)`` every pass: they are PROVISIONAL, inside
+        the row's reservation and past its committed frontier, and stand
+        for good once the commit pass has run over the finished ids; the
+        frontier then moves by a block, at the commit's launch, and the
+        block's tokens reach the stream at its reconcile. Only a row under
+        ``low_confidence_dynamic`` (a block's length in passes is the
+        device's to say) collapses the lag first, each step, as a
+        grammar-constrained row does in ``_decode_locked``."""
+        chaos.fire("engine.decode", batch=len(self._running))
+        self._step_kind = "decode"
+        t0 = obs.clock()
+        t0_wall = obs.wall()
+        bs, W = self.cfg.block_size, self._block_len
+        with self._phase("engine.batch"):
+            batch = self._eligible_locked()
+        emitted = 0
+        if self._inflight and (
+                not batch or any(r.blk.dynamic for r in batch)):
+            emitted += self._collapse_locked()
+            batch = self._eligible_locked()
+        if not batch:
+            self._account_step_locked(
+                "decode", obs.clock() - t0, t0_wall, emitted, batch=0,
+                tokens=emitted,
+            )
+            return
+        ahead = self._inflight[-1] if self._inflight else None
+        steady = ahead is not None
+        with self._phase("kv.reserve"):
+            self._apply_promotions_locked()
+            pairs: list[tuple[int, int]] = []
+            kv_tokens = 0
+            for r in batch:
+                # the block's rows, committed or not, lie inside the
+                # row's reservation: a page is whole blocks
+                end = r.blk.start + W
+                r.drawn_blocks += self.cache.ensure_capacity(r.id, end)
+                cow = self.cache.prepare_write(r.id, r.blk.start, end)
+                r.drawn_blocks += len(cow)
+                pairs.extend(cow)
+                # what the kernel reads: the context to the block's END
+                kv_tokens += -(-end // bs) * bs
+            self._apply_copies_locked(pairs)
+        with self._phase("engine.batch"):
+            n = len(batch)
+            B = pad_to_bucket(n, self._batch_buckets)
+            ctx = pad_to_bucket(
+                max(max(r.blk.start + W,
+                        self.cache.num_allocated(r.id) * bs)
+                    for r in batch),
+                self._length_buckets,
+            )
+            nb = self._table_blocks(ctx)
+            positions = self._scratch_buf("dec_positions", (B,), np.int32)
+            tables = self._tables_buf("dec_tables", B, nb)
+            slots = self._slots_buf_locked("dec_slots", batch, B)
+            fill = self._scratch_buf("blk_fill", (B,), np.int32)
+            mode = self._scratch_buf("blk_mode", (B,), np.int32)
+            # row i: where its ids are in the pass in flight (-1: on the
+            # host), then the ids and bits the host holds
+            feed = self._scratch_buf("blk_feed", (B, W + 2), np.int32)
+            for buf in (positions, fill, mode, feed):
+                buf[n:] = 0
+            tables[..., n:, :] = 0
+            # the ids of a row that rides the PASS in flight are in its
+            # result; a prefill step in flight holds no id of any row
+            riding = steady and ahead.passes is not None
+            passes = []
+            for i, r in enumerate(batch):
+                k = r.blk
+                positions[i] = k.start
+                tables[..., i, :] = self._table_for(r, nb, k.start)
+                mode[i] = k.mode
+                if riding and r.inflight:
+                    feed[i, 0] = ahead.row_of(r)
+                else:
+                    feed[i, 0] = -1
+                    feed[i, 1:] = k.x
+                # under a static order the schedule says which pass this
+                # is; under the dynamic one the lag was collapsed and the
+                # bits the last pass gave back do
+                commit = (k.x[W] == 0 if k.dynamic
+                          else k.p >= len(k.fills))
+                if commit:
+                    fill[i] = 0
+                    deliver = min(
+                        W - k.lead, r.sampling.max_new_tokens - k.due)
+                    passes.append((True, k.lead, deliver))
+                    # the host's view moves on at the launch: the next
+                    # pass works on the next block, all masks
+                    k.due += deliver
+                    k.start, k.lead, k.p = k.start + W, 0, 0
+                    k.fills = k.whole
+                else:
+                    # (dynamic: AT LEAST so many, and one where the
+                    # schedule has run out)
+                    fill[i] = k.fills[k.p] if k.p < len(k.fills) else 1
+                    passes.append((False, 0, 0))
+                    k.p += 1
+            same = riding and batch == ahead.batch
+            if same:
+                tokens_src, feed = ahead.tokens, None
+            elif riding:
+                tokens_src = ahead.tokens
+            else:  # every row's ids are on the host
+                tokens_src, feed = feed[:, 1:], None
+            sample = self._sample_args_locked(batch, B)
+            sample.update(fill=fill, remasking=mode)
+        commits = [p for p in passes if p[0]]
+        counts = self._block_counts
+        counts["block_passes"] += n
+        counts["block_passes_commit"] += len(commits)
+        kv = {"kv_tokens": kv_tokens, "rows": n, "block_len": W,
+              "rows_commit": len(commits),
+              "tokens_committed": sum(p[2] for p in commits)}
+        span = {"kind": "decode", "seq": self._launched + 1, **kv}
+        next_dev = self.executor.decode_step(
+            tokens_src, positions, tables, sample=sample, span=span,
+            slots=slots, feed=feed,
+        )
+        remapped = steady and not same
+        self._decode_steps += 1
+        self._decode_steps_steady += steady
+        self._decode_steps_remapped += remapped
+        for r in batch:
+            r.inflight += 1
+        rec = self._launched_locked(_InFlight(
+            kind="decode", tokens=next_dev, batch=batch, passes=passes))
+        emitted += self._reconcile_older_locked(rec)
+        dt = obs.clock() - t0
+        self._decode_step_window.append(dt)
+        self._account_step_locked(
+            "decode", dt, t0_wall, emitted, batch=n, bucket_b=B,
+            bucket_len=ctx, nb=nb, tokens=emitted, **kv,
+            gmm_form=self._gmm_form(B * W), steady=steady,
+            remapped=remapped, trace_ids=self._trace_ids_locked(batch),
+        )
+
+    def _emit_blocks_locked(self, rec: _InFlight, toks) -> int:
+        """``_emit_decoded_locked`` for a pass over blocks: ``toks [B, W +
+        1]`` is what the pass gave back a row (its ids, its masked bits),
+        kept as the row's ``x``. A row whose pass was its block's COMMIT
+        puts the block on its stream, in one go under the step's one
+        timestamp: the ids its last denoising pass left (the ``x`` kept
+        at that pass's reconcile), from behind the prompt's tail, cut at
+        ``max_new_tokens`` or behind an EOS or a stop sequence (what is
+        cut was generated and is not delivered: ``block_tokens_cut``). The
+        gaps between a block's tokens are what the client sees, 0, and
+        are booked so; a reconciled step emits 0 or up to W tokens a
+        row."""
+        book = _StepTokens()
+        W, counts = self._block_len, self._block_counts
+        for r, x, (commit, lead, deliver) in zip(
+                rec.batch, toks.tolist(), rec.passes):
+            r.inflight -= 1
+            if r.done:
+                # passes launched behind a row's last block (EOS, cancel)
+                self._release_blocks_locked(r)
+                continue
+            k = r.blk
+            if commit:
+                sent = 0
+                for tok in k.x[lead:lead + deliver]:
+                    self._emit_token_locked(r, tok, book)
+                    sent += 1
+                    if r.done:
+                        break
+                counts["blocks_committed"] += 1
+                counts["block_tokens_committed"] += sent
+                counts["block_tokens_cut"] += W - lead - sent
+            k.x = x
+        return self._book_tokens_locked(book)
 
     def _propose_drafts_locked(self, batch: list) -> list[list[int]] | None:
         """Ask the drafter for up to ``speculative_k`` candidate tokens

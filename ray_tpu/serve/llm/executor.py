@@ -43,7 +43,11 @@ from typing import Any
 import numpy as np
 
 from ray_tpu.serve.llm import obs
-from ray_tpu.serve.llm.decode import DecodeFns, family_param_axes
+from ray_tpu.serve.llm.decode import (
+    DecodeFns,
+    family_param_axes,
+    get_family,
+)
 
 logger = logging.getLogger("ray_tpu.serve.llm")
 
@@ -105,6 +109,32 @@ def pad_ids(ids, width: int):
 
         _pad_ids = jax.jit(pad_ids, static_argnums=1)
     return _pad_ids(ids, width)
+
+
+_feed_rows = None
+
+
+def feed_rows(source, feed):
+    """``feed_ids`` for a family whose decode step carries a BLOCK a row
+    (decode.py ``Family.block_steps``): row ``i`` of the step's input
+    ``[B, W + 1]`` (a block's ids, then the bits of its masked positions)
+    is ``source[feed[i, 0]]`` where ``feed[i, 0] >= 0`` (``source``: what
+    the pass in flight gives back, never synced), else ``feed[i, 1:]``,
+    the row as the host holds it. ``jit_feed_rows``, a shape a pair of
+    row buckets."""
+    global _feed_rows
+    if _feed_rows is None:
+        import jax
+        import jax.numpy as jnp
+
+        def feed_rows(source, feed):
+            index, held = feed[:, 0], feed[:, 1:]
+            taken = jnp.take(
+                source, jnp.maximum(index, 0), axis=0, mode="clip")
+            return jnp.where(index[:, None] >= 0, taken, held)
+
+        _feed_rows = jax.jit(feed_rows)
+    return _feed_rows(source, feed)
 
 
 def _host_blocks(kv) -> np.ndarray:
@@ -209,6 +239,17 @@ class ModelExecutor:
         # buckets run, whose pairs have their id gather compiled
         self._ids_seen: dict[int, Any] = {}
         self._feed_rows: set[int] = set()
+        # how a decode step's ids are put together from the step in
+        # flight, and the host array that says so: a row's one id
+        # (``feed_ids``, ``[2, B]``), or for a family that steps by blocks
+        # its block's ids and bits (``feed_rows``, ``[B, W + 2]``);
+        # settled here, once
+        self._gather, self._feed_shape = feed_ids, lambda B: (2, B)
+        self._ids_ndim = 1  # a decode step's ids: [B], or [B, W + 1]
+        if get_family(family).block_steps:
+            width = model_cfg.block_length + 2
+            self._gather, self._feed_shape = feed_rows, lambda B: (B, width)
+            self._ids_ndim = 2
         self.fns = DecodeFns(
             family, model_cfg, platform=self._devices()[0].platform)
         self.params = (
@@ -401,7 +442,7 @@ class ModelExecutor:
                 self.stage_masks += isinstance(mask, np.ndarray)
             if feed is not None:
                 with obs.phase(self.spans, "executor.feed"):
-                    arrays = (feed_ids(arrays[0], feed), *arrays[1:])
+                    arrays = (self._gather(arrays[0], feed), *arrays[1:])
             elif put_first and isinstance(arrays[0], np.ndarray):
                 import jax
 
@@ -478,8 +519,8 @@ class ModelExecutor:
         gather, and none is compiled under traffic. The sources are the
         steps' OWN arrays (a few bytes, kept), so placement and commitment,
         which are part of a jitted call's cache key, are the real ones."""
-        if sample is None:
-            return
+        if sample is None or ids.ndim != self._ids_ndim:
+            return  # logits; or a block family's prefill, which chooses none
         width = ids.shape[0]
         pairs = set()
         if width not in self._ids_seen:
@@ -489,7 +530,8 @@ class ModelExecutor:
             self._feed_rows.add(rows)
             pairs |= {(w, rows) for w in self._ids_seen}
         for w, B in pairs:
-            feed_ids(self._ids_seen[w], np.zeros((2, B), np.int32))
+            self._gather(
+                self._ids_seen[w], np.zeros(self._feed_shape(B), np.int32))
 
     def verify_step(self, tokens, starts, draft_len, tables, sample=None,
                     span=None):
@@ -621,7 +663,10 @@ class ModelExecutor:
         full vocab argmax/pick after the logits all-reduce), so the
         transfer is the same O(batch) int32 regardless of device count."""
         toks = _host_tokens(tokens_dev)
-        assert toks.dtype == np.int32 and toks.ndim == 1, (
+        # [B]: a prefill's ids, a decode step's; a block family's pass
+        # gives [B, W + 1], a block's ids and the bits of its masked
+        assert toks.dtype == np.int32 and toks.ndim in (
+            1, self._ids_ndim), (
             "sync path must move O(batch) int32, got "
             f"{toks.dtype}/{toks.shape}"
         )
@@ -814,6 +859,20 @@ class ModelExecutor:
                 for window, layers in cfg.groups]
         return report
 
+    def _generation_report(self) -> dict:
+        """``{"generation": ..}`` for a family that generates by diffusion
+        over blocks, with the model configuration's settings (a request
+        may override the steps and the order); nothing for a family that
+        yields one token a row a decode step."""
+        if not get_family(self.family).block_steps:
+            return {}
+        cfg = self.model_cfg
+        return {"generation": {
+            "kind": "block_diffusion", "block_length": cfg.block_length,
+            "denoising_steps": cfg.denoising_steps or cfg.block_length,
+            "remasking": cfg.remasking,
+            "confidence_threshold": cfg.confidence_threshold}}
+
     def describe(self) -> dict:
         """Stable summary for stats()/debug_dump()/benchmarks: which
         executor is serving, over how many devices, which decode
@@ -825,7 +884,8 @@ class ModelExecutor:
                 "quantization": getattr(
                     self.model_cfg, "quantization", None),
                 **self._weights_report(), **self._state_report(),
-                "speculative": self.speculative}
+                "speculative": self.speculative,
+                **self._generation_report()}
 
 
 class SingleDeviceExecutor(ModelExecutor):

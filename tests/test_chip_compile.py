@@ -1738,3 +1738,100 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
     entry = text[text.index("ENTRY"):]
     assert re.search(r"%state__kda__", entry)
     assert re.search(r"%params__layers___1___kda_w_qkv__", entry)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk"])
+def test_sdar_moe_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The sdar cell's step programs as the executor compiles them, WITH
+    their epilogue, at the cell's own shapes: the 128-row block pass (ids
+    and bits ``[128, 5]`` over tables ``[128, 640]``: the 10,240-token
+    bucket) and the packed chunk's widest rung (16 pieces of 128). The
+    pool is lane-dense ``[6, num_blocks, 16, 512]``: both arrays are in
+    the program's ``input_output_alias`` and nothing pool-sized is among
+    its temporaries; every layer calls ``paged_attention`` ONCE (a block
+    pass is the decode kernel's situation at 32 query rows a K/V head: no
+    kernel of its own) and has its grouped product over all 128 experts'
+    matrices as stored; the block pass runs the head on all 512 positions
+    (the float32 logits are its largest temporary)."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/sdar-30b-a3b-chat-6l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/blockdiff-chat-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("sdar_moe")
+    init = common.load_named("reference", "sdar_moe").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, 0)))
+    pool = _struct(pool_shape(cfg.n_layer, engine["num_blocks"], 16,
+                              cfg.n_kv_head, cfg.head_dim),
+                   cfg.dtype, one_chip)
+    assert pool.shape == (6, engine["num_blocks"], 16, 512)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    f32 = functools.partial(_struct, dtype=jnp.float32, sharding=one_chip)
+    B = engine["max_batch_size"] if kind == "decode" else 16
+    nb = engine["length_buckets"][-1] // 16
+    assert (B, nb, cfg.block_length) in ((128, 640, 4), (16, 640, 4))
+    sample = {"seeds": _struct((B,), jnp.uint32, one_chip),
+              "temperature": f32((B,)), "top_k": i32((B,)),
+              "top_p": f32((B,)),
+              "mask": _struct((B, -(-cfg.vocab_size // 32)), jnp.uint32,
+                              one_chip)}
+    fns = decode.DecodeFns("sdar_moe", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        sample.update(fill=i32((B,)), remasking=i32((B,)))
+        lowered = fns._decode.lower(
+            params, pool, pool, i32((B, cfg.block_length + 1)), i32((B,)),
+            i32((B, nb)), sample=sample, **more)
+    else:
+        lowered = fns._prefill.lower(
+            params, pool, pool, i32((B, 128)), i32((B,)), i32((B, nb)),
+            start=i32((B,)), sample=sample, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * math.prod(pool.shape) * 2
+    # 8.72 GB of weights and the pool (a chunk chooses no token: its
+    # program holds no head, and the compiler's count of the rest varies)
+    if kind == "decode":
+        assert abs(mem.argument_size_in_bytes
+                   - (8.722e9 + pool_bytes)) < 0.03e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    print(kind, "args", mem.argument_size_in_bytes, "temp",
+          mem.temp_size_in_bytes, "code", mem.generated_code_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 15.5e9
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"%paged_attention[.\d]* = ", entry)) == 6
+    # one grouped product a layer; a prompt's chunk chooses no token, so
+    # what its LAST layer's experts would add meets nothing and is not
+    # computed (nor is the head: 1.83 GB of weights the chunk never reads)
+    assert len(_gmm_calls(entry)) == (6 if kind == "decode" else 5)
+    assert "ragged-dot" not in entry
+    if kind == "decode":
+        assert "f32[128,4,151936]" in text or "f32[512,151936]" in text
+        for needle in ("moe_route_w", "moe_gmm_w_in"):
+            assert re.search(
+                rf"\(.*%params__layers___\d___{needle}__", entry), needle
+    else:
+        assert "151936]" not in entry.split("ROOT")[-1]
+    assert "cross_program_prefetch_index" not in text

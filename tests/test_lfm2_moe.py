@@ -678,7 +678,7 @@ def test_unknown_model_name_raises(jax_cpu):
     assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
                                 "ling_hybrid", "llama", "longcat_flash",
                                 "minicpm_sala", "pangu_ultra_moe",
-                                "smallthinker"]
+                                "sdar_moe", "smallthinker"]
     for name in ("gpt", "llama"):
         assert get_family(name).init_state is None
         assert get_family(name).verify_step is not None

@@ -694,7 +694,8 @@ def test_the_families_are_served_and_named(jax_cpu):
 
     assert sorted(decode.FAMILIES) == [
         "evabyte", "gpt", "laguna", "lfm2_moe", "ling_hybrid", "llama",
-        "longcat_flash", "minicpm_sala", "pangu_ultra_moe", "smallthinker"]
+        "longcat_flash", "minicpm_sala", "pangu_ultra_moe", "sdar_moe",
+        "smallthinker"]
     with pytest.raises(ValueError, match="smallthinker"):
         decode.get_family("smallthinker2")
     fam = decode.get_family("smallthinker")
