@@ -466,7 +466,8 @@ def _attend_latent(step, pool, layer, q, k, v, tables, at, backend, scale,
 # ----------------------------------------------------------------------------
 # A family that generates by diffusion over BLOCKS (models/sdar_moe.py):
 # attention is full inside a block of ``cfg.block_length`` positions and
-# causal from block to block, and a decode step carries a block a row.
+# causal from block to block, and a decode step carries a block a row (a
+# finished block AND the fresh one behind it, where a row folds).
 # Appended here, every line above as it was: no line of another family's
 # kernel call chain moves.
 # ----------------------------------------------------------------------------
@@ -498,18 +499,39 @@ def _block_step(fam, kind, params, cache_k, cache_v, tokens, rows,
     their K/V is written and NOTHING is chosen (a block family has no
     token to give before its first block is denoised): ``out`` is zeros
     ``[B]`` int32, or with ``sample=None`` the logits of every position
-    ``[B, S, V]``. ``block``: one pass over a block a row, ``tokens [B, W
-    + 1]`` the block's ids and the bits of its masked positions, ``rows``
-    the blocks' first positions: the block's K/V rows are (re)written,
-    all W queries attend up to the block's end, the head runs on all W
-    positions and the epilogue fills what the row's schedule says
-    (ops/sampling.py ``unmask_tokens``): ``out [B, W + 1]``, the row's
-    next ids and bits, or with ``sample=None`` the logits ``[B, W, V]``."""
+    ``[B, S, V]``.
+
+    ``block``: one pass a row, ``tokens [B, W + 1]`` the row's block (its
+    ids and the bits of its masked positions), ``rows`` the blocks' first
+    positions. A row whose block holds NO masked position and whose
+    schedule still fills (``sample["fill"] > 0``: another block is due)
+    FOLDS: it carries ``[finished block | next block, all MASK]``, 2W
+    positions from the finished block's start, so the pass that leaves
+    the finished block's K/V in the cache for good IS the next block's
+    first denoising pass. Every other row carries its block alone (a
+    denoising pass; or, no bit set and ``fill`` 0, the commit of a
+    request's LAST block) and its other W columns are padding: written to
+    the garbage block, routed to no expert, counted nowhere. The step is
+    a prompt chunk of ``[B, 2W]`` under the block mask: the K/V rows of
+    the valid positions are (re)written, a query attends up to ITS
+    block's end (the finished block's never see the fresh one's keys, so
+    their K/V is what a commit pass of their own leaves; the fresh
+    block's see them as this pass's own scatter wrote them, a layer at a
+    time). The head runs on W positions a row, the ones that CHOOSE (a
+    folding row's fresh block, else the row's block), and the epilogue
+    fills what the row's schedule says (ops/sampling.py
+    ``unmask_tokens``): ``out [B, W + 1]``, the ids and bits of the block
+    that chose, or with ``sample=None`` (no row folds) its logits ``[B,
+    W, V]``."""
     W = cfg.block_length
-    masked = None
+    ids = masked = fold = None
     if kind == "block":
-        tokens, masked = tokens[:, :W], tokens[:, W]
-        start, rows = rows, jnp.full_like(rows, W)
+        ids, masked = tokens[:, :W], tokens[:, W]
+        fill = 0 if sample is None else sample["fill"]
+        fold = (masked == 0) & (fill > 0)
+        tokens = jnp.concatenate(
+            [ids, jnp.full_like(ids, cfg.mask_token_id)], axis=1)
+        start, rows = rows, jnp.where(fold, 2 * W, W).astype(rows.dtype)
     elif start is None:
         start = jnp.zeros_like(rows)
     with jax.named_scope("embed"):
@@ -524,13 +546,22 @@ def _block_step(fam, kind, params, cache_k, cache_v, tokens, rows,
         state = fam.close_state(state, work, step, cfg)
     if kind == "block_chunk" and sample is not None:
         return jnp.zeros(rows.shape, jnp.int32), cache_k, cache_v, state
+    pos = step.pos
     with jax.named_scope("head"):
+        if kind == "block":
+            # the W positions a row that choose, BEFORE the head: its
+            # product and the float32 logits stay ``rows x W``
+            pick = fold[:, None]
+            x = jnp.where(pick[..., None], x[:, W:], x[:, :W])
+            pos = jnp.where(pick, pos[:, W:], pos[:, :W])
+            tokens = jnp.where(pick, cfg.mask_token_id, ids)
+            masked = jnp.where(fold, (1 << W) - 1, masked)
         logits = fam.head(params, fam.final_norm(params, x, cfg), cfg)
     if sample is None:
         return logits, cache_k, cache_v, state
     with jax.named_scope("sample"):
         out = unmask_tokens(
-            logits, tokens, masked, step.pos, sample, cfg.mask_token_id,
+            logits, tokens, masked, pos, sample, cfg.mask_token_id,
             cfg.confidence_threshold)
     return out, cache_k, cache_v, state
 
@@ -540,7 +571,9 @@ def block_steps(fam: CachedFamily):
     ``(prefill, decode_step)`` under the names ``<fam.name>_prefill`` /
     ``_decode_step`` and with the arguments ``steps`` gives them
     (``_block_step`` says what differs: ``decode_step``'s ``tokens`` are
-    ``[B, block_length + 1]`` at the blocks' first ``positions``). No
+    ``[B, block_length + 1]`` at the blocks' first ``positions``, and the
+    step it traces is ``[B, 2 * block_length]`` wide: a row whose block is
+    finished carries the next one behind it). No
     verify step: there is nothing to draft for. The family's layers are a
     LIST with ``open_state`` / ``close_state``."""
 

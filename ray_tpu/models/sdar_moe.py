@@ -27,13 +27,22 @@ pass; the rest is the decoding loop (serve/llm/engine.py
       a PASS: logits of the block's B positions given the committed K/V and
         X; ``fill`` still-masked positions get their argmax (the logits AT
         a masked position choose THAT position's token: ``logit_position``)
-      when no position is masked: one more pass over X, the COMMIT, leaves
-        the block's K/V in the cache for good; its tokens go to the client
+      when no position is masked: one more pass over X leaves the block's
+        K/V in the cache for good (the COMMIT); its tokens go to the client
 
-A pass rewrites the block's ``B`` K/V rows past the committed frontier,
-whatever its ids: they are provisional until the commit pass has run, so a
-commit IS a denoising pass with nothing left to fill, and the family has ONE
-decode program over ids ``[rows, B]``, each row's phase data.
+A pass rewrites the K/V rows of what it carries past the committed
+frontier, whatever its ids: they are provisional until a pass has run over
+the block's FINISHED ids. That pass is not one of its own where another
+block is due: the finished block FOLDS into the next block's first pass, a
+row carrying ``[finished block | next block, all MASK]``, 2B positions
+under ``sees`` (the finished block's queries see the cache and their
+block, the fresh block's see both: one pass computes what a commit and a
+first denoising pass compute, from the same inputs). Only a request's
+last block is committed by a pass of its own that chooses nothing. The
+family has ONE decode program, the row's block ``[rows, B + 1]`` in and
+out (ids and masked bits), traced ``[rows, 2B]``; each row's phase is data
+(models/cached.py ``_block_step``: a row folds where no bit is set and
+its schedule fills on).
 
 Which positions a pass fills (``remasking``; ops/sampling.py
 ``unmask_tokens``): ``sequential`` the first ``n`` masked, left to right;
@@ -55,7 +64,8 @@ Same conventions as models/smallthinker.py (a LIST of per-layer trees,
 float32 masters, activations in ``cfg.dtype``, the cached step of
 models/cached.py, ``state`` the expert layers' counters alone), with
 ``cached.block_steps``: a prompt chunk and a block pass are the chunk step
-under the block mask; a pass runs the head on all ``B`` positions of a row.
+under the block mask; a pass runs the head on the ``B`` positions of a row
+that choose (a folding row's fresh block, else the row's block).
 """
 from __future__ import annotations
 

@@ -262,9 +262,16 @@ def unmask_tokens(
     ``low_confidence_static`` the ``fill`` of highest confidence (ties to
     the left); ``low_confidence_dynamic`` those, and every one whose
     confidence passes ``threshold``. Returns ``[B, W + 1]`` int32: the
-    block's ids with the chosen filled, then the bits still masked. A row
-    that came with NO masked position ran its commit pass: what it gets
-    back is its NEXT block, all ``mask_token_id`` and every bit set."""
+    block's ids with the chosen filled, then the bits still masked.
+
+    The block is the one that CHOOSES in the pass (models/cached.py
+    ``_block_step``): a row that folds a finished block into its next
+    block's first pass hands in the fresh block (all ``mask_token_id``,
+    every bit set) with the logits at ITS positions, and is filled like
+    any other. A row that comes with NO masked position ran a commit pass
+    of its own (a request's last block): it chooses nothing, and what it
+    gets back is a block of all ``mask_token_id`` with every bit set,
+    which nothing reads."""
     B, W, V = logits.shape
     offs = jnp.arange(W, dtype=jnp.int32)
     is_masked = ((masked[:, None] >> offs[None, :]) & 1) != 0

@@ -490,17 +490,22 @@ class _BlockRow:
     ``counts``: positions a pass fills (ops/sampling.py ``fill_counts``,
     the request's steps or the model's); ``mode``: the index of its order
     in ``REMASKING`` there.
-    ``start``: the first position of the block the next pass works on: the
-    committed frontier, in whole blocks; it moves by a block, at the
-    launch of a commit pass. ``lead``: the block's leading positions that
-    are the prompt's tail (the first block's ``len(prompt) % W``).
-    ``fills`` / ``p``: what each denoising pass of this block fills under
-    a static order (``whole``: of a block that is all masks), and the
-    passes launched for it (``p == len(fills)``: the commit is next). ``x``: the block's ids and the bits of its masked
-    positions ``[W + 1]`` as the LAST RECONCILED pass gave them back (at
-    first: the prompt's tail, then masks): what a pass is fed from where
-    the row rides no pass in flight, and what a commit's reconcile emits.
-    ``due``: the tokens the commits launched so far deliver."""
+    ``start``: the first position of the block the row's next pass
+    CHOOSES in: the committed frontier, in whole blocks; it moves by a
+    block at the launch of the pass that commits one (a fold, which
+    carries the finished block from ``start`` AND the fresh one behind
+    it, or a last block's commit). ``lead``: the block's leading
+    positions that are the prompt's tail (the first block's ``len(prompt)
+    % W``). ``fills`` / ``p``: what each denoising pass of this block
+    fills under a static order (``whole``: of a block that is all masks),
+    and the passes launched for it (``p == len(fills)``: the block is
+    finished; a fold leaves ``p`` at 1, being the fresh block's first
+    pass). ``x``: the ids and the bits of the masked positions ``[W +
+    1]`` of the block that chose in the LAST RECONCILED pass, as it gave
+    them back (at first: the prompt's tail, then masks): what a pass is
+    fed from where the row rides no pass in flight, and, kept from the
+    reconcile before, what a committing pass's reconcile emits. ``due``:
+    the tokens the committing passes launched so far deliver."""
 
     __slots__ = ("counts", "mode", "start", "lead", "fills", "whole", "p",
                  "x", "due")
@@ -521,6 +526,13 @@ class _BlockRow:
     def dynamic(self) -> bool:
         """The order under which the DEVICE says when a block is full."""
         return self.mode == 2
+
+    @property
+    def finished(self) -> bool:
+        """Whether the block holds no masked position: under a static
+        order the schedule says (every pass launched), under the dynamic
+        one the bits the last pass gave back do (the lag was collapsed)."""
+        return self.x[-1] == 0 if self.dynamic else self.p >= len(self.fills)
 
 
 class _StepTokens:
@@ -566,8 +578,8 @@ class _InFlight:
     fields: dict | None = None  # prefill: the flight record's shape fields
     index: dict | None = None   # request -> the row of its id, on demand
     ids_at: list | None = None  # packed prefill: the rows of ``batch``'s ids
-    # a block family's decode step: (commit pass?, the block's leading
-    # prompt positions, the tokens its commit delivers) a row
+    # a block family's decode step: (does the pass commit a block?, the
+    # block's leading prompt positions, the tokens it delivers) a row
     passes: list | None = None
 
     def row_of(self, r) -> int:
@@ -1118,12 +1130,15 @@ class LLMEngine:
         self.executor.phases = self._step_phases
         self.executor.spans = self._host_spans
         # ---- generation by diffusion over blocks ----
-        # row-passes launched, those that were commits; blocks committed,
+        # row-passes launched, those that chose no token (a request's
+        # last block's commit) and those that FOLDED (a finished block
+        # committed by the next block's first pass); blocks committed,
         # their tokens that reached a stream, and those generated and not
         # delivered (a last block's tail, what follows an EOS)
         self._block_counts = dict.fromkeys(
-            ("block_passes", "block_passes_commit", "blocks_committed",
-             "block_tokens_committed", "block_tokens_cut"), 0)
+            ("block_passes", "block_passes_commit", "block_passes_folded",
+             "blocks_committed", "block_tokens_committed",
+             "block_tokens_cut"), 0)
         if self._block_len:
             from ray_tpu.ops.sampling import (
                 REMASKING, fill_counts, pass_fills)
@@ -1469,10 +1484,11 @@ class LLMEngine:
     def _routed_dims(self, kind: str, ids_shape: tuple) -> tuple:
         """``(rows[, tokens a row])`` a step program routes through its
         expert layers, for ``stats()["moe_gmm_form"]``: the shape of its
-        ids, but a block pass's, which is a block a row whatever words ride
-        beside the ids."""
+        ids, but a block pass's, which is traced two blocks a row (a
+        finished block and the fresh one behind it: models/cached.py
+        ``_block_step``) whatever words ride beside the ids."""
         if kind == "decode" and self._block_len:
-            return (ids_shape[0], self._block_len)
+            return (ids_shape[0], 2 * self._block_len)
         return tuple(ids_shape)
 
     def _block_row(self, prompt: list,
@@ -3133,32 +3149,44 @@ class LLMEngine:
         """``_eligible_locked`` where a step carries a block a row: the
         rows whose launched commits do not yet deliver all they may. A
         row whose last commit is in flight is not launched again; one
-        that meets EOS inside a block is known only at that commit's
-        reconcile, and the pass launched behind it is wasted."""
+        that meets EOS inside a block is known only at the reconcile of
+        the pass that commits it, and what was launched behind the
+        finished block (a fold's second half, the pass after it) is
+        wasted."""
         return [r for r in self._running
                 if r.blk.due < r.sampling.max_new_tokens]
 
     def _decode_blocks_locked(self) -> None:
         """``_decode_locked`` for a family that generates by diffusion
-        over blocks: ONE pass of the family's decode program over a block
-        of ``W`` positions a row. A row is in one of its block's
-        denoising passes or in the commit pass; which, and how many
-        positions the pass fills, the HOST knows from the row's schedule
-        (``_BlockRow``) without reading the device, so the dispatch lag
-        stays: pass N + 1 is launched behind pass N with the rows' ids
-        and masked bits taken where they are, in pass N's on-device
-        result (the same rows in the same order: that array itself; rows
-        joined or left: one gather, ``executor.feed_rows``) or on the host
-        (a row whose prefill has just ended, or whose last pass has been
-        reconciled). The program rewrites the block's ``W`` K/V rows at
-        ``[start, start + W)`` every pass: they are PROVISIONAL, inside
-        the row's reservation and past its committed frontier, and stand
-        for good once the commit pass has run over the finished ids; the
-        frontier then moves by a block, at the commit's launch, and the
-        block's tokens reach the stream at its reconcile. Only a row under
-        ``low_confidence_dynamic`` (a block's length in passes is the
-        device's to say) collapses the lag first, each step, as a
-        grammar-constrained row does in ``_decode_locked``."""
+        over blocks: ONE pass of the family's decode program a row. A row
+        is in one of its block's denoising passes (the pass carries the
+        block, ``W`` positions), or its block is finished. A finished
+        block behind which another is due FOLDS: the row carries
+        ``[finished block | next block, all masks]``, ``2 W`` positions
+        from the finished block's start, and the pass that leaves the
+        finished block's K/V in the cache for good is the next block's
+        first denoising pass (models/cached.py ``_block_step``). Only a
+        request's LAST block (``max_new_tokens`` reached: nothing to
+        append) is committed by a pass of its own, which chooses no
+        token. So n blocks under T steps take ``T n + 1`` row-passes.
+        Which phase a row is in, and how many positions the pass fills,
+        the HOST knows from the row's schedule (``_BlockRow``) without
+        reading the device, so the dispatch lag stays: pass N + 1 is
+        launched behind pass N with the rows' ids and masked bits taken
+        where they are, in pass N's on-device result (the same rows in
+        the same order: that array itself; rows joined or left: one
+        gather, ``executor.feed_rows``) or on the host (a row whose
+        prefill has just ended, or whose last pass has been reconciled);
+        the program reads a fold from the row's own data, no bit set and
+        a ``fill`` above 0. The program rewrites the K/V rows of what a
+        row carries, from ``start``, every pass: they are PROVISIONAL,
+        inside the row's reservation and past its committed frontier,
+        and a block's stand for good once a pass has run over its
+        finished ids; the frontier then moves by a block, at that pass's
+        launch, and the block's tokens reach the stream at its reconcile.
+        Only a row under ``low_confidence_dynamic`` (a block's length in
+        passes is the device's to say) collapses the lag first, each
+        step, as a grammar-constrained row does in ``_decode_locked``."""
         chaos.fire("engine.decode", batch=len(self._running))
         self._step_kind = "decode"
         t0 = obs.clock()
@@ -3183,24 +3211,37 @@ class LLMEngine:
             self._apply_promotions_locked()
             pairs: list[tuple[int, int]] = []
             kv_tokens = 0
+            # a row: (the tokens its finished block delivers, None where
+            # the pass denoises; whether the next block rides behind it;
+            # the end of what the row carries)
+            plan = []
             for r in batch:
-                # the block's rows, committed or not, lie inside the
-                # row's reservation: a page is whole blocks
-                end = r.blk.start + W
+                k = r.blk
+                deliver, folds = None, False
+                if k.finished:
+                    due = k.due + min(
+                        W - k.lead, r.sampling.max_new_tokens - k.due)
+                    deliver = due - k.due
+                    folds = due < r.sampling.max_new_tokens
+                # what the row carries, committed or not, lies inside its
+                # reservation: a page is whole blocks, and a fold's second
+                # block exists only while tokens are due
+                end = k.start + (2 * W if folds else W)
+                plan.append((deliver, folds, end))
                 r.drawn_blocks += self.cache.ensure_capacity(r.id, end)
-                cow = self.cache.prepare_write(r.id, r.blk.start, end)
+                cow = self.cache.prepare_write(r.id, k.start, end)
                 r.drawn_blocks += len(cow)
                 pairs.extend(cow)
-                # what the kernel reads: the context to the block's END
+                # what the kernel reads: the context to the END of the
+                # last block the row carries
                 kv_tokens += -(-end // bs) * bs
             self._apply_copies_locked(pairs)
         with self._phase("engine.batch"):
             n = len(batch)
             B = pad_to_bucket(n, self._batch_buckets)
             ctx = pad_to_bucket(
-                max(max(r.blk.start + W,
-                        self.cache.num_allocated(r.id) * bs)
-                    for r in batch),
+                max(max(end, self.cache.num_allocated(r.id) * bs)
+                    for r, (_, _, end) in zip(batch, plan)),
                 self._length_buckets,
             )
             nb = self._table_blocks(ctx)
@@ -3219,7 +3260,7 @@ class LLMEngine:
             # result; a prefill step in flight holds no id of any row
             riding = steady and ahead.passes is not None
             passes = []
-            for i, r in enumerate(batch):
+            for i, (r, (deliver, folds, _)) in enumerate(zip(batch, plan)):
                 k = r.blk
                 positions[i] = k.start
                 tables[..., i, :] = self._table_for(r, nb, k.start)
@@ -3229,27 +3270,20 @@ class LLMEngine:
                 else:
                     feed[i, 0] = -1
                     feed[i, 1:] = k.x
-                # under a static order the schedule says which pass this
-                # is; under the dynamic one the lag was collapsed and the
-                # bits the last pass gave back do
-                commit = (k.x[W] == 0 if k.dynamic
-                          else k.p >= len(k.fills))
-                if commit:
-                    fill[i] = 0
-                    deliver = min(
-                        W - k.lead, r.sampling.max_new_tokens - k.due)
-                    passes.append((True, k.lead, deliver))
-                    # the host's view moves on at the launch: the next
-                    # pass works on the next block, all masks
-                    k.due += deliver
-                    k.start, k.lead, k.p = k.start + W, 0, 0
-                    k.fills = k.whole
-                else:
+                if deliver is None:
                     # (dynamic: AT LEAST so many, and one where the
                     # schedule has run out)
                     fill[i] = k.fills[k.p] if k.p < len(k.fills) else 1
                     passes.append((False, 0, 0))
                     k.p += 1
+                    continue
+                passes.append((True, k.lead, deliver))
+                # the host's view moves on at the launch: the next pass
+                # chooses in the next block; a fold was its first pass
+                k.due += deliver
+                k.start, k.lead, k.fills = k.start + W, 0, k.whole
+                fill[i] = k.fills[0] if folds else 0
+                k.p = int(folds)
             same = riding and batch == ahead.batch
             if same:
                 tokens_src, feed = ahead.tokens, None
@@ -3259,13 +3293,15 @@ class LLMEngine:
                 tokens_src, feed = feed[:, 1:], None
             sample = self._sample_args_locked(batch, B)
             sample.update(fill=fill, remasking=mode)
-        commits = [p for p in passes if p[0]]
+        folded = sum(folds for _, folds, _ in plan)
+        delivered = [d for d, _, _ in plan if d is not None]
         counts = self._block_counts
         counts["block_passes"] += n
-        counts["block_passes_commit"] += len(commits)
+        counts["block_passes_folded"] += folded
+        counts["block_passes_commit"] += len(delivered) - folded
         kv = {"kv_tokens": kv_tokens, "rows": n, "block_len": W,
-              "rows_commit": len(commits),
-              "tokens_committed": sum(p[2] for p in commits)}
+              "rows_commit": len(delivered) - folded, "rows_folded": folded,
+              "tokens_committed": sum(delivered)}
         span = {"kind": "decode", "seq": self._launched + 1, **kv}
         next_dev = self.executor.decode_step(
             tokens_src, positions, tables, sample=sample, span=span,
@@ -3285,22 +3321,23 @@ class LLMEngine:
         self._account_step_locked(
             "decode", dt, t0_wall, emitted, batch=n, bucket_b=B,
             bucket_len=ctx, nb=nb, tokens=emitted, **kv,
-            gmm_form=self._gmm_form(B * W), steady=steady,
+            gmm_form=self._gmm_form(B * 2 * W), steady=steady,
             remapped=remapped, trace_ids=self._trace_ids_locked(batch),
         )
 
     def _emit_blocks_locked(self, rec: _InFlight, toks) -> int:
         """``_emit_decoded_locked`` for a pass over blocks: ``toks [B, W +
-        1]`` is what the pass gave back a row (its ids, its masked bits),
-        kept as the row's ``x``. A row whose pass was its block's COMMIT
-        puts the block on its stream, in one go under the step's one
-        timestamp: the ids its last denoising pass left (the ``x`` kept
-        at that pass's reconcile), from behind the prompt's tail, cut at
+        1]`` is what the pass gave back a row (the ids and masked bits of
+        the block that chose), kept as the row's ``x``. A row whose pass
+        COMMITTED a block (a fold, or a last block's commit) puts that
+        block on its stream, in one go under the step's one timestamp:
+        the ids its last denoising pass left (the ``x`` kept at that
+        pass's reconcile), from behind the prompt's tail, cut at
         ``max_new_tokens`` or behind an EOS or a stop sequence (what is
-        cut was generated and is not delivered: ``block_tokens_cut``). The
-        gaps between a block's tokens are what the client sees, 0, and
-        are booked so; a reconciled step emits 0 or up to W tokens a
-        row."""
+        cut was generated and is not delivered: ``block_tokens_cut``; a
+        fold's fresh block is then dropped with the row). The gaps
+        between a block's tokens are what the client sees, 0, and are
+        booked so; a reconciled step emits 0 or up to W tokens a row."""
         book = _StepTokens()
         W, counts = self._block_len, self._block_counts
         for r, x, (commit, lead, deliver) in zip(

@@ -862,8 +862,11 @@ class ModelExecutor:
     def _generation_report(self) -> dict:
         """``{"generation": ..}`` for a family that generates by diffusion
         over blocks, with the model configuration's settings (a request
-        may override the steps and the order); nothing for a family that
-        yields one token a row a decode step."""
+        may override the steps and the order) and the positions a row the
+        decode program is TRACED with (two blocks: a finished block rides
+        its next block's first pass; a row that carries one block has the
+        other's columns as padding); nothing for a family that yields one
+        token a row a decode step."""
         if not get_family(self.family).block_steps:
             return {}
         cfg = self.model_cfg
@@ -871,7 +874,8 @@ class ModelExecutor:
             "kind": "block_diffusion", "block_length": cfg.block_length,
             "denoising_steps": cfg.denoising_steps or cfg.block_length,
             "remasking": cfg.remasking,
-            "confidence_threshold": cfg.confidence_threshold}}
+            "confidence_threshold": cfg.confidence_threshold,
+            "step_positions": 2 * cfg.block_length}}
 
     def describe(self) -> dict:
         """Stable summary for stats()/debug_dump()/benchmarks: which

@@ -1740,20 +1740,32 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
     assert re.search(r"%params__layers___1___kda_w_qkv__", entry)
 
 
+# the sdar cell's block pass (ids and bits [128, 5] under tables [128, 640]):
+# re-recorded by ISSUE 55, whose change it is (a row carries a finished block
+# and the fresh one behind it: the step is traced [128, 8] and the head runs
+# on the 4 positions a row that choose). Under ``PARENTS_JAX`` as the others.
+SDAR_DECODE_TEXT = "c0f407c3b381296b"
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk"])
 def test_sdar_moe_step_programs_compile_at_published_widths(
         one_chip, monkeypatch, kind):
     """The sdar cell's step programs as the executor compiles them, WITH
     their epilogue, at the cell's own shapes: the 128-row block pass (ids
     and bits ``[128, 5]`` over tables ``[128, 640]``: the 10,240-token
-    bucket) and the packed chunk's widest rung (16 pieces of 128). The
-    pool is lane-dense ``[6, num_blocks, 16, 512]``: both arrays are in
-    the program's ``input_output_alias`` and nothing pool-sized is among
-    its temporaries; every layer calls ``paged_attention`` ONCE (a block
-    pass is the decode kernel's situation at 32 query rows a K/V head: no
-    kernel of its own) and has its grouped product over all 128 experts'
-    matrices as stored; the block pass runs the head on all 512 positions
-    (the float32 logits are its largest temporary)."""
+    bucket; traced ``[128, 8]``: a finished block and the fresh one behind
+    it, ISSUE 55) and the packed chunk's widest rung (16 pieces of 128).
+    The pool is lane-dense ``[6, num_blocks, 16, 512]``: both arrays are
+    in the program's ``input_output_alias`` and nothing pool-sized is
+    among its temporaries; every layer calls ``paged_attention`` ONCE (a
+    block pass is the decode kernel's situation at 64 query rows a K/V
+    head: no kernel of its own) and has its grouped product over all 128
+    experts' matrices as stored, ONE ``moe_gmm_few_rows`` call; the block
+    pass runs the head on the 512 positions that CHOOSE, 4 a row (the
+    float32 logits are its largest temporary: nothing ``[128, 8,
+    151936]`` exists), and its temporaries are the 0.57 GB they were
+    before a row carried two blocks. ``SDAR_DECODE_TEXT`` records the
+    block pass's text (re-recorded by ISSUE 55, which changed it)."""
     import sys
 
     import jax
@@ -1828,7 +1840,20 @@ def test_sdar_moe_step_programs_compile_at_published_widths(
     assert len(_gmm_calls(entry)) == (6 if kind == "decode" else 5)
     assert "ragged-dot" not in entry
     if kind == "decode":
+        # ONE head product, over rows x W positions: no value of the
+        # vocabulary's width spans a row's 2 W traced positions
         assert "f32[128,4,151936]" in text or "f32[512,151936]" in text
+        for wide in ("[128,8,151936]", "[1024,151936]"):
+            assert wide not in text, wide
+        assert len(re.findall(
+            r"= f32\[(?:128,4|512),151936\]\S* (?:convolution|dot)\(",
+            text)) == 1
+        # the traced step is 1,024 positions: 8,192 sorted pairs a layer
+        assert "[8192,2048]" in text
+        assert 0.5e9 < mem.temp_size_in_bytes < 0.65e9
+        if jax.__version__ == PARENTS_JAX:
+            assert _program_text_sha(text) == SDAR_DECODE_TEXT, \
+                _program_text_sha(text)
         for needle in ("moe_route_w", "moe_gmm_w_in"):
             assert re.search(
                 rf"\(.*%params__layers___\d___{needle}__", entry), needle

@@ -7,8 +7,10 @@ logits of every choosing pass, the engine (every ``len(prompt) % 4``, every
 output residue, 1 / 2 / 4 steps, the three orders, a request that overrides
 them, batched against solo, the mask's id as an ordinary token, prefix
 reuse at page boundaries, EOS inside a block, a cancel mid-block, the
-dispatch lag kept), what the reference notices, what the engine refuses,
-and the counters.
+dispatch lag kept), the FOLD (a finished block committed by the next
+block's first pass: its K/V bit for bit a separate commit's, the schedule's
+pass counts, what a fold wastes and gives back), what the reference
+notices, what the engine refuses, and the counters.
 
 Program and reference in float32 compute the same mathematics and differ in
 the order of sums: 1e-4 on logits of size ~4 (seen 5e-6).
@@ -392,6 +394,92 @@ def test_cached_passes_match_generates_choosing_logits(tiny, ref):
             assert np.abs(chose[len(p) + j] - g["logits"][j]).max() < 1e-4
 
 
+def _greedy(fill, mode=0):
+    """``sample`` for rows that fill ``fill`` positions, greedy."""
+    n = len(fill)
+    return {"seeds": np.zeros(n, np.uint32),
+            "temperature": np.zeros(n, np.float32),
+            "top_k": np.zeros(n, np.int32), "top_p": np.ones(n, np.float32),
+            "fill": np.asarray(fill, np.int32),
+            "remasking": np.full(n, mode, np.int32)}
+
+
+def test_a_fold_commits_what_a_pass_of_its_own_commits(tiny):
+    """The decode program both ways on ONE pool, float32: row 0's block is
+    finished and another is due. Apart: a commit pass (``fill`` 0), then
+    the next block's first pass over all masks. Folded: one pass, the
+    finished ids with no bit set and ``fill`` 2. The finished block's K/V
+    rows in the pool are the same BIT FOR BIT, and so are the fresh
+    block's provisional rows, its chosen ids and its bits. Row 1 is
+    mid-block beside it (a denoising row: its other W columns padding) and
+    gets the same either way; the experts count VALID positions alone."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import sdar_moe
+    from ray_tpu.models.sdar_moe import sdar_moe_counters, sdar_moe_init_state
+    from ray_tpu.ops.paged_attention import pool_shape
+
+    cfg, params = tiny
+    prefill = jax.jit(functools.partial(sdar_moe.sdar_moe_prefill, cfg=cfg))
+    decode = jax.jit(functools.partial(sdar_moe.sdar_moe_decode_step, cfg=cfg))
+    prompts = _prompts([12, 16], seed=11)
+    bs = 8
+    k = jnp.zeros(pool_shape(cfg.n_layer, 9, bs, cfg.n_kv_head,
+                             cfg.head_dim), jnp.float32)
+    v = jnp.zeros_like(k)
+    state = sdar_moe_init_state(cfg, 0)
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]])
+    slots = jnp.ones((2,), jnp.int32)
+    toks = np.zeros((2, 16), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    _, k, v, state = prefill(
+        params, k, v, jnp.asarray(toks), jnp.asarray([12, 16], jnp.int32),
+        tables, start=jnp.zeros((2,), jnp.int32), sample=_greedy([0, 0]),
+        state=state, slots=slots)
+    starts = jnp.asarray([12, 16], jnp.int32)
+    x = jnp.asarray([[MASK_ID] * W + [0b1111]] * 2, jnp.int32)
+    # row 0: two denoising passes finish its block; row 1 is held back a
+    # pass (``fill`` 0 over a masked block changes nothing)
+    for fill in ([2, 0], [2, 2]):
+        x, k, v, state = decode(params, k, v, x, starts, tables,
+                                sample=_greedy(fill), state=state, slots=slots)
+    assert x[0, W] == 0 and x[1, W] == 0b1100
+    pairs0 = int(sdar_moe_counters(state)["moe_pairs_decode"])
+    # apart: the commit (row 1 denoises on), then the fresh block's pass
+    xa, ka, va, sa = decode(params, k, v, x, starts, tables,
+                            sample=_greedy([0, 2]), state=state, slots=slots)
+    assert xa[0].tolist() == [MASK_ID] * W + [0b1111] and xa[1, W] == 0
+    committed = (np.asarray(ka), np.asarray(va))
+    # (row 1 sits this one out: a table of the garbage block, slot 0)
+    xa2, ka, va, _ = decode(
+        params, ka, va, jnp.asarray([[MASK_ID] * W + [0b1111]] * 2),
+        jnp.asarray([16, 0], jnp.int32), tables.at[1].set(0),
+        sample=_greedy([2, 0]), state=sa, slots=jnp.asarray([1, 0]))
+    # folded: one pass
+    xb, kb, vb, sb = decode(params, k, v, x, starts, tables,
+                            sample=_greedy([2, 2]), state=state, slots=slots)
+    assert xb[0].tolist() == xa2[0].tolist() and xb[0, W] == 0b1100
+    assert xb[1].tolist() == xa[1].tolist()
+    for apart, folded, first in ((committed[0], kb, ka), (committed[1], vb, va)):
+        folded = np.asarray(folded)
+        # the finished block [12, 16): page 2's second half; the denoising
+        # row's block [16, 20): page 7's first half. A commit's, exactly
+        assert np.array_equal(folded[:, 2, 4:], apart[:, 2, 4:])
+        assert np.array_equal(folded[:, 7, :4], apart[:, 7, :4])
+        assert np.abs(folded[:, 2, 4:]).max() > 0
+        # the fresh block [16, 20): page 3's first half, as its own first
+        # pass wrote it; nothing else of a real page moved
+        assert np.array_equal(folded[:, 3, :4], np.asarray(first)[:, 3, :4])
+        assert np.array_equal(folded[:, 1:], np.asarray(first)[:, 1:])
+    # 8 + 4 valid positions were routed, the padding columns nowhere
+    assert int(sdar_moe_counters(sb)["moe_pairs_decode"]) - pairs0 == (
+        12 * cfg.top_k * cfg.n_layer)
+
+
 # ----------------------------------------------------- the engine, served
 
 
@@ -589,19 +677,11 @@ def test_the_stepping_thread_serves_what_hand_steps_do(tiny, ref):
 # ------------------------------------------------- counters, spans, clocks
 
 
-def test_counters_spans_and_clocks_count_blocks(tiny, monkeypatch):
-    """``stats()`` counts row-passes, commits, blocks and tokens (cut ones
-    apart); a block step's ``executor.dispatch`` span says ``kind``
-    ``decode``, its rows, the contexts to the blocks' END in whole pages,
-    the block's length, the rows that commit and the tokens they deliver;
-    a committed block reaches its stream under ONE timestamp (TTFT is the
-    first block's; the gaps inside a block are 0), and the expert
-    counters count a pass's pairs as decode."""
-    from benchmark.layer_metrics.block_attn_hbm_pct import block_attn_bytes
+def _spy_on_dispatch(monkeypatch):
+    """The attributes of every ``executor.dispatch`` span from here on."""
+    import ray_tpu.serve.llm.executor as executor
     from ray_tpu.serve.llm import obs
 
-    cfg, params = tiny
-    eng = _engine(cfg, params)
     spans = []
     real = obs.phase
 
@@ -611,9 +691,25 @@ def test_counters_spans_and_clocks_count_blocks(tiny, monkeypatch):
         return real(table, name, **attrs)
 
     monkeypatch.setattr(obs, "phase", spy)
-    import ray_tpu.serve.llm.executor as executor
-
     monkeypatch.setattr(executor.obs, "phase", spy)
+    return spans
+
+
+def test_counters_spans_and_clocks_count_blocks(tiny, monkeypatch):
+    """``stats()`` counts row-passes, those that chose no token (a
+    request's last commit), those that folded, blocks and tokens (cut
+    ones apart); a block step's ``executor.dispatch`` span says ``kind``
+    ``decode``, its rows, the contexts to the END of what the rows carry
+    (a folding row's: its NEXT block's) in whole pages, the block's
+    length, the rows that commit alone, the rows that fold and the tokens
+    both deliver; a committed block reaches its stream under ONE
+    timestamp (TTFT is the first block's; the gaps inside a block are 0),
+    and the expert counters count a pass's VALID positions as decode."""
+    from benchmark.layer_metrics.block_attn_hbm_pct import block_attn_bytes
+
+    cfg, params = tiny
+    eng = _engine(cfg, params)
+    spans = _spy_on_dispatch(monkeypatch)
     prompts = _prompts([6, 9], seed=3)
     streams = [eng.submit(p, max_new_tokens=n)
                for p, n in zip(prompts, [7, 9])]
@@ -623,32 +719,226 @@ def test_counters_spans_and_clocks_count_blocks(tiny, monkeypatch):
     assert st["blocks_committed"] == 6
     assert st["block_tokens_committed"] == 16 == st["host"]["emit_rows"]
     assert st["block_tokens_cut"] == 3 + 2
-    assert st["block_passes_commit"] == 6
-    # passes: a first block of 2 masked takes 1 + commit, of 3: 2 + commit
-    assert st["block_passes"] == (2 + 3 + 3) + (3 + 3 + 3)
-    assert st["moe_pairs_decode"] == st["block_passes"] * W * cfg.top_k \
+    # a request's last block alone is committed by a pass of its own
+    assert st["block_passes_commit"] == 2
+    assert st["block_passes_folded"] == 2 + 2
+    # 2 passes a block and the last commit; a first block of 2 masked
+    # takes 1 pass, of 3: 2
+    assert st["block_passes"] == (1 + 2 + 2 + 1) + (2 + 2 + 2 + 1)
+    # a folding row routes two blocks' positions
+    assert st["moe_pairs_decode"] == (
+        st["block_passes"] + st["block_passes_folded"]) * W * cfg.top_k \
         * cfg.n_layer
     assert st["executor"]["generation"] == {
         "kind": "block_diffusion", "block_length": 4, "denoising_steps": 2,
-        "remasking": "sequential", "confidence_threshold": 0.02}
-    assert st["moe_gmm_form"]["decode@4x4"] == "ragged"
+        "remasking": "sequential", "confidence_threshold": 0.02,
+        "step_positions": 8}
+    assert st["moe_gmm_form"]["decode@4x8"] == "ragged"
     decodes = [s for s in spans if s.get("kind") == "decode"]
     assert decodes and all(s["block_len"] == W for s in decodes)
     assert sum(s["rows"] for s in decodes) == st["block_passes"]
-    assert sum(s["rows_commit"] for s in decodes) == 6
+    assert sum(s["rows_commit"] for s in decodes) == 2
+    assert sum(s["rows_folded"] for s in decodes) == 4
     assert sum(s["tokens_committed"] for s in decodes) == 16
     # the first pass: blocks [4, 8) and [8, 12): one and two pages of 8
     assert decodes[0]["kv_tokens"] == 8 + 16
+    assert decodes[0]["rows_folded"] == 0
+    # the second: row 0 folds, [4, 8) and [8, 12) behind it: to 12, two
+    # pages; row 1 fills the last position of [8, 12)
+    assert decodes[1]["rows_folded"] == 1
+    assert decodes[1]["kv_tokens"] == 16 + 16
     assert block_attn_bytes(24, cfg.n_kv_head, cfg.head_dim, 4,
                             cfg.n_layer) == 24 * 2 * 2 * 16 * 4 * 3
     records = [r for r in eng.debug_dump()["steps"]
                if r.get("kind") == "decode" and r.get("batch")]
-    assert all(r["block_len"] == W and "rows_commit" in r for r in records)
+    assert all(r["block_len"] == W and "rows_commit" in r
+               and "rows_folded" in r for r in records)
+    assert sum(r["rows_folded"] for r in records) == 4
     for s in streams:
         tl = eng.request_timeline(s.request_id)
         stamps = [e["ts"] for e in tl["events"]
                   if e["event"] in ("first_token", "token")]
         assert len(stamps) == len(list(s)) and len(set(stamps)) == 3
+    eng.shutdown()
+
+
+# ----------------------------------- the fold: the schedule and its wastes
+
+
+ORDERS = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_a_request_takes_its_steps_a_block_and_one_commit(
+        tiny, ref, engine, monkeypatch, steps, order):
+    """n blocks under T steps take ``T n + 1`` row-passes (less what a
+    first block's prompt tail saves: it has fewer masks to fill): every
+    block but the last is committed by the NEXT block's first pass
+    (``block_passes_folded`` n - 1 a request), the last by a pass of its
+    own that chooses nothing (``block_passes_commit`` 1 a request). Under
+    ``low_confidence_dynamic`` the device ends a block early where
+    confidences pass the threshold, so T n + 1 bounds the passes from
+    above; the folds and the commits are counted the same. Three requests
+    of different tails ride one batch, so a step holds a folding row
+    beside a denoising one beside a last commit; every stream is the
+    reference's."""
+    from ray_tpu.ops.sampling import fill_counts, pass_fills
+
+    cfg, params = tiny
+    lens, news = [8, 6, 11], [13, 9, 12]
+    prompts = _prompts(lens, seed=60 + steps)
+    spans = _spy_on_dispatch(monkeypatch)
+    before = engine.stats()
+    got = _serve(engine, prompts, news, denoising_steps=steps,
+                 remasking=order)
+    after = engine.stats()
+    moved = {name: after[name] - before[name] for name in (
+        "block_passes", "block_passes_commit", "block_passes_folded",
+        "blocks_committed", "block_tokens_committed", "moe_pairs_decode")}
+    counts = fill_counts(W, steps)
+    blocks = [-(-(n + L % W) // W) for L, n in zip(lens, news)]
+    most = sum(steps * b + 1 - (steps - len(pass_fills(W - L % W, counts)))
+               for b, L in zip(blocks, lens))
+    assert moved["block_passes_commit"] == len(lens)
+    assert moved["block_passes_folded"] == sum(blocks) - len(lens)
+    assert moved["blocks_committed"] == sum(blocks)
+    assert moved["block_tokens_committed"] == sum(news)
+    if order == "low_confidence_dynamic":
+        assert sum(blocks) + len(lens) <= moved["block_passes"] <= most
+    else:
+        assert moved["block_passes"] == most
+    assert moved["moe_pairs_decode"] == (
+        moved["block_passes"] + moved["block_passes_folded"]) * W \
+        * cfg.top_k * cfg.n_layer
+    decodes = [s for s in spans if s.get("kind") == "decode"]
+    assert sum(s["rows_folded"] for s in decodes) == sum(blocks) - len(lens)
+    # rows of one batch at different phases: a folding row beside one
+    # that does not; under 2 steps these lengths put a folding row, a
+    # denoising row and a last commit into ONE step
+    assert any(0 < s["rows_folded"] < s["rows"] for s in decodes)
+    if steps == 2:
+        assert any(s["rows_folded"] and s["rows_commit"]
+                   and s["rows"] > s["rows_folded"] + s["rows_commit"]
+                   for s in decodes)
+    for p, n, out in zip(prompts, news, got):
+        assert out == ref.generate(params, p, n, cfg, steps=steps,
+                                   remasking=order, pad_to=PAD)["tokens"]
+    assert after["kv_used_blocks"] == 0
+
+
+def _watch_writes(eng):
+    """Every ``(request, end)`` the engine asks pages for and every
+    ``(request, start, end)`` it prepares to write, from here on."""
+    asked, wrote = [], []
+    ensure, prepare = eng.cache.ensure_capacity, eng.cache.prepare_write
+
+    def ensure_capacity(req, end):
+        asked.append((req, end))
+        return ensure(req, end)
+
+    def prepare_write(req, start, end):
+        wrote.append((req, start, end))
+        return prepare(req, start, end)
+
+    eng.cache.ensure_capacity = ensure_capacity
+    eng.cache.prepare_write = prepare_write
+    return asked, wrote
+
+
+@pytest.mark.parametrize("lens,news", [
+    ([9], [6]), ([8], [5]), ([7], [1]), ([5, 12], [11, 4])])
+def test_no_fold_writes_past_the_last_block(tiny, ref, lens, news):
+    """``max_new_tokens`` ending inside a block: the block is the LAST, a
+    pass of its own commits it and nothing rides behind it, so no write
+    reaches past the block that holds the request's last token, which is
+    inside the worst-case reservation (a page is whole blocks); what the
+    last block generated past the limit is counted as cut."""
+    cfg, params = tiny
+    eng = _engine(cfg, params)
+    asked, wrote = _watch_writes(eng)
+    prompts = _prompts(lens, seed=70)
+    streams = [eng.submit(p, max_new_tokens=n)
+               for p, n in zip(prompts, news)]
+    last = {s.request_id: -(-(L + n) // W) * W
+            for s, L, n in zip(streams, lens, news)}
+    reserved = {s.request_id: -(-(L + n) // 8) * 8
+                for s, L, n in zip(streams, lens, news)}
+    _drive(eng, streams)
+    while eng.step():
+        pass
+    for p, n, s in zip(prompts, news, streams):
+        assert list(s) == ref.generate(params, p, n, cfg, pad_to=PAD)["tokens"]
+    assert {req for req, _ in asked} == set(last)
+    for req, end in asked:
+        assert end <= last[req] <= reserved[req]
+    whole = {s.request_id: L // W * W for s, L in zip(streams, lens)}
+    wrote = [w for w in wrote if w[1] >= whole[w[0]]]  # the passes' own
+    for req, start, end in wrote:
+        assert end <= last[req] and end - start in (W, 2 * W)
+    assert any(end - start == 2 * W for _, start, end in wrote) == any(
+        -(-(n + L % W) // W) > 1 for L, n in zip(lens, news))
+    st = eng.stats()
+    assert st["block_tokens_cut"] == sum(
+        last[s.request_id] - L - n for s, L, n in zip(streams, lens, news))
+    assert st["kv_used_blocks"] == 0 and st["decode_inflight"] == 0
+    eng.shutdown()
+
+
+def test_eos_found_at_a_folds_reconcile_wastes_the_fresh_block(tiny, ref):
+    """EOS inside a finished block is found where the block is committed:
+    at the reconcile of the FOLD that carried it. The fold's second half
+    (the fresh block's first pass) and the pass launched behind it are
+    wasted, the row's provisional rows go back, and what the finished
+    block held past the EOS is counted as cut."""
+    cfg, params = tiny
+    prompt = _prompts([8], seed=5)[0]
+    want = ref.generate(params, prompt, 16, cfg, pad_to=PAD)["tokens"]
+    eos = next(t for t in want[4:8] if t not in want[:4])
+    at = want.index(eos)
+    eng = _engine(cfg, params, eos_id=eos)
+    asked, _ = _watch_writes(eng)
+    assert _serve(eng, [prompt], [16])[0] == want[:at + 1]
+    while eng.step():
+        pass
+    st = eng.stats()
+    # block 0 was folded into block 1's first pass, block 1 (the EOS's)
+    # into block 2's: that fold and the pass behind it gave nothing
+    assert st["blocks_committed"] == 2 and st["block_passes_folded"] == 2
+    assert st["block_passes_commit"] == 0
+    assert st["block_passes"] == 2 + 2 + 2
+    assert st["block_tokens_cut"] == 2 * W - (at + 1)
+    assert max(end for _, end in asked) == 8 + 3 * W
+    assert st["kv_used_blocks"] == 0 and st["decode_inflight"] == 0
+    eng.shutdown()
+
+
+def test_a_row_cancelled_during_a_fold_drops_both_blocks(tiny):
+    """A cancel with a fold in flight: up to 2 W provisional positions
+    (the finished block, not yet delivered, and the fresh one) go back
+    with the row's pages; no token of the finished block reaches the
+    stream after the cancel."""
+    from ray_tpu.exceptions import RequestCancelledError
+
+    cfg, params = tiny
+    eng = _engine(cfg, params)
+    prompt = _prompts([12], seed=9)[0]
+    stream = eng.submit(prompt, max_new_tokens=40)
+    for _ in range(40):
+        eng.step()
+        st = eng.stats()
+        if st["block_passes_folded"] and st["decode_inflight"]:
+            break
+    assert st["block_passes_folded"] == 1 and st["blocks_committed"] == 0
+    assert st["kv_used_blocks"] > 0
+    assert eng.cancel(stream.request_id)
+    while eng.step():
+        pass
+    st = eng.stats()
+    assert st["kv_used_blocks"] == 0 and st["decode_inflight"] == 0
+    assert st["block_tokens_committed"] == 0
+    with pytest.raises(RequestCancelledError):
+        list(stream)
     eng.shutdown()
 
 
