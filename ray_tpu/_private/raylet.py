@@ -250,7 +250,12 @@ class Raylet:
                 }
                 disk = self._fs_monitor.usage_fraction()
                 if disk is not None:
-                    hb["disk_used_frac"] = disk
+                    # in hundredths, as the threshold is stated and as
+                    # state.py prints it: the GCS re-versions a node whose
+                    # report changed, and the unrounded fraction changes
+                    # with every write to the disk — a settled cluster's
+                    # delta was then all N nodes every tick
+                    hb["disk_used_frac"] = round(disk, 2)
                 reply = self.gcs.call("heartbeat", hb)
                 if reply.get("reregister"):
                     # the GCS restarted and lost the node table — re-announce

@@ -3,7 +3,7 @@
 A served family runs these kinds of step against the paged K/V pool
 (ops/kv_cache.py; serve/llm drives them): ``prefill`` of a right-padded
 prompt chunk, ``decode_step`` of one token a row (an autoregressive family)
-or of one BLOCK a row (``block_steps``, at the file's end), ``verify_step``
+or of one BLOCK a row (``_block_steps``, at the file's end), ``verify_step``
 of a speculative window. They are ONE step: tokens ``[B, S]`` at true
 positions, every layer writing the chunk's K/V rows into the pool at its
 own layer index and attending over the paged context there, then the head
@@ -52,6 +52,19 @@ only what is the family's own, in a ``CachedFamily``:
   Absent: what ``layer`` returns is the next state (None where None was
   given).
 
+Beside how its step is built, the record says what the serving layer asks
+of the family and could only copy from here. serve/llm/decode.py READS its
+``Family`` from the module's record (``FAMILY``) and the module's names
+(``<name>_init``, ``_prefill``, ``_decode_step``, ``_verify_step``,
+``_param_axes``, ``_quant_axes``, ``_init_state``, ``_counters``), and its
+``Family`` docstring says what each of these means to the engine:
+``config`` (the config class; its ``tiny()`` is the default config),
+``no_verify`` (WHY the family has no verify step, for which its module
+then keeps no name; None: it has one), ``state_rows``,
+``block_state_bytes``, ``step_attrs``, ``gmm_form``,
+``donated_state_counters`` and ``block_steps`` (``steps`` then builds
+``_block_step``'s, at the file's end).
+
 Every step takes ``state=None, slots=None`` by keyword and returns ``(out,
 cache_k', cache_v', state')``: None is an empty pytree to ``jax.jit``, so
 a family without state has neither among its program's parameters.
@@ -89,6 +102,7 @@ from ray_tpu.ops.sparse_select import (
 @dataclass(frozen=True)
 class CachedFamily:
     name: str  # the programs are jit_<name>_prefill / _decode_step / ...
+    config: type
     stack: str
     embed: Callable
     layer: Callable
@@ -97,6 +111,13 @@ class CachedFamily:
     open_state: Callable | None = None
     close_state: Callable | None = None
     place: Callable | None = None
+    no_verify: str | None = None
+    state_rows: bool = True
+    block_state_bytes: Callable | None = None
+    step_attrs: Callable | None = None
+    gmm_form: Callable | None = None
+    donated_state_counters: tuple | None = None
+    block_steps: bool = False
 
 
 class Step(NamedTuple):
@@ -368,6 +389,9 @@ def _step(fam, kind, params, cache_k, cache_v, tokens, rows, block_tables,
 def steps(fam: CachedFamily):
     """The family's (prefill, decode_step, verify_step), each named
     ``<fam.name>_<step>``: a jitted program takes its name from there.
+    A family that says why it has no verify step (``fam.no_verify``) keeps
+    no name for the third; a family that generates by diffusion over blocks
+    (``fam.block_steps``) gets ``_block_steps``'s two and None.
 
     All take ``(params, cache_k, cache_v, ...)``, the pool lane-dense
     ``[n_kv_layer, num_blocks, block_size, n_kv_head * head_dim]`` (a
@@ -411,6 +435,9 @@ def steps(fam: CachedFamily):
     cover ``draft_len`` positions past the frontier. ``out``: the packed
     verdicts ``[B, W + 1]`` int32 of ``verify_tokens``, or with
     ``sample=None`` the window's logits ``[B, W, V]`` float32."""
+
+    if fam.block_steps:
+        return (*_block_steps(fam), None)
 
     def prefill(params, cache_k, cache_v, tokens, lengths, block_tables,
                 cfg, start=None, sample=None, *, state=None, slots=None):
@@ -566,7 +593,7 @@ def _block_step(fam, kind, params, cache_k, cache_v, tokens, rows,
     return out, cache_k, cache_v, state
 
 
-def block_steps(fam: CachedFamily):
+def _block_steps(fam: CachedFamily):
     """``steps`` for a family that generates by diffusion over blocks:
     ``(prefill, decode_step)`` under the names ``<fam.name>_prefill`` /
     ``_decode_step`` and with the arguments ``steps`` gives them

@@ -304,7 +304,8 @@ def _head(params, h, cfg: EvaByteConfig):
         preferred_element_type=jnp.float32)
 
 
-evabyte_prefill, evabyte_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "evabyte", "blocks", _cached_embed, _cached_layer, _final_norm,
-        _head, place=_place))
+FAMILY = cached.CachedFamily(
+    "evabyte", EvaByteConfig, "blocks", _cached_embed, _cached_layer,
+    _final_norm, _head, place=_place,
+    no_verify="a rejected draft would already be in its chunk's sum")
+evabyte_prefill, evabyte_decode_step, _ = cached.steps(FAMILY)

@@ -377,9 +377,10 @@ def _head(params, h, cfg: GPTConfig):
     )
 
 
-gpt_prefill, gpt_decode_step, gpt_verify_step = cached.steps(
-    cached.CachedFamily(
-        "gpt", "blocks", _cached_embed, _cached_layer, _final_norm, _head))
+FAMILY = cached.CachedFamily(
+    "gpt", GPTConfig, "blocks", _cached_embed, _cached_layer, _final_norm,
+    _head)
+gpt_prefill, gpt_decode_step, gpt_verify_step = cached.steps(FAMILY)
 
 
 def gpt_num_params(cfg: GPTConfig) -> int:

@@ -56,10 +56,18 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
-from ray_tpu.models.lfm2_moe import _count_add, _swiglu, count_value
-from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.models.parts import (
+    close_experts,
+    count_value,
+    final_norm,
+    head_untied,
+    leaf_tree,
+    open_experts,
+    swiglu,
+    windowed_attention,
+)
 from ray_tpu.ops.layers import rms_norm, rope_partial, yarn_inv_freq
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 
 LAYER_KINDS = ("full_attention", "sliding_attention")
 QK_GAIN = 1.4  # ``laguna_init``: wq and wk against fan_in ** -0.5
@@ -267,37 +275,27 @@ _LEAF_AXES = {
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
     "moe_shared_w_in": ("embed", "mlp"), "moe_shared_w_out": ("mlp", "embed"),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, and the router and the gate, which are read in float32)
 _LEAF_QUANT = {
     "wq": 0, "wk": 0, "wv": 0, "wo": 0, "mlp_in": 0, "mlp_out": 0,
     "moe_gmm_w_in": 1, "moe_gmm_w_out": 1,
-    "moe_shared_w_in": 0, "moe_shared_w_out": 0,
+    "moe_shared_w_in": 0, "moe_shared_w_out": 0, "wte": 1, "lm_head": 0,
 }
-
-
-def _leaf_tree(cfg: LagunaConfig, leaf, wte, ln_f, head) -> dict:
-    shape = jax.eval_shape(lambda: laguna_init(jax.random.PRNGKey(0), cfg))
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in lp}
-                   for lp in shape["layers"]],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
 
 
 def laguna_param_axes(cfg: LagunaConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(laguna_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def laguna_quant_axes(cfg: LagunaConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(laguna_init, cfg, lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -392,7 +390,7 @@ def _ffn(x, lp, cfg: LagunaConfig, valid):
     B, S, D = x.shape
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if "mlp_in" in lp:
-        return x + _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
+        return x + swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
     flat = h.reshape(B * S, D)
     weights, experts = moe_route(
         flat, lp["moe_route_w"], None, cfg.top_k,
@@ -401,38 +399,9 @@ def _ffn(x, lp, cfg: LagunaConfig, valid):
         flat, weights, experts, lp["moe_gmm_w_in"], lp["moe_gmm_w_out"],
         dtype=cfg.dtype, valid=valid.reshape(B * S), held=cfg.experts_held)
     with jax.named_scope("moe_shared"):
-        shared = _swiglu(h, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
-                         cfg.dtype)
+        shared = swiglu(h, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
+                        cfg.dtype)
     return x + shared + y.reshape(B, S, D), sizes
-
-
-def _final_norm(params, x, cfg: LagunaConfig):
-    return rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-
-
-def _head(params, h, cfg: LagunaConfig):
-    """[..., D] -> float32 logits through the untied head."""
-    return jnp.einsum(
-        "...d,dv->...v", h.astype(cfg.dtype),
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _windowed_attention(q, k, v, window: int | None):
-    """Plain attention over a whole sequence, q [B, S, Hq, hd], GQA by
-    regrouping the queries: [B, S, Hq * hd] in q's dtype."""
-    B, S, Hq, hd = q.shape
-    Hkv = k.shape[2]
-    qg = q.reshape(B, S, Hkv, Hq // Hkv, hd)
-    s = jnp.einsum("bshgd,bthd->bhgst", qg, k,
-                   preferred_element_type=jnp.float32) * hd ** -0.5
-    t = jnp.arange(S)
-    mask = t[None, :] <= t[:, None]
-    if window is not None:
-        mask = mask & (t[None, :] > t[:, None] - window)
-    p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhgst,bthd->bshgd", p, v).reshape(B, S, Hq * hd)
 
 
 def laguna_forward(params: dict, tokens: jax.Array,
@@ -447,12 +416,12 @@ def laguna_forward(params: dict, tokens: jax.Array,
     for lp, kind in zip(params["layers"], cfg.layer_types):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(h, lp, kind, tables, cfg)
-        attn = _windowed_attention(
+        attn = windowed_attention(
             q, k, v,
             cfg.sliding_window if kind == "sliding_attention" else None)
         x = _attn_out(x, h, attn, lp, kind, cfg)
         x, _ = _ffn(x, lp, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_untied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -468,16 +437,6 @@ def laguna_forward(params: dict, tokens: jax.Array,
 def _cached_embed(params, tokens, step, cfg: LagunaConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
     return x, _rotary_tables(step.pos, cfg)
-
-
-def _open_state(state: dict, step, cfg: LagunaConfig) -> dict:
-    """The step's working state: the index of the next layer, each expert
-    layer's held pairs, and the mask of the tokens that are routed."""
-    routed = (step.slots > 0)[:, None]
-    if step.valid is not None:
-        routed = step.valid & routed
-    return {"layer": 0, "sizes": [],
-            "routed": jnp.broadcast_to(routed, step.pos.shape)}
 
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: LagunaConfig):
@@ -497,25 +456,11 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: LagunaConfig):
     return x, work
 
 
-def _close_state(state: dict, work: dict, step, cfg: LagunaConfig) -> dict:
-    kind = int(step.kind == "decode")
-    sizes = work["sizes"]
-    out = dict(state)
-    if not sizes:
-        return out
-    out["pairs"] = state["pairs"].at[kind].set(
-        _count_add(state["pairs"][kind], sum(sizes)))
-    out["routed"] = state["routed"].at[kind].set(_count_add(
-        state["routed"][kind],
-        jnp.sum(work["routed"]) * (cfg.top_k * len(sizes))))
-    if kind:
-        out["reads"] = _count_add(
-            state["reads"], sum(jnp.sum(s > 0) for s in sizes))
-    return out
-
-
-# no verify step: the engine refuses speculation over grouped tables
-laguna_prefill, laguna_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "laguna", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "laguna", LagunaConfig, "layers", _cached_embed, _cached_layer,
+    final_norm, head_untied, open_state=open_experts,
+    close_state=close_experts,
+    no_verify="a rejected window may reach behind freed blocks (the engine "
+              "refuses speculation over grouped tables)",
+    gmm_form=step_gmm_form)
+laguna_prefill, laguna_decode_step, _ = cached.steps(FAMILY)

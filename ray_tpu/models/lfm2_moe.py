@@ -34,7 +34,8 @@ three differences this family forces:
   ``pairs`` ``[2, E, 2]`` (routed token-expert pairs by expert, prefill and
   decode apart, padding rows excluded) and ``reads`` ``[2]`` (experts that
   got at least one token, summed over decode steps and expert layers),
-  each a (low, high) pair of uint32 words (``count_value``).
+  each a (low, high) pair of uint32 words (models/parts.py
+  ``count_value``).
 """
 from __future__ import annotations
 
@@ -45,9 +46,18 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
+from ray_tpu.models.parts import (
+    count_pairs,
+    count_value,
+    final_norm,
+    head_tied,
+    leaf_tree,
+    routed_mask,
+    swiglu,
+)
 from ray_tpu.ops.attention import mha_reference
 from ray_tpu.ops.layers import rms_norm, rope
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 from ray_tpu.ops.short_conv import short_conv_decode, short_conv_prefill
 
 LAYER_KINDS = ("conv", "full_attention")
@@ -192,36 +202,27 @@ _LEAF_AXES = {
     "moe_route_w": (None, None), "moe_route_bias": (None,),
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, the conv filter, and the router, which is read in float32)
 _LEAF_QUANT = {
     "short_conv_in": 0, "short_conv_out": 0, "wq": 0, "wk": 0, "wv": 0,
     "wo": 0, "mlp_in": 0, "mlp_out": 0,
-    "moe_gmm_w_in": 1, "moe_gmm_w_out": 1,
+    "moe_gmm_w_in": 1, "moe_gmm_w_out": 1, "wte": 1,
 }
-
-
-def _leaf_tree(cfg: Lfm2MoeConfig, leaf, wte, ln_f) -> dict:
-    shape = jax.eval_shape(lambda: lfm2_moe_init(jax.random.PRNGKey(0), cfg))
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in lp}
-                   for lp in shape["layers"]],
-        "ln_f_scale": ln_f,
-    }
 
 
 def lfm2_moe_param_axes(cfg: Lfm2MoeConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",))
+    return leaf_tree(lfm2_moe_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def lfm2_moe_quant_axes(cfg: Lfm2MoeConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1)
+    return leaf_tree(lfm2_moe_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -239,21 +240,6 @@ def lfm2_moe_init_state(cfg: Lfm2MoeConfig, slots: int) -> dict:
     }
 
 
-def _count_add(acc: jax.Array, n: jax.Array) -> jax.Array:
-    """``acc`` [..., 2] uint32 (low, high words) plus ``n`` [...] >= 0."""
-    low = acc[..., 0] + n.astype(jnp.uint32)
-    high = acc[..., 1] + (low < acc[..., 0]).astype(jnp.uint32)
-    return jnp.stack([low, high], axis=-1)
-
-
-def count_value(acc) -> Any:
-    """Host side: the integers a (low, high) counter array holds."""
-    import numpy as np
-
-    a = np.asarray(acc).astype(np.uint64)
-    return (a[..., 1] << np.uint64(32)) + a[..., 0]
-
-
 def lfm2_moe_counters(state: dict) -> dict:
     """``state``'s counters as plain integers (a device->host read)."""
     pairs = count_value(state["pairs"])  # [2, E]: prefill, decode
@@ -268,11 +254,6 @@ def lfm2_moe_counters(state: dict) -> dict:
 # ----------------------------------------------------------------- layers
 
 
-def _swiglu(h, w_in, w_out, dtype):
-    gate, up = jnp.split(h @ w_in.astype(dtype), 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ w_out.astype(dtype)
-
-
 def _ffn(x, lp, cfg: Lfm2MoeConfig, valid):
     """RMSNorm + (SwiGLU | experts) + residual on x [B, S, D]. ``valid``
     [B, S] marks the real tokens. Returns (x', the expert layer's routed
@@ -280,7 +261,7 @@ def _ffn(x, lp, cfg: Lfm2MoeConfig, valid):
     B, S, D = x.shape
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if "mlp_in" in lp:
-        return x + _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
+        return x + swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
     flat = h.reshape(B * S, D)
     weights, experts = moe_route(
         flat, lp["moe_route_w"],
@@ -292,21 +273,6 @@ def _ffn(x, lp, cfg: Lfm2MoeConfig, valid):
         dtype=cfg.dtype, valid=valid.reshape(B * S),
     )
     return x + y.reshape(B, S, D), sizes
-
-
-def _counted(state: dict, conv, sizes: list, decode: bool) -> dict:
-    """The next ``state``: the conv rows as the step left them, and the
-    step's routed pairs (``sizes``: one [E] per expert layer) added to the
-    counters of its kind."""
-    kind = int(decode)
-    per_expert = sum(sizes)
-    out = {"conv": conv, "reads": state["reads"],
-           "pairs": state["pairs"].at[kind].set(
-               _count_add(state["pairs"][kind], per_expert))}
-    if decode:
-        out["reads"] = _count_add(
-            state["reads"], sum(jnp.sum(s > 0) for s in sizes))
-    return out
 
 
 def _rope_at(pos, cfg: Lfm2MoeConfig):
@@ -331,18 +297,6 @@ def _qkv(h, lp, cos, sin, cfg: Lfm2MoeConfig):
     q = rope(rms_norm(q, lp["q_norm"], cfg.norm_eps), cos, sin)
     k = rope(rms_norm(k, lp["k_norm"], cfg.norm_eps), cos, sin)
     return q, k, v
-
-
-def _final_norm(params, x, cfg: Lfm2MoeConfig):
-    return rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-
-
-def _head(params, h, cfg: Lfm2MoeConfig):
-    """[..., D] -> float32 logits over the tied embedding."""
-    return jnp.einsum(
-        "...d,vd->...v", h.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
 
 
 def lfm2_moe_forward(params: dict, tokens: jax.Array,
@@ -371,7 +325,7 @@ def lfm2_moe_forward(params: dict, tokens: jax.Array,
             ).transpose(0, 2, 1, 3).reshape(B, S, -1)
             x = x + attn @ lp["wo"].astype(cfg.dtype)
         x, _ = _ffn(x, lp, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_tied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -397,11 +351,8 @@ def _open_state(state: dict, step, cfg: Lfm2MoeConfig) -> dict:
     """The step's working state: the conv rows as the layers so far left
     them and the ordinal of the next conv layer, each expert layer's
     routed pairs, and the mask of the tokens that are routed."""
-    routed = (step.slots > 0)[:, None]
-    if step.valid is not None:
-        routed = step.valid & routed
     return {"conv": state["conv"], "conv_done": 0, "sizes": [],
-            "routed": routed}
+            "routed": routed_mask(step)}
 
 
 def _mixer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
@@ -446,12 +397,15 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: Lfm2MoeConfig):
 
 
 def _close_state(state: dict, work: dict, step, cfg: Lfm2MoeConfig) -> dict:
-    return _counted(state, work["conv"], work["sizes"],
-                    decode=step.kind == "decode")
+    """The next ``state``: the conv rows as the step left them, and the
+    step's routed pairs added to the counters of its kind."""
+    return {"conv": work["conv"], "reads": state["reads"], **count_pairs(
+        state, work["sizes"], int(step.kind == "decode"))}
 
 
-# no verify step: rejected drafts would need the conv state rolled back
-lfm2_moe_prefill, lfm2_moe_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "lfm2_moe", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "lfm2_moe", Lfm2MoeConfig, "layers", _cached_embed, _cached_layer,
+    final_norm, head_tied, open_state=_open_state, close_state=_close_state,
+    no_verify="rejected drafts would need the conv state rolled back",
+    gmm_form=step_gmm_form)
+lfm2_moe_prefill, lfm2_moe_decode_step, _ = cached.steps(FAMILY)

@@ -7,8 +7,8 @@ Follows the public ``bailing_hybrid`` configuration (inclusionAI
 Ling-3.0-flash ``config.json``), whose mixers are two published layers:
 Kimi Delta Attention (Kimi Linear, arXiv:2510.26692 section 3;
 ops/kda.py) and DeepSeek-V2's multi-head latent attention
-(models/pangu_ultra_moe.py serves it; this file reuses its absorbed and
-expanded forms). With ``u = RMSNorm(x)`` a layer is ``h = x + Mixer(u)``,
+(models/pangu_ultra_moe.py serves it; the absorbed and expanded forms are
+models/parts.py's). With ``u = RMSNorm(x)`` a layer is ``h = x + Mixer(u)``,
 ``x' = h + FFN(RMSNorm(h))``: plain pre-norm residuals, no sandwich.
 
 ``kda`` (H heads of K = V = ``kda_head_dim``): ``[q~ | k~ | v~] = u [W_q |
@@ -62,13 +62,26 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import cached, laguna, pangu_ultra_moe
-from ray_tpu.models.lfm2_moe import _count_add, _swiglu, count_value
-from ray_tpu.models.pangu_ultra_moe import QK_GAIN, _cached_heads
+from ray_tpu.models import cached
+from ray_tpu.models.laguna import laguna_counters
+from ray_tpu.models.pangu_ultra_moe import QK_GAIN
+from ray_tpu.models.parts import (
+    cached_heads,
+    close_experts,
+    count_add,
+    count_value,
+    final_norm,
+    head_untied,
+    latent_step_attrs,
+    leaf_tree,
+    open_experts,
+    rotary_at,
+    swiglu,
+)
 from ray_tpu.ops import kda
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import (
-    moe_dropless, moe_route_grouped, score_groups_bad)
+    moe_dropless, moe_route_grouped, score_groups_bad, step_gmm_form)
 from ray_tpu.ops.paged_attention import plane_width, resolve_backend
 from ray_tpu.ops.short_conv import short_conv_decode, short_conv_prefill
 
@@ -320,6 +333,8 @@ _LEAF_AXES = {
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
     "moe_shared_w_in": ("embed", "mlp"), "moe_shared_w_out": ("mlp", "embed"),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, the filter, the gates' small leaves, and the router, which is
@@ -329,32 +344,20 @@ _LEAF_QUANT = {
     "mla_w_q": 0, "mla_w_dkv": 0, "mla_w_uk": 0, "mla_w_uv": 0,
     "mla_w_o": 0, "mlp_in": 0, "mlp_out": 0,
     "moe_gmm_w_in": 1, "moe_gmm_w_out": 1,
-    "moe_shared_w_in": 0, "moe_shared_w_out": 0,
+    "moe_shared_w_in": 0, "moe_shared_w_out": 0, "wte": 1, "lm_head": 0,
 }
-
-
-def _leaf_tree(cfg: LingHybridConfig, leaf, wte, ln_f, head) -> dict:
-    shape = jax.eval_shape(
-        lambda: ling_hybrid_init(jax.random.PRNGKey(0), cfg))
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in lp}
-                   for lp in shape["layers"]],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
 
 
 def ling_hybrid_param_axes(cfg: LingHybridConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(ling_hybrid_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def ling_hybrid_quant_axes(cfg: LingHybridConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(ling_hybrid_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -385,7 +388,7 @@ def ling_hybrid_counters(state: dict) -> dict:
     layer)s routed) with ``moe_groups_held`` (those of them whose kept
     groups hold one of this device's)."""
     groups = count_value(state["groups"])  # [2, 2]: kind x (routed, held)
-    return {**laguna.laguna_counters(state),
+    return {**laguna_counters(state),
             "moe_tokens_routed": int(groups[:, 0].sum()),
             "moe_groups_held": int(groups[:, 1].sum())}
 
@@ -399,7 +402,7 @@ def step_attrs(cfg: LingHybridConfig, kind: str, rows: list) -> dict:
     ``ops.kda.PIECE`` tokens its rows are cut into, and the latent layer's
     ``expanded_pairs`` / ``prefix_blocks`` as models/pangu_ultra_moe.py's."""
     H, K = cfg.kda_n_head, cfg.kda_head_dim
-    latent = pangu_ultra_moe.step_attrs(cfg, kind, rows)
+    latent = latent_step_attrs(cfg, kind, rows)
     if kind == "decode":
         return {"rows": len(rows), **latent,
                 "state_mb": round(
@@ -467,15 +470,6 @@ def _latent_gate(u, lp):
         lp["mla_w_g"].astype(jnp.float32)))
 
 
-def _rotary_at(pos, cfg: LingHybridConfig):
-    """(cos, sin) ``[B, S, R // 2]`` at the true positions; no scaling."""
-    R = cfg.qk_rope_head_dim
-    inv_freq = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(0, R, 2, dtype=jnp.float32) / R))
-    ang = pos.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
-
-
 def _rotate(x, cos, sin):
     """The rotary embedding of x ``[B, S, heads, R]`` over INTERLEAVED
     pairs ``(2i, 2i + 1)`` (``rope_interleave``)."""
@@ -486,21 +480,9 @@ def _rotate(x, cos, sin):
         x.shape).astype(x.dtype)
 
 
-def _final_norm(params, x, cfg: LingHybridConfig):
-    return rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-
-
-def _head(params, h, cfg: LingHybridConfig):
-    """[..., D] -> float32 logits over the held rows of the vocabulary."""
-    return jnp.einsum(
-        "...d,dv->...v", h.astype(cfg.dtype),
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32)
-
-
 def _cached_embed(params, tokens, step, cfg: LingHybridConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
-    return x, _rotary_at(step.pos, cfg)
+    return x, rotary_at(step.pos, cfg)
 
 
 def _open_state(state: dict, step, cfg: LingHybridConfig) -> dict:
@@ -508,7 +490,7 @@ def _open_state(state: dict, step, cfg: LingHybridConfig) -> dict:
     far left them, the ordinals of the next layer and the next KDA layer,
     each expert layer's held pairs and held-group tokens, and the mask of
     the tokens that are routed."""
-    return {**laguna._open_state(state, step, cfg), "kda": state["kda"],
+    return {**open_experts(state, step, cfg), "kda": state["kda"],
             "conv": state["conv"], "kda_done": 0, "groups": []}
 
 
@@ -583,7 +565,7 @@ def _kda_mixer(u, lp, step, work: dict, cfg: LingHybridConfig):
 def _latent_mixer(u, lp, attend, step, cfg: LingHybridConfig):
     """``Mixer(u)`` of a latent layer: the projections, the row written
     and attended through the cache in the form the kind of step wants
-    (models/pangu_ultra_moe.py ``_cached_heads``), the gate a head."""
+    (models/parts.py ``cached_heads``), the gate a head."""
     B, S, _ = u.shape
     H, N, R, C, V = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                      cfg.kv_lora_rank, cfg.v_head_dim)
@@ -592,8 +574,8 @@ def _latent_mixer(u, lp, attend, step, cfg: LingHybridConfig):
     kv = u @ lp["mla_w_dkv"].astype(cfg.dtype)
     c = rms_norm(kv[..., :C], lp["mla_kv_norm"], cfg.norm_eps)
     k_r = _rotate(kv[..., None, C:], cos, sin)[:, :, 0]
-    heads = _cached_heads(q[..., :N], _rotate(q[..., N:], cos, sin), c, k_r,
-                          lp, attend, step, cfg)
+    heads = cached_heads(q[..., :N], _rotate(q[..., N:], cos, sin), c, k_r,
+                         lp, attend, step, cfg)
     gate = _latent_gate(u, lp).astype(cfg.dtype)            # [B, S, H]
     heads = (heads.reshape(B, S, H, V) * gate[..., None]).reshape(B, S, -1)
     return heads @ lp["mla_w_o"].astype(cfg.dtype)
@@ -609,7 +591,7 @@ def _ffn(x, lp, cfg: LingHybridConfig, valid):
     B, S, D = x.shape
     z = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if "mlp_in" in lp:
-        return _swiglu(z, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None, None
+        return swiglu(z, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None, None
     flat = z.reshape(B * S, D)
     weights, experts, stays = moe_route_grouped(
         flat, lp["moe_route_w"], lp["moe_route_bias"], cfg.top_k,
@@ -622,8 +604,8 @@ def _ffn(x, lp, cfg: LingHybridConfig, valid):
         mine = jnp.any(stays[:, jnp.asarray(cfg.groups_held)], axis=-1)
         met = jnp.sum(mine & valid.reshape(B * S))
     with jax.named_scope("moe_shared"):
-        shared = _swiglu(z, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
-                         cfg.dtype)
+        shared = swiglu(z, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
+                        cfg.dtype)
     return shared + y.reshape(B, S, D), sizes, met
 
 
@@ -648,19 +630,23 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: LingHybridConfig):
 def _close_state(state: dict, work: dict, step, cfg: LingHybridConfig):
     """The next ``state``: the rows as the step left them, and the step's
     routed tokens, held pairs and held-group tokens added to the counters."""
-    out = {**laguna._close_state(state, work, step, cfg),
+    out = {**close_experts(state, work, step, cfg),
            "kda": work["kda"], "conv": work["conv"]}
     if work["sizes"]:
         kind = int(step.kind == "decode")
         tokens = jnp.sum(work["routed"]) * len(work["sizes"])
-        out["groups"] = state["groups"].at[kind].set(_count_add(
+        out["groups"] = state["groups"].at[kind].set(count_add(
             state["groups"][kind], jnp.stack([tokens, sum(work["groups"])])))
     return out
 
 
-# no verify step: rejected drafts would need the KDA state rolled back
-# (the multi-token-prediction module that would draft is not held)
-ling_hybrid_prefill, ling_hybrid_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "ling_hybrid", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "ling_hybrid", LingHybridConfig, "layers", _cached_embed, _cached_layer,
+    final_norm, head_untied, open_state=_open_state,
+    close_state=_close_state,
+    no_verify="rejected drafts would need the KDA state (a matrix a head a "
+              "sequence, and the convolution's rows) rolled back; the "
+              "prediction module that would draft is not held",
+    step_attrs=step_attrs, gmm_form=step_gmm_form,
+    donated_state_counters=COUNTER_LEAVES)
+ling_hybrid_prefill, ling_hybrid_decode_step, _ = cached.steps(FAMILY)

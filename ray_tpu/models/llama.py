@@ -424,9 +424,10 @@ def _head(params, h, cfg: LlamaConfig):
     )
 
 
-llama_prefill, llama_decode_step, llama_verify_step = cached.steps(
-    cached.CachedFamily(
-        "llama", "blocks", _cached_embed, _cached_layer, _final_norm, _head))
+FAMILY = cached.CachedFamily(
+    "llama", LlamaConfig, "blocks", _cached_embed, _cached_layer,
+    _final_norm, _head)
+llama_prefill, llama_decode_step, llama_verify_step = cached.steps(FAMILY)
 
 
 def llama_num_params(cfg: LlamaConfig) -> int:

@@ -27,11 +27,10 @@ The two rescalings (``mla_scale_q_lora``, ``mla_scale_kv_lora``) multiply
 ``q`` (both parts) and the NORMED ``c`` (so the keys' nope part and the
 values, not ``k_r``). The pool caches ``[c | k_r]`` with ``c`` ALREADY
 rescaled and the cached step computes the form its kind wants (models/
-pangu_ultra_moe.py ``_cached_heads``: a decode step the ABSORBED form,
-``_absorb`` before ``attend(..., latent=scale)``, ``_unabsorb`` after; a
-prefill step the EXPANDED one, ``attend(..., up=)``; this file imports
-them, there is one copy); ``longcat_flash_forward`` (no cache) computes
-the EXPANDED form.
+parts.py ``cached_heads``: a decode step the ABSORBED form, ``absorb``
+before ``attend(..., latent=scale)``, ``unabsorb`` after; a prefill step
+the EXPANDED one, ``attend(..., up=)``; there is one copy, pangu's too);
+``longcat_flash_forward`` (no cache) computes the EXPANDED form.
 
 ``MoE(h)``: ``p = softmax(h W_r)`` over ALL ``num_experts +
 num_zero_experts`` outputs (512 + 256), float32 at the highest precision;
@@ -72,27 +71,25 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
-from ray_tpu.models.laguna import (
-    _close_state as _close_pairs,
-    _open_state as _open_pairs,
-    laguna_counters,
-    laguna_init_state,
-)
-from ray_tpu.models.lfm2_moe import _count_add, _swiglu, count_value
-from ray_tpu.models.pangu_ultra_moe import (
-    QK_GAIN,
-    _absorb,
-    _cached_heads,
-    _final_norm,
-    _head,
-    _queries_and_row,
-    _rotary_at,
-    _unabsorb,
+from ray_tpu.models.laguna import laguna_counters, laguna_init_state
+from ray_tpu.models.pangu_ultra_moe import QK_GAIN
+from ray_tpu.models.parts import (
+    cached_heads,
+    close_experts,
+    count_add,
+    count_value,
     expanded_attention,
-    step_attrs,
+    final_norm,
+    head_untied,
+    latent_step_attrs,
+    leaf_tree,
+    open_experts,
+    queries_and_row,
+    rotary_at,
+    swiglu,
 )
 from ray_tpu.ops.layers import rms_norm
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 from ray_tpu.ops.paged_attention import plane_width
 
 # ``state["step_pairs"]``: decode steps by the held real pairs they computed
@@ -278,25 +275,18 @@ _LEAF_QUANT = {
 }
 
 
-def _by_leaf_name(cfg: LongCatFlashConfig, of) -> dict:
-    """``of(name)`` for every leaf of the parameter tree, by the leaf's
-    own name (the last key of its path)."""
-    shape = jax.eval_shape(
-        lambda: longcat_flash_init(jax.random.PRNGKey(0), cfg))
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: of(path[-1].key), shape)
-
-
 def longcat_flash_param_axes(cfg: LongCatFlashConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own,
     every norm scale is ``("embed",)``."""
-    return _by_leaf_name(cfg, lambda n: _LEAF_AXES.get(n, ("embed",)))
+    return leaf_tree(longcat_flash_init, cfg,
+                     lambda n: _LEAF_AXES.get(n, ("embed",)))
 
 
 def longcat_flash_quant_axes(cfg: LongCatFlashConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _by_leaf_name(cfg, lambda n: _LEAF_QUANT.get(n, -1))
+    return leaf_tree(longcat_flash_init, cfg,
+                     lambda n: _LEAF_QUANT.get(n, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -333,14 +323,14 @@ def longcat_flash_counters(state: dict) -> dict:
 
 
 def _rows(u, sp, cos, sin, cfg: LongCatFlashConfig):
-    return _queries_and_row(u, sp, cos, sin, cfg, q_scale=cfg.q_scale,
-                            c_scale=cfg.c_scale)
+    return queries_and_row(u, sp, cos, sin, cfg, q_scale=cfg.q_scale,
+                           c_scale=cfg.c_scale)
 
 
 def _dense_ffn(h, sp, cfg: LongCatFlashConfig):
     with jax.named_scope("dense_ffn"):
-        return _swiglu(h, sp["dense_ffn_w_in"], sp["dense_ffn_w_out"],
-                       cfg.dtype)
+        return swiglu(h, sp["dense_ffn_w_in"], sp["dense_ffn_w_out"],
+                      cfg.dtype)
 
 
 def _routed(h, lp, cfg: LongCatFlashConfig, valid):
@@ -391,7 +381,7 @@ def longcat_flash_forward(params: dict, tokens: jax.Array,
     once, no cache, attention in the expanded form."""
     B, S = tokens.shape
     x = params["wte"].astype(cfg.dtype)[tokens]
-    cos, sin = _rotary_at(
+    cos, sin = rotary_at(
         jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), cfg)
     valid = jnp.ones((B, S), bool)
 
@@ -401,7 +391,7 @@ def longcat_flash_forward(params: dict, tokens: jax.Array,
 
     for lp in params["layers"]:
         x, _, _ = _layer(x, lp, attention, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_untied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -414,17 +404,17 @@ def longcat_flash_forward(params: dict, tokens: jax.Array,
 
 def _cached_embed(params, tokens, step, cfg: LongCatFlashConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
-    return x, _rotary_at(step.pos, cfg)
+    return x, rotary_at(step.pos, cfg)
 
 
 def _open_state(state: dict, step, cfg: LongCatFlashConfig) -> dict:
-    return {**_open_pairs(state, step, cfg), "zero": []}
+    return {**open_experts(state, step, cfg), "zero": []}
 
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: LongCatFlashConfig):
     def attention(u, sp):
         # the pool's layer is the attending call's ordinal: 2 l + j
-        heads = _cached_heads(
+        heads = cached_heads(
             *_rows(u, sp, *step.aux, cfg), sp, attend, step, cfg)
         return heads @ sp["mla_w_o"].astype(cfg.dtype)
 
@@ -436,9 +426,9 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: LongCatFlashConfig):
 
 def _close_state(state: dict, work: dict, step, cfg: LongCatFlashConfig):
     kind = int(step.kind == "decode")
-    out = _close_pairs(state, work, step, cfg)
+    out = close_experts(state, work, step, cfg)
     out["zero"] = state["zero"].at[kind].set(
-        _count_add(state["zero"][kind], sum(work["zero"])))
+        count_add(state["zero"][kind], sum(work["zero"])))
     if kind:
         held = jnp.sum(sum(work["sizes"]))
         out["step_pairs"] = state["step_pairs"].at[
@@ -446,9 +436,10 @@ def _close_state(state: dict, work: dict, step, cfg: LongCatFlashConfig):
     return out
 
 
-# no verify step: nothing here drafts
-longcat_flash_prefill, longcat_flash_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "longcat_flash", "layers", _cached_embed, _cached_layer,
-        _final_norm, _head, open_state=_open_state,
-        close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "longcat_flash", LongCatFlashConfig, "layers", _cached_embed,
+    _cached_layer, final_norm, head_untied, open_state=_open_state,
+    close_state=_close_state,
+    no_verify="nothing drafts; the latent family's refusals apply",
+    state_rows=False, step_attrs=latent_step_attrs, gmm_form=step_gmm_form)
+longcat_flash_prefill, longcat_flash_decode_step, _ = cached.steps(FAMILY)

@@ -47,7 +47,7 @@ family forces:
   slot held); ``ckeys`` ``[n_sparse, num_blocks, segments, Hkv * hd]``
   float32, the compressed keys' segment sums addressed by BLOCK ID like K
   and V (ops/sparse_select.py); and counters (``steps``, ``blocks``: (low,
-  high) uint32 words, models/lfm2_moe.py ``count_value``).
+  high) uint32 words, models/parts.py ``count_value``).
 - ``attend(q, k, v, select=...)`` (models/cached.py): the layer hands the
   cache side its selection, a page list a (row, K/V head) in decode, the
   row's segment sums in prefill.
@@ -62,7 +62,13 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
-from ray_tpu.models.lfm2_moe import _count_add, _swiglu, count_value
+from ray_tpu.models.parts import (
+    count_add,
+    count_value,
+    head_untied,
+    leaf_tree,
+    swiglu,
+)
 from ray_tpu.ops.layers import rms_norm, rope
 from ray_tpu.ops.lightning import (
     lightning_chunk,
@@ -242,33 +248,25 @@ _LEAF_AXES = {
     "wg": ("embed", "mlp"), "wo": ("mlp", "embed"),
     "q_norm": (None,), "k_norm": (None,),
     "mlp_in": ("embed", "mlp"), "mlp_out": ("mlp", "embed"),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
-# the contraction axis of each matmul weight; -1: kept as given (norms)
-_LEAF_QUANT = {name: 0 for name, axes in _LEAF_AXES.items() if len(axes) == 2}
-
-
-def _leaf_tree(cfg: MiniCPMSALAConfig, leaf, wte, ln_f, head) -> dict:
-    shape = jax.eval_shape(
-        lambda: minicpm_sala_init(jax.random.PRNGKey(0), cfg))
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in lp}
-                   for lp in shape["layers"]],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
+# the contraction axis of each matmul weight (the embedding's is its
+# second); -1: kept as given (norms)
+_LEAF_QUANT = {name: int(name == "wte")
+               for name, axes in _LEAF_AXES.items() if len(axes) == 2}
 
 
 def minicpm_sala_param_axes(cfg: MiniCPMSALAConfig) -> dict:
     """Logical axis names per leaf."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(minicpm_sala_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def minicpm_sala_quant_axes(cfg: MiniCPMSALAConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(minicpm_sala_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -338,14 +336,6 @@ def minicpm_sala_counters(state: dict) -> dict:
 def _final_norm(params, x, cfg: MiniCPMSALAConfig):
     h = rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
     return h * jnp.asarray(cfg.dim_model_base / cfg.d_model, h.dtype)
-
-
-def _head(params, h, cfg: MiniCPMSALAConfig):
-    """[..., D] -> float32 logits over the untied head."""
-    return jnp.einsum(
-        "...d,dv->...v", h.astype(cfg.dtype),
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32)
 
 
 def _cached_embed(params, tokens, step, cfg: MiniCPMSALAConfig):
@@ -456,7 +446,7 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: MiniCPMSALAConfig):
         x = x + a * y
     with jax.named_scope("ffn"):
         h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        x = x + a * _swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype)
+        x = x + a * swiglu(h, lp["mlp_in"], lp["mlp_out"], cfg.dtype)
     return x, {**work, "layer": work["layer"] + 1}
 
 
@@ -468,9 +458,9 @@ def _close_state(state: dict, work: dict, step, cfg: MiniCPMSALAConfig):
     if work["counts"]:
         real = step.slots > 0
         sparse = work["counts"][0]["sparse"] & real
-        steps = _count_add(steps, jnp.stack(
+        steps = count_add(steps, jnp.stack(
             [jnp.sum(sparse), jnp.sum(real & ~sparse)]))
-        blocks = _count_add(blocks, jnp.stack([
+        blocks = count_add(blocks, jnp.stack([
             sum(jnp.sum(jnp.where(sparse, c[name], 0))
                 for c in work["counts"])
             for name in ("attended", "visible")]))
@@ -478,8 +468,12 @@ def _close_state(state: dict, work: dict, step, cfg: MiniCPMSALAConfig):
             "steps": steps, "blocks": blocks}
 
 
-# no verify step: rejected drafts would need the lightning state rolled back
-minicpm_sala_prefill, minicpm_sala_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "minicpm_sala", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "minicpm_sala", MiniCPMSALAConfig, "layers", _cached_embed,
+    _cached_layer, _final_norm, head_untied, open_state=_open_state,
+    close_state=_close_state,
+    no_verify="rejected drafts would need the lightning state (a matrix a "
+              "head a sequence) rolled back",
+    block_state_bytes=block_state_bytes, step_attrs=step_attrs,
+    donated_state_counters=COUNTER_LEAVES)
+minicpm_sala_prefill, minicpm_sala_decode_step, _ = cached.steps(FAMILY)

@@ -20,11 +20,11 @@ at position ``t`` is::
 
 ``[c | k_r]`` (C + R numbers) is ALL a layer caches of a token, one row
 for every head. The cached step computes the form its KIND wants
-(``_cached_heads``). A DECODE step the ABSORBED form: attention of the
-heads over one shared row whose key is the row and whose value is its
-first C numbers (models/cached.py ``attend(..., latent=s)``,
-ops/paged_attention.py ``latent_attention``; ``_absorb`` before it,
-``_unabsorb`` after). A PREFILL step the EXPANDED form (``attend(...,
+(models/parts.py ``cached_heads``). A DECODE step the ABSORBED form:
+attention of the heads over one shared row whose key is the row and whose
+value is its first C numbers (models/cached.py ``attend(..., latent=s)``,
+ops/paged_attention.py ``latent_attention``; ``absorb`` before it,
+``unabsorb`` after). A PREFILL step the EXPANDED form (``attend(...,
 latent=s, up=(W_uk, W_uv))``, ops/latent_prefill.py: a key's
 up-projection is shared by the step's many queries, at 3.4 x fewer
 operations a pair), as ``pangu_ultra_moe_forward`` (no cache) does; the
@@ -36,11 +36,12 @@ RMSNorm, an untied head. The multi-token-prediction module behind the last
 layer drafts and is no part of the next-token forward pass: not held.
 What the configuration does not say and this file reads by convention is
 listed in benchmark/configs/openpangu-ultra-moe-ep32-5l.json ``assumed``;
-the rotary form is ONE function here (``_rotate``: by halves).
+the rotary form is ONE function (models/parts.py ``rotate``: by halves).
 
 Same conventions as models/laguna.py (a LIST of per-layer trees, float32
 masters, activations in ``cfg.dtype``, ``experts_held``, the counters in
-``state``: laguna's very functions) with what this family forces:
+``state``: models/parts.py ``open_experts`` / ``close_experts`` over
+laguna's counters) with what this family forces:
 
 - THE POOL IS ONE PLANE (``kv_planes``): ``cache_k`` holds a token's row
   ``[c | k_rope]`` for all heads, ``[n_layer, num_blocks, block_size,
@@ -62,16 +63,24 @@ import jax.numpy as jnp
 
 from ray_tpu.models import cached
 from ray_tpu.models.laguna import (
-    _close_state,
-    _open_state,
     laguna_counters as pangu_ultra_moe_counters,
     laguna_init_state as pangu_ultra_moe_init_state,
 )
-from ray_tpu.models.lfm2_moe import _swiglu
-from ray_tpu.ops.attention import NEG_INF
-from ray_tpu.ops.latent_prefill import prefix_blocks
-from ray_tpu.ops.layers import rms_norm, rope
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.models.parts import (
+    cached_heads,
+    close_experts,
+    expanded_attention,
+    final_norm,
+    head_untied,
+    latent_step_attrs,
+    leaf_tree,
+    open_experts,
+    queries_and_row,
+    rotary_at,
+    swiglu,
+)
+from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 from ray_tpu.ops.paged_attention import plane_width
 
 # ``pangu_ultra_moe_init``: W_uq, and on the key's side W_uk and the rotary
@@ -239,6 +248,8 @@ _LEAF_AXES = {
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
     "moe_shared_w_in": ("embed", "mlp"), "moe_shared_w_out": ("mlp", "embed"),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, and the router, which is read in float32)
@@ -246,92 +257,23 @@ _LEAF_QUANT = {
     "mla_w_dq": 0, "mla_w_uq": 0, "mla_w_dkv": 0, "mla_w_uk": 0,
     "mla_w_uv": 0, "mla_w_o": 0, "mlp_in": 0, "mlp_out": 0,
     "moe_gmm_w_in": 1, "moe_gmm_w_out": 1,
-    "moe_shared_w_in": 0, "moe_shared_w_out": 0,
+    "moe_shared_w_in": 0, "moe_shared_w_out": 0, "wte": 1, "lm_head": 0,
 }
-
-
-def _leaf_tree(cfg: PanguUltraMoEConfig, leaf, wte, ln_f, head) -> dict:
-    shape = jax.eval_shape(
-        lambda: pangu_ultra_moe_init(jax.random.PRNGKey(0), cfg))
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in lp}
-                   for lp in shape["layers"]],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
 
 
 def pangu_ultra_moe_param_axes(cfg: PanguUltraMoEConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(pangu_ultra_moe_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def pangu_ultra_moe_quant_axes(cfg: PanguUltraMoEConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(pangu_ultra_moe_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ----------------------------------------------------------------- layers
-
-
-def _rotary_at(pos, cfg: PanguUltraMoEConfig):
-    """(cos, sin) ``[B, S, R // 2]`` at the true positions ``pos`` [B, S];
-    no scaling of the frequencies (the config has no ``rope_scaling``)."""
-    R = cfg.qk_rope_head_dim
-    inv_freq = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(0, R, 2, dtype=jnp.float32) / R))
-    ang = pos.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _rotate(x, cos, sin):
-    """The rotary embedding of x ``[B, S, heads, R]``, pairs BY HALVES
-    (dimension i with i + R / 2: assumed; the other reading, interleaved
-    pairs, is this function and the reference's ``_rotate``)."""
-    return rope(x, cos, sin)
-
-
-def _queries_and_row(u, lp, cos, sin, cfg: PanguUltraMoEConfig, *,
-                     q_scale: float | None = None,
-                     c_scale: float | None = None):
-    """The projections of the layer's normed input ``u`` [B, S, D]:
-    ``(q_nope [B, S, H, N], q_rope [B, S, H, R], c [B, S, C], k_r [B, S,
-    R])``, the last two the token's row as the pool keeps it. ``q_scale``
-    multiplies both parts of every head's query and ``c_scale`` the normed
-    latent (so keys' nope part and values, not ``k_r``):
-    models/longcat_flash.py's two rescalings; None: none."""
-    B, S, _ = u.shape
-    H, N, R, C = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                  cfg.kv_lora_rank)
-    c_q = rms_norm(u @ lp["mla_w_dq"].astype(cfg.dtype), lp["mla_q_norm"],
-                   cfg.norm_eps)
-    q = (c_q @ lp["mla_w_uq"].astype(cfg.dtype)).reshape(B, S, H, N + R)
-    kv = u @ lp["mla_w_dkv"].astype(cfg.dtype)
-    c = rms_norm(kv[..., :C], lp["mla_kv_norm"], cfg.norm_eps)
-    if q_scale is not None:
-        q = q * jnp.asarray(q_scale, q.dtype)
-    if c_scale is not None:
-        c = c * jnp.asarray(c_scale, c.dtype)
-    k_r = _rotate(kv[..., None, C:], cos, sin)[:, :, 0]
-    return q[..., :N], _rotate(q[..., N:], cos, sin), c, k_r
-
-
-def _absorb(q_nope, lp, cfg: PanguUltraMoEConfig):
-    """``q~_h = q_nope,h W_uk,h^T``: [B, S, H, N] -> [B, S, H, C]."""
-    w = lp["mla_w_uk"].astype(cfg.dtype).reshape(
-        cfg.kv_lora_rank, cfg.n_head, cfg.qk_nope_head_dim)
-    return jnp.einsum("bshn,chn->bshc", q_nope, w)
-
-
-def _unabsorb(o, lp, cfg: PanguUltraMoEConfig):
-    """``o_h = o~_h W_uv,h``: [B, S, H, C] -> [B, S, H * V]."""
-    B, S = o.shape[:2]
-    w = lp["mla_w_uv"].astype(cfg.dtype).reshape(
-        cfg.kv_lora_rank, cfg.n_head, cfg.v_head_dim)
-    return jnp.einsum("bshc,chv->bshv", o, w).reshape(B, S, -1)
 
 
 def _attn_out(x, heads, lp, cfg: PanguUltraMoEConfig):
@@ -348,7 +290,7 @@ def _ffn(x, lp, cfg: PanguUltraMoEConfig, valid):
     B, S, D = x.shape
     z = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if "mlp_in" in lp:
-        out, sizes = _swiglu(z, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
+        out, sizes = swiglu(z, lp["mlp_in"], lp["mlp_out"], cfg.dtype), None
     else:
         flat = z.reshape(B * S, D)
         weights, experts = moe_route(
@@ -359,43 +301,10 @@ def _ffn(x, lp, cfg: PanguUltraMoEConfig, valid):
             dtype=cfg.dtype, valid=valid.reshape(B * S),
             held=cfg.experts_held)
         with jax.named_scope("moe_shared"):
-            shared = _swiglu(z, lp["moe_shared_w_in"],
-                             lp["moe_shared_w_out"], cfg.dtype)
+            shared = swiglu(z, lp["moe_shared_w_in"],
+                            lp["moe_shared_w_out"], cfg.dtype)
         out = shared + y.reshape(B, S, D)
     return x + rms_norm(out, lp["ffn_post_norm"], cfg.norm_eps), sizes
-
-
-def _final_norm(params, x, cfg: PanguUltraMoEConfig):
-    return rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-
-
-def _head(params, h, cfg: PanguUltraMoEConfig):
-    """[..., D] -> float32 logits over the held rows of the vocabulary."""
-    return jnp.einsum(
-        "...d,dv->...v", h.astype(cfg.dtype),
-        params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def expanded_attention(q_nope, q_rope, c, k_r, lp, cfg: PanguUltraMoEConfig):
-    """The EXPANDED form over a whole sequence, no cache: keys ``[c W_uk,h
-    | k_r]`` and values ``c W_uv,h`` by head, a causal softmax. [B, S, H *
-    V] in q's dtype."""
-    B, S, H, N = q_nope.shape
-    C, V = cfg.kv_lora_rank, cfg.v_head_dim
-    k_nope = (c @ lp["mla_w_uk"].astype(cfg.dtype)).reshape(B, S, H, N)
-    v = (c @ lp["mla_w_uv"].astype(cfg.dtype)).reshape(B, S, H, V)
-    s = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bshr,btr->bhst", q_rope, k_r,
-                      preferred_element_type=jnp.float32)
-         ) * cfg.softmax_scale
-    t = jnp.arange(S)
-    p = jax.nn.softmax(
-        jnp.where(t[None, :] <= t[:, None], s, NEG_INF), axis=-1
-    ).astype(q_nope.dtype)
-    return jnp.einsum("bhst,bthv->bshv", p, v).reshape(B, S, H * V)
 
 
 def pangu_ultra_moe_forward(params: dict, tokens: jax.Array,
@@ -404,15 +313,15 @@ def pangu_ultra_moe_forward(params: dict, tokens: jax.Array,
     once, no cache, attention in the expanded form."""
     B, S = tokens.shape
     x = params["wte"].astype(cfg.dtype)[tokens]
-    cos, sin = _rotary_at(
+    cos, sin = rotary_at(
         jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), cfg)
     valid = jnp.ones((B, S), bool)
     for lp in params["layers"]:
         u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         heads = expanded_attention(
-            *_queries_and_row(u, lp, cos, sin, cfg), lp, cfg)
+            *queries_and_row(u, lp, cos, sin, cfg), lp, cfg)
         x, _ = _ffn(_attn_out(x, heads, lp, cfg), lp, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_untied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -425,50 +334,15 @@ def pangu_ultra_moe_forward(params: dict, tokens: jax.Array,
 
 def _cached_embed(params, tokens, step, cfg: PanguUltraMoEConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
-    return x, _rotary_at(step.pos, cfg)
-
-
-def _cached_heads(q_nope, q_rope, c, k_r, lp, attend, step,
-                  cfg: PanguUltraMoEConfig):
-    """The heads' outputs ``[B, S, H * V]`` through the cache, in the form
-    the KIND of step wants (models/cached.py ``_attend_latent``). A decode
-    row reads one shared row a token for all heads: the ABSORBED form
-    (``W_uk`` into the query, ``W_uv`` out of the result). A prefill
-    step's many queries share each key's up-projection: the EXPANDED form,
-    the queries as projected and the two matrices by head handed on."""
-    C, H = cfg.kv_lora_rank, cfg.n_head
-    if step.kind == "decode":
-        q = jnp.concatenate([_absorb(q_nope, lp, cfg), q_rope], axis=-1)
-        o = attend(q, c, k_r, latent=cfg.softmax_scale)  # [B, S, H * C]
-        return _unabsorb(o.reshape(*o.shape[:2], H, C), lp, cfg)
-    return attend(
-        jnp.concatenate([q_nope, q_rope], axis=-1), c, k_r,
-        latent=cfg.softmax_scale,
-        up=tuple(lp[w].astype(cfg.dtype).reshape(C, H, -1)
-                 for w in ("mla_w_uk", "mla_w_uv")))
-
-
-def step_attrs(cfg, kind: str, rows: list) -> dict:
-    """What a step's ``executor.dispatch`` span says of the form its
-    latent layers attended in (decode.py ``Family.step_attrs``; ``rows``
-    ``[(first position, tokens)]`` a request): ``expanded_pairs``, the
-    (query, key) pairs that went through the expanded form (every pair of
-    a prefill step, none of a decode step), and a prefill step's
-    ``prefix_blocks``, the key blocks of resident prefixes it up-projected
-    a layer."""
-    if kind == "decode":
-        return {"expanded_pairs": 0}
-    return {"expanded_pairs": sum(n * first + n * (n + 1) // 2
-                                  for first, n in rows),
-            "prefix_blocks": sum(prefix_blocks(first) for first, _ in rows)}
+    return x, rotary_at(step.pos, cfg)
 
 
 def _cached_layer(x, lp, attend, step, work: dict,
                   cfg: PanguUltraMoEConfig):
     with jax.named_scope("attn_proj"):
         u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        heads = _cached_heads(
-            *_queries_and_row(u, lp, *step.aux, cfg), lp, attend, step, cfg)
+        heads = cached_heads(
+            *queries_and_row(u, lp, *step.aux, cfg), lp, attend, step, cfg)
         x = _attn_out(x, heads, lp, cfg)
     with jax.named_scope("ffn"):
         x, sizes = _ffn(x, lp, cfg, work["routed"])
@@ -478,9 +352,10 @@ def _cached_layer(x, lp, attend, step, work: dict,
     return x, work
 
 
-# no verify step: nothing here drafts (the prediction module is not held)
-pangu_ultra_moe_prefill, pangu_ultra_moe_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "pangu_ultra_moe", "layers", _cached_embed, _cached_layer,
-        _final_norm, _head, open_state=_open_state,
-        close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "pangu_ultra_moe", PanguUltraMoEConfig, "layers", _cached_embed,
+    _cached_layer, final_norm, head_untied, open_state=open_experts,
+    close_state=close_experts,
+    no_verify="nothing drafts (the prediction module is not held)",
+    state_rows=False, step_attrs=latent_step_attrs, gmm_form=step_gmm_form)
+pangu_ultra_moe_prefill, pangu_ultra_moe_decode_step, _ = cached.steps(FAMILY)

@@ -63,9 +63,10 @@ reading ONE function here and one in benchmark/reference/sdar_moe.py
 Same conventions as models/smallthinker.py (a LIST of per-layer trees,
 float32 masters, activations in ``cfg.dtype``, the cached step of
 models/cached.py, ``state`` the expert layers' counters alone), with
-``cached.block_steps``: a prompt chunk and a block pass are the chunk step
-under the block mask; a pass runs the head on the ``B`` positions of a row
-that choose (a folding row's fresh block, else the row's block).
+``CachedFamily(block_steps=True)``: a prompt chunk and a block pass are the
+chunk step under the block mask; a pass runs the head on the ``B``
+positions of a row that choose (a folding row's fresh block, else the
+row's block).
 """
 from __future__ import annotations
 
@@ -76,11 +77,17 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
-from ray_tpu.models.laguna import _final_norm, _head, _open_state
-from ray_tpu.models.lfm2_moe import _count_add, lfm2_moe_counters
-from ray_tpu.models.smallthinker import _rotary_tables  # the whole head
+from ray_tpu.models.lfm2_moe import lfm2_moe_counters as sdar_moe_counters
+from ray_tpu.models.parts import (
+    count_pairs,
+    final_norm,
+    head_untied,
+    leaf_tree,
+    open_experts,
+    rotary_tables,
+)
 from ray_tpu.ops.layers import rms_norm, rope_partial
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 from ray_tpu.ops.sampling import REMASKING
 
 # the two head norms' scales at init: the scores' std is then 2 on every
@@ -195,33 +202,25 @@ _LEAF_AXES = {
     "wo": ("mlp", "embed"), "moe_route_w": (None, None),
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, and the router, which is read in float32)
 _LEAF_QUANT = {"wq": 0, "wk": 0, "wv": 0, "wo": 0,
-               "moe_gmm_w_in": 1, "moe_gmm_w_out": 1}
-
-
-def _leaf_tree(cfg: SdarMoeConfig, leaf, wte, ln_f, head) -> dict:
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in _LEAF_AXES}
-                   for _ in range(cfg.n_layer)],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
+               "moe_gmm_w_in": 1, "moe_gmm_w_out": 1, "wte": 1, "lm_head": 0}
 
 
 def sdar_moe_param_axes(cfg: SdarMoeConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(sdar_moe_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def sdar_moe_quant_axes(cfg: SdarMoeConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(sdar_moe_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -234,9 +233,6 @@ def sdar_moe_init_state(cfg: SdarMoeConfig, slots: int) -> dict:
     del slots
     return {"pairs": jnp.zeros((2, cfg.num_experts, 2), jnp.uint32),
             "reads": jnp.zeros((2,), jnp.uint32)}
-
-
-sdar_moe_counters = lfm2_moe_counters
 
 
 # ----------------------------------------------------------------- layers
@@ -305,7 +301,7 @@ def sdar_moe_forward(params: dict, tokens: jax.Array,
     once under the block mask, no cache (the program's own full forward)."""
     B, S = tokens.shape
     x = params["wte"].astype(cfg.dtype)[tokens]
-    tables = _rotary_tables(
+    tables = rotary_tables(
         jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), cfg)
     valid = jnp.ones((B, S), bool)
     for lp in params["layers"]:
@@ -313,7 +309,7 @@ def sdar_moe_forward(params: dict, tokens: jax.Array,
         q, k, v = _qkv(h, lp, tables, cfg)
         x = x + _block_attention(q, k, v, cfg) @ lp["wo"].astype(cfg.dtype)
         x, _ = _experts(x, lp, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_untied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -326,7 +322,7 @@ def sdar_moe_forward(params: dict, tokens: jax.Array,
 
 def _cached_embed(params, tokens, step, cfg: SdarMoeConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
-    return x, _rotary_tables(step.pos, cfg)
+    return x, rotary_tables(step.pos, cfg)
 
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: SdarMoeConfig):
@@ -342,20 +338,16 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: SdarMoeConfig):
 
 def _close_state(state: dict, work: dict, step, cfg: SdarMoeConfig):
     # a block pass is this family's decode step
-    kind = int(step.kind == "block")
-    sizes = work["sizes"]
-    out = dict(state)
-    out["pairs"] = state["pairs"].at[kind].set(
-        _count_add(state["pairs"][kind], sum(sizes)))
-    if kind:
-        out["reads"] = _count_add(
-            state["reads"], sum(jnp.sum(s > 0) for s in sizes))
-    return out
+    return {**state, **count_pairs(
+        state, work["sizes"], int(step.kind == "block"))}
 
 
-# no verify step: there is nothing to draft for (a pass fills a block's
-# positions in any order; no next-token distribution is left to check)
-sdar_moe_prefill, sdar_moe_decode_step = cached.block_steps(
-    cached.CachedFamily(
-        "sdar_moe", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "sdar_moe", SdarMoeConfig, "layers", _cached_embed, _cached_layer,
+    final_norm, head_untied, open_state=open_experts,
+    close_state=_close_state,
+    no_verify="there is nothing to draft for (a pass fills a block's "
+              "positions in any order; no next-token distribution is left "
+              "to check)",
+    state_rows=False, gmm_form=step_gmm_form, block_steps=True)
+sdar_moe_prefill, sdar_moe_decode_step, _ = cached.steps(FAMILY)

@@ -41,8 +41,8 @@ reading ONE function here and one in benchmark/reference/smallthinker.py:
 Same conventions as models/laguna.py (a LIST of per-layer trees, float32
 masters, activations in ``cfg.dtype``, the prefill / decode-step contract
 of models/cached.py, K/V by GROUP of layers: ``kv_layout``,
-``kv_table_groups``), whose final norm, head, working state and plain
-windowed attention are used as they are, with what this family forces:
+``kv_table_groups``), with models/parts.py's final norm, head, working
+state and plain windowed attention, and what this family forces:
 
 - The route is taken BEFORE ``attend`` and carried past it to the expert
   layer (``_cached_layer``: ``route -> attend -> experts``): no other
@@ -64,12 +64,19 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import cached
-from ray_tpu.models.laguna import (
-    LagunaConfig, _final_norm, _head, _open_state, _windowed_attention,
+from ray_tpu.models.laguna import LagunaConfig
+from ray_tpu.models.lfm2_moe import lfm2_moe_counters as smallthinker_counters
+from ray_tpu.models.parts import (
+    count_pairs,
+    final_norm,
+    head_untied,
+    leaf_tree,
+    open_experts,
+    rotary_tables,
+    windowed_attention,
 )
-from ray_tpu.models.lfm2_moe import _count_add, lfm2_moe_counters
 from ray_tpu.ops.layers import rms_norm, rope_partial
-from ray_tpu.ops.moe import moe_dropless, moe_route
+from ray_tpu.ops.moe import moe_dropless, moe_route, step_gmm_form
 
 LAYER_KINDS = ("full_attention", "sliding_attention")
 QK_GAIN = 1.4  # ``smallthinker_init``: wq and wk against fan_in ** -0.5
@@ -191,33 +198,25 @@ _LEAF_AXES = {
     "wo": ("mlp", "embed"), "moe_route_w": (None, None),
     "moe_gmm_w_in": ("expert", None, "mlp"),
     "moe_gmm_w_out": ("expert", "mlp", None),
+    "wte": ("vocab", "embed"), "ln_f_scale": ("embed",),
+    "lm_head": ("embed", "vocab"),
 }
 # the contraction axis of each matmul weight; -1: kept as given (norm
 # scales, and the router, which is read in float32)
 _LEAF_QUANT = {"wq": 0, "wk": 0, "wv": 0, "wo": 0,
-               "moe_gmm_w_in": 1, "moe_gmm_w_out": 1}
-
-
-def _leaf_tree(cfg: SmallThinkerConfig, leaf, wte, ln_f, head) -> dict:
-    return {
-        "wte": wte,
-        "layers": [{name: leaf(name) for name in _LEAF_AXES}
-                   for _ in range(cfg.n_layer)],
-        "ln_f_scale": ln_f,
-        "lm_head": head,
-    }
+               "moe_gmm_w_in": 1, "moe_gmm_w_out": 1, "wte": 1, "lm_head": 0}
 
 
 def smallthinker_param_axes(cfg: SmallThinkerConfig) -> dict:
     """Logical axis names per leaf; the experts get an axis of their own."""
-    return _leaf_tree(cfg, _LEAF_AXES.__getitem__, ("vocab", "embed"),
-                      ("embed",), ("embed", "vocab"))
+    return leaf_tree(smallthinker_init, cfg, _LEAF_AXES.__getitem__)
 
 
 def smallthinker_quant_axes(cfg: SmallThinkerConfig) -> dict:
     """Per leaf, the contraction axis of a matmul weight (>= 0: the
     executor stores it in ``cfg.dtype``, experts included) or -1."""
-    return _leaf_tree(cfg, lambda name: _LEAF_QUANT.get(name, -1), 1, -1, 0)
+    return leaf_tree(smallthinker_init, cfg,
+                     lambda name: _LEAF_QUANT.get(name, -1))
 
 
 # ------------------------------------------------------------------ state
@@ -230,9 +229,6 @@ def smallthinker_init_state(cfg: SmallThinkerConfig, slots: int) -> dict:
     del slots
     return {"pairs": jnp.zeros((2, cfg.num_experts, 2), jnp.uint32),
             "reads": jnp.zeros((2,), jnp.uint32)}
-
-
-smallthinker_counters = lfm2_moe_counters
 
 
 # ----------------------------------------------------------------- layers
@@ -262,15 +258,6 @@ def _route(x, h, lp, cfg: SmallThinkerConfig):
     flat = router_input(x, h).reshape(-1, x.shape[-1])
     return moe_route(flat, lp["moe_route_w"], None, cfg.top_k,
                      norm_topk=cfg.norm_topk_prob, score="softmax_topk")
-
-
-def _rotary_tables(pos, cfg: SmallThinkerConfig):
-    """(cos, sin) ``[B, S, hd // 2]`` at the true positions ``pos`` [B, S]:
-    the sliding layers' rotary embedding, over the whole head."""
-    hd = cfg.head_dim
-    ang = pos.astype(jnp.float32)[..., None] / (cfg.rope_theta ** (
-        jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    return jnp.cos(ang), jnp.sin(ang)
 
 
 def _qkv(h, lp, kind: str, tables, cfg: SmallThinkerConfig):
@@ -305,19 +292,19 @@ def smallthinker_forward(params: dict, tokens: jax.Array,
     once, no cache (the program's own full forward)."""
     B, S = tokens.shape
     x = params["wte"].astype(cfg.dtype)[tokens]
-    tables = _rotary_tables(
+    tables = rotary_tables(
         jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), cfg)
     valid = jnp.ones((B, S), bool)
     for lp, kind in zip(params["layers"], cfg.layer_types):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         route = _route(x, h, lp, cfg)
         q, k, v = _qkv(h, lp, kind, tables, cfg)
-        attn = _windowed_attention(
+        attn = windowed_attention(
             q, k, v,
             window_keys(cfg) if kind == "sliding_attention" else None)
         x = x + attn @ lp["wo"].astype(cfg.dtype)
         x, _ = _experts(x, lp, route, cfg, valid)
-    return _head(params, _final_norm(params, x, cfg), cfg)
+    return head_untied(params, final_norm(params, x, cfg), cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -331,7 +318,7 @@ def smallthinker_forward(params: dict, tokens: jax.Array,
 
 def _cached_embed(params, tokens, step, cfg: SmallThinkerConfig):
     x = step.take(params["wte"].astype(cfg.dtype), tokens)
-    return x, _rotary_tables(step.pos, cfg)
+    return x, rotary_tables(step.pos, cfg)
 
 
 def _cached_layer(x, lp, attend, step, work: dict, cfg: SmallThinkerConfig):
@@ -351,19 +338,15 @@ def _cached_layer(x, lp, attend, step, work: dict, cfg: SmallThinkerConfig):
 
 
 def _close_state(state: dict, work: dict, step, cfg: SmallThinkerConfig):
-    kind = int(step.kind == "decode")
-    sizes = work["sizes"]
-    out = dict(state)
-    out["pairs"] = state["pairs"].at[kind].set(
-        _count_add(state["pairs"][kind], sum(sizes)))
-    if kind:
-        out["reads"] = _count_add(
-            state["reads"], sum(jnp.sum(s > 0) for s in sizes))
-    return out
+    return {**state, **count_pairs(
+        state, work["sizes"], int(step.kind == "decode"))}
 
 
-# no verify step: the engine refuses speculation over grouped tables
-smallthinker_prefill, smallthinker_decode_step, _ = cached.steps(
-    cached.CachedFamily(
-        "smallthinker", "layers", _cached_embed, _cached_layer, _final_norm,
-        _head, open_state=_open_state, close_state=_close_state))
+FAMILY = cached.CachedFamily(
+    "smallthinker", SmallThinkerConfig, "layers", _cached_embed,
+    _cached_layer, final_norm, head_untied, open_state=open_experts,
+    close_state=_close_state,
+    no_verify="a rejected window may reach behind freed blocks (the engine "
+              "refuses speculation over grouped tables)",
+    state_rows=False, gmm_form=step_gmm_form)
+smallthinker_prefill, smallthinker_decode_step, _ = cached.steps(FAMILY)

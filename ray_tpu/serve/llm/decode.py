@@ -16,6 +16,7 @@ of a layer each instruction of the compiled program belongs to.
 from __future__ import annotations
 
 import functools
+import importlib
 from dataclasses import dataclass
 from typing import Callable
 
@@ -25,8 +26,9 @@ from ray_tpu.serve.llm import obs
 @dataclass(frozen=True)
 class Family:
     """One model family the engine serves: everything the engine, the
-    executor and ``DecodeFns`` ask about a family, so that adding one
-    means one loader in ``FAMILIES`` below.
+    executor and ``DecodeFns`` ask about a family. It is READ from the
+    family's module (``_family``): adding a family is its model file and
+    one name in ``FAMILIES`` below.
 
     ``init`` / ``prefill`` / ``decode_step`` / ``verify_step`` (None: the
     family has no verify step) are the model's functions, the
@@ -36,7 +38,7 @@ class Family:
     over blocks (``block_steps`` True: its ``decode_step`` is one PASS over
     a block of ``model_cfg.block_length`` positions a row, ids and masked
     bits ``[B, block_length + 1]`` in and out, and its ``prefill`` chooses
-    no token; models/cached.py ``block_steps``). ``param_axes``
+    no token; models/cached.py ``_block_steps``). ``param_axes``
     and ``quant_axes`` map a model config to trees matching ``init``'s
     output; ``default_config()`` is the tiny config an engine built
     without one gets. ``init_state`` is None for a family whose only
@@ -93,151 +95,37 @@ class Family:
     block_steps: bool = False
 
 
-def _gpt() -> Family:
-    from ray_tpu.models import gpt as m
-
-    return Family(m.gpt_init, m.gpt_prefill, m.gpt_decode_step,
-                  m.gpt_verify_step, m.gpt_param_axes, m.gpt_quant_axes,
-                  m.GPTConfig.tiny)
-
-
-def _llama() -> Family:
-    from ray_tpu.models import llama as m
-
-    return Family(m.llama_init, m.llama_prefill, m.llama_decode_step,
-                  m.llama_verify_step, m.llama_param_axes,
-                  m.llama_quant_axes, m.LlamaConfig.tiny)
+# THE registry of served families (``EngineConfig.model`` names a key): the
+# module that holds the family, imported when the family is first asked for
+FAMILIES: dict[str, str] = {
+    name: f"ray_tpu.models.{name}" for name in (
+        "gpt", "llama", "lfm2_moe", "laguna", "evabyte", "pangu_ultra_moe",
+        "smallthinker", "longcat_flash", "minicpm_sala", "ling_hybrid",
+        "sdar_moe")}
 
 
-def _lfm2_moe() -> Family:
-    from ray_tpu.models import lfm2_moe as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: rejected drafts would need the conv state rolled back
-    return Family(m.lfm2_moe_init, m.lfm2_moe_prefill,
-                  m.lfm2_moe_decode_step, None, m.lfm2_moe_param_axes,
-                  m.lfm2_moe_quant_axes, m.Lfm2MoeConfig.tiny,
-                  init_state=m.lfm2_moe_init_state,
-                  counters=m.lfm2_moe_counters, gmm_form=step_gmm_form)
-
-
-def _laguna() -> Family:
-    from ray_tpu.models import laguna as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: a rejected window may reach behind freed blocks
-    return Family(m.laguna_init, m.laguna_prefill, m.laguna_decode_step,
-                  None, m.laguna_param_axes, m.laguna_quant_axes,
-                  m.LagunaConfig.tiny, init_state=m.laguna_init_state,
-                  counters=m.laguna_counters, gmm_form=step_gmm_form)
-
-
-def _evabyte() -> Family:
-    from ray_tpu.models import evabyte as m
-
-    # no verify step: a rejected draft would already be in its chunk's sum
-    return Family(m.evabyte_init, m.evabyte_prefill, m.evabyte_decode_step,
-                  None, m.evabyte_param_axes, m.evabyte_quant_axes,
-                  m.EvaByteConfig.tiny)
-
-
-def _pangu_ultra_moe() -> Family:
-    from ray_tpu.models import pangu_ultra_moe as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: nothing drafts (the prediction module is not held)
-    return Family(m.pangu_ultra_moe_init, m.pangu_ultra_moe_prefill,
-                  m.pangu_ultra_moe_decode_step, None,
-                  m.pangu_ultra_moe_param_axes, m.pangu_ultra_moe_quant_axes,
-                  m.PanguUltraMoEConfig.tiny,
-                  init_state=m.pangu_ultra_moe_init_state,
-                  counters=m.pangu_ultra_moe_counters, state_rows=False,
-                  step_attrs=m.step_attrs, gmm_form=step_gmm_form)
-
-
-def _smallthinker() -> Family:
-    from ray_tpu.models import smallthinker as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: a rejected window may reach behind freed blocks
-    return Family(m.smallthinker_init, m.smallthinker_prefill,
-                  m.smallthinker_decode_step, None,
-                  m.smallthinker_param_axes, m.smallthinker_quant_axes,
-                  m.SmallThinkerConfig.tiny,
-                  init_state=m.smallthinker_init_state,
-                  counters=m.smallthinker_counters, state_rows=False,
-                  gmm_form=step_gmm_form)
-
-
-def _longcat_flash() -> Family:
-    from ray_tpu.models import longcat_flash as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: nothing drafts; the latent family's refusals apply
-    return Family(m.longcat_flash_init, m.longcat_flash_prefill,
-                  m.longcat_flash_decode_step, None,
-                  m.longcat_flash_param_axes, m.longcat_flash_quant_axes,
-                  m.LongCatFlashConfig.tiny,
-                  init_state=m.longcat_flash_init_state,
-                  counters=m.longcat_flash_counters, state_rows=False,
-                  step_attrs=m.step_attrs, gmm_form=step_gmm_form)
-
-
-def _minicpm_sala() -> Family:
-    from ray_tpu.models import minicpm_sala as m
-
-    # no verify step: rejected drafts would need the lightning state (a
-    # matrix a head a sequence) rolled back
-    return Family(m.minicpm_sala_init, m.minicpm_sala_prefill,
-                  m.minicpm_sala_decode_step, None,
-                  m.minicpm_sala_param_axes, m.minicpm_sala_quant_axes,
-                  m.MiniCPMSALAConfig.tiny,
-                  init_state=m.minicpm_sala_init_state,
-                  counters=m.minicpm_sala_counters,
-                  block_state_bytes=m.block_state_bytes,
-                  step_attrs=m.step_attrs,
-                  donated_state_counters=m.COUNTER_LEAVES)
-
-
-def _ling_hybrid() -> Family:
-    from ray_tpu.models import ling_hybrid as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: rejected drafts would need the KDA state (a matrix
-    # a head a sequence, and the convolution's rows) rolled back
-    return Family(m.ling_hybrid_init, m.ling_hybrid_prefill,
-                  m.ling_hybrid_decode_step, None,
-                  m.ling_hybrid_param_axes, m.ling_hybrid_quant_axes,
-                  m.LingHybridConfig.tiny,
-                  init_state=m.ling_hybrid_init_state,
-                  counters=m.ling_hybrid_counters,
-                  step_attrs=m.step_attrs, gmm_form=step_gmm_form,
-                  donated_state_counters=m.COUNTER_LEAVES)
-
-
-def _sdar_moe() -> Family:
-    from ray_tpu.models import sdar_moe as m
-    from ray_tpu.ops.moe import step_gmm_form
-
-    # no verify step: there is nothing to draft for (a pass fills a
-    # block's positions in any order)
-    return Family(m.sdar_moe_init, m.sdar_moe_prefill,
-                  m.sdar_moe_decode_step, None, m.sdar_moe_param_axes,
-                  m.sdar_moe_quant_axes, m.SdarMoeConfig.tiny,
-                  init_state=m.sdar_moe_init_state,
-                  counters=m.sdar_moe_counters, state_rows=False,
-                  gmm_form=step_gmm_form, block_steps=True)
-
-
-# THE registry of served families (``EngineConfig.model`` names a key);
-# each entry imports its model file when it is first asked for
-FAMILIES: dict[str, Callable[[], Family]] = {
-    "gpt": _gpt, "llama": _llama, "lfm2_moe": _lfm2_moe,
-    "laguna": _laguna, "evabyte": _evabyte,
-    "pangu_ultra_moe": _pangu_ultra_moe, "smallthinker": _smallthinker,
-    "longcat_flash": _longcat_flash, "minicpm_sala": _minicpm_sala,
-    "ling_hybrid": _ling_hybrid, "sdar_moe": _sdar_moe,
-}
+@functools.cache
+def _family(name: str) -> Family:
+    """The engine's view of the family in ``FAMILIES[name]``, read from its
+    module: the functions by their names (``<name>_init``, ``_prefill``,
+    ``_decode_step``, ``_param_axes``, ``_quant_axes``, which every family
+    has; ``_init_state`` and ``_counters`` where it has them) and the rest
+    from the record the module declares (``FAMILY``: models/cached.py
+    ``CachedFamily``). The record alone says whether there is a
+    ``<name>_verify_step``: ``no_verify`` holds the reason there is none."""
+    m = importlib.import_module(FAMILIES[name])
+    rec = m.FAMILY
+    return Family(
+        **{f: getattr(m, f"{name}_{f}") for f in (
+            "init", "prefill", "decode_step", "param_axes", "quant_axes")},
+        verify_step=(None if rec.no_verify
+                     else getattr(m, f"{name}_verify_step")),
+        **{f: getattr(m, f"{name}_{f}", None)
+           for f in ("init_state", "counters")},
+        default_config=rec.config.tiny,
+        **{f: getattr(rec, f) for f in (
+            "state_rows", "block_state_bytes", "step_attrs", "gmm_form",
+            "donated_state_counters", "block_steps")})
 
 
 def get_family(name: str) -> Family:
@@ -247,7 +135,7 @@ def get_family(name: str) -> Family:
             f"unknown model family {name!r}; expected one of "
             f"{sorted(FAMILIES)}"
         )
-    return FAMILIES[name]()
+    return _family(name)
 
 
 def family_param_axes(name: str, model_cfg):

@@ -21,6 +21,10 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 # compiles matter. Keyed by jax version + backend + program hash, so hits
 # return byte-identical executables; thresholds are zeroed because the
 # tiny-model compiles this suite repeats are individually sub-second.
+# It stays ONE directory for every worker: an entry's key holds the
+# directory's path, and ``--dist load`` hands a worker other tests every
+# run, so a directory a worker never warms (PR 56: 270-340 s for six
+# modules that take 116-143 s on the shared one).
 _cache_dir = os.path.join(
     os.environ.get("TMPDIR", "/tmp"), "ray_tpu_jax_test_cache"
 )
@@ -106,6 +110,48 @@ def jax_cpu():
     devices = jax.devices()
     assert len(devices) >= 8, f"need 8 virtual devices, got {len(devices)}"
     return jax
+
+
+def _mappings() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to count, nothing to guard
+        return 0
+
+
+try:
+    with open("/proc/sys/vm/max_map_count") as _f:
+        _MAX_MAPPINGS = int(_f.read())
+except (OSError, ValueError):
+    _MAX_MAPPINGS = 65530  # the kernel's default
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_runtest_teardown(item, nextitem):
+    """Keep a test process under the kernel's limit of memory mappings.
+
+    A loaded XLA:CPU executable holds about 60 mappings and jax's caches
+    keep every one a process ever ran; an xdist worker of the whole suite
+    loads over a thousand. At ``vm.max_map_count`` (65,530) the next
+    ``mmap`` fails inside ``backend.deserialize_executable`` and the worker
+    dies there (``Segmentation fault`` / ``Aborted`` under
+    ``compilation_cache.get_executable_and_time``): five or six workers a
+    run, each once, a different test each time (PR 56 sampled the workers:
+    those lost had last stood at 64,625, 65,045 and 65,440). So past half
+    the limit the caches are dropped; what is needed again comes back from
+    the persistent cache above. Between modules, after the last one's
+    fixtures are gone (``trylast``), since a test that counts compiles does
+    so within its module; past three quarters, wherever it is."""
+    same_module = nextitem is not None and (
+        getattr(nextitem, "module", None) is getattr(item, "module", None))
+    if _mappings() > (0.75 if same_module else 0.5) * _MAX_MAPPINGS:
+        import gc
+
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
 
 
 @pytest.hookimpl(tryfirst=True)

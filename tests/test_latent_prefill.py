@@ -358,7 +358,7 @@ def test_a_familys_chunk_program_in_both_forms(models, monkeypatch, family,
     """The chunk program over pieces of two sequences, one with a resident
     prefix of one and a half key blocks: its logits in the EXPANDED form
     are those of the same program made to take the ABSORBED form (``step``
-    handed to ``_cached_heads`` as a decode step's) and the reference's
+    handed to ``cached_heads`` as a decode step's) and the reference's
     full forward; longcat's two sub-layers and its two rescalings ride in
     ``q`` and in the stored ``c``."""
     import importlib
@@ -366,7 +366,7 @@ def test_a_familys_chunk_program_in_both_forms(models, monkeypatch, family,
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import pangu_ultra_moe as pangu
+    from ray_tpu.models import parts
     from ray_tpu.ops import latent_prefill as lp
     from ray_tpu.serve.llm.decode import get_family
 
@@ -385,10 +385,10 @@ def test_a_familys_chunk_program_in_both_forms(models, monkeypatch, family,
     layers = getattr(cfg, "n_kv_layer", cfg.n_layer)
 
     def run(absorbed):
-        heads = pangu._cached_heads
+        heads = parts.cached_heads
         if absorbed:
             monkeypatch.setattr(
-                m, "_cached_heads", lambda *args: heads(
+                m, "cached_heads", lambda *args: heads(
                     *args[:6], args[6]._replace(kind="decode"), args[7]))
         k, v = jnp.zeros((layers, 1 + 2 * NB, bs, sum(
             stored for _, _, stored in cfg.kv_planes))), None
@@ -413,7 +413,7 @@ def test_a_familys_chunk_program_in_both_forms(models, monkeypatch, family,
                                       tables[1], np.zeros(NB, np.int32)])),
                 cfg, start=jnp.asarray([48, 64, 0, 16, 0]), state=state,
                 slots=jnp.asarray([1, 1, 1, 1, 0], jnp.int32))
-        monkeypatch.setattr(m, "_cached_heads", heads)
+        monkeypatch.setattr(m, "cached_heads", heads)
         return np.asarray(out)
 
     expanded, absorbed = run(False), run(True)

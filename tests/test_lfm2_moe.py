@@ -525,10 +525,10 @@ def test_stats_reads_the_device_outside_the_lock(tiny):
 def test_counter_words_carry(jax_cpu):
     import jax.numpy as jnp
 
-    from ray_tpu.models.lfm2_moe import _count_add, count_value
+    from ray_tpu.models.parts import count_add, count_value
 
     acc = jnp.asarray([[2 ** 32 - 3, 0], [7, 1]], jnp.uint32)
-    out = _count_add(acc, jnp.asarray([5, 1]))
+    out = count_add(acc, jnp.asarray([5, 1]))
     assert count_value(out).tolist() == [2 ** 32 + 2, 2 ** 32 + 8]
 
 

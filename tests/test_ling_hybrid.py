@@ -312,8 +312,8 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, ref):
     assert int(sizes.sum()) == 2 * 9 * cfg.top_k and int(met) == 18
     size = cfg.num_experts // cfg.n_group
     z = m.rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    shared = m._swiglu(z, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
-                       cfg.dtype)
+    shared = m.swiglu(z, lp["moe_shared_w_in"], lp["moe_shared_w_out"],
+                      cfg.dtype)
     total, pairs, tokens = shared, 0, 0
     for g in range(cfg.n_group):
         held = (g * size, size)

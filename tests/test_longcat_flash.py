@@ -161,6 +161,7 @@ def test_absorbed_attention_is_the_expanded_one_sublayer(tiny, ref):
     import jax.numpy as jnp
 
     from ray_tpu.models import longcat_flash as m
+    from ray_tpu.models.parts import absorb, unabsorb
 
     cfg, params = tiny
     sp = params["layers"][1]["sub"][1]
@@ -168,17 +169,17 @@ def test_absorbed_attention_is_the_expanded_one_sublayer(tiny, ref):
     pos = np.broadcast_to(np.arange(29, dtype=np.int32), (2, 29))
 
     def absorbed_attention(q_nope, q_rope, c, k_r):
-        s = (jnp.einsum("bshc,btc->bhst", m._absorb(q_nope, sp, cfg), c)
+        s = (jnp.einsum("bshc,btc->bhst", absorb(q_nope, sp, cfg), c)
              + jnp.einsum("bshr,btr->bhst", q_rope, k_r)) * cfg.softmax_scale
         t = jnp.arange(c.shape[1])
         p = jax.nn.softmax(
             jnp.where(t[None, :] <= t[:, None], s, -1e30), axis=-1)
-        return m._unabsorb(jnp.einsum("bhst,btc->bshc", p, c), sp, cfg)
+        return unabsorb(jnp.einsum("bhst,btc->bshc", p, c), sp, cfg)
 
     with jax.default_matmul_precision("highest"):
-        rot = m._rotary_at(pos, cfg)
+        rot = m.rotary_at(pos, cfg)
         parts = m._rows(u, sp, *rot, cfg)
-        plain = m._queries_and_row(u, sp, *rot, cfg)
+        plain = m.queries_and_row(u, sp, *rot, cfg)
         absorbed = absorbed_attention(*parts)
         expanded = m.expanded_attention(*parts, sp, cfg)
         unscaled = m.expanded_attention(*plain, sp, cfg)
@@ -214,7 +215,7 @@ def test_the_init_keeps_scores_neither_flat_nor_one_hot(jax_cpu):
     sp = m.longcat_flash_init(jax.random.PRNGKey(5), cfg)["layers"][0]["sub"][0]
     u = jax.random.normal(jax.random.PRNGKey(6), (1, 64, cfg.d_model))
     pos = jnp.arange(64, dtype=jnp.int32)[None]
-    q_nope, q_rope, c, k_r = m._rows(u, sp, *m._rotary_at(pos, cfg), cfg)
+    q_nope, q_rope, c, k_r = m._rows(u, sp, *m.rotary_at(pos, cfg), cfg)
     k_nope = (c @ sp["mla_w_uk"]).reshape(1, 64, cfg.n_head, -1)
     s = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope)
          + jnp.einsum("bshr,btr->bhst", q_rope, k_r)) * cfg.softmax_scale
@@ -224,22 +225,23 @@ def test_the_init_keeps_scores_neither_flat_nor_one_hot(jax_cpu):
 
 
 def test_rotary_pairs_are_by_halves(jax_cpu, ref):
-    """The ONE rotary function (pangu's, imported) and the reference's turn
-    the same pairs (i, i + R / 2)."""
+    """The ONE rotary function (models/parts.py's, which pangu's file
+    names too) and the reference's turn the same pairs (i, i + R / 2)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import longcat_flash as m
     from ray_tpu.models import pangu_ultra_moe as pangu
+    from ray_tpu.models import parts
 
-    for shared in ("_absorb", "_unabsorb", "_queries_and_row",
-                   "_rotary_at", "expanded_attention"):
-        assert getattr(m, shared) is getattr(pangu, shared), shared
-    assert m._swiglu is pangu._swiglu
+    for shared in ("cached_heads", "queries_and_row", "rotary_at",
+                   "expanded_attention", "swiglu"):
+        assert getattr(m, shared) is getattr(pangu, shared) \
+            is getattr(parts, shared), shared
     cfg = m.LongCatFlashConfig.tiny()
     x = jax.random.normal(jax.random.PRNGKey(4), (9, 3, 4))
     pos = jnp.arange(9, dtype=jnp.int32)[None]
-    got = pangu._rotate(x[None], *m._rotary_at(pos, cfg))[0]
+    got = parts.rotate(x[None], *m.rotary_at(pos, cfg))[0]
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref._rotate(x, cfg.rope_theta)),
                                atol=1e-6)

@@ -167,6 +167,7 @@ def test_absorbed_attention_is_the_expanded_one_layer(tiny, ref):
     import jax.numpy as jnp
 
     from ray_tpu.models import pangu_ultra_moe as m
+    from ray_tpu.models.parts import absorb, unabsorb
 
     cfg, params = tiny
     lp = params["layers"][1]
@@ -176,15 +177,15 @@ def test_absorbed_attention_is_the_expanded_one_layer(tiny, ref):
         # what the cached step computes against the pool, written out:
         # W_uk absorbed into the query, ONE row a token as key and value,
         # W_uv after the sum
-        s = (jnp.einsum("bshc,btc->bhst", m._absorb(q_nope, lp, cfg), c)
+        s = (jnp.einsum("bshc,btc->bhst", absorb(q_nope, lp, cfg), c)
              + jnp.einsum("bshr,btr->bhst", q_rope, k_r)) * cfg.softmax_scale
         t = jnp.arange(c.shape[1])
         p = jax.nn.softmax(
             jnp.where(t[None, :] <= t[:, None], s, -1e30), axis=-1)
-        return m._unabsorb(jnp.einsum("bhst,btc->bshc", p, c), lp, cfg)
+        return unabsorb(jnp.einsum("bhst,btc->bshc", p, c), lp, cfg)
 
     with jax.default_matmul_precision("highest"):
-        parts = m._queries_and_row(u, lp, *m._rotary_at(pos, cfg), cfg)
+        parts = m.queries_and_row(u, lp, *m.rotary_at(pos, cfg), cfg)
         absorbed = absorbed_attention(*parts)
         expanded = m.expanded_attention(*parts, lp, cfg)
         want = np.stack([np.asarray(ref.attention(u[b], lp, cfg))
@@ -202,12 +203,13 @@ def test_rotary_pairs_are_by_halves(jax_cpu, ref):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models import parts
     from ray_tpu.models import pangu_ultra_moe as m
 
     cfg = m.PanguUltraMoEConfig.tiny()
     x = jax.random.normal(jax.random.PRNGKey(4), (9, 3, 4))
     pos = jnp.arange(9, dtype=jnp.int32)[None]
-    got = m._rotate(x[None], *m._rotary_at(pos, cfg))[0]
+    got = parts.rotate(x[None], *parts.rotary_at(pos, cfg))[0]
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref._rotate(x, cfg.rope_theta)),
                                atol=1e-6)

@@ -669,6 +669,60 @@ def test_cached_step_is_written_once(name):
     assert not offenders, f"{name} outside models/cached.py: {offenders}"
 
 
+def _model_imports():
+    """``(file, line, module, names)`` of every import under
+    ray_tpu/models/ that names a ``ray_tpu`` module."""
+    import ast
+    import pathlib
+
+    models = pathlib.Path(__file__).resolve().parents[1] / "ray_tpu" / "models"
+    out = []
+    for path in sorted(models.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text(), str(path))):
+            if isinstance(node, ast.ImportFrom) and node.module and \
+                    node.module.startswith("ray_tpu"):
+                out.append((path.name, node.lineno, node.module,
+                            [a.name for a in node.names]))
+            elif isinstance(node, ast.Import):
+                out.extend((path.name, node.lineno, a.name, [])
+                           for a in node.names if a.name.startswith("ray_tpu"))
+    return out
+
+
+def test_families_share_through_parts():
+    """ISSUE 56: what two families compute the same way lives ONCE in
+    models/parts.py (beside the cached step of models/cached.py), under a
+    public name. A file under ray_tpu/models/ takes no underscored name
+    from another file there and imports no family's module whole (its
+    private names would be an attribute away): only ``cached`` and
+    ``parts`` are imported as modules, a family's file at most for a
+    public config or state function."""
+    shared = {"cached", "parts"}
+    imports = _model_imports()
+    assert any(mod == "ray_tpu.models.parts" for _, _, mod, _ in imports)
+    offenders = []
+    for name, line, mod, names in imports:
+        if mod == "ray_tpu.models":
+            bad = [n for n in names if n not in shared]
+        elif mod.startswith("ray_tpu.models.") and name != "__init__.py":
+            bad = [n for n in names if n.startswith("_")]
+            if not names:  # ``import ray_tpu.models.<file>``
+                bad = [mod]
+        else:
+            continue
+        offenders += [f"{name}:{line} {mod} {n}" for n in bad]
+    assert not offenders, offenders
+
+
+def test_models_import_nothing_of_serve():
+    """ISSUE 56: the layering is one way. serve/llm/decode.py READS a
+    family from its model file; no file under ray_tpu/models/ imports
+    ``ray_tpu.serve``."""
+    offenders = [f"{name}:{line} {mod}" for name, line, mod, _ in
+                 _model_imports() if mod.startswith("ray_tpu.serve")]
+    assert not offenders, offenders
+
+
 def test_no_full_pool_dequant_outside_attention_kernels():
     """Quantized-serving lint (ISSUE 20): a quantized KV pool must be
     dequantized IN-REGISTER inside the attention paths — the Pallas

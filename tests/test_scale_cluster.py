@@ -1,4 +1,4 @@
-"""Control-plane scale: 150 in-process raylets against one GCS.
+"""Control-plane scale: many in-process raylets against one GCS.
 
 The reference's envelope is 2k nodes / 10k concurrent tasks
 (release/benchmarks/README.md:9-11); this box can't host that, but 150
@@ -8,17 +8,28 @@ eating the GCS, delta-sync payloads growing with cluster size instead
 of with changes, and dispatch latency degrading with node count. Bounds
 are pinned near today's measured numbers (heartbeat handler ~0.03 ms
 CPU, dispatch p50 ~9 ms on this 1-core box), not 10x headroom — a 10x
-regression must FAIL here."""
+regression must FAIL here.
+
+The module runs at two sizes. 40 nodes is tier-1's: the cluster comes up
+in under a minute beside five busy xdist workers, ``count >= n_nodes`` and
+the O(changes) assertions scale with the size, and the latency bounds are
+upper bounds at any size. The 150-node case is marked ``slow``: its
+fixture alone held one worker for 411 of a 1,013 s tier-1 run (PR 55's),
+and under six-way load its heartbeat count fell short of its own bound."""
 import time
 
 import pytest
 
 
-N_NODES = 150
+@pytest.fixture(scope="module", params=[
+    pytest.param(40, id="40-nodes"),
+    pytest.param(150, id="150-nodes", marks=pytest.mark.slow)])
+def n_nodes(request):
+    return request.param
 
 
 @pytest.fixture(scope="module")
-def big_cluster():
+def big_cluster(n_nodes):
     from ray_tpu._private.node import Cluster
     import ray_tpu
     from ray_tpu._private.ids import JobID
@@ -26,7 +37,7 @@ def big_cluster():
 
     cluster = Cluster(head_resources={"CPU": 2})
     # lightweight members: tiny object stores, 1 CPU each
-    for _ in range(N_NODES - 1):
+    for _ in range(n_nodes - 1):
         cluster.add_node(num_cpus=1, object_store_memory=8 * 1024 * 1024)
     job_id = JobID(cluster.head.raylet.gcs.call("next_job_id")["job_id"])
     core = CoreWorker(
@@ -44,54 +55,54 @@ def big_cluster():
     cluster.shutdown()
 
 
-def _wait_all_visible(cluster, timeout=60.0):
+def _wait_all_visible(cluster, n_nodes, timeout=60.0):
     import ray_tpu
 
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         alive = [n for n in ray_tpu.nodes() if n["alive"]]
-        if len(alive) >= N_NODES:
+        if len(alive) >= n_nodes:
             return alive
         time.sleep(0.5)
-    raise AssertionError(f"only {len(alive)} of {N_NODES} nodes registered")
+    raise AssertionError(f"only {len(alive)} of {n_nodes} nodes registered")
 
 
-def test_all_nodes_register_and_sync(big_cluster):
-    alive = _wait_all_visible(big_cluster)
-    assert len(alive) == N_NODES
+def test_all_nodes_register_and_sync(big_cluster, n_nodes):
+    alive = _wait_all_visible(big_cluster, n_nodes)
+    assert len(alive) == n_nodes
 
 
-def test_heartbeat_fanin_stays_bounded(big_cluster):
-    """150 nodes x 1 Hz heartbeats: the GCS handler must spend well under
+def test_heartbeat_fanin_stays_bounded(big_cluster, n_nodes):
+    """N nodes x 1 Hz heartbeats: the GCS handler must spend well under
     a tenth of one core on them. CPU-time stats (not wall: 150 in-process
     raylets share one GIL, so wall mostly measures the scheduler)."""
     from ray_tpu._private import event_stats
 
-    _wait_all_visible(big_cluster)
+    _wait_all_visible(big_cluster, n_nodes)
     time.sleep(2.0)  # settle boot-time churn out of the window
     event_stats.reset()
     window = 5.0
     time.sleep(window)
     snap = event_stats.snapshot()
     hb = snap.get("rpc.gcs.heartbeat.cpu")
-    assert hb is not None and hb["count"] >= N_NODES, (
-        f"expected ≥{N_NODES} heartbeats in {window}s, saw {hb}")
+    assert hb is not None and hb["count"] >= n_nodes, (
+        f"expected ≥{n_nodes} heartbeats in {window}s, saw {hb}")
     busy_frac = hb["total_ms"] / 1000.0 / window
     # measured 0.4% of a core at 150 nodes; the bound catches a 10x
     # regression while staying under the round-4 review's <10% bar
     assert busy_frac < 0.05, (
         f"heartbeat fan-in consumed {busy_frac:.1%} of a core at "
-        f"{N_NODES} nodes — O(N) handler work")
+        f"{n_nodes} nodes — O(N) handler work")
     # no single heartbeat scans the world: measured mean ~0.03 ms CPU —
     # an O(N) delta read would push this past 1 ms at 150 nodes
     assert hb["mean_ms"] < 1.0, hb
 
 
-def test_delta_sync_payload_is_o_changes(big_cluster):
+def test_delta_sync_payload_is_o_changes(big_cluster, n_nodes):
     """A settled cluster's heartbeat replies carry EMPTY deltas — payload
     scales with changes, not with node count."""
     cluster = big_cluster
-    _wait_all_visible(cluster)
+    _wait_all_visible(cluster, n_nodes)
     gcs = cluster.head.raylet.gcs
     # one full pull to get current seq, then quiesce and re-ask
     first = gcs.call("heartbeat", {
@@ -99,7 +110,7 @@ def test_delta_sync_payload_is_o_changes(big_cluster):
         "available": {}, "load": 0, "pending_shapes": [],
         "seen_seq": 0,
     })
-    assert len(first.get("delta", ())) >= N_NODES  # cold sync sees everyone
+    assert len(first.get("delta", ())) >= n_nodes  # cold sync sees everyone
     seq = first["seq"]
     time.sleep(3.5)  # several heartbeat periods of steady state
     # re-baseline once: late boot-time churn (a node's first load report)
@@ -122,14 +133,14 @@ def test_delta_sync_payload_is_o_changes(big_cluster):
     assert not reply.get("full")
 
 
-def test_dispatch_latency_not_degraded_by_node_count(big_cluster):
+def test_dispatch_latency_not_degraded_by_node_count(big_cluster, n_nodes):
     """Serial task round-trips on the head node must stay in the
-    tens-of-ms band with 149 idle peers registered: the dispatch path may
+    tens-of-ms band with the idle peers registered: the dispatch path may
     not scan or wait on the cluster. p50 is pinned near today's ~9 ms;
     p90 absorbs this 1-core box's scheduling noise."""
     import ray_tpu
 
-    _wait_all_visible(big_cluster)
+    _wait_all_visible(big_cluster, n_nodes)
 
     @ray_tpu.remote(num_cpus=1)
     def f(x):
@@ -145,6 +156,6 @@ def test_dispatch_latency_not_degraded_by_node_count(big_cluster):
     lat.sort()
     p50, p90 = lat[len(lat) // 2], lat[int(len(lat) * 0.9)]
     assert p50 < 0.05, (
-        f"dispatch p50 {p50 * 1e3:.0f} ms at {N_NODES} nodes "
+        f"dispatch p50 {p50 * 1e3:.0f} ms at {n_nodes} nodes "
         "(measured ~9 ms — this is a big regression)")
-    assert p90 < 0.25, f"dispatch p90 {p90 * 1e3:.0f} ms at {N_NODES} nodes"
+    assert p90 < 0.25, f"dispatch p90 {p90 * 1e3:.0f} ms at {n_nodes} nodes"

@@ -647,3 +647,124 @@ def test_first_call_of_a_signature_gets_stack_room(depths):
         assert fns._call(step, sig, 1, 2, sample=3) == ((1, 2), {"sample": 3})
     assert frames == ["_with_stack_room", "_call", "_call"]
     assert seen == [sig] and fns.signatures == {sig}
+
+
+# What the engine asks of each served family (``decode.Family``), as the
+# hand-written factory of each gave it before ISSUE 56 and as ``get_family``
+# now READS it from the family's module and its ``CachedFamily`` record:
+# (config class, verify step, state functions, state_rows, block_steps,
+# which of the optional callables are set, donated_state_counters).
+FAMILY_FACTS = {
+    "gpt": ("GPTConfig", True, False, True, False, (), None),
+    "llama": ("LlamaConfig", True, False, True, False, (), None),
+    "lfm2_moe": ("Lfm2MoeConfig", False, True, True, False,
+                 ("gmm_form",), None),
+    "laguna": ("LagunaConfig", False, True, True, False,
+               ("gmm_form",), None),
+    "evabyte": ("EvaByteConfig", False, False, True, False, (), None),
+    "pangu_ultra_moe": ("PanguUltraMoEConfig", False, True, False, False,
+                        ("step_attrs", "gmm_form"), None),
+    "smallthinker": ("SmallThinkerConfig", False, True, False, False,
+                     ("gmm_form",), None),
+    "longcat_flash": ("LongCatFlashConfig", False, True, False, False,
+                      ("step_attrs", "gmm_form"), None),
+    "minicpm_sala": ("MiniCPMSALAConfig", False, True, True, False,
+                     ("block_state_bytes", "step_attrs"),
+                     ("steps", "blocks")),
+    "ling_hybrid": ("LingHybridConfig", False, True, True, False,
+                    ("step_attrs", "gmm_form"),
+                    ("pairs", "routed", "reads", "groups")),
+    "sdar_moe": ("SdarMoeConfig", False, True, False, True,
+                 ("gmm_form",), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FAMILY_FACTS))
+def test_a_family_is_read_from_its_module(jax_cpu, name):
+    """ISSUE 56: ``FAMILIES`` is a table name -> module, and the fifteen
+    fields of the engine's ``Family`` are read from the module: its
+    functions by their names, the rest from the record it declares."""
+    import importlib
+
+    from ray_tpu.models import cached
+    from ray_tpu.ops.moe import step_gmm_form
+    from ray_tpu.serve.llm import decode
+
+    assert set(decode.FAMILIES) == set(FAMILY_FACTS)
+    config, verifies, has_state, rows, blocks, optional, donated = \
+        FAMILY_FACTS[name]
+    fam = decode.get_family(name)
+    assert fam is decode.get_family(name)
+    m = importlib.import_module(decode.FAMILIES[name])
+    assert isinstance(m.FAMILY, cached.CachedFamily)
+    assert m.FAMILY.name == name
+    for field in ("init", "prefill", "decode_step", "param_axes",
+                  "quant_axes"):
+        assert getattr(fam, field) is getattr(m, f"{name}_{field}"), field
+    assert fam.prefill.__name__ == f"{name}_prefill"
+    assert fam.decode_step.__name__ == f"{name}_decode_step"
+    assert (fam.verify_step is not None) == verifies
+    assert (m.FAMILY.no_verify is None) == verifies
+    # the record alone says whether the module holds a verify step
+    assert hasattr(m, f"{name}_verify_step") == verifies
+    if verifies:
+        assert fam.verify_step is getattr(m, f"{name}_verify_step")
+    cfg = fam.default_config()
+    assert type(cfg).__name__ == config and cfg == getattr(m, config).tiny()
+    assert (fam.init_state is not None) == has_state
+    assert (fam.counters is not None) == has_state
+    if has_state:
+        assert fam.init_state is getattr(m, f"{name}_init_state")
+        assert fam.counters is getattr(m, f"{name}_counters")
+    assert fam.state_rows is rows and fam.block_steps is blocks
+    for field in ("block_state_bytes", "step_attrs", "gmm_form"):
+        assert (getattr(fam, field) is not None) == (field in optional), field
+    if "gmm_form" in optional:
+        assert fam.gmm_form is step_gmm_form
+    assert fam.donated_state_counters == donated
+    assert len(dataclasses.fields(fam)) == 15
+
+
+def test_a_family_that_lacks_a_required_name_fails_where_it_is_read(
+        monkeypatch):
+    """A misnamed ``<name>_quant_axes`` is an AttributeError that names it,
+    raised by ``get_family``, not a None that fails in the executor."""
+    import sys
+    import types
+
+    from ray_tpu.models import gpt
+    from ray_tpu.serve.llm import decode
+
+    fake = types.ModuleType("fake_family")
+    fake.FAMILY = gpt.FAMILY
+    for field in ("init", "prefill", "decode_step", "verify_step",
+                  "param_axes"):
+        setattr(fake, f"fake_{field}", getattr(gpt, f"gpt_{field}"))
+    monkeypatch.setitem(sys.modules, "fake_family", fake)
+    monkeypatch.setitem(decode.FAMILIES, "fake", "fake_family")
+    with pytest.raises(AttributeError, match="fake_quant_axes"):
+        decode.get_family("fake")
+
+
+def test_a_familys_module_is_imported_when_it_is_first_asked_for():
+    """``get_family`` imports LAZILY: importing the registry imports no
+    family's file, and asking for one family imports that one (and what
+    its file names of others', no more)."""
+    import subprocess
+    import sys
+
+    code = """
+import sys
+from ray_tpu.serve.llm import decode
+held = [n for n in decode.FAMILIES if decode.FAMILIES[n] in sys.modules]
+assert not held, held
+decode.get_family("evabyte")
+held = [n for n in decode.FAMILIES if decode.FAMILIES[n] in sys.modules]
+assert "evabyte" in held and "sdar_moe" not in held, held
+print("lazy", len(decode.FAMILIES))
+"""
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "lazy 11"
+

@@ -300,7 +300,7 @@ def test_full_layers_carry_no_position_and_sliding_layers_do(tiny):
     lp = params["layers"][1]
     h = jax.random.normal(jax.random.PRNGKey(5), (1, 12, cfg.d_model))
     pos = jnp.arange(12, dtype=jnp.int32)[None]
-    at = {name: m._rotary_tables(p, cfg) for name, p in (
+    at = {name: m.rotary_tables(p, cfg) for name, p in (
         ("plain", pos), ("stretched", 2 * pos + 3), ("shifted", pos + 40))}
     full = {n: m._qkv(h, lp, "full_attention", t, cfg) for n, t in at.items()}
     slid = {n: m._qkv(h, lp, "sliding_attention", t, cfg)

@@ -9,14 +9,39 @@ import tempfile
 import pytest
 
 
-def test_sync_hyperband_cuts_at_barrier(ray_start):
+def test_sync_hyperband_cuts_at_barrier(ray_start, tmp_path):
     from ray_tpu import tune
 
+    base = str(tmp_path)
+
     def trainable(config):
+        # Every report carries a checkpoint, so a trial that reaches the
+        # band's milestone first is PARKED there until its peers arrive and
+        # all are judged at the same budget, however late a peer's worker
+        # started. A trial without one free-runs past the milestone (the
+        # scheduler's fallback) and is then judged at whatever iteration it
+        # has reached: with ``acc`` growing by the iteration a trial that
+        # started a quarter of a second early outranked a better one (two
+        # runs in ten beside five busy workers: ``assert 1.0 == 2.0``).
+        import json
+        import os
         import time
 
-        for i in range(16):
-            tune.report({"acc": config["q"] * (i + 1)})
+        from ray_tpu.train import Checkpoint
+
+        start = 0
+        ckpt = tune.get_checkpoint()
+        if ckpt:
+            with open(os.path.join(ckpt.path, "i.json")) as f:
+                start = json.load(f)["i"]
+        for i in range(start, 16):
+            d = os.path.join(base, f"q{config['q']}-{i}")
+            os.makedirs(d, exist_ok=True)
+            with open(os.path.join(d, "i.json"), "w") as f:
+                json.dump({"i": i + 1}, f)
+            tune.report({"acc": config["q"] * (i + 1),
+                         "training_iteration": i + 1},
+                        checkpoint=Checkpoint.from_directory(d))
             time.sleep(0.05)
 
     results = tune.Tuner(
@@ -37,6 +62,69 @@ def test_sync_hyperband_cuts_at_barrier(ray_start):
     assert iters[0] < 16 and iters[-1] >= 16
     # successive halving: at most half survive each cut
     assert sum(1 for i in iters if i >= 16) <= 2
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "ROADMAP D10: HyperBandScheduler.on_trial_result never parks a trial "
+    "that has no checkpoint, and after a cut takes its NEXT report past the "
+    "new milestone as its score there, at a later iteration than its "
+    "peers': not the same-budget comparison its docstring promises"))
+def test_sync_hyperband_judges_a_free_running_trial_at_its_peers_budget(
+        ray_start, tmp_path):
+    """The trials report NO checkpoint, so none can be parked at the
+    barrier. ``q=1.0`` runs ahead (30 iterations before ``q=2.0`` makes its
+    second), ``q=2.0`` is better at every budget and has to win. The order
+    is held by files, not by sleeps alone: left to the workers' starts the
+    same fault showed in two runs of ten beside five busy workers (the
+    checkpointing test above is the steadied one)."""
+    import time
+
+    from ray_tpu import tune
+
+    base = str(tmp_path)
+
+    def trainable(config):
+        import os
+        import time
+
+        def wait(name, then):
+            deadline = time.monotonic() + 120
+            while (not os.path.exists(os.path.join(base, name))
+                   and time.monotonic() < deadline):
+                time.sleep(0.02)
+            time.sleep(then)  # the controller polls what was reported
+
+        def say(name):
+            open(os.path.join(base, name), "w").close()
+
+        q = config["q"]
+        for i in range(1, 65):
+            if q != 1.0 and i == 2:
+                wait("ahead", 2.0)
+            if q == 2.0 and i == 4:
+                say("judged")
+            tune.report({"acc": q * i})
+            if q == 1.0 and i == 30:
+                say("ahead")
+            if q == 1.0 and i == 40:
+                wait("judged", 1.0)
+            # past its fourth report ``q=2.0`` only waits for the verdict
+            time.sleep(0.3 if q == 2.0 and i >= 4 else 0.01)
+
+    results = tune.Tuner(
+        trainable,
+        param_space={"q": tune.grid_search([0.1, 1.0, 2.0])},
+        tune_config=tune.TuneConfig(
+            metric="acc", mode="max",
+            scheduler=tune.HyperBandScheduler(
+                grace_period=2, reduction_factor=2, max_t=64),
+            max_concurrent_trials=3,
+        ),
+        run_config=tune.TuneRunConfig(storage_path=tempfile.mkdtemp()),
+    ).fit()
+    assert not results.errors
+    # at milestone 4 ``q=2.0`` holds 8.0 and ``q=1.0`` held 4.0
+    assert results.get_best_result().config["q"] == 2.0
 
 
 def test_sync_hyperband_unit_barrier_semantics():
