@@ -1,12 +1,13 @@
 """The expert layer's grouped product timed alone on the chip, at the decode
-shapes of the benchmark's five expert cells (docs/MICROBENCHMARKS.md,
-PERF.md PR 49):
+shapes of the benchmark's seven expert cells (docs/MICROBENCHMARKS.md,
+PERF.md PR 49, PR 57):
 
     chiprun -- python3 ray_tpu/benchmarks/moe_gmm_time.py <tree> <part,...>
 
 ``<tree>`` is the checkout whose ``ray_tpu`` is imported (``.`` or a copy of
 another commit under ``.scratch/``). A shape is a cell's decode step: T rows,
-the router's outputs, ``top_k``, ``experts_held``, ``d_model``, ``d_expert``;
+the router's outputs, ``top_k``, ``experts_held``, ``d_model``, ``d_expert``
+(and the share of its rows that are padding: cell 13's folded block pass);
 the picks are drawn as the cells' routers draw them over random weights (k
 distinct experts a row, evenly), so the group sizes are the cells'. A part is
 
@@ -28,9 +29,13 @@ groups of four experts' width (suspect 3). One JSON line a (shape, part):
 the device's kind, microseconds a call (the host's clock around ONE program
 that makes 16 calls in a row, each fed a word of the one before, so that
 the host's ~240 us a dispatch is not in it), GB/s and the share of 819 GB/s
-over the bytes of the experts that met a row (the part's own matrices).
+over the bytes of the experts that met a row (the part's own matrices),
+and beside them ``items``, the work items the kernel's list holds for these
+groups (each streams its expert once: ``ops.moe.few_rows_items``; a tree
+from before PR 57 made one a tile of 128 sorted rows a group reaches). The
+``kernel`` part also says how far its rows stand from ``ragged_dot``'s.
 ``ONLY=a,b`` keeps those shapes; ``cell8-pangu@2048`` is the shape at
-another T (a prefill step's rows). Off a TPU the script refuses; ``REHEARSE=1`` runs tiny shapes (a kernel through
+another T (a prefill step's rows, none of them padding). Off a TPU the script refuses; ``REHEARSE=1`` runs tiny shapes (a kernel through
 the Pallas interpreter) to show that the script runs, and prints NO time."""
 import contextlib, json, os, statistics, sys, time
 from unittest import mock
@@ -49,27 +54,41 @@ if device.platform != "tpu" and not rehearse:
              "would mean nothing (REHEARSE=1 checks the script alone)")
 
 # name: (T rows, router outputs, top_k, held (first, count) or None,
-#        d_model, d_expert, zero_from or None)
+#        d_model, d_expert, zero_from or None, the share of rows that are
+#        padding)
 SHAPES = {
-    "cell5-lfm2": (64, 64, 4, None, 2048, 1536, None),
-    "cell6-laguna": (64, 256, 8, (0, 32), 2048, 512, None),
-    "cell8-pangu": (128, 256, 8, (0, 8), 7680, 2048, None),
-    "cell9-smallthinker": (48, 64, 6, None, 2560, 768, None),
-    "cell10-longcat": (96, 768, 12, (0, 16), 6144, 2048, 512),
+    "cell5-lfm2": (64, 64, 4, None, 2048, 1536, None, 0),
+    "cell6-laguna": (64, 256, 8, (0, 32), 2048, 512, None, 0),
+    "cell8-pangu": (128, 256, 8, (0, 8), 7680, 2048, None, 0),
+    "cell9-smallthinker": (48, 64, 6, None, 2560, 768, None, 0),
+    "cell10-longcat": (96, 768, 12, (0, 16), 6144, 2048, 512, 0),
+    "cell12-ling": (128, 512, 8, (0, 64), 2560, 768, None, 0),
+    # a folded block pass: 128 rows x 8 positions, of which a quarter hold
+    # no token (PERF.md section 6, PR 55)
+    "cell13-sdar": (1024, 128, 8, None, 2048, 768, None, 0.25),
     # suspect 2: cell 8's 15.7 M numbers a matrix under another d_model
-    "cell8-d6144": (128, 256, 8, (0, 8), 6144, 2560, None),
-    "cell8-d8192": (128, 256, 8, (0, 8), 8192, 1920, None),
+    "cell8-d6144": (128, 256, 8, (0, 8), 6144, 2560, None, 0),
+    "cell8-d8192": (128, 256, 8, (0, 8), 8192, 1920, None, 0),
     # suspect 3: cell 6's bytes as 8 groups of four experts end to end
-    "cell6-8x4": (64, 64, 8, (0, 8), 2048, 2048, None),
+    "cell6-8x4": (64, 64, 8, (0, 8), 2048, 2048, None, 0),
 }
 if os.environ.get("ONLY"):  # a name, or name@T: the shape at another T
-    SHAPES = {k: (int(k.partition("@")[2] or SHAPES[k.partition("@")[0]][0]),
-                  *SHAPES[k.partition("@")[0]][1:])
+    SHAPES = {k: ((int(k.partition("@")[2]), *SHAPES[k.partition("@")[0]][1:7], 0)
+                  if "@" in k else SHAPES[k])
               for k in os.environ["ONLY"].split(",")}
 if rehearse:  # tiny, for the interpreter
     SHAPES = {k: (8, v[1] // 8, min(v[2], 4), v[3] and (0, max(2, v[3][1] // 8)),
-                  256, 128, v[6] and v[6] // 8)
-              for k, v in list(SHAPES.items())[:5]}
+                  256, 128, v[6] and v[6] // 8, v[7])
+              for k, v in list(SHAPES.items())[:7]}
+
+
+def items_of(sizes):
+    """The work items the tree's kernel makes of these groups."""
+    if hasattr(moe, "few_rows_items"):
+        return int(moe.few_rows_items(sizes))
+    tm = moe._FEW_ROWS_TILE  # before PR 57: a tile of sorted rows a group reaches
+    ends = np.cumsum(sizes)
+    return int(np.where(sizes > 0, (ends - 1) // tm - (ends - sizes) // tm + 1, 0).sum())
 
 
 def stub_ragged_dot(lhs, rhs, group_sizes, **kw):
@@ -106,7 +125,7 @@ def time_call(fn, small, *rest):
 
 rng = np.random.default_rng(0)
 out_lines = []
-for name, (T, E_all, k, held, D, F, zero_from) in SHAPES.items():
+for name, (T, E_all, k, held, D, F, zero_from, padding) in SHAPES.items():
     first, E = held or (0, E_all)
     key = jax.random.PRNGKey(1)
     x = jax.random.normal(key, (T, D), jnp.bfloat16)
@@ -115,8 +134,9 @@ for name, (T, E_all, k, held, D, F, zero_from) in SHAPES.items():
     experts = jnp.asarray(np.stack(
         [rng.permutation(E_all)[:k] for _ in range(T)]).astype(np.int32))
     weights = jnp.full((T, k), 1.0 / k, jnp.float32)
-    valid = jnp.ones((T,), bool)
-    flat = np.asarray(experts).reshape(-1) - first
+    valid_np = rng.permutation(T) >= int(T * padding)
+    valid = jnp.asarray(valid_np)
+    flat = np.asarray(experts)[valid_np].reshape(-1) - first
     sizes_np = np.bincount(flat[(flat >= 0) & (flat < E)], minlength=E).astype(np.int32)
     met, rows = int((sizes_np > 0).sum()), int(sizes_np.sum())
     m = T * k
@@ -177,7 +197,13 @@ for name, (T, E_all, k, held, D, F, zero_from) in SHAPES.items():
             line = {"tree": tree, "shape": name, "part": part,
                     "device_kind": device.device_kind, "rows": m,
                     "rows_in_groups": rows, "experts_met": met, "of": E,
-                    "mb": nbytes / 1e6}
+                    "items": items_of(sizes_np), "mb": nbytes / 1e6}
+            if head == "kernel":  # the kernel's rows against ``ragged_dot``'s
+                gate, up = jnp.split(product(jnp.asarray(sizes_np), xs, w_in), 2, axis=-1)
+                want = product(jnp.asarray(sizes_np),
+                               (jax.nn.silu(gate) * up).astype(xs.dtype), w_out)
+                line["max_diff_vs_ragged"] = float(
+                    jnp.max(jnp.abs(o[:rows] - want[:rows])))
             if head not in ("in", "out", "around", "kernel"):
                 o32 = np.asarray(o.astype(jnp.float32))
                 ref = o32 if ref is None else ref

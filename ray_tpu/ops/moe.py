@@ -8,10 +8,11 @@ product over the experts, gated experts (SwiGLU, or ReLU-gated;
 ZERO-COMPUTE experts return their input and cost no product), weighted
 combine. The grouped product has TWO FORMS, chosen by ``gmm_form`` from
 the shapes a step is traced with (no option, no model's name):
-``few_rows``, the repo's Pallas kernel ``moe_gmm_few_rows`` (tiles of 128
-sorted rows stand still, each expert that met a row streams past them
-once a tile, gate | up | activation | down in one call: every decode step
-and every prefill step of the five expert cells), and ``ragged``, XLA's
+``few_rows``, the repo's Pallas kernel ``moe_gmm_few_rows`` (a group's rows
+stand still, 128 at a time counted from the group's first, and its expert
+streams past them once, gate | up | activation | down in one call: every
+decode step and every prefill step of the seven expert cells), and
+``ragged``, XLA's
 ``jax.lax.ragged_dot`` twice (widths that are no whole lane tiles, and
 steps wider than any that was timed: ``_FEW_PAIRS_AN_EXPERT``). XLA's
 kernel picks its row tile from m = T x k and pays a whole tile's pass a
@@ -255,6 +256,9 @@ def score_groups_bad(experts: int, n_group: int, topk_group: int) -> bool:
 GMM_KERNEL_NAME = "moe_gmm_few_rows"
 GMM_FORMS = ("ragged", "few_rows")
 _FEW_ROWS_TILE = 128
+# a window of sorted rows starts on a whole tile of the output's rows (8 of
+# float32; HBM tiles bf16 by 8 rows too): the chip's copies take no other
+_FEW_ROWS_ALIGN = 8
 _FEW_WEIGHT_TILE_BYTES = 2 * 1024 * 1024
 # the widest step timed: pairs (held or not) an expert the weights hold
 _FEW_PAIRS_AN_EXPERT = 2048
@@ -271,24 +275,42 @@ def _tile_rows(rows: int, row_bytes: int, target: int) -> int:
     return best
 
 
-def _work_items(sizes, tm: int, n_rt: int, items: int):
+def few_rows_items(sizes, tm: int = _FEW_ROWS_TILE):
+    """How many work items ``moe_gmm_few_rows`` makes of groups of
+    ``sizes`` rows (numpy or jax, [E]): ``ceil(size / tm)`` a group, which
+    is how many times the kernel streams that group's expert. One wherever
+    a group has at most ``tm`` rows, whatever row it starts at."""
+    return (-(-sizes // tm)).sum()
+
+
+def few_rows_items_bound(experts: int, m: int, tm: int = _FEW_ROWS_TILE) -> int:
+    """The static length of the kernel's lists over ``m`` sorted rows: no
+    sizes that sum to ``m`` or less make more items (a group with rows
+    makes one, and one more for every whole ``tm`` rows it holds)."""
+    return min(experts, m) + m // tm
+
+
+def _work_items(sizes, tm: int, items: int, align: int, last_win: int):
     """The kernel's lists, from the groups' sizes [E] int32: for every group
-    with rows, one item a tile of ``tm`` sorted rows it reaches (of
-    ``n_rt``), in order: ``(group, row tile, the group's first row, its
-    end)`` each [items] int32, and how many items there are. ``items`` is
-    the lists' length (every group, and a tile's edge more for each that a
-    group can cross); past the count they hold group 0 and no rows, and the
-    grid does not go there. Sums over masks, no scan, search or gather:
-    eight small operations on the device where the plain form (two
-    ``cumsum``, a ``searchsorted``, four gathers) compiled to twenty, 15 us
-    of laguna's 288 us call (docs/MICROBENCHMARKS.md, PR 49)."""
+    with rows, one item for each ``tm`` rows OF THE GROUP, counted from its
+    first row (``few_rows_items``), in order: ``(group, window, first row,
+    end)`` each [items] int32, and how many items there are. An item's
+    rows are ``first .. end`` (at most ``tm``, the group's next); its
+    window is the ``tm + align`` sorted rows from ``window * align``, the
+    tile of ``align`` rows that holds its first row (or ``last_win``, the
+    last window that ends inside the buffer). ``items`` is the lists'
+    length (``few_rows_items_bound``); past the count they hold group 0 and
+    no rows, and the grid does not go there. Sums over masks, no scan,
+    search or gather: eight small operations on the device where the
+    plain form (two ``cumsum``, a ``searchsorted``, four gathers) compiled
+    to twenty, 15 us of laguna's 288 us call (docs/MICROBENCHMARKS.md,
+    PR 49)."""
     E = sizes.shape[0]
     g = jnp.arange(E, dtype=jnp.int32)
     upto = g[None, :] <= g[:, None]  # [E, E]: group j stands at or before g
     ends = jnp.sum(jnp.where(upto, sizes[None, :], 0), axis=1)
     starts = ends - sizes
-    first = starts // tm
-    reach = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    reach = -(-sizes // tm)
     item_end = jnp.sum(jnp.where(upto, reach[None, :], 0), axis=1)
     item_start = item_end - reach
     w = jnp.arange(items, dtype=jnp.int32)
@@ -296,26 +318,57 @@ def _work_items(sizes, tm: int, n_rt: int, items: int):
     mine = (item_start[None, :] <= w[:, None]) & (w[:, None] < item_end[None, :])
 
     def pick(v):
-        return jnp.sum(jnp.where(mine, v[None, :], 0), axis=1)
+        return jnp.sum(jnp.where(mine, v, 0), axis=1)
 
-    til = jnp.clip(pick(first - item_start) + w, 0, n_rt - 1)
-    return pick(g), til, pick(starts), pick(ends), item_end[-1]
+    lo = pick(starts[None, :] + (w[:, None] - item_start[None, :]) * tm)
+    hi = jnp.minimum(lo + tm, pick(ends[None, :]))
+    win = jnp.minimum(lo // align, last_win)
+    return pick(g[None, :]), win, lo, hi, item_end[-1]
 
 
-def _moe_gmm_few_rows_kernel(grp, til, lo, hi, tot, x_ref, w_in_ref,
-                             w_out_ref, y_ref, h_scr, g_scr, *, n_in, n_out,
-                             tm, F, tf, act):
-    """One work item (an expert x a tile of ``tm`` sorted rows that holds
-    rows of its group) a step of grid axis 0; axis 1 walks the expert's
-    matrices: ``n_in`` row tiles of ``w_in`` [td, 2F] into ``h_scr`` (the
-    gate and up products, float32), the activation once into ``g_scr`` with
-    the rows of OTHER groups zeroed, then ``n_out`` row tiles of ``w_out``
-    [tf, D] into the output's row tile, which consecutive items of one tile
-    share (zeroed rows add nothing there)."""
+def _moe_gmm_few_rows_kernel(grp, win, lo, hi, tot, x_ref, w_in_ref,
+                             w_out_ref, y_hbm, h_scr, g_scr, res, carry,
+                             sent, sem, *, n_in, n_out, rows, align, F, tf,
+                             act):
+    """One work item (an expert x the next rows of its group, at most
+    ``tm``) a step of grid axis 0, over a WINDOW of ``rows`` = ``tm +
+    align`` sorted rows that starts on a tile of ``align`` and holds the
+    item's; axis 1 walks the expert's matrices: ``n_in`` row tiles of
+    ``w_in`` [td, 2F] into ``h_scr`` (the gate and up products, float32),
+    the activation once into ``g_scr`` with the rows that are not the
+    item's zeroed, then ``n_out`` row tiles of ``w_out`` [tf, D] into
+    ``res``. The output stays in HBM and the kernel copies ``res`` there
+    itself, a whole tile of ``align`` rows once: the tile an item ends
+    inside is CARRIED to the next item (whose rows begin there) and added
+    to the head of its window; an item sends the tiles from its first row's
+    to the one its end falls in (the last item that one too), in pieces of
+    1, 2, 4 .. tiles, the binary digits of their count."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     w, t = pl.program_id(0), pl.program_id(1)
     real = tot[0] > 0  # a grid has one step even where no group has a row
+    tiles = rows // align
+
+    def pieces(n, src, dst):
+        """(whether, the copy) for each binary digit of ``n`` tiles sent
+        from tile ``src`` of ``res`` to tile ``dst`` of the output."""
+        for j in range(tiles.bit_length()):
+            before = (n >> (j + 1)) << (j + 1)
+
+            def at(tile):
+                return pl.ds(pl.multiple_of((tile + before) * align, align),
+                             align << j)
+            yield (n >> j) & 1 == 1, pltpu.make_async_copy(
+                res.at[at(src)], y_hbm.at[at(dst)], sem.at[0])
+
+    def wait_sent():
+        for sending, copy in pieces(sent[0], 0, 0):  # sizes alone matter
+            pl.when(sending)(copy.wait)
+
+    @pl.when((w == 0) & (t == 0))
+    def _():
+        carry[...] = jnp.zeros_like(carry)
 
     @pl.when(real & (t < n_in))
     def _():
@@ -333,8 +386,9 @@ def _moe_gmm_few_rows_kernel(grp, til, lo, hi, tot, x_ref, w_in_ref,
     @pl.when(real & (t == n_in - 1))
     def _():
         h = h_scr[...]
-        rows = til[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-        mine = (rows >= lo[w]) & (rows < hi[w])
+        row = win[w] * align + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0)
+        mine = (row >= lo[w]) & (row < hi[w])
         gated = jnp.where(
             mine, EXPERT_ACTS[act](h[:, :F]) * h[:, F:], 0.0
         ).astype(g_scr.dtype)
@@ -346,15 +400,34 @@ def _moe_gmm_few_rows_kernel(grp, til, lo, hi, tot, x_ref, w_in_ref,
         j = t - n_in
         prod = jnp.dot(g_scr[j], w_out_ref[0],
                        preferred_element_type=jnp.float32)
-        opens = (j == 0) & ((w == 0) | (til[jnp.maximum(w - 1, 0)] != til[w]))
 
-        @pl.when(opens)
+        @pl.when(j == 0)
         def _():
-            y_ref[...] = prod
+            # the item before's tiles have left ``res`` (they had this
+            # item's whole first product to go)
+            pl.when(w > 0)(wait_sent)
+            res[...] = prod
 
-        @pl.when(jnp.logical_not(opens))
+        @pl.when(j > 0)
         def _():
-            y_ref[...] += prod
+            res[...] += prod
+
+    @pl.when(real & (t == n_in + n_out - 1))
+    def _():
+        first = lo[w] // align - win[w]  # tiles, from the window's start
+        end = hi[w] // align - win[w]
+        open_ = hi[w] % align > 0  # the item ends inside a tile
+        last = w == tot[0] - 1
+        head = pl.ds(pl.multiple_of(first * align, align), align)
+        res[head, :] += carry[...]
+        tail = pl.ds(pl.multiple_of(
+            jnp.minimum(end, tiles - 1) * align, align), align)
+        carry[...] = jnp.where(open_, res[tail, :], 0.0)
+        sent[0] = end - first + (last & open_).astype(jnp.int32)
+        for sending, copy in pieces(sent[0], first, win[w] + first):
+            pl.when(sending)(copy.start)
+
+        pl.when(last)(wait_sent)
 
 
 @functools.partial(
@@ -374,18 +447,23 @@ def _moe_gmm_few_rows_call(xs, w_in, w_out, sizes, *, act, tm, tile_bytes,
     td = _tile_rows(D, 2 * F * itemsize, tile_bytes)
     tf = _tile_rows(F, D * itemsize, tile_bytes)
     n_in, n_out = D // td, F // tf
-    n_rt = -(-m // tm)
-    xs = jnp.pad(xs, ((0, n_rt * tm - m), (0, 0)))
-    grp, til, lo, hi, total = _work_items(
-        sizes, tm, n_rt, min(E, m) + n_rt - 1)
+    align = _FEW_ROWS_ALIGN
+    rows = tm + align  # a window: an item's rows, from a tile's first row
+    # whole tiles and one window at the least: nothing to pad where a step
+    # sorts a multiple of 8 pairs, 136 or more
+    m_pad = max(-(-m // align) * align, rows)
+    xs = jnp.pad(xs, ((0, m_pad - m), (0, 0)))
+    grp, win, lo, hi, total = _work_items(
+        sizes, tm, few_rows_items_bound(E, m, tm), align,
+        (m_pad - rows) // align)
 
-    def x_map(w, t, grp, til, lo, hi, tot):
-        return (til[w], jnp.minimum(t, n_in - 1))
+    def x_map(w, t, grp, win, lo, hi, tot):
+        return (win[w] * align, jnp.minimum(t, n_in - 1) * td)
 
-    def w_in_map(w, t, grp, til, lo, hi, tot):
+    def w_in_map(w, t, grp, win, lo, hi, tot):
         return (grp[w], jnp.minimum(t, n_in - 1), 0)
 
-    def w_out_map(w, t, grp, til, lo, hi, tot):
+    def w_out_map(w, t, grp, win, lo, hi, tot):
         # while an item's ``w_in`` streams, the item BEFORE's last tile
         # stays: the first tile of this item's ``w_out`` is then copied
         # under the last ``w_in`` product, not beside the first
@@ -393,40 +471,43 @@ def _moe_gmm_few_rows_call(xs, w_in, w_out, sizes, *, act, tm, tile_bytes,
         return (jnp.where(ahead, grp[jnp.maximum(w - 1, 0)], grp[w]),
                 jnp.where(ahead, n_out - 1, jnp.maximum(t - n_in, 0)), 0)
 
-    def y_map(w, t, grp, til, lo, hi, tot):
-        return (til[w], 0)
-
-    vmem = 2 * (tm * td + td * 2 * F + tf * D) * itemsize \
-        + 2 * tm * D * 4 + 2 * tm * 2 * F * 4 + tm * F * itemsize
+    vmem = 2 * (rows * td + td * 2 * F + tf * D) * itemsize \
+        + (rows + align) * D * 4 + 2 * rows * 2 * F * 4 + rows * F * itemsize
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         # as many items as there are: the bound is the lists' length only
         grid=(jnp.maximum(total, 1), n_in + n_out),
         in_specs=[
-            pl.BlockSpec((tm, td), x_map),
+            # a window starts at a ROW (of whole tiles), not at a block
+            pl.BlockSpec((pl.Element(rows), pl.Element(td)), x_map),
             pl.BlockSpec((1, td, 2 * F), w_in_map),
             pl.BlockSpec((1, tf, D), w_out_map),
         ],
-        out_specs=pl.BlockSpec((tm, D), y_map),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((tm, 2 * F), jnp.float32),
-            pltpu.VMEM((n_out, tm, tf), xs.dtype),
+            pltpu.VMEM((rows, 2 * F), jnp.float32),
+            pltpu.VMEM((n_out, rows, tf), xs.dtype),
+            pltpu.VMEM((rows, D), jnp.float32),
+            pltpu.VMEM((align, D), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((1,)),
         ],
     )
     ys = pl.pallas_call(
         functools.partial(
-            _moe_gmm_few_rows_kernel, n_in=n_in, n_out=n_out, tm=tm, F=F,
-            tf=tf, act=act),
+            _moe_gmm_few_rows_kernel, n_in=n_in, n_out=n_out, rows=rows,
+            align=align, F=F, tf=tf, act=act),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rt * tm, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m_pad, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            # in order, on one core: items of one row tile share its block
+            # in order, on one core: an item takes the tile the one before
+            # ended inside
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=max(2 * vmem, _VMEM_DEFAULT),
         ),
         name=GMM_KERNEL_NAME,
         interpret=interpret,
-    )(grp, til, lo, hi, total.reshape(1), xs, w_in, w_out)
+    )(grp, win, lo, hi, total.reshape(1), xs, w_in, w_out)
     return ys[:m]
 
 
@@ -442,14 +523,21 @@ def moe_gmm_few_rows(xs: jax.Array, w_in: jax.Array, w_out: jax.Array,
     of no group hold whatever was there (the caller masks them, as after
     ``ragged_dot``).
 
-    The rows stand still and the weights stream: each expert that met a
-    row is read ONCE for each tile of ``_FEW_ROWS_TILE`` sorted rows its
-    group reaches (one, but for a group across a tile's edge), in tiles of
-    whole rows of the stored matrices (``_FEW_WEIGHT_TILE_BYTES``: each one
-    contiguous copy), the next tile, the next expert's first included,
-    copied under the current one's product by the pipeline; an expert with
-    no row is never in the list. ``h`` (gate | up, float32) and the
-    activation stay in fast memory."""
+    The rows stand still and the weights stream: a work item is a group's
+    next ``_FEW_ROWS_TILE`` rows, counted from the GROUP's first row, so an
+    expert with n rows is read ``ceil(n / 128)`` times (``few_rows_items``):
+    once wherever n <= 128, whatever sorted row the group starts at (until
+    PR 57 an item was an aligned tile of sorted rows, and a group across a
+    tile's edge read its expert twice: 175 reads for 128 experts a folded
+    pass of cell 13). The weights come in tiles of whole rows of the stored
+    matrices (``_FEW_WEIGHT_TILE_BYTES``: each one contiguous copy), the
+    next tile, the next expert's first included, copied under the current
+    one's product by the pipeline; an expert with no row is never in the
+    list. An item's rows reach the kernel as a window of 128 + 8 sorted
+    rows from the tile of 8 that holds its first (the chip copies rows by
+    whole tiles), ``h`` (gate | up, float32), the activation and the
+    window's output stay in fast memory, and every tile of 8 output rows
+    goes to HBM once: the one an item ends inside rides to the next item."""
     if interpret is None:
         interpret = pallas_interpret()
     return _moe_gmm_few_rows_call(
@@ -464,7 +552,9 @@ def gmm_form(pairs: int, experts: int, d_model: int, d_expert: int) -> str:
     weights hold, their widths. ``few_rows`` wherever the kernel can tile
     the widths (whole lane tiles) and the step lies inside what was timed
     (docs/MICROBENCHMARKS.md, PR 49: the layer alone on a v5e, ``ragged``
-    -> ``few_rows`` in us): the five cells' decode steps 2,496 -> 1,695
+    -> ``few_rows`` in us, with the list of then, an item an aligned tile
+    of 128 sorted rows; PR 57's list, an item a group's next 128 rows, is
+    timed there beside it): the five cells' decode steps 2,496 -> 1,695
     (lfm2, 4 pairs an expert), 567 -> 286 (laguna, 16 of which 2 held),
     2,548 -> 1,187 (openPangu, 128 of which 5 held), 1,431 -> 1,133
     (smallthinker, 4.5), 1,422 -> 1,211 (LongCat, 72 of which 1.6 held),

@@ -1285,24 +1285,28 @@ PARENTS_TEXT = {
     # re-recorded by ISSUE 49 (the grouped expert product is the kernel
     # ``moe_gmm_few_rows``, one call an expert layer, in every step program
     # of the five expert families; the seven others above are as they were)
-    "laguna-decode": "85a1e51ebd241889",
-    "lfm2-decode": "4fca8ec5f3634977",
+    # and, with the five expert programs below, by ISSUE 57 (the kernel's
+    # operands changed: its list holds a window where it held a tile, its
+    # output stays in HBM and is copied by the kernel; the seven above and
+    # every operation outside the ``moe_gmm`` scope are as they were)
+    "laguna-decode": "ef51a924a6c96adc",
+    "lfm2-decode": "dadfcdb3ee1843b2",
     # re-recorded by ISSUE 53 (a latent family's pool is ONE plane, a page
     # one copy: the four programs below carry one pool-sized array and the
     # latent kernel one HBM operand; the ten others here are to the letter
     # the texts PR 52's tree compiled to, which is what this test asserts)
     # plane [5, 40961, 16, 640], table [128, 768]
-    "pangu-decode": "6d439f4165277305",
+    "pangu-decode": "1370dc4e4d8afe2f",
     # plane [8, 16385, 16, 640], table [96, 384]
-    "longcat-decode": "22d3142dbc515154",
+    "longcat-decode": "4e37176c9684407e",
     # the chunk program over [16, 128] under [16, 768] and over [8, 128]
     # under [8, 384], the cells' planes (ISSUE 47; ISSUE 51: a prefill step
     # attends in the expanded form, ``flash_fwd`` over its own keys, a loop
     # over its resident prefix, whose blocks are read from the one plane)
-    "pangu-packed": "243c3be3a2a30f13",
-    "longcat-packed": "76e154c31d23b474",
+    "pangu-packed": "fa6b4eaa6ee03207",
+    "longcat-packed": "5ba8792445ebf8c1",
     # pool [2, 65537, 16, 512], tables [4, 48, 1024]
-    "smallthinker-decode": "1229fb1663ecaf6f",
+    "smallthinker-decode": "d50a8e6b47762f6a",
 }
 # of PR 49's tree, names as ordinals (``_program_shape_sha``)
 PARENTS_SHAPE = {
@@ -1744,7 +1748,7 @@ def test_ling_hybrid_step_programs_compile_at_published_widths(
 # re-recorded by ISSUE 55, whose change it is (a row carries a finished block
 # and the fresh one behind it: the step is traced [128, 8] and the head runs
 # on the 4 positions a row that choose). Under ``PARENTS_JAX`` as the others.
-SDAR_DECODE_TEXT = "c0f407c3b381296b"
+SDAR_DECODE_TEXT = "dd0d1ac56c77b2c9"
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill_chunk"])
@@ -1765,7 +1769,8 @@ def test_sdar_moe_step_programs_compile_at_published_widths(
     float32 logits are its largest temporary: nothing ``[128, 8,
     151936]`` exists), and its temporaries are the 0.57 GB they were
     before a row carried two blocks. ``SDAR_DECODE_TEXT`` records the
-    block pass's text (re-recorded by ISSUE 55, which changed it)."""
+    block pass's text (re-recorded by ISSUE 55, which changed it, and by
+    ISSUE 57, which changed the grouped product's operands)."""
     import sys
 
     import jax

@@ -122,17 +122,123 @@ def test_both_forms_equal_the_dense_reference(jax_cpu, monkeypatch, cell,
         assert int(want_sizes[1]) == T * k
 
 
-def test_the_kernel_alone_leaves_no_group_out(jax_cpu):
-    """``moe_gmm_few_rows`` against ``ragged_dot`` on sorted rows: groups
-    that start and end inside a row tile, one that spans three, empty ones
-    between them, and rows behind the last group that no item visits."""
+def _drawn(T, E_all, k, held=None, padding=0.0, seed=0):
+    """Group sizes as a cell's router draws them: k distinct experts a row,
+    evenly; ``held`` keeps a slice's, ``padding`` is the share of rows that
+    hold no token. -> (sizes [E], the sorted buffer's rows T * k)."""
+    rng = np.random.default_rng(seed)
+    picks = np.stack([rng.permutation(E_all)[:k] for _ in range(T)])
+    picks = picks[rng.permutation(T) >= int(T * padding)].reshape(-1)
+    first, count = held or (0, E_all)
+    picks = picks[(picks >= first) & (picks < first + count)] - first
+    return np.bincount(picks, minlength=count).astype(np.int32), T * k
+
+
+# name: (sizes [E], the sorted buffer's rows m, the row tile tm)
+LISTS = {
+    "cell13_decode": (*_drawn(1024, 128, 8, padding=0.25), 128),
+    "cell13_prefill": (*_drawn(2048, 128, 8, seed=1), 128),
+    "cell5_decode": (*_drawn(64, 64, 4, seed=2), 128),
+    "cell5_prefill": (*_drawn(768, 64, 4, seed=3), 128),
+    "ep_slice_decode": (*_drawn(64, 256, 8, held=(0, 32), seed=4), 128),
+    "ep_slice_prefill": (*_drawn(2048, 256, 8, held=(0, 8), seed=5), 128),
+    "one_group_of_every_row": (np.array([0, 0, 300, 0], np.int32), 300, 128),
+    "exactly_a_tile": (np.array([5, 128, 0, 3], np.int32), 136, 128),
+    "a_tile_and_a_row": (np.array([5, 129, 0, 3], np.int32), 144, 128),
+    "empty_groups_between": (
+        np.array([0, 7, 0, 0, 16, 0, 17, 0, 0, 1, 0], np.int32), 48, 16),
+    "every_group_one_row": (np.ones(24, np.int32), 24, 16),
+    "more_groups_than_rows": (
+        np.array([1, 0, 2, 0, 0, 1, 0, 0, 0, 0, 0, 0], np.int32), 4, 16),
+    "no_group_at_all": (np.zeros(8, np.int32), 64, 16),
+}
+
+
+@pytest.mark.parametrize("case", LISTS)
+def test_the_list_cuts_at_group_edges(jax_cpu, case):
+    """``_work_items`` alone: an item is a group's next ``tm`` rows counted
+    from the GROUP's first row, so there are ``sum(ceil(sizes / tm))`` of
+    them (``few_rows_items``: an expert streams once wherever its group has
+    at most ``tm`` rows, whatever row it starts at), every row of every
+    group lies in exactly one item, the items stand in the rows' order,
+    each inside its window, and none past the lists' static length."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    sizes, m, tm = LISTS[case]
+    align = moe._FEW_ROWS_ALIGN
+    E = len(sizes)
+    rows = tm + align
+    m_pad = max(-(-m // align) * align, rows)
+    bound = moe.few_rows_items_bound(E, m, tm)
+    grp, win, lo, hi, total = (np.asarray(a) for a in moe._work_items(
+        jnp.asarray(sizes), tm, bound, align, (m_pad - rows) // align))
+    want = int(np.sum(-(-sizes.astype(np.int64) // tm)))
+    assert int(total) == want == int(moe.few_rows_items(sizes, tm))
+    assert int(moe.few_rows_items(jnp.asarray(sizes), tm)) == want
+    assert want <= bound == len(grp) == len(win) == len(lo) == len(hi)
+    if tm == moe._FEW_ROWS_TILE:
+        assert int(moe.few_rows_items(sizes)) == want
+    ends = np.cumsum(sizes)
+    owner = np.full(m_pad, -1)  # the group of every sorted row
+    for g in range(E):
+        owner[ends[g] - sizes[g]:ends[g]] = g
+    seen = np.zeros(m_pad, np.int32)
+    for w in range(want):
+        assert 0 < hi[w] - lo[w] <= tm
+        assert np.all(owner[lo[w]:hi[w]] == grp[w])
+        seen[lo[w]:hi[w]] += 1
+        # counted from the group's first row
+        assert (lo[w] - (ends[grp[w]] - sizes[grp[w]])) % tm == 0
+        # inside its window, which starts on a tile and ends in the buffer
+        assert win[w] * align <= lo[w] and hi[w] <= win[w] * align + rows
+        assert win[w] * align + rows <= m_pad
+        if w:  # in the rows' order, nothing between two items
+            assert lo[w] == hi[w - 1]
+    np.testing.assert_array_equal(seen, owner >= 0)
+    # past the count: group 0 and no rows
+    assert not np.any(grp[want:]) and np.all(hi[want:] <= lo[want:])
+    # the list of before made an item an ALIGNED tile of sorted rows a
+    # group reaches: a group more, and one for every tile's edge crossed
+    before = int(np.sum(np.where(
+        sizes > 0, (ends - 1) // tm - (ends - sizes) // tm + 1, 0)))
+    assert want <= before
+    if case == "cell13_decode":  # ~48 rows a group: once each, was 128 + 47
+        assert want == int(np.sum(sizes > 0)) == 128 and before >= 170
+
+
+# name: (sizes [8], the sorted buffer's rows m); a row tile of 16 and tiles
+# of 8 output rows: groups that start and end inside a row tile, off a
+# multiple of 16 and of 8, of exactly a tile and a row more, that end with
+# the buffer (its last window), a buffer of less than a window, no group
+ALONE = {
+    "spans_three": ([3, 0, 0, 37, 1, 0, 9, 0], 96),
+    "off_every_edge": ([5, 16, 17, 0, 2, 1, 30, 0], 96),
+    "a_tile_and_a_row_more": ([3, 16, 17, 0, 16, 0, 0, 7], 64),
+    "ends_with_the_buffer": ([1, 0, 20, 0, 0, 0, 3, 16], 40),
+    "less_than_a_window": ([2, 0, 1, 0, 0, 0, 0, 0], 5),
+    "one_group_of_every_row": ([0, 0, 0, 50, 0, 0, 0, 0], 50),
+    "one_row_each": ([1, 1, 1, 1, 1, 1, 1, 1], 24),
+    "no_group": ([0, 0, 0, 0, 0, 0, 0, 0], 32),
+}
+
+
+@pytest.mark.parametrize("case", ALONE)
+def test_the_kernel_alone_leaves_no_group_out(jax_cpu, case):
+    """``moe_gmm_few_rows`` against ``ragged_dot`` on sorted rows: every
+    group's rows are ``ragged_dot``'s whatever row the group starts at (an
+    item's window overhangs its group on both sides: the neighbours' rows
+    are theirs all the same); behind the last group, the rest of its tile
+    of 8 is zero as ``ragged_dot`` leaves it, and no item visits the rows
+    past it (the interpreter's untouched output is NaN)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import moe
 
-    sizes = jnp.asarray([3, 0, 0, 37, 1, 0, 9, 0], jnp.int32)
-    m = 96
+    sizes, m = ALONE[case]
+    sizes = jnp.asarray(sizes, jnp.int32)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     xs = jax.random.normal(keys[0], (m, D))
     w_in = jax.random.normal(keys[1], (8, D, 2 * F)) * D ** -0.5
@@ -147,6 +253,9 @@ def test_the_kernel_alone_leaves_no_group_out(jax_cpu):
     assert got.shape == (m, D) and got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
                                atol=2e-5)
+    tile = min(-(-n // moe._FEW_ROWS_ALIGN) * moe._FEW_ROWS_ALIGN, m)
+    np.testing.assert_array_equal(np.asarray(got[n:tile]), 0.0)
+    assert np.all(np.isnan(np.asarray(got[tile:])))
 
 
 def test_an_engine_decodes_through_the_kernel_and_says_so(jax_cpu):
