@@ -18,7 +18,10 @@ that XLA fuses with the gate; float32 inside, the caller's dtype out.
 The PLAIN form (``gate=None``; models/ling_hybrid.py's KDA layers: width
 4 over ``[q | k | v]``, then SiLU): the same filter and the same state,
 with ``act`` on ``z`` where the gated form multiplies by ``C``, under the
-caller's ``scope``.
+caller's ``scope``; with a ``bias`` [D] it is ``act(z + bias)``
+(models/falcon_h1.py: Mamba-2's convolution over ``[x | B | C]``,
+``mamba_conv_bias``). Without one nothing is added: the other callers'
+programs hold the operations they held.
 """
 from __future__ import annotations
 
@@ -33,8 +36,11 @@ def _named(scope: str | None):
         else jax.named_scope(scope)
 
 
-def _finish(z, gate, act):
-    """``z`` float32 through the gate or the activation of the form."""
+def _finish(z, gate, act, bias=None):
+    """``z`` float32 through the bias, then the gate or the activation of
+    the form."""
+    if bias is not None:
+        z = z + bias.astype(jnp.float32)
     if gate is not None:
         z = gate.astype(jnp.float32) * z
     return z if act is None else act(z)
@@ -42,7 +48,8 @@ def _finish(z, gate, act):
 
 def short_conv_prefill(v: jax.Array, gate: jax.Array | None, w: jax.Array,
                        state: jax.Array | None, lengths: jax.Array, *,
-                       act=None, scope: str | None = None):
+                       act=None, scope: str | None = None,
+                       bias: jax.Array | None = None):
     """A chunk of positions: ``v``, ``gate`` [B, S, D], filter ``w`` [K, D],
     ``state`` [B, K-1, D] holding the rows before the chunk (None: the
     chunk starts the sequence, zeros), ``lengths`` [B] the valid columns.
@@ -61,12 +68,13 @@ def short_conv_prefill(v: jax.Array, gate: jax.Array | None, w: jax.Array,
         # is column lengths + i of ``ext``
         cols = lengths[:, None] + jnp.arange(K - 1, dtype=lengths.dtype)
         nxt = jnp.take_along_axis(ext, cols[:, :, None], axis=1)
-        return _finish(z, gate, act).astype(v.dtype), nxt
+        return _finish(z, gate, act, bias).astype(v.dtype), nxt
 
 
 def short_conv_decode(v: jax.Array, gate: jax.Array | None, w: jax.Array,
                       state: jax.Array, *, act=None,
-                      scope: str | None = None):
+                      scope: str | None = None,
+                      bias: jax.Array | None = None):
     """One position: ``v``, ``gate`` [B, D], ``state`` [B, K-1, D].
     Returns (``gate * z`` [B, D], the next state)."""
     with _named(scope):
@@ -75,4 +83,4 @@ def short_conv_decode(v: jax.Array, gate: jax.Array | None, w: jax.Array,
         # the same three products in the same order as the chunked form
         z = sum(ext[:, j].astype(jnp.float32) * w32[j]
                 for j in range(w.shape[0]))
-        return _finish(z, gate, act).astype(v.dtype), ext[:, 1:]
+        return _finish(z, gate, act, bias).astype(v.dtype), ext[:, 1:]

@@ -101,7 +101,7 @@ FAMILIES: dict[str, str] = {
     name: f"ray_tpu.models.{name}" for name in (
         "gpt", "llama", "lfm2_moe", "laguna", "evabyte", "pangu_ultra_moe",
         "smallthinker", "longcat_flash", "minicpm_sala", "ling_hybrid",
-        "sdar_moe")}
+        "sdar_moe", "falcon_h1")}
 
 
 @functools.cache

@@ -1234,15 +1234,15 @@ class LLMEngine:
                           blocks: bool = False) -> None:
         """Raise for each option that cannot yet carry what the family
         keeps: per-sequence state beside the pool (``lfm2_moe``: a short
-        convolution's rows; ``minicpm_sala``: a matrix a head of lightning
-        state, and its compressed keys by block id; ``ling_hybrid``: a
-        matrix a head of KDA state and a convolution's rows, BESIDE a pool
-        in planes: it meets the state's list first, which refuses every
-        option the planes' list does and preemption besides), tables
-        by group of layers (``laguna``), a ring and a table of chunk
-        summaries (``evabyte``) or one latent row a token in planes
-        (``pangu_ultra_moe``, ``longcat_flash``), or generation by
-        diffusion over blocks (``sdar_moe``), each with its reason."""
+        convolution's rows; ``minicpm_sala``: a lightning matrix a head, and
+        compressed keys by block id; ``ling_hybrid``: a KDA matrix a head
+        and a convolution's rows, BESIDE a pool in planes: the state's list
+        refuses all the planes' list does and preemption besides;
+        ``falcon_h1``: an SSM matrix a head and a convolution's rows in
+        EVERY layer, each of which also pages), tables by group of layers
+        (``laguna``), a ring and chunk summaries (``evabyte``), one latent
+        row a token in planes (``pangu_ultra_moe``, ``longcat_flash``), or
+        generation by diffusion over blocks (``sdar_moe``), each with why."""
         asked = {
             "speculative_k": cfg.speculative_k > 0,
             "host_cache_bytes": cfg.host_cache_bytes > 0,
@@ -1263,8 +1263,8 @@ class LLMEngine:
                     "a paused stream's state slot is not demoted with its "
                     "blocks",
                 "quantization":
-                    "the weights of the layers that keep the state (expert "
-                    "and conv; lightning; kda) have no quantized path, and a "
+                    "the weights of the layers that keep the state (expert and "
+                    "conv; lightning; kda; ssm) have no quantized path, and a "
                     "quantized pool has no plane for compressed keys",
                 "tp/fsdp/mesh":
                     "ShardedExecutor has no expert axis and does not place "

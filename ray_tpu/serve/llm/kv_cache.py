@@ -308,8 +308,8 @@ class KVCacheConfig:
             return ("tables by group: a window's blocks go back behind a "
                     "position")
         if self.state_slots:
-            return ("state rows beside the pool: a piece's short "
-                    "convolution needs the piece before it")
+            return ("state rows beside the pool: a piece's short convolution "
+                    "and its matrix state need the piece before it")
         return None
 
     @property

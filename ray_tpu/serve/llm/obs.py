@@ -329,7 +329,12 @@ def shape_key(sig: tuple) -> str:
 #   ``kda_conv`` (the convolution over ``[q | k | v]``, SiLU, the L2
 #   norms), ``kda_gate`` (the decay a channel, ``beta``), ``kda_step`` /
 #   ``kda_chunk`` (the delta rule, ops/kda.py), ``kda_out`` (the norm a
-#   head and the output gate);
+#   head and the output gate); a Mamba-2 branch's parts
+#   (models/falcon_h1.py, beside attention in the same layer): ``ssd_proj``
+#   (its in / out products and the multipliers on them), ``ssd_conv`` (the
+#   convolution over ``[x | B | C]`` with bias, SiLU, ``dt``'s softplus),
+#   ``ssd_step`` / ``ssd_chunk`` (the recurrence, ops/ssd.py), ``ssd_out``
+#   (the gate and the norm a group);
 # - ``ffn``: the feed-forward half of a layer: its norm, the dense MLP,
 #   the residual. Inside it ``dense_ffn`` (a double layer's SwiGLUs),
 #   ``moe_route`` (router product, scores, top-k), ``moe_move`` (what
@@ -344,7 +349,8 @@ SCOPES = (
     "embed", "layer_stack", "attn_proj", "attn_cache", "attn_kernel",
     "sparse_select", "sparse_prefill_attention", "eva_summarize",
     "short_conv", "lightning_step", "lightning_chunk", "kda_conv",
-    "kda_gate", "kda_step", "kda_chunk", "kda_out", "ffn", "dense_ffn",
+    "kda_gate", "kda_step", "kda_chunk", "kda_out", "ssd_proj", "ssd_conv",
+    "ssd_step", "ssd_chunk", "ssd_out", "ffn", "dense_ffn",
     "moe_route", "moe_move", "moe_gmm", "moe_zero", "moe_shared", "head",
     "sample", "counters",
 )

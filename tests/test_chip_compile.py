@@ -1865,3 +1865,130 @@ def test_sdar_moe_step_programs_compile_at_published_widths(
     else:
         assert "151936]" not in entry.split("ROOT")[-1]
     assert "cross_program_prefetch_index" not in text
+
+
+@pytest.mark.parametrize("rows", [96, 16, 1])
+def test_ssd_step_compiles_at_the_cells_shape(one_chip, rows):
+    """The Falcon-H1 cell's state kernel alone: 96 (16, 1) rows x 32 heads
+    over a float32 state ``[128, 256]`` a head in the slots' array ``[5,
+    97, 32, 128, 256]`` (2.03 GB), a block a (row, 8 heads of one group),
+    the array aliased in and out: no copy of it among the temporaries."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssd
+
+    S_ = functools.partial(_struct, sharding=one_chip)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    states = S_((5, 97, 32, 128, 256), f32)
+    fn = functools.partial(ssd.ssd_step_pallas, layer=3, interpret=False)
+    compiled = jax.jit(
+        lambda x, dt, A, Bm, Cm, D, states, slots: fn(
+            x, dt, A, Bm, Cm, D, states, slots=slots),
+        donate_argnums=(6,)).lower(
+        S_((rows, 32, 128), bf16), S_((rows, 32), f32), S_((32,), f32),
+        S_((rows, 2, 256), bf16), S_((rows, 2, 256), bf16), S_((32,), f32),
+        states, S_((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert re.search(r"%ssd_step[.\d]* = ", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(states.shape) * 4
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk", "prefill"])
+def test_falcon_h1_step_programs_compile_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """The Falcon-H1 cell's step programs as the executor compiles them, at
+    the cell's own shapes: the 96-row decode step and the 1,024-token chunk
+    against the 384-entry table of the 6,144-token bucket, the fresh prefill
+    over 64 entries. The pool spans ALL five layers (``[5, 16385, 16,
+    512]``: 1.34 GB a plane) and so does ``state`` (97 slots: 2.05 GB), both
+    in the program's ``input_output_alias``: every layer scatters its K/V
+    rows, calls the paged kernel AND the kernel ``ssd_step``, which updates
+    a row's state where it stands. A prefill program holds no ``ssd_step``
+    (the chunked form is XLA's, under the scope ``ssd_chunk``). All of it
+    inside the chip's 16 GB with the weights' 9.65 GB: the decode step's
+    float32 logits ``[96, 261120]`` are 100 MB of its temporaries."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import common
+    from ray_tpu.ops.paged_attention import pool_shape
+    from ray_tpu.serve.llm import decode
+
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET")
+    held = common.load_json(os.path.join(
+        root, "benchmark/configs/falcon-h1-34b-instruct-5l.json"))
+    engine = common.load_json(os.path.join(
+        root, "benchmark/traffic/worked-answers-closed.json"))["engine"]
+    cfg = dataclasses.replace(common.model_config(held),
+                              attention_backend="pallas")
+    fam = decode.get_family("falcon_h1")
+    init = common.load_named("reference", "falcon_h1").init_fn()
+    on_chip = lambda s: _struct(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    assert params["wte"].dtype == jnp.bfloat16
+    slots = engine["max_batch_size"] + 1
+    state = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: fam.init_state(cfg, slots)))
+    assert state["ssd"].shape == (5, 97, 32, 128, 256)
+    assert state["conv"].shape == (5, 97, 3, 5120)
+    assert engine["block_size"] == 16
+    pool = _struct(pool_shape(cfg.n_layer, engine["num_blocks"], 16,
+                              cfg.n_kv_head, cfg.head_dim), cfg.dtype,
+                   one_chip)
+    assert pool.shape == (5, 16385, 16, 512)
+    i32 = functools.partial(_struct, dtype=jnp.int32, sharding=one_chip)
+    ctx = engine["length_buckets"][-1]
+    chunk = engine["prefill_chunk_tokens"]
+    B = engine["max_batch_size"] if kind == "decode" else 1
+    assert (ctx, chunk, B) in ((6144, 1024, 96), (6144, 1024, 1))
+    fns = decode.DecodeFns("falcon_h1", cfg, platform="tpu")
+    more = {"state": state, "slots": i32((B,))}
+    if kind == "decode":
+        lowered = fns._decode.lower(
+            params, pool, pool, i32((B,)), i32((B,)), i32((B, ctx // 16)),
+            sample=None, **more)
+    else:
+        nb = ctx // 16 if kind == "prefill_chunk" else chunk // 16
+        if kind == "prefill_chunk":
+            more["start"] = i32((B,))
+        lowered = fns._prefill.lower(
+            params, pool, pool, i32((B, chunk)), i32((B,)), i32((B, nb)),
+            sample=None, **more)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * math.prod(pool.shape) * 2
+    assert abs(pool_bytes - 2.685e9) < 0.001e9
+    state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree.leaves(state))
+    assert abs(state_bytes - 2.049e9) < 0.001e9
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(params))
+    assert abs(weights - 9.649e9) < 0.002e9
+    assert abs(mem.argument_size_in_bytes
+               - (weights + pool_bytes + state_bytes)) < 0.02e9
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes - 1e6
+    print(kind, "temp", mem.temp_size_in_bytes, "code",
+          mem.generated_code_size_in_bytes)
+    assert mem.temp_size_in_bytes < (0.5e9 if kind == "decode" else 1.2e9), \
+        mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 16e9
+    text = compiled.as_text()
+    count = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+    calls = (count("ssd_step"), count("paged_attention"))
+    assert calls == ((5, 5) if kind == "decode" else (0, calls[1])), calls
+    assert calls[1] >= (5 if kind != "prefill" else 0)
+    assert "cross_program_prefetch_index" not in text
+    entry = text[text.index("ENTRY"):]
+    assert re.search(r"%state__ssd__", entry)
+    assert re.search(r"%params__layers___1___ssm_w_in__", entry)

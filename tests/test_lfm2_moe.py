@@ -675,7 +675,8 @@ def test_unknown_model_name_raises(jax_cpu):
 
     with pytest.raises(ValueError, match="unknown model family 'mamba'"):
         LLMEngine(EngineConfig(model="mamba"), auto_step=False)
-    assert sorted(FAMILIES) == ["evabyte", "gpt", "laguna", "lfm2_moe",
+    assert sorted(FAMILIES) == ["evabyte", "falcon_h1", "gpt", "laguna",
+                                "lfm2_moe",
                                 "ling_hybrid", "llama", "longcat_flash",
                                 "minicpm_sala", "pangu_ultra_moe",
                                 "sdar_moe", "smallthinker"]

@@ -689,7 +689,7 @@ def test_program_names_and_the_familys_entry(jax_cpu):
         "longcat_flash_decode_step"
     fam = decode.get_family("longcat_flash")
     assert fam.verify_step is None and fam.state_rows is False
-    assert "longcat_flash" in decode.FAMILIES and len(decode.FAMILIES) == 11
+    assert "longcat_flash" in decode.FAMILIES and len(decode.FAMILIES) == 12
     cfg = fam.default_config()
     axes, quant = fam.param_axes(cfg), fam.quant_axes(cfg)
     sub = quant["layers"][0]["sub"][1]

@@ -437,8 +437,8 @@ LAYOUTS = {
     "quantized heads": (dict(_PLAIN, quantization="int8"), None),
     "planes, counters-only state": (_LATENT, None),
     "state rows": (dict(_PLAIN, state_slots=5),
-                   "state rows beside the pool: a piece's short "
-                   "convolution needs the piece before it"),
+                   "state rows beside the pool: a piece's short convolution "
+                   "and its matrix state need the piece before it"),
     "sliding groups": (dict(_PLAIN, groups=((None, (0,)), (8, (1,)))),
                        "tables by group: a window's blocks go back behind "
                        "a position"),

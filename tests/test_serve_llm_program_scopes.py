@@ -21,10 +21,11 @@ SETTINGS = {
     "minicpm_sala": dict(block_size=8, num_blocks=129, max_batch_size=4),
     "ling_hybrid": dict(block_size=8, num_blocks=129, max_batch_size=4),
     "sdar_moe": dict(block_size=8, num_blocks=129, max_batch_size=4),
+    "falcon_h1": dict(block_size=8, num_blocks=129, max_batch_size=4),
 }
 FAMILIES = ("gpt", "llama", "lfm2_moe", "laguna", "evabyte",
             "pangu_ultra_moe", "smallthinker", "longcat_flash",
-            "minicpm_sala", "ling_hybrid", "sdar_moe")
+            "minicpm_sala", "ling_hybrid", "sdar_moe", "falcon_h1")
 HEAVY = re.compile(r" (dot|convolution|ragged-dot|custom-call)\(")
 
 

@@ -693,7 +693,8 @@ def test_the_families_are_served_and_named(jax_cpu):
     from ray_tpu.serve.llm import decode
 
     assert sorted(decode.FAMILIES) == [
-        "evabyte", "gpt", "laguna", "lfm2_moe", "ling_hybrid", "llama",
+        "evabyte", "falcon_h1", "gpt", "laguna", "lfm2_moe", "ling_hybrid",
+        "llama",
         "longcat_flash", "minicpm_sala", "pangu_ultra_moe", "sdar_moe",
         "smallthinker"]
     with pytest.raises(ValueError, match="smallthinker"):
